@@ -1,7 +1,6 @@
 """CI bench-regression gate: fresh CPU runs vs the committed baselines.
 
-The benchmark lineage (BENCH_r01..r05.json at the repo root, distilled
-into benchmarks/results.jsonl) records, per config, the comm-ROUND count
+The benchmark lineage (benchmarks/results.jsonl) records, per config, the comm-ROUND count
 to the certified duality-gap target.  Rounds are the one benchmark axis
 that is backend-independent (the math is bit-exact per platform and
 platform-stable to within a few evals), so CI can guard it on plain CPU
@@ -704,7 +703,7 @@ def evaluate(gate: dict, fresh: dict, committed: dict) -> list:
             f"{cfg}: ROUND REGRESSION — fresh {fresh['rounds']} rounds vs "
             f"committed {base['rounds']} (+{gate['rounds_tol'] * 100:.0f}% "
             f"tolerance = {bound}); a convergence change must update the "
-            f"baseline deliberately (benchmarks/regen.py), not ride in")
+            f"baseline deliberately (benchmarks/run.py), not ride in")
     return failures
 
 

@@ -2,7 +2,7 @@
 
 ``python -m cocoa_tpu.analysis`` lints the package against this repo's
 proven JAX failure classes (donation misses, silent host syncs, f64
-leaks, Pallas budget drift, the jax-0.4.37 mesh-API debt) and exits
+leaks, Pallas budget drift) and exits
 nonzero on any finding that is neither inline-suppressed
 (``# jaxlint: allow=<rule> -- reason``) nor carried by the committed
 baseline with a justification.  See docs/DESIGN.md §10.

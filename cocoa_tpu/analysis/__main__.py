@@ -89,8 +89,6 @@ def main(argv=None) -> int:
         tag = ("" if f.actionable
                else " [baselined]" if f.baselined else " [allowed]")
         print(f"{f.location()}: {f.severity}[{f.rule}]{tag} {f.message}")
-        if f.replacement:
-            print(f"    replacement: {f.replacement}")
 
     for e in stale:
         print(f"stale baseline entry {e['fingerprint']} "

@@ -3,7 +3,7 @@
 The analyzer is **repo-native**: its rules encode this repo's own proven
 failure classes (the PR-2 donation miss, the io_callback ordering
 conventions, the f64-only-in-certificate-math policy, the Pallas budget
-gates, the jax-0.4.37 mesh-API debt) rather than generic style.  The
+gates) rather than generic style.  The
 machinery here is rule-agnostic:
 
 - :class:`Finding` — one diagnostic, with a line-number-independent
@@ -15,8 +15,7 @@ machinery here is rule-agnostic:
   exists to remove;
 - the committed baseline (``cocoa_tpu/analysis/baseline.json``) — known
   findings with justifications.  CI fails only on findings that are
-  neither suppressed nor baselined, so the mesh-API worklist (ROADMAP
-  item 4) can ride along as an inventory without blocking merges;
+  neither suppressed nor baselined;
 - the JSONL report — one ``analysis_manifest`` header line plus one line
   per finding, validated by ``cocoa_tpu/telemetry/schema.py`` (the same
   checker CI runs on every other JSONL artifact this repo emits).
@@ -34,7 +33,7 @@ import re
 import tokenize
 from typing import Iterable, Optional
 
-SEVERITIES = ("error", "warning", "inventory")
+SEVERITIES = ("error", "warning")
 
 # the scan surface: package + benchmark drivers.  tests/ is excluded on
 # purpose — the known-bad rule fixtures live there, and f64 parity
@@ -48,12 +47,11 @@ _ALLOW_RE = re.compile(
 @dataclasses.dataclass
 class Finding:
     rule: str
-    severity: str          # error | warning | inventory
+    severity: str          # error | warning
     path: str              # repo-relative, forward slashes
     line: int              # 1-based
     col: int
     message: str
-    replacement: Optional[str] = None   # mesh-api: the supported API
     fingerprint: str = ""
     suppressed: bool = False            # inline ``jaxlint: allow``
     suppression_reason: Optional[str] = None
@@ -65,8 +63,6 @@ class Finding:
              "path": self.path, "line": self.line, "col": self.col,
              "message": self.message, "fingerprint": self.fingerprint,
              "suppressed": self.suppressed, "baselined": self.baselined}
-        if self.replacement is not None:
-            d["replacement"] = self.replacement
         if self.suppression_reason is not None:
             d["suppression_reason"] = self.suppression_reason
         if self.justification is not None:

@@ -353,6 +353,39 @@ def local_sdca_block(
     return alpha_final - alpha, dw
 
 
+def resolve_block_form(*, sparse: bool, hybrid: bool, k: int, block: int,
+                       d: int, n_shard: int, width: int, itemsize: int,
+                       sparse_gram: "bool | None" = None) -> str:
+    """Which form of the batched block round a configuration runs —
+    ``fused`` | ``split`` | ``sparse_gram`` | ``hybrid``.  The ONE
+    decision :func:`local_sdca_block_batched` dispatches on and the
+    solver-path record (solvers/cocoa.resolve_solver_path) reports, so
+    what a run says it ran is what it ran.
+
+    ``sparse_gram=None`` (auto): the sparse Gram path is the sparse-layout
+    block default whenever the fused kernel cannot hold the densified
+    tile (the rcv1 regime) and the CSR streams fit the SMEM segmentation;
+    on a hybrid layout (``--hotCols``) that path is the hybrid branch and
+    ``width`` is the cold residual's."""
+    from cocoa_tpu.ops.pallas_chain import fused_fits
+    from cocoa_tpu.ops.pallas_sparse import sparse_chain_fits
+
+    fused_ok = fused_fits(k, block, d, itemsize, n_shard)
+    if sparse_gram is None:
+        sparse_gram = (
+            sparse
+            and itemsize == 4
+            and not fused_ok
+            and sparse_chain_fits(k, n_shard, d, width, block, itemsize)
+        )
+    if sparse_gram:
+        if not sparse:
+            raise ValueError("sparse_gram=True requires the padded-CSR "
+                             "(sparse) layout")
+        return "hybrid" if hybrid else "sparse_gram"
+    return "fused" if fused_ok else "split"
+
+
 def local_sdca_block_batched(
     w: jax.Array,          # (d,) shared primal vector (replicated)
     alpha: jax.Array,      # (K, n_shard)
@@ -460,10 +493,7 @@ def local_sdca_block_batched(
     inert there, so a pipelined-vs-serial A/B on a sparse-Gram config
     measures nothing).
     """
-    from cocoa_tpu.ops.pallas_chain import (
-        chain_block_batched, fused_block, fused_fits,
-    )
-    from cocoa_tpu.ops.pallas_sparse import sparse_chain_fits
+    from cocoa_tpu.ops.pallas_chain import chain_block_batched, fused_block
 
     losses.validate(loss, smoothing)
     sig_eff, qii_factor = mode_factors(mode, sigma)
@@ -506,28 +536,19 @@ def local_sdca_block_batched(
 
     gat = lambda v, bidx: jnp.take_along_axis(v, bidx, axis=1)  # noqa: E731
 
-    itemsize = jnp.dtype(dtype).itemsize
-    if sparse_gram is None:
-        # auto: the sparse Gram path is the sparse-layout block default
-        # whenever the fused kernel cannot hold the densified tile (the
-        # rcv1 regime) and the CSR streams fit the SMEM segmentation
-        sparse_gram = (
-            "sp_indices" in shards
-            and itemsize == 4
-            and not fused_fits(k, block, d, itemsize, alpha.shape[1])
-            and sparse_chain_fits(k, alpha.shape[1], d,
-                                  int(shards["sp_indices"].shape[-1]),
-                                  block, itemsize)
-        )
-    if sparse_gram:
+    form = resolve_block_form(
+        sparse="sp_indices" in shards, hybrid="X_hot" in shards, k=k,
+        block=block, d=d, n_shard=alpha.shape[1],
+        width=(int(shards["sp_indices"].shape[-1])
+               if "sp_indices" in shards else 0),
+        itemsize=jnp.dtype(dtype).itemsize, sparse_gram=sparse_gram,
+    )
+    if form in ("sparse_gram", "hybrid"):
         from cocoa_tpu.ops.pallas_sparse import (
             GROUP, row_lengths, sparse_block_apply, sparse_block_gram,
             wd_delta, wd_stack,
         )
 
-        if "sp_indices" not in shards:
-            raise ValueError("sparse_gram=True requires the padded-CSR "
-                             "(sparse) layout")
         sp_idx, sp_val = shards["sp_indices"], shards["sp_values"]
         w_nnz = sp_idx.shape[-1]
         group = min(GROUP, max(1, w_nnz))
@@ -546,7 +567,7 @@ def local_sdca_block_batched(
         # between panel and streams, so gram/mbase/Δw each split exactly
         # (hot + cold permutes the per-nonzero sums; parity pinned by
         # tests/test_hybrid_sparse.py).
-        hybrid = "X_hot" in shards
+        hybrid = form == "hybrid"
         if hybrid:
             xh_all = shards["X_hot"]                  # (K, n_shard, n_hot)
             hot_cols_k = shards["hot_cols"]           # (K, n_hot)
@@ -657,8 +678,7 @@ def local_sdca_block_batched(
         )
         return carry, outs
 
-    if fused_fits(k, block, d, itemsize,
-                  alpha.shape[1]):
+    if form == "fused":
         dw0 = jnp.zeros((k, d), dtype) + 0.0 * w[None]
 
         def fused_call(dw, xb, bidx, yb, qb, live, a0b):

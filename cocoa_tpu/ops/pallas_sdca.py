@@ -76,11 +76,6 @@ SUBLANES = 8  # f32 sublane count: rows fold to (8, d/8)
 VMEM_BUDGET = 12 << 20  # leave ~4 MB of the ~16 MB VMEM for the compiler
 UNROLL_CANDIDATES = (16, 8, 4, 2, 1)
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both so the
-# interpret-mode (CPU CI) tests run on older jax too
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def check_dtype(dtype) -> None:
     """2-byte dtypes are rejected: bf16 SDCA can't certify a 1e-4 duality
@@ -503,7 +498,7 @@ def pallas_sdca_round(
             jax.ShapeDtypeStruct((k, SUBLANES, d8), dtype),
             jax.ShapeDtypeStruct((k, n_blocks, LANES), dtype),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
         interpret=interpret,

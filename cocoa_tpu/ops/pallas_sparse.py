@@ -83,7 +83,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from cocoa_tpu.ops import losses
 from cocoa_tpu.ops.local_sdca import coef_divisor, mode_factors
-from cocoa_tpu.ops.pallas_sdca import COMPILER_PARAMS, LANES, check_dtype
+from cocoa_tpu.ops.pallas_sdca import LANES, check_dtype
 
 ROW_BLOCK = 8          # aligned sublane block for the per-step value row
 SMEM_IDX_BUDGET = 512 << 10
@@ -496,7 +496,7 @@ def pallas_sparse_sdca_round(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -836,7 +836,7 @@ def sparse_block_gram(
             jax.ShapeDtypeStruct((s, lanes_out), dtype),
             jax.ShapeDtypeStruct((1, lanes_out), dtype),
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -943,7 +943,7 @@ def sparse_block_apply(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((n_dblk, 2 * LANES), dtype)],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

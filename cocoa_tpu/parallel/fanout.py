@@ -25,9 +25,7 @@ from cocoa_tpu.parallel.mesh import DP_AXIS, manual_axes
 
 def _to_varying(x):
     """Mark a replicated value as varying over dp (VMA cast inside shard_map)."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (DP_AXIS,), to="varying")
-    return lax.pvary(x, DP_AXIS)  # older jax
+    return lax.pcast(x, (DP_AXIS,), to="varying")
 
 
 def shards_per_device(mesh: Optional[Mesh], k: int) -> int:

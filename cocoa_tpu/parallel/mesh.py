@@ -35,12 +35,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.5: explicit/auto axis types (the fp axis rides Auto)
-    from jax.sharding import AxisType
-except ImportError:  # older jax: dp-only meshes work; fp needs AxisType
-    AxisType = None
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 DP_AXIS = "dp"
 FP_AXIS = "fp"
@@ -77,15 +72,18 @@ def make_mesh(
         )
     if fp == 1:
         return jax.make_mesh((k,), (DP_AXIS,), devices=devices[:need])
-    if AxisType is None:
-        raise ValueError(
-            "feature-parallel (fp) meshes need jax.sharding.AxisType "
-            "(jax >= 0.5); this jax only supports dp meshes"
-        )
     return jax.make_mesh(
         (k, fp), (DP_AXIS, FP_AXIS), devices=devices[:need],
         axis_types=(AxisType.Explicit, AxisType.Auto),
     )
+
+
+def infer_dp_size(k: int, n_devices: int) -> int:
+    """The dp mesh a K-shard run gets when nobody names one: the largest
+    divisor of K that fits the device budget (m = K/D logical shards then
+    multiplex per device; 1 = the single-chip vmap path)."""
+    return max((d for d in range(1, min(k, n_devices) + 1) if k % d == 0),
+               default=1)
 
 
 def has_fp(mesh: Optional[Mesh]) -> bool:

@@ -404,8 +404,20 @@ def io_callback_supported() -> bool:
             jax.jit(probe)(jnp.float32(0.0))[0].block_until_ready()
             jax.effects_barrier()
             _IO_CALLBACK_OK = seen == [0, 1, 2]
-        except Exception:
+            why = f"the probe loop saw {seen}, expected [0, 1, 2]"
+        except Exception as e:
             _IO_CALLBACK_OK = False
+            why = f"{type(e).__name__}: {e}"
+        if not _IO_CALLBACK_OK:
+            # the probe's outcome changes the compiled --deviceLoop
+            # program under --events; say once why it went the other way
+            import warnings
+
+            warnings.warn(
+                f"ordered io_callback is unavailable on this backend "
+                f"({why}); device-loop telemetry replays its events from "
+                f"the end-of-run fetch instead of streaming them live",
+                RuntimeWarning)
     return _IO_CALLBACK_OK
 
 

@@ -290,7 +290,7 @@ ANALYSIS_FINDING_FIELDS = {
     "fingerprint": (str,),
 }
 
-ANALYSIS_SEVERITIES = ("error", "warning", "inventory")
+ANALYSIS_SEVERITIES = ("error", "warning")
 
 
 # benchmarks/results.jsonl: "config" identifies the row; every OTHER known

@@ -1,8 +1,7 @@
 """jaxlint (cocoa_tpu/analysis): per-rule known-good/known-bad fixtures,
-the PR-2 donation-miss regression, the mesh-API inventory completeness
-contract, the baseline/suppression machinery, and the dynamic sanitizer
-smoke on the CPU drive loop (compile-once + zero unintended device→host
-transfers, telemetry-on and -off)."""
+the PR-2 donation-miss regression, the baseline/suppression machinery,
+and the dynamic sanitizer smoke on the CPU drive loop (compile-once +
+zero unintended device→host transfers, telemetry-on and -off)."""
 
 import json
 import os
@@ -281,34 +280,6 @@ def parse(tokens):
     assert len(found) == 1
     assert found[0].suppressed
     assert "exact parse" in found[0].suppression_reason
-
-
-# --- mesh-api inventory -----------------------------------------------------
-
-
-def test_mesh_inventory_complete():
-    """The deprecated/unsupported mesh-API worklist (ROADMAP item 4) is
-    exactly the set jaxlint catalogues — every call site named, each with
-    a supported-API replacement.  If this fails after editing the mesh
-    layer, the refactor either migrated a site (update the count AND the
-    baseline) or introduced a new unsupported call (migrate it)."""
-    findings, _, _ = analysis.run_analysis(with_budget_checks=False)
-    inv = sorted((f.path, f.line, f.message.split("`")[1])
-                 for f in findings if f.rule == "mesh-api")
-    by_file = {}
-    for path, _, api in inv:
-        by_file.setdefault(path, []).append(api)
-    assert by_file == {
-        "cocoa_tpu/parallel/fanout.py": [
-            "lax.pcast", "lax.pvary", "jax.shard_map", "jax.shard_map"],
-        "cocoa_tpu/parallel/mesh.py": [
-            "jax.make_mesh(axis_types=...)", "jax.sharding.AxisType"],
-    }, inv
-    assert len(inv) == 6
-    # every inventory entry must carry its supported-API replacement
-    for f in findings:
-        if f.rule == "mesh-api":
-            assert f.replacement, f.location()
 
 
 # --- pallas-budget ----------------------------------------------------------

@@ -789,9 +789,6 @@ def test_chaos_real_training_shrink_bit_identical(tmp_path, monkeypatch,
     generation's re-ingest must be a full cache hit with ZERO re-parsed
     bytes (the ISSUE-15 shrink contract — shard artifacts are
     geometry-free, so the survivor maps its inherited shards warm)."""
-    if not hasattr(jax, "shard_map"):
-        pytest.skip("the 2-process training gang rides the mesh path, "
-                    "which needs jax.shard_map (newer jax)")
     from cocoa_tpu.data.synth import synth_sparse, write_libsvm
 
     _gang_env(monkeypatch)

@@ -202,7 +202,6 @@ def test_resolve_ingest_mode_rejects_fp_mesh():
 
     if len(jax.devices()) < 4:
         pytest.skip("needs 4 virtual devices")
-    # plain Mesh construction (make_mesh's AxisType path needs newer jax)
     fp_mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
                    ("dp", "fp"))
     with pytest.raises(ValueError, match="feature-parallel"):
@@ -249,11 +248,9 @@ def test_stream_detects_file_change(tmp_path):
 
 # --- the acceptance pin: 2-process streamed multiplexed ≡ replicated ------
 #
-# Two halves, because this container's jax (0.4.37) cannot run jit
-# computations over a multi-process CPU mesh at all (the same known
-# limitation that fails tests/test_multihost.py's solver runs on the
-# seed — "Multiprocess computations aren't implemented on the CPU
-# backend"):
+# Two halves (written when the CPU backend could not run jit computations
+# over a multi-process mesh; moving the real 2-process run into the fast
+# sweep is ROADMAP D2):
 #
 # 1. REAL 2-process build (subprocess workers over jax.distributed/Gloo,
 #    one device each, K=4 multiplexing m=2 per device): every worker
@@ -404,10 +401,7 @@ def test_streamed_multiplexed_trajectory_matches_replicated_control(
 
     data = write_libsvm(tmp_path / "mh.svm")
     params = Params(n=data.n, num_rounds=5, local_iters=10, lam=0.01)
-    # the multiplexed shard_map path needs newer jax; the replicated vmap
-    # arm below still pins streamed-vs-whole trajectory bit-identity here
-    mesh = (make_mesh(2) if len(jax.devices()) >= 2
-            and hasattr(jax, "shard_map") else None)
+    mesh = make_mesh(2) if len(jax.devices()) >= 2 else None
 
     def train(ds, mesh):
         w, alpha, traj = run_cocoa(ds, params,

@@ -314,11 +314,6 @@ def test_stall_watchdog_recovers_wedged_but_alive_gang(tmp_path, monkeypatch):
     tests/test_crash_resume.py; this pins the watchdog mechanics
     end-to-end: detection without a death, teardown, restart, completion).
     """
-    import jax as _jax
-
-    if not hasattr(_jax, "shard_map"):
-        pytest.skip("the 2-process gang rides the mesh path, which needs "
-                    "jax.shard_map (newer jax)")
     from cocoa_tpu import checkpoint as ckpt_lib
     from cocoa_tpu import elastic
     from cocoa_tpu.data.synth import synth_sparse, write_libsvm
