@@ -28,6 +28,7 @@ from cocoa_tpu.data.sharding import ShardedDataset
 from cocoa_tpu.ops import losses
 from cocoa_tpu.ops.rows import eval_margins
 from cocoa_tpu.parallel.fanout import fanout, mesh_of
+from cocoa_tpu.telemetry.tracing import SCOPE_EVAL
 
 
 @functools.lru_cache(maxsize=None)
@@ -73,6 +74,7 @@ def _error_sum_fn(mesh):
     return f
 
 
+@jax.named_scope(SCOPE_EVAL)
 def eval_metrics(
     w, alpha, shard_arrays, lam, n, mesh=None,
     test_shard_arrays=None, test_n: int = 0,

@@ -233,6 +233,7 @@ def chain_block_batched(
             jax.ShapeDtypeStruct((k, b), scal.dtype),
         ],
         interpret=interpret,
+        name="pallas_chain_block",
     )(scal_rows, gq)
     return delta, coefs
 
@@ -505,5 +506,6 @@ def fused_block(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="pallas_chain_fused_block",
     )(xb, idxf, idxf.T, yb, qb, a0, live, v)
     return delta, dwu

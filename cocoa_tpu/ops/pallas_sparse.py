@@ -500,6 +500,7 @@ def pallas_sparse_sdca_round(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="pallas_sparse_sdca_round",
     )
 
     if hot:
@@ -840,6 +841,7 @@ def sparse_block_gram(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="pallas_sparse_block_gram",
     )
 
     def body(carry, xs_p):
@@ -947,6 +949,7 @@ def sparse_block_apply(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
+        name="pallas_sparse_block_apply",
     )
 
     def body(wd_c, xs_p):

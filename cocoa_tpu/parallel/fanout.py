@@ -14,6 +14,7 @@ output across shards.  Two paths with identical math:
 
 from __future__ import annotations
 
+import contextlib
 from typing import Callable, Optional
 
 import jax
@@ -21,11 +22,18 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from cocoa_tpu.parallel.mesh import DP_AXIS, manual_axes
+from cocoa_tpu.telemetry.tracing import SCOPE_DW_REDUCE
 
 
 def _to_varying(x):
     """Mark a replicated value as varying over dp (VMA cast inside shard_map)."""
     return lax.pcast(x, (DP_AXIS,), to="varying")
+
+
+@jax.named_scope(SCOPE_DW_REDUCE)
+def _shard_sum(dw):
+    """Σ over the leading (local shards) axis of the per-shard Δw."""
+    return dw.sum(axis=0)
 
 
 def shards_per_device(mesh: Optional[Mesh], k: int) -> int:
@@ -50,6 +58,7 @@ def fanout(
     mesh: Optional[Mesh],
     w: jax.Array,
     *sharded,
+    reduce_scope: Optional[str] = None,
 ):
     """Run ``per_shard(w, *shard_slices) -> (reduced, aux...)`` over K shards.
 
@@ -62,7 +71,15 @@ def fanout(
     see :func:`shards_per_device`): each device then runs its m local
     shards under an inner vmap, sums their contributions in-device, and
     the cross-device combine stays ONE psum per call either way.
+
+    ``reduce_scope``: the ``jax.named_scope`` the combine runs under — a
+    round's Δw sum passes :data:`SCOPE_DW_REDUCE`; an eval's partial sums
+    pass nothing and stay inside the eval's own scope.
     """
+    def reducing():
+        return (jax.named_scope(reduce_scope) if reduce_scope
+                else contextlib.nullcontext())
+
     if mesh is not None:
         k = jax.tree.leaves(sharded)[0].shape[0]
         m = shards_per_device(mesh, k)
@@ -76,7 +93,9 @@ def fanout(
                 slices = jax.tree.map(lambda a: a[0], slices)
                 out = per_shard(w, *slices)
                 red, aux = out[0], out[1:]
-                return (lax.psum(red, DP_AXIS), *(a[None] for a in aux))
+                with reducing():
+                    red = lax.psum(red, DP_AXIS)
+                return (red, *(a[None] for a in aux))
             # multiplexed: the local (m, ...) block is the single-chip
             # "m logical shards on one device" case — vmap it, sum the
             # reduced outputs in-device, then the same single psum
@@ -84,7 +103,9 @@ def fanout(
                 w, *slices
             )
             red, aux = out[0], out[1:]
-            return (lax.psum(red.sum(axis=0), DP_AXIS), *aux)
+            with reducing():
+                red = lax.psum(red.sum(axis=0), DP_AXIS)
+            return (red, *aux)
 
         in_specs = (P(), *(jax.tree.map(lambda _: P(DP_AXIS), s) for s in sharded))
         # probe output structure abstractly to build out_specs: first output
@@ -104,7 +125,9 @@ def fanout(
     in_axes = (None, *([0] * len(sharded)))
     out = jax.vmap(per_shard, in_axes=in_axes)(w, *sharded)
     red, aux = out[0], out[1:]
-    return (red.sum(axis=0), *aux)
+    with reducing():
+        red = red.sum(axis=0)
+    return (red, *aux)
 
 
 def invariant_from_varying(x):
@@ -170,7 +193,8 @@ def chunk_fanout(
                 def body(c, x):
                     w, carry_k = c
                     dw, carry2 = per_round(w, carry_k, x, static)
-                    w2 = apply_fn(w, lax.psum(dw, DP_AXIS), x)
+                    with jax.named_scope(SCOPE_DW_REDUCE):
+                        w2 = apply_fn(w, lax.psum(dw, DP_AXIS), x)
                     return (w2, carry2), None
             else:
                 # multiplexed (m shards per device): the local (m, ...)
@@ -190,8 +214,9 @@ def chunk_fanout(
                         dw, carry2 = jax.vmap(
                             per_round, in_axes=(None, 0, x_axes, 0)
                         )(w, carry_k, x, static)
-                        dw_local = dw.sum(axis=0)
-                    w2 = apply_fn(w, lax.psum(dw_local, DP_AXIS), x)
+                        dw_local = _shard_sum(dw)
+                    with jax.named_scope(SCOPE_DW_REDUCE):
+                        w2 = apply_fn(w, lax.psum(dw_local, DP_AXIS), x)
                     return (w2, carry2), None
 
             (w, carry), _ = lax.scan(body, (w, carry), xs)
@@ -222,8 +247,10 @@ def chunk_fanout(
             dw, carry2 = jax.vmap(per_round, in_axes=(None, 0, x_axes, 0))(
                 w, carry, x, static_sharded
             )
-            dw_sum = dw.sum(axis=0)
-        return (apply_fn(w, dw_sum, x), carry2), None
+            dw_sum = _shard_sum(dw)
+        with jax.named_scope(SCOPE_DW_REDUCE):
+            w2 = apply_fn(w, dw_sum, x)
+        return (w2, carry2), None
 
     (w, carry), _ = lax.scan(body, (w, carry_sharded), xs_sharded)
     return w, carry

@@ -801,7 +801,8 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
 
         def body(s):
             i, done_tgt, done_stall, stall, best, best_prev, state, traj = s
-            chunk = jax.tree.map(lambda a: a[i], idxs_all)
+            with jax.named_scope(_tracing.SCOPE_INDICES):
+                chunk = jax.tree.map(lambda a: a[i], idxs_all)
             state = chunk_kernel(state, chunk, shard_arrays)
             metrics = eval_kernel(state, shard_arrays, test_arrays)
             done_tgt = metrics[1] <= tgt
@@ -1079,8 +1080,9 @@ def drive_on_device(
                        rounds=n_chunks * c, cadence=c), \
             _sanitize.device_loop_guard(), \
             _tele.device_tap(tap if stream else None):
-        with (_sanitize.allow_transfers() if stream
-              else _ctx.nullcontext()):
+        with _tracing.span("dispatch"), (
+                _sanitize.allow_transfers() if stream
+                else _ctx.nullcontext()):
             i, done_tgt, done_stall, state, traj_buf = run(
                 *state, idxs_all, shard_arrays, test_arrays)
         # the single host sync of the whole run — marked as the
@@ -1088,7 +1090,8 @@ def drive_on_device(
         # (analysis/sanitize.py) can disallow every OTHER device→host
         # path and production --metrics runs count it
         # (host_transfers_total: ~1 per super-block, never per round)
-        with _sanitize.intended_fetch("device_loop_fetch"):
+        with _tracing.span("fetch"), \
+                _sanitize.intended_fetch("device_loop_fetch"):
             n_done = int(i)
             stop_tgt = bool(done_tgt)
             stop_stall = bool(done_stall)
@@ -1105,30 +1108,33 @@ def drive_on_device(
 
     traj = Trajectory(name, quiet=quiet)
     prev_sigma = None
-    for j in range(n_done):
-        end = start_round - 1 + (j + 1) * c
-        primal, gap, test_err = (float(v) for v in traj_host[j, :3])
-        sigma = (sigma_levels[int(traj_host[j, 3])] if anneal else None)
-        traj.log_round(
-            end, primal=primal,
-            # NaN slots mean "not applicable" (no dual state / no test set)
-            # — decode to None exactly as objectives.evaluate does
-            gap=None if np.isnan(gap) else gap,
-            test_error=None if np.isnan(test_err) else test_err,
-            # per-round wall-clock is unobservable here: the whole run is one
-            # dispatch and one fetch — don't fabricate flat timestamps
-            wall_time=None,
-            sigma=sigma,
-            # events for this run were already emitted by the tap (live
-            # stream or fetch replay) — don't double-emit
-            emit=False,
-        )
-        if (not quiet and anneal and prev_sigma is not None
-                and sigma != prev_sigma):
-            print(f"{name}: σ′ anneal — backed off to σ′={sigma:g} in the "
-                  f"device loop at round {end} (iterate kept, certificate "
-                  f"exact)")
-        prev_sigma = sigma
+    with _tracing.span("decode_trajectory"):
+        for j in range(n_done):
+            end = start_round - 1 + (j + 1) * c
+            primal, gap, test_err = (float(v) for v in traj_host[j, :3])
+            sigma = (sigma_levels[int(traj_host[j, 3])] if anneal
+                     else None)
+            traj.log_round(
+                end, primal=primal,
+                # NaN slots mean "not applicable" (no dual state / no test
+                # set) — decode to None exactly as objectives.evaluate does
+                gap=None if np.isnan(gap) else gap,
+                test_error=None if np.isnan(test_err) else test_err,
+                # per-round wall-clock is unobservable here: the whole run
+                # is one dispatch and one fetch — don't fabricate flat
+                # timestamps
+                wall_time=None,
+                sigma=sigma,
+                # events for this run were already emitted by the tap (live
+                # stream or fetch replay) — don't double-emit
+                emit=False,
+            )
+            if (not quiet and anneal and prev_sigma is not None
+                    and sigma != prev_sigma):
+                print(f"{name}: σ′ anneal — backed off to σ′={sigma:g} in "
+                      f"the device loop at round {end} (iterate kept, "
+                      f"certificate exact)")
+            prev_sigma = sigma
     # classify from the device-side stop flags themselves (not from
     # n_done < n_chunks, which misses a guard fire on the FINAL chunk —
     # ADVICE r5): the while_loop carried exactly why it stopped
@@ -1352,19 +1358,28 @@ def drive_device_full(
         start = done + 1
 
         def stage(t0, nb):
-            flat = sampler.chunk_indices(t0, nb * c)
-            reshaped = jax.tree.map(
-                lambda a: a.reshape(nb, c, *a.shape[1:]), flat)
-            if mesh is not None:
-                # committing to the default device would conflict with
-                # the mesh-sharded state at dispatch ("incompatible
-                # devices"); on a mesh let jit place the tables as before
-                return reshaped
-            return jax.tree.map(jax.device_put, reshaped)
+            # on the staging thread: overlaps whatever span the driving
+            # thread holds (wait_indices, or the previous block's solve)
+            with _tracing.span("stage_indices", t0=t0, rounds=nb * c):
+                flat = sampler.chunk_indices(t0, nb * c)
+                reshaped = jax.tree.map(
+                    lambda a: a.reshape(nb, c, *a.shape[1:]), flat)
+                if mesh is not None:
+                    # committing to the default device would conflict with
+                    # the mesh-sharded state at dispatch ("incompatible
+                    # devices"); on a mesh let jit place the tables as
+                    # before
+                    return reshaped
+                return jax.tree.map(jax.device_put, reshaped)
 
-        fut = _Prefetch(stage, start, sizes[0])
+        # wait_indices: the driving thread held up by the staging thread —
+        # starting it, then blocked on its result.  With one block a job
+        # all of block 0's sampling is paid here.
+        with _tracing.span("wait_indices", t0=start):
+            fut = _Prefetch(stage, start, sizes[0])
         for bi, b in enumerate(sizes):
-            idxs_all = fut.result()
+            with _tracing.span("wait_indices", t0=start, rounds=b * c):
+                idxs_all = fut.result()
             if bi + 1 < len(sizes):
                 fut = _Prefetch(stage, start + b * c, sizes[bi + 1])
             state, dev_traj = drive_on_device(
@@ -1573,12 +1588,14 @@ class IndexSampler:
         (chunk calls always are — the permuted stream slices on ts[0])."""
         from cocoa_tpu.utils import prng
 
-        if self.mode == "reference":
-            return prng.device_sample_per_shard(self.seed, ts, self.h,
-                                                self.counts)
-        if self.mode == "permuted":
-            return prng.permuted_tables(self.seed, ts, self.h, self.counts)
-        return prng.hash_tables(self.seed, ts, self.h, self.counts)
+        with jax.named_scope(_tracing.SCOPE_INDICES):
+            if self.mode == "reference":
+                return prng.device_sample_per_shard(self.seed, ts, self.h,
+                                                    self.counts)
+            if self.mode == "permuted":
+                return prng.permuted_tables(self.seed, ts, self.h,
+                                            self.counts)
+            return prng.hash_tables(self.seed, ts, self.h, self.counts)
 
 
 def resolve_sampling(sampling: str, sampler: "IndexSampler",
@@ -2014,12 +2031,14 @@ def drive_fleet_on_device(
                        round=start_round - 1 + n_chunks * c,
                        rounds=n_chunks * c, cadence=c, tenants=t_fleet), \
             _sanitize.device_loop_guard():
-        out = run(*carry.args(), *state, idxs_all, shard_arrays, scal,
-                  gap_targets)
+        with _tracing.span("dispatch"):
+            out = run(*carry.args(), *state, idxs_all, shard_arrays, scal,
+                      gap_targets)
         (i, done_tgt, done_stall, stall, best, best_prev, cert,
          stall_chunk, state, traj_buf) = out
         # the single host sync of the whole fleet block
-        with _sanitize.intended_fetch("fleet_loop_fetch"):
+        with _tracing.span("fetch"), \
+                _sanitize.intended_fetch("fleet_loop_fetch"):
             n_done = int(i)
             traj_host = np.asarray(traj_buf[:n_done])
     carry = FleetCarry(done_tgt, done_stall, stall, best, best_prev,

@@ -27,6 +27,7 @@ from cocoa_tpu.data.sharding import ShardedDataset
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops import local_sdca
 from cocoa_tpu.solvers import base
+from cocoa_tpu.telemetry import tracing as _tracing
 
 
 def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
@@ -380,6 +381,7 @@ def _sdca_round_parts(
         if pallas:
             raise ValueError("the Pallas kernel implies math='fast'")
 
+        @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
         def per_shard(w, alpha_k, idxs_k, shard_k):
             da, dw = local_sdca(
                 w, alpha_k, shard_k, idxs_k, params.lam, params.n,
@@ -408,6 +410,7 @@ def _sdca_round_parts(
             pipeline=block_pipeline,
         )
 
+    @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
     def per_shard(w, alpha_k, idxs_k, shard_k):
         if pallas:
             # only reached inside the chunked mesh driver, which runs its
@@ -443,6 +446,7 @@ def _sdca_round_parts(
     if pallas:
         # the Pallas kernels own the shard axis via their (K, H) grids —
         # used on the single-chip path instead of vmap(per_shard)
+        @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
         def per_round_batched(w, alpha, idxs_kh, shards):
             dw, a_inner = _pallas_batched(
                 w, alpha, idxs_kh, shards, params, mode, sigma,
@@ -454,6 +458,7 @@ def _sdca_round_parts(
         # the batched block kernel advances every shard's chain inside one
         # Pallas instance — vmap(per_shard) would serialize K kernel
         # instances through the grid instead
+        @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
         def per_round_batched(w, alpha, idxs_kh, shards):
             da, dw = block_round(w, alpha, idxs_kh, shards)
             return dw.sum(axis=0), alpha + scaling * da
@@ -469,9 +474,11 @@ def make_round_step(mesh, params: Params, k: int, alg, **parts_kw):
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def round_step(w, alpha, idxs, shard_arrays):
         dw_sum, alpha_new = base.fanout(
-            per_shard, mesh, w, alpha, idxs, shard_arrays
+            per_shard, mesh, w, alpha, idxs, shard_arrays,
+            reduce_scope=_tracing.SCOPE_DW_REDUCE,
         )
-        return apply_fn(w, dw_sum), alpha_new
+        with jax.named_scope(_tracing.SCOPE_DW_REDUCE):
+            return apply_fn(w, dw_sum), alpha_new
 
     return round_step
 
@@ -700,17 +707,22 @@ def run_sdca_family(
             "--dtype=float32, or drop --gapTarget for an uncertified "
             "bf16 run"
         )
-    w = jnp.zeros(ds.num_features, dtype=dtype) if w_init is None else jnp.array(w_init, dtype=dtype, copy=True)
-    alpha = (
-        jnp.zeros((k, ds.n_shard), dtype=dtype)
-        if alpha_init is None
-        else base.align_alpha(alpha_init, ds, dtype)
-    )
-    if mesh is not None:
-        from cocoa_tpu.parallel.mesh import primal_sharding, sharded_rows
+    # init_state (here and where the scheduled paths add their leaves):
+    # the start state is built one tiny device program at a time, each a
+    # launch the device waits for — a named part of a job's fixed cost
+    with _tracing.span("init_state"):
+        w = (jnp.zeros(ds.num_features, dtype=dtype) if w_init is None
+             else jnp.array(w_init, dtype=dtype, copy=True))
+        alpha = (
+            jnp.zeros((k, ds.n_shard), dtype=dtype)
+            if alpha_init is None
+            else base.align_alpha(alpha_init, ds, dtype)
+        )
+        if mesh is not None:
+            from cocoa_tpu.parallel.mesh import primal_sharding, sharded_rows
 
-        w = jax.device_put(w, primal_sharding(mesh))
-        alpha = jax.device_put(alpha, sharded_rows(mesh, extra_dims=1))
+            w = jax.device_put(w, primal_sharding(mesh))
+            alpha = jax.device_put(alpha, sharded_rows(mesh, extra_dims=1))
 
     path = resolve_solver_path(
         ds, params.local_iters, mesh, math=math, pallas=pallas,
@@ -884,6 +896,7 @@ def run_sdca_family(
                     idxs_ckh = sampler.tables_from_ts(idxs_ckh["t"])
                 c_len = idxs_ckh.shape[0]
 
+                @jax.named_scope(_tracing.SCOPE_ACCEL_JUMP)
                 def take_jump(w, alpha):
                     # secant (Anderson-1) jump from the banked window
                     # displacements (solvers/base.py layout note): the
@@ -974,19 +987,22 @@ def run_sdca_family(
                                   sampler.chunk_indices(t0, c),
                                   shard_arrays)
 
-            hist0 = (jnp.zeros((2,) + alpha.shape, dtype=dtype)
-                     if hist_init is None
-                     else jnp.array(hist_init, dtype=dtype, copy=True))
-            sched0 = base.sched_init_array(start_round, sched_init,
-                                           accel=True)
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
+            with _tracing.span("init_state"):
+                hist0 = (jnp.zeros((2,) + alpha.shape, dtype=dtype)
+                         if hist_init is None
+                         else jnp.array(hist_init, dtype=dtype, copy=True))
+                sched0 = base.sched_init_array(start_round, sched_init,
+                                               accel=True)
+                if mesh is not None:
+                    from jax.sharding import (NamedSharding,
+                                              PartitionSpec as P)
 
-                from cocoa_tpu.parallel.mesh import DP_AXIS
+                    from cocoa_tpu.parallel.mesh import DP_AXIS
 
-                hist0 = jax.device_put(
-                    hist0, NamedSharding(mesh, P(None, DP_AXIS)))
-                sched0 = jax.device_put(sched0, NamedSharding(mesh, P()))
+                    hist0 = jax.device_put(
+                        hist0, NamedSharding(mesh, P(None, DP_AXIS)))
+                    sched0 = jax.device_put(sched0,
+                                            NamedSharding(mesh, P()))
             state0 = (w, alpha, hist0, sched0)
         elif scheduled:
             # one statically-specialized kernel per (σ′ stage, loss phase):
@@ -1035,11 +1051,14 @@ def run_sdca_family(
                 return chunk_step(state[0], state[1], state[2],
                                   sampler.chunk_indices(t0, c), shard_arrays)
 
-            sched0 = base.sched_init_array(start_round, sched_init)
-            if mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
+            with _tracing.span("init_state"):
+                sched0 = base.sched_init_array(start_round, sched_init)
+                if mesh is not None:
+                    from jax.sharding import (NamedSharding,
+                                              PartitionSpec as P)
 
-                sched0 = jax.device_put(sched0, NamedSharding(mesh, P()))
+                    sched0 = jax.device_put(sched0,
+                                            NamedSharding(mesh, P()))
             state0 = (w, alpha, sched0)
         else:
             levels = None

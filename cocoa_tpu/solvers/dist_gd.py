@@ -25,12 +25,14 @@ from cocoa_tpu.data.sharding import ShardedDataset
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops import subgradient_pass
 from cocoa_tpu.solvers import base
+from cocoa_tpu.telemetry import tracing as _tracing
 
 
 def _gd_parts(params: Params, k: int):
     lam = params.lam
     beta = params.beta
 
+    @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
     def per_shard_round(w, carry, x, shard_k):
         return (
             subgradient_pass(w, shard_k, lam, loss=params.loss,
@@ -54,8 +56,10 @@ def make_round_step(mesh, params: Params, k: int):
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def round_step(w, t, shard_arrays):
-        (dw_sum,) = base.fanout(per_shard, mesh, w, shard_arrays)
-        return apply_fn(w, dw_sum, {"t": t})
+        (dw_sum,) = base.fanout(per_shard, mesh, w, shard_arrays,
+                                reduce_scope=_tracing.SCOPE_DW_REDUCE)
+        with jax.named_scope(_tracing.SCOPE_DW_REDUCE):
+            return apply_fn(w, dw_sum, {"t": t})
 
     return round_step
 
@@ -109,11 +113,13 @@ def run_dist_gd(
               f"distributed over {k} workers")
 
     dtype = ds.labels.dtype
-    w = jnp.zeros(ds.num_features, dtype=dtype) if w_init is None else jnp.array(w_init, dtype=dtype, copy=True)
-    if mesh is not None:
-        from cocoa_tpu.parallel.mesh import primal_sharding
+    with _tracing.span("init_state"):
+        w = (jnp.zeros(ds.num_features, dtype=dtype) if w_init is None
+             else jnp.array(w_init, dtype=dtype, copy=True))
+        if mesh is not None:
+            from cocoa_tpu.parallel.mesh import primal_sharding
 
-        w = jax.device_put(w, primal_sharding(mesh))
+            w = jax.device_put(w, primal_sharding(mesh))
 
     ts_sampler = base.TsSampler(None, dtype, counts=ds.counts)
     shard_arrays = ds.shard_arrays()

@@ -41,6 +41,7 @@ from cocoa_tpu.data.fleet import FleetDataset
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops.local_sdca import local_sdca, local_sdca_fast
 from cocoa_tpu.solvers import base
+from cocoa_tpu.telemetry import tracing as _tracing
 
 DRIVE_MODES = ("plain", "anneal", "accel")
 
@@ -85,6 +86,7 @@ def _tenant_chunk_parts(params: Params, mode: str, scaling: float,
             return w + scaling * dw_sum
 
         if math == "exact":
+            @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
             def per_shard(w, alpha_k, idxs_k, shard_k):
                 da, dw = local_sdca(
                     w, alpha_k, shard_k, idxs_k, 0.0, 0, mode=mode,
@@ -94,6 +96,7 @@ def _tenant_chunk_parts(params: Params, mode: str, scaling: float,
         else:
             from cocoa_tpu.ops.rows import shard_margins
 
+            @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
             def per_shard(w, alpha_k, idxs_k, shard_k):
                 m0 = shard_margins(w, shard_k)
                 da, dw = local_sdca_fast(
@@ -282,6 +285,7 @@ def run_cocoa_fleet(
             # (w, α) stays a feasible certified pair
             w, alpha, hist, sched = state
 
+            @jax.named_scope(_tracing.SCOPE_ACCEL_JUMP)
             def take_jump(w, alpha):
                 from cocoa_tpu.ops import rows as _rows
 

@@ -55,8 +55,10 @@ from cocoa_tpu.data.sharding import ShardedDataset
 from cocoa_tpu.ops.rows import shard_margins
 from cocoa_tpu.parallel.fanout import fanout
 from cocoa_tpu.solvers.cocoa import run_sdca_family
+from cocoa_tpu.telemetry.tracing import SCOPE_EVAL
 
 
+@jax.named_scope(SCOPE_EVAL)
 def lasso_metrics(r, x, shard_arrays, b, l1: float, l2: float, mesh=None):
     """(primal, gap, NaN) for the elastic-net objective, as one stacked
     device array — one fan-out over the column shards (Σ|x|, Σx², the

@@ -28,6 +28,7 @@ from cocoa_tpu.data.sharding import ShardedDataset
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops import local_sgd
 from cocoa_tpu.solvers import base
+from cocoa_tpu.telemetry import tracing as _tracing
 
 
 def _sgd_parts(params: Params, k: int, local: bool):
@@ -44,6 +45,7 @@ def _sgd_parts(params: Params, k: int, local: bool):
         eta = 1.0 / (lam * t)  # SGD.scala:44
         return w * (1.0 - eta * lam)  # driver-side pre-scale (SGD.scala:46-50)
 
+    @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
     def per_shard_round(w, carry, x, shard_k):
         t = x["t"]
         t_global = (t - 1.0) * h * k  # SGD.scala:53
@@ -70,9 +72,11 @@ def make_round_step(mesh, params: Params, k: int, local: bool):
     @functools.partial(jax.jit, donate_argnums=(0,))
     def round_step(w, idxs, t, shard_arrays):
         (dw_sum,) = base.fanout(
-            per_shard, mesh, w, idxs, _rep(t, k), shard_arrays
+            per_shard, mesh, w, idxs, _rep(t, k), shard_arrays,
+            reduce_scope=_tracing.SCOPE_DW_REDUCE,
         )
-        return apply_fn(w, dw_sum, {"t": t})
+        with jax.named_scope(_tracing.SCOPE_DW_REDUCE):
+            return apply_fn(w, dw_sum, {"t": t})
 
     return round_step
 
@@ -146,11 +150,13 @@ def run_sgd(
               f"data examples, distributed over {k} workers")
 
     dtype = ds.labels.dtype
-    w = jnp.zeros(ds.num_features, dtype=dtype) if w_init is None else jnp.array(w_init, dtype=dtype, copy=True)
-    if mesh is not None:
-        from cocoa_tpu.parallel.mesh import primal_sharding
+    with _tracing.span("init_state"):
+        w = (jnp.zeros(ds.num_features, dtype=dtype) if w_init is None
+             else jnp.array(w_init, dtype=dtype, copy=True))
+        if mesh is not None:
+            from cocoa_tpu.parallel.mesh import primal_sharding
 
-        w = jax.device_put(w, primal_sharding(mesh))
+            w = jax.device_put(w, primal_sharding(mesh))
 
     sampler = base.IndexSampler(rng, debug.seed, params.local_iters, ds.counts)
     sampler.device = base.resolve_sampling(sampling, sampler,

@@ -19,14 +19,16 @@ until its final host sync.  This package closes that gap:
   histogram) — what elastic.py's supervisor and external scrapers watch.
 - :mod:`cocoa_tpu.telemetry.schema` — the JSONL schema checker shared by
   the tests and CI (event streams, trajectory dumps, benchmark results).
-- :mod:`cocoa_tpu.telemetry.profiling` — the profiler capture/summarize
-  core (promoted from benchmarks/trace.py so production runs and
-  benchmarks share one implementation) and the round-windowed
+- :mod:`cocoa_tpu.telemetry.profiling` — the round-windowed
   ``--profile=<dir>,<start>,<stop>`` capture riding the event stream.
-- :mod:`cocoa_tpu.telemetry.tracing` — gang-wide span tracing
-  (``--trace``): per-phase, per-worker timed spans emitted through the
-  bus as typed ``span`` events (ingest passes, KV exchanges, local-solve
-  super-blocks, eval windows, checkpoints, supervisor generations).
+- :mod:`cocoa_tpu.telemetry.tracing` — the program's phases, named: on
+  the host, spans (ingest passes, KV exchanges, the drive ladder's
+  state init / index wait / dispatch / fetch, eval windows, checkpoints,
+  supervisor generations) that always open a ``cocoa/<phase>`` profiler
+  annotation and, under ``--trace``, are also emitted through the bus
+  as typed per-worker ``span`` events; inside ``jit``, the
+  ``jax.named_scope`` names of local solve, Δw reduce, certificate eval,
+  index sampling and the ``--accel`` jump.
 - :mod:`cocoa_tpu.telemetry.trace_report` — the offline assembler:
   merges a gang's per-process span streams, exports Perfetto/Chrome
   trace JSON, computes the per-round critical path, and attributes

@@ -1,15 +1,8 @@
-"""Profiler capture, trace summarization, and round-windowed capture.
+"""Round-windowed ``jax.profiler`` capture (``--profile``).
 
-The capture/summarize core lived in benchmarks/trace.py (VERDICT r3 item
-8: record what the hardware actually did, not just the analytic roofline).
-It is promoted here so production runs and benchmarks share ONE
-implementation: benchmarks/trace.py now imports :func:`capture`,
-:func:`parse_trace` and :func:`device_table` from this module, and the CLI
-exposes the same machinery as ``--profile=<dir>[,<start>,<stop>]``.
-
-The round window: a whole-run trace of a production run is dominated by
-compile + warmup and can reach GBs; what a perf question usually needs is
-a few steady-state rounds.  :class:`RoundWindowProfiler` subscribes to the
+A whole-run trace of a production run is dominated by compile + warmup
+and can reach GBs; what a perf question usually needs is a few
+steady-state rounds.  :class:`RoundWindowProfiler` subscribes to the
 telemetry event bus and starts/stops ``jax.profiler`` when the
 ``round_eval`` stream crosses the requested round bounds — which works on
 the device-resident driver precisely BECAUSE the io_callback bridge emits
@@ -17,85 +10,16 @@ evals while the ``lax.while_loop`` is still running (a post-hoc trigger
 would fire after the loop already finished).  On the fallback (replayed)
 bridge the events arrive at the end-of-run fetch, so the window degrades
 to a no-op capture — live streaming is what makes windowed capture real.
+
+What such a capture holds of the program itself: the ``cocoa/<phase>``
+host spans and the ``cocoa_*`` device scopes (telemetry/tracing.py).  The
+reduction of a trace to numbers is the benchmark's (chipbench/), not this
+package's.
 """
 
 from __future__ import annotations
 
-import glob
-import gzip
-import json
 import os
-from collections import defaultdict
-
-
-def capture(tag, run_fn, out_root):
-    """Run ``run_fn`` under the profiler; return the capture directory."""
-    import shutil
-
-    import jax
-
-    tdir = os.path.join(out_root, tag)
-    # start clean: the profiler appends new session dirs, and parse_trace
-    # globs recursively — stale captures would silently mix into the
-    # aggregation (observed: a re-capture summed two generations of ops).
-    # A rmtree failure must be LOUD for the same reason.
-    if os.path.exists(tdir):
-        shutil.rmtree(tdir)
-    os.makedirs(tdir, exist_ok=True)
-    jax.profiler.start_trace(tdir)
-    try:
-        run_fn()
-    finally:
-        jax.profiler.stop_trace()
-    return tdir
-
-
-def parse_trace(tdir):
-    """Aggregate complete events from the Perfetto trace.json.gz files:
-    {track_name: {op_name: total_us}}."""
-    out = defaultdict(lambda: defaultdict(float))
-    for path in glob.glob(os.path.join(
-            tdir, "**", "*.trace.json.gz"), recursive=True):
-        with gzip.open(path, "rt") as f:
-            data = json.load(f)
-        events = data.get("traceEvents", [])
-        # map (pid, tid) -> track name from metadata events
-        pids = {}
-        tids = {}
-        for e in events:
-            if e.get("ph") == "M" and e.get("name") == "process_name":
-                pids[e.get("pid")] = e["args"].get("name", "")
-            if e.get("ph") == "M" and e.get("name") == "thread_name":
-                tids[(e.get("pid"), e.get("tid"))] = e["args"].get("name", "")
-        for e in events:
-            if e.get("ph") != "X":
-                continue
-            pname = pids.get(e.get("pid"), "")
-            tname = tids.get((e.get("pid"), e.get("tid")), "")
-            track = f"{pname}/{tname}".strip("/")
-            out[track][e.get("name", "?")] += float(e.get("dur", 0.0))
-    return {k: dict(v) for k, v in out.items()}
-
-
-def device_table(tracks, top=18):
-    """The device-side op table: the track(s) that look like TPU op
-    streams (XLA ops land on '/device:TPU... XLA Ops'-style threads).
-    Control-flow container events (while/cond shells) are excluded — their
-    durations INCLUDE their children and would double-count every loop
-    body op."""
-    rows = []
-    for track, ops in tracks.items():
-        low = track.lower()
-        if not ("tpu" in low or "device" in low):
-            continue
-        if "xla op" not in low and "step" not in low and "ops" not in low:
-            continue
-        for name, us in ops.items():
-            if name.split(".")[0] in ("while", "cond", "conditional"):
-                continue
-            rows.append((track, name, us))
-    rows.sort(key=lambda r: -r[2])
-    return rows[:top], sum(r[2] for r in rows)
 
 
 def parse_profile_flag(value: str):

@@ -20,19 +20,31 @@ Design constraints, in order:
   (cocoa_tpu/analysis) enforces the corollary statically: a span
   enter/exit must never appear inside jit/lax bodies, where it would be
   a trace-time no-op at best and a host sync at worst.
-- **Inert by default.**  ``span()`` on a disabled tracer yields a shared
-  null context — one attribute read and no allocation beyond the
-  contextmanager frame — so the instrumented call sites cost nothing on
-  untraced runs.
-- **Clock model** (docs/DESIGN.md "Observability"): durations come from
-  ``time.monotonic()`` (immune to NTP steps mid-span); the placement of
-  a span on the merged timeline comes from its wall-clock ``start_ts``
-  (``time.time()`` at enter).  Cross-process alignment is therefore
-  wall-clock-grade (NTP skew bounds it); per-span durations — what the
-  critical path and straggler slack are computed from — are exact per
-  process.  Within one process, nesting is tracked by a thread-local
-  stack, so a span's ``parent_id`` names the span it ran inside (the
-  KV gets inside an allgather inside a round).
+- **Two halves, two clocks** (docs/DESIGN.md "Observability").  Every
+  span, armed or not, opens a ``jax.profiler.TraceAnnotation`` named
+  ``cocoa/<phase>`` for its lifetime: with no profiler session running
+  that is a ``TraceMe`` that records nothing (2 us a span, measured), and
+  under ``jax.profiler`` it lands in the ``.xplane.pb`` on the
+  *profiler's* clock, the one the device's ops are on — which is what
+  lets a reader put each idle gap of the device down to the phase the
+  host was in (chipbench/phases.py).  The *bus half* — ids, ``start_ts``,
+  ``dur_s``, the ``span`` event — exists only on an armed tracer with an
+  active bus and keeps the *host's* clocks: durations from
+  ``time.monotonic()`` (immune to NTP steps mid-span), placement on the
+  merged timeline from wall-clock ``start_ts`` (``time.time()`` at
+  enter).  Cross-process alignment is therefore wall-clock-grade (NTP
+  skew bounds it); per-span durations — what the critical path and
+  straggler slack are computed from — are exact per process.  Within
+  one process, nesting is tracked by a thread-local stack, so a span's
+  ``parent_id`` names the span it ran inside (the KV gets inside an
+  allgather inside a round).
+- **Inert by default.**  On a disabled tracer ``span()`` is the
+  annotation and one attribute read: no id, no clock read, no event.
+- **Device scopes.**  Inside ``jit`` nothing can be timed from the host;
+  there the phases carry ``jax.named_scope`` names (:data:`SCOPES`), which
+  change an op's metadata and nothing else, and reach the profiler's
+  file as the scope path of every device op.  One name per phase, no
+  ``/`` in a name, the same names on every drive path.
 
 Span event fields: ``phase`` (the instrument point's name), ``span_id``
 / ``parent_id`` (per-process, thread-safe counter), ``worker`` (the
@@ -48,6 +60,21 @@ import functools
 import itertools
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
+
+# the profiler-clock name of a span: ``cocoa/<phase>``
+ANNOTATION_PREFIX = "cocoa/"
+
+# jax.named_scope names of the phases inside jit (what runs on the device)
+SCOPE_LOCAL_SOLVE = "cocoa_local_solve"   # one round's per-shard solve
+SCOPE_DW_REDUCE = "cocoa_dw_reduce"       # the dw sum/psum and its apply
+SCOPE_EVAL = "cocoa_eval"                 # the certificate evaluation
+SCOPE_INDICES = "cocoa_indices"           # a round's sampled row indices
+SCOPE_ACCEL_JUMP = "cocoa_accel_jump"     # --accel: the secant jump, and the
+                                          # pass over the rows that moves w
+SCOPES = (SCOPE_LOCAL_SOLVE, SCOPE_DW_REDUCE, SCOPE_EVAL, SCOPE_INDICES,
+          SCOPE_ACCEL_JUMP)
 
 
 class Tracer:
@@ -88,41 +115,42 @@ class Tracer:
     def span(self, phase: str, **attrs):
         """Time one phase execution; emits the ``span`` event at exit.
 
-        Yields the span id (or None when disabled).  The event is
-        emitted even when the body raises — a phase that died mid-way
-        is exactly what the flight recorder wants on its ring — with
-        an ``error`` attribute naming the exception type.
+        Always open for the span's lifetime: the profiler annotation
+        ``cocoa/<phase>``.  Yields the span id (or None when disabled).
+        The event is emitted even when the body raises — a phase that
+        died mid-way is exactly what the flight recorder wants on its
+        ring — with an ``error`` attribute naming the exception type.
         """
-        if not self.enabled:
-            yield None
-            return
-        from cocoa_tpu.telemetry import events as _events
+        with TraceAnnotation(ANNOTATION_PREFIX + phase):
+            bus = None
+            if self.enabled:
+                from cocoa_tpu.telemetry import events as _events
 
-        bus = _events.get_bus()
-        if not bus.active():
-            yield None
-            return
-        sid = next(self._ids)
-        stack = self._stack()
-        parent = stack[-1] if stack else None
-        stack.append(sid)
-        start_ts = time.time()
-        t0 = time.monotonic()
-        err = None
-        try:
-            yield sid
-        except BaseException as e:
-            err = type(e).__name__
-            raise
-        finally:
-            dur = time.monotonic() - t0
-            stack.pop()
-            fields = dict(phase=str(phase), span_id=sid, parent_id=parent,
-                          worker=self.worker, start_ts=start_ts,
-                          dur_s=dur, **attrs)
-            if err is not None:
-                fields["error"] = err
-            bus.emit("span", **fields)
+                bus = _events.get_bus()
+            if bus is None or not bus.active():
+                yield None
+                return
+            sid = next(self._ids)
+            stack = self._stack()
+            parent = stack[-1] if stack else None
+            stack.append(sid)
+            start_ts = time.time()
+            t0 = time.monotonic()
+            err = None
+            try:
+                yield sid
+            except BaseException as e:
+                err = type(e).__name__
+                raise
+            finally:
+                dur = time.monotonic() - t0
+                stack.pop()
+                fields = dict(phase=str(phase), span_id=sid,
+                              parent_id=parent, worker=self.worker,
+                              start_ts=start_ts, dur_s=dur, **attrs)
+                if err is not None:
+                    fields["error"] = err
+                bus.emit("span", **fields)
 
     def traced(self, phase: str, **attrs):
         """Decorator form: ``@tracer.traced("checkpoint_save")``."""

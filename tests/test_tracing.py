@@ -4,10 +4,17 @@
 What these tests pin:
 
 - **span mechanics**: nesting/parent ids, the decorator form, the error
-  attribute, and total inertness when the tracer or the bus is off;
+  attribute, and no event when the tracer or the bus is off — while the
+  profiler annotation ``cocoa/<phase>`` opens either way;
 - **the acceptance pin**: tracing-on ``(w, alpha)`` and the sched leaf
   are bit-identical to tracing-off — spans are host-side bookkeeping
   and may not perturb the run, exactly like the PR-4 telemetry bridge;
+  and the same for the device scopes: under a profiler session, with
+  the ``jax.named_scope`` names, and with every name stripped, one
+  lowered computation and one ``(w, alpha, trajectory)``;
+- **the scope names** in the lowered device loop: each of
+  ``tracing.SCOPES`` present for hinge, logistic and a mesh, none inside
+  another;
 - **trace_report**: merged multi-worker streams yield a schema-valid
   Chrome/Perfetto trace, a nonempty per-round critical path over LEAF
   spans (no parent/child double counting), and a straggler table whose
@@ -30,7 +37,6 @@ import os
 import signal
 import subprocess
 import sys
-import time
 
 import numpy as np
 import pytest
@@ -116,6 +122,64 @@ def test_disabled_tracer_and_inert_bus_emit_nothing(tmp_path):
     assert [e for e in events if e["event"] == "span"] == []
 
 
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records the names
+    opened and closed."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        outer = self
+
+        class _One:
+            def __enter__(self):
+                outer.log.append(("open", name))
+
+            def __exit__(self, *exc):
+                outer.log.append(("close", name))
+
+        return _One()
+
+
+@pytest.mark.parametrize("armed", [False, True])
+def test_span_opens_the_profiler_annotation_armed_or_not(monkeypatch,
+                                                         armed):
+    """The profiler half of a span is always on: a disarmed tracer (and
+    an armed one with an inert bus) opens ``cocoa/<phase>`` for the
+    span's lifetime, nested like the spans, and emits no event."""
+    notes = _Annotations()
+    monkeypatch.setattr(tracing, "TraceAnnotation", notes)
+    events = _collect()
+    tele_events.get_bus().reset()               # inert bus
+    if armed:
+        tracing.configure(enabled=True, worker=0)
+    with tracing.span("local_solve", round=3) as outer:
+        with tracing.span("fetch") as inner:
+            pass
+    with pytest.raises(KeyError):
+        with tracing.span("doomed"):
+            raise KeyError("x")
+    assert outer is None and inner is None and events == []
+    assert notes.log == [
+        ("open", "cocoa/local_solve"), ("open", "cocoa/fetch"),
+        ("close", "cocoa/fetch"), ("close", "cocoa/local_solve"),
+        ("open", "cocoa/doomed"), ("close", "cocoa/doomed")]
+
+
+def test_armed_span_keeps_its_bus_half_inside_the_annotation(monkeypatch):
+    notes = _Annotations()
+    monkeypatch.setattr(tracing, "TraceAnnotation", notes)
+    events = _collect()
+    tracing.configure(enabled=True, worker=1)
+    with tracing.span("eval", round=9) as sid:
+        assert notes.log == [("open", "cocoa/eval")]
+    (ev,) = [e for e in events if e["event"] == "span"]
+    assert ev["span_id"] == sid and ev["phase"] == "eval"
+    assert ev["round"] == 9 and ev["worker"] == 1
+    assert notes.log[-1] == ("close", "cocoa/eval")
+
+
 # --- the acceptance pin: tracing must not perturb the run --------------------
 
 
@@ -160,6 +224,138 @@ def test_tracing_on_vs_off_state_bit_identical(tmp_path):
             assert m1["sched"] == m2["sched"], nm
 
 
+# --- the device scopes: names only -------------------------------------------
+
+
+@pytest.fixture
+def lowered(monkeypatch):
+    """Every device loop built while the fixture is live leaves its
+    lowered text here: ``(with debug info, without)``."""
+    from cocoa_tpu.solvers import base
+
+    texts = []
+    build = base._build_device_run
+
+    def capturing(*args, **kw):
+        run = build(*args, **kw)
+
+        def call(*run_args):
+            low = run.lower(*run_args)
+            texts.append((low.as_text(debug_info=True), low.as_text()))
+            return run(*run_args)
+
+        return call
+
+    monkeypatch.setattr(base, "_build_device_run", capturing)
+    base._DEVICE_RUNS.clear()
+    yield texts
+    base._DEVICE_RUNS.clear()
+
+
+def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off"):
+    import jax
+
+    from cocoa_tpu.data.synth import synth_dense_sharded
+
+    ds = synth_dense_sharded(256, 32, K, seed=1, mesh=mesh)
+    params = Params(n=ds.n, num_rounds=20, local_iters=16, lam=1e-2,
+                    loss=loss)
+    w, alpha, traj = run_cocoa(
+        ds, params, DebugParams(debug_iter=5, seed=0), plus=True,
+        quiet=True, math="fast", device_loop=True, rng="permuted",
+        gap_target=1e-9, mesh=mesh, pallas=pallas, accel=accel)
+    jax.block_until_ready((w, alpha))
+    return (np.asarray(w), np.asarray(alpha),
+            [(r.round, r.primal, r.gap) for r in traj.records])
+
+
+def _loc_names(text):
+    import re
+
+    return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+@pytest.mark.parametrize("case", ["hinge_pallas", "logistic",
+                                  "mesh_accel"])
+def test_lowered_device_loop_carries_each_scope_once(lowered, case):
+    """The scope names reach the lowered loop — the kernel and its glue
+    under the local solve, the dw sum and apply, the certificate eval,
+    the index tables, and under ``--accel`` the secant jump — on the
+    batched Pallas path (interpreted here), the vmapped logistic path and
+    a 4-device mesh; no name sits inside another, so an op belongs to one
+    phase."""
+    from cocoa_tpu.parallel import make_mesh
+
+    kw = {"hinge_pallas": dict(pallas=True), "logistic": dict(
+        loss="logistic"), "mesh_accel": dict(mesh=make_mesh(4),
+                                             accel="on")}[case]
+    _loop_run(**kw)
+    names = _loc_names(lowered[-1][0])
+    for scope in tracing.SCOPES:
+        jump = scope == tracing.SCOPE_ACCEL_JUMP
+        assert any(scope in n for n in names) == (
+            not jump or case == "mesh_accel"), (scope, case)
+        assert "/" not in scope
+    assert [n for n in names
+            if sum(n.count(sc) for sc in tracing.SCOPES) > 1] == []
+    if case == "hinge_pallas":      # the kernel call itself is inside
+        assert any(n.startswith(tracing.SCOPE_LOCAL_SOLVE + "/jit(pallas")
+                   for n in names)
+
+
+def test_traced_scoped_and_plain_runs_are_one_computation(lowered,
+                                                          monkeypatch,
+                                                          tmp_path):
+    """Scopes and annotations are names: a run under a live profiler
+    session, a plain run with the scopes, and a run with every scope name
+    stripped lower to the same computation and return the same bits."""
+    import jax
+
+    scoped = _loop_run()
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with jax.profiler.TraceAnnotation("job"):
+            traced = _loop_run()
+    finally:
+        jax.profiler.stop_trace()
+    # strip: named_scope's context manager, made a no-op
+    cm = type(jax.named_scope("x"))
+    monkeypatch.setattr(cm, "__enter__", lambda self: None)
+    monkeypatch.setattr(cm, "__exit__", lambda self, *exc: None)
+    from cocoa_tpu.solvers import base
+
+    base._DEVICE_RUNS.clear()
+    plain = _loop_run()
+    monkeypatch.undo()
+    (scoped_dbg, scoped_txt), (_, traced_txt), (plain_dbg, plain_txt) = \
+        lowered
+    assert all(sc in scoped_dbg for sc in tracing.SCOPES[:4])
+    assert not any(sc in plain_dbg for sc in tracing.SCOPES)
+    assert scoped_txt == traced_txt == plain_txt
+    for a, b in ((scoped, traced), (scoped, plain)):
+        np.testing.assert_array_equal(a[0], b[0])
+        np.testing.assert_array_equal(a[1], b[1])
+        assert a[2] == b[2]
+    # and the profile holds the drive ladder's spans on the job's thread,
+    # the staging thread's on another
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    lines = [{ev.name for ev in line.events
+              if ev.name == "job" or ev.name.startswith("cocoa/")}
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines]
+    (driving,) = [names for names in lines if "job" in names]
+    assert driving >= {"cocoa/init_state", "cocoa/wait_indices",
+                       "cocoa/local_solve", "cocoa/dispatch", "cocoa/fetch",
+                       "cocoa/decode_trajectory"}
+    assert "cocoa/stage_indices" not in driving
+    assert any("cocoa/stage_indices" in names for names in lines)
+
+
 def test_span_stream_schema_valid_and_round_attributed(tmp_path):
     """The device-loop run's spans validate as events and trace_report
     attributes the ladder's spans to rounds via their own round attrs."""
@@ -184,23 +380,48 @@ def test_span_stream_schema_valid_and_round_attributed(tmp_path):
 # --- trace_report unit -------------------------------------------------------
 
 
+class _Clock:
+    """The two clocks a span's bus half reads, moved by hand: a stream
+    built on it holds exactly the durations the test wrote down, however
+    loaded the machine is."""
+
+    def __init__(self, start=1_000.0):
+        self.now = start
+
+    def time(self):
+        return self.now
+
+    def monotonic(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += seconds
+
+
 def _synthetic_streams(tmp_path, skew=0.01, rounds=(1, 2)):
+    """Two workers' span streams; worker 1's kv_allgather takes ``skew``
+    longer.  The tracer's clock is a :class:`_Clock` for the duration."""
     paths = []
-    for w in (0, 1):
+    clock, real = _Clock(), tracing.time
+    tracing.time = clock
+    try:
+        for w in (0, 1):
+            tele_events.get_bus().reset()
+            tracing.reset()
+            p = str(tmp_path / f"ev{w}.jsonl")
+            paths.append(p)
+            tele_events.get_bus().configure(jsonl_path=p)
+            tracing.configure(enabled=True, worker=w)
+            for t in rounds:
+                with tracing.span("round", round=t):
+                    with tracing.span("kv_allgather"):
+                        clock.sleep(0.002 + (skew if w == 1 else 0.0))
+                    with tracing.span("local_step"):
+                        clock.sleep(0.002)
+    finally:
+        tracing.time = real
         tele_events.get_bus().reset()
         tracing.reset()
-        p = str(tmp_path / f"ev{w}.jsonl")
-        paths.append(p)
-        tele_events.get_bus().configure(jsonl_path=p)
-        tracing.configure(enabled=True, worker=w)
-        for t in rounds:
-            with tracing.span("round", round=t):
-                with tracing.span("kv_allgather"):
-                    time.sleep(0.002 + (skew if w == 1 else 0.0))
-                with tracing.span("local_step"):
-                    time.sleep(0.002)
-    tele_events.get_bus().reset()
-    tracing.reset()
     return paths
 
 
@@ -216,10 +437,11 @@ def test_trace_report_merge_critical_path_and_stragglers(tmp_path):
         phases = {e["phase"] for e in c["entries"]}
         assert phases == {"kv_allgather", "local_step"}
         assert all(e["workers"] == 2 for e in c["entries"])
-        assert c["critical_s"] >= 0.004
+        # the slowest worker per phase: 12 ms of allgather + 2 ms of step
+        assert c["critical_s"] == pytest.approx(0.014)
     rows = trace_report.stragglers(spans)
     assert rows[0]["worker"] == 1 and rows[0]["phase"] == "kv_allgather"
-    assert rows[0]["slack_s"] > 0.01
+    assert rows[0]["slack_s"] == pytest.approx(0.02)    # 10 ms a round
     assert {(r["worker"], r["phase"]) for r in rows} == {
         (0, "kv_allgather"), (0, "local_step"),
         (1, "kv_allgather"), (1, "local_step")}
