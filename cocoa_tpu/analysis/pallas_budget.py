@@ -28,13 +28,23 @@ import importlib
 
 from cocoa_tpu.analysis.core import Finding
 
-# physical caps (pallas_guide: VMEM ~16 MB/core; SMEM "small" — the
-# repo's scalar streams must stay well under 1 MiB)
+# caps.  PHYS_VMEM is what a kernel gets when it sets no limit: Mosaic's
+# default scoped VMEM, 16 MiB.  A v5e core has 128 MiB (measured, PR 26:
+# a kernel with an 84 MB scratch compiles and runs there under
+# ``vmem_limit_bytes`` = 100 MiB); a module that asks for more than the
+# default declares the limit it passes beside its budget
+# (``<NAME>_VMEM_LIMIT`` for ``<NAME>_VMEM_BUDGET``:
+# ops/pallas_sparse_hbm.py sets ``HBM_VMEM_LIMIT`` = 100 MiB for
+# ``HBM_VMEM_BUDGET`` = 88 MiB), and the budget is then held to that
+# limit, the limit to the core.  SMEM is 1 MiB in all (a kernel whose
+# tables take 1,026 KB does not compile).
 PHYS_VMEM = 16 << 20
+PHYS_VMEM_CORE = 128 << 20
 PHYS_SMEM = 1 << 20
 
 _OPS_MODULES = ("cocoa_tpu.ops.pallas_sdca", "cocoa_tpu.ops.pallas_sparse",
-                "cocoa_tpu.ops.pallas_chain")
+                "cocoa_tpu.ops.pallas_chain",
+                "cocoa_tpu.ops.pallas_sparse_hbm")
 
 # dispatch-realistic sweep: (k, n_shard, d, max_nnz, b, n_hot) covering
 # rcv1 production geometry (d=47236, ~170k rows over K=4, row width 548
@@ -48,6 +58,18 @@ _SHAPES = (
     (8,  65536, 16384,     128, 512,   512),
     (1,    128,   256,       8,  64,     0),   # single-shard corner
     (16, 32768, 47236,    1024, 128,     0),   # fat rows: should NOT fit
+    (8, 2408016, 29890095,  64, 512,     0),   # kddb: past VMEM, the
+                                               # HBM-state kernel's shape
+)
+# (d, max_nnz, h) of the HBM-state sparse kernel's plan: kddb at
+# localIterFrac 0.1 and 1, rcv1-full, and rows as wide as SMEM allows
+_HBM_SHAPES = (
+    (29890095,   64,  240801),
+    (29890095,   64, 2408013),
+    (47236,     548,    8467),
+    (3231961,   414,   29951),
+    (16609143, 4096,    2187),
+    (16609143, 16384,   2187),                 # too wide: no plan
 )
 
 
@@ -79,11 +101,20 @@ def check_budget_constants() -> list:
                 continue
             cap = PHYS_SMEM if "SMEM" in name else PHYS_VMEM
             kind = "SMEM" if "SMEM" in name else "VMEM"
+            limit = getattr(mod, name.replace("BUDGET", "LIMIT"), None)
+            if kind == "VMEM" and isinstance(limit, int):
+                # the module asks Mosaic for more than the default
+                if limit > PHYS_VMEM_CORE - (16 << 20):
+                    flag(1, f"{name.replace('BUDGET', 'LIMIT')} = {limit} "
+                            f"bytes leaves under 16 MiB of a core's "
+                            f"{PHYS_VMEM_CORE} bytes of VMEM")
+                cap = limit - (8 << 20)     # spills, semaphores, buffers
             if val > cap:
                 flag(1, f"{name} = {val} bytes exceeds the physical "
                         f"{kind} cap ({cap}) — the fits gates admit "
                         f"kernels the hardware cannot hold")
-            elif "SMEM" not in name and val > PHYS_VMEM - (1 << 20):
+            elif "SMEM" not in name and limit is None \
+                    and val > PHYS_VMEM - (1 << 20):
                 flag(1, f"{name} = {val} bytes leaves under 1 MiB of "
                         f"VMEM headroom for Mosaic spills/semaphores",
                      severity="warning")
@@ -99,6 +130,7 @@ def check_gate_estimate_agreement() -> list:
     sdca = importlib.import_module("cocoa_tpu.ops.pallas_sdca")
     sparse = importlib.import_module("cocoa_tpu.ops.pallas_sparse")
     chain = importlib.import_module("cocoa_tpu.ops.pallas_chain")
+    hbm = importlib.import_module("cocoa_tpu.ops.pallas_sparse_hbm")
     itemsize = 4  # f32, the TPU compute dtype (DESIGN.md §6)
 
     def flag(modname, message):
@@ -107,6 +139,35 @@ def check_gate_estimate_agreement() -> list:
             path=modname.replace(".", "/") + ".py", line=1, col=0,
             message=message))
 
+    for (d, max_nnz, h) in _HBM_SHAPES:
+        # the HBM-state sparse kernel: a plan's working sets (the VMEM
+        # scratch and output, the SMEM step tables) stay inside the
+        # budgets the gate compares, and its segments cover the round
+        plan = hbm.hbm_plan(d, max_nnz, h, itemsize)
+        if hbm.sparse_hbm_fits(d, max_nnz, h, itemsize) != (plan is not
+                                                            None):
+            flag("cocoa_tpu.ops.pallas_sparse_hbm",
+                 f"sparse_hbm_fits and hbm_plan disagree at d={d} "
+                 f"W={max_nnz} H={h}")
+        if plan is None:
+            continue
+        est = hbm.hbm_vmem_estimate(plan.s, plan.m, itemsize)
+        if est > hbm.HBM_VMEM_BUDGET:
+            flag("cocoa_tpu.ops.pallas_sparse_hbm",
+                 f"hbm_plan(d={d}, W={max_nnz}, H={h}) = {plan} but "
+                 f"hbm_vmem_estimate={est} exceeds HBM_VMEM_BUDGET")
+        if hbm.hbm_smem_estimate(plan.chunk, plan.w_r) > \
+                hbm.HBM_SMEM_BUDGET:
+            flag("cocoa_tpu.ops.pallas_sparse_hbm",
+                 f"hbm_plan(d={d}, W={max_nnz}, H={h}) = {plan} "
+                 f"overflows HBM_SMEM_BUDGET")
+        if plan.t * plan.s < h or plan.m < min(
+                -(-d // 1024) * 1024, plan.s * plan.w_r) \
+                or plan.s % plan.chunk or (
+                    not plan.direct and plan.m % plan.column_chunk):
+            flag("cocoa_tpu.ops.pallas_sparse_hbm",
+                 f"hbm_plan(d={d}, W={max_nnz}, H={h}) = {plan} does not "
+                 f"cover the round or its columns")
     for (k, n_shard, d, max_nnz, b, n_hot) in _SHAPES:
         # sequential sparse kernel: fits ⇒ estimate under budget AND the
         # SMEM segment split leaves at least one step per invocation

@@ -75,6 +75,76 @@ def row_axpy(row: Row, coef, vec: jax.Array) -> jax.Array:
     return vec
 
 
+# a gather over all nonzeros of a shard makes a temporary the size of the
+# shard's rows again (w[sp_indices] is (n_shard, W) floats): past this many
+# slots the pass runs in blocks of rows, and the temporary is one block's.
+# The size is measured (v5e, kddb's 1.2e9 slots, PR 26): a block of 2^20
+# slots a shard gathers at 16.8 ns a slot and scatters at 9.8, any smaller
+# block the same; blocks of 2^24 take 23 and 12.7 ns a slot.
+GATHER_BLOCK_SLOTS = 1 << 20
+
+
+def row_block(n_rows: int, width: int) -> int:
+    """Rows per block of a pass over a padded-CSR shard's nonzeros (whole
+    128-row lane tiles), or ``n_rows`` where one block holds them all."""
+    if n_rows * width <= GATHER_BLOCK_SLOTS:
+        return n_rows
+    return max(128, GATHER_BLOCK_SLOTS // max(1, width) // 128 * 128)
+
+
+# A gather of single elements from a d-vector that the program was HANDED
+# (w as the device loop carries it: a buffer the runtime places) runs at one
+# of two speeds on a v5e, by where that buffer happens to sit: 16.8 or 22.6
+# ns an element at kddb (20.7 or 28 s a certificate eval; 12 of 26 placements
+# slow, the same buffer always the same; PR 26).  The hot head of a Zipf
+# column law is half of all reads, and it lands on few or many memory banks
+# with the buffer's address.  So the big gathers read a copy made inside the
+# program, with the vector cut into SPREAD_ROWS-element rows and transposed:
+# neighbouring columns end up d / SPREAD_ROWS apart, and every run reads at
+# one speed (24.9 s, eight placements, to the millisecond).  The values
+# gathered are the same.
+SPREAD_ROWS = 4096
+
+
+def spread_table(vec: jax.Array) -> jax.Array:
+    """``vec`` with element q·SPREAD_ROWS + r moved to r·C + q (C = the
+    number of SPREAD_ROWS-element rows): read it at :func:`spread_index`."""
+    import jax.numpy as jnp
+
+    c = -(-vec.shape[0] // SPREAD_ROWS)
+    return jnp.pad(vec, (0, SPREAD_ROWS * c - vec.shape[0])).reshape(
+        c, SPREAD_ROWS).T.reshape(-1)
+
+
+def spread_index(idx: jax.Array, d: int) -> jax.Array:
+    """Where :func:`spread_table` of a d-vector keeps element ``idx``."""
+    c = -(-d // SPREAD_ROWS)
+    return (idx % SPREAD_ROWS) * c + idx // SPREAD_ROWS
+
+
+def _by_row_blocks(per_rows, idx: jax.Array, val: jax.Array) -> jax.Array:
+    """``per_rows(idx, val) -> (rows,)`` over an (n, W) padded-CSR shard,
+    run on :func:`row_block` (< n) rows at a time (``lax.map``) and put back
+    together as the (n,) result: the same per-row values as one pass over
+    all rows.  The last block starts early enough to end on the last row;
+    the rows it shares with its neighbour are taken from the neighbour."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n, width = idx.shape
+    block = row_block(n, width)
+    nb = -(-n // block)
+
+    def one(b):
+        start = jnp.minimum(b * block, n - block)
+        return per_rows(lax.dynamic_slice_in_dim(idx, start, block, 0),
+                        lax.dynamic_slice_in_dim(val, start, block, 0))
+
+    out = lax.map(one, jnp.arange(nb))                   # (nb, block)
+    tail = n - (nb - 1) * block                          # rows only the last has
+    return jnp.concatenate([out[:-1].reshape(-1), out[-1, block - tail:]])
+
+
 def shard_margins(w: jax.Array, shard: dict) -> jax.Array:
     """x_i·w for every row of one shard at once, shape (n_shard,).
 
@@ -92,7 +162,13 @@ def shard_margins(w: jax.Array, shard: dict) -> jax.Array:
     """
     if "X" in shard:
         return shard["X"] @ w
-    m = (w[shard["sp_indices"]] * shard["sp_values"]).sum(-1)
+    idx, val = shard["sp_indices"], shard["sp_values"]
+    if row_block(*idx.shape) < idx.shape[0]:
+        ws, d = spread_table(w), w.shape[0]     # note above SPREAD_ROWS
+        m = _by_row_blocks(
+            lambda i, v: (ws[spread_index(i, d)] * v).sum(-1), idx, val)
+    else:
+        m = (w[idx] * val).sum(-1)
     if "X_hot" in shard:
         m = m + shard["X_hot"] @ w[shard["hot_cols"]]
     return m
@@ -210,8 +286,26 @@ def shards_axpy(coefs: jax.Array, shards: dict, vec: jax.Array) -> jax.Array:
         return vec + dw.reshape(-1)[:vec.shape[0]]
     if "X" in shards:
         return vec + jnp.einsum("kn,knd->d", coefs, shards["X"])
-    vec = vec.at[shards["sp_indices"]].add(
-        coefs[..., None] * shards["sp_values"])
+    idx, val = shards["sp_indices"], shards["sp_values"]
+    n, block = idx.shape[1], row_block(idx.shape[1], idx.shape[2])
+    if block >= n:
+        vec = vec.at[idx].add(coefs[..., None] * val)
+    else:
+        # in blocks of rows, so that coefs x values is never the size of
+        # the rows again; the last block starts early enough to end on the
+        # last row, and the rows it shares with its neighbour add nothing
+        from jax import lax
+
+        def add_block(b, vec):
+            start = jnp.minimum(b * block, n - block)
+            own = start + jnp.arange(block) >= b * block
+            c = jnp.where(own, lax.dynamic_slice_in_dim(coefs, start, block,
+                                                        1), 0)
+            return vec.at[lax.dynamic_slice_in_dim(idx, start, block, 1)
+                          ].add(c[..., None] * lax.dynamic_slice_in_dim(
+                              val, start, block, 1))
+
+        vec = lax.fori_loop(0, -(-n // block), add_block, vec)
     if "X_hot" in shards:
         # hot_cols arrives (K, n_hot) — replicated per shard by the
         # loader — so the panel contribution scatters per shard: a

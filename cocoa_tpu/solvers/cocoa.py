@@ -31,13 +31,27 @@ from cocoa_tpu.telemetry import tracing as _tracing
 
 
 def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
-                    interpret):
+                    interpret, state="vmem"):
     """One Pallas SDCA round over all K shards: dense kernel (margins
     precomputed as one MXU matvec, folded-row X) or sparse kernel (margins
-    read in-kernel from the VMEM-resident w).  Returns (dw (K, d),
+    read in-kernel from the VMEM-resident w; ``state="hbm"``: the kernel
+    whose w, Δw and α stay in HBM, ops/pallas_sparse_hbm.py).  Returns
+    (dw (K, d) — (1, d), already summed, from the HBM-state kernel —
     alpha_inner (K, n_shard))."""
     common = dict(mode=mode, sigma=sigma, interpret=interpret,
                   loss=params.loss, smoothing=params.smoothing)
+    if "sp_indices" in shards and state == "hbm":
+        from cocoa_tpu.ops.pallas_sparse_hbm import pallas_sparse_hbm_round
+
+        # the shards' dw arrives summed (one (d,) target): as the one row
+        # of a (1, d) "per-shard" result it takes the callers' shard sum
+        dw_sum, alpha_inner = pallas_sparse_hbm_round(
+            w, alpha, shards["sp_indices"], shards["sp_values"],
+            shards["labels"], shards["sq_norms"], idxs_kh,
+            params.lam, params.n, row_len=shards.get("sp_row_len"),
+            **common,
+        )
+        return dw_sum[None], alpha_inner
     if "sp_indices" in shards:
         from cocoa_tpu.ops.pallas_sparse import pallas_sparse_sdca_round
 
@@ -140,7 +154,12 @@ class SolverPath:
     so that the device keeps them as the device loop reads them (no
     relayout per dispatch); ``device_default``: everything else (on a TPU
     that can be the row index on the lanes, which every dispatch
-    transposes first — ops/pallas_sdca.fold_rows)."""
+    transposes first — ops/pallas_sdca.fold_rows).  ``state``: where w, Δw
+    and α live while the local solve runs — ``vmem``: resident on the chip
+    for the round (the Pallas kernels of ops/pallas_sdca.py and
+    ops/pallas_sparse.py); ``hbm``: in HBM, only a segment's touched part
+    of them on the chip (ops/pallas_sparse_hbm.py, the sparse kernel for
+    sets whose d or n_shard outgrow VMEM; and every ``fori`` path)."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -150,6 +169,7 @@ class SolverPath:
     devices: int
     shards_per_device: int
     rows: str = "device_default"
+    state: str = "hbm"
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -174,7 +194,9 @@ class SolverPath:
             what = f"block {self.kernel}, pallas chain ({how})"
         else:
             what = (f"sequential {self.kernel}"
-                    + (f" ({how})" if self.kernel == "pallas" else ""))
+                    + (f" ({how})" if self.kernel == "pallas" else "")
+                    + (", state in HBM" if self.pallas
+                       and self.state == "hbm" else ""))
         rows = ", rows stored row-major" if self.rows == "row_major" else ""
         return (f"{what}, {self.layout} layout{rows}, on {self.platform} x "
                 f"{self.devices} ({self.shards_per_device} shard(s) per "
@@ -205,6 +227,22 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
     sparse = ds.layout == "sparse"
     layout = "hybrid" if sparse and ds.n_hot else ds.layout
     width = int(ds.sp_indices.shape[-1]) if sparse else 0
+    vmem_fits = hbm_state = False
+    if sparse:
+        # which sequential sparse kernel could hold the set: the
+        # VMEM-resident one (the SMEM feature-index table and the
+        # lane-blocked d-vectors must fit — pallas_sparse docstring; hybrid
+        # layouts additionally account the hot panel's VMEM), or, past
+        # VMEM, the one whose state stays in HBM (no hot panel: the hybrid
+        # layouts keep the fori path there)
+        from cocoa_tpu.ops.pallas_sparse import sparse_kernel_fits
+        from cocoa_tpu.ops.pallas_sparse_hbm import sparse_hbm_fits
+
+        vmem_fits = sparse_kernel_fits(
+            m_local, ds.n_shard, ds.num_features, width, local_iters,
+            itemsize, n_hot=ds.n_hot)
+        hbm_state = (not vmem_fits and not ds.n_hot and sparse_hbm_fits(
+            ds.num_features, width, local_iters, itemsize))
     if block_size > 0:
         # the block-coordinate kernel is an alternative inner loop — it and
         # the Pallas sequential kernels are mutually exclusive by design
@@ -225,20 +263,9 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         # (explicit pallas=True overrides, and Mosaic then reports the
         # allocation failure itself).
         from cocoa_tpu.ops.pallas_sdca import pick_unroll
-        from cocoa_tpu.ops.pallas_sparse import sparse_kernel_fits
 
-        if not sparse:
-            fits = pick_unroll(ds.n_shard, ds.num_features, itemsize,
-                               local_iters) > 0
-        else:
-            # sparse kernel: the SMEM feature-index table and the
-            # lane-blocked d-vectors must fit (pallas_sparse docstring);
-            # hybrid layouts additionally account the hot panel's VMEM
-            # (per-shard Δw_hot + the per-step panel row buffers)
-            fits = sparse_kernel_fits(
-                m_local, ds.n_shard, ds.num_features, width, local_iters,
-                itemsize, n_hot=ds.n_hot,
-            )
+        fits = (vmem_fits or hbm_state) if sparse else pick_unroll(
+            ds.n_shard, ds.num_features, itemsize, local_iters) > 0
         pallas = (
             math == "fast"
             and itemsize == 4
@@ -303,6 +330,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             rows=("row_major" if pallas and not sparse
                   and stores_row_major(ds.num_features)
                   else "device_default"),
+            state="vmem" if pallas and not hbm_state else "hbm",
             **placement)
     if block_chain == "xla":
         return SolverPath(inner="block", kernel="xla", chain="xla",
@@ -353,6 +381,7 @@ def _sdca_round_parts(
     math: str = "exact",
     pallas: bool = False,
     pallas_interpret: bool = False,
+    pallas_state: str = "vmem",
     block: int = 0,
     block_chain: str = "xla",
     block_distinct: bool = False,
@@ -431,7 +460,7 @@ def _sdca_round_parts(
             batched = jax.tree.map(lambda a: a[None], shard_k)
             dw, a_inner = _pallas_batched(
                 w, alpha_k[None], idxs_k[None], batched, params, mode,
-                sigma, pallas_interpret,
+                sigma, pallas_interpret, pallas_state,
             )
             da = a_inner[0] - alpha_k
             return dw[0], alpha_k + scaling * da
@@ -462,7 +491,7 @@ def _sdca_round_parts(
         def per_round_batched(w, alpha, idxs_kh, shards):
             dw, a_inner = _pallas_batched(
                 w, alpha, idxs_kh, shards, params, mode, sigma,
-                pallas_interpret,
+                pallas_interpret, pallas_state,
             )
             alpha_new = alpha + scaling * (a_inner - alpha)
             return dw.sum(axis=0), alpha_new
@@ -751,6 +780,7 @@ def run_sdca_family(
     parts_kw = dict(
         math=math, pallas=pallas,
         pallas_interpret=path.pallas and path.interpret,
+        pallas_state=path.state,
         block=block_size, block_chain=block_chain,
         block_sparse_gram=block_sparse_gram,
         block_pipeline=block_pipeline,

@@ -73,8 +73,12 @@ SCOPE_EVAL = "cocoa_eval"                 # the certificate evaluation
 SCOPE_INDICES = "cocoa_indices"           # a round's sampled row indices
 SCOPE_ACCEL_JUMP = "cocoa_accel_jump"     # --accel: the secant jump, and the
                                           # pass over the rows that moves w
+SCOPE_SPARSE_GATHER = "cocoa_sparse_gather"  # sparse rows past VMEM: the
+                                          # gathers, local-id remap and
+                                          # scatters that feed and drain the
+                                          # chain (ops/pallas_sparse_hbm.py)
 SCOPES = (SCOPE_LOCAL_SOLVE, SCOPE_DW_REDUCE, SCOPE_EVAL, SCOPE_INDICES,
-          SCOPE_ACCEL_JUMP)
+          SCOPE_ACCEL_JUMP, SCOPE_SPARSE_GATHER)
 
 
 class Tracer:

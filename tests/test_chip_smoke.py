@@ -144,7 +144,10 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     assert path.rows == rows
     assert list(path.as_dict()) == [
         "inner", "kernel", "chain", "interpret", "layout", "platform",
-        "devices", "shards_per_device", "rows"]
+        "devices", "shards_per_device", "rows", "state"]
+    # where w, dw and alpha live during the solve: on the chip for the
+    # resident Pallas kernels at these sizes, in HBM on the fori path
+    assert path.state == ("vmem" if pallas else "hbm")
     assert ("rows stored row-major" in path.describe()) == (
         rows == "row_major")
 

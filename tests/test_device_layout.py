@@ -157,3 +157,103 @@ def test_device_loop_opens_with_no_copy_of_the_rows(monkeypatch, one_chip,
     else:
         # the fold cache, stored with the row index on the lanes; never X
         assert len(copied) == 1 and "X_folded" in copied[0], copied
+
+
+# --- the sparse deployment that fills a chip (kddb), with no chip -----------
+
+KDDB = dict(n=19264097, d=29890095, k=8, width=64, frac=0.1, lam=1e-5)
+
+
+def _capture_sparse_run(monkeypatch):
+    """``(run, its arguments, the SolverPath)`` of one CoCoA+ job at the
+    kddb shape with the cell's flags, stopped at the dispatch.  The dataset
+    is shapes only (nothing is made: 10 GB), so the one array the program
+    derives from the rows eagerly, the per-row lengths, is given as a shape
+    too."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import (ShardedDataset, pad_rows,
+                                         split_sizes)
+    from cocoa_tpu.solvers import base, run_cocoa
+    from cocoa_tpu.solvers import cocoa as cocoa_mod
+
+    got = {}
+    build = base._build_device_run
+
+    def capturing(*args, **kw):
+        run = build(*args, **kw)
+
+        def call(*run_args):
+            got["run"], got["args"] = run, run_args
+            raise _Captured
+
+        return call
+
+    resolve = cocoa_mod.resolve_solver_path
+
+    def compiled_pallas(*args, **kw):
+        got["path"] = dataclasses.replace(
+            resolve(*args, **{**kw, "pallas": True}), interpret=False)
+        return got["path"]
+
+    monkeypatch.setattr(base, "_build_device_run", capturing)
+    monkeypatch.setattr(cocoa_mod, "resolve_solver_path", compiled_pallas)
+    base._DEVICE_RUNS.clear()
+    k, width = KDDB["k"], KDDB["width"]
+    sizes = split_sizes(KDDB["n"], k)
+    n_shard = pad_rows(int(sizes.max()))
+    here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=here)
+
+    rows = sds((k, n_shard), jnp.float32)
+    ds = ShardedDataset(
+        layout="sparse", n=KDDB["n"], num_features=KDDB["d"],
+        counts=sizes.astype(np.int64), labels=rows, mask=rows,
+        sq_norms=rows, sp_indices=sds((k, n_shard, width), jnp.int32),
+        sp_values=sds((k, n_shard, width), jnp.float32))
+    ds._row_len_cache = sds((k, n_shard), jnp.int32)
+    h = int(KDDB["frac"] * KDDB["n"] / k)
+    with pytest.raises(_Captured):
+        run_cocoa(ds, Params(n=ds.n, num_rounds=300, local_iters=h,
+                             lam=KDDB["lam"]),
+                  DebugParams(debug_iter=5, seed=0), plus=True, quiet=True,
+                  math="fast", device_loop=True, rng="permuted",
+                  gap_target=1e-2, accel="auto")
+    base._DEVICE_RUNS.clear()
+    return got["run"], got["args"], got["path"], n_shard
+
+
+def test_kddb_job_fits_one_chip_and_copies_nothing_large(monkeypatch,
+                                                         one_chip):
+    """The whole device loop of a kddb job — rounds on the kernel whose
+    state stays in HBM, the certificate eval in row blocks, the ``--accel``
+    jump — compiled for one described v5e: arguments plus program
+    temporaries stay under 15 GB of the chip's 15.75, and no ``copy(`` in
+    the entry computation makes a d-sized or (K, n_shard, W)-sized array
+    (a gather of whole rows would: the rows are stored with the row index
+    on the lanes, and layout assignment copies both 9.2 GB arrays
+    row-major first — found here before any chip run)."""
+    import jax
+
+    with jax.enable_x64(False):
+        run, args, path, n_shard = _capture_sparse_run(monkeypatch)
+        assert (path.kernel, path.state) == ("pallas", "hbm")
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    stats = compiled.memory_analysis()
+    held = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 10e9 < stats.argument_size_in_bytes < 11e9    # the deployment
+    assert held < 15e9, (stats.argument_size_in_bytes,
+                         stats.temp_size_in_bytes)
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 2    # the fetch and the chain
+    k, width, d = KDDB["k"], KDDB["width"], KDDB["d"]
+    large = re.compile(rf"\[{k},{n_shard},{width}\]|\[{k},{width},"
+                       rf"{n_shard}\]|\[{d}\]|\[{k},{d}\]")
+    entry = hlo[hlo.index("ENTRY"):]
+    copies = [line.strip()[:160] for line in entry.splitlines()
+              if " copy(" in line and large.search(line.split(" copy(")[0])]
+    assert copies == []

@@ -252,12 +252,22 @@ def lowered(monkeypatch):
     base._DEVICE_RUNS.clear()
 
 
-def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off"):
+def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off",
+              sparse=False):
     import jax
 
     from cocoa_tpu.data.synth import synth_dense_sharded
 
-    ds = synth_dense_sharded(256, 32, K, seed=1, mesh=mesh)
+    if sparse:
+        import jax.numpy as jnp
+
+        from cocoa_tpu.data import shard_dataset
+        from cocoa_tpu.data.synth import synth_sparse
+
+        ds = shard_dataset(synth_sparse(256, 200, nnz_mean=6, seed=1), k=K,
+                           layout="sparse", dtype=jnp.float32)
+    else:
+        ds = synth_dense_sharded(256, 32, K, seed=1, mesh=mesh)
     params = Params(n=ds.n, num_rounds=20, local_iters=16, lam=1e-2,
                     loss=loss)
     w, alpha, traj = run_cocoa(
@@ -276,25 +286,35 @@ def _loc_names(text):
 
 
 @pytest.mark.parametrize("case", ["hinge_pallas", "logistic",
-                                  "mesh_accel"])
-def test_lowered_device_loop_carries_each_scope_once(lowered, case):
+                                  "mesh_accel", "sparse_hbm"])
+def test_lowered_device_loop_carries_each_scope_once(lowered, case,
+                                                     monkeypatch):
     """The scope names reach the lowered loop — the kernel and its glue
     under the local solve, the dw sum and apply, the certificate eval,
     the index tables, and under ``--accel`` the secant jump — on the
     batched Pallas path (interpreted here), the vmapped logistic path and
     a 4-device mesh; no name sits inside another, so an op belongs to one
-    phase."""
+    phase.  Two names have a path of their own: the jump only under
+    ``--accel``, and ``cocoa_sparse_gather`` only where sparse rows take
+    the kernel whose state stays in HBM (a sibling of the local solve's
+    scope there, never inside it)."""
+    from cocoa_tpu.ops import pallas_sparse
     from cocoa_tpu.parallel import make_mesh
 
     kw = {"hinge_pallas": dict(pallas=True), "logistic": dict(
         loss="logistic"), "mesh_accel": dict(mesh=make_mesh(4),
-                                             accel="on")}[case]
+                                             accel="on"),
+          "sparse_hbm": dict(sparse=True, pallas=True)}[case]
+    if case == "sparse_hbm":     # the VMEM-resident kernel would fit here
+        monkeypatch.setattr(pallas_sparse, "sparse_kernel_fits",
+                            lambda *a, **k: False)
     _loop_run(**kw)
     names = _loc_names(lowered[-1][0])
+    own_case = {tracing.SCOPE_ACCEL_JUMP: "mesh_accel",
+                tracing.SCOPE_SPARSE_GATHER: "sparse_hbm"}
     for scope in tracing.SCOPES:
-        jump = scope == tracing.SCOPE_ACCEL_JUMP
         assert any(scope in n for n in names) == (
-            not jump or case == "mesh_accel"), (scope, case)
+            own_case.get(scope, case) == case), (scope, case)
         assert "/" not in scope
     assert [n for n in names
             if sum(n.count(sc) for sc in tracing.SCOPES) > 1] == []
