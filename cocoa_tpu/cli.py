@@ -1559,7 +1559,7 @@ def main(argv=None) -> int:
             manifest["ingest"] = ingest_reports[0].as_fields()
         manifest["solver_path"] = resolve_solver_path(
             ds_solved, local_iters, mesh, math=cfg.math,
-            block_size=block_size).as_dict()
+            block_size=block_size, loss=cfg.loss).as_dict()
         bus.emit("run_start", manifest=manifest)
         for rep in ingest_reports:
             bus.emit("ingest", **rep.as_fields())

@@ -45,6 +45,18 @@ _EPS = 1e-12
 _U_MAX = 35.0  # |logit| cap: σ(±35) is exact 0/1 in f32, underflow-safe
 _NEWTON_ITERS = 10
 
+# losses whose ``alpha_step`` is an iterative solve: a chain of dependent
+# transcendentals (logistic: a log, _NEWTON_ITERS x (exp, two divides, a
+# clip), a last sigmoid), where the others are a handful of selects.  A
+# kernel that advances K chains in lockstep reads this, never a loss's
+# name, to solve the K steps as one vector (ops/pallas_sdca.py).
+ITERATIVE_STEPS = frozenset({"logistic"})
+
+
+def step_is_iterative(loss: str) -> bool:
+    """Whether ``alpha_step(loss, ...)`` iterates (see ITERATIVE_STEPS)."""
+    return loss in ITERATIVE_STEPS
+
 
 def validate(loss: str, smoothing=None) -> str:
     if loss not in LOSSES + PROX_RULES:
@@ -122,6 +134,16 @@ def alpha_step(loss: str, a, z, qii, lam_n, smoothing: float = 1.0):
 
     ``z`` is the margin the subproblem sees (mode-dependent: w, w+Δw, or
     w+σ′Δw — the caller computes it); ``qii`` is the σ′-scaled ‖x‖².
+
+    Elementwise over ``a``, ``z``, ``qii`` of any ONE shape (pure ``jnp``,
+    no reduction, no data-dependent control flow): 0-d values give one
+    step, a vector gives one independent step per element with the same
+    operations in the same order.  Where the solve runs is the caller's
+    choice: the ``fori`` kernels, the sparse and the block kernels call it
+    on one coordinate's scalars; the dense Pallas kernel calls an
+    iterative loss's step (``step_is_iterative``) once for all the shards
+    it advances in lockstep, one shard per lane (pallas_sdca
+    ``_solve_in_lanes``).
 
     - hinge: the reference's exact sequence — projected gradient against the
       box's active face, vanishing-gradient no-op, qii==0 → 1, clip

@@ -18,7 +18,19 @@ round — at localIterFrac = 0.1 that is 10× the rows the round touches
 (~90% of the demo round's HBM traffic, ~4 ms/round at epsilon scale).
 The sparse kernel (ops/pallas_sparse.py) has computed margins in-kernel
 since round 2 for the same reason.  Per step the kernel does the two row
-dots, scalar box-projection logic, one row axpy, and an α write.
+dots, the loss's coordinate update (ops/losses.py ``alpha_step``), one row
+axpy, and an α write.
+
+**Where the coordinate update runs.**  A closed-form loss's update (hinge's
+box projection, smooth_hinge's clip) is a handful of selects on the step's
+own 0-d values, emitted once per shard per step.  A loss whose update is an
+iterative solve (``losses.step_is_iterative``: logistic's ten Newton
+iterations) is not emitted per shard: the K shards that advance in lockstep
+put their (α, margin, ‖x‖²) on K lanes of one vector and ONE ``alpha_step``
+solves them together (:func:`_solve_in_lanes`) — K dependent chains of
+``exp`` and divide on single values cost K times what one chain on a vector
+does (PERF.md §6, PR 27).  The branch is static, taken at trace time from
+what the loss declares; no kernel here tests a loss's name.
 
 **Folded rows.**  A (1, d) row uses one sublane — 1/8 of the VPU.  The
 caller reinterprets each dense row as an (8, d/8) tile instead (a free
@@ -195,11 +207,47 @@ def fold_rows(X: jax.Array, row_major: bool = False) -> jax.Array:
 STACK = 3  # lane-concatenated per-shard rows: [labels, sqn, alpha]
 
 
-def _step_body(srow, sub_lane, live, x, dw_k, w_k, *, frozen, sig_eff,
-               qii_factor, lam_n, coef_div, loss, smoothing):
-    """One coordinate step given the (1, 3·LANES) lane-concatenated state
-    row (labels in lanes [0,128), ‖x‖² [128,256), α [256,384)).  Returns
-    (new row, Δw contribution).
+def _solve_in_lanes(loss, triples, lam_n, smoothing):
+    """The new α of each chain in ``triples`` — chain c's 0-d (α, z, qii),
+    all at the same lockstep step — from ONE ``losses.alpha_step``: the
+    triples sit in lane c of three (1, 128) vectors (whole (8, 128) tiles
+    past 128 chains) and the step runs in the vector domain from its first
+    ``log`` to its last sigmoid.
+
+    An iterative step (``losses.step_is_iterative``) is a dependent chain
+    of transcendentals, the same length for every shard.  Emitted once per
+    shard on 0-d values, the K chains are paid one after another, each
+    ``exp`` and divide at the latency of a round trip between the scalar
+    and the vector side (PERF.md §6, PR 27: 155 cycles a Newton iteration
+    a chain at epsilon, K = 8; ~20 for all eight side by side in one
+    register).  Each lane computes what the scalar call computes,
+    operation for operation (``alpha_step`` is elementwise).  Lanes past
+    the last chain hold α = ½, z = 0, qii = 0, where the logistic step
+    stays put: nothing there is ever non-finite."""
+    n = len(triples)
+    rows = 1 if n <= LANES else -(-n // (SUBLANES * LANES)) * SUBLANES
+    shape = (rows, LANES)
+    lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * LANES
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    mine = [lane == c for c in range(n)]
+    dtype = triples[0][0].dtype
+    a_v = jnp.full(shape, 0.5, dtype)
+    z_v = q_v = jnp.zeros(shape, dtype)
+    for here, (a, z, qii) in zip(mine, triples):
+        a_v = jnp.where(here, a, a_v)
+        z_v = jnp.where(here, z, z_v)
+        q_v = jnp.where(here, qii, q_v)
+    new_v = losses.alpha_step(loss, a_v, z_v, q_v, lam_n,
+                              smoothing=smoothing)
+    return [jnp.sum(jnp.where(here, new_v, 0.0)) for here in mine]
+
+
+def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
+             qii_factor, lam_n, coef_div, loss, smoothing):
+    """One coordinate step of every chain in ``chains``, each a (shard, row
+    block ref, Δw accumulator ref, state ref) whose state rows are the
+    (1, 3·LANES) lane concatenation: labels in lanes [0,128), ‖x‖²
+    [128,256), α [256,384).
 
     The concatenated layout is the kernel's key scalar-unit optimization:
     all three per-step values arrive from ONE dynamic slice, and the α
@@ -210,40 +258,65 @@ def _step_body(srow, sub_lane, live, x, dw_k, w_k, *, frozen, sig_eff,
     is also why the base margin is one more VPU reduce against the
     VMEM-resident w₀ rather than a precomputed margins0 read (see the
     module docstring: the whole-shard matvec it replaces was most of the
-    round's HBM traffic)."""
-    lane4 = jax.lax.broadcasted_iota(jnp.int32, (1, STACK * LANES), 1)
-    y = jnp.sum(jnp.where(lane4 == sub_lane, srow, 0.0))
-    sq = jnp.sum(jnp.where(lane4 == sub_lane + LANES, srow, 0.0))
-    a = jnp.sum(jnp.where(lane4 == sub_lane + 2 * LANES, srow, 0.0))
+    round's HBM traffic).
 
-    margin = jnp.sum(x * w_k)
-    if not frozen:
-        margin = margin + sig_eff * jnp.sum(x * dw_k)
-    # the dual coordinate update is pure scalar jnp — shared with the
-    # fori_loop kernels via ops/losses.py (hinge = CoCoA.scala:166-178)
-    new_a = losses.alpha_step(loss, a, y * margin, sq * qii_factor, lam_n,
-                              smoothing=smoothing)
-    coef = y * (new_a - a) / coef_div
-    wmask = lane4 == sub_lane + 2 * LANES
-    if live is not None:   # tail group past H (only when unroll ∤ H): inert
-        coef = jnp.where(live, coef, 0.0)
-        wmask = wmask & live
-    return jnp.where(wmask, new_a, srow), coef * x
+    A step is a *read* (one dynamic row read; y, ‖x‖², α and the margin
+    reduced to scalars), the loss's ``alpha_step``, and a *write* (coef,
+    the Δw contribution, the masked α write).  A closed-form loss runs the
+    three chain by chain, ``alpha_step`` on the chain's own scalars.  An
+    iterative one (``losses.step_is_iterative``: a property the loss
+    declares, static at trace time) runs every chain's read, ONE solve
+    with a chain per lane (:func:`_solve_in_lanes`), then every chain's
+    write."""
+    def read(shard, x_ref, dw_acc, state):
+        idx = idxs_ref[shard, step]
+        blk = idx // LANES
+        srow = state[pl.ds(blk, 1)]           # (1, 3·LANES): one dyn read
+        x = x_ref[0, 0]                       # (8, d8): the folded row
+        sub_lane = idx - blk * LANES
+        dw_k, w_k = dw_acc[...], w_ref[...]
+        lane4 = jax.lax.broadcasted_iota(jnp.int32, (1, STACK * LANES), 1)
+        y = jnp.sum(jnp.where(lane4 == sub_lane, srow, 0.0))
+        sq = jnp.sum(jnp.where(lane4 == sub_lane + LANES, srow, 0.0))
+        a = jnp.sum(jnp.where(lane4 == sub_lane + 2 * LANES, srow, 0.0))
+        margin = jnp.sum(x * w_k)
+        if not frozen:
+            margin = margin + sig_eff * jnp.sum(x * dw_k)
+        return (blk, lane4, sub_lane, srow, x, y), (a, y * margin,
+                                                    sq * qii_factor)
+
+    def write(chain, at, a, new_a):
+        (_, _, dw_acc, state), (blk, lane4, sub_lane, srow, x, y) = chain, at
+        coef = y * (new_a - a) / coef_div
+        wmask = lane4 == sub_lane + 2 * LANES
+        if live is not None:   # tail group past H (unroll ∤ H only): inert
+            coef = jnp.where(live, coef, 0.0)
+            wmask = wmask & live
+        new_row, dws = jnp.where(wmask, new_a, srow), coef * x
+        dw_acc[...] = dw_acc[...] + dws
+        state[pl.ds(blk, 1)] = new_row        # one dyn write
+
+    if losses.step_is_iterative(loss):
+        ats, triples = zip(*(read(*chain) for chain in chains))
+        new_as = _solve_in_lanes(loss, triples, lam_n, smoothing)
+        for chain, at, (a, _, _), new_a in zip(chains, ats, triples, new_as):
+            write(chain, at, a, new_a)
+        return
+    for chain in chains:
+        at, (a, z, qii) = read(*chain)
+        # the dual coordinate update is pure scalar jnp — shared with the
+        # fori_loop kernels via ops/losses.py (hinge = CoCoA.scala:166-178)
+        write(chain, at, a, losses.alpha_step(loss, a, z, qii, lam_n,
+                                              smoothing=smoothing))
 
 
 def _kernel(
     idxs_ref,        # scalar-prefetch: (K, H) int32 sampled rows
     *refs,           # S row blocks, w, stacked vecs, 2 outs, 2 scratch
-    lam_n: float,
-    coef_div: float,
-    sig_eff: float,
-    qii_factor: float,
-    frozen: bool,
     h: int,
-    loss: str,
-    smoothing: float,
     unroll: int,
     n_groups: int,
+    **step_kw,       # the step's static parameters (:func:`_advance`)
 ):
     # refs layout:
     #   x_refs[j]      (1, 1, 8, d8) VMEM: folded row of sample j
@@ -272,19 +345,9 @@ def _kernel(
         # groups past H clamp their index (the row spec's index map does the
         # same clamp, so the DMA'd block matches) and zero their update;
         # when unroll | H there is no tail and the masking drops out
-        idx = idxs_ref[k_, step if exact else jnp.minimum(step, h - 1)]
-        live = None if exact else step < h
-        blk = idx // LANES
-        srow = stacked_sc[pl.ds(blk, 1)]      # (1, 3·LANES): one dyn read
-        x = x_refs[j][0, 0]                   # (8, d8): the folded row
-        new_row, dws = _step_body(
-            srow, idx - blk * LANES, live, x, dw_acc[...], w_ref[...],
-            frozen=frozen,
-            sig_eff=sig_eff, qii_factor=qii_factor, lam_n=lam_n,
-            coef_div=coef_div, loss=loss, smoothing=smoothing,
-        )
-        dw_acc[...] = dw_acc[...] + dws
-        stacked_sc[pl.ds(blk, 1)] = new_row   # one dyn write
+        _advance([(k_, x_refs[j], dw_acc, stacked_sc)], idxs_ref,
+                 step if exact else jnp.minimum(step, h - 1),
+                 None if exact else step < h, w_ref, **step_kw)
 
     @pl.when(i == n_groups - 1)
     def _flush_shard():
@@ -295,17 +358,11 @@ def _kernel(
 def _kernel_interleaved(
     idxs_ref,        # scalar-prefetch: (K, H) int32 sampled rows
     *refs,           # K*S row blocks, stacked_in, 2 outs, 2K scratch
-    lam_n: float,
-    coef_div: float,
-    sig_eff: float,
-    qii_factor: float,
-    frozen: bool,
     h: int,
-    loss: str,
-    smoothing: float,
     unroll: int,
     n_groups: int,
     k: int,
+    **step_kw,
 ):
     """Shard-interleaved variant: 1-D grid over step groups; each iteration
     advances EVERY shard's chain by S steps.  The K chains are independent
@@ -332,21 +389,10 @@ def _kernel_interleaved(
     for j in range(unroll):
         step = i * unroll + j
         live = None if exact else step < h
-        step_c = step if exact else jnp.minimum(step, h - 1)
-        for kk in range(k):
-            idx = idxs_ref[kk, step_c]
-            blk = idx // LANES
-            srow = st_scs[kk][pl.ds(blk, 1)]
-            x = x_refs[j * k + kk][0, 0]
-            new_row, dws = _step_body(
-                srow, idx - blk * LANES, live, x, dw_accs[kk][...],
-                w_ref[...],
-                frozen=frozen, sig_eff=sig_eff, qii_factor=qii_factor,
-                lam_n=lam_n, coef_div=coef_div, loss=loss,
-                smoothing=smoothing,
-            )
-            dw_accs[kk][...] = dw_accs[kk][...] + dws
-            st_scs[kk][pl.ds(blk, 1)] = new_row
+        _advance([(kk, x_refs[j * k + kk], dw_accs[kk], st_scs[kk])
+                  for kk in range(k)], idxs_ref,
+                 step if exact else jnp.minimum(step, h - 1), live, w_ref,
+                 **step_kw)
 
     @pl.when(i == n_groups - 1)
     def _flush():
@@ -434,7 +480,7 @@ def pallas_sdca_round(
 
     # lane-block the per-shard vectors and lane-concatenate them into the
     # (K, n_blocks, 3·128) stacked state the kernel reads with ONE dynamic
-    # slice per step (see _step_body).  Sampled indices never exceed the
+    # slice per step (see _advance).  Sampled indices never exceed the
     # shard's true row count, so zero padding is inert.
     n_pad = -(-n_shard // LANES) * LANES
     pad = [(0, 0), (0, n_pad - n_shard)]

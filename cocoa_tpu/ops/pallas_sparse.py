@@ -13,7 +13,7 @@ precompute the round's margins.  This kernel removes both:
   and the scatter is a masked row update through the same slice.  Per
   nonzero: 2 dynamically-addressed VMEM accesses.  (Scalar-core address
   generation is the per-step bottleneck — same finding as the dense
-  kernel, see pallas_sdca._step_body.)
+  kernel, see pallas_sdca._advance.)
 - margins are computed **in-kernel** from the VMEM-resident ``w``
   (``margin = x·w + sig_eff·(x·Δw)``, the same decomposition as
   ops/local_sdca.py ``mode_factors`` with margins0 evaluated on the fly),

@@ -159,7 +159,14 @@ class SolverPath:
     for the round (the Pallas kernels of ops/pallas_sdca.py and
     ops/pallas_sparse.py); ``hbm``: in HBM, only a segment's touched part
     of them on the chip (ops/pallas_sparse_hbm.py, the sparse kernel for
-    sets whose d or n_shard outgrow VMEM; and every ``fori`` path)."""
+    sets whose d or n_shard outgrow VMEM; and every ``fori`` path).
+    ``step_solve``: how a coordinate step's new α is solved — ``lanes``:
+    the dense Pallas kernel under a loss whose step iterates
+    (ops/losses.step_is_iterative: logistic's Newton), the K shards it
+    advances in lockstep solved as one vector, a shard a lane;
+    ``scalar``: everything else — a closed-form step, and an iterative
+    one wherever a kernel still solves it chain by chain on one
+    coordinate's scalars (``fori``, the sparse and the block kernels)."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -170,6 +177,7 @@ class SolverPath:
     shards_per_device: int
     rows: str = "device_default"
     state: str = "hbm"
+    step_solve: str = "scalar"
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -198,7 +206,10 @@ class SolverPath:
                     + (", state in HBM" if self.pallas
                        and self.state == "hbm" else ""))
         rows = ", rows stored row-major" if self.rows == "row_major" else ""
-        return (f"{what}, {self.layout} layout{rows}, on {self.platform} x "
+        solve = (", the shards' steps solved in lanes"
+                 if self.step_solve == "lanes" else "")
+        return (f"{what}, {self.layout} layout{rows}{solve}, on "
+                f"{self.platform} x "
                 f"{self.devices} ({self.shards_per_device} shard(s) per "
                 f"device)")
 
@@ -206,12 +217,14 @@ class SolverPath:
 def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                         math: str = "exact", pallas=None,
                         block_size: int = 0, block_chain=None,
-                        block_sparse_gram=None) -> SolverPath:
+                        block_sparse_gram=None,
+                        loss: str = "hinge") -> SolverPath:
     """Pick the inner solver for a run — the ONE place that decides (see
     :class:`SolverPath`).  ``pallas`` / ``block_chain`` /
     ``block_sparse_gram`` None = auto; explicit values override (tests
     use ``block_chain="pallas_interpret"`` and ``pallas=True`` on CPU to
-    exercise the driver-integrated kernels in interpret mode)."""
+    exercise the driver-integrated kernels in interpret mode).  ``loss``
+    chooses no kernel: it is what ``step_solve`` reports on."""
     from cocoa_tpu.ops.local_sdca import resolve_block_form
     from cocoa_tpu.parallel.fanout import shards_per_device
     from cocoa_tpu.parallel.mesh import has_fp
@@ -322,6 +335,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         shards_per_device=m_local,
     )
     if block_size <= 0:
+        from cocoa_tpu.ops import losses
         from cocoa_tpu.ops.pallas_sdca import stores_row_major
 
         return SolverPath(
@@ -331,6 +345,8 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                   and stores_row_major(ds.num_features)
                   else "device_default"),
             state="vmem" if pallas and not hbm_state else "hbm",
+            step_solve=("lanes" if pallas and not sparse
+                        and losses.step_is_iterative(loss) else "scalar"),
             **placement)
     if block_chain == "xla":
         return SolverPath(inner="block", kernel="xla", chain="xla",
@@ -773,7 +789,7 @@ def run_sdca_family(
     path = resolve_solver_path(
         ds, params.local_iters, mesh, math=math, pallas=pallas,
         block_size=block_size, block_chain=block_chain,
-        block_sparse_gram=block_sparse_gram)
+        block_sparse_gram=block_sparse_gram, loss=params.loss)
     pallas, block_chain = path.pallas, path.block_chain
     if not quiet:
         print(f"local solver: {path.describe()}")

@@ -79,6 +79,7 @@ def test_demo_phase_and_auto_selected_path_on_cpu(out):
     assert rep["solver_path"]["interpret"] is False
     assert rep["solver_path"]["platform"] == "cpu"
     assert rep["solver_path"]["rows"] == "device_default"   # no fold cache
+    assert rep["solver_path"]["step_solve"] == "scalar"
     assert rep["stopped"] == "target" and rep["gap"] <= 1e-4
     assert rep["checkpoint"].startswith("CoCoA+-r")
     # the same record rides the events file a user would read
@@ -130,6 +131,7 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     path is (resolve_solver_path — what run_start's manifest and
     Trajectory.meta carry) from the kernel and the row width alone, and
     said on the console line when the fold cache is stored row-major."""
+    import dataclasses
     import types
 
     import jax.numpy as jnp
@@ -144,12 +146,63 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     assert path.rows == rows
     assert list(path.as_dict()) == [
         "inner", "kernel", "chain", "interpret", "layout", "platform",
-        "devices", "shards_per_device", "rows", "state"]
+        "devices", "shards_per_device", "rows", "state", "step_solve"]
     # where w, dw and alpha live during the solve: on the chip for the
     # resident Pallas kernels at these sizes, in HBM on the fori path
     assert path.state == ("vmem" if pallas else "hbm")
     assert ("rows stored row-major" in path.describe()) == (
         rows == "row_major")
+    # hinge's step is a closed form: solved on the coordinate's own scalars
+    assert path.step_solve == "scalar"
+    assert "solved in lanes" not in path.describe()
+    # logistic's iterates, and only the dense Pallas kernel solves its K
+    # lockstep shards as one vector; the fori and the sparse kernels do not
+    lanes = resolve_solver_path(ds, 8, math="fast", pallas=pallas,
+                                loss="logistic")
+    assert lanes.step_solve == (
+        "lanes" if pallas and layout == "dense" else "scalar")
+    assert ("solved in lanes" in lanes.describe()) == (
+        lanes.step_solve == "lanes")
+    assert dataclasses.replace(lanes, step_solve="scalar") == path
+
+
+def test_logistic_run_says_its_steps_are_solved_in_lanes(
+        out, interpret_kernels, capfd):
+    """A run whose K shards' Newton steps are solved as one vector says so
+    on every surface a run has: the console line, ``run_start``'s manifest
+    (the CLI's own resolver call) and ``Trajectory.meta`` (the driver's)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.synth import synth_dense_sharded
+    from cocoa_tpu.solvers import run_cocoa
+
+    rng = np.random.default_rng(0)
+    train = os.path.join(out, "lanes_train.dat")
+    with open(train, "w") as f:
+        for _ in range(96):
+            row = " ".join(f"{j}:{v:.4f}" for j, v in
+                           enumerate(rng.normal(size=16), start=1))
+            f.write(f"{rng.choice((-1, 1)):+d} {row}\n")
+    argv = [f"--trainFile={train}", "--numFeatures=16", "--numRounds=2",
+            "--localIterFrac=0.1", "--numSplits=3", "--lambda=0.01",
+            "--justCoCoA=true", "--math=fast", "--layout=dense",
+            "--loss=logistic", "--debugIter=2"]
+    _, events = chip_smoke.run_cli(argv, os.path.join(out, "lanes.jsonl"))
+    (start,) = [e for e in events if e["event"] == "run_start"]
+    path = start["manifest"]["solver_path"]
+    assert (path["kernel"], path["layout"], path["step_solve"]) == (
+        "pallas", "dense", "lanes")
+    assert "the shards' steps solved in lanes" in capfd.readouterr().out
+
+    ds = synth_dense_sharded(96, 16, 3, seed=0, dtype=jnp.float32)
+    _, _, traj = run_cocoa(
+        ds, Params(n=ds.n, num_rounds=2, local_iters=4, lam=1e-2,
+                   loss="logistic"),
+        DebugParams(debug_iter=2, seed=0), plus=True, quiet=True,
+        math="fast")
+    assert traj.meta["solver_path"]["step_solve"] == "lanes"
 
 
 def test_rcv1_phases_with_interpreted_kernels(out, interpret_kernels,
@@ -158,6 +211,7 @@ def test_rcv1_phases_with_interpreted_kernels(out, interpret_kernels,
     rep = chip_smoke.phase_rcv1_seq(cfg, out, INTERPRETED)
     assert rep["stopped"] == "target" and rep["parser"] in ("native",
                                                             "python")
+    assert rep["solver_path"]["step_solve"] == "scalar"     # sparse, hinge
     rep = chip_smoke.phase_rcv1_hybrid(cfg, out, INTERPRETED)
     assert rep["solver_path"]["layout"] == "hybrid"
     # at this width the fused kernel would hold the densified tile; the
@@ -177,6 +231,7 @@ def test_epsilon_phase_with_interpreted_kernels(out, interpret_kernels):
     for name in ("seq", "block"):
         assert rep[name]["stopped"] == "target"
         assert rep[name]["alpha_devices"] == rep["data_devices"]
+        assert rep[name]["solver_path"]["step_solve"] == "scalar"   # hinge
     assert rep["block"]["solver_path"]["kernel"] == "fused"
 
 

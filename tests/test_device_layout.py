@@ -63,7 +63,7 @@ class _Captured(Exception):
     pass
 
 
-def _capture_run(monkeypatch, d, accel):
+def _capture_run(monkeypatch, d, accel, loss="hinge"):
     """``(run, its arguments, the resolved SolverPath)`` of one CoCoA+ job
     on the dense Pallas path, stopped at the dispatch: the kernels are held
     at compiled (``interpret=False``; this process's platform is cpu), so
@@ -104,7 +104,7 @@ def _capture_run(monkeypatch, d, accel):
         sq_norms=ones, X=jnp.zeros((K, N_SHARD, d), jnp.float32))
     with pytest.raises(_Captured):
         run_cocoa(ds, Params(n=ds.n, num_rounds=600, local_iters=H,
-                             lam=1e-3),
+                             lam=1e-3, loss=loss),
                   DebugParams(debug_iter=10, seed=0), plus=True, quiet=True,
                   math="fast", device_loop=True, rng="permuted",
                   gap_target=1e-4, accel=accel)
@@ -157,6 +157,55 @@ def test_device_loop_opens_with_no_copy_of_the_rows(monkeypatch, one_chip,
     else:
         # the fold cache, stored with the row index on the lanes; never X
         assert len(copied) == 1 and "X_folded" in copied[0], copied
+
+
+# --- the logistic step solved in lanes: Mosaic takes it, with no chip ------
+
+def test_logistic_job_compiles_with_its_steps_solved_in_lanes(monkeypatch,
+                                                              one_chip):
+    """The epsilon-shaped logistic job (K = 8 interleaved shards, step
+    groups of 2): the packed Newton solve of ops/pallas_sdca.py
+    ``_solve_in_lanes`` — lane-iota selects into (1, 128) vectors, one
+    ``alpha_step`` on them, masked lane reduces back to scalars — lowers
+    through Mosaic inside the program's own ``run``, and the run's record
+    says ``lanes``."""
+    import jax
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    d = WIDTHS["rows_on_lanes"]
+    assert pallas_sdca.pick_interleave(K, N_SHARD, d, 4, H) == 2
+    with jax.enable_x64(False):
+        run, args, path = _capture_run(monkeypatch, d, "auto",
+                                       loss="logistic")
+        assert (path.kernel, path.state, path.step_solve) == (
+            "pallas", "vmem", "lanes")
+        hlo = run.lower(*_on_chip(args, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_logistic_kernel_compiles_at_epsilon_size(one_chip):
+    """The kernel alone at the benchmark's shapes (8 x 50,000 x 2,000, H =
+    5,000: 9.9 MB of the 14 MB the interleaved kernel may hold): the
+    packed solve adds no VMEM the chip's compiler refuses."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    k, n_shard, d, h = 8, 50000, 2000, 5000
+    assert pallas_sdca.pick_interleave(k, n_shard, d, 4, h) == 2
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = on_chip((k, n_shard))
+    with jax.enable_x64(False):
+        hlo = pallas_sdca.pallas_sdca_round.lower(
+            on_chip((d,)), rows, on_chip((k, n_shard, 8, d // 8)), rows,
+            rows, on_chip((k, h), jnp.int32), 1e-3, k * n_shard,
+            mode="plus", sigma=float(k), loss="logistic").compile().as_text()
+    assert "tpu_custom_call" in hlo
 
 
 # --- the sparse deployment that fills a chip (kddb), with no chip -----------

@@ -90,6 +90,59 @@ def test_pallas_interpret_matches_xla_fast(tiny_data, mode, sigma):
                                    np.asarray(da), atol=1e-12)
 
 
+def _logistic_round_matches_fast(tiny_data, k, h, mode, sigma, **kernel):
+    """One logistic round of the dense Pallas kernel (f64, interpreted)
+    against ``local_sdca_fast`` on every shard."""
+    ds = shard_dataset(tiny_data, k=k, layout="dense", dtype=jnp.float64)
+    rng = np.random.default_rng(2)
+    d = tiny_data.num_features
+    w = jnp.asarray(rng.normal(size=d) * 0.1)
+    alpha = jnp.asarray(
+        np.clip(rng.normal(size=(k, ds.n_shard)) * 0.3 + 0.3, 0, 1)
+    )
+    idxs = jnp.asarray(
+        sample_indices_per_shard(5, range(1, 2), h, ds.counts)[:, 0, :]
+    )
+    dw_p, a_p = pallas_sdca_round(
+        w, alpha, ds.X, ds.labels, ds.sq_norms, idxs, 0.01, tiny_data.n,
+        mode=mode, sigma=sigma, interpret=True, loss="logistic", **kernel,
+    )
+    assert np.all(np.isfinite(np.asarray(a_p)))
+    fast = jax.jit(jax.vmap(lambda m0, a, shard, ix: local_sdca_fast(
+        m0, a, shard, ix, 0.01, tiny_data.n, jnp.zeros(d, dtype=jnp.float64),
+        mode=mode, sigma=sigma, loss="logistic")))
+    da, dw = fast(jnp.einsum("knd,d->kn", ds.X, w), alpha,
+                  ds.shard_arrays(), idxs)
+    np.testing.assert_allclose(np.asarray(dw_p), np.asarray(dw), atol=1e-12)
+    np.testing.assert_allclose(np.asarray(a_p - alpha), np.asarray(da),
+                               atol=1e-12)
+    moved = float(jnp.max(jnp.abs(da)))
+    assert moved > 1e-3     # the round stepped: equality is not 0 == 0
+
+
+@pytest.mark.parametrize("k", [1, 3, 4])
+@pytest.mark.parametrize("mode,sigma", [("plus", 4.0), ("cocoa", 1.0),
+                                        ("frozen", 1.0)])
+@pytest.mark.parametrize("interleave", [True, False])
+def test_pallas_logistic_interpret_matches_xla_fast(tiny_data, interleave,
+                                                    mode, sigma, k):
+    """The logistic step is solved for the K lockstep chains as one vector,
+    a shard a lane (pallas_sdca ``_solve_in_lanes``): K = 3 leaves filler
+    lanes beside the chains, K = 1 is one lane whichever kernel runs, and
+    ``interleave=False`` is the shard-major kernel's one-lane solve.
+    Step groups of 2, as at epsilon."""
+    _logistic_round_matches_fast(tiny_data, k, 30, mode, sigma,
+                                 interleave=interleave, unroll=2)
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+def test_pallas_logistic_inert_tail(tiny_data, interleave):
+    """``unroll`` ∤ H: the group past H solves its lanes like any other and
+    its ``live`` mask drops the result."""
+    _logistic_round_matches_fast(tiny_data, 3, 31, "plus", 3.0,
+                                 interleave=interleave, unroll=4)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("mode,sigma", [("cocoa", 1.0), ("plus", 4.0), ("frozen", 1.0)])
 def test_pallas_sparse_interpret_matches_xla_fast(tiny_data, mode, sigma):

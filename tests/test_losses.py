@@ -127,6 +127,106 @@ def test_alpha_step_maximizes_coordinate_dual(loss):
 
 # ---------------------------------------------------------- end-to-end
 
+# ------------------------------------- the step solved for K chains at once
+
+def _step_grid():
+    """(α, z, qii) over the box's corners and their neighbours, margins out
+    to |z| = 50 and curvatures from none to 10³·λn: 126 triples."""
+    return [(a, z, q)
+            for a in (0.0, 1e-12, 0.3, 0.5, 1.0 - 1e-12, 1.0)
+            for z in (-50.0, -5.0, -0.5, 0.0, 0.5, 5.0, 50.0)
+            for q in (0.0, 1e-3, 1e3)]
+
+
+@pytest.mark.parametrize("dtype, n", [("float64", 126), ("float32", 126),
+                                      ("float64", 3), ("float64", 130)])
+def test_lane_packed_solve_is_the_scalar_solve(dtype, n):
+    """``alpha_step`` is elementwise: n chains' logistic steps solved in
+    the lanes of one (1, 128) vector (one (8, 128) past 128 chains) are
+    the n scalar steps, value for value — to 1e-12 in f64; to 4 ulp in
+    f32, where the CPU's vector and scalar ``exp`` differ in the last bit."""
+    from cocoa_tpu.ops.pallas_sdca import _solve_in_lanes
+
+    lam_n = 2.0
+    grid = (_step_grid() * 2)[:n]
+    triples = [tuple(jnp.asarray(v, dtype) for v in (a, z, q * lam_n))
+               for a, z, q in grid]
+    packed = np.asarray(jnp.stack(
+        _solve_in_lanes("logistic", triples, lam_n, 1.0)))
+    each = np.asarray(jnp.stack(
+        [losses.alpha_step("logistic", a, z, qii, lam_n)
+         for a, z, qii in triples]))
+    assert packed.dtype == each.dtype == np.dtype(dtype)
+    assert np.all((each >= 0.0) & (each <= 1.0))
+    tol = 1e-12 if dtype == "float64" else 4 * np.spacing(each)
+    assert np.all(np.abs(packed - each) <= tol), np.abs(packed - each).max()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+def test_filler_lanes_stay_finite(dtype):
+    """Lanes past the last chain hold α = ½, z = 0, qii = 0, where the
+    Newton iteration stays put: no intermediate anywhere in the vector is
+    ever non-finite, whatever the chains' own values are."""
+    import jax
+
+    from cocoa_tpu.ops.pallas_sdca import _solve_in_lanes
+
+    triples = [tuple(jnp.asarray(v, dtype) for v in t)
+               for t in [(0.0, 50.0, 0.0), (1.0, -50.0, 1e3), (0.5, 0.0, 0.0)]]
+    with jax.debug_nans(True):
+        new = _solve_in_lanes("logistic", triples, 2.0, 1.0)
+    assert float(new[2]) == 0.5
+    # the filler's own values, alone: not so much as an inf on the way
+    # (a chain at α = 1 in f32 does pass one: log(1/0), clipped to _U_MAX)
+    half = jnp.full((1, 128), 0.5, dtype)
+    zero = jnp.zeros((1, 128), dtype)
+    with jax.debug_nans(True), jax.debug_infs(True):
+        rest = losses.alpha_step("logistic", half, zero, zero, 2.0)
+    assert np.all(np.asarray(rest) == 0.5)
+
+
+def _kernel_jaxpr(loss, k, interleave):
+    import jax
+
+    from cocoa_tpu.ops.pallas_sdca import pallas_sdca_round
+
+    n_shard, d, h = 256, 16, 4     # two lane blocks: no (1, 128) state
+    args = (jnp.zeros(d), jnp.zeros((k, n_shard)), jnp.zeros((k, n_shard, d)),
+            jnp.ones((k, n_shard)), jnp.ones((k, n_shard)),
+            jnp.zeros((k, h), jnp.int32))
+    return str(jax.make_jaxpr(lambda *a: pallas_sdca_round(
+        *a, 0.01, 1000, mode="plus", sigma=3.0, loss=loss, smoothing=S,
+        interleave=interleave, unroll=2))(*args))
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+@pytest.mark.parametrize("loss", ALL)
+def test_only_an_iterative_step_is_solved_in_lanes(loss, interleave):
+    """Read off the traced kernel: a closed-form loss's program holds no
+    transcendental and no (1, 128) packing vector (the scalar branch, as
+    before); logistic's holds ONE Newton chain per lockstep step on such a
+    vector, whatever K is — not K of them on 0-d values."""
+    import re
+
+    k, unroll = 3, 2
+    text = _kernel_jaxpr(loss, k, interleave)
+    exps = re.findall(r":(\w+)\[([\d,]*)\] = exp ", text)
+    packed = re.findall(r"\[1,128\]", text)
+    if not losses.step_is_iterative(loss):
+        assert not exps and not packed and " log " not in text
+        return
+    assert loss == "logistic"
+    assert len(exps) == unroll * (losses._NEWTON_ITERS + 1)
+    assert {shape for _, shape in exps} == {"1,128"}
+    assert text.count(" = log ") == unroll
+
+
+def test_iterative_steps_are_declared_by_the_loss():
+    assert losses.ITERATIVE_STEPS <= set(losses.LOSSES + losses.PROX_RULES)
+    assert [name for name in losses.LOSSES + losses.PROX_RULES
+            if losses.step_is_iterative(name)] == ["logistic"]
+
+
 @pytest.mark.parametrize("loss", ["smooth_hinge", "logistic"])
 @pytest.mark.parametrize("plus", [True, False])
 def test_cocoa_converges_each_loss(tiny_data, loss, plus):
