@@ -1,18 +1,16 @@
 #!/usr/bin/env bash
 # Fetch the real benchmark datasets (rcv1_train.binary, epsilon_normalized)
-# from the LIBSVM dataset mirror into benchmarks/data/, so benchmarks/run.py
-# prefers them over the synthetic stand-ins (rows then read rcv1(real) /
-# epsilon(real)).
+# from the LIBSVM dataset mirror into benchmarks/data/, for --trainFile runs
+# on the real files in place of the synthetic stand-ins.
 #
 # Integrity: this repo is built on an air-gapped machine, so upstream
 # sha256 digests cannot be pinned here ahead of time.  Instead:
 #   - trust-on-first-use: the first successful download records each file's
 #     sha256 into benchmarks/data.sha256 (commit it!); every later fetch
 #     verifies against the recorded digest and fails loudly on mismatch.
-#   - shape pins: benchmarks/run.py additionally validates the PUBLISHED
-#     dataset shapes (rcv1_train.binary: n=20,242 d=47,236; epsilon:
-#     n=400,000 d=2,000) at load time, so a wrong/corrupt file cannot
-#     silently stand in even on the very first use.
+#   - shape pins: pass the PUBLISHED dataset shapes (rcv1_train.binary:
+#     n=20,242 d=47,236; epsilon: n=400,000 d=2,000) as --numFeatures; the
+#     loader refuses a file with a larger feature index.
 #
 # Usage:  bash benchmarks/fetch_data.sh [rcv1|epsilon|all]
 set -euo pipefail
@@ -48,8 +46,8 @@ fetch() {
 # exact pins: this machine is air-gapped, so an exact published byte count
 # cannot be confirmed here, and a wrong exact pin would reject good files.
 # Truncated/partial downloads (the realistic corruption) fall far below
-# these; a same-size wrong file is caught by run.py's (n, d, nnz/row)
-# pins at load time.
+# these; a same-size wrong file is for the caller to catch against the
+# published (n, d) above.
 size_pin() {
     local name="$1" bytes="$2"
     local min=0

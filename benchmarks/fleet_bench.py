@@ -11,14 +11,14 @@ executable) plus a dispatch + fetch per super-block per tenant, while
 the fleet pays one compile and one dispatch for everything.
 
     python benchmarks/fleet_bench.py                  # fleet + serial A/B
-    python benchmarks/fleet_bench.py --fleet-only     # the CI-gate mode
+    python benchmarks/fleet_bench.py --fleet-only     # skip the serial control
     python benchmarks/fleet_bench.py --row=out.jsonl  # write the results row
 
 Rounds and certified counts are backend-independent (the per-tenant
 math is the solo math bit-for-bit in map mode and to float ulps in vmap
 mode); the wallclock/speedup columns are CPU-measured and re-measured by
-``--row`` runs.  benchmarks/check_regression.py gates the fleet-only
-rounds + full certification against the committed baseline row.
+``--row`` runs.  tests/test_count_gates.py holds the fleet-only rounds and
+full certification.
 """
 
 from __future__ import annotations
@@ -102,7 +102,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", type=int, default=256)
     ap.add_argument("--fleet-only", action="store_true",
-                    help="skip the serial control (the CI-gate mode)")
+                    help="skip the serial control")
     ap.add_argument("--lanes", default="vmap", choices=("vmap", "map"))
     ap.add_argument("--row", default=None,
                     help="write the benchmarks-results row here")

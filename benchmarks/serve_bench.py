@@ -21,13 +21,11 @@ threads for the duration, and reports
   (must equal the bucket count: the one-compile-per-bucket pin)
 
     python benchmarks/serve_bench.py                 # print the row
-    python benchmarks/serve_bench.py --row=out.jsonl # write it (CI gate)
+    python benchmarks/serve_bench.py --row=out.jsonl # write it
 
 Latency/qps are CPU-measured host wall-clock: the server child is pinned
-to the CPU backend, so no number here is a device metric (ROADMAP S5
-measures serving on the chip).  benchmarks/check_regression.py gates the SLA, the
-compile count, and a catastrophic-throughput floor against the
-committed row.
+to the CPU backend, so no number here is a device metric (ROADMAP R3
+measures serving on the chip).
 
 ``--serveDtype=bf16|int8`` switches to the low-precision A/B mode
 (docs/DESIGN.md §20): compiled-path margin throughput of the packed
@@ -98,7 +96,7 @@ EXPECTED_COMPILES_Q = 3
 T_FLEET = 4
 Q_PER_LINE = 16     # ';'-separated queries per protocol line
 FLEET_LINES = 64    # distinct preassembled lines cycled per client
-# the tracing A/B (docs/DESIGN.md §22): the committed fleet row carries
+# the tracing A/B (docs/DESIGN.md §22): the fleet row carries
 # a tracing-on closed-loop window (every line trace=-prefixed, the
 # router samples 1 in TRACE_SAMPLE into query_trace events) against a
 # back-to-back untraced window of the same shape; the overhead of the
@@ -865,10 +863,8 @@ def main(argv=None) -> int:
                     help="f32 = the canonical serving row; bf16/int8 = "
                          "the low-precision A/B row vs an f32 control")
     ap.add_argument("--ratio-bar", type=float, default=1.7,
-                    help="qps_ratio bar for the A/B self-gate: 1.7 is "
-                         "the acceptance bar a COMMITTED row must hold; "
-                         "CI fresh re-runs pass a catastrophic floor "
-                         "instead (shared-runner wall-clock)")
+                    help="qps_ratio bar for the A/B self-gate (1.7 is "
+                         "the acceptance bar)")
     ap.add_argument("--correctness-only", action="store_true",
                     help="skip the qps_ratio bar and gate only the "
                          "correctness axes (flips / compiles / swap): "
@@ -890,10 +886,8 @@ def main(argv=None) -> int:
                          "capacity")
     ap.add_argument("--trace-bar", type=float, default=TRACE_BAR_PCT,
                     help="max tracing-on qps overhead (%%) the fleet "
-                         "A/B may show: the committed row holds the "
-                         "default 5%% acceptance bar; CI fresh re-runs "
-                         "pass a looser catastrophic bound "
-                         "(shared-runner wall-clock)")
+                         "A/B may show (default: the 5%% acceptance "
+                         "bar)")
     args = ap.parse_args(argv)
 
     if args.serveReplicas >= 2:
@@ -933,8 +927,7 @@ def main(argv=None) -> int:
         if row["dominant_hop"] is None:
             failures.append("no sampled query_trace assembled into a "
                             "waterfall — tracing went dark under the "
-                            "committed 1-in-"
-                            f"{TRACE_SAMPLE} sampling")
+                            f"1-in-{TRACE_SAMPLE} sampling")
         for msg in failures:
             print(f"serve_bench FAIL: {msg}", file=sys.stderr)
         return 1 if failures else 0

@@ -56,12 +56,13 @@ PHASES = ("demo", "rcv1_seq", "rcv1_block", "rcv1_hybrid", "epsilon", "serve")
 FULL = {
     "demo": dict(train="data/small_train.dat", test="data/small_test.dat",
                  d=9947, k=4, lam=1e-3, rounds=600, gap=1e-4),
-    # the benchmarks/run.py rcv1 config (synth_sparse stands in for the
-    # LIBSVM file no round could download); 350 rounds to 1e-3 on record
+    # the rcv1 shape (synth_sparse stands in for the LIBSVM file no round
+    # could download); 350 rounds to 1e-3 on record
     "rcv1": dict(n=20242, d=47236, nnz_mean=75, k=8, lam=1e-4,
                  debug_iter=25, seq_rounds=600, seq_gap=1e-3,
                  window_rounds=50, block=128, hot="auto"),
-    # the benchmarks/run.py epsilon config; ~100 / ~20 rounds on record
+    # the epsilon shape (chipbench/configs/epsilon.json); ~100 / ~20
+    # rounds on record (reference / permuted sampling)
     "epsilon": dict(n=400_000, d=2000, k=8, lam=1e-3, rounds=400, gap=1e-4,
                     block=128),
     "serve": dict(d=9947, lines=8, per_line=8, nnz=24),

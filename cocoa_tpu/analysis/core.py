@@ -38,7 +38,7 @@ SEVERITIES = ("error", "warning")
 # the scan surface: package + benchmark drivers.  tests/ is excluded on
 # purpose — the known-bad rule fixtures live there, and f64 parity
 # pinning is the tests' JOB (the f64 rule's allowlist made code-level).
-DEFAULT_SCAN = ("cocoa_tpu", "benchmarks", "bench.py")
+DEFAULT_SCAN = ("cocoa_tpu", "benchmarks")
 
 _ALLOW_RE = re.compile(
     r"#\s*jaxlint:\s*allow=([\w,\-]+)\s*(?:--\s*(.*))?")

@@ -34,12 +34,6 @@ size per data layout — sparse layouts whose densified tile cannot ride
 the fused kernel use the in-kernel CSR Gram path of ops/pallas_sparse
 when it fits, and keep the sequential kernel otherwise, since
 SPLIT-path densified sparse blocks lose to it),
-``--blockPipeline=auto|on|off`` (the two-phase software-pipelined block
-scan: block b+1's row-tile gather overlapped with block b's chain
-kernel — bit-identical schedules, auto = on for multi-block rounds;
-``off`` is the serial A/B control benchmarks/kernels.py measures
-against.  Dense/densified block paths only: the sparse CSR Gram path
-always runs serial and the flag is inert there),
 ``--divergenceGuard=auto|on|off`` (the
 gap-target stall watch; auto arms it only when σ′ is overridden below
 the safe K·γ bound — see solvers/base.resolve_divergence_guard),
@@ -116,8 +110,7 @@ through ONE compiled vmapped round (solvers/fleet.py): per-tenant λ·n
 rides the unchanged SDCA kernels as a traced scalar, each tenant's σ′
 schedule / secant bank / gap watch is an independent lane, certified
 tenants mask out bitwise-frozen, and the whole fleet costs one compile,
-one dispatch and one fetch (256 tenants measured at 173× the serial
-solo path's models/s on CPU).  ``--fleetLanes=vmap|map`` picks batched
+one dispatch and one fetch.  ``--fleetLanes=vmap|map`` picks batched
 lanes (throughput) vs sequential lanes in the same jit (bit-parity with
 the solo path at any T).  The fleet surface is deliberately narrow:
 every flag that cannot mean anything on the one-dispatch path
@@ -178,7 +171,7 @@ _TPU_FLAGS = ("dtype", "layout", "rng", "math", "loss",
 _EXTRA_FLAGS = ("mesh", "fp", "trajOut", "gapTarget", "resume", "scanChunk",
                 "deviceLoop", "master", "processId", "numProcesses",
                 "profile", "objective", "l2", "blockSize",
-                "blockPipeline", "divergenceGuard",
+                "divergenceGuard",
                 "sigmaSchedule", "warmStart", "accel", "theta",
                 "elastic", "stallTimeout", "evalDense", "hotCols",
                 "ingest", "ingestCache", "metrics", "events", "quiet",
@@ -419,8 +412,6 @@ def main(argv=None) -> int:
                            "ref)",
             "blockSize": "the block/Pallas kernels own their shard axes "
                          "and cannot ride the tenant vmap",
-            "blockPipeline": "the block/Pallas kernels own their shard "
-                             "axes and cannot ride the tenant vmap",
         }
         if cfg.test_file:
             print("error: --testFile does not combine with --fleet: "
@@ -1631,19 +1622,8 @@ def main(argv=None) -> int:
     if ds is not None and block_auto:
         # dense always blocks; sparse blocks only when the in-kernel CSR
         # Gram path fits (a densified sparse block LOSES to the sequential
-        # sparse kernel, benchmarks/KERNELS.md)
+        # sparse kernel)
         block_size = _resolve_auto_block(ds, mesh, k, dtype, quiet=quiet)
-
-    bp = (extras["blockPipeline"] or "auto").lower()
-    if bp not in ("auto", "on", "off"):
-        print(f"error: --blockPipeline must be auto|on|off, got "
-              f"{extras['blockPipeline']!r}", file=sys.stderr)
-        return 2
-    if bp != "auto" and not (block_size or block_auto):
-        print("error: --blockPipeline controls the block-coordinate scan "
-              "schedule and needs --blockSize", file=sys.stderr)
-        return 2
-    block_pipeline = None if bp == "auto" else (bp == "on")
 
     guard = (extras["divergenceGuard"] or "auto").lower()
     if guard not in ("auto", "on", "off"):
@@ -1716,8 +1696,7 @@ def main(argv=None) -> int:
             sampling=cfg.sampling, quiet=quiet,
             gap_target=gap_target, scan_chunk=cfg.scan_chunk,
             math=cfg.math, device_loop=cfg.device_loop,
-            block_size=block_size, block_pipeline=block_pipeline,
-            divergence_guard=guard, **resume_kw,
+            block_size=block_size, divergence_guard=guard, **resume_kw,
         )
         from cocoa_tpu.solvers.prox_cocoa import _metrics_fn
 
@@ -1805,10 +1784,10 @@ def main(argv=None) -> int:
 
     cocoa_kw = dict(gap_target=gap_target, scan_chunk=cfg.scan_chunk,
                     math=cfg.math, device_loop=cfg.device_loop,
-                    block_size=block_size, block_pipeline=block_pipeline,
-                    divergence_guard=guard, sigma_schedule=sigma_schedule,
-                    warm_start=warm_start, accel=accel_flag,
-                    theta=theta_flag, overlap_io=overlap_io)
+                    block_size=block_size, divergence_guard=guard,
+                    sigma_schedule=sigma_schedule, warm_start=warm_start,
+                    accel=accel_flag, theta=theta_flag,
+                    overlap_io=overlap_io)
 
     def run_all():
         w, alpha, traj = run_cocoa(ds, params, debug, plus=True,
@@ -1824,8 +1803,8 @@ def main(argv=None) -> int:
                            device_loop=cfg.device_loop)
             w, alpha, traj = run_minibatch_cd(
                 ds, params, debug, math=cfg.math, block_size=block_size,
-                block_pipeline=block_pipeline, divergence_guard=guard,
-                **loop_kw, **restore("Mini-batch CD"), **common)
+                divergence_guard=guard, **loop_kw,
+                **restore("Mini-batch CD"), **common)
             finish(traj, w, alpha)
 
             w, traj = run_sgd(ds, params, debug, local=False, **loop_kw,

@@ -2,8 +2,7 @@
 
 The compile cache (utils/compile_cache.py) made the SECOND run's XLA
 compiles free; ingest stayed the dominant fixed cost — every process
-re-parsed the LIBSVM text on every start (benchmarks/RESULTS.md
-"Fixed-cost breakdown").  The CoCoA premise (arXiv:1409.1458) is that
+re-parsed the LIBSVM text on every start.  The CoCoA premise (arXiv:1409.1458) is that
 local data is touched ONCE and then reused across many cheap rounds;
 elastic restarts (PR 9), serve-while-train trainer relaunches (PR 13),
 fleet manifests sharing a dataset ref (PR 12), bench sweeps, and CI all

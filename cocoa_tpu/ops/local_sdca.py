@@ -472,26 +472,24 @@ def local_sdca_block_batched(
     block) software-pipelines the dense block scan into a two-phase
     schedule: the row tile for block b+1 is gathered by block b's scan
     iteration — as an op with NO data dependence on block b's chain
-    kernel — and rides the scan carry into iteration b+1.  The round-5
-    trace (benchmarks/TRACE.md) showed the serial schedule spending
-    1.17 ms/round in the row-tile gather and ~0.5 ms in a tile copy
-    strictly SERIALIZED with the 1.39 ms chain kernel; the pipelined
-    schedule (a) hands XLA's scheduler a gather whose DMA traffic can
-    overlap the Pallas kernel's execution window, and (b) lands the
-    gather directly in the loop-carried tile buffer instead of a fresh
-    per-iteration allocation, which is what fed the ~0.5 ms ``copy.13``
-    relayout.  The prefetch reorders memory traffic ONLY — every kernel
-    invocation consumes a tile gathered from the same indices by the same
-    gather op, so the pipelined and serial schedules are bit-identical
-    (pinned by tests/test_block.py); the last block prefetches block 0's
-    tile and discards it (one dead gather per round, ~1/nb of the gather
-    budget).  ``False`` restores the serial schedule (the A/B control in
-    benchmarks/kernels.py).  Scope: the fused and split (dense/densified)
-    paths only — the ``sparse_gram`` CSR path returns before the pipeline
-    machinery and always runs its serial schedule (its streams are
-    SMEM-prefetched inside the kernels; an explicit ``pipeline`` value is
-    inert there, so a pipelined-vs-serial A/B on a sparse-Gram config
-    measures nothing).
+    kernel — and rides the scan carry into iteration b+1.  The serial
+    schedule runs the row-tile gather and a tile copy strictly
+    SERIALIZED with the chain kernel; the pipelined schedule (a) hands
+    XLA's scheduler a gather whose DMA traffic can overlap the Pallas
+    kernel's execution window, and (b) lands the gather directly in the
+    loop-carried tile buffer instead of a fresh per-iteration
+    allocation, which is what fed the tile copy's relayout.  The
+    prefetch reorders memory traffic ONLY — every kernel invocation
+    consumes a tile gathered from the same indices by the same gather
+    op, so the pipelined and serial schedules are bit-identical (pinned
+    by tests/test_block.py); the last block prefetches block 0's tile
+    and discards it (one dead gather per round, ~1/nb of the gather
+    budget).  ``False`` is the serial schedule a single-block round
+    takes; no driver passes a value.  Scope: the fused and split
+    (dense/densified) paths only — the ``sparse_gram`` CSR path returns
+    before the pipeline machinery and always runs its serial schedule
+    (its streams are SMEM-prefetched inside the kernels; an explicit
+    ``pipeline`` value is inert there).
     """
     from cocoa_tpu.ops.pallas_chain import chain_block_batched, fused_block
 

@@ -51,16 +51,15 @@ LANES = 128
 SCAL_ROWS = 6  # [margins0 | labels | qii | alpha0 | mb | live-mask]
 CHAIN_VMEM_BUDGET = 12 << 20  # leave ~4 MB of the ~16 MB VMEM for Mosaic
 
-# ``--blockSize=auto`` candidates in MEASURED preference order — the B
-# sweep in benchmarks/kernels.py (block-{128,256,512} rows, KERNELS.md).
-# 128 is the measured-best tile on the epsilon flagship (v5e: 3.94 ms vs
-# block-256's 4.25 — and 256 already fails fused_fits there, falling to
-# the slower split path; 512 additionally fails chain_fits at K=8 and
-# falls all the way to the XLA chain).  The resolver (solvers/cocoa.py
-# auto_block_size) walks this ranking and takes the FIRST candidate that
-# passes the same fit accounting the dispatch layer uses — a measured
-# choice, not largest-that-fits.  Re-rank when benchmarks/kernels.py
-# regenerates KERNELS.md with a different winner.
+# ``--blockSize=auto`` candidates in preference order.  At the epsilon
+# shape (400,000 x 2,000, K=8) 128 is the only tile that rides the fused
+# kernel: 256 already fails fused_fits there and falls to the split
+# path; 512 additionally fails chain_fits at K=8 and falls all the way
+# to the XLA chain.  The resolver (solvers/cocoa.py auto_block_size)
+# walks this ranking and takes the FIRST candidate that passes the same
+# fit accounting the dispatch layer uses — a ranked choice, not
+# largest-that-fits.  No cell runs a block path, so the ranking has no
+# number on record (ROADMAP D4).
 BLOCK_SIZE_PREFERENCE = (128, 256, 512)
 
 

@@ -547,8 +547,8 @@ def pallas_sparse_sdca_round(
 # The dense block path (ops/local_sdca.local_sdca_block_batched) gathers each
 # sampled block into a (K, B, d) dense tile before the Gram matmul; at rcv1
 # scale (d≈47k, ~73 nnz/row) that is ~650x more HBM traffic than the rows'
-# nonzeros, and benchmarks/KERNELS.md measured the densified block path 2.2x
-# SLOWER than the sequential sparse kernel.  These kernels replace every
+# nonzeros, so the densified block path loses to the sequential sparse
+# kernel there.  These kernels replace every
 # O(B·d) dense tile with O(nnz) work over the same SMEM-scalar-prefetched
 # padded-CSR layout the sequential kernel proved out:
 #

@@ -137,8 +137,8 @@ MAX_SIGMA_LEVELS = 8
 # bounded to one eval cadence.  All slots are small integers or f32
 # gaps — exact in float32, exact in the checkpoint meta JSON round trip.
 #
-# Measured-out alternatives on the rcv1-synth λ=1e-4 config (SWEEPS.md
-# "accelerated outer loop"): per-round growing-β Nesterov momentum on w
+# Measured-out alternatives on the rcv1-synth λ=1e-4 config: per-round
+# growing-β Nesterov momentum on w
 # DIVERGES (54 restarts, never certifies — one CoCoA+ round is a large
 # contraction step, and 25 unmonitored β→1 extrapolations overshoot the
 # dual box); eval-windowed fixed β down to 0.05 still diverges; damped
@@ -149,7 +149,7 @@ MAX_SIGMA_LEVELS = 8
 # the regime the signed secant coefficient adapts to: measured 1.76×
 # fewer rounds to the 1e-4 certificate on full rcv1-synth at the safe
 # σ′ = K·γ (1100 → 625), 1.38× at σ′ = K/2 — the ratio grows with the
-# control's round count (benchmarks/SWEEPS.md).
+# control's round count.
 ACCEL_LEN = 8
 A_HIST = SCHED_LEN
 A_JUMP = SCHED_LEN + 1
@@ -196,7 +196,7 @@ def secant_coef(xp, rho):
 #     0.5-rel watch moves it up within two evals).
 # The ladder starts at H/2, not lower: H has strongly diminishing
 # returns at the top (2×/10× MORE local work buys only 1.06–1.10×
-# fewer rounds, SWEEPS.md), so halving it costs almost nothing per
+# fewer rounds), so halving it costs almost nothing per
 # round — but an H/4 stage was measured to push the λ=1e-4 rcv1-synth
 # A/B from 800 to 925 rounds (the early fast-decay rounds ARE
 # productive, and their secant windows degrade too: 6 restarts vs 2).
@@ -987,7 +987,7 @@ def drive_on_device(
 
     Rationale: the per-round device compute of these solvers is microseconds,
     so the wall-clock of the host-stepped drivers is pure host/device
-    round-trip latency (one blocking scalar fetch per eval; see bench.py).
+    round-trip latency (one blocking scalar fetch per eval).
     The reference has the same structure (driver
     JVM ⇄ executors every round, CoCoA.scala:39-63) and pays it; riding the
     whole loop device-side is the TPU-native answer, not a benchmark trick —

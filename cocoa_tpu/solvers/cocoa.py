@@ -82,11 +82,10 @@ def auto_block_size(ds: ShardedDataset, m_local: int, dtype) -> int:
     """Resolve ``--blockSize=auto`` per data layout, mirroring EXACTLY the
     path local_sdca_block_batched would dispatch to.
 
-    Candidates are walked in the MEASURED ranking from the
-    benchmarks/kernels.py B sweep (pallas_chain.BLOCK_SIZE_PREFERENCE,
-    recorded in KERNELS.md) — the first candidate that passes the same
-    fit accounting the dispatch layer uses wins, so auto picks the
-    measured-best tile, not just the largest that fits:
+    Candidates are walked in the ranking of
+    pallas_chain.BLOCK_SIZE_PREFERENCE — the first candidate that passes
+    the same fit accounting the dispatch layer uses wins, so auto picks
+    the preferred tile, not just the largest that fits:
 
     - dense: a candidate fits when the lockstep chain kernel fits VMEM
       (chain_fits);
@@ -402,7 +401,6 @@ def _sdca_round_parts(
     block_chain: str = "xla",
     block_distinct: bool = False,
     block_sparse_gram=None,
-    block_pipeline=None,
 ):
     """The per-shard local update and driver-side apply shared by the
     per-round and chunked builders (so the two paths cannot diverge), for
@@ -416,11 +414,9 @@ def _sdca_round_parts(
     kernel — ops/pallas_sdca.py for the dense layout, ops/pallas_sparse.py
     for padded-CSR.  ``block > 0`` runs the fast inner loop as the
     block-coordinate MXU kernel (ops/local_sdca.local_sdca_block) with that
-    block size; ``block_pipeline`` (None = auto) controls the two-phase
-    software-pipelined block scan — next block's row-tile gather overlapped
-    with the current chain kernel, bit-identical schedules (see
-    local_sdca_block_batched).  Returns (per_shard, per_round_batched |
-    None, apply_fn)."""
+    block size (a round of more than one block takes the two-phase
+    software-pipelined scan, see local_sdca_block_batched).  Returns
+    (per_shard, per_round_batched | None, apply_fn)."""
     if math not in ("exact", "fast"):
         raise ValueError(f"math must be 'exact' or 'fast', got {math!r}")
     if block and pallas:
@@ -464,7 +460,6 @@ def _sdca_round_parts(
             sigma=sigma, loss=params.loss, smoothing=params.smoothing,
             block=block, interpret=(block_chain == "pallas_interpret"),
             distinct=block_distinct, sparse_gram=block_sparse_gram,
-            pipeline=block_pipeline,
         )
 
     @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
@@ -622,7 +617,6 @@ def run_sdca_family(
     block_size: int = 0,
     block_chain=None,
     block_sparse_gram=None,
-    block_pipeline=None,
     device_loop: bool = False,
     eval_fn=None,
     eval_kernel=None,
@@ -700,14 +694,6 @@ def run_sdca_family(
     sparse block-chain path for padded-CSR data: the block Gram and margin
     base come from SMEM CSR streams in-kernel and the Δw apply is a sparse
     scatter (ops/pallas_sparse) — no (K, B, d) densify.
-
-    ``block_pipeline`` (None = auto: on for multi-block rounds; flag
-    ``--blockPipeline``) software-pipelines the dense block scan: block
-    b+1's row-tile gather rides block b's scan iteration with no data
-    dependence on its chain kernel, so the gather traffic can hide behind
-    the kernel.  Bit-identical to the serial schedule
-    (local_sdca_block_batched; parity pinned by tests/test_block.py);
-    ``False`` is the A/B control benchmarks/kernels.py measures against.
 
     ``overlap_io=True`` (flag ``--overlapComm``, single-process runs
     only — resolved by the CLI): checkpoint WRITES on the device-loop
@@ -799,7 +785,6 @@ def run_sdca_family(
         pallas_state=path.state,
         block=block_size, block_chain=block_chain,
         block_sparse_gram=block_sparse_gram,
-        block_pipeline=block_pipeline,
         # permuted sampling with n_local % H == 0 keeps every round inside
         # one epoch's permutation, so the round's H draws are pairwise
         # distinct per shard — the license for the block kernel's
@@ -823,10 +808,9 @@ def run_sdca_family(
     if pallas and ds.layout == "dense":
         # fold X for the dense kernel ONCE per DATASET (cached on the ds
         # object): folding inside the round loop would relayout the whole
-        # X every round, and folding per RUN was a measured fixed cost a
-        # process that reuses the dataset — the bench slope pair, sweep
-        # loops, the sigma=auto trial+safe pair — paid on every call
-        # (bench.py's fixed-cost breakdown, VERDICT r5 weak #6).  Safe to
+        # X every round, and folding per RUN is a fixed cost a process
+        # that reuses the dataset — back-to-back jobs, sweep loops, the
+        # sigma=auto trial+safe pair — would pay on every call.  Safe to
         # share: the folded tile is a jit INPUT (never donated), so no
         # dispatch can overwrite it.  Where the lane padding is small it
         # is stored lane-padded (path.rows), which makes the layout the
@@ -1146,7 +1130,7 @@ def run_sdca_family(
 
         cache_key = (
             "sdca", alg_name, alg, math, pallas, block_size, block_chain,
-            block_sparse_gram, block_pipeline, sched_token,
+            block_sparse_gram, sched_token,
             sampler.cache_token(), k, mesh,
             params.lam, params.n, params.local_iters, params.beta,
             params.gamma, params.loss, params.smoothing,
@@ -1199,7 +1183,7 @@ def run_cocoa(
     checkpoint/resume).
 
     ``params.sigma="auto"`` (flag ``--sigma=auto``) exploits the measured
-    σ′ trade-off (benchmarks/SWEEPS.md: the aggressive σ′ = K·γ/2 HALVES
+    σ′ trade-off (the aggressive σ′ = K·γ/2 HALVES
     the certified comm-rounds on randomly partitioned data, while σ′
     pushed below the data's coherence diverges) in one of two ways,
     selected by ``sigma_schedule`` (flag ``--sigmaSchedule``):
@@ -1221,7 +1205,7 @@ def run_cocoa(
     ``warm_start=(s, rounds)`` (flag ``--warmStart=<s>,<rounds>``): run a
     smooth_hinge(s) phase for the first ``rounds`` rounds (rounded up to
     the ``debugIter`` cadence), handing off to hinge inside the same
-    device loop — the measured-but-manual SWEEPS.md "warm smooth_hinge"
+    device loop — the measured-but-manual "warm smooth_hinge"
     procedure as a flag.  Requires ``--loss=hinge``; the handoff is exact
     because the smooth-hinge dual keeps α in the hinge dual's [0,1] box,
     and the reported gap is the hinge certificate throughout.

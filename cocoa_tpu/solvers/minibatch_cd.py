@@ -40,7 +40,6 @@ def run_minibatch_cd(
     pallas=None,
     block_size: int = 0,
     block_chain=None,
-    block_pipeline=None,
     device_loop: bool = False,
     sampling: str = "auto",
     divergence_guard: str = "auto",
@@ -53,7 +52,6 @@ def run_minibatch_cd(
         start_round=start_round, quiet=quiet, gap_target=gap_target,
         scan_chunk=scan_chunk, math=math, pallas=pallas,
         block_size=block_size, block_chain=block_chain,
-        block_pipeline=block_pipeline,
         device_loop=device_loop, sampling=sampling,
         divergence_guard=divergence_guard,
     )

@@ -118,7 +118,6 @@ def run_prox_cocoa(
     pallas=None,
     block_size: int = 0,
     block_chain=None,
-    block_pipeline=None,
     device_loop: bool = False,
     sampling: str = "auto",
     divergence_guard: str = "auto",
@@ -174,8 +173,7 @@ def run_prox_cocoa(
         rng=rng, w_init=w_init, alpha_init=x_init, start_round=start_round,
         quiet=quiet, gap_target=gap_target, scan_chunk=scan_chunk,
         math=math, pallas=pallas, block_size=block_size,
-        block_chain=block_chain, block_pipeline=block_pipeline,
-        device_loop=device_loop,
+        block_chain=block_chain, device_loop=device_loop,
         eval_fn=eval_fn, eval_kernel=eval_kernel, sampling=sampling,
         divergence_guard=divergence_guard,
     )

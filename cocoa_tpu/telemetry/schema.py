@@ -10,8 +10,9 @@ telemetry step, covering the six JSONL dialects this repo emits:
 - **trajectory dumps** (``--trajOut``, utils/logging.Trajectory): a
   manifest header line followed by per-round records, ``stopped`` carried
   on the final record;
-- **benchmark results** (benchmarks/results.jsonl): one config row per
-  line.
+- **benchmark results** (the ``--row`` artifact of
+  benchmarks/fleet_bench.py and benchmarks/serve_bench.py): one config
+  row per line.
 - **analysis reports** (``python -m cocoa_tpu.analysis --report=...``):
   an ``analysis_manifest`` header plus one finding per line, unique
   fingerprints (what the jaxlint baseline keys on).
@@ -293,26 +294,15 @@ ANALYSIS_FINDING_FIELDS = {
 ANALYSIS_SEVERITIES = ("error", "warning")
 
 
-# benchmarks/results.jsonl: "config" identifies the row; every OTHER known
-# key is type-checked when present (rows carry different column subsets —
-# svm vs lasso vs perf-accounting)
+# benchmark result rows (the ``--row`` artifact of
+# benchmarks/fleet_bench.py and benchmarks/serve_bench.py): "config"
+# identifies the row; every OTHER known key is type-checked when present
+# (fleet and serving rows carry different column subsets)
 RESULTS_FIELDS = {
-    "config": (str,), "n": (int,), "d": (int,), "k": (int,),
-    "lam": _NUM, "rounds": (int,), "gap": _NUM, "primal": _NUM,
-    "wallclock_s": _NUM, "fixed_s": _NUM, "l2": _NUM,
-    "vs_oracle": _NUM, "vs_oracle_same_gap": _NUM, "oracle_basis": (str,),
-    "type": (str,), "device": (str,), "ms_per_round": _NUM,
-    "us_per_step": _NUM, "useful_gflops": _NUM, "physical_gflops": _NUM,
-    "mfu_pct": _NUM, "physical_mfu_pct": _NUM, "hbm_floor_ms": _NUM,
-    "hbm_bound_pct": _NUM, "bound": (str,),
-    # h / gap_target are numeric but legacy rows carry e.g. "n/a"
-    "h": (int, str), "gap_target": (int, float, str),
-    # the accelerated outer loop A/B row (--accel, benchmarks/run.py):
-    # control rounds, measured ratio, and the theoretical Nesterov floor
-    # (perf.predict_accel_rounds)
-    "control_rounds": (int,), "rounds_ratio": _NUM,
-    "accel_floor_rounds": (int,), "stopped": (str, type(None)),
-    "sigma_ladder": (str,),
+    "config": (str,), "type": (str,), "device": (str,),
+    "n": (int,), "d": (int,), "k": (int,), "lam": _NUM,
+    "rounds": (int,), "gap": _NUM, "gap_target": _NUM,
+    "wallclock_s": _NUM, "stopped": (str, type(None)),
     # the fleet rows (--fleet / benchmarks/fleet_bench.py): tenants
     # certified per second through the one compiled vmapped round, with
     # the serial solo control and the measured speedup alongside
@@ -320,17 +310,6 @@ RESULTS_FIELDS = {
     "serial_models_per_second": _NUM, "speedup": _NUM, "compiles": (int,),
     "lam_lo": _NUM, "lam_hi": _NUM, "drive_mode": (str,),
     "lane_exec": (str,),
-    # the ingest A/B rows (benchmarks/run.py bench_ingest): per-process
-    # parse wallclock / bytes / peak host RSS, stream vs whole, with the
-    # perf.ingest_model predictions alongside
-    "mode": (str,), "processes": (int,), "file_mb": _NUM,
-    "parse_s": _NUM, "bytes_read_mb": _NUM, "peak_rss_mb": _NUM,
-    "rss_delta_mb": _NUM, "rss_vs_whole": _NUM,
-    "predicted_parse_s": _NUM, "predicted_csr_mb": _NUM,
-    # the warm-ingest rows (--ingestCache, benchmarks/run.py
-    # bench_ingest "warm" mode): zero-parse slab mapping vs the streamed
-    # cold parse of the same file/geometry
-    "warm_speedup": _NUM, "bytes_mapped_mb": _NUM,
     # the serving rows (--serve / benchmarks/serve_bench.py): queries/s
     # under a pinned p99 SLA plus the model-freshness (gap age) the run
     # observed; buckets is the static bucket ladder ("64/256"), compiles
@@ -364,7 +343,7 @@ RESULTS_FIELDS = {
     # docs/DESIGN.md §22): closed-loop qps with every line
     # trace=-prefixed (1-in-N sampled into query_trace events) vs the
     # same-shape untraced window, the measured overhead percentage
-    # (gated ≤5% on the committed row), the sampled-trace count, the
+    # (self-gated ≤5% by serve_bench), the sampled-trace count, the
     # trace stream's schema-violation count (gated 0), and the
     # waterfall's dominant hop over the run's sampled traces
     "traced_qps": _NUM, "trace_overhead_pct": _NUM,
@@ -466,7 +445,7 @@ def check_trajectory_lines(objs) -> list:
 
 
 def check_results_lines(objs) -> list:
-    """Validate benchmarks/results.jsonl rows."""
+    """Validate benchmark result rows (``--row`` artifacts)."""
     errors = []
     for ln, obj in objs:
         where = f"line {ln}"

@@ -14,8 +14,8 @@ cache sits at ONE fixed path inside the checkout (:data:`DEFAULT_DIR`,
 git-ignored) — never a temp dir, a pid or a timestamp: a directory that
 moves never hits.
 
-Enabled by the CLI, bench.py, benchmarks/{run,kernels,trace}.py and
-every process chip_smoke.py starts; set ``COCOA_NO_COMPILE_CACHE=1`` to
+Enabled by the CLI, chipbench/run.py and every process chip_smoke.py
+starts; set ``COCOA_NO_COMPILE_CACHE=1`` to
 opt out (e.g. when measuring compile time itself).
 """
 

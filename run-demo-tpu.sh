@@ -7,13 +7,12 @@
 # certified 1e-4 gap instead of a fixed round budget.  Index tables are
 # generated in-jit on the device (--sampling=auto).  Append --blockSize=128
 # on large dense problems (H >= a few hundred) for the fused block-
-# coordinate MXU kernel (1.36x faster epsilon rounds than the sequential
-# kernel with the round-5 distinct path, benchmarks/KERNELS.md), and
-# --sigma=auto on randomly-partitioned data: the reference's sigma'=K
+# coordinate MXU kernel (no cell of the benchmark runs it yet: PERF.md
+# §4), and --sigma=auto on randomly-partitioned data: the reference's sigma'=K
 # aggregation bound is worst-case — auto tries K/2 (which HALVED the
 # certified comm-rounds on the rcv1 config) and falls back to the safe K
 # if the divergence guard fires, so a wrong guess costs ~12 evals, not
-# the round budget (benchmarks/SWEEPS.md).  Append
+# the round budget.  Append
 # --accel=on --theta=adaptive for the round-12 accelerated outer loop:
 # a secant extrapolation of the dual at eval-window boundaries with a
 # gap-monitored restart (the rounds themselves are unmodified CoCoA+ and
@@ -21,8 +20,7 @@
 # comm rounds to the same gap on rcv1-synth at the safe σ′), plus the adaptive
 # local-accuracy ladder — early rounds run H/2 inner steps, tightening
 # to the full H near the target, resolved on device from the gap
-# estimate (docs/DESIGN.md "Accelerated outer loop"; A/B'd in
-# benchmarks/RESULTS.md and SWEEPS.md).
+# estimate (docs/DESIGN.md "Accelerated outer loop").
 cd "$(dirname "$0")"
 exec python -m cocoa_tpu.cli \
   --trainFile=data/small_train.dat \

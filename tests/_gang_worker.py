@@ -77,7 +77,7 @@ EXCHANGE_PHASES = ("kv_get", "kv_allgather", "kv_post", "exchange_join")
 def supervise_gang(argv, n: int = 2, events=None, **kw):
     """One-shot supervised run of THIS worker module — the launch
     contract shared by the slow tests, the CI chaos smoke, and the
-    benchmarks/check_regression gang gates (one place to change if the
+    gang cases of tests/test_count_gates.py (one place to change if the
     gang ever needs a new required flag or stream convention).
 
     Returns ``(rc, records)``: the supervisor's exit code and the

@@ -611,52 +611,6 @@ def test_pipelined_split_matches_serial_bit_exact(tiny_data):
     np.testing.assert_array_equal(np.asarray(dw_p), np.asarray(dw_s))
 
 
-def test_pipelined_through_driver_matches_serial(tiny_data):
-    """Driver-level A/B: ``block_pipeline`` on/off through run_cocoa
-    (chunked driver, interpret chain) produces the same trajectory — the
-    flag changes the schedule, never the observable run."""
-    ds = shard_dataset(tiny_data, k=K, layout="dense", dtype=jnp.float32)
-    p = _params(tiny_data, num_rounds=4)
-    dbg = DebugParams(debug_iter=4, seed=0)
-    outs = {}
-    for pipe in (False, True):
-        outs[pipe] = run_cocoa(ds, p, dbg, plus=True, quiet=True,
-                               math="fast", block_size=128,
-                               block_chain="pallas_interpret",
-                               block_pipeline=pipe, scan_chunk=2)
-    w_s, a_s, traj_s = outs[False]
-    w_p, a_p, traj_p = outs[True]
-    np.testing.assert_array_equal(np.asarray(w_p), np.asarray(w_s))
-    np.testing.assert_array_equal(np.asarray(a_p), np.asarray(a_s))
-    assert [r.gap for r in traj_p.records] == [r.gap for r in traj_s.records]
-
-
-def test_cli_block_pipeline_flag(tmp_path, capsys):
-    """--blockPipeline validates its value and requires --blockSize."""
-    from cocoa_tpu import cli
-
-    train = tmp_path / "tiny.dat"
-    train.write_text("\n".join(
-        ["+1 1:0.5 3:1.0", "-1 2:0.25 4:0.5", "+1 1:0.75",
-         "-1 3:0.5 4:0.25"] * 8) + "\n")
-    base = [f"--trainFile={train}", "--numFeatures=4", "--numSplits=2",
-            "--numRounds=4", "--localIterFrac=0.5", "--lambda=.01",
-            "--justCoCoA=true", "--debugIter=2", "--mesh=1"]
-    rc = cli.main(base + ["--math=fast", "--blockSize=8",
-                          "--blockPipeline=banana"])
-    assert rc == 2
-    assert "--blockPipeline" in capsys.readouterr().err
-
-    rc = cli.main(base + ["--blockPipeline=on"])
-    assert rc == 2
-    assert "--blockSize" in capsys.readouterr().err
-
-    rc = cli.main(base + ["--math=fast", "--blockSize=8",
-                          "--blockPipeline=off"])
-    assert rc == 0
-    assert "CoCoA+" in capsys.readouterr().out
-
-
 def test_block_distinct_through_driver_permuted(tiny_data, monkeypatch):
     """End-to-end: the driver auto-enables the distinct α update for
     permuted sampling exactly when counts % H == 0 (observed via a spy on

@@ -2,7 +2,7 @@
 
 σ′ = K·γ (CoCoA.scala:45) is the paper's SAFE aggregation bound: it assumes
 worst-case cross-shard coherence.  The --sigma override buys comm-rounds on
-randomly partitioned data (benchmarks/SWEEPS.md: σ′=K/2 halves the rcv1
+randomly partitioned data (σ′=K/2 halves the rcv1
 certified rounds) but diverges when pushed below the problem's tolerance —
 and before this guard, a diverging run burned its entire round budget before
 the certificate reported it.  These tests drive a run that PROVABLY needs

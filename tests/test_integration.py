@@ -70,11 +70,18 @@ def test_cli_end_to_end(capsys):
     assert "Test Error:" in out
 
 
-def test_cli_rejects_unknown_flag():
+@pytest.mark.parametrize("flag", ["--bogus=1", "--blockPipeline=off"])
+def test_cli_rejects_unknown_flag(flag):
+    """``--blockPipeline`` was a flag until PR 28; the block scan picks
+    its schedule from the block count and the name is refused like any
+    other unknown one."""
     from cocoa_tpu import cli
 
-    with pytest.raises(SystemExit, match="Invalid argument: --bogus"):
-        cli.parse_args(["--bogus=1"])
+    name = flag.split("=")[0]
+    with pytest.raises(SystemExit, match=f"Invalid argument: {name}"):
+        cli.parse_args([flag])
+    with pytest.raises(SystemExit, match=f"Invalid argument: {name}"):
+        cli.main([flag])
 
 
 def test_cli_requires_trainfile(capsys):

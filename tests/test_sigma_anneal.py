@@ -267,7 +267,7 @@ def _warm_ds():
 @pytest.mark.slow
 def test_warm_start_equals_manual_handoff():
     """The in-loop smooth_hinge→hinge handoff must equal the manual
-    two-run procedure (SWEEPS.md 'warm smooth_hinge' rows) bit-for-bit:
+    two-run procedure bit-for-bit:
     warm run to round W, then a hinge run resumed from its state."""
     ds, n = _warm_ds()
     debug = DebugParams(debug_iter=10, seed=0)
@@ -338,7 +338,7 @@ def test_rcv1_synth_anneal_certifies_at_575_rounds_no_restart():
     """The acceptance pin: the rcv1-synth production config (H=253,
     permuted, γ=1, λ=1e-4) under --sigma=auto --sigmaSchedule=anneal
     certifies the 1e-4 gap in ≤ 575 rounds — the measured σ′=K/2 sweet
-    spot (benchmarks/SWEEPS.md) — with zero backoffs and zero restarts."""
+    spot — with zero backoffs and zero restarts."""
     n, d, k = 20242, 47236, 8
     data = synth_sparse(n, d, nnz_mean=75, seed=0)
     ds = shard_dataset(data, k=k, layout="sparse", dtype=jnp.float32,

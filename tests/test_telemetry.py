@@ -15,7 +15,7 @@ What these tests pin:
 - the satellites: trajectory dumps carry a manifest header and the
   ``stopped`` reason; ``--quiet`` divergence still emits a
   machine-readable event; the metrics textfile counters; the schema
-  checker accepts benchmarks/results.jsonl and rejects malformed streams.
+  checker accepts benchmark result rows and rejects malformed streams.
 """
 
 import json
@@ -334,10 +334,33 @@ def test_round_window_profiler(monkeypatch):
     assert 100 in evals and 200 in evals
 
 
-def test_schema_checker_accepts_results_jsonl():
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    path = os.path.join(root, "benchmarks", "results.jsonl")
-    assert tele_schema.check_file(path) == []
+@pytest.mark.parametrize("row, mistyped", [
+    # the --row artifact of benchmarks/fleet_bench.py ...
+    ({"config": "fleet-256-synth", "type": "fleet", "tenants": 256,
+      "certified": 256, "rounds": 80, "gap": 9.9e-3, "stopped": "target",
+      "gap_target": 1e-2, "models_per_second": 1.0, "compiles": 1,
+      "lane_exec": "vmap", "device": "cpu"}, ("certified", "all")),
+    # ... and of benchmarks/serve_bench.py
+    ({"config": "serve-cpu-synth", "type": "serve", "device": "cpu",
+      "d": 512, "queries": 1000, "qps": 1.0, "p50_ms": 1.0, "p99_ms": 2.0,
+      "sla_ms": 50.0, "buckets": "64/256", "compiles": 2, "swaps": 1,
+      "stopped": None}, ("p99_ms", "2.0")),
+], ids=["fleet", "serve"])
+def test_schema_checker_results_rows(tmp_path, row, mistyped):
+    good = tmp_path / "row.jsonl"
+    good.write_text(json.dumps(row) + "\n")
+    assert tele_schema.check_file(str(good), kind="results") == []
+    assert tele_schema.check_file(str(good)) == []    # sniffed
+    field, wrong = mistyped
+    bad = tmp_path / "bad.jsonl"
+    bad.write_text(json.dumps({**row, field: wrong}) + "\n")
+    errs = tele_schema.check_file(str(bad), kind="results")
+    assert any(field in e for e in errs)
+    nameless = tmp_path / "nameless.jsonl"
+    nameless.write_text(json.dumps(
+        {k: v for k, v in row.items() if k != "config"}) + "\n")
+    assert any("config" in e for e in
+               tele_schema.check_file(str(nameless), kind="results"))
 
 
 def test_schema_checker_rejects_malformed(tmp_path):
