@@ -1689,7 +1689,10 @@ def main(argv=None) -> int:
                 meta, r0, x0 = ckpt_lib.load(path)
                 print(f"resuming ProxCoCoA+ from round {meta['round']} "
                       f"({path})")
-                resume_kw = dict(r_init=r0, x_init=x0,
+                from cocoa_tpu.data.sharding import rows_as_ordered
+
+                # kept by coordinate as built; taken in ds_c's order
+                resume_kw = dict(r_init=r0, x_init=rows_as_ordered(ds_c, x0),
                                  start_round=meta["round"] + 1)
         x, r, traj = run_prox_cocoa(
             ds_c, b, lasso_params, cfg.to_debug(), mesh=mesh, rng=cfg.rng,
@@ -1720,6 +1723,7 @@ def main(argv=None) -> int:
         import numpy as _np
 
         from cocoa_tpu import checkpoint as ckpt_lib
+        from cocoa_tpu.data.sharding import rows_as_ordered
 
         path = ckpt_lib.latest(cfg.chkpt_dir, algorithm)
         if path is None:
@@ -1727,14 +1731,17 @@ def main(argv=None) -> int:
         meta, arrays = ckpt_lib.load_full(path)
         print(f"resuming {algorithm} from round {meta['round']} ({path})")
         out = dict(w_init=arrays["w"], start_round=meta["round"] + 1)
+        # a checkpoint keeps α and the window bank by the rows' positions
+        # as built; the solvers take them in the order ds has its rows in
+        # (data/sharding.order_rows_by_length; the same until it is ordered)
         if arrays.get("alpha") is not None:
-            out["alpha_init"] = arrays["alpha"]
+            out["alpha_init"] = rows_as_ordered(ds, arrays["alpha"])
         if meta.get("sched") is not None:
             out["sched_init"] = _np.asarray(meta["sched"], _np.float32)
         if arrays.get("hist") is not None:
             # the --accel secant window bank: restoring it (with the
             # sched accel slots) makes a mid-momentum resume bit-identical
-            out["hist_init"] = arrays["hist"]
+            out["hist_init"] = rows_as_ordered(ds, arrays["hist"])
         return out
 
     def finish(traj, w, alpha=None):

@@ -21,6 +21,7 @@ reductions).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -153,6 +154,12 @@ class ShardedDataset:
                                       #   original column id (identical per
                                       #   shard; K-leading so it rides the
                                       #   fan-out plumbing like every leaf)
+    row_order: Optional[jax.Array] = None  # (K, n_shard) int32, present once
+                                      #   a sparse dataset's rows are in
+                                      #   length order
+                                      #   (:func:`order_rows_by_length`): the
+                                      #   row of shard k now at position j
+                                      #   was built at position row_order[k, j]
 
     @property
     def k(self) -> int:
@@ -188,6 +195,11 @@ class ShardedDataset:
                 out["hot_cols"] = self.hot_cols
             if self.X_eval is not None:
                 out["X_eval"] = self.X_eval
+            row_len = getattr(self, "_row_len_cache", None)
+            if self.row_order is not None and row_len is not None:
+                # rows in length order: the all-rows passes that run in row
+                # blocks stop at a block's longest row (ops/rows.SLOT_GROUP)
+                out["sp_row_len"] = row_len
         return out
 
     # --- pytree protocol: array fields are leaves, metadata is static, so a
@@ -196,7 +208,7 @@ class ShardedDataset:
         children = (
             self.labels, self.mask, self.sq_norms,
             self.X, self.sp_indices, self.sp_values, self.X_eval,
-            self.X_hot, self.hot_cols,
+            self.X_hot, self.hot_cols, self.row_order,
         )
         aux = (self.layout, self.n, self.num_features, tuple(self.counts))
         return children, aux
@@ -204,7 +216,7 @@ class ShardedDataset:
     @classmethod
     def tree_unflatten(cls, aux, children):
         (labels, mask, sq_norms, X, sp_indices, sp_values, X_eval,
-         X_hot, hot_cols) = children
+         X_hot, hot_cols, row_order) = children
         layout, n, num_features, counts = aux
         return cls(
             layout=layout,
@@ -220,6 +232,7 @@ class ShardedDataset:
             X_eval=X_eval,
             X_hot=X_hot,
             hot_cols=hot_cols,
+            row_order=row_order,
         )
 
 
@@ -229,6 +242,132 @@ try:
     )
 except ValueError:
     pass  # already registered (module re-imported/reloaded)
+
+
+# Ordering a shard's rows sorts each of its (n_shard, c) row arrays with the
+# lengths as the key: the sort's operands and results are the shard's rows
+# twice over.  Row arrays past this size on one device are ordered a shard at
+# a time into the donated array, so that the whole set is never held twice
+# (kddb: 4.9 GB an array beside 16.9 GB of HBM; a shard's is 0.6 GB);
+# smaller ones, and arrays spread over a mesh, all shards at once.
+ORDER_AT_ONCE_BYTES = 1 << 30
+
+
+@functools.partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
+def _order_rows(a, key, first, shards: int):
+    """Rows of shards [first, first + shards) of ``a`` (K, n_shard, c) put
+    in ascending order of ``key`` (shards, n_shard), stably, in place."""
+    from jax import lax
+
+    part = lax.dynamic_slice_in_dim(a, first, shards, 0)
+    _, part = lax.sort(
+        (jnp.broadcast_to(key[:, :, None], part.shape), part),
+        dimension=1, num_keys=1, is_stable=True)
+    return lax.dynamic_update_slice_in_dim(a, part, first, 0)
+
+
+@jax.jit
+def _order_row_scalars(row_len, width, scalars):
+    """The stable descending-length order of every shard's rows, (K,
+    n_shard) int32, its sort key, and the (K, n_shard) ``scalars`` in it."""
+    from jax import lax
+
+    key = width - row_len
+    _, order = lax.sort(
+        (key, lax.broadcasted_iota(jnp.int32, key.shape, 1)), dimension=1,
+        num_keys=1, is_stable=True)
+    return order, key, [jnp.take_along_axis(a, order, 1) for a in scalars]
+
+
+def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
+    """Put each shard's rows of a sparse dataset in descending order of
+    length (ops/pallas_sparse.row_lengths), IN PLACE: ``ds``'s row fields
+    are rebound to the ordered arrays, the row arrays made in their donated
+    buffers (any other reference to them dies), and ``ds.row_order`` says
+    where each row was.  Stable, and a padding row is as short as a row
+    gets, so padding rows stay last and ``counts`` keeps its meaning.  A
+    dataset that has a ``row_order`` is returned as it is.
+
+    Which rows a shard holds does not change, so neither does anything
+    CoCoA computes from a shard as a set: the objectives, the certificate,
+    w(α).  What the order buys: rows of one length share a row block, and
+    the all-rows passes that run in row blocks stop at a block's longest
+    row (ops/rows.SLOT_GROUP).  α, and anything else kept by row, is in the
+    dataset's order from here on; :func:`rows_as_built` maps it back."""
+    if ds.layout != "sparse" or ds.row_order is not None:
+        return ds
+    from cocoa_tpu.ops.pallas_sparse import row_lengths
+
+    row_len = getattr(ds, "_row_len_cache", None)
+    if row_len is None:
+        row_len = row_lengths(ds.sp_values)
+    order, key, (row_len, ds.labels, ds.mask, ds.sq_norms) = \
+        _order_row_scalars(row_len, ds.sp_values.shape[-1],
+                           [row_len, ds.labels, ds.mask, ds.sq_norms])
+    for name in ("sp_indices", "sp_values", "X_hot", "X_eval"):
+        a = getattr(ds, name)
+        if a is None:
+            continue
+        at_once = (a.nbytes <= ORDER_AT_ONCE_BYTES
+                   or len(a.sharding.device_set) > 1)
+        step = ds.k if at_once else 1
+        for first in range(0, ds.k, step):
+            a = _order_rows(a, key[first:first + step], first, step)
+        setattr(ds, name, a)
+    ds.row_order = order
+    ds._row_len_cache = row_len
+    return ds
+
+
+def order_rows_for_passes(ds: "ShardedDataset") -> "ShardedDataset":
+    """:func:`order_rows_by_length` where it moves a number: a sparse dataset
+    whose all-rows passes run in row blocks (ops/rows.row_block, from the
+    shapes alone).  A set that one block holds (rcv1, every small set) keeps
+    its rows as built, and its runs stay what they were bit for bit."""
+    from cocoa_tpu.ops import rows
+
+    if (ds.layout == "sparse" and ds.row_order is None and rows.row_block(
+            ds.n_shard, ds.sp_indices.shape[-1]) < ds.n_shard):
+        order_rows_by_length(ds)
+    return ds
+
+
+def _on_host(a) -> np.ndarray:
+    """``a`` whole on this host: a multi-process run gathers its shards, as
+    ``checkpoint.save`` does with α."""
+    if isinstance(a, jax.Array) and not a.is_fully_addressable:
+        from jax.experimental import multihost_utils
+
+        a = multihost_utils.process_allgather(a, tiled=True)
+    return np.asarray(a)
+
+
+def rows_as_built(ds: "ShardedDataset", by_row) -> np.ndarray:
+    """A (.., K, n_shard) array kept by row in ``ds``'s order (α, the
+    ``--accel`` window bank), by the rows' positions as the shards were
+    built: what a checkpoint stores, so that it resumes under a freshly
+    built dataset whatever order that one keeps its rows in."""
+    a = _on_host(by_row)
+    if ds.row_order is None:
+        return a
+    out = np.empty_like(a)
+    np.put_along_axis(out, np.broadcast_to(_on_host(ds.row_order), a.shape),
+                      a, axis=-1)
+    return out
+
+
+def rows_as_ordered(ds: "ShardedDataset", as_built) -> np.ndarray:
+    """The inverse of :func:`rows_as_built`: a checkpoint's by-row array in
+    ``ds``'s order.  A shard axis shorter than ``n_shard`` (a checkpoint from
+    before a larger padding) is zero-padded first, as ``align_alpha`` does."""
+    a = np.asarray(as_built)
+    if ds.row_order is None or a.shape[-1] > ds.n_shard:
+        return a        # too long a shard axis: ``align_alpha`` says so
+    if a.shape[-1] < ds.n_shard:
+        a = np.pad(a, [(0, 0)] * (a.ndim - 1)
+                   + [(0, ds.n_shard - a.shape[-1])])
+    return np.take_along_axis(
+        a, np.broadcast_to(_on_host(ds.row_order), a.shape), axis=-1)
 
 
 def _densify_rows(data, lo, hi, n_shard, d, np_dtype, row_nnz) -> np.ndarray:
@@ -529,7 +668,7 @@ def shard_dataset(
                 f"{mesh.devices.size} devices"
             )
         d_eff = mesh_lib.pad_features(d, mesh) if layout == "dense" else d
-        return _shard_dataset_distributed(
+        return order_rows_for_passes(_shard_dataset_distributed(
             data, k, layout, np_dtype, mesh, sizes, offsets, n_shard,
             # mirror the replicated path: only the dense layout pads d
             d_eff,
@@ -537,7 +676,7 @@ def shard_dataset(
             hot_ids=hot_ids, eval_dense=eval_dense,
             cache_view=_slab_view(cache, layout, k, n_shard, width,
                                   n_hot, d_eff, np_dtype, eval_dense),
-        )
+        ))
 
     if layout == "dense":
         d = mesh_lib.pad_features(d, mesh)
@@ -560,8 +699,8 @@ def shard_dataset(
         hc = np.zeros(n_hot, dtype=np.int32)
         hc[:len(hot_ids)] = hot_ids
         arrs["hot_cols"] = np.tile(hc[None], (k, 1))
-    return _finalize_replicated(arrs, layout=layout, n=n, d=d, mesh=mesh,
-                                sizes=sizes)
+    return order_rows_for_passes(_finalize_replicated(
+        arrs, layout=layout, n=n, d=d, mesh=mesh, sizes=sizes))
 
 
 def _finalize_replicated(arrs, *, layout, n, d, mesh, sizes

@@ -25,6 +25,7 @@ from __future__ import annotations
 from typing import NamedTuple, Optional
 
 import jax
+import jax.custom_batching
 
 
 class Row(NamedTuple):
@@ -122,27 +123,133 @@ def spread_index(idx: jax.Array, d: int) -> jax.Array:
     return (idx % SPREAD_ROWS) * c + idx // SPREAD_ROWS
 
 
-def _by_row_blocks(per_rows, idx: jax.Array, val: jax.Array) -> jax.Array:
-    """``per_rows(idx, val) -> (rows,)`` over an (n, W) padded-CSR shard,
-    run on :func:`row_block` (< n) rows at a time (``lax.map``) and put back
-    together as the (n,) result: the same per-row values as one pass over
-    all rows.  The last block starts early enough to end on the last row;
-    the rows it shares with its neighbour are taken from the neighbour."""
+# A block's rows sit on the lanes and their slots on the sublanes (how the
+# device stores a (n, W) shard), so a block whose longest row has L nonzeros
+# needs only its first ceil(L / SLOT_GROUP) sublane tiles: the block passes
+# below take a block's slots a tile at a time and stop there.  That pays once
+# the shard's rows are in length order (data/sharding.order_rows_by_length):
+# in file order every 16,384-row block of kddb holds a 64-long row.
+SLOT_GROUP = 8
+
+
+@jax.custom_batching.custom_vmap
+def _longest(row_len: jax.Array) -> jax.Array:
+    """``row_len.max()``, which under ``vmap`` stays ONE number: the longest
+    row of every batched shard's block.  A loop bounded by it keeps an
+    unbatched trip count and counter (a batched one would turn the loop's
+    slices into gathers); a shard whose own block is shorter reads slots
+    that hold column 0, value 0."""
+    return row_len.max()
+
+
+@_longest.def_vmap
+def _longest_of_all(axis_size, in_batched, row_len):
+    del axis_size, in_batched
+    return _longest(row_len), False
+
+
+def _slot_groups(width: int) -> tuple:
+    """``(slots a group, groups a row)`` of a W-slot padded-CSR row."""
+    group = min(SLOT_GROUP, width)
+    return group, -(-width // group)
+
+
+def _group_trips(row_len, start, block: int, axis: int, width: int):
+    """How many slot groups rows [start, start + block) fill: all of them
+    where the lengths are not known."""
+    from jax import lax
+
+    group, n_groups = _slot_groups(width)
+    if row_len is None:
+        return n_groups
+    longest = _longest(lax.dynamic_slice_in_dim(row_len, start, block, axis))
+    return (longest + (group - 1)) // group
+
+
+def _block_by_groups(a, start, block: int):
+    """Rows [start, start + block) of a (.., n, W) padded-CSR array with
+    their slots cut into groups and the rows last, as the device stores
+    them: (groups, .., group, block), a short last group filled with zeros
+    (column 0, value 0).  The block is taken whole, as one slice of the
+    array in the layout it is stored in — a group sliced straight from the
+    array inside the loop makes layout assignment copy the whole array
+    slot-minor first, 2 x 9.2 GB at kddb — and streaming a block's padding
+    costs nothing beside its gathers."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    width = a.shape[-1]
+    group, n_groups = _slot_groups(width)
+    part = lax.dynamic_slice_in_dim(a, start, block, a.ndim - 2)
+    if n_groups * group != width:
+        part = jnp.pad(part, [(0, 0)] * (a.ndim - 1)
+                       + [(0, n_groups * group - width)])
+    part = jnp.swapaxes(part, -1, -2)
+    return jnp.moveaxis(
+        part.reshape(part.shape[:-2] + (n_groups, group, block)), -3, 0)
+
+
+def _margins_by_row_blocks(w: jax.Array, idx: jax.Array, val: jax.Array,
+                           row_len=None) -> jax.Array:
+    """x_i·w for the rows of an (n, W) padded-CSR shard, :func:`row_block`
+    (< n) rows at a time (``lax.map``) and within a block one slot group at
+    a time, as far as the block's longest row reaches (``row_len``: (n,)
+    int32, ops/pallas_sparse.row_lengths; without it, every group).  The
+    last block starts early enough to end on the last row; the rows it
+    shares with its neighbour are taken from the neighbour."""
     import jax.numpy as jnp
     from jax import lax
 
     n, width = idx.shape
     block = row_block(n, width)
     nb = -(-n // block)
+    ws, d = spread_table(w), w.shape[0]     # note above SPREAD_ROWS
 
     def one(b):
         start = jnp.minimum(b * block, n - block)
-        return per_rows(lax.dynamic_slice_in_dim(idx, start, block, 0),
-                        lax.dynamic_slice_in_dim(val, start, block, 0))
+        cols = _block_by_groups(idx, start, block)
+        vals = _block_by_groups(val, start, block)
+
+        def add_group(g, m):
+            i = lax.dynamic_index_in_dim(cols, g, 0, keepdims=False)
+            v = lax.dynamic_index_in_dim(vals, g, 0, keepdims=False)
+            return m + (ws[spread_index(i, d)] * v).sum(-2)
+
+        # zeros LIKE a row of values: under shard_map the carry has to vary
+        # over the mesh as what is added to it does
+        return lax.fori_loop(0, _group_trips(row_len, start, block, 0, width),
+                             add_group, jnp.zeros_like(vals[0, 0]))
 
     out = lax.map(one, jnp.arange(nb))                   # (nb, block)
     tail = n - (nb - 1) * block                          # rows only the last has
     return jnp.concatenate([out[:-1].reshape(-1), out[-1, block - tail:]])
+
+
+def pass_slots(row_len, width: int, together: int = 0) -> int:
+    """Slots one all-rows pass over (K, n, W) padded-CSR shards touches,
+    counted from their (K, n) row lengths as the block passes run:
+    ``together`` shards at a time share a loop bound (0: all K, the
+    single-chip ``vmap``; on a mesh the shards of one device), and a block
+    goes as far as the longest row it reads.  The rows the last block shares
+    with its neighbour count as the neighbour's, so the count is K·n·W
+    wherever every block holds a full-width row — and where one block holds
+    a shard (:func:`row_block`), which takes the single pass."""
+    import numpy as np
+
+    k, n = row_len.shape
+    block = row_block(n, width)
+    if block >= n:
+        return k * n * width
+    group, _ = _slot_groups(width)
+    nb = -(-n // block)
+    starts = np.minimum(np.arange(nb) * block, n - block)
+    own = np.full(nb, block)
+    own[-1] = n - (nb - 1) * block
+    lens = np.asarray(row_len).reshape(-1, together or k, n)
+    longest = np.stack([lens[:, :, s:s + block].max(axis=(1, 2))
+                        for s in starts], axis=1)        # (devices, blocks)
+    trips = -(-longest.astype(np.int64) // group)
+    return int((trips * own).sum()) * (together or k) * group
 
 
 def shard_margins(w: jax.Array, shard: dict) -> jax.Array:
@@ -164,9 +271,7 @@ def shard_margins(w: jax.Array, shard: dict) -> jax.Array:
         return shard["X"] @ w
     idx, val = shard["sp_indices"], shard["sp_values"]
     if row_block(*idx.shape) < idx.shape[0]:
-        ws, d = spread_table(w), w.shape[0]     # note above SPREAD_ROWS
-        m = _by_row_blocks(
-            lambda i, v: (ws[spread_index(i, d)] * v).sum(-1), idx, val)
+        m = _margins_by_row_blocks(w, idx, val, shard.get("sp_row_len"))
     else:
         m = (w[idx] * val).sum(-1)
     if "X_hot" in shard:
@@ -293,17 +398,30 @@ def shards_axpy(coefs: jax.Array, shards: dict, vec: jax.Array) -> jax.Array:
     else:
         # in blocks of rows, so that coefs x values is never the size of
         # the rows again; the last block starts early enough to end on the
-        # last row, and the rows it shares with its neighbour add nothing
+        # last row, and the rows it shares with its neighbour add nothing.
+        # Within a block a slot group at a time, as far as its longest row
+        # reaches (note above SLOT_GROUP)
         from jax import lax
+
+        row_len = shards.get("sp_row_len")
 
         def add_block(b, vec):
             start = jnp.minimum(b * block, n - block)
             own = start + jnp.arange(block) >= b * block
             c = jnp.where(own, lax.dynamic_slice_in_dim(coefs, start, block,
                                                         1), 0)
-            return vec.at[lax.dynamic_slice_in_dim(idx, start, block, 1)
-                          ].add(c[..., None] * lax.dynamic_slice_in_dim(
-                              val, start, block, 1))
+
+            cols = _block_by_groups(idx, start, block)
+            vals = _block_by_groups(val, start, block)
+
+            def add_group(g, vec):
+                i = lax.dynamic_index_in_dim(cols, g, 0, keepdims=False)
+                v = lax.dynamic_index_in_dim(vals, g, 0, keepdims=False)
+                return vec.at[i].add(c[:, None, :] * v)
+
+            return lax.fori_loop(
+                0, _group_trips(row_len, start, block, 1, idx.shape[2]),
+                add_group, vec)
 
         vec = lax.fori_loop(0, -(-n // block), add_block, vec)
     if "X_hot" in shards:

@@ -488,6 +488,26 @@ def _last_gap(traj):
     return None
 
 
+def checkpoint_arguments(debug: DebugParams, name: str, round_t: int,
+                         state: tuple, traj, ckpt_rows=None) -> tuple:
+    """``(args, kwargs)`` of the ``checkpoint.save`` every drive* path makes
+    of ``state`` — ``(w,)``, ``(w, α)``, ``(w, α, sched)`` or ``(w, α, hist,
+    sched)``.  ``ckpt_rows`` (``data.sharding.rows_as_built`` of a dataset
+    whose rows were put in length order) maps what is kept by row — α and
+    the ``--accel`` window bank — to the rows' positions as the shards were
+    built: a checkpoint never depends on the order a dataset keeps its rows
+    in, so it resumes under a freshly built one."""
+    alpha = state[1] if len(state) > 1 else None
+    hist = state[2] if len(state) > 3 else None
+    if ckpt_rows is not None:
+        alpha = None if alpha is None else ckpt_rows(alpha)
+        hist = None if hist is None else ckpt_rows(hist)
+    return ((debug.chkpt_dir, name, round_t, state[0], alpha),
+            dict(seed=debug.seed,
+                 sched=state[-1] if len(state) > 2 else None,
+                 hist=hist, gap=_last_gap(traj)))
+
+
 class _GapWatch:
     """Windowed no-improvement watch over eval-cadence gap values;
     ``update(gap)`` returns True when the run should bail out (diverged or
@@ -523,6 +543,7 @@ def drive(
     gap_target: Optional[float] = None,
     start_round: int = 1,
     divergence_guard: bool = True,
+    ckpt_rows=None,
 ):
     """The outer driver loop shared by every solver (CoCoA.scala:39-63
     skeleton): run rounds, gate evaluation to every ``debugIter`` rounds,
@@ -553,13 +574,9 @@ def drive(
                 break
 
         if debug.chkpt_dir and debug.chkpt_iter > 0 and t % debug.chkpt_iter == 0:
-            ckpt_lib.save(
-                debug.chkpt_dir, name, t, state[0],
-                state[1] if len(state) > 1 else None, seed=debug.seed,
-                sched=state[-1] if len(state) > 2 else None,
-                hist=state[2] if len(state) > 3 else None,
-                gap=_last_gap(traj),
-            )
+            args, kwargs = checkpoint_arguments(debug, name, t, state, traj,
+                                                ckpt_rows)
+            ckpt_lib.save(*args, **kwargs)
     return state, traj
 
 
@@ -577,6 +594,7 @@ def drive_chunked(
     divergence_guard: bool = True,
     sigma_levels: Optional[tuple] = None,
     accel: Optional["AccelConfig"] = None,
+    ckpt_rows=None,
 ):
     """Chunked variant of :func:`drive`: rounds run device-side in blocks of
     up to ``chunk`` via ``lax.scan`` (one dispatch per block instead of one
@@ -678,13 +696,9 @@ def drive_chunked(
                 break
 
         if ckpt_on and end % debug.chkpt_iter == 0:
-            ckpt_lib.save(
-                debug.chkpt_dir, name, end, state[0],
-                state[1] if len(state) > 1 else None, seed=debug.seed,
-                sched=state[-1] if len(state) > 2 else None,
-                hist=state[2] if len(state) > 3 else None,
-                gap=_last_gap(traj),
-            )
+            args, kwargs = checkpoint_arguments(debug, name, end, state,
+                                                traj, ckpt_rows)
+            ckpt_lib.save(*args, **kwargs)
     return state, traj
 
 
@@ -1167,6 +1181,7 @@ def drive_device_full(
     sigma_levels: Optional[tuple] = None,
     accel: Optional["AccelConfig"] = None,
     overlap_io: bool = False,
+    ckpt_rows=None,
 ):
     """Cadence-aligned wrapper around :func:`drive_on_device`, usable by any
     solver whose round has the (state, idxs, shards) shape: host-steps the
@@ -1224,14 +1239,8 @@ def drive_device_full(
     def maybe_ckpt(done_round):
         nonlocal last_saved
         if ckpt_on and done_round - last_saved >= debug.chkpt_iter:
-            args = (debug.chkpt_dir, name, done_round, state[0],
-                    state[1] if len(state) > 1 else None)
-            kwargs = dict(
-                seed=debug.seed,
-                sched=state[-1] if len(state) > 2 else None,
-                hist=state[2] if len(state) > 3 else None,
-                gap=_last_gap(traj),
-            )
+            args, kwargs = checkpoint_arguments(debug, name, done_round,
+                                                state, traj, ckpt_rows)
             if overlap_io:
                 _join_io()
                 # copy=True is load-bearing: np.asarray of a CPU jax
@@ -1658,12 +1667,13 @@ def drive_device_paths(
     sigma_levels: Optional[tuple] = None,
     accel: Optional["AccelConfig"] = None,
     overlap_io: bool = False,
+    ckpt_rows=None,
 ):
     """The scan_chunk / device_loop dispatch shared by every solver: builds
     the fused eval kernel (dual state iff ``alpha_in_state``; overridable
     for non-classification objectives) and routes to
     :func:`drive_device_full` or :func:`drive_chunked`.  Returns
-    (state, Trajectory)."""
+    (state, Trajectory).  ``ckpt_rows``: :func:`checkpoint_arguments`."""
     from cocoa_tpu.evals import objectives
 
     if device_loop:
@@ -1687,13 +1697,13 @@ def drive_device_paths(
             else (*cache_key, test_n, divergence_guard),
             mesh=mesh, divergence_guard=divergence_guard,
             sigma_levels=sigma_levels, accel=accel,
-            overlap_io=overlap_io,
+            overlap_io=overlap_io, ckpt_rows=ckpt_rows,
         )
     return drive_chunked(
         name, params, debug, state, chunk_fn, eval_fn, quiet=quiet,
         gap_target=gap_target, start_round=start_round, chunk=scan_chunk,
         divergence_guard=divergence_guard, sigma_levels=sigma_levels,
-        accel=accel,
+        accel=accel, ckpt_rows=ckpt_rows,
     )
 
 

@@ -23,9 +23,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from cocoa_tpu.config import DebugParams, Params
-from cocoa_tpu.data.sharding import ShardedDataset
+from cocoa_tpu.data.sharding import (ShardedDataset, order_rows_for_passes,
+                                     rows_as_built, rows_as_ordered)
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops import local_sdca
+from cocoa_tpu.ops import rows as _rows
 from cocoa_tpu.solvers import base
 from cocoa_tpu.telemetry import tracing as _tracing
 
@@ -165,7 +167,13 @@ class SolverPath:
     advances in lockstep solved as one vector, a shard a lane;
     ``scalar``: everything else — a closed-form step, and an iterative
     one wherever a kernel still solves it chain by chain on one
-    coordinate's scalars (``fori``, the sparse and the block kernels)."""
+    coordinate's scalars (``fori``, the sparse and the block kernels).
+    ``pass_slot_share``: of a sparse set's padded slots, the share one
+    all-rows pass (the certificate's margins, the ``--accel`` jump) touches:
+    1.0 where one block holds a shard or the rows' lengths are not known;
+    less where the pass runs in row blocks and stops at each block's longest
+    row (ops/rows.pass_slots, counted from the lengths: about a half for
+    rows in length order, data/sharding.order_rows_by_length)."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -177,6 +185,7 @@ class SolverPath:
     rows: str = "device_default"
     state: str = "hbm"
     step_solve: str = "scalar"
+    pass_slot_share: float = 1.0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -207,10 +216,31 @@ class SolverPath:
         rows = ", rows stored row-major" if self.rows == "row_major" else ""
         solve = (", the shards' steps solved in lanes"
                  if self.step_solve == "lanes" else "")
+        if self.pass_slot_share < 1.0:
+            solve += (f", all-rows passes touch {self.pass_slot_share:.3f} "
+                      f"of the padded slots")
         return (f"{what}, {self.layout} layout{rows}{solve}, on "
                 f"{self.platform} x "
                 f"{self.devices} ({self.shards_per_device} shard(s) per "
                 f"device)")
+
+
+def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
+    """:attr:`SolverPath.pass_slot_share` of a sparse dataset, counted once
+    from the row lengths it carries (``_row_len_cache``: attached by the
+    ordering or by an earlier run) and kept on it beside them."""
+    width = int(ds.sp_indices.shape[-1])
+    row_len = getattr(ds, "_row_len_cache", None)
+    if (not isinstance(row_len, jax.Array)      # none, or a shape alone
+            or _rows.row_block(ds.n_shard, width) >= ds.n_shard):
+        return 1.0
+    cached = getattr(ds, "_pass_slot_share_cache", None)
+    if cached is None or cached[0] != together:
+        cached = (together,
+                  _rows.pass_slots(np.asarray(row_len), width, together)
+                  / (ds.k * ds.n_shard * width))
+        ds._pass_slot_share_cache = cached
+    return cached[1]
 
 
 def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
@@ -332,6 +362,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         layout=layout, platform=platform,
         devices=len(ds.labels.sharding.device_set),
         shards_per_device=m_local,
+        pass_slot_share=_pass_slot_share(ds, m_local) if sparse else 1.0,
     )
     if block_size <= 0:
         from cocoa_tpu.ops import losses
@@ -731,6 +762,20 @@ def run_sdca_family(
     mid-momentum resume).
     """
     base.check_shards(ds)
+    # a sparse set whose all-rows passes run in row blocks: they stop at a
+    # block's longest row, and rows in length order make that a half of the
+    # slots.  Once per dataset, in place; α is in ds's order, here and after
+    as_built = ds.row_order is None
+    order_rows_for_passes(ds)
+    if as_built and ds.row_order is not None:
+        # what the caller holds by row is in the order ds had on entry
+        alpha_init, hist_init = (
+            None if a is None else rows_as_ordered(ds, a)
+            for a in (alpha_init, hist_init))
+    # checkpoints keep what is held by row (α, the --accel window bank) by
+    # the rows' positions as built, whatever order ds keeps them in
+    ckpt_rows = (None if ds.row_order is None
+                 else functools.partial(rows_as_built, ds))
     guard_on = base.resolve_divergence_guard(
         divergence_guard, alg[0], alg[2], ds.k, params.gamma)
     k = ds.k
@@ -824,11 +869,13 @@ def run_sdca_family(
                                row_major=path.rows == "row_major")
             ds._x_folded_cache = folded
         shard_arrays = {**shard_arrays, "X_folded": folded}
-    if (pallas or block_size > 0) and ds.layout == "sparse":
+    if ((pallas or block_size > 0) and ds.layout == "sparse"
+            and "sp_row_len" not in shard_arrays):
         # per-row nnz counts for the kernels' group early exit (sequential
         # sparse kernel AND the sparse block-chain path) — same per-dataset
         # cache rationale as the dense fold above (per round it would
-        # re-read the whole values array inside the scan)
+        # re-read the whole values array inside the scan); a dataset in
+        # length order brings them itself
         row_len = getattr(ds, "_row_len_cache", None)
         if row_len is None:
             from cocoa_tpu.ops.pallas_sparse import row_lengths
@@ -906,8 +953,6 @@ def run_sdca_family(
             # eval-boundary bookkeeping): the rounds themselves are
             # UNMODIFIED CoCoA+ — acceleration lives entirely between
             # windows, so the certificate arithmetic never changes.
-            from cocoa_tpu.ops import rows as _rows
-
             accel_cfg = base.AccelConfig(
                 base.theta_ladder(params.local_iters, theta == "adaptive"),
                 gap_target)
@@ -1145,7 +1190,7 @@ def run_sdca_family(
             device_loop=device_loop, cache_key=cache_key,
             eval_kernel=eval_kernel, divergence_guard=guard_on,
             sigma_levels=levels, accel=accel_cfg,
-            overlap_io=overlap_io,
+            overlap_io=overlap_io, ckpt_rows=ckpt_rows,
         )
         traj.meta["solver_path"] = path.as_dict()
         return state[0], state[1], traj
@@ -1159,7 +1204,7 @@ def run_sdca_family(
     (w, alpha), traj = base.drive(
         alg_name, params, debug, (w, alpha), round_fn, eval_fn,
         quiet=quiet, gap_target=gap_target, start_round=start_round,
-        divergence_guard=guard_on,
+        divergence_guard=guard_on, ckpt_rows=ckpt_rows,
     )
     traj.meta["solver_path"] = path.as_dict()
     return w, alpha, traj

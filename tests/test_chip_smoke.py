@@ -146,7 +146,11 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     assert path.rows == rows
     assert list(path.as_dict()) == [
         "inner", "kernel", "chain", "interpret", "layout", "platform",
-        "devices", "shards_per_device", "rows", "state", "step_solve"]
+        "devices", "shards_per_device", "rows", "state", "step_solve",
+        "pass_slot_share"]
+    # one block holds these shards: an all-rows pass touches every slot
+    assert path.pass_slot_share == 1.0
+    assert "all-rows passes touch" not in path.describe()
     # where w, dw and alpha live during the solve: on the chip for the
     # resident Pallas kernels at these sizes, in HBM on the fori path
     assert path.state == ("vmem" if pallas else "hbm")
@@ -203,6 +207,58 @@ def test_logistic_run_says_its_steps_are_solved_in_lanes(
         DebugParams(debug_iter=2, seed=0), plus=True, quiet=True,
         math="fast")
     assert traj.meta["solver_path"]["step_solve"] == "lanes"
+
+
+def test_run_says_what_share_of_the_slots_its_passes_touch(out, capfd,
+                                                          monkeypatch):
+    """A sparse run whose all-rows passes run in row blocks and stop at the
+    rows' lengths says what share of the padded slots they touch, on every
+    surface ``step_solve`` is on: the console line, ``run_start``'s
+    manifest (the CLI's own resolver call, on the dataset ``shard_dataset``
+    ordered at ingest) and ``Trajectory.meta`` (the driver's)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data import load_libsvm, shard_dataset
+    from cocoa_tpu.ops import rows
+    from cocoa_tpu.solvers import run_cocoa
+
+    rng = np.random.default_rng(0)
+    train = os.path.join(out, "lengths_train.dat")
+    with open(train, "w") as f:
+        for _ in range(900):
+            cols = np.sort(rng.choice(64, rng.integers(1, 17),
+                                      replace=False)) + 1
+            f.write(f"{rng.choice((-1, 1)):+d} "
+                    + " ".join(f"{j}:{rng.normal():.4f}" for j in cols)
+                    + "\n")
+    monkeypatch.setattr(rows, "GATHER_BLOCK_SLOTS", 16 * 128)
+    argv = [f"--trainFile={train}", "--numFeatures=64", "--numRounds=2",
+            "--localIterFrac=0.1", "--numSplits=3", "--lambda=0.01",
+            "--justCoCoA=true", "--math=fast", "--layout=sparse",
+            "--debugIter=2"]
+    _, events = chip_smoke.run_cli(argv, os.path.join(out, "lengths.jsonl"))
+    (start,) = [e for e in events if e["event"] == "run_start"]
+    share = start["manifest"]["solver_path"]["pass_slot_share"]
+    ds = shard_dataset(load_libsvm(train, 64), k=3, layout="sparse",
+                       dtype=jnp.float32)
+    assert ds.row_order is not None
+    lens = np.asarray(ds._row_len_cache)
+    width = ds.sp_indices.shape[-1]
+    # on the CLI's mesh every shard has a device, and a loop, of its own
+    assert share == rows.pass_slots(lens, width, together=1) / (
+        lens.size * width)
+    assert 0.3 < share < 1.0
+    assert (f"all-rows passes touch {share:.3f} of the padded slots"
+            in capfd.readouterr().out)
+    _, _, traj = run_cocoa(
+        ds, Params(n=ds.n, num_rounds=2, local_iters=4, lam=1e-2),
+        DebugParams(debug_iter=2, seed=0), plus=True, quiet=True,
+        math="fast")
+    # on one device the K shards share a loop: as far as the longest
+    assert traj.meta["solver_path"]["pass_slot_share"] == rows.pass_slots(
+        lens, width) / (lens.size * width) >= share
 
 
 def test_rcv1_phases_with_interpreted_kernels(out, interpret_kernels,

@@ -328,3 +328,150 @@ def test_row_blocked_margins_and_axpy_equal_the_whole_pass(n_rows,
         coefs, shards, w)
     np.testing.assert_array_equal(m_blocks, m_whole)
     np.testing.assert_allclose(a_blocks, a_whole, atol=1e-4, rtol=0)
+
+
+def _rows_of(lengths, width, d, seed):
+    """(K, n, W) padded-CSR rows of the given (K, n) lengths."""
+    r = np.random.RandomState(seed)
+    lengths = np.asarray(lengths)
+    live = np.arange(width)[None, None, :] < lengths[..., None]
+    idx = np.where(live, r.randint(0, d, lengths.shape + (width,)), 0)
+    val = np.where(live, r.randn(*lengths.shape, width), 0.0)
+    return idx.astype(np.int32), val.astype(F32)
+
+
+LENGTH_LAWS = {
+    # n = 300 rows a shard in blocks of 128: two blocks and a tail block
+    # that starts 84 rows early (it overlaps its neighbour)
+    "0_1_8_9_64": lambda r, k, n, w: np.sort(
+        r.choice([0, 1, 8, 9, w], (k, n)), axis=1)[:, ::-1],
+    "all_equal": lambda r, k, n, w: np.full((k, n), 9),
+    "all_empty": lambda r, k, n, w: np.zeros((k, n), int),
+    "unordered": lambda r, k, n, w: r.randint(0, w + 1, (k, n)),
+    # K shards whose blocks' longest rows differ: under vmap the loop runs
+    # to the longest of them
+    "shards_differ": lambda r, k, n, w: np.sort(np.stack(
+        [r.randint(0, min(m, w) + 1, n) for m in (3, w, 17)[:k]]), axis=1)[:, ::-1],
+}
+
+
+@pytest.mark.parametrize("width", [64, 12])
+@pytest.mark.parametrize("law", list(LENGTH_LAWS))
+def test_length_aware_block_passes_equal_the_whole_pass(law, width,
+                                                        monkeypatch):
+    """The block passes stop at a block's longest row, a slot group at a
+    time: the same margins and the same scatter-add as one pass over every
+    slot, to float32 rounding (a row's sum is made group by group), with
+    the lengths and without them, at a width that is no multiple of the
+    group too."""
+    from cocoa_tpu.ops import rows
+
+    r = np.random.RandomState(4)
+    k, n, d = 3, 300, 700
+    lengths = LENGTH_LAWS[law](r, k, n, width)
+    idx, val = _rows_of(lengths, width, d, seed=5)
+    w = jnp.asarray(r.randn(d).astype(F32))
+    coefs = jnp.asarray(r.randn(k, n).astype(F32))
+    whole = dict(sp_indices=jnp.asarray(idx), sp_values=jnp.asarray(val))
+    known = {**whole, "sp_row_len": jnp.asarray(lengths, jnp.int32)}
+
+    def both(shards):
+        m = jax.jit(jax.vmap(rows.shard_margins, in_axes=(None, 0)))(
+            w, shards)
+        return m, jax.jit(rows.shards_axpy)(coefs, shards, w)
+
+    m_whole, a_whole = both(whole)
+    monkeypatch.setattr(rows, "GATHER_BLOCK_SLOTS", width * 128)
+    assert rows.row_block(n, width) == 128
+    for shards in (known, whole):
+        m, a = both(shards)
+        np.testing.assert_allclose(m, m_whole, atol=2e-6, rtol=2e-6)
+        np.testing.assert_allclose(a, a_whole, atol=1e-4, rtol=0)
+    # one shard alone (no vmap), as a mesh's shard_map body sees it
+    one = {name: a[1] for name, a in known.items()}
+    np.testing.assert_allclose(jax.jit(rows.shard_margins)(w, one),
+                               m_whole[1], atol=2e-6, rtol=2e-6)
+
+
+def test_pass_slots_counts_what_the_block_passes_touch(monkeypatch):
+    """``rows.pass_slots`` against a count by hand: blocks of 128 rows, the
+    tail block pulled back (it goes as far as the longest row it reads, and
+    counts the 44 rows that are its own), groups of 8 slots, every shard
+    that shares a loop going as far as the longest of them."""
+    from cocoa_tpu.ops import rows
+
+    k, n, width = 2, 300, 64
+    lengths = np.zeros((k, n), int)
+    lengths[0, :128] = 64       # block 0: 8 groups (shard 1: 20 -> 3)
+    lengths[1, :128] = 20
+    lengths[0, 128:256] = 9     # block 1: 2 groups
+    lengths[1, 128:256] = 16
+    lengths[:, 256:] = 1        # tail block = rows 172..299: it sees the 16
+    assert rows.pass_slots(lengths, width) == k * n * width   # one block
+    monkeypatch.setattr(rows, "GATHER_BLOCK_SLOTS", width * 128)
+    together = (8 * 128 + 2 * 128 + 2 * 44) * 8 * k
+    assert rows.pass_slots(lengths, width) == together
+    # each shard with a loop of its own (one shard a device)
+    apart = ((8 + 3) * 128 + (2 + 2) * 128 + (2 + 2) * 44) * 8
+    assert rows.pass_slots(lengths, width, together=1) == apart
+    # rows of kddb's length law, in order: about a half
+    r = np.random.RandomState(0)
+    law = np.clip(np.round(np.exp(3.27 + 0.5 * r.randn(1, 128 * 200))), 1,
+                  64).astype(int)
+    share = rows.pass_slots(-np.sort(-law, axis=1), 64) / law.size / 64
+    assert 0.48 < share < 0.56
+    assert rows.pass_slots(law, 64) / law.size / 64 > 0.99   # unordered
+
+
+def test_job_from_an_unordered_sparse_set_orders_it_once(monkeypatch):
+    """One interpret-mode job on the HBM-state kernel from a sparse dataset
+    in built order whose all-rows passes run in row blocks: the entry puts
+    its rows in length order (once: a second job finds the order), the job
+    certifies, says what share of the slots its passes touch, and its α,
+    taken back to the rows as built, is the w it returns: w = w(α) against
+    ``tests/oracle.py``'s objectives on the original rows."""
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data import shard_dataset, sharding
+    from cocoa_tpu.data.synth import synth_sparse
+    from cocoa_tpu.ops import pallas_sparse, rows
+    from cocoa_tpu.solvers import run_cocoa
+
+    data = synth_sparse(1200, 400, nnz_mean=6, seed=2)
+    ds = shard_dataset(data, k=4, layout="sparse", dtype=jnp.float32)
+    width = ds.sp_indices.shape[-1]
+    assert ds.row_order is None
+    monkeypatch.setattr(rows, "GATHER_BLOCK_SLOTS", width * 128)
+    monkeypatch.setattr(pallas_sparse, "sparse_kernel_fits",
+                        lambda *a, **k: False)
+    calls = []
+    order = sharding.order_rows_by_length
+    monkeypatch.setattr(sharding, "order_rows_by_length",
+                        lambda ds: calls.append(1) or order(ds))
+    params = Params(n=ds.n, num_rounds=60, local_iters=30, lam=1e-2)
+    kw = dict(plus=True, quiet=True, math="fast", device_loop=True,
+              rng="permuted", gap_target=2e-2, accel="auto", pallas=True)
+    debug = DebugParams(debug_iter=5, seed=0)
+    w, alpha, traj = run_cocoa(ds, params, debug, **kw)
+    assert calls == [1] and ds.row_order is not None
+    path = traj.meta["solver_path"]
+    assert (path["kernel"], path["state"], path["interpret"]) == (
+        "pallas", "hbm", True)
+    lens = np.asarray(ds._row_len_cache)
+    assert path["pass_slot_share"] == pytest.approx(
+        rows.pass_slots(lens, width) / lens.size / width)
+    assert 0.2 < path["pass_slot_share"] < 0.8
+    assert traj.stopped == "target" and traj.records[-1].gap <= 2e-2
+    w2, alpha2, traj2 = run_cocoa(ds, params, debug, **kw)
+    assert calls == [1]                             # found in order
+    np.testing.assert_array_equal(np.asarray(w2), np.asarray(w))
+    # on the rows as built, through row_order
+    X, y = data.to_dense(), data.labels
+    sizes = ds.counts
+    a_built = sharding.rows_as_built(ds, alpha)
+    a_rows = np.concatenate([a_built[k, :sizes[k]] for k in range(ds.k)])
+    assert a_rows.min() >= 0.0 and a_rows.max() <= 1.0
+    w_of_alpha = (y * a_rows) @ X / (params.lam * ds.n)
+    np.testing.assert_allclose(np.asarray(w), w_of_alpha, atol=2e-5)
+    gap = oracle.duality_gap(X, y, np.asarray(w, np.float64), a_rows.sum(),
+                             params.lam)
+    assert gap == pytest.approx(traj.records[-1].gap, abs=2e-5)
