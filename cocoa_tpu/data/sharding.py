@@ -160,6 +160,19 @@ class ShardedDataset:
                                       #   (:func:`order_rows_by_length`): the
                                       #   row of shard k now at position j
                                       #   was built at position row_order[k, j]
+    # the STREAM storage of a sparse dataset (:func:`stream_suits`): rows of
+    # thousands of nonzeros are not padded to the longest.  sp_indices /
+    # sp_values are then (K, n_pieces, STREAM_PIECE): each shard's nonzeros
+    # as one run of slots, row after row, a row starting on a STREAM_ALIGN
+    # boundary (the slots up to it hold column 0, value 0), and
+    sp_row_ptr: Optional[jax.Array] = None  # (K, n_shard) int32: a row's
+                                      #   first slot / STREAM_ALIGN
+    sp_row_len: Optional[jax.Array] = None  # (K, n_shard) int32: its nonzeros
+    sp_row_iota: Optional[jax.Array] = None  # (K, W) int32 0..W-1, W the
+                                      #   longest row rounded up to
+                                      #   STREAM_ALIGN: the static width the
+                                      #   per-row accessors window a row by
+                                      #   (ops/rows.get_row)
 
     @property
     def k(self) -> int:
@@ -190,6 +203,10 @@ class ShardedDataset:
         else:
             out["sp_indices"] = self.sp_indices
             out["sp_values"] = self.sp_values
+            if self.sp_row_ptr is not None:
+                out["sp_row_ptr"] = self.sp_row_ptr
+                out["sp_row_len"] = self.sp_row_len
+                out["sp_row_iota"] = self.sp_row_iota
             if self.X_hot is not None:
                 out["X_hot"] = self.X_hot
                 out["hot_cols"] = self.hot_cols
@@ -208,7 +225,8 @@ class ShardedDataset:
         children = (
             self.labels, self.mask, self.sq_norms,
             self.X, self.sp_indices, self.sp_values, self.X_eval,
-            self.X_hot, self.hot_cols, self.row_order,
+            self.X_hot, self.hot_cols, self.row_order, self.sp_row_ptr,
+            self.sp_row_len, self.sp_row_iota,
         )
         aux = (self.layout, self.n, self.num_features, tuple(self.counts))
         return children, aux
@@ -216,7 +234,8 @@ class ShardedDataset:
     @classmethod
     def tree_unflatten(cls, aux, children):
         (labels, mask, sq_norms, X, sp_indices, sp_values, X_eval,
-         X_hot, hot_cols, row_order) = children
+         X_hot, hot_cols, row_order, sp_row_ptr, sp_row_len,
+         sp_row_iota) = children
         layout, n, num_features, counts = aux
         return cls(
             layout=layout,
@@ -233,6 +252,9 @@ class ShardedDataset:
             X_hot=X_hot,
             hot_cols=hot_cols,
             row_order=row_order,
+            sp_row_ptr=sp_row_ptr,
+            sp_row_len=sp_row_len,
+            sp_row_iota=sp_row_iota,
         )
 
 
@@ -242,6 +264,78 @@ try:
     )
 except ValueError:
     pass  # already registered (module re-imported/reloaded)
+
+
+# --- the stream storage ------------------------------------------------------
+# The rectangle (K, n_shard, W) pads every row to the longest: fine where
+# rows are short and even (kddb: W = 64 over a mean of 29), impossible where
+# the longest row is many times the mean (webspam: 350,000 x 32,768 slots is
+# 92 GB for 10 GB of nonzeros).  The stream keeps a shard's rows end to end.
+STREAM_ALIGN = 8                 # slots a row's start is aligned to (the
+                                 # kernels' slot group, ops/pallas_longrows)
+STREAM_PIECE = 128               # slots a piece: one full lane row
+STREAM_SPARE_PIECES = 8          # past a shard's last row: a kernel's last
+                                 # chunk of a row may read this far
+STREAM_MIN_MEAN = 256            # rows this long on average, and
+STREAM_RECTANGLE_RATIO = 2.0     # a rectangle this many times the stream
+
+
+def stream_row_slots(row_nnz) -> np.ndarray:
+    """Slots each row takes in the stream: its nonzeros up to the next
+    STREAM_ALIGN boundary."""
+    row_nnz = np.asarray(row_nnz, np.int64)
+    return -(-row_nnz // STREAM_ALIGN) * STREAM_ALIGN
+
+
+def stream_suits(row_nnz, itemsize: int = 4) -> bool:
+    """Whether rows of these lengths are kept as a stream: from the lengths
+    the loader observes alone.  Long rows (the mean past STREAM_MIN_MEAN:
+    the nonzeros of a step are then its work, and the stream's own padding
+    is under 1.5%) that the rectangle would at least double.  Short-rowed
+    sets (kddb, rcv1, every small test set) keep the rectangle and with it
+    their bytes, kernels and trajectories.  float32 only: the stream's
+    passes are the kernels of ops/pallas_longrows.py."""
+    row_nnz = np.asarray(row_nnz, np.int64)
+    if itemsize != 4 or not row_nnz.size:
+        return False
+    rectangle = row_nnz.size * int(row_nnz.max(initial=1))
+    return (row_nnz.mean() >= STREAM_MIN_MEAN and rectangle
+            > STREAM_RECTANGLE_RATIO * int(stream_row_slots(row_nnz).sum()))
+
+
+def stream_pieces(slots_per_shard) -> int:
+    """Pieces a shard's stream array holds for the fullest shard's
+    ``slots_per_shard``: whole (8, 128) tiles, STREAM_SPARE_PIECES spare."""
+    need = -(-int(np.max(slots_per_shard)) // STREAM_PIECE)
+    return -(-(need + STREAM_SPARE_PIECES) // 8) * 8
+
+
+def stream_row_iota(longest: int, k: int) -> np.ndarray:
+    """``sp_row_iota`` for a longest row of ``longest`` nonzeros."""
+    w = max(STREAM_ALIGN, -(-int(longest) // STREAM_ALIGN) * STREAM_ALIGN)
+    return np.tile(np.arange(w, dtype=np.int32)[None], (k, 1))
+
+
+def _build_stream_slab(data, lo, hi, n_shard, n_pieces, np_dtype,
+                       row_nnz) -> dict:
+    """Rows [lo, hi) of ``data`` as one shard's stream arrays."""
+    m = hi - lo
+    nnz = np.asarray(row_nnz[lo:hi], np.int64)
+    first = np.concatenate([[0], np.cumsum(stream_row_slots(nnz))])[:m]
+    a, b = data.indptr[lo], data.indptr[hi]
+    at = (np.repeat(first, nnz) + np.arange(a, b)
+          - np.repeat(np.asarray(data.indptr[lo:hi], np.int64), nnz))
+    spi = np.zeros(n_pieces * STREAM_PIECE, np.int32)
+    spv = np.zeros(n_pieces * STREAM_PIECE, np_dtype)
+    spi[at] = data.indices[a:b]
+    spv[at] = data.values[a:b]
+    ptr = np.zeros(n_shard, np.int32)
+    ptr[:m] = first // STREAM_ALIGN
+    length = np.zeros(n_shard, np.int32)
+    length[:m] = nnz
+    return dict(sp_indices=spi.reshape(n_pieces, STREAM_PIECE),
+                sp_values=spv.reshape(n_pieces, STREAM_PIECE),
+                sp_row_ptr=ptr, sp_row_len=length)
 
 
 # Ordering a shard's rows sorts each of its (n_shard, c) row arrays with the
@@ -294,8 +388,9 @@ def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
     the all-rows passes that run in row blocks stop at a block's longest
     row (ops/rows.SLOT_GROUP).  α, and anything else kept by row, is in the
     dataset's order from here on; :func:`rows_as_built` maps it back."""
-    if ds.layout != "sparse" or ds.row_order is not None:
-        return ds
+    if (ds.layout != "sparse" or ds.row_order is not None
+            or ds.sp_row_ptr is not None):
+        return ds               # (a stream's passes go by nonzeros as it is)
     from cocoa_tpu.ops.pallas_sparse import row_lengths
 
     row_len = getattr(ds, "_row_len_cache", None)
@@ -326,7 +421,8 @@ def order_rows_for_passes(ds: "ShardedDataset") -> "ShardedDataset":
     its rows as built, and its runs stay what they were bit for bit."""
     from cocoa_tpu.ops import rows
 
-    if (ds.layout == "sparse" and ds.row_order is None and rows.row_block(
+    if (ds.layout == "sparse" and ds.row_order is None
+            and ds.sp_row_ptr is None and rows.row_block(
             ds.n_shard, ds.sp_indices.shape[-1]) < ds.n_shard):
         order_rows_by_length(ds)
     return ds
@@ -383,7 +479,7 @@ def _densify_rows(data, lo, hi, n_shard, d, np_dtype, row_nnz) -> np.ndarray:
 
 def _build_shard_slabs(data, lo, hi, n_shard, layout, np_dtype, d, width,
                        row_nnz, row_sq, *, rank=None, n_hot=0,
-                       eval_dense=False) -> dict:
+                       eval_dense=False, stream=False) -> dict:
     """One shard's COMPLETE padded host arrays (rows [lo, hi) of
     ``data``): labels/mask/sq_norms plus the layout slabs — dense X,
     plain padded-CSR, or (``n_hot > 0``) the hybrid hot panel + cold
@@ -411,6 +507,10 @@ def _build_shard_slabs(data, lo, hi, n_shard, layout, np_dtype, d, width,
         out["X_hot"] = X_hot
         out["sp_indices"] = spi
         out["sp_values"] = spv
+    elif stream:
+        # ``width`` is the stream's piece count (:func:`stream_pieces`)
+        out.update(_build_stream_slab(data, lo, hi, n_shard, width, np_dtype,
+                                      row_nnz))
     else:
         a, b = data.indptr[lo], data.indptr[hi]
         rows = np.repeat(np.arange(m), row_nnz[lo:hi])
@@ -613,6 +713,18 @@ def shard_dataset(
                 f"row nnz {int(row_nnz.max())} exceeds max_nnz {width}"
             )
 
+    # rows of thousands of nonzeros, the longest many times the mean: kept
+    # as a stream, not padded to the longest (from the lengths alone; a
+    # single process's plain sparse layout only: the hybrid split, a forced
+    # width and the multi-process assembly keep the rectangle)
+    stream = (layout == "sparse" and not hot_cols and max_nnz is None
+              and not (mesh is not None and jax.process_count() > 1)
+              and stream_suits(row_nnz, np_dtype.itemsize))
+    if stream:
+        width = stream_pieces([stream_row_slots(
+            row_nnz[offsets[s]:offsets[s + 1]]).sum() for s in range(k)])
+        cache = None            # the slab cache keys rectangles
+
     hot_ids = None
     rank = None
     n_hot = 0
@@ -689,7 +801,7 @@ def shard_dataset(
             lambda s=s: _build_shard_slabs(
                 data, offsets[s], offsets[s + 1], n_shard, layout,
                 np_dtype, d, width, row_nnz, row_sq, rank=rank,
-                n_hot=n_hot, eval_dense=eval_dense))
+                n_hot=n_hot, eval_dense=eval_dense, stream=stream))
         for f, v in slab.items():
             arrs.setdefault(f, np.zeros((k, *v.shape), v.dtype))[s] = v
     if n_hot:
@@ -699,6 +811,8 @@ def shard_dataset(
         hc = np.zeros(n_hot, dtype=np.int32)
         hc[:len(hot_ids)] = hot_ids
         arrs["hot_cols"] = np.tile(hc[None], (k, 1))
+    if stream:
+        arrs["sp_row_iota"] = stream_row_iota(row_nnz.max(initial=1), k)
     return order_rows_for_passes(_finalize_replicated(
         arrs, layout=layout, n=n, d=d, mesh=mesh, sizes=sizes))
 
@@ -733,4 +847,7 @@ def _finalize_replicated(arrs, *, layout, n, d, mesh, sizes
         X_eval=put(arrs.get("X_eval")),
         X_hot=put(arrs.get("X_hot")),
         hot_cols=put(arrs.get("hot_cols")),
+        sp_row_ptr=put(arrs.get("sp_row_ptr")),
+        sp_row_len=put(arrs.get("sp_row_len")),
+        sp_row_iota=put(arrs.get("sp_row_iota")),
     )

@@ -1,0 +1,435 @@
+"""Sparse rows in pieces: the kernels of sets whose rows hold thousands of
+nonzeros (webspam: 3,727 a row, the longest 8.8 times that).
+
+``data/sharding.py`` keeps such a set as a **stream**: a shard's nonzeros
+as one run of (column, value) slots, row after row, each row starting on an
+``ALIGN``-slot boundary, the run cut into ``PIECE``-slot pieces (one full
+lane row each) — ``sp_indices`` / ``sp_values`` of shape (K, n_pieces,
+PIECE), ``sp_row_ptr`` (K, n_shard) a row's first slot in units of ALIGN,
+``sp_row_len`` its nonzeros.  Stored slots follow the nonzeros (under ALIGN
+slots of padding a row), not n x the longest row, and a row is one
+contiguous run of memory.
+
+What the v5e measured decides the rest (PERF.md §6, PR 30 step 0).  A
+gather or scatter of single elements costs 11-24 ns in XLA whatever the
+table, so nothing here gathers per nonzero in XLA.  One d-vector of 16.6M
+float32 (66 MB) does fit a core's 128 MiB of VMEM, two do not.  So one
+kernel body serves the three passes over rows, each with **one** d-vector
+resident in VMEM, lane-blocked (d/128, 128), and the rows streamed from HBM
+as they are stored:
+
+- ``dots``: x_i . v for a list of rows (the certificate's margins over all
+  rows; x_i . w of a round's sampled rows, w being fixed for the round);
+- ``chain``: the sequential SDCA steps of one shard against the VMEM-held
+  dw alone — margin = x_i . w (from ``dots``) + sigma' x_i . dw, the alpha
+  step of ``losses.alpha_step``, dw += coef x_i — in the order of the
+  ``fori`` reference (CoCoA.scala:148-188);
+- ``axpy``: v += c_i x_i over a list of rows (the ``--accel`` jump).
+
+A row's slots reach the scalar core by DMA, HBM to SMEM, ``CHUNK_PIECES``
+pieces at a time through a two-slot ring (addresses must be scalars), so a
+step's tables follow the row's own length and a row of any length takes as
+many chunks as it needs: SMEM holds two chunks, not a row.  Per nonzero one
+dynamic sublane read of the d-vector and a masked multiply-add (dots) or a
+masked single-lane store (axpy), as in ``pallas_sparse_hbm``; the cost is
+paced by nonzeros.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from cocoa_tpu.data.sharding import STREAM_ALIGN as ALIGN
+from cocoa_tpu.data.sharding import STREAM_PIECE as PIECE
+from cocoa_tpu.ops import losses
+from cocoa_tpu.ops.local_sdca import coef_divisor, mode_factors
+from cocoa_tpu.ops.pallas_sdca import LANES, check_dtype
+
+assert PIECE == LANES             # a piece is one full lane row: the unit a
+                                 # DMA may start at
+CHUNK_PIECES = 8                 # pieces a DMA brings to SMEM (4 KB a table)
+CHUNK = CHUNK_PIECES * PIECE     # slots a chunk
+GROUP = ALIGN                    # slots per unrolled body of the slot loop:
+                                 # a group never holds slots of two rows
+ROW_BLOCK = 1024                 # rows whose scalars stream through SMEM at once
+VEC_VMEM_BUDGET = 88 << 20       # the resident d-vector
+VMEM_LIMIT = 100 << 20           # what the kernels ask Mosaic for
+
+
+def vec_rows(d: int) -> int:
+    """Sublane rows of a d-vector lane-blocked into whole (8, 128) tiles."""
+    return -(-d // (8 * LANES)) * 8
+
+
+def longrows_fits(d: int, itemsize: int = 4) -> bool:
+    """The resolver's gate: one lane-blocked d-vector fits the VMEM budget."""
+    return itemsize == 4 and vec_rows(d) * LANES * itemsize <= VEC_VMEM_BUDGET
+
+
+def _index(i):
+    """A dynamic sublane index in the default integer type (the tests run
+    with x64 on; ``pallas_sparse_hbm._index``)."""
+    return i.astype(jnp.asarray(0).dtype)
+
+
+def _kernel(*refs, mode: str, n_f: int, step_consts: dict):
+    """Rows ``ROW_BLOCK`` at a time (grid axis 1) of group ``g`` (grid axis
+    0).  Operands: ``start`` (a row's first slot, in ALIGN-slot groups of
+    the whole stream) / ``cnt`` (and, chain, ``prev``) int32 and
+    ``n_f`` float32 per-row tables as (1, ROW_BLOCK) SMEM blocks; the piece
+    arrays and the d-vector in HBM; outputs the per-row result block
+    (1, rows/128, 128) in VMEM and (chain, axpy) the d-vector after."""
+    n_i = 3 if mode == "chain" else 2
+    itabs, ftabs = refs[:n_i], refs[n_i:n_i + n_f]
+    rest = refs[n_i + n_f:]
+    cols_hbm, vals_hbm = rest[0], rest[1]
+    rest = rest[2:]
+    vec_in = None
+    if mode != "chain":
+        vec_in, rest = rest[0], rest[1:]
+    if mode == "axpy":
+        (vec_out, vec_sc, cbuf, vbuf, sem), out = rest, None
+    elif mode == "dots":
+        (out, vec_sc, cbuf, vbuf, sem), vec_out = rest, None
+    else:
+        out, vec_out, vec_sc, cbuf, vbuf, sem = rest
+    g, b = pl.program_id(0), pl.program_id(1)
+    first = (g == 0) & (b == 0)
+    last = (g == pl.num_programs(0) - 1) & (b == pl.num_programs(1) - 1)
+    dtype = vec_sc.dtype
+
+    @pl.when(first)
+    def _load():
+        if vec_in is None:
+            vec_sc[...] = jnp.zeros_like(vec_sc)
+        else:
+            pltpu.sync_copy(vec_in, vec_sc)
+
+    if out is not None:
+        @pl.when(b == 0)
+        def _init():
+            out[...] = jnp.zeros_like(out)
+
+    lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def copies(piece, c, slot):
+        src = pl.ds(piece + c * CHUNK_PIECES, CHUNK_PIECES)
+        return (pltpu.make_async_copy(cols_hbm.at[src], cbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(vals_hbm.at[src], vbuf.at[slot],
+                                      sem.at[1, slot]))
+
+    def over_row(start, cnt, body, init):
+        """``init = body(slot, j0, lo, hi, init)`` for each chunk of the row
+        whose first slot is ``start`` ALIGN-slot groups into the stream:
+        the chunk sits in ring slot ``slot``, its groups [lo, hi) hold the
+        row's slots, and its slot 0 is slot ``j0`` of the row (negative in
+        the first chunk of a row that starts inside a piece)."""
+        piece, lead = start >> 4, (start & 15) << 3
+        span = lead + cnt
+        n_chunks = jnp.where(cnt > 0, (span + (CHUNK - 1)) // CHUNK, 0)
+
+        @pl.when(n_chunks > 0)
+        def _prime():
+            for cp in copies(piece, 0, 0):
+                cp.start()
+
+        def chunk(c, acc):
+            slot = c & 1
+
+            @pl.when(c + 1 < n_chunks)
+            def _next():
+                for cp in copies(piece, c + 1, 1 - slot):
+                    cp.start()
+
+            for cp in copies(piece, c, slot):
+                cp.wait()
+            here = jnp.minimum(span - c * CHUNK, CHUNK)
+            return body(slot, c * CHUNK - lead,
+                        jnp.where(c == 0, lead >> 3, 0),
+                        (here + (GROUP - 1)) // GROUP, acc)
+
+        return lax.fori_loop(0, n_chunks, chunk, init)
+
+    def over_groups(slot, lo, hi, group, init):
+        """``init = group(piece, g, init)`` over the chunk's slot groups
+        [lo, hi), piece by piece: group ``g`` of ``piece`` holds the chunk's
+        slots piece x PIECE + g x GROUP + (0..GROUP-1).  Two loops, so that a
+        slot's SMEM address is a piece's base, g x GROUP and a constant."""
+        per = PIECE // GROUP
+
+        def piece(pc, acc):
+            return lax.fori_loop(
+                jnp.maximum(lo - pc * per, 0), jnp.minimum(hi - pc * per, per),
+                functools.partial(group, pc), acc)
+
+        return lax.fori_loop(lo // per, (hi + (per - 1)) // per, piece, init)
+
+    def dot_row(start, cnt):
+        """x . vec_sc: a masked multiply-add into a lane vector per nonzero,
+        one cross-lane sum a row.  The slots between a row's end and the
+        next ALIGN boundary hold column 0, value 0."""
+        def body(slot, j0, lo, hi, acc):
+            del j0
+
+            def group(pc, g, acc):
+                for u in range(GROUP):
+                    f = cbuf[slot, pc, 0, g * GROUP + u]
+                    vj = vbuf[slot, pc, 0, g * GROUP + u]
+                    row = vec_sc[pl.ds(f >> 7, 1)]              # (1, LANES)
+                    acc = acc + row * jnp.where(
+                        lane == (f & (LANES - 1)), vj, 0.0).astype(dtype)
+                return acc
+
+            return over_groups(slot, lo, hi, group, acc)
+
+        return jnp.sum(over_row(start, cnt, body,
+                                jnp.zeros((1, LANES), dtype)))
+
+    def axpy_row(start, cnt, coef):
+        """vec_sc += coef x: a masked store of the one lane each nonzero
+        owns.  A row has no column twice, so within a group no store feeds
+        a later slot's read and the group's reads all go first; a slot past
+        the row's length (column 0, value 0) stores nothing: it would put
+        back a lane read before this group's stores."""
+        def body(slot, j0, lo, hi, carry):
+            def group(pc, g, carry):
+                at = pc * PIECE + g * GROUP
+                pairs = [(cbuf[slot, pc, 0, g * GROUP + u],
+                          vbuf[slot, pc, 0, g * GROUP + u])
+                         for u in range(GROUP)]
+                rows = [vec_sc[pl.ds(f >> 7, 1)] for f, _ in pairs]
+                for u, ((f, vj), row) in enumerate(zip(pairs, rows)):
+                    pltpu.store(
+                        vec_sc.at[pl.ds(_index(f >> 7), 1)],
+                        row + (coef * vj).astype(dtype),
+                        mask=(lane == (f & (LANES - 1)))
+                        & (j0 + at + u < cnt))
+                return carry
+
+            return over_groups(slot, lo, hi, group, carry)
+
+        over_row(start, cnt, body, jnp.int32(0))
+
+    def put(ref, r, value):
+        pltpu.store(ref.at[0, pl.ds(_index(r >> 7), 1)],
+                    jnp.broadcast_to(value, (1, LANES)).astype(dtype),
+                    mask=lane == (r & (LANES - 1)))
+
+    def one_row(i, carry):
+        r = b * ROW_BLOCK + i                  # the row within the group
+        start, cnt = itabs[0][0, i], itabs[1][0, i]
+        if mode == "dots":
+            put(out, r, dot_row(start, cnt))
+        elif mode == "axpy":
+            axpy_row(start, cnt, ftabs[0][0, i])
+        else:
+            prev = itabs[2][0, i]
+            m0, y, qii, a0 = (t[0, i] for t in ftabs)
+            # a row this round already stepped on: alpha is that step's
+            pj = jnp.maximum(prev, 0)
+            prow = out[0, pl.ds(pj >> 7, 1)]
+            a_prev = jnp.sum(jnp.where(lane == (pj & (LANES - 1)), prow, 0.0))
+            a = jnp.where(prev >= 0, a_prev, a0)
+            margin = m0 + step_consts["sig_eff"] * dot_row(start, cnt)
+            new_a = losses.alpha_step(
+                step_consts["loss"], a, y * margin, qii,
+                step_consts["lam_n"], smoothing=step_consts["smoothing"])
+            axpy_row(start, cnt, y * (new_a - a) / step_consts["coef_div"])
+            put(out, r, new_a)
+        return carry
+
+    lax.fori_loop(0, ROW_BLOCK, one_row, jnp.int32(0))
+
+    if vec_out is not None:
+        @pl.when(last)
+        def _flush():
+            pltpu.sync_copy(vec_sc, vec_out)
+
+
+def _call(mode: str, groups: int, rows: int, n_f: int, d_rows: int, dtype,
+          interpret: bool, **step_consts):
+    """The ``pallas_call`` of one pass: ``groups`` x ``rows`` rows
+    (``rows`` a multiple of ROW_BLOCK)."""
+    n_i = 3 if mode == "chain" else 2
+    n_blocks = rows // ROW_BLOCK
+    tab = pl.BlockSpec((1, ROW_BLOCK), lambda g, b: (0, g * n_blocks + b),
+                       memory_space=pltpu.SMEM)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    per_row = pl.BlockSpec((1, rows // LANES, LANES), lambda g, b: (g, 0, 0))
+    vec = jax.ShapeDtypeStruct((d_rows, LANES), dtype)
+    out_specs, out_shape = [], []
+    if mode != "axpy":
+        out_specs.append(per_row)
+        out_shape.append(jax.ShapeDtypeStruct(
+            (groups, rows // LANES, LANES), dtype))
+    if mode != "dots":
+        out_specs.append(any_)
+        out_shape.append(vec)
+    return pl.pallas_call(
+        functools.partial(_kernel, mode=mode, n_f=n_f,
+                          step_consts=step_consts),
+        grid=(groups, rows // ROW_BLOCK),
+        in_specs=[tab] * (n_i + n_f) + [any_, any_]
+        + ([any_] if mode != "chain" else []),
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((d_rows, LANES), dtype),
+            pltpu.SMEM((2, CHUNK_PIECES, 1, PIECE), jnp.int32),
+            pltpu.SMEM((2, CHUNK_PIECES, 1, PIECE), dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=f"pallas_longrows_{mode}",
+    )
+
+
+def _as_pieces(a):
+    """(K, n_pieces, PIECE) as the kernels' (K x n_pieces, 1, PIECE): the
+    same bytes (a piece is one full lane row), every piece its own tile, so
+    a DMA may start at any piece."""
+    return a.reshape(-1, 1, PIECE)
+
+
+def _lane_blocked(vec, d_rows: int):
+    return jnp.pad(vec, (0, d_rows * LANES - vec.shape[0])).reshape(
+        d_rows, LANES)
+
+
+def _row_tables(tables, rows: int):
+    """(G, R) per-row tables padded to ``rows`` columns, as the one line
+    (1, G x rows) whose ROW_BLOCK-wide blocks stream through SMEM."""
+    return [jnp.pad(t, ((0, 0), (0, rows - t.shape[1]))).reshape(1, -1)
+            for t in tables]
+
+
+def _global_start(row_ptr, n_pieces: int):
+    """(K, R) row starts within a shard's stream, in ALIGN-slot groups ->
+    within the (K x n_pieces) stream the kernels see."""
+    k = row_ptr.shape[0]
+    if k * n_pieces * (PIECE // ALIGN) >= 1 << 31:
+        raise ValueError(f"{k} x {n_pieces} pieces: a row's start no longer "
+                         f"fits an int32")
+    return row_ptr + (jnp.arange(k, dtype=jnp.int32)
+                      * (n_pieces * (PIECE // ALIGN)))[:, None]
+
+
+def _pad_rows(r: int) -> int:
+    return -(-r // ROW_BLOCK) * ROW_BLOCK
+
+
+def rows_dot(vec, sp_indices, sp_values, start, cnt, interpret: bool):
+    """x_r . vec for the (G, R) rows that begin at pieces ``start`` (within
+    the whole (K x n_pieces) stream) and hold ``cnt`` nonzeros: (G, R)."""
+    g, r = start.shape
+    rows, d_rows = _pad_rows(r), vec_rows(vec.shape[0])
+    call = _call("dots", g, rows, 0, d_rows, vec.dtype, interpret)
+    (out,) = call(*_row_tables([start, cnt], rows), _as_pieces(sp_indices),
+                  _as_pieces(sp_values), _lane_blocked(vec, d_rows))
+    return out.reshape(g, rows)[:, :r]
+
+
+def rows_axpy(vec, sp_indices, sp_values, start, cnt, coefs,
+              interpret: bool):
+    """vec + sum_r coefs_r x_r over the (G, R) rows: (d,)."""
+    g, r = start.shape
+    rows, d_rows = _pad_rows(r), vec_rows(vec.shape[0])
+    call = _call("axpy", g, rows, 1, d_rows, vec.dtype, interpret)
+    (out,) = call(*_row_tables([start, cnt, coefs.astype(vec.dtype)], rows),
+                  _as_pieces(sp_indices), _as_pieces(sp_values),
+                  _lane_blocked(vec, d_rows))
+    return out.reshape(-1)[:vec.shape[0]]
+
+
+def shard_margins(w, shard: dict, interpret: bool):
+    """x_i . w for every row of the stacked (K, ..) piece shards."""
+    idx = shard["sp_indices"]
+    return rows_dot(w, idx, shard["sp_values"],
+                    _global_start(shard["sp_row_ptr"], idx.shape[1]),
+                    shard["sp_row_len"], interpret)
+
+
+def shards_axpy(coefs, shards: dict, vec, interpret: bool):
+    idx = shards["sp_indices"]
+    return rows_axpy(vec, idx, shards["sp_values"],
+                     _global_start(shards["sp_row_ptr"], idx.shape[1]),
+                     shards["sp_row_len"], coefs, interpret)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("lam", "n", "mode", "sigma", "interpret", "loss",
+                     "smoothing"),
+)
+def pallas_longrows_round(
+    w: jax.Array,            # (d,) the round's primal vector
+    alpha: jax.Array,        # (K, n_shard)
+    sp_indices: jax.Array,   # (K, n_pieces, PIECE) int32
+    sp_values: jax.Array,    # (K, n_pieces, PIECE)
+    row_ptr: jax.Array,      # (K, n_shard) int32: a row's first piece
+    row_len: jax.Array,      # (K, n_shard) int32: its nonzeros
+    labels: jax.Array,       # (K, n_shard)
+    sq_norms: jax.Array,     # (K, n_shard)
+    idxs: jax.Array,         # (K, H) int32 sampled rows
+    lam: float,
+    n: int,
+    mode: str = "plus",
+    sigma: float = 1.0,
+    interpret: bool = False,
+    loss: str = "hinge",
+    smoothing: float = 1.0,
+):
+    """One SDCA round for K shards of piece rows on this chip, one shard
+    after another.  Returns ``(dw_sum (d,), alpha_inner (K, n_shard))`` as
+    ``pallas_sparse_hbm_round`` does."""
+    from cocoa_tpu.ops.pallas_sparse_hbm import _link_repeats
+    from cocoa_tpu.telemetry.tracing import (SCOPE_LOCAL_SOLVE,
+                                             SCOPE_SPARSE_GATHER)
+
+    k, n_pieces, _ = sp_indices.shape
+    h, d, dtype = idxs.shape[1], w.shape[0], w.dtype
+    check_dtype(dtype)
+    sig_eff, qii_factor = mode_factors(mode, sigma)
+    rows, d_rows = _pad_rows(h), vec_rows(d)
+    idxs = idxs.astype(jnp.int32)
+    at = lambda a: jnp.take_along_axis(a, idxs, 1)  # noqa: E731
+    with jax.named_scope(SCOPE_SPARSE_GATHER):
+        start = _global_start(at(row_ptr), n_pieces)
+        cnt = at(row_len)
+        live = jnp.ones((h,), bool)
+        prev, last = jax.vmap(lambda i: _link_repeats(i, live))(idxs)
+        ftabs = [at(labels), at(sq_norms) * qii_factor, at(alpha)]
+    with jax.named_scope(SCOPE_LOCAL_SOLVE):
+        # x_i . w of the round's rows: w does not move within a round
+        m0 = rows_dot(w, sp_indices, sp_values, start, cnt, interpret)
+        chain = _call(
+            "chain", 1, rows, 4, d_rows, dtype, interpret,
+            lam_n=float(lam * n), coef_div=float(coef_divisor(mode, lam * n)),
+            sig_eff=float(sig_eff),
+            loss=losses.validate(loss, smoothing),
+            smoothing=float(smoothing))
+        cols, vals = _as_pieces(sp_indices), _as_pieces(sp_values)
+
+        def one_shard(dw_sum, xs):
+            tabs = _row_tables([x[None] for x in xs], rows)
+            a_new, dwk = chain(*tabs, cols, vals)
+            return dw_sum + dwk, a_new.reshape(-1)[:h]
+
+        dw_sum, a_new = lax.scan(
+            one_shard, jnp.zeros((d_rows, LANES), dtype),
+            (start, cnt, prev.astype(jnp.int32), m0, *ftabs))
+    with jax.named_scope(SCOPE_SPARSE_GATHER):
+        n_shard = alpha.shape[1]
+        alpha = jax.vmap(lambda a, i, keep, new: a.at[
+            jnp.where(keep, i, n_shard)].set(new, mode="drop"))(
+                alpha, idxs, last, a_new)
+    return dw_sum.reshape(-1)[:d], alpha

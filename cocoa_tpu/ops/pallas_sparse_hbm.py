@@ -182,6 +182,26 @@ def hbm_plan(d: int, max_nnz: int, h: int, itemsize: int = 4):
     return HbmPlan(t=t, s=s, m=m, w_r=w_r, chunk=chunk, direct=m >= d_pad)
 
 
+def hbm_refusal(d: int, max_nnz: int, h: int, itemsize: int = 4) -> str:
+    """Why :func:`hbm_plan` has no plan for these sizes, with the numbers
+    (empty where it has one): what the resolver reports beside ``fori``."""
+    if hbm_plan(d, max_nnz, h, itemsize) is not None:
+        return ""
+    w_r = _w_round(max_nnz)
+    if (h + 2 * LANES) * w_r >= (1 << 31):
+        return (f"H = {h} steps x W = {w_r} slots: a slot's position no "
+                f"longer fits an int32")
+    smem = hbm_smem_estimate(8, w_r)
+    if smem > HBM_SMEM_BUDGET:
+        return (f"a row outgrows SMEM: the two step tables of the smallest "
+                f"block (8 steps) at W = {w_r} take {smem} B of the "
+                f"{HBM_SMEM_BUDGET} B budget (1 MiB of SMEM); rows this long "
+                f"are kept as a stream where the loader sees their lengths "
+                f"(data/sharding.stream_suits)")
+    return (f"no segment of {CHUNK} steps at W = {w_r} fits the "
+            f"{HBM_VMEM_BUDGET} B of VMEM asked for [w | dw]")
+
+
 def sparse_hbm_fits(d: int, max_nnz: int, h: int, itemsize: int) -> bool:
     """The resolver's gate: a plan exists (its segments fit the budgets)."""
     return hbm_plan(d, max_nnz, h, itemsize) is not None

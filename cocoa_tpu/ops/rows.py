@@ -41,6 +41,8 @@ class Row(NamedTuple):
 def get_row(shard: dict, i) -> Row:
     if "X" in shard:
         return Row(dense=jax.lax.dynamic_index_in_dim(shard["X"], i, 0, keepdims=False))
+    if "sp_row_ptr" in shard:
+        return _stream_row(shard, i)
     hot = hot_cols = None
     if "X_hot" in shard:
         hot = jax.lax.dynamic_index_in_dim(shard["X_hot"], i, 0,
@@ -52,6 +54,69 @@ def get_row(shard: dict, i) -> Row:
         hot=hot,
         hot_cols=hot_cols,
     )
+
+
+def _stream_row(shard: dict, i) -> Row:
+    """Row ``i`` of a stream shard (data/sharding.py, STREAM_ALIGN) in the
+    padded-CSR form of the rectangle's rows, (W,) columns and values with W
+    the set's longest row (``sp_row_iota``'s static width): a W-slot window
+    of the stream from the row's first slot, what lies past its length set
+    to column 0, value 0.  The window of a row near the stream's end starts
+    early enough to fit and is rotated back.  The portable per-row path (the
+    ``fori`` solve, the oracles); the kernels of ops/pallas_longrows.py read
+    the stream as it is stored."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from cocoa_tpu.data.sharding import STREAM_ALIGN
+
+    iota = shard["sp_row_iota"]
+    width = iota.shape[0]
+    cols, vals = (shard[f].reshape(-1) for f in ("sp_indices", "sp_values"))
+    first = shard["sp_row_ptr"][i] * STREAM_ALIGN
+    start = jnp.minimum(first, cols.shape[0] - width)
+    live = iota < shard["sp_row_len"][i]
+
+    def window(a):
+        win = jnp.roll(lax.dynamic_slice_in_dim(a, start, width),
+                       start - first)
+        return jnp.where(live, win, jnp.zeros_like(win))
+
+    return Row(idx=window(cols), val=window(vals))
+
+
+def _stream_interpret() -> bool:
+    """The stream kernels run compiled on a TPU, interpreted anywhere else."""
+    return jax.default_backend() != "tpu"
+
+
+def _stream_margins_stacked(w, cols, vals, row_ptr, row_len):
+    """x_i . w for the rows of stacked (K, ..) stream shards: one kernel
+    over all of them, w loaded once."""
+    from cocoa_tpu.ops import pallas_longrows
+
+    shard = dict(sp_indices=cols, sp_values=vals, sp_row_ptr=row_ptr,
+                 sp_row_len=row_len)
+    return pallas_longrows.shard_margins(w, shard, _stream_interpret())
+
+
+@jax.custom_batching.custom_vmap
+def _stream_margins(w, cols, vals, row_ptr, row_len):
+    """x_i . w for the rows of ONE stream shard; under ``vmap`` over shards
+    (base.fanout) it stays the one kernel of the stacked form."""
+    return _stream_margins_stacked(
+        w, *(a[None] for a in (cols, vals, row_ptr, row_len)))[0]
+
+
+@_stream_margins.def_vmap
+def _stream_margins_vmapped(axis_size, in_batched, w, *rows):
+    import jax.numpy as jnp
+
+    if in_batched[0]:
+        raise NotImplementedError("stream margins against a batch of w")
+    stacked = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+               for a, b in zip(rows, in_batched[1:])]
+    return _stream_margins_stacked(w, *stacked), True
 
 
 def row_dot(row: Row, vec: jax.Array) -> jax.Array:
@@ -270,6 +335,9 @@ def shard_margins(w: jax.Array, shard: dict) -> jax.Array:
     if "X" in shard:
         return shard["X"] @ w
     idx, val = shard["sp_indices"], shard["sp_values"]
+    if "sp_row_ptr" in shard:
+        return _stream_margins(w, idx, val, shard["sp_row_ptr"],
+                               shard["sp_row_len"])
     if row_block(*idx.shape) < idx.shape[0]:
         m = _margins_by_row_blocks(w, idx, val, shard.get("sp_row_len"))
     else:
@@ -392,6 +460,11 @@ def shards_axpy(coefs: jax.Array, shards: dict, vec: jax.Array) -> jax.Array:
     if "X" in shards:
         return vec + jnp.einsum("kn,knd->d", coefs, shards["X"])
     idx, val = shards["sp_indices"], shards["sp_values"]
+    if "sp_row_ptr" in shards:
+        from cocoa_tpu.ops import pallas_longrows
+
+        return pallas_longrows.shards_axpy(coefs, shards, vec,
+                                           _stream_interpret())
     n, block = idx.shape[1], row_block(idx.shape[1], idx.shape[2])
     if block >= n:
         vec = vec.at[idx].add(coefs[..., None] * val)

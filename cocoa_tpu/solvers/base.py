@@ -1473,9 +1473,18 @@ def align_alpha(alpha_init, ds: ShardedDataset, dtype):
     return a
 
 
-def check_shards(ds: ShardedDataset) -> None:
+def check_shards(ds: ShardedDataset, rectangle: bool = False) -> None:
     """Reject empty shards up front: the reference crashes inside the task
-    (``nextInt(0)``) when numSplits > rows; we fail with a clear message."""
+    (``nextInt(0)``) when numSplits > rows; we fail with a clear message.
+    ``rectangle``: the caller reads sparse rows as the (K, n_shard, W)
+    rectangle (the primal solvers' subgradient passes), so rows kept as a
+    stream (data/sharding.stream_suits) are refused, not misread."""
+    if rectangle and ds.sp_row_ptr is not None:
+        raise ValueError(
+            "this solver reads padded-CSR rectangles; the dataset's rows "
+            "(thousands of nonzeros, the longest many times the mean) are "
+            "kept as a stream, which the SDCA family solves "
+            "(run_cocoa / run_minibatch_cd)")
     if np.any(ds.counts <= 0):
         raise ValueError(
             f"every shard needs at least one example; shard sizes are "

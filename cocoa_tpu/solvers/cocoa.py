@@ -42,6 +42,17 @@ def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
     alpha_inner (K, n_shard))."""
     common = dict(mode=mode, sigma=sigma, interpret=interpret,
                   loss=params.loss, smoothing=params.smoothing)
+    if "sp_row_ptr" in shards:
+        from cocoa_tpu.ops.pallas_longrows import pallas_longrows_round
+
+        # rows kept as a stream: the kernels paced by nonzeros; dw arrives
+        # summed, as from the HBM-state kernel below
+        dw_sum, alpha_inner = pallas_longrows_round(
+            w, alpha, shards["sp_indices"], shards["sp_values"],
+            shards["sp_row_ptr"], shards["sp_row_len"], shards["labels"],
+            shards["sq_norms"], idxs_kh, params.lam, params.n, **common,
+        )
+        return dw_sum[None], alpha_inner
     if "sp_indices" in shards and state == "hbm":
         from cocoa_tpu.ops.pallas_sparse_hbm import pallas_sparse_hbm_round
 
@@ -106,8 +117,8 @@ def auto_block_size(ds: ShardedDataset, m_local: int, dtype) -> int:
     from cocoa_tpu.ops.pallas_sparse import hybrid_fits, sparse_chain_fits
 
     itemsize = jnp.dtype(dtype).itemsize
-    if itemsize != 4:
-        return 0
+    if itemsize != 4 or ds.sp_row_ptr is not None:
+        return 0                # (rows kept as a stream: sequential only)
     for b in BLOCK_SIZE_PREFERENCE:
         if not chain_fits(m_local, b, itemsize):
             continue
@@ -173,7 +184,17 @@ class SolverPath:
     1.0 where one block holds a shard or the rows' lengths are not known;
     less where the pass runs in row blocks and stops at each block's longest
     row (ops/rows.pass_slots, counted from the lengths: about a half for
-    rows in length order, data/sharding.order_rows_by_length)."""
+    rows in length order, data/sharding.order_rows_by_length).
+    ``storage`` (sparse sets): ``rectangle``: rows padded to the longest,
+    (K, n_shard, W); ``stream``: rows of thousands of nonzeros kept end to
+    end (data/sharding.stream_suits), solved by the kernels of
+    ops/pallas_longrows.py, which hold ONE d-vector in VMEM at a time (the
+    shard's dw during its chain, w during the passes over rows: ``state``
+    reads ``vmem``).  ``slot_fill``: nonzeros / stored slots, counted on
+    the host from the row lengths (None where they are not known);
+    ``longest_row``: the longest row's nonzeros (the rectangle's width W
+    where the lengths are not known).  ``refused``: why a sparse set that
+    no Pallas kernel takes runs ``fori``, with the numbers."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -186,6 +207,10 @@ class SolverPath:
     state: str = "hbm"
     step_solve: str = "scalar"
     pass_slot_share: float = 1.0
+    storage: str = "rectangle"
+    slot_fill: Optional[float] = None
+    longest_row: int = 0
+    refused: str = ""
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -212,13 +237,19 @@ class SolverPath:
             what = (f"sequential {self.kernel}"
                     + (f" ({how})" if self.kernel == "pallas" else "")
                     + (", state in HBM" if self.pallas
-                       and self.state == "hbm" else ""))
+                       and self.state == "hbm" else "")
+                    + (f" [{self.refused}]" if self.refused else ""))
         rows = ", rows stored row-major" if self.rows == "row_major" else ""
         solve = (", the shards' steps solved in lanes"
                  if self.step_solve == "lanes" else "")
         if self.pass_slot_share < 1.0:
             solve += (f", all-rows passes touch {self.pass_slot_share:.3f} "
                       f"of the padded slots")
+        if self.storage == "stream":
+            fill = ("" if self.slot_fill is None
+                    else f" (slot fill {self.slot_fill:.3f})")
+            solve += (f", rows kept as a stream{fill}, the longest "
+                      f"{self.longest_row} nonzeros")
         return (f"{what}, {self.layout} layout{rows}{solve}, on "
                 f"{self.platform} x "
                 f"{self.devices} ({self.shards_per_device} shard(s) per "
@@ -229,6 +260,8 @@ def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
     """:attr:`SolverPath.pass_slot_share` of a sparse dataset, counted once
     from the row lengths it carries (``_row_len_cache``: attached by the
     ordering or by an earlier run) and kept on it beside them."""
+    if ds.sp_row_ptr is not None:
+        return 1.0
     width = int(ds.sp_indices.shape[-1])
     row_len = getattr(ds, "_row_len_cache", None)
     if (not isinstance(row_len, jax.Array)      # none, or a shape alone
@@ -241,6 +274,26 @@ def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
                   / (ds.k * ds.n_shard * width))
         ds._pass_slot_share_cache = cached
     return cached[1]
+
+
+def _slot_stats(ds: ShardedDataset) -> tuple:
+    """``(slot_fill, longest_row)`` of a sparse dataset (:class:`SolverPath`),
+    counted once on the host from the row lengths it carries and kept on
+    it."""
+    stream = ds.sp_row_ptr is not None
+    row_len = ds.sp_row_len if stream else getattr(ds, "_row_len_cache",
+                                                   None)
+    widest = int(ds.sp_row_iota.shape[-1] if stream
+                 else ds.sp_indices.shape[-1])
+    if not isinstance(row_len, jax.Array):      # none, or a shape alone
+        return None, widest
+    cached = getattr(ds, "_slot_stats_cache", None)
+    if cached is None:
+        lens = np.asarray(row_len, np.int64)
+        cached = (float(lens.sum() / max(1, ds.sp_indices.size)),
+                  int(lens.max(initial=0)))
+        ds._slot_stats_cache = cached
+    return cached
 
 
 def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
@@ -268,9 +321,26 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                 else jax.devices()[0].platform)
     sparse = ds.layout == "sparse"
     layout = "hybrid" if sparse and ds.n_hot else ds.layout
+    stream = sparse and ds.sp_row_ptr is not None
     width = int(ds.sp_indices.shape[-1]) if sparse else 0
     vmem_fits = hbm_state = False
-    if sparse:
+    refused = ""
+    if stream:
+        # rows kept as a stream: one d-vector at a time in VMEM
+        from cocoa_tpu.ops.pallas_longrows import (VEC_VMEM_BUDGET,
+                                                   longrows_fits)
+
+        if block_size > 0:
+            raise ValueError("the block-coordinate kernels read padded-CSR "
+                             "rectangles; rows kept as a stream "
+                             "(data/sharding.stream_suits) run the "
+                             "sequential solve: block_size=0")
+        vmem_fits = longrows_fits(ds.num_features, itemsize)
+        if not vmem_fits:
+            refused = (f"rows kept as a stream need one float32 d-vector in "
+                       f"VMEM: d = {ds.num_features} x {itemsize} B against "
+                       f"{VEC_VMEM_BUDGET} B")
+    elif sparse:
         # which sequential sparse kernel could hold the set: the
         # VMEM-resident one (the SMEM feature-index table and the
         # lane-blocked d-vectors must fit — pallas_sparse docstring; hybrid
@@ -285,6 +355,11 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             itemsize, n_hot=ds.n_hot)
         hbm_state = (not vmem_fits and not ds.n_hot and sparse_hbm_fits(
             ds.num_features, width, local_iters, itemsize))
+        if not vmem_fits and not hbm_state and not ds.n_hot:
+            from cocoa_tpu.ops.pallas_sparse_hbm import hbm_refusal
+
+            refused = hbm_refusal(ds.num_features, width, local_iters,
+                                  itemsize)
     if block_size > 0:
         # the block-coordinate kernel is an alternative inner loop — it and
         # the Pallas sequential kernels are mutually exclusive by design
@@ -363,7 +438,10 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         devices=len(ds.labels.sharding.device_set),
         shards_per_device=m_local,
         pass_slot_share=_pass_slot_share(ds, m_local) if sparse else 1.0,
+        storage="stream" if stream else "rectangle",
     )
+    if sparse:
+        placement["slot_fill"], placement["longest_row"] = _slot_stats(ds)
     if block_size <= 0:
         from cocoa_tpu.ops import losses
         from cocoa_tpu.ops.pallas_sdca import stores_row_major
@@ -377,6 +455,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             state="vmem" if pallas and not hbm_state else "hbm",
             step_solve=("lanes" if pallas and not sparse
                         and losses.step_is_iterative(loss) else "scalar"),
+            refused="" if pallas else refused,
             **placement)
     if block_chain == "xla":
         return SolverPath(inner="block", kernel="xla", chain="xla",

@@ -106,7 +106,7 @@ def run_dist_gd(
     device_loop: bool = False,
 ):
     """Train; returns (w, Trajectory)."""
-    base.check_shards(ds)
+    base.check_shards(ds, rectangle=True)
     k = ds.k
     if not quiet:
         print(f"\nRunning DistGD on {params.n} data examples, "

@@ -143,7 +143,7 @@ def run_sgd(
     whole run — rounds, evals — as one on-device ``lax.while_loop`` (see
     run_sdca_family for semantics; SGD has no duality gap so there is no
     gap-target early stop)."""
-    base.check_shards(ds)
+    base.check_shards(ds, rectangle=True)
     k = ds.k
     if not quiet:
         print(f"\nRunning SGD (with local updates = {local}) on {params.n} "

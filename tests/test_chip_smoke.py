@@ -141,13 +141,18 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     labels = jnp.zeros((2, 128), jnp.float32)
     ds = types.SimpleNamespace(
         k=2, labels=labels, layout=layout, n_hot=0, n_shard=128,
-        num_features=d, sp_indices=jnp.zeros((2, 128, 4), jnp.int32))
+        num_features=d, sp_indices=jnp.zeros((2, 128, 4), jnp.int32),
+        sp_row_ptr=None)
     path = resolve_solver_path(ds, 8, math="fast", pallas=pallas)
     assert path.rows == rows
     assert list(path.as_dict()) == [
         "inner", "kernel", "chain", "interpret", "layout", "platform",
         "devices", "shards_per_device", "rows", "state", "step_solve",
-        "pass_slot_share"]
+        "pass_slot_share", "storage", "slot_fill", "longest_row", "refused"]
+    # rows padded to the longest, their lengths not known here
+    assert (path.storage, path.slot_fill, path.refused) == (
+        "rectangle", None, "")
+    assert path.longest_row == (4 if layout == "sparse" else 0)
     # one block holds these shards: an all-rows pass touches every slot
     assert path.pass_slot_share == 1.0
     assert "all-rows passes touch" not in path.describe()

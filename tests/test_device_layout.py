@@ -354,3 +354,56 @@ def test_ordering_a_kddb_shard_happens_in_its_donated_rows(one_chip):
     layout = re.compile(rf"f32\[{k},{n_shard},{width}\]\{{1,2,0")
     assert len(layout.findall(hlo.split("\n", 1)[0])) == 2   # in and out
     assert not re.search(rf"\[{k},{n_shard},{width}\][^ ]* copy\(", hlo)
+
+
+# webspam as the benchmark holds it (chipbench/configs/webspam.json: one of
+# two chips' share): 8 shards of 21,875 rows, five 2^24-slot windows each
+WEBSPAM = dict(k=8, n=175000, d=16609143, h=2187, pieces=5 * (1 << 17))
+
+
+@pytest.mark.parametrize("what", ["margins", "axpy", "round"])
+def test_stream_kernels_compile_at_webspam_size(one_chip, what):
+    """The kernels of ops/pallas_longrows.py at webspam's shapes: one 66 MB
+    d-vector in VMEM beside the two-chunk SMEM ring compiles under the 100
+    MB the kernels ask Mosaic for; the stream is read where it is stored
+    (no whole-array copy: the (K, pieces, 128) arrays seen piece by piece
+    are the same bytes), and a pass's temporaries are a d-vector or two,
+    not the rows again."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.data.sharding import pad_rows, split_sizes
+    from cocoa_tpu.ops import pallas_longrows as plr
+
+    k, d, h, pieces = (WEBSPAM[x] for x in ("k", "d", "h", "pieces"))
+    n_shard = pad_rows(int(split_sizes(WEBSPAM["n"], k).max()))
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows, irows = sds((k, n_shard)), sds((k, n_shard), jnp.int32)
+    stream = dict(sp_indices=sds((k, pieces, 128), jnp.int32),
+                  sp_values=sds((k, pieces, 128)), sp_row_ptr=irows,
+                  sp_row_len=irows)
+    assert plr.longrows_fits(d) and not plr.longrows_fits(2 * d)
+    with jax.enable_x64(False):
+        if what == "margins":
+            fn = lambda w, sh: plr.shard_margins(w, sh, False)  # noqa: E731
+            args = (sds((d,)), stream)
+        elif what == "axpy":
+            fn = lambda c, sh, w: plr.shards_axpy(c, sh, w, False)  # noqa: E731
+            args = (rows, stream, sds((d,)))
+        else:
+            fn = lambda w, a, sh, y, q, i: plr.pallas_longrows_round(  # noqa: E731
+                w, a, sh["sp_indices"], sh["sp_values"], sh["sp_row_ptr"],
+                sh["sp_row_len"], y, q, i, 1e-4, WEBSPAM["n"], mode="plus",
+                sigma=float(k))
+            args = (sds((d,)), rows, stream, rows, rows,
+                    sds((k, h), jnp.int32))
+        compiled = jax.jit(fn).lower(*args).compile()
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 4 * d * 4, stats.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert f"pallas_longrows_{'dots' if what != 'axpy' else 'axpy'}" in hlo
+    assert not re.search(rf"\[{k},{pieces},128\][^ ]* copy\(", hlo)
+    assert not re.search(rf"\[{k * pieces},1,128\][^ ]* copy\(", hlo)
