@@ -1530,11 +1530,14 @@ def main(argv=None) -> int:
         cfg_manifest["layout_split"] = layout_split
         run_meta["config_hash"] = telemetry.events.config_hash(cfg_manifest)
 
-    def emit_run_start(ds_solved, local_iters):
+    def emit_run_start(ds_solved, local_iters, mode):
         """``run_start`` waits for the last thing it records: the local
         solver the SDCA-family drivers will resolve to for this dataset
         and flag set (solvers/cocoa.resolve_solver_path — the same call
-        run_sdca_family makes), so the manifest says which kernel ran."""
+        run_sdca_family makes), so the manifest says which kernel ran.
+        ``mode``: the first algorithm's, for the one field that follows
+        the algorithm (``SolverPath.margin``; each run's own is on its
+        ``Trajectory.meta``)."""
         if not bus.active():
             return
         from cocoa_tpu.solvers.cocoa import resolve_solver_path
@@ -1550,7 +1553,8 @@ def main(argv=None) -> int:
             manifest["ingest"] = ingest_reports[0].as_fields()
         manifest["solver_path"] = resolve_solver_path(
             ds_solved, local_iters, mesh, math=cfg.math,
-            block_size=block_size, loss=cfg.loss).as_dict()
+            block_size=block_size, loss=cfg.loss,
+        ).for_mode(mode).as_dict()
         bus.emit("run_start", manifest=manifest)
         for rep in ingest_reports:
             bus.emit("ingest", **rep.as_fields())
@@ -1679,7 +1683,7 @@ def main(argv=None) -> int:
         lasso_params = dataclasses.replace(
             cfg.to_params(d, k), loss="lasso", smoothing=l2,
         )
-        emit_run_start(ds_c, lasso_params.local_iters)
+        emit_run_start(ds_c, lasso_params.local_iters, "prox")
         resume_kw = {}
         if resume:
             from cocoa_tpu import checkpoint as ckpt_lib
@@ -1711,7 +1715,7 @@ def main(argv=None) -> int:
             traj.dump_jsonl(f"{extras['trajOut']}.ProxCoCoA+.jsonl")
         return 0
 
-    emit_run_start(ds, params.local_iters)
+    emit_run_start(ds, params.local_iters, "plus")
 
     def restore(algorithm):
         """(w_init, alpha_init, start_round[, sched_init]) from the latest

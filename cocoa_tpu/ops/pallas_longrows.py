@@ -19,11 +19,20 @@ resident in VMEM, lane-blocked (d/128, 128), and the rows streamed from HBM
 as they are stored:
 
 - ``dots``: x_i . v for a list of rows (the certificate's margins over all
-  rows; x_i . w of a round's sampled rows, w being fixed for the round);
-- ``chain``: the sequential SDCA steps of one shard against the VMEM-held
-  dw alone — margin = x_i . w (from ``dots``) + sigma' x_i . dw, the alpha
-  step of ``losses.alpha_step``, dw += coef x_i — in the order of the
-  ``fori`` reference (CoCoA.scala:148-188);
+  rows), v = w resident;
+- ``chain``: the sequential SDCA steps of one shard, in the order of the
+  ``fori`` reference (CoCoA.scala:148-188).  A step reads w and dw_k only
+  as its margin x_i . (w + sigma' dw_k), so the resident vector is
+  **v = w + sigma' dw_k**: loaded as w, the margin one dot against it, the
+  alpha step of ``losses.alpha_step``, the update v += sigma' coef x_i; the
+  round gives back dw_k = (v - w) / sigma' by one dense pass in XLA.  A
+  sampled nonzero is walked twice (dot, update), not three times, and an
+  update rounds at |v|, not at |dw| (within 4 eps |w|_inf sqrt(steps on the
+  column) of float64; exactly 0 on a column no step touched).  Mini-batch
+  CD (``frozen``, sigma' = 0: its margin never reads dw_k, so no v gives
+  dw_k back) keeps the ``split`` form: x_i . w of the round's rows from a
+  ``dots`` pass, w being fixed for the round, then the chain against dw_k
+  alone (:func:`margin_form`, reported as ``SolverPath.margin``);
 - ``axpy``: v += c_i x_i over a list of rows (the ``--accel`` jump).
 
 A row's slots reach the scalar core by DMA, HBM to SMEM, ``CHUNK_PIECES``
@@ -72,26 +81,36 @@ def longrows_fits(d: int, itemsize: int = 4) -> bool:
     return itemsize == 4 and vec_rows(d) * LANES * itemsize <= VEC_VMEM_BUDGET
 
 
+def margin_form(mode: str) -> str:
+    """How the chain of a round takes a step's margin x . (w + sig_eff dw_k):
+    ``combined``: as one dot against the resident v = w + sig_eff dw_k;
+    ``split``: x . w from a ``dots`` pass before the chain, beside a resident
+    dw_k — ``frozen``'s form, whose margin never reads dw_k (sig_eff 0), so
+    no v gives dw_k back."""
+    return "split" if mode == "frozen" else "combined"
+
+
 def _index(i):
     """A dynamic sublane index in the default integer type (the tests run
     with x64 on; ``pallas_sparse_hbm._index``)."""
     return i.astype(jnp.asarray(0).dtype)
 
 
-def _kernel(*refs, mode: str, n_f: int, step_consts: dict):
+def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
     """Rows ``ROW_BLOCK`` at a time (grid axis 1) of group ``g`` (grid axis
     0).  Operands: ``start`` (a row's first slot, in ALIGN-slot groups of
     the whole stream) / ``cnt`` (and, chain, ``prev``) int32 and
     ``n_f`` float32 per-row tables as (1, ROW_BLOCK) SMEM blocks; the piece
-    arrays and the d-vector in HBM; outputs the per-row result block
-    (1, rows/128, 128) in VMEM and (chain, axpy) the d-vector after."""
+    arrays and (but for the ``split`` chain, which starts from zeros) the
+    d-vector in HBM; outputs the per-row result block (1, rows/128, 128) in
+    VMEM and (chain, axpy) the d-vector after."""
     n_i = 3 if mode == "chain" else 2
     itabs, ftabs = refs[:n_i], refs[n_i:n_i + n_f]
     rest = refs[n_i + n_f:]
     cols_hbm, vals_hbm = rest[0], rest[1]
     rest = rest[2:]
     vec_in = None
-    if mode != "chain":
+    if not split:
         vec_in, rest = rest[0], rest[1:]
     if mode == "axpy":
         (vec_out, vec_sc, cbuf, vbuf, sem), out = rest, None
@@ -231,17 +250,23 @@ def _kernel(*refs, mode: str, n_f: int, step_consts: dict):
             axpy_row(start, cnt, ftabs[0][0, i])
         else:
             prev = itabs[2][0, i]
-            m0, y, qii, a0 = (t[0, i] for t in ftabs)
+            y, qii, a0 = (t[0, i] for t in ftabs[-3:])
             # a row this round already stepped on: alpha is that step's
             pj = jnp.maximum(prev, 0)
             prow = out[0, pl.ds(pj >> 7, 1)]
             a_prev = jnp.sum(jnp.where(lane == (pj & (LANES - 1)), prow, 0.0))
             a = jnp.where(prev >= 0, a_prev, a0)
-            margin = m0 + step_consts["sig_eff"] * dot_row(start, cnt)
+            # vec_sc holds v = w + sig_eff dw_k, or (split) dw_k beside
+            # the table of x . w
+            sig_eff = step_consts["sig_eff"]
+            margin = dot_row(start, cnt)
+            if split:
+                margin = ftabs[0][0, i] + sig_eff * margin
             new_a = losses.alpha_step(
                 step_consts["loss"], a, y * margin, qii,
                 step_consts["lam_n"], smoothing=step_consts["smoothing"])
-            axpy_row(start, cnt, y * (new_a - a) / step_consts["coef_div"])
+            coef = y * (new_a - a) / step_consts["coef_div"]
+            axpy_row(start, cnt, coef if split else sig_eff * coef)
             put(out, r, new_a)
         return carry
 
@@ -254,9 +279,10 @@ def _kernel(*refs, mode: str, n_f: int, step_consts: dict):
 
 
 def _call(mode: str, groups: int, rows: int, n_f: int, d_rows: int, dtype,
-          interpret: bool, **step_consts):
+          interpret: bool, split: bool = False, **step_consts):
     """The ``pallas_call`` of one pass: ``groups`` x ``rows`` rows
-    (``rows`` a multiple of ROW_BLOCK)."""
+    (``rows`` a multiple of ROW_BLOCK).  ``split``: the chain of
+    :func:`margin_form`'s ``split`` form."""
     n_i = 3 if mode == "chain" else 2
     n_blocks = rows // ROW_BLOCK
     tab = pl.BlockSpec((1, ROW_BLOCK), lambda g, b: (0, g * n_blocks + b),
@@ -273,11 +299,11 @@ def _call(mode: str, groups: int, rows: int, n_f: int, d_rows: int, dtype,
         out_specs.append(any_)
         out_shape.append(vec)
     return pl.pallas_call(
-        functools.partial(_kernel, mode=mode, n_f=n_f,
+        functools.partial(_kernel, mode=mode, n_f=n_f, split=split,
                           step_consts=step_consts),
         grid=(groups, rows // ROW_BLOCK),
         in_specs=[tab] * (n_i + n_f) + [any_, any_]
-        + ([any_] if mode != "chain" else []),
+        + ([] if split else [any_]),
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((d_rows, LANES), dtype),
@@ -409,24 +435,37 @@ def pallas_longrows_round(
         prev, last = jax.vmap(lambda i: _link_repeats(i, live))(idxs)
         ftabs = [at(labels), at(sq_norms) * qii_factor, at(alpha)]
     with jax.named_scope(SCOPE_LOCAL_SOLVE):
-        # x_i . w of the round's rows: w does not move within a round
-        m0 = rows_dot(w, sp_indices, sp_values, start, cnt, interpret)
+        split = margin_form(mode) == "split"
         chain = _call(
-            "chain", 1, rows, 4, d_rows, dtype, interpret,
+            "chain", 1, rows, len(ftabs) + split, d_rows, dtype, interpret,
+            split=split,
             lam_n=float(lam * n), coef_div=float(coef_divisor(mode, lam * n)),
             sig_eff=float(sig_eff),
             loss=losses.validate(loss, smoothing),
             smoothing=float(smoothing))
         cols, vals = _as_pieces(sp_indices), _as_pieces(sp_values)
+        if split:
+            # x_i . w of the round's rows, a pass of its own: the chain
+            # starts from zeros and its vector is dw_k alone
+            ftabs.insert(0, rows_dot(w, sp_indices, sp_values, start, cnt,
+                                     interpret))
+            vec_in = ()
+        else:
+            vec_in = (_lane_blocked(w, d_rows),)
 
         def one_shard(dw_sum, xs):
-            tabs = _row_tables([x[None] for x in xs], rows)
-            a_new, dwk = chain(*tabs, cols, vals)
-            return dw_sum + dwk, a_new.reshape(-1)[:h]
+            a_new, vec = chain(*_row_tables([x[None] for x in xs], rows),
+                               cols, vals, *vec_in)
+            if not split:
+                # the chain ran from w and returns v = w + sig_eff dw_k: a
+                # column no step touched still holds w's own bits, so dw_k
+                # is exactly 0 there
+                vec = (vec - vec_in[0]) / sig_eff
+            return dw_sum + vec, a_new.reshape(-1)[:h]
 
         dw_sum, a_new = lax.scan(
             one_shard, jnp.zeros((d_rows, LANES), dtype),
-            (start, cnt, prev.astype(jnp.int32), m0, *ftabs))
+            (start, cnt, prev.astype(jnp.int32), *ftabs))
     with jax.named_scope(SCOPE_SPARSE_GATHER):
         n_shard = alpha.shape[1]
         alpha = jax.vmap(lambda a, i, keep, new: a.at[

@@ -188,9 +188,15 @@ class SolverPath:
     ``storage`` (sparse sets): ``rectangle``: rows padded to the longest,
     (K, n_shard, W); ``stream``: rows of thousands of nonzeros kept end to
     end (data/sharding.stream_suits), solved by the kernels of
-    ops/pallas_longrows.py, which hold ONE d-vector in VMEM at a time (the
-    shard's dw during its chain, w during the passes over rows: ``state``
-    reads ``vmem``).  ``slot_fill``: nonzeros / stored slots, counted on
+    ops/pallas_longrows.py, which hold ONE d-vector in VMEM at a time
+    (``state`` reads ``vmem``): w during the passes over rows, and during a
+    shard's chain what ``margin`` says.  ``margin`` (the stream's Pallas
+    kernels, once :meth:`for_mode` knows the algorithm; None anywhere
+    else): ``combined``: the chain holds v = w + sigma' dw_k and a step's
+    margin is one dot against it; ``split``: it holds dw_k, and x . w of
+    the round's rows is a pass of its own before it (mini-batch CD, whose
+    margin never reads dw_k — ops/pallas_longrows.margin_form).
+    ``slot_fill``: nonzeros / stored slots, counted on
     the host from the row lengths (None where they are not known);
     ``longest_row``: the longest row's nonzeros (the rectangle's width W
     where the lengths are not known).  ``refused``: why a sparse set that
@@ -208,12 +214,22 @@ class SolverPath:
     step_solve: str = "scalar"
     pass_slot_share: float = 1.0
     storage: str = "rectangle"
+    margin: Optional[str] = None
     slot_fill: Optional[float] = None
     longest_row: int = 0
     refused: str = ""
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
+
+    def for_mode(self, mode: str) -> "SolverPath":
+        """This path as the algorithm of ``mode`` (ops/local_sdca.MODES)
+        runs it: ``margin`` filled in where the stream's kernels run."""
+        if not (self.pallas and self.storage == "stream"):
+            return self
+        from cocoa_tpu.ops.pallas_longrows import margin_form
+
+        return dataclasses.replace(self, margin=margin_form(mode))
 
     @property
     def pallas(self) -> bool:
@@ -250,6 +266,8 @@ class SolverPath:
                     else f" (slot fill {self.slot_fill:.3f})")
             solve += (f", rows kept as a stream{fill}, the longest "
                       f"{self.longest_row} nonzeros")
+            if self.margin:
+                solve += f", margin {self.margin}"
         return (f"{what}, {self.layout} layout{rows}{solve}, on "
                 f"{self.platform} x "
                 f"{self.devices} ({self.shards_per_device} shard(s) per "
@@ -899,7 +917,8 @@ def run_sdca_family(
     path = resolve_solver_path(
         ds, params.local_iters, mesh, math=math, pallas=pallas,
         block_size=block_size, block_chain=block_chain,
-        block_sparse_gram=block_sparse_gram, loss=params.loss)
+        block_sparse_gram=block_sparse_gram, loss=params.loss,
+    ).for_mode(alg[0])
     pallas, block_chain = path.pallas, path.block_chain
     if not quiet:
         print(f"local solver: {path.describe()}")

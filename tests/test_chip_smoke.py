@@ -148,10 +148,13 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     assert list(path.as_dict()) == [
         "inner", "kernel", "chain", "interpret", "layout", "platform",
         "devices", "shards_per_device", "rows", "state", "step_solve",
-        "pass_slot_share", "storage", "slot_fill", "longest_row", "refused"]
+        "pass_slot_share", "storage", "margin", "slot_fill", "longest_row",
+        "refused"]
     # rows padded to the longest, their lengths not known here
     assert (path.storage, path.slot_fill, path.refused) == (
         "rectangle", None, "")
+    # no stream: no chain whose margin has a form, whatever the algorithm
+    assert path.margin is None and path.for_mode("plus") == path
     assert path.longest_row == (4 if layout == "sparse" else 0)
     # one block holds these shards: an all-rows pass touches every slot
     assert path.pass_slot_share == 1.0

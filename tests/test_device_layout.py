@@ -361,14 +361,17 @@ def test_ordering_a_kddb_shard_happens_in_its_donated_rows(one_chip):
 WEBSPAM = dict(k=8, n=175000, d=16609143, h=2187, pieces=5 * (1 << 17))
 
 
-@pytest.mark.parametrize("what", ["margins", "axpy", "round"])
+@pytest.mark.parametrize("what", ["margins", "axpy", "round",
+                                  "round_frozen"])
 def test_stream_kernels_compile_at_webspam_size(one_chip, what):
     """The kernels of ops/pallas_longrows.py at webspam's shapes: one 66 MB
     d-vector in VMEM beside the two-chunk SMEM ring compiles under the 100
     MB the kernels ask Mosaic for; the stream is read where it is stored
     (no whole-array copy: the (K, pieces, 128) arrays seen piece by piece
     are the same bytes), and a pass's temporaries are a d-vector or two,
-    not the rows again."""
+    not the rows again.  CoCoA+'s round is the chain alone, run from w
+    (three int and three float tables, the stream, w lane-blocked in HBM);
+    mini-batch CD's keeps x . w as a ``dots`` pass before its chain."""
     import jax
     import jax.numpy as jnp
 
@@ -396,7 +399,8 @@ def test_stream_kernels_compile_at_webspam_size(one_chip, what):
         else:
             fn = lambda w, a, sh, y, q, i: plr.pallas_longrows_round(  # noqa: E731
                 w, a, sh["sp_indices"], sh["sp_values"], sh["sp_row_ptr"],
-                sh["sp_row_len"], y, q, i, 1e-4, WEBSPAM["n"], mode="plus",
+                sh["sp_row_len"], y, q, i, 1e-4, WEBSPAM["n"],
+                mode="frozen" if what == "round_frozen" else "plus",
                 sigma=float(k))
             args = (sds((d,)), rows, stream, rows, rows,
                     sds((k, h), jnp.int32))
@@ -404,6 +408,20 @@ def test_stream_kernels_compile_at_webspam_size(one_chip, what):
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < 4 * d * 4, stats.temp_size_in_bytes
     hlo = compiled.as_text()
-    assert f"pallas_longrows_{'dots' if what != 'axpy' else 'axpy'}" in hlo
+    ran = {p for p in ("dots", "axpy", "chain")
+           if f"pallas_longrows_{p}" in hlo}
+    assert ran == {"margins": {"dots"}, "axpy": {"axpy"}, "round": {"chain"},
+                   "round_frozen": {"dots", "chain"}}[what]
+    if what == "round":
+        rows, vec = plr._pad_rows(h), plr.vec_rows(d)
+        assert vec == 129760
+        operands = ([f"s32[1,{rows}]{{1,0}}"] * 3
+                    + [f"f32[1,{rows}]{{1,0}}"] * 3
+                    + [f"s32[{k * pieces},1,128]{{2,1,0}}",
+                       f"f32[{k * pieces},1,128]{{2,1,0}}",
+                       f"f32[{vec},128]{{1,0}}"])
+        assert ("operand_layout_constraints={" + ", ".join(operands) + "}"
+                in hlo)
+        assert f'"size":"{plr.VMEM_LIMIT}"' in hlo
     assert not re.search(rf"\[{k},{pieces},128\][^ ]* copy\(", hlo)
     assert not re.search(rf"\[{k * pieces},1,128\][^ ]* copy\(", hlo)

@@ -31,8 +31,16 @@ def test_resolver_reports_the_stream(ds):
     assert path.interpret and path.longest_row == LONGEST
     assert 0.85 <= path.slot_fill <= 1.0    # (the cell's: over 1 / 1.10)
     assert "kept as a stream" in path.describe()
+    # how the chain takes a margin follows the algorithm, which the driver
+    # knows: mini-batch CD never reads dw_k, so no v gives it back
+    assert path.margin is None and "margin" not in path.describe()
+    for mode, want in (("plus", "combined"), ("cocoa", "combined"),
+                       ("prox", "combined"), ("frozen", "split")):
+        assert path.for_mode(mode).margin == want
+        assert f"margin {want}" in path.for_mode(mode).describe()
     auto = resolve_solver_path(ds, 12, None, math="fast")    # a CPU: fori
     assert (auto.kernel, auto.storage) == ("fori", "stream")
+    assert auto.for_mode("plus").margin is None
     with pytest.raises(ValueError, match="block"):
         resolve_solver_path(ds, 12, None, math="fast", block_size=128)
 
@@ -63,12 +71,38 @@ def test_driver_on_the_stream_matches_the_fori_path(data, ds, loss):
             device_loop=True, rng="permuted", accel="auto", **kw)
         runs[name] = (np.asarray(w), np.asarray(alpha), traj)
     path = runs["pallas"][2].meta["solver_path"]
-    assert (path["kernel"], path["storage"]) == ("pallas", "stream")
-    assert runs["fori"][2].meta["solver_path"]["kernel"] == "fori"
+    assert (path["kernel"], path["storage"], path["margin"]) == (
+        "pallas", "stream", "combined")
+    fori = runs["fori"][2].meta["solver_path"]
+    assert (fori["kernel"], fori["margin"]) == ("fori", None)
     np.testing.assert_allclose(runs["pallas"][0], runs["fori"][0], atol=1e-5)
     np.testing.assert_allclose(runs["pallas"][1], runs["fori"][1], atol=1e-5)
     gaps = [rec.gap for rec in runs["pallas"][2].records]
     assert gaps[-1] < gaps[0]
+
+
+def test_minibatch_cd_on_the_stream_keeps_the_split_margin(data, ds, capsys):
+    """Mini-batch CD (``frozen``) on the Pallas path: x . w of the round's
+    rows stays a pass of its own, said on the console line and on
+    ``Trajectory.meta``, and the run matches the fori path."""
+    from cocoa_tpu import solvers
+    from cocoa_tpu.config import DebugParams, Params
+
+    params = Params(n=data.n, num_rounds=2, local_iters=16, lam=LAM)
+    debug = DebugParams(debug_iter=2, seed=3)
+    runs = {}
+    for name, pallas in (("pallas", True), ("fori", False)):
+        w, alpha, traj = solvers.run_minibatch_cd(
+            ds, params, debug, math="fast", device_loop=True,
+            rng="permuted", pallas=pallas)
+        runs[name] = (np.asarray(w), np.asarray(alpha), traj)
+    said = capsys.readouterr().out
+    assert "rows kept as a stream" in said and ", margin split" in said
+    assert runs["pallas"][2].meta["solver_path"]["margin"] == "split"
+    assert runs["fori"][2].meta["solver_path"]["margin"] is None
+    assert np.abs(runs["pallas"][0]).max() > 0
+    np.testing.assert_allclose(runs["pallas"][0], runs["fori"][0], atol=1e-5)
+    np.testing.assert_allclose(runs["pallas"][1], runs["fori"][1], atol=1e-5)
 
 
 def test_the_primal_solvers_refuse_a_stream(ds):
