@@ -1530,7 +1530,7 @@ def main(argv=None) -> int:
         cfg_manifest["layout_split"] = layout_split
         run_meta["config_hash"] = telemetry.events.config_hash(cfg_manifest)
 
-    def emit_run_start(ds_solved, local_iters, mode):
+    def emit_run_start(ds_solved, local_iters, mode, smoothing=0.0):
         """``run_start`` waits for the last thing it records: the local
         solver the SDCA-family drivers will resolve to for this dataset
         and flag set (solvers/cocoa.resolve_solver_path — the same call
@@ -1554,7 +1554,8 @@ def main(argv=None) -> int:
         manifest["solver_path"] = resolve_solver_path(
             ds_solved, local_iters, mesh, math=cfg.math,
             block_size=block_size, loss=cfg.loss,
-        ).for_mode(mode).as_dict()
+        ).for_mode(mode, smoothing).as_dict()
+        manifest["vector_len"] = int(ds_solved.num_features)
         bus.emit("run_start", manifest=manifest)
         for rep in ingest_reports:
             bus.emit("ingest", **rep.as_fields())
@@ -1670,8 +1671,8 @@ def main(argv=None) -> int:
         from cocoa_tpu.solvers import run_prox_cocoa
 
         try:
-            ds_c, b = shard_columns(data, k, dtype=dtype, mesh=mesh,
-                                    layout=cfg.layout)
+            ds_c = shard_columns(data, k, dtype=dtype, mesh=mesh,
+                                 layout=cfg.layout)
         except ValueError as e:  # e.g. sparse columns + fp mesh
             print(f"error: {e}", file=sys.stderr)
             return 2
@@ -1683,7 +1684,7 @@ def main(argv=None) -> int:
         lasso_params = dataclasses.replace(
             cfg.to_params(d, k), loss="lasso", smoothing=l2,
         )
-        emit_run_start(ds_c, lasso_params.local_iters, "prox")
+        emit_run_start(ds_c, lasso_params.local_iters, "prox", l2)
         resume_kw = {}
         if resume:
             from cocoa_tpu import checkpoint as ckpt_lib
@@ -1699,16 +1700,18 @@ def main(argv=None) -> int:
                 resume_kw = dict(r_init=r0, x_init=rows_as_ordered(ds_c, x0),
                                  start_round=meta["round"] + 1)
         x, r, traj = run_prox_cocoa(
-            ds_c, b, lasso_params, cfg.to_debug(), mesh=mesh, rng=cfg.rng,
+            ds_c, lasso_params, cfg.to_debug(), mesh=mesh, rng=cfg.rng,
             sampling=cfg.sampling, quiet=quiet,
             gap_target=gap_target, scan_chunk=cfg.scan_chunk,
             math=cfg.math, device_loop=cfg.device_loop,
-            block_size=block_size, divergence_guard=guard, **resume_kw,
+            block_size=block_size, divergence_guard=guard, l2=l2,
+            **resume_kw,
         )
         from cocoa_tpu.solvers.prox_cocoa import _metrics_fn
 
         final = [float(v) for v in
-                 _metrics_fn(mesh, cfg.lam, l2)(r, x, ds_c.shard_arrays(), b)]
+                 _metrics_fn(mesh, cfg.lam, l2)(r, x, ds_c.shard_arrays(),
+                                                ds_c.target)]
         traj.meta.update(run_meta)
         traj.summary(final[0], gap=final[1], test_error=None)
         if extras["trajOut"]:

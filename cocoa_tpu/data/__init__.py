@@ -18,7 +18,9 @@ from cocoa_tpu.data.ingest import (  # noqa: F401
     stream_shard_dataset,
 )
 from cocoa_tpu.data.slab_cache import SlabCache  # noqa: F401
-from cocoa_tpu.data.columns import shard_columns  # noqa: F401
+from cocoa_tpu.data.columns import (  # noqa: F401
+    shard_columns, shard_dense_columns,
+)
 from cocoa_tpu.data.fleet import (  # noqa: F401
     FleetDataset,
     TenantSpec,

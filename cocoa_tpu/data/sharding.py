@@ -173,6 +173,11 @@ class ShardedDataset:
                                       #   STREAM_ALIGN: the static width the
                                       #   per-row accessors window a row by
                                       #   (ops/rows.get_row)
+    target: Optional[jax.Array] = None  # column shards only (data/columns.py):
+                                      #   (num_features,) the regression
+                                      #   target b, replicated like the
+                                      #   residual it is the start of; no
+                                      #   K axis, so not in shard_arrays()
 
     @property
     def k(self) -> int:
@@ -226,7 +231,7 @@ class ShardedDataset:
             self.labels, self.mask, self.sq_norms,
             self.X, self.sp_indices, self.sp_values, self.X_eval,
             self.X_hot, self.hot_cols, self.row_order, self.sp_row_ptr,
-            self.sp_row_len, self.sp_row_iota,
+            self.sp_row_len, self.sp_row_iota, self.target,
         )
         aux = (self.layout, self.n, self.num_features, tuple(self.counts))
         return children, aux
@@ -235,7 +240,7 @@ class ShardedDataset:
     def tree_unflatten(cls, aux, children):
         (labels, mask, sq_norms, X, sp_indices, sp_values, X_eval,
          X_hot, hot_cols, row_order, sp_row_ptr, sp_row_len,
-         sp_row_iota) = children
+         sp_row_iota, target) = children
         layout, n, num_features, counts = aux
         return cls(
             layout=layout,
@@ -255,6 +260,7 @@ class ShardedDataset:
             sp_row_ptr=sp_row_ptr,
             sp_row_len=sp_row_len,
             sp_row_iota=sp_row_iota,
+            target=target,
         )
 
 

@@ -1672,6 +1672,7 @@ def drive_device_paths(
     device_loop: bool = False,
     cache_key=None,
     eval_kernel=None,
+    eval_arrays=None,
     divergence_guard: bool = True,
     sigma_levels: Optional[tuple] = None,
     accel: Optional["AccelConfig"] = None,
@@ -1682,12 +1683,18 @@ def drive_device_paths(
     the fused eval kernel (dual state iff ``alpha_in_state``; overridable
     for non-classification objectives) and routes to
     :func:`drive_device_full` or :func:`drive_chunked`.  Returns
-    (state, Trajectory).  ``ckpt_rows``: :func:`checkpoint_arguments`."""
+    (state, Trajectory).  ``ckpt_rows``: :func:`checkpoint_arguments`.
+    ``eval_arrays``: what an ``eval_kernel`` of the caller's own takes as
+    its third argument in place of the test set's arrays (the prox
+    family's regression target): an argument of the device loop, never a
+    constant inside it."""
     from cocoa_tpu.evals import objectives
 
     if device_loop:
         test_arrays = test_ds.shard_arrays() if test_ds is not None else None
         test_n = test_ds.n if test_ds is not None else 0
+        if eval_arrays is not None:
+            test_arrays = eval_arrays
 
         if eval_kernel is None:
             def eval_kernel(state, shard_arrays, test_arrays):

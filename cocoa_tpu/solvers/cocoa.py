@@ -200,7 +200,17 @@ class SolverPath:
     the host from the row lengths (None where they are not known);
     ``longest_row``: the longest row's nonzeros (the rectangle's width W
     where the lengths are not known).  ``refused``: why a sparse set that
-    no Pallas kernel takes runs ``fori``, with the numbers."""
+    no Pallas kernel takes runs ``fori``, with the numbers.
+    ``objective``: ``svm`` (the dual family: hinge, smoothed hinge,
+    logistic) | ``lasso`` | ``elastic_net`` (the prox family,
+    solvers/prox_cocoa.py, whose shards are A's columns and whose shared
+    vector is the residual), filled in by :meth:`for_mode` from the
+    algorithm and the loss the run was handed; it chooses no kernel.
+    ``form`` (the dense Pallas kernel; None anywhere else): which of its
+    two kernels runs, chosen from the fit alone
+    (ops/pallas_sdca.dense_form) — ``interleaved``: every shard's state in
+    VMEM at once, the K chains advanced in lockstep; ``shard_major``: a
+    shard at a time."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -218,18 +228,26 @@ class SolverPath:
     slot_fill: Optional[float] = None
     longest_row: int = 0
     refused: str = ""
+    objective: str = "svm"
+    form: Optional[str] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
 
-    def for_mode(self, mode: str) -> "SolverPath":
+    def for_mode(self, mode: str, smoothing: float = 0.0) -> "SolverPath":
         """This path as the algorithm of ``mode`` (ops/local_sdca.MODES)
-        runs it: ``margin`` filled in where the stream's kernels run."""
-        if not (self.pallas and self.storage == "stream"):
-            return self
+        runs it: ``objective`` from the mode (and, in the prox family,
+        from whether the l2 weight ``smoothing`` is there), ``margin``
+        filled in where the stream's kernels run."""
+        path = self
+        if mode == "prox":
+            path = dataclasses.replace(
+                path, objective="elastic_net" if smoothing > 0 else "lasso")
+        if not (path.pallas and path.storage == "stream"):
+            return path
         from cocoa_tpu.ops.pallas_longrows import margin_form
 
-        return dataclasses.replace(self, margin=margin_form(mode))
+        return dataclasses.replace(path, margin=margin_form(mode))
 
     @property
     def pallas(self) -> bool:
@@ -256,6 +274,10 @@ class SolverPath:
                        and self.state == "hbm" else "")
                     + (f" [{self.refused}]" if self.refused else ""))
         rows = ", rows stored row-major" if self.rows == "row_major" else ""
+        if self.form:
+            what += f" {self.form}"
+        if self.objective != "svm":
+            rows += f", objective {self.objective}"
         solve = (", the shards' steps solved in lanes"
                  if self.step_solve == "lanes" else "")
         if self.pass_slot_share < 1.0:
@@ -462,11 +484,14 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         placement["slot_fill"], placement["longest_row"] = _slot_stats(ds)
     if block_size <= 0:
         from cocoa_tpu.ops import losses
-        from cocoa_tpu.ops.pallas_sdca import stores_row_major
+        from cocoa_tpu.ops.pallas_sdca import dense_form, stores_row_major
 
         return SolverPath(
             inner="sequential", kernel="pallas" if pallas else "fori",
             chain=None, interpret=bool(pallas and platform == "cpu"),
+            form=(dense_form(m_local, ds.n_shard, ds.num_features, itemsize,
+                             local_iters)
+                  if pallas and not sparse else None),
             rows=("row_major" if pallas and not sparse
                   and stores_row_major(ds.num_features)
                   else "device_default"),
@@ -748,6 +773,7 @@ def run_sdca_family(
     device_loop: bool = False,
     eval_fn=None,
     eval_kernel=None,
+    eval_arrays=None,
     sampling: str = "auto",
     divergence_guard: str = "auto",
     sigma_levels=None,
@@ -784,7 +810,9 @@ def run_sdca_family(
     ``eval_fn(state) -> (primal, gap|None, test_err|None)`` and
     ``eval_kernel(state, shard_arrays, test_arrays) -> (3,) metrics``
     override the classification objectives (needed when the state has
-    different semantics — e.g. ProxCoCoA+'s residual/coordinates).
+    different semantics — e.g. ProxCoCoA+'s residual/coordinates);
+    ``eval_arrays`` is then what the device loop hands ``eval_kernel`` as
+    ``test_arrays`` (the prox family's regression target, ``ds.target``).
 
     Extensions over the reference: ``gap_target`` stops early once the
     duality gap — checked at the ``debugIter`` cadence — falls below the
@@ -918,10 +946,11 @@ def run_sdca_family(
         ds, params.local_iters, mesh, math=math, pallas=pallas,
         block_size=block_size, block_chain=block_chain,
         block_sparse_gram=block_sparse_gram, loss=params.loss,
-    ).for_mode(alg[0])
+    ).for_mode(alg[0], params.smoothing)
     pallas, block_chain = path.pallas, path.block_chain
     if not quiet:
-        print(f"local solver: {path.describe()}")
+        print(f"local solver: {path.describe()}; the shared vector is "
+              f"{ds.num_features} long")
     parts_kw = dict(
         math=math, pallas=pallas,
         pallas_interpret=path.pallas and path.interpret,
@@ -1286,11 +1315,13 @@ def run_sdca_family(
             test_ds=test_ds, quiet=quiet, gap_target=gap_target,
             start_round=start_round, scan_chunk=scan_chunk,
             device_loop=device_loop, cache_key=cache_key,
-            eval_kernel=eval_kernel, divergence_guard=guard_on,
+            eval_kernel=eval_kernel, eval_arrays=eval_arrays,
+            divergence_guard=guard_on,
             sigma_levels=levels, accel=accel_cfg,
             overlap_io=overlap_io, ckpt_rows=ckpt_rows,
         )
-        traj.meta["solver_path"] = path.as_dict()
+        traj.meta.update(solver_path=path.as_dict(),
+                         vector_len=int(ds.num_features))
         return state[0], state[1], traj
 
     step = make_round_step(mesh, params, k, alg, **parts_kw)
@@ -1304,7 +1335,8 @@ def run_sdca_family(
         quiet=quiet, gap_target=gap_target, start_round=start_round,
         divergence_guard=guard_on, ckpt_rows=ckpt_rows,
     )
-    traj.meta["solver_path"] = path.as_dict()
+    traj.meta.update(solver_path=path.as_dict(),
+                     vector_len=int(ds.num_features))
     return w, alpha, traj
 
 

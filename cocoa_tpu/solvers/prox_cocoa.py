@@ -103,7 +103,6 @@ def _metrics_fn(mesh, l1: float, l2: float):
 
 def run_prox_cocoa(
     ds: ShardedDataset,
-    b: jax.Array,
     params: Params,
     debug: DebugParams,
     mesh=None,
@@ -121,26 +120,42 @@ def run_prox_cocoa(
     device_loop: bool = False,
     sampling: str = "auto",
     divergence_guard: str = "auto",
+    l2: float = 0.0,
 ):
     """Train; returns (x, r, Trajectory) with x (K, d_shard) the sharded
     coordinates and r = A·x − b the replicated residual (v = r + b).
 
-    ``ds``/``b`` come from :func:`cocoa_tpu.data.columns.shard_columns`.
-    ``params.lam`` is the L1 weight λ, ``params.smoothing`` the elastic-net
-    l2 weight η (0 = pure lasso), ``params.gamma`` the aggregation γ
-    (γ=1 additive, σ′ = K·γ — the CoCoA+ safe default), ``params.local_iters``
-    the per-round coordinate steps H.  ``gap_target`` stops at the duality
-    gap (certified for both lasso and elastic net — module docstring).
-    Execution options (``scan_chunk``,
-    ``math``, ``pallas``, ``device_loop``) as in run_sdca_family — all
-    paths incl. both Pallas kernels work on the transposed layout."""
-    l1, l2 = float(params.lam), float(params.smoothing)
+    The entry takes what every solver's does, ``(ds, params, debug, ...)``.
+    ``ds`` is column shards that carry the regression target b as
+    ``ds.target`` (:func:`cocoa_tpu.data.columns.shard_columns`, or
+    :func:`~cocoa_tpu.data.columns.shard_dense_columns` from device
+    arrays); a dataset without one (the SVM solvers' row shards) is
+    refused.
+
+    ``params.lam`` is the L1 weight λ, ``params.gamma`` the aggregation γ
+    (γ=1 additive, σ′ = K·γ — the CoCoA+ safe default),
+    ``params.local_iters`` the per-round coordinate steps H; ``l2`` is the
+    elastic-net weight η (the CLI's ``--l2``; 0 = pure lasso —
+    ``params.smoothing``, the smoothed hinge's s with its default of 1, is
+    not read).  ``gap_target`` stops at the duality gap (certified for
+    both lasso and elastic net — module docstring).  Execution options
+    (``scan_chunk``, ``math``, ``pallas``, ``device_loop``) as in
+    run_sdca_family — all paths incl. both Pallas kernels work on the
+    transposed layout."""
+    if ds.target is None:
+        raise ValueError(
+            "run_prox_cocoa takes column shards that carry the regression "
+            "target (data.columns.shard_columns / shard_dense_columns); "
+            "this dataset has none: it is row shards, an SVM solver's")
+    l1, l2 = float(params.lam), float(l2)
     # mode="prox" has no λn factor: clone with n=1 so the shared parts'
-    # lam_n == λ exactly, and select the lasso prox rule
-    parts_params = dataclasses.replace(params, n=1, loss="lasso")
+    # lam_n == λ exactly, and select the lasso prox rule (its ``smoothing``
+    # is the elastic-net weight)
+    parts_params = dataclasses.replace(params, n=1, loss="lasso",
+                                       smoothing=l2)
     alg = ("prox", params.gamma, ds.k * params.gamma)
-    dtype = ds.labels.dtype
-    b = jnp.asarray(b, dtype)
+    b = ds.target
+    dtype = b.dtype
     metrics = _metrics_fn(mesh, l1, l2)
 
     def eval_fn(state):
@@ -149,32 +164,29 @@ def run_prox_cocoa(
         primal, gap, _ = (float(v) for v in out)
         return primal, (None if np.isnan(gap) else gap), None
 
-    def eval_kernel(state, shard_arrays, test_arrays):
-        # b arrives as the (otherwise unused) test_arrays ARGUMENT, not a
-        # closure constant: device-loop executables are cached per config
+    def eval_kernel(state, shard_arrays, target):
+        # b arrives as an ARGUMENT of the device loop (``eval_arrays``), not
+        # a closure constant: device-loop executables are cached per config
         # (base._DEVICE_RUNS), and a baked-in b would make a cached
         # executable evaluate against the wrong dataset
         r, x = state
-        return lasso_metrics(r, x, shard_arrays, test_arrays, l1, l2,
-                             mesh=mesh)
-
-    class _BCarrier:
-        """Quacks like a test dataset so drive_device_paths ships b as the
-        eval kernel's test_arrays argument."""
-        n = 0
-
-        def shard_arrays(self):
-            return b
+        return lasso_metrics(r, x, shard_arrays, target, l1, l2, mesh=mesh)
 
     w_init = -b if r_init is None else jnp.asarray(r_init, dtype)
     r, x, traj = run_sdca_family(
         ds, parts_params, debug, "ProxCoCoA+", alg, mesh=mesh,
-        test_ds=_BCarrier(),
-        rng=rng, w_init=w_init, alpha_init=x_init, start_round=start_round,
+        eval_arrays=b, rng=rng, w_init=w_init, alpha_init=x_init,
+        start_round=start_round,
         quiet=quiet, gap_target=gap_target, scan_chunk=scan_chunk,
         math=math, pallas=pallas, block_size=block_size,
         block_chain=block_chain, device_loop=device_loop,
         eval_fn=eval_fn, eval_kernel=eval_kernel, sampling=sampling,
         divergence_guard=divergence_guard,
     )
+    # the support of the returned x: what an L1 run is for (one scalar
+    # fetched after the run, beside the trajectory)
+    traj.meta["x_nnz"] = int(jnp.count_nonzero(x))
+    if not quiet:
+        print(f"ProxCoCoA+: x has {traj.meta['x_nnz']} nonzero "
+              f"coordinates of {ds.n}")
     return x, r, traj

@@ -180,14 +180,14 @@ def test_block_prox_lasso_matches_plain(tiny_data):
     from cocoa_tpu.data.columns import shard_columns
     from cocoa_tpu.solvers import run_prox_cocoa
 
-    ds_c, b = shard_columns(tiny_data, K, dtype=jnp.float64)
+    ds_c = shard_columns(tiny_data, K, dtype=jnp.float64)
     d = tiny_data.num_features
     lam = 0.1 * float(np.max(np.abs(tiny_data.to_dense().T @ tiny_data.labels)))
     p = Params(n=d, num_rounds=10, local_iters=4, lam=lam, loss="lasso",
                smoothing=0.0)
     dbg = DebugParams(debug_iter=10, seed=0)
-    x0, r0, traj0 = run_prox_cocoa(ds_c, b, p, dbg, quiet=True, math="fast")
-    x1, r1, traj1 = run_prox_cocoa(ds_c, b, p, dbg, quiet=True, math="fast",
+    x0, r0, traj0 = run_prox_cocoa(ds_c, p, dbg, quiet=True, math="fast")
+    x1, r1, traj1 = run_prox_cocoa(ds_c, p, dbg, quiet=True, math="fast",
                                    block_size=4)
     np.testing.assert_allclose(np.asarray(x1), np.asarray(x0),
                                rtol=1e-9, atol=1e-12)

@@ -149,7 +149,11 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
         "inner", "kernel", "chain", "interpret", "layout", "platform",
         "devices", "shards_per_device", "rows", "state", "step_solve",
         "pass_slot_share", "storage", "margin", "slot_fill", "longest_row",
-        "refused"]
+        "refused", "objective", "form"]
+    # the dual family; which dense kernel runs, on the dense Pallas path
+    assert path.objective == "svm"
+    assert path.form == ("interleaved" if pallas and layout == "dense"
+                         else None)
     # rows padded to the longest, their lengths not known here
     assert (path.storage, path.slot_fill, path.refused) == (
         "rectangle", None, "")

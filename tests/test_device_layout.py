@@ -425,3 +425,104 @@ def test_stream_kernels_compile_at_webspam_size(one_chip, what):
         assert f'"size":"{plr.VMEM_LIMIT}"' in hlo
     assert not re.search(rf"\[{k},{pieces},128\][^ ]* copy\(", hlo)
     assert not re.search(rf"\[{k * pieces},1,128\][^ ]* copy\(", hlo)
+
+
+# --- epsilon's lasso (the prox family's cell), with no chip -----------------
+
+LASSO = dict(k=8, cols=2000, n=400000, h=25, lam=72.0)
+
+
+def _capture_prox_run(monkeypatch):
+    """``(run, its arguments, the SolverPath)`` of one ProxCoCoA+ job at
+    epsilon-lasso's shape with the cell's flags, stopped at the dispatch.
+    The columns (3.28 GB) and their folded copy are shapes only; the target
+    and the per-column vectors are arrays (the entry negates the target)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import ShardedDataset, split_sizes
+    from cocoa_tpu.solvers import base, run_prox_cocoa
+    from cocoa_tpu.solvers import cocoa as cocoa_mod
+
+    got = {}
+    build = base._build_device_run
+
+    def capturing(*args, **kw):
+        run = build(*args, **kw)
+
+        def call(*run_args):
+            got["run"], got["args"] = run, run_args
+            raise _Captured
+
+        return call
+
+    resolve = cocoa_mod.resolve_solver_path
+
+    def compiled_pallas(*args, **kw):
+        got["path"] = dataclasses.replace(
+            resolve(*args, **{**kw, "pallas": True}), interpret=False)
+        return got["path"]
+
+    monkeypatch.setattr(base, "_build_device_run", capturing)
+    monkeypatch.setattr(cocoa_mod, "resolve_solver_path", compiled_pallas)
+    base._DEVICE_RUNS.clear()
+    k, n = LASSO["k"], LASSO["n"]
+    sizes = split_sizes(LASSO["cols"], k)
+    d_shard = -(-int(sizes.max()) // 16) * 16
+    here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    cols = (jnp.arange(d_shard)[None, :]
+            < jnp.asarray(sizes)[:, None]).astype(jnp.float32)
+    ds = ShardedDataset(
+        layout="dense", n=LASSO["cols"], num_features=n,
+        counts=sizes.astype(np.int64), labels=cols, mask=cols,
+        sq_norms=200.0 * cols,
+        X=jax.ShapeDtypeStruct((k, d_shard, n), jnp.float32, sharding=here),
+        target=jnp.ones(n, jnp.float32))
+    ds._x_folded_cache = jax.ShapeDtypeStruct(
+        (k, d_shard, 8, -(-n // 1024) * 128), jnp.float32, sharding=here)
+    with pytest.raises(_Captured):
+        run_prox_cocoa(
+            ds, Params(n=ds.n, num_rounds=10000, local_iters=LASSO["h"],
+                       lam=LASSO["lam"], loss="lasso"),
+            DebugParams(debug_iter=50, seed=0), quiet=True, math="fast",
+            device_loop=True, rng="permuted", gap_target=20.0)
+    base._DEVICE_RUNS.clear()
+    return got["run"], got["args"], got["path"], d_shard
+
+
+def test_lasso_job_compiles_at_epsilon_size_with_a_float32_certificate(
+        monkeypatch, one_chip):
+    """The whole device loop of an epsilon-lasso job compiled for one
+    described v5e: the prox rule (a soft threshold on an unbounded
+    coordinate, no label factor) lowers through Mosaic at a 400,000-long
+    shared vector, in the shard-major kernel the fit picks (1.6 MB rows:
+    all eight shards' blocks do not fit beside each other); the columns are
+    read where they are stored (no whole-array copy at the loop's entry);
+    arguments and temporaries are the two copies of A and little else; and
+    nothing is rounded to bfloat16 on the way into the certificate's
+    A^T r (a bf16 A^T r scales the dual point wrongly, PERF.md §7)."""
+    import jax
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    k, n, h = LASSO["k"], LASSO["n"], LASSO["h"]
+    with jax.enable_x64(False):
+        run, args, path, d_shard = _capture_prox_run(monkeypatch)
+        assert pallas_sdca.pick_interleave(k, d_shard, n, 4, h) == 0
+        assert pallas_sdca.pick_unroll(d_shard, n, 4, h) == 1
+        assert (path.kernel, path.state, path.form, path.rows) == (
+            "pallas", "vmem", "shard_major", "row_major")
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    stats = compiled.memory_analysis()
+    assert 6.5e9 < stats.argument_size_in_bytes < 6.7e9   # A, twice
+    assert stats.temp_size_in_bytes < 0.1e9, stats.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    big = re.compile(rf"f32\[{k},{d_shard},(8,)?\d+\][^ ]* copy\(")
+    assert not big.search(hlo)
+    # A^T r is a float32 multiply-and-reduce on the vector unit: no MXU op
+    # (whose default precision rounds its operands to bf16) and no bf16
+    # value anywhere in the program
+    assert "bf16[" not in hlo
+    assert not re.search(r" (dot|convolution)\(", hlo)
