@@ -51,6 +51,22 @@ _COMPILE_RE = re.compile(
 # production mirror of what a sanitizer context observes per run)
 _counters_lock = threading.Lock()
 intended_fetches_total = 0
+# process-lifetime count of the programs the drive ladder issued, counted
+# on the host where it issues them (:func:`count_launch`)
+launches_total = 0
+
+
+def count_launch(n: int = 1) -> None:
+    """The drive ladder issued ``n`` device programs: a start program, a
+    loop program, a host-stepped chunk or eval, a leaf made eagerly from
+    an init that was handed in.  Counted where the ladder issues them, so
+    a CPU test and a user can read a job's launches without a trace
+    (``Trajectory.meta["launches"]``); an eager op that lowers to more
+    than one program (``jnp.zeros``: two) still counts one, so off the
+    one-start-program path the chip's trace is the exact count."""
+    global launches_total
+    with _counters_lock:
+        launches_total += n
 
 
 @dataclasses.dataclass
@@ -181,6 +197,18 @@ def allow_transfers():
     import jax
 
     with jax.transfer_guard("allow"):
+        yield
+
+
+@contextlib.contextmanager
+def allow_uploads():
+    """Un-counted host→device allow, for a dispatch whose arguments
+    include a host array (the device-mode chunk spec, a few dozen
+    integers built with NumPy, rides the loop program's dispatch).
+    Device→host stays as the surrounding guard has it."""
+    import jax
+
+    with jax.transfer_guard_host_to_device("allow"):
         yield
 
 

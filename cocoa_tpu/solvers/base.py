@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from cocoa_tpu import checkpoint as ckpt_lib
+from cocoa_tpu.analysis import sanitize as _sanitize
 from cocoa_tpu.config import DebugParams, Params
 from cocoa_tpu.data.sharding import ShardedDataset
 from cocoa_tpu.parallel.fanout import fanout  # noqa: F401  (re-export)
@@ -359,16 +360,17 @@ def anneal_levels(start: float, safe: float, factor: float = 2.0,
     return tuple(levels)
 
 
-def sched_init_array(start_round: int, sched_init=None, accel: bool = False):
-    """The initial sched vector (see the layout notes above): a restored
-    mid-schedule state, or a fresh stage-0 watch starting at
-    ``start_round``.  With ``accel`` the vector carries the ACCEL_LEN
-    momentum/Θ tail too; a restored plain (SCHED_LEN,) state is extended
-    with fresh accel slots (resuming a pre-accel checkpoint restarts the
-    momentum sequence — sound: any (w, α) is a valid primal-dual pair),
-    and an accel-length state resumed WITHOUT accel keeps its σ′ head."""
-    import jax.numpy as jnp
-
+def sched_init_values(start_round: int, sched_init=None,
+                      accel: bool = False) -> np.ndarray:
+    """The initial sched vector (see the layout notes above) as host
+    constants: a restored mid-schedule state, or a fresh stage-0 watch
+    starting at ``start_round``.  With ``accel`` the vector carries the
+    ACCEL_LEN momentum/Θ tail too; a restored plain (SCHED_LEN,) state is
+    extended with fresh accel slots (resuming a pre-accel checkpoint
+    restarts the momentum sequence — sound: any (w, α) is a valid
+    primal-dual pair), and an accel-length state resumed WITHOUT accel
+    keeps its σ′ head.  float32 NumPy: the SDCA family's start program
+    takes it as an argument (nothing of it is computed on the device)."""
     head = np.array([0.0, 0.0, np.inf, np.inf, float(start_round)],
                     dtype=np.float32)
     tail = np.array([0.0, 0.0, 0.0, np.inf, 0.0, 0.0, np.inf, np.inf],
@@ -384,8 +386,15 @@ def sched_init_array(start_round: int, sched_init=None, accel: bool = False):
             s = np.concatenate([s, tail])
         elif not accel and s.shape == (SCHED_LEN + ACCEL_LEN,):
             s = s[:SCHED_LEN]
-        return jnp.asarray(s)
-    return jnp.asarray(np.concatenate([head, tail]) if accel else head)
+        return s
+    return np.concatenate([head, tail]) if accel else head
+
+
+def sched_init_array(start_round: int, sched_init=None, accel: bool = False):
+    """:func:`sched_init_values` as a device array."""
+    import jax.numpy as jnp
+
+    return jnp.asarray(sched_init_values(start_round, sched_init, accel))
 
 
 def _watch_update(xp, gv, best, best_prev, stall, rel):
@@ -560,10 +569,12 @@ def drive(
     watch = _GapWatch(n_evals=stall_window(debug.debug_iter))
     for t in range(start_round, params.num_rounds + 1):
         state = round_fn(t, state)
+        _sanitize.count_launch()
 
         if debug.debug_iter > 0 and t % debug.debug_iter == 0:
             with _tracing.span("eval", algorithm=name, round=t):
                 primal, gap, test_err = eval_fn(state)
+                _sanitize.count_launch()
             traj.log_round(t, primal=primal, gap=gap, test_error=test_err)
             if gap_target is not None and gap is not None and gap <= gap_target:
                 traj.stopped = "target"
@@ -632,11 +643,13 @@ def drive_chunked(
         with _tracing.span("local_solve", algorithm=name, round=end,
                            t0=t, rounds=c):
             state = chunk_fn(t, c, state)
+            _sanitize.count_launch()
         t = end + 1
 
         if debug.debug_iter > 0 and end % debug.debug_iter == 0:
             with _tracing.span("eval", algorithm=name, round=end):
                 primal, gap, test_err = eval_fn(state)
+                _sanitize.count_launch()
             anneal_on = (gap_target is not None and divergence_guard
                          and anneal)
             hit = (gap_target is not None and gap is not None
@@ -967,9 +980,28 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
              jnp.asarray(jnp.inf, dtype=state[0].dtype),
              jnp.asarray(jnp.inf, dtype=state[0].dtype), state, traj0),
         )
-        return i, done_tgt, done_stall, state, traj
+        # what the host reads of the loop, as two leaves: the stop round
+        # and the two stop flags in one int32 vector, beside the buffer
+        # (whose dtype is the state's and could not hold a round count)
+        head = jnp.stack([i, done_tgt.astype(jnp.int32),
+                          done_stall.astype(jnp.int32)])
+        return head, state, traj
 
     return run
+
+
+def fetch_loop_result(head, traj_buf, label: str):
+    """The one read of a device loop, solo or fleet: ``head`` (the count
+    of chunks done first, whatever stop flags follow it) and the WHOLE
+    trajectory buffer come back as one host copy — every leaf's transfer
+    is started before the first is waited for — and the rows the loop
+    wrote are cut on the host, so no program is launched once the loop
+    has ended.  Opens the ``fetch`` span and the sanctioned
+    ``intended_fetch(label)``.  Returns ``(head, rows)`` as NumPy."""
+    with _tracing.span("fetch"), _sanitize.intended_fetch(label):
+        head_host, traj_host = jax.device_get((head, traj_buf))
+    head_host = np.atleast_1d(head_host)
+    return head_host, traj_host[:int(head_host[0])]
 
 
 def drive_on_device(
@@ -992,7 +1024,12 @@ def drive_on_device(
 ):
     """Fully device-resident outer driver: the ENTIRE run — every round,
     every ``debugIter`` evaluation, and the gap-target early-stop test — is
-    one ``lax.while_loop`` inside one jit.  One dispatch, one host fetch.
+    one ``lax.while_loop`` inside one jit.  One dispatch, one host fetch:
+    between the two the host issues the loop program and nothing else, and
+    reads its result once (:func:`fetch_loop_result`: the stop round, the
+    stop flags and the whole trajectory buffer in one copy, the rows cut on
+    the host).  ``idxs_all`` may be host arrays (the device-mode spec, a
+    few dozen integers): their upload rides the dispatch.
 
     ``sigma_levels`` (more than one): σ′-anneal mode — the stall watch and
     schedule stage ride ``state[-1]`` (see :data:`SCHED_LEN`) and a fired
@@ -1036,8 +1073,6 @@ def drive_on_device(
     # so mesh runs use the fetch replay below).  Where ordered callbacks
     # are unsupported, the SAME tap replays the fetched buffer — identical
     # events, emitted at the end-of-run sync instead of live.
-    from cocoa_tpu.analysis import sanitize as _sanitize
-
     bus = _tele.get_bus()
     emit = bus.active()
     stream = emit and mesh is None and _tele.io_callback_supported()
@@ -1078,11 +1113,11 @@ def drive_on_device(
     # the sanitizer's device-loop contract (analysis/sanitize.py): from
     # dispatch to the sanctioned fetch, nothing crosses host↔device on
     # this thread.  Inert unless a strict sanitizer armed it.  The one
-    # exception is the streaming dispatch itself: the ordered
-    # io_callback's zero-byte effect token rides h2d with the args —
-    # sanctioned tap machinery, not a leak.
-    import contextlib as _ctx
-
+    # exception is the dispatch itself, where arguments go up: the
+    # device-mode spec (host integers), and on a streaming run the ordered
+    # io_callback's zero-byte effect token — sanctioned machinery, not a
+    # leak.
+    #
     # the super-block span: one dispatch + the run's single host fetch —
     # the drive* ladder's host boundary.  Per-eval timing INSIDE the
     # device loop is unobservable by construction (one dispatch, one
@@ -1094,22 +1129,24 @@ def drive_on_device(
                        rounds=n_chunks * c, cadence=c), \
             _sanitize.device_loop_guard(), \
             _tele.device_tap(tap if stream else None):
+        # the dispatch is where arguments go up: a spec the ladder built
+        # on the host (device-mode sampling) is uploaded by it, sanctioned
+        # like the stream's effect token
         with _tracing.span("dispatch"), (
                 _sanitize.allow_transfers() if stream
-                else _ctx.nullcontext()):
-            i, done_tgt, done_stall, state, traj_buf = run(
+                else _sanitize.allow_uploads()):
+            head, state, traj_buf = run(
                 *state, idxs_all, shard_arrays, test_arrays)
+            _sanitize.count_launch()
         # the single host sync of the whole run — marked as the
         # sanctioned fetch point, so the transfer-guard sanitizer
         # (analysis/sanitize.py) can disallow every OTHER device→host
         # path and production --metrics runs count it
         # (host_transfers_total: ~1 per super-block, never per round)
-        with _tracing.span("fetch"), \
-                _sanitize.intended_fetch("device_loop_fetch"):
-            n_done = int(i)
-            stop_tgt = bool(done_tgt)
-            stop_stall = bool(done_stall)
-            traj_host = np.asarray(traj_buf[:n_done])
+        head_host, traj_host = fetch_loop_result(head, traj_buf,
+                                                 "device_loop_fetch")
+        n_done = len(traj_host)
+        stop_tgt, stop_stall = bool(head_host[1]), bool(head_host[2])
         if stream:
             # join the callback stream before leaving the tap context —
             # the fetch orders the computation, not the host callbacks
@@ -1190,6 +1227,13 @@ def drive_device_full(
     as one dispatch, then host-steps the sub-cadence tail (num_rounds %
     debugIter remainder, no eval — same observable behavior as
     :func:`drive_chunked`).  Returns (state, Trajectory).
+
+    A super-block's tables come one of two ways, by ``sampler.device``:
+    where the kernels sample in-jit they are a NumPy spec of round numbers
+    built on this thread under ``wait_indices`` and uploaded by the loop's
+    dispatch — no thread, no program; where they are host tables a staging
+    thread samples block i+1's under ``stage_indices`` while the device
+    runs block i.  Either way a block is one loop program and one read.
 
     With ``sigma_levels`` (σ′ anneal) the stall watch rides ``state[-1]``
     ACROSS super-block boundaries — the host-twin watch below is then
@@ -1276,10 +1320,12 @@ def drive_device_full(
         with _tracing.span("local_solve", algorithm=name, round=head_end,
                            t0=t, rounds=head_end - t + 1):
             state = chunk_fn(t, head_end - t + 1, state)
+            _sanitize.count_launch()
         t = head_end + 1
         if head_end % c == 0:
             with _tracing.span("eval", algorithm=name, round=head_end):
                 primal, gap, test_err = eval_fn(state)
+                _sanitize.count_launch()
             sigma_val = stage = stall_v = None
             backed = False
             hit = (gap_target is not None and gap is not None
@@ -1352,27 +1398,38 @@ def drive_device_full(
             remaining -= b
 
         done = t - 1
-        # one-ahead sampling WITH pre-staged index specs: block i+1's
-        # tables are generated on a daemon host thread while the device
-        # executes block i — hiding the numpy LCG cost behind device time
-        # (at epsilon scale both are ~ms/round) — and the thread also
-        # reshapes them to the (n_chunks, C, ...) chunk layout and commits
-        # them to the device, so the table's h2d transfer overlaps the
-        # previous block's execution instead of landing on the next
-        # dispatch's critical path (see IndexSampler).  On early stop the
-        # in-flight
-        # speculative block is abandoned — bounded waste, overlapped with
-        # the final device block either way, and the daemon thread cannot
-        # delay interpreter exit.
         start = done + 1
+
+        def block_tables(t0, nb):
+            flat = sampler.chunk_indices(t0, nb * c)
+            return jax.tree.map(
+                lambda a: a.reshape(nb, c, *a.shape[1:]), flat)
+
+        # Two ways to a block's ``idxs_all``, by what the sampler says of
+        # itself.  ``sampler.device``: the kernels draw in-jit and the
+        # block's "table" is a spec of its round numbers — NumPy, built
+        # here on the driving thread for nothing, shaped (n_blocks, C) and
+        # handed to the loop program as an argument (its upload rides the
+        # dispatch; on a mesh jit places it).  No thread, no launch, no
+        # prefetch: nothing costs.  Host tables (``--sampling=host``,
+        # ``--rng=reference`` past int32): one-ahead sampling WITH
+        # pre-staged tables — block i+1's are generated on a daemon host
+        # thread while the device executes block i, hiding the numpy LCG
+        # cost behind device time (at epsilon scale both are ~ms/round);
+        # the thread also reshapes them to the (n_chunks, C, ...) chunk
+        # layout and commits them to the device, so the table's h2d
+        # transfer overlaps the previous block's execution instead of
+        # landing on the next dispatch's critical path (see IndexSampler).
+        # On early stop the in-flight speculative block is abandoned —
+        # bounded waste, overlapped with the final device block either
+        # way, and the daemon thread cannot delay interpreter exit.
+        staged = not sampler.device
 
         def stage(t0, nb):
             # on the staging thread: overlaps whatever span the driving
             # thread holds (wait_indices, or the previous block's solve)
             with _tracing.span("stage_indices", t0=t0, rounds=nb * c):
-                flat = sampler.chunk_indices(t0, nb * c)
-                reshaped = jax.tree.map(
-                    lambda a: a.reshape(nb, c, *a.shape[1:]), flat)
+                reshaped = block_tables(t0, nb)
                 if mesh is not None:
                     # committing to the default device would conflict with
                     # the mesh-sharded state at dispatch ("incompatible
@@ -1381,15 +1438,20 @@ def drive_device_full(
                     return reshaped
                 return jax.tree.map(jax.device_put, reshaped)
 
-        # wait_indices: the driving thread held up by the staging thread —
-        # starting it, then blocked on its result.  With one block a job
-        # all of block 0's sampling is paid here.
-        with _tracing.span("wait_indices", t0=start):
-            fut = _Prefetch(stage, start, sizes[0])
+        # wait_indices: what the driving thread spends on a block's
+        # tables — in device mode the NumPy spec (microseconds: the span
+        # opens all the same, the readers of a job's fixed cost go by its
+        # name); with host tables starting the staging thread, then
+        # blocked on its result (with one block a job all of block 0's
+        # sampling is paid here).
+        fut = None
         for bi, b in enumerate(sizes):
             with _tracing.span("wait_indices", t0=start, rounds=b * c):
-                idxs_all = fut.result()
-            if bi + 1 < len(sizes):
+                if staged:
+                    idxs_all = (fut or _Prefetch(stage, start, b)).result()
+                else:
+                    idxs_all = block_tables(start, b)
+            if staged and bi + 1 < len(sizes):
                 fut = _Prefetch(stage, start + b * c, sizes[bi + 1])
             state, dev_traj = drive_on_device(
                 name, state, chunk_kernel, eval_kernel, idxs_all,
@@ -1443,6 +1505,7 @@ def drive_device_full(
         with _tracing.span("local_solve", algorithm=name,
                            round=params.num_rounds, t0=t, rounds=rem):
             state = chunk_fn(t, rem, state)
+            _sanitize.count_launch()
         maybe_ckpt(params.num_rounds)
     # every overlapped checkpoint write must have LANDED before this
     # driver reports done (a caller may read/validate the files next)
@@ -1581,10 +1644,10 @@ class IndexSampler:
         """Tables for rounds t0..t0+c-1: a concrete (C, K, H) int32 array,
         or — in device mode — the ``{"t": (C,) int32}`` spec the solver
         kernels expand in-jit via :meth:`tables_from_ts`."""
-        import jax.numpy as jnp
-
         if self.device:
-            return {"t": jnp.arange(t0, t0 + c, dtype=jnp.int32)}
+            # host constants: a jitted consumer uploads them with its
+            # dispatch, and no program is launched to make them
+            return {"t": np.arange(t0, t0 + c, dtype=np.int32)}
         return self._tables(t0, c)
 
     def _tables(self, t0: int, c: int) -> jax.Array:
@@ -2035,8 +2098,6 @@ def drive_fleet_on_device(
     the executable is cached per ``cache_key``, so a multi-block fleet
     still compiles exactly once), ``traj_host`` is the fetched
     ``(n_done, T, FLEET_N_COLS)`` eval buffer in the solo row layout."""
-    from cocoa_tpu.analysis import sanitize as _sanitize
-
     t_fleet = int(gap_targets.shape[0])
     if carry is None:
         carry = FleetCarry.init(t_fleet, state[0].dtype)
@@ -2062,11 +2123,10 @@ def drive_fleet_on_device(
                       gap_targets)
         (i, done_tgt, done_stall, stall, best, best_prev, cert,
          stall_chunk, state, traj_buf) = out
-        # the single host sync of the whole fleet block
-        with _tracing.span("fetch"), \
-                _sanitize.intended_fetch("fleet_loop_fetch"):
-            n_done = int(i)
-            traj_host = np.asarray(traj_buf[:n_done])
+        # the single host sync of the whole fleet block: the solo loop's
+        # read (the watch vectors stay on the device for the next block)
+        _, traj_host = fetch_loop_result(i, traj_buf, "fleet_loop_fetch")
+        n_done = len(traj_host)
     carry = FleetCarry(done_tgt, done_stall, stall, best, best_prev,
                        cert, stall_chunk)
     return state, carry, n_done, traj_host
@@ -2112,7 +2172,7 @@ class TsSampler:
                 # float ``t`` leaf rides the compute dtype for the η(t)
                 # schedules and cannot carry them (bf16 collapses integers
                 # past 256)
-                out["ti"] = jnp.arange(t0, t0 + c, dtype=jnp.int32)
+                out["ti"] = np.arange(t0, t0 + c, dtype=np.int32)
             else:
                 out["idxs"] = self.sampler.chunk_indices(t0, c)
         return out

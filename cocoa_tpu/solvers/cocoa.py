@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from cocoa_tpu.analysis import sanitize as _sanitize
 from cocoa_tpu.config import DebugParams, Params
 from cocoa_tpu.data.sharding import (ShardedDataset, order_rows_for_passes,
                                      rows_as_built, rows_as_ordered)
@@ -750,6 +751,83 @@ def make_chunk_step(mesh, params: Params, k: int, alg, sampler=None,
     return step
 
 
+_START_PROGRAMS: dict = base.ExecutableCache()
+
+
+def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
+                 start_round: int, w_init, alpha_init, hist_init,
+                 sched_init) -> tuple:
+    """The start state of an SDCA-family job, ``(w, α)`` and per ``arm``
+    the leaves its loop carries: ``"accel"`` the (2, K, n_shard) window
+    bank and the schedule leaf, ``"sched"`` the schedule leaf, ``"plain"``
+    neither.  ``residual`` (the prox family): the shared vector is
+    r = A·x − b and a job with no ``w_init`` starts it at −``ds.target``
+    (x = 0), not at 0.
+
+    Nothing handed in (every benchmark job, every first run): ONE jitted
+    program per (shapes, dtype, arm, mesh) makes every leaf, placed by its
+    ``out_shardings``, and is dispatched without waiting — the loop
+    program goes out right behind it, and the device fills the zeros while
+    the host walks the rest of its path.  The schedule leaf's start values
+    are host constants (base.sched_init_values), passed in.  An init
+    handed in (resume, warm start): a leaf at a time, as before — each a
+    tiny program the device waits for, then its ``device_put`` on a mesh."""
+    # plain ints: the cached start program must not keep ``ds`` alive
+    d, k, n_shard = int(ds.num_features), ds.k, int(ds.n_shard)
+    accel = arm == "accel"
+    sched = (None if arm == "plain"
+             else base.sched_init_values(start_round, sched_init, accel))
+    shardings = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from cocoa_tpu.parallel.mesh import (DP_AXIS, primal_sharding,
+                                             sharded_rows)
+
+        shardings = (primal_sharding(mesh),
+                     sharded_rows(mesh, extra_dims=1),
+                     *((NamedSharding(mesh, P(None, DP_AXIS)),)
+                       if accel else ()),
+                     *(() if sched is None else (NamedSharding(mesh, P()),)))
+    if all(v is None for v in (w_init, alpha_init, hist_init, sched_init)):
+        key = (d, k, n_shard, str(dtype), arm, residual, mesh)
+        start = _START_PROGRAMS.get(key)
+        if start is None:
+            def start(sched, target):
+                w = (jnp.zeros(d, dtype=dtype) if target is None
+                     else (-target).astype(dtype))
+                alpha = jnp.zeros((k, n_shard), dtype=dtype)
+                hist = ((jnp.zeros((2, k, n_shard), dtype=dtype),)
+                        if accel else ())
+                return (w, alpha, *hist,
+                        *(() if sched is None else (sched,)))
+
+            start = _START_PROGRAMS[key] = jax.jit(
+                start, out_shardings=shardings)
+        _sanitize.count_launch()
+        return start(sched, ds.target if residual else None)
+
+    if w_init is not None:
+        w = jnp.array(w_init, dtype=dtype, copy=True)
+    elif residual:
+        w = (-ds.target).astype(dtype)
+    else:
+        w = jnp.zeros(d, dtype=dtype)
+    alpha = (jnp.zeros((k, n_shard), dtype=dtype) if alpha_init is None
+             else base.align_alpha(alpha_init, ds, dtype))
+    leaves = [w, alpha]
+    if accel:
+        leaves.append(jnp.zeros((2,) + alpha.shape, dtype=dtype)
+                      if hist_init is None
+                      else jnp.array(hist_init, dtype=dtype, copy=True))
+    if sched is not None:
+        leaves.append(jnp.asarray(sched))
+    _sanitize.count_launch(len(leaves))
+    if shardings is not None:
+        leaves = [jax.device_put(a, sh) for a, sh in zip(leaves, shardings)]
+    return tuple(leaves)
+
+
 def run_sdca_family(
     ds: ShardedDataset,
     params: Params,
@@ -925,22 +1003,21 @@ def run_sdca_family(
             "--dtype=float32, or drop --gapTarget for an uncertified "
             "bf16 run"
         )
-    # init_state (here and where the scheduled paths add their leaves):
-    # the start state is built one tiny device program at a time, each a
-    # launch the device waits for — a named part of a job's fixed cost
+    # the loop's carry beyond (w, α): --accel the window bank and the
+    # schedule leaf, a σ′ schedule or warm start the schedule leaf (both
+    # take the chunked or the device-resident driver: see below)
+    scheduled = ((sigma_levels is not None and len(sigma_levels) > 1)
+                 or warm_start is not None)
+    arm = "accel" if accel else "sched" if scheduled else "plain"
+    counted = (_sanitize.launches_total, _sanitize.intended_fetches_total)
+    # init_state: one start program when the job starts from nothing —
+    # dispatched here, ahead of the host's path to the loop's dispatch, so
+    # the device fills the leaves meanwhile (_start_state)
     with _tracing.span("init_state"):
-        w = (jnp.zeros(ds.num_features, dtype=dtype) if w_init is None
-             else jnp.array(w_init, dtype=dtype, copy=True))
-        alpha = (
-            jnp.zeros((k, ds.n_shard), dtype=dtype)
-            if alpha_init is None
-            else base.align_alpha(alpha_init, ds, dtype)
-        )
-        if mesh is not None:
-            from cocoa_tpu.parallel.mesh import primal_sharding, sharded_rows
-
-            w = jax.device_put(w, primal_sharding(mesh))
-            alpha = jax.device_put(alpha, sharded_rows(mesh, extra_dims=1))
+        state0 = _start_state(ds, dtype, mesh, arm, alg[0] == "prox",
+                              start_round, w_init, alpha_init, hist_init,
+                              sched_init)
+    w, alpha = state0[:2]
 
     path = resolve_solver_path(
         ds, params.local_iters, mesh, math=math, pallas=pallas,
@@ -1020,6 +1097,19 @@ def run_sdca_family(
                 ds, state[0], state[1], params.lam, test_ds=test_ds,
                 loss=params.loss, smoothing=params.smoothing)
 
+    def record_job(traj):
+        # beside the path: what the drive ladder issued for this job and
+        # how often it read the device (sanitize.count_launch,
+        # sanitize.intended_fetch), counted on the host
+        launches = _sanitize.launches_total - counted[0]
+        fetches = _sanitize.intended_fetches_total - counted[1]
+        traj.meta.update(solver_path=path.as_dict(),
+                         vector_len=int(ds.num_features),
+                         launches=launches, fetches=fetches)
+        if not quiet:
+            print(f"drive ladder: {launches} programs launched, "
+                  f"{fetches} host fetches")
+
     if theta not in ("fixed", "adaptive"):
         raise ValueError(f"theta must be fixed|adaptive, got {theta!r}")
     if accel:
@@ -1031,8 +1121,6 @@ def run_sdca_family(
             raise ValueError(
                 "--theta=adaptive requires --gapTarget (the Θ ladder's "
                 "final full-accuracy stage is keyed to the target)")
-    scheduled = ((sigma_levels is not None and len(sigma_levels) > 1)
-                 or warm_start is not None)
     if (scheduled or accel) and scan_chunk <= 0 and not device_loop:
         # the schedule leaf rides the chunked/device drivers' state; the
         # per-round driver path is equivalent at chunk=1 (pinned by tests)
@@ -1210,23 +1298,6 @@ def run_sdca_family(
                                   sampler.chunk_indices(t0, c),
                                   shard_arrays)
 
-            with _tracing.span("init_state"):
-                hist0 = (jnp.zeros((2,) + alpha.shape, dtype=dtype)
-                         if hist_init is None
-                         else jnp.array(hist_init, dtype=dtype, copy=True))
-                sched0 = base.sched_init_array(start_round, sched_init,
-                                               accel=True)
-                if mesh is not None:
-                    from jax.sharding import (NamedSharding,
-                                              PartitionSpec as P)
-
-                    from cocoa_tpu.parallel.mesh import DP_AXIS
-
-                    hist0 = jax.device_put(
-                        hist0, NamedSharding(mesh, P(None, DP_AXIS)))
-                    sched0 = jax.device_put(sched0,
-                                            NamedSharding(mesh, P()))
-            state0 = (w, alpha, hist0, sched0)
         elif scheduled:
             # one statically-specialized kernel per (σ′ stage, loss phase):
             # every Pallas/block configuration keeps its baked-in scalars,
@@ -1273,16 +1344,6 @@ def run_sdca_family(
             def chunk_fn(t0, c, state):
                 return chunk_step(state[0], state[1], state[2],
                                   sampler.chunk_indices(t0, c), shard_arrays)
-
-            with _tracing.span("init_state"):
-                sched0 = base.sched_init_array(start_round, sched_init)
-                if mesh is not None:
-                    from jax.sharding import (NamedSharding,
-                                              PartitionSpec as P)
-
-                    sched0 = jax.device_put(sched0,
-                                            NamedSharding(mesh, P()))
-            state0 = (w, alpha, sched0)
         else:
             levels = None
             raw_kernel = _make_chunk_kernel(mesh, params, k, alg,
@@ -1297,8 +1358,6 @@ def run_sdca_family(
             def chunk_fn(t0, c, state):
                 return chunk_step(state[0], state[1],
                                   sampler.chunk_indices(t0, c), shard_arrays)
-
-            state0 = (w, alpha)
 
         cache_key = (
             "sdca", alg_name, alg, math, pallas, block_size, block_chain,
@@ -1320,8 +1379,7 @@ def run_sdca_family(
             sigma_levels=levels, accel=accel_cfg,
             overlap_io=overlap_io, ckpt_rows=ckpt_rows,
         )
-        traj.meta.update(solver_path=path.as_dict(),
-                         vector_len=int(ds.num_features))
+        record_job(traj)
         return state[0], state[1], traj
 
     step = make_round_step(mesh, params, k, alg, **parts_kw)
@@ -1335,8 +1393,7 @@ def run_sdca_family(
         quiet=quiet, gap_target=gap_target, start_round=start_round,
         divergence_guard=guard_on, ckpt_rows=ckpt_rows,
     )
-    traj.meta.update(solver_path=path.as_dict(),
-                     vector_len=int(ds.num_features))
+    record_job(traj)
     return w, alpha, traj
 
 

@@ -266,8 +266,8 @@ def run_cocoa_fleet(
                                     data, scal_t["lam_n"])
             return (w2, a2, sched.at[4].add(jnp.float32(c_len)))
 
-        state0 = (np.tile(np.asarray(
-            base.sched_init_array(start_round))[None], (t_fleet, 1)),)
+        state0 = (np.tile(
+            base.sched_init_values(start_round)[None], (t_fleet, 1)),)
     else:   # accel
         def chunk_kernel(state, idxs_ckh, data, scal_t):
             w, alpha, hist, sched = state
@@ -311,8 +311,8 @@ def run_cocoa_fleet(
 
         state0 = (
             np.zeros((t_fleet, 2, k, fleet.n_shard), np.dtype(dtype)),
-            np.tile(np.asarray(base.sched_init_array(
-                start_round, accel=True))[None], (t_fleet, 1)),
+            np.tile(base.sched_init_values(
+                start_round, accel=True)[None], (t_fleet, 1)),
         )
 
     def eval_kernel(state, data, scal_t):

@@ -172,7 +172,9 @@ def run_prox_cocoa(
         r, x = state
         return lasso_metrics(r, x, shard_arrays, target, l1, l2, mesh=mesh)
 
-    w_init = -b if r_init is None else jnp.asarray(r_init, dtype)
+    # no r_init: run_sdca_family starts the residual at -b itself (x = 0),
+    # in its one start program
+    w_init = None if r_init is None else jnp.asarray(r_init, dtype)
     r, x, traj = run_sdca_family(
         ds, parts_params, debug, "ProxCoCoA+", alg, mesh=mesh,
         eval_arrays=b, rng=rng, w_init=w_init, alpha_init=x_init,
@@ -186,6 +188,7 @@ def run_prox_cocoa(
     # the support of the returned x: what an L1 run is for (one scalar
     # fetched after the run, beside the trajectory)
     traj.meta["x_nnz"] = int(jnp.count_nonzero(x))
+    traj.meta["launches"] += 1
     if not quiet:
         print(f"ProxCoCoA+: x has {traj.meta['x_nnz']} nonzero "
               f"coordinates of {ds.n}")

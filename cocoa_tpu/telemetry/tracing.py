@@ -40,6 +40,20 @@ Design constraints, in order:
   allgather inside a round).
 - **Inert by default.**  On a disabled tracer ``span()`` is the
   annotation and one attribute read: no id, no clock read, no event.
+- **The drive ladder's spans** (solvers/cocoa.py ``run_sdca_family``,
+  solvers/base.py ``drive_device_full`` / ``drive_on_device``), which the
+  benchmark's readers of a job's fixed cost go by name and which
+  therefore open on every job, however short: ``init_state`` (a job from
+  nothing: its one start program, dispatched and not waited for; an init
+  handed in: a leaf at a time), ``wait_indices`` (a block's tables: a
+  NumPy spec of round numbers where the kernels sample in-jit, else the
+  driving thread held up by the staging thread), ``local_solve`` around
+  ``dispatch`` (the loop program; the spec's upload rides it) and
+  ``fetch`` (the one read of the stop header and the whole trajectory
+  buffer, ``base.fetch_loop_result``), ``decode_trajectory``,
+  ``checkpoint_save``.  ``stage_indices`` runs on the staging thread and
+  exists only where the tables are host work (``--sampling=host``,
+  ``--rng=reference`` past int32).
 - **Device scopes.**  Inside ``jit`` nothing can be timed from the host;
   there the phases carry ``jax.named_scope`` names (:data:`SCOPES`), which
   change an op's metadata and nothing else, and reach the profiler's

@@ -253,7 +253,7 @@ def lowered(monkeypatch):
 
 
 def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off",
-              sparse=False):
+              sparse=False, sampling="auto"):
     import jax
 
     from cocoa_tpu.data.synth import synth_dense_sharded
@@ -273,7 +273,8 @@ def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off",
     w, alpha, traj = run_cocoa(
         ds, params, DebugParams(debug_iter=5, seed=0), plus=True,
         quiet=True, math="fast", device_loop=True, rng="permuted",
-        gap_target=1e-9, mesh=mesh, pallas=pallas, accel=accel)
+        gap_target=1e-9, mesh=mesh, pallas=pallas, accel=accel,
+        sampling=sampling)
     jax.block_until_ready((w, alpha))
     return (np.asarray(w), np.asarray(alpha),
             [(r.round, r.primal, r.gap) for r in traj.records])
@@ -336,6 +337,8 @@ def test_traced_scoped_and_plain_runs_are_one_computation(lowered,
     try:
         with jax.profiler.TraceAnnotation("job"):
             traced = _loop_run()
+        with jax.profiler.TraceAnnotation("job_host_tables"):
+            hosted = _loop_run(sampling="host")
     finally:
         jax.profiler.stop_trace()
     # strip: named_scope's context manager, made a no-op
@@ -347,33 +350,49 @@ def test_traced_scoped_and_plain_runs_are_one_computation(lowered,
     base._DEVICE_RUNS.clear()
     plain = _loop_run()
     monkeypatch.undo()
-    (scoped_dbg, scoped_txt), (_, traced_txt), (plain_dbg, plain_txt) = \
+    # (the job on host tables is another loop program: it takes a table)
+    (scoped_dbg, scoped_txt), (_, traced_txt), _, (plain_dbg, plain_txt) = \
         lowered
     assert all(sc in scoped_dbg for sc in tracing.SCOPES[:4])
     assert not any(sc in plain_dbg for sc in tracing.SCOPES)
     assert scoped_txt == traced_txt == plain_txt
-    for a, b in ((scoped, traced), (scoped, plain)):
+    for a, b in ((scoped, traced), (scoped, plain), (scoped, hosted)):
         np.testing.assert_array_equal(a[0], b[0])
         np.testing.assert_array_equal(a[1], b[1])
         assert a[2] == b[2]
-    # and the profile holds the drive ladder's spans on the job's thread,
-    # the staging thread's on another
+    # and the profile holds the drive ladder's spans on the job's thread;
+    # a job that samples in-jit stages nothing (its spec is NumPy, built
+    # under wait_indices), a job on host tables stages them on another
     import glob
 
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*"
                             / "*.xplane.pb"))
-    lines = [{ev.name for ev in line.events
-              if ev.name == "job" or ev.name.startswith("cocoa/")}
+    lines = [[ev for ev in line.events
+              if ev.name.startswith(("job", "cocoa/"))]
              for plane in ProfileData.from_file(path).planes
              for line in plane.lines]
-    (driving,) = [names for names in lines if "job" in names]
-    assert driving >= {"cocoa/init_state", "cocoa/wait_indices",
-                       "cocoa/local_solve", "cocoa/dispatch", "cocoa/fetch",
-                       "cocoa/decode_trajectory"}
-    assert "cocoa/stage_indices" not in driving
-    assert any("cocoa/stage_indices" in names for names in lines)
+    (driving,) = [evs for evs in lines
+                  if any(ev.name == "job" for ev in evs)]
+    (job,), (job_host,) = ([ev for ev in driving if ev.name == nm]
+                           for nm in ("job", "job_host_tables"))
+
+    def under(job, evs):
+        return {ev.name for ev in evs
+                if job.start_ns <= ev.start_ns < job.end_ns
+                and ev.name.startswith("cocoa/")}
+
+    for j in (job, job_host):
+        assert under(j, driving) >= {
+            "cocoa/init_state", "cocoa/wait_indices", "cocoa/local_solve",
+            "cocoa/dispatch", "cocoa/fetch", "cocoa/decode_trajectory"}
+        assert "cocoa/stage_indices" not in under(j, driving)
+    staged = [evs for evs in lines if evs is not driving]
+    assert not any("cocoa/stage_indices" in under(job, evs)
+                   for evs in staged)
+    assert any("cocoa/stage_indices" in under(job_host, evs)
+               for evs in staged)
 
 
 def test_span_stream_schema_valid_and_round_attributed(tmp_path):
