@@ -683,9 +683,11 @@ def check_pallas_budget_ast(src: SourceFile, index: ModuleIndex,
 
 # --- rule: span-hygiene -----------------------------------------------------
 
-# the tracing surface (telemetry/tracing.py): the context-manager form
-# and the decorator form, module-level or on a Tracer instance
-_SPAN_CALLEES = {"span", "traced"}
+# the tracing surface (telemetry/tracing.py): the context-manager forms
+# (a span, and a cold span, which besides reads every device's allocator
+# and waits for what it made) and the decorator form, module-level or on
+# a Tracer instance
+_SPAN_CALLEES = {"span", "cold_span", "traced"}
 
 # receiver names that identify the tracing module/object — required for
 # the attribute form so ``re.Match.span()`` and other unrelated ``span``
@@ -694,7 +696,8 @@ _TRACING_RECEIVERS = ("tracing", "tracer")
 
 
 def _is_span_call(node: ast.Call) -> Optional[str]:
-    """'span'/'traced' when ``node`` is a TRACING call, else None.
+    """'span'/'cold_span'/'traced' when ``node`` is a TRACING call, else
+    None.
     Matches ``tracing.span(...)`` / ``_tracing.span(...)`` /
     ``get_tracer().span(...)`` (receiver names the tracing surface), a
     bare imported ``span("phase", ...)``/``traced("phase")`` (string

@@ -420,16 +420,22 @@ def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
     return ds
 
 
-def order_rows_for_passes(ds: "ShardedDataset") -> "ShardedDataset":
-    """:func:`order_rows_by_length` where it moves a number: a sparse dataset
-    whose all-rows passes run in row blocks (ops/rows.row_block, from the
-    shapes alone).  A set that one block holds (rcv1, every small set) keeps
-    its rows as built, and its runs stay what they were bit for bit."""
+def passes_want_order(ds: "ShardedDataset") -> bool:
+    """Where :func:`order_rows_by_length` moves a number and has not run: a
+    sparse dataset whose all-rows passes run in row blocks
+    (ops/rows.row_block, from the shapes alone)."""
     from cocoa_tpu.ops import rows
 
-    if (ds.layout == "sparse" and ds.row_order is None
+    return (ds.layout == "sparse" and ds.row_order is None
             and ds.sp_row_ptr is None and rows.row_block(
-            ds.n_shard, ds.sp_indices.shape[-1]) < ds.n_shard):
+            ds.n_shard, ds.sp_indices.shape[-1]) < ds.n_shard)
+
+
+def order_rows_for_passes(ds: "ShardedDataset") -> "ShardedDataset":
+    """:func:`order_rows_by_length` where :func:`passes_want_order`.  A set
+    that one block holds (rcv1, every small set) keeps its rows as built,
+    and its runs stay what they were bit for bit."""
+    if passes_want_order(ds):
         order_rows_by_length(ds)
     return ds
 

@@ -21,6 +21,7 @@ Every algorithm's round has the same communication shape (the reference's
 
 from __future__ import annotations
 
+import contextlib
 from collections import OrderedDict
 from typing import Callable, Optional
 
@@ -1101,7 +1102,11 @@ def drive_on_device(
 
     run_key = None if cache_key is None else (cache_key, stream)
     run = _DEVICE_RUNS.get(run_key) if run_key is not None else None
-    if run is None:
+    # the loop program's first call pays its trace, lower and compile (or
+    # cache load): build_loop around that dispatch, first_run around its
+    # fetch (telemetry/tracing.py, the cold path); a warm call opens neither
+    first_call = run is None
+    if first_call:
         run = _build_device_run(
             chunk_kernel, eval_kernel, tgt, n_state, mesh=mesh,
             stall_evals=stall_evals, divergence_guard=divergence_guard,
@@ -1132,19 +1137,25 @@ def drive_on_device(
         # the dispatch is where arguments go up: a spec the ladder built
         # on the host (device-mode sampling) is uploaded by it, sanctioned
         # like the stream's effect token
-        with _tracing.span("dispatch"), (
-                _sanitize.allow_transfers() if stream
-                else _sanitize.allow_uploads()):
-            head, state, traj_buf = run(
-                *state, idxs_all, shard_arrays, test_arrays)
-            _sanitize.count_launch()
+        with (_tracing.cold_span("build_loop") if first_call
+              else contextlib.nullcontext()) as cold:
+            if first_call:
+                cold.built(run, *state, idxs_all, shard_arrays, test_arrays)
+            with _tracing.span("dispatch"), (
+                    _sanitize.allow_transfers() if stream
+                    else _sanitize.allow_uploads()):
+                head, state, traj_buf = run(
+                    *state, idxs_all, shard_arrays, test_arrays)
+                _sanitize.count_launch()
         # the single host sync of the whole run — marked as the
         # sanctioned fetch point, so the transfer-guard sanitizer
         # (analysis/sanitize.py) can disallow every OTHER device→host
         # path and production --metrics runs count it
         # (host_transfers_total: ~1 per super-block, never per round)
-        head_host, traj_host = fetch_loop_result(head, traj_buf,
-                                                 "device_loop_fetch")
+        with (_tracing.cold_span("first_run") if first_call
+              else contextlib.nullcontext()):
+            head_host, traj_host = fetch_loop_result(head, traj_buf,
+                                                     "device_loop_fetch")
         n_done = len(traj_host)
         stop_tgt, stop_stall = bool(head_host[1]), bool(head_host[2])
         if stream:

@@ -25,7 +25,8 @@ import numpy as np
 from cocoa_tpu.analysis import sanitize as _sanitize
 from cocoa_tpu.config import DebugParams, Params
 from cocoa_tpu.data.sharding import (ShardedDataset, order_rows_for_passes,
-                                     rows_as_built, rows_as_ordered)
+                                     passes_want_order, rows_as_built,
+                                     rows_as_ordered)
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops import local_sdca
 from cocoa_tpu.ops import rows as _rows
@@ -792,7 +793,13 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     if all(v is None for v in (w_init, alpha_init, hist_init, sched_init)):
         key = (d, k, n_shard, str(dtype), arm, residual, mesh)
         start = _START_PROGRAMS.get(key)
-        if start is None:
+        target = ds.target if residual else None
+        _sanitize.count_launch()
+        if start is not None:
+            return start(sched, target)
+        # the process's first job of this shape: the one place that waits
+        # for the leaves, so the span's closing reading holds them
+        with _tracing.cold_span("build_start") as cold:
             def start(sched, target):
                 w = (jnp.zeros(d, dtype=dtype) if target is None
                      else (-target).astype(dtype))
@@ -804,8 +811,10 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
 
             start = _START_PROGRAMS[key] = jax.jit(
                 start, out_shardings=shardings)
-        _sanitize.count_launch()
-        return start(sched, ds.target if residual else None)
+            leaves = start(sched, target)
+            cold.built(start, sched, target)
+            cold.made(leaves)
+        return leaves
 
     if w_init is not None:
         w = jnp.array(w_init, dtype=dtype, copy=True)
@@ -828,6 +837,7 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     return tuple(leaves)
 
 
+@_tracing.cold_entry
 def run_sdca_family(
     ds: ShardedDataset,
     params: Params,
@@ -969,7 +979,10 @@ def run_sdca_family(
     # block's longest row, and rows in length order make that a half of the
     # slots.  Once per dataset, in place; α is in ds's order, here and after
     as_built = ds.row_order is None
-    order_rows_for_passes(ds)
+    if passes_want_order(ds):
+        with _tracing.cold_span("order_rows") as cold:
+            order_rows_for_passes(ds)
+            cold.made(ds.shard_arrays(), ds.row_order)
     if as_built and ds.row_order is not None:
         # what the caller holds by row is in the order ds had on entry
         alpha_init, hist_init = (
@@ -1069,8 +1082,10 @@ def run_sdca_family(
         if folded is None:
             from cocoa_tpu.ops.pallas_sdca import fold_rows
 
-            folded = fold_rows(shard_arrays["X"],
-                               row_major=path.rows == "row_major")
+            with _tracing.cold_span("fold_rows") as cold:
+                folded = fold_rows(shard_arrays["X"],
+                                   row_major=path.rows == "row_major")
+                cold.made(folded)
             ds._x_folded_cache = folded
         shard_arrays = {**shard_arrays, "X_folded": folded}
     if ((pallas or block_size > 0) and ds.layout == "sparse"
@@ -1084,7 +1099,9 @@ def run_sdca_family(
         if row_len is None:
             from cocoa_tpu.ops.pallas_sparse import row_lengths
 
-            row_len = row_lengths(shard_arrays["sp_values"])
+            with _tracing.cold_span("row_lengths") as cold:
+                row_len = row_lengths(shard_arrays["sp_values"])
+                cold.made(row_len)
             ds._row_len_cache = row_len
         shard_arrays = {**shard_arrays, "sp_row_len": row_len}
 
@@ -1103,12 +1120,17 @@ def run_sdca_family(
         # sanitize.intended_fetch), counted on the host
         launches = _sanitize.launches_total - counted[0]
         fetches = _sanitize.intended_fetches_total - counted[1]
+        # and, of a first job, what its cold branches took in seconds and
+        # in bytes (telemetry/tracing.py; empty for a warm job)
+        cold = _tracing.finish_job()
         traj.meta.update(solver_path=path.as_dict(),
                          vector_len=int(ds.num_features),
-                         launches=launches, fetches=fetches)
+                         launches=launches, fetches=fetches, cold=cold)
         if not quiet:
             print(f"drive ladder: {launches} programs launched, "
                   f"{fetches} host fetches")
+            if cold:
+                print(f"cold path: {_tracing.cold_line(cold)}")
 
     if theta not in ("fixed", "adaptive"):
         raise ValueError(f"theta must be fixed|adaptive, got {theta!r}")

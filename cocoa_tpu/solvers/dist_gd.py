@@ -93,6 +93,7 @@ def make_chunk_step(mesh, params: Params, k: int):
     return step
 
 
+@_tracing.cold_entry
 def run_dist_gd(
     ds: ShardedDataset,
     params: Params,

@@ -123,6 +123,7 @@ def make_chunk_step(mesh, params: Params, k: int, local: bool,
     return step
 
 
+@_tracing.cold_entry
 def run_sgd(
     ds: ShardedDataset,
     params: Params,

@@ -128,6 +128,7 @@ EVENT_FIELDS = {
              "parent_id": (int, type(None)),
              "worker": (int, type(None)),
              "start_ts": _NUM, "dur_s": _NUM},
+    # (a span of the cold path adds COLD_SPAN_FIELDS, checked below)
     # the JSONL sink hit its --eventsMaxMB cap and rolled to `.1`
     # (events.EventBus._rotate) — always the first event of a fresh file
     "events_rotate": {"path": (str,), "rotated_to": (str,),
@@ -352,6 +353,17 @@ RESULTS_FIELDS = {
 }
 
 
+# what a cold span's ``span`` event carries beside the span's own fields
+# (telemetry/tracing.py ColdSpan): the ordinal of the solver-entry call it
+# ran in (None outside one) and the HBM readings at open and at close, a
+# list with one object a device
+COLD_SPAN_FIELDS = {"job": (int, type(None)), "hbm_open": (list,),
+                    "hbm_close": (list,)}
+HBM_READING_FIELDS = {"device": (int,),
+                      "bytes_in_use": (int, type(None)),
+                      "peak_bytes_in_use": (int, type(None))}
+
+
 def _typecheck(obj, fields, where, errors, required=True):
     for name, types in fields.items():
         if name not in obj:
@@ -399,6 +411,17 @@ def check_event_lines(objs) -> list:
         if not isinstance(obj.get("ts"), _NUM):
             errors.append(f"{where}: missing/invalid ts")
         _typecheck(obj, EVENT_FIELDS[ev], where, errors)
+        if ev == "span" and any(k in obj for k in COLD_SPAN_FIELDS):
+            _typecheck(obj, COLD_SPAN_FIELDS, where, errors)
+            for key in ("hbm_open", "hbm_close"):
+                for reading in obj.get(key) or ():
+                    if not isinstance(reading, dict):
+                        errors.append(f"{where}: {key} holds a "
+                                      f"{type(reading).__name__}, expected "
+                                      f"an object a device")
+                    else:
+                        _typecheck(reading, HBM_READING_FIELDS,
+                                   f"{where}: {key}", errors)
         if ev == "run_start":
             man = obj.get("manifest")
             split = man.get("layout_split") if isinstance(man, dict) else None

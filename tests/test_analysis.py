@@ -416,6 +416,27 @@ def test_span_hygiene_span_in_jit_caught(tmp_path):
     assert "times the trace" in found[0].message
 
 
+COLD_SPAN_IN_JIT = """
+import jax
+from cocoa_tpu.telemetry import tracing as _tracing
+
+@jax.jit
+def fold(x):
+    with _tracing.cold_span("fold_rows") as cold:
+        y = x.reshape(-1, 8)
+        cold.made(y)
+        return y
+"""
+
+
+def test_span_hygiene_cold_span_in_jit_caught(tmp_path):
+    """A cold span reads the allocator and waits for the device: inside a
+    jit body it is an error like a span there."""
+    found = lint(tmp_path, COLD_SPAN_IN_JIT, rule="span-hygiene")
+    assert len(found) == 1 and found[0].severity == "error"
+    assert "`cold_span(...)` inside traced code" in found[0].message
+
+
 def test_span_hygiene_span_in_lax_body_caught(tmp_path):
     found = lint(tmp_path, SPAN_IN_LAX_BODY, rule="span-hygiene")
     assert len(found) == 1 and found[0].severity == "error"
