@@ -66,9 +66,16 @@ Block/alignment rules used:
   the grid's k index (their second-to-last dim is the full axis, which is
   always legal); they stay VMEM-resident across that shard's H steps and
   re-DMA only when k advances;
-- outputs (Δw, α) are per-shard blocks too: the kernel writes them at the
-  shard's last step and Pallas flushes each block to HBM when the grid
-  moves to the next shard — no cross-shard masking.
+- α leaves as per-shard blocks too: the kernel writes one at the shard's
+  last step and Pallas flushes it to HBM when the grid moves to the next
+  shard — no cross-shard masking;
+- Δw leaves as ONE (1, 8, d/8) block, the K shards' sum: every caller adds
+  the shards' Δw at once, so the kernel does, in its epilogue (shard 0
+  first, left to right, in the rows' dtype).  The block's index never
+  moves, so it stays in VMEM over the whole grid and leaves the kernel
+  once — K d-vectors a round are neither written, relaid nor read back
+  (PERF.md §6, PR 37).  Each chain's own accumulator stays a scratch of
+  its own: the step reads x·(w₀ + σ′Δw_k), never the other shards' work.
 """
 
 from __future__ import annotations
@@ -350,7 +357,8 @@ def _kernel(
     #   w_ref          (8, d8) VMEM: the replicated w₀ (margin base)
     #   stacked_in     (1, n_blocks, 3·LANES) VMEM: shard k's lane-blocked
     #                  [labels | sq_norms | alpha] concatenation
-    #   dw_ref         out (1, 8, d8) VMEM: shard k's Δw (flushed on k advance)
+    #   dw_ref         out (1, 8, d8) VMEM: the shards' summed Δw, one block
+    #                  for the whole grid (it leaves once, at the grid's end)
     #   alpha_ref      out (1, n_blocks, LANES) VMEM (flushed on k advance)
     #   dw_acc         scratch (8, d8) VMEM: this shard's Δw accumulator
     #   stacked_sc     scratch (n_blocks, 3·LANES): the advancing state
@@ -376,10 +384,20 @@ def _kernel(
                  step if exact else jnp.minimum(step, h - 1),
                  None if exact else step < h, w_ref, **step_kw)
 
-    @pl.when(i == n_groups - 1)
+    last = i == n_groups - 1
+
+    @pl.when(last)
     def _flush_shard():
-        dw_ref[0] = dw_acc[...]
         alpha_ref[0] = stacked_sc[:, 2 * LANES:]
+
+    # the shards' sum, shard 0 first: the out block is the grid's one
+    @pl.when(last & (k_ == 0))
+    def _first_shard():
+        dw_ref[0] = dw_acc[...]
+
+    @pl.when(last & (k_ > 0))
+    def _add_shard():
+        dw_ref[0] = dw_ref[0] + dw_acc[...]
 
 
 def _kernel_interleaved(
@@ -423,8 +441,11 @@ def _kernel_interleaved(
 
     @pl.when(i == n_groups - 1)
     def _flush():
+        dw_sum = dw_accs[0][...]
+        for kk in range(1, k):          # shard 0 first, left to right
+            dw_sum = dw_sum + dw_accs[kk][...]
+        dw_ref[0] = dw_sum
         for kk in range(k):
-            dw_ref[kk] = dw_accs[kk][...]
             alpha_ref[kk] = st_scs[kk][:, 2 * LANES:]
 
 
@@ -451,8 +472,10 @@ def pallas_sdca_round(
     interleave=None,
 ):
     """One SDCA round for K shards on this chip.  Returns (dw, alpha_inner):
-    dw (K, d) unreduced per-shard updates; alpha_inner (K, n_shard) the
-    locally-advanced alpha (callers apply the outer scaling law).
+    dw (1, d) the K shards' updates summed (shard 0 first, in X's dtype:
+    the contract of the HBM-state and the stream kernels); alpha_inner
+    (K, n_shard) the locally-advanced alpha (callers apply the outer
+    scaling law).
 
     ``unroll`` = coordinate steps per grid iteration (0 = auto: the largest
     of 16/8/4/2/1 whose row blocks fit the VMEM budget).  Any value yields
@@ -566,7 +589,7 @@ def pallas_sdca_round(
                              lambda i_, idxs_: (0, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((k, SUBLANES, d8), lambda i_, idxs_: (0, 0, 0)),
+                pl.BlockSpec((1, SUBLANES, d8), lambda i_, idxs_: (0, 0, 0)),
                 pl.BlockSpec((k, n_blocks, LANES),
                              lambda i_, idxs_: (0, 0, 0)),
             ],
@@ -592,7 +615,7 @@ def pallas_sdca_round(
             ],
             out_specs=[
                 pl.BlockSpec((1, SUBLANES, d8),
-                             lambda k_, i_, idxs_: (k_, 0, 0)),
+                             lambda k_, i_, idxs_: (0, 0, 0)),
                 pl.BlockSpec((1, n_blocks, LANES),
                              lambda k_, i_, idxs_: (k_, 0, 0)),
             ],
@@ -608,7 +631,7 @@ def pallas_sdca_round(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((k, SUBLANES, d8), dtype),
+            jax.ShapeDtypeStruct((1, SUBLANES, d8), dtype),
             jax.ShapeDtypeStruct((k, n_blocks, LANES), dtype),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -617,4 +640,8 @@ def pallas_sdca_round(
         interpret=interpret,
     )(idxs, *([X_folded] * n_row_ops), w_folded, stacked)
     alpha_inner = alpha_blocked.reshape(k, n_pad)[:, :n_shard]
-    return dw.reshape(k, d)[:, :d_orig], alpha_inner
+    # unfolded and cut to length as a 1-D vector, THEN given its axis of
+    # one: a (1, d) row is tiled a sublane a vreg, and cutting that to
+    # length cost 21 us for 1.6 MB at the lasso, as much as summing K = 8 of
+    # them had (PERF.md §6, PR 37); 1-D, the cut rides the caller's add
+    return dw.reshape(d)[:d_orig][None], alpha_inner

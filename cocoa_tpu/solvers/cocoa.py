@@ -40,8 +40,9 @@ def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
     precomputed as one MXU matvec, folded-row X) or sparse kernel (margins
     read in-kernel from the VMEM-resident w; ``state="hbm"``: the kernel
     whose w, Δw and α stay in HBM, ops/pallas_sparse_hbm.py).  Returns
-    (dw (K, d) — (1, d), already summed, from the HBM-state kernel —
-    alpha_inner (K, n_shard))."""
+    (dw, alpha_inner (K, n_shard)): dw's rows add up to the K shards' Δw —
+    (1, d), summed by the kernel, from the dense, the HBM-state and the
+    stream kernels; (K, d) from the VMEM-resident sparse kernel."""
     common = dict(mode=mode, sigma=sigma, interpret=interpret,
                   loss=params.loss, smoothing=params.smoothing)
     if "sp_row_ptr" in shards:

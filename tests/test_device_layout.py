@@ -25,6 +25,7 @@ time may load the TPU's library, and every xdist worker imports this file.
 """
 
 import dataclasses
+import functools
 import os
 import re
 
@@ -124,6 +125,13 @@ def _on_chip(args, chip):
     return jax.tree.map(spec, args)
 
 
+def _shape_on(chip, shape, dtype="float32"):
+    """One kernel argument as a shape on the described chip."""
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
 def _row_copies(hlo: str, d: int) -> list:
     """Names of the entry parameters of the rows' shapes that some
     ``copy(`` takes as its operand."""
@@ -195,10 +203,7 @@ def test_logistic_kernel_compiles_at_epsilon_size(one_chip):
 
     k, n_shard, d, h = 8, 50000, 2000, 5000
     assert pallas_sdca.pick_interleave(k, n_shard, d, 4, h) == 2
-
-    def on_chip(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    on_chip = functools.partial(_shape_on, one_chip)
     rows = on_chip((k, n_shard))
     with jax.enable_x64(False):
         hlo = pallas_sdca.pallas_sdca_round.lower(
@@ -206,6 +211,64 @@ def test_logistic_kernel_compiles_at_epsilon_size(one_chip):
             rows, on_chip((k, h), jnp.int32), 1e-3, k * n_shard,
             mode="plus", sigma=float(k), loss="logistic").compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+# --- the dense kernel hands back ONE summed dw: nothing relays K of them ----
+
+# (k, n_shard, the shared vector's length, d/8 as stored, H, the form, the
+# step's keywords): the lasso's column shards (rows of 1.6 MB, lane-padded:
+# shard-major), epsilon's and one chip's of x4 (interleaved), as PERF.md §4
+# has them
+SUMMED_DW = {
+    "epsilon_lasso": (8, 256, 400_000, 50048, 25, "shard_major",
+                      dict(mode="prox", sigma=8.0, loss="lasso",
+                           smoothing=0.0)),
+    "epsilon": (8, 50000, 2000, 250, 5000, "interleaved",
+                dict(mode="plus", sigma=8.0)),
+    # imagenet.cocoa_plus.x4, one chip's two shards of the mesh's eight
+    "imagenet_x4_chip": (2, 4094, 160_000, 20096, 409, "interleaved",
+                         dict(mode="plus", sigma=8.0)),
+}
+
+
+@pytest.mark.parametrize("name", list(SUMMED_DW))
+def test_dense_kernel_returns_one_dw_and_nothing_sums_k(one_chip, name):
+    """The round as its caller takes it (``w + dw.sum(axis=0)`` of the
+    kernel's result), compiled for the chip: the ``pallas_call``'s first
+    result is ONE (1, 8, d/8) block; no op of the program holds K folded
+    vectors — the K blocks written, relaid (``copy``) and summed
+    (``reduce``) every round until PR 37; and none holds the vector as a
+    one-row (1, d) matrix, which the chip tiles a sublane a vreg and cuts
+    to length in 21 us at the lasso whatever K was (PERF.md §6, PR 37:
+    the kernel's result is unfolded and cut as a 1-D vector)."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    k, n_shard, d, d8, h, form, step = SUMMED_DW[name]
+    assert pallas_sdca.dense_form(k, n_shard, 8 * d8, 4, h) == form
+    on_chip = functools.partial(_shape_on, one_chip)
+
+    def round_apply(w, alpha, X, labels, sq_norms, idxs):
+        dw, a = pallas_sdca.pallas_sdca_round(
+            w, alpha, X, labels, sq_norms, idxs, 1e-3, 1, **step)
+        return w + dw.sum(axis=0), a
+
+    rows = on_chip((k, n_shard))
+    with jax.enable_x64(False):
+        hlo = jax.jit(round_apply).lower(
+            on_chip((d,)), rows, on_chip((k, n_shard, 8, d8)), rows, rows,
+            on_chip((k, h), jnp.int32)).compile().as_text()
+    call = re.search(r"= \((f32\[[\d,]+\])\S*, f32\[[\d,]+\]\S*\) "
+                     r"custom-call\(.*custom_call_target=\"tpu_custom_call\"",
+                     hlo)
+    assert call and call.group(1) == f"f32[1,8,{d8}]", call
+    k_vectors = re.compile(rf"f32\[(1,)?{k},8,{d8}\]")
+    one_row = re.compile(rf"f32\[1,({d}|{8 * d8})\]")
+    held = [line.strip()[:160] for line in hlo.splitlines()
+            if k_vectors.search(line) or one_row.search(line)]
+    assert held == [], held
 
 
 # --- the sparse deployment that fills a chip (kddb), with no chip -----------

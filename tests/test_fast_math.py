@@ -76,6 +76,7 @@ def test_pallas_interpret_matches_xla_fast(tiny_data, mode, sigma):
         mode=mode, sigma=sigma, interpret=True,
     )
     m0 = jnp.einsum("knd,d->kn", ds.X, w)
+    dw_sum = jnp.zeros(d, dtype=jnp.float64)
     for s in range(k):
         shard = {kk: v[s] for kk, v in ds.shard_arrays().items()}
         da, dw = local_sdca_fast(
@@ -84,15 +85,23 @@ def test_pallas_interpret_matches_xla_fast(tiny_data, mode, sigma):
         )
         # in-kernel margins reduce x·w in a different order than the
         # einsum the fast path precomputes — x64 agreement to ~1e-13
-        np.testing.assert_allclose(np.asarray(dw_p[s]), np.asarray(dw),
-                                   atol=1e-12)
+        dw_sum = dw_sum + dw
         np.testing.assert_allclose(np.asarray(a_p[s] - alpha[s]),
                                    np.asarray(da), atol=1e-12)
+    # the kernel hands back the shards' sum, one row
+    np.testing.assert_allclose(np.asarray(dw_p), np.asarray(dw_sum)[None],
+                               atol=1e-12)
 
 
-def _logistic_round_matches_fast(tiny_data, k, h, mode, sigma, **kernel):
-    """One logistic round of the dense Pallas kernel (f64, interpreted)
-    against ``local_sdca_fast`` on every shard."""
+def _round_matches_fast(tiny_data, k, h, mode, sigma, loss="logistic",
+                        lam=0.01, n=None, **kernel):
+    """One round of the dense Pallas kernel (f64, interpreted) against
+    ``local_sdca_fast`` on every shard: α shard by shard — what still pins
+    each chain by itself — and Δw as the shards' sum, the one (1, d) row
+    the kernel returns."""
+    n = n or tiny_data.n
+    step = dict(mode=mode, sigma=sigma, loss=loss,
+                smoothing=0.0 if loss == "lasso" else 1.0)
     ds = shard_dataset(tiny_data, k=k, layout="dense", dtype=jnp.float64)
     rng = np.random.default_rng(2)
     d = tiny_data.num_features
@@ -104,20 +113,25 @@ def _logistic_round_matches_fast(tiny_data, k, h, mode, sigma, **kernel):
         sample_indices_per_shard(5, range(1, 2), h, ds.counts)[:, 0, :]
     )
     dw_p, a_p = pallas_sdca_round(
-        w, alpha, ds.X, ds.labels, ds.sq_norms, idxs, 0.01, tiny_data.n,
-        mode=mode, sigma=sigma, interpret=True, loss="logistic", **kernel,
+        w, alpha, ds.X, ds.labels, ds.sq_norms, idxs, lam, n,
+        interpret=True, **step, **kernel,
     )
     assert np.all(np.isfinite(np.asarray(a_p)))
     fast = jax.jit(jax.vmap(lambda m0, a, shard, ix: local_sdca_fast(
-        m0, a, shard, ix, 0.01, tiny_data.n, jnp.zeros(d, dtype=jnp.float64),
-        mode=mode, sigma=sigma, loss="logistic")))
+        m0, a, shard, ix, lam, n, jnp.zeros(d, dtype=jnp.float64), **step)))
     da, dw = fast(jnp.einsum("knd,d->kn", ds.X, w), alpha,
                   ds.shard_arrays(), idxs)
-    np.testing.assert_allclose(np.asarray(dw_p), np.asarray(dw), atol=1e-12)
+    assert dw_p.shape == (1, d) and a_p.shape == alpha.shape
+    np.testing.assert_allclose(np.asarray(dw_p),
+                               np.asarray(dw.sum(axis=0, keepdims=True)),
+                               atol=1e-12)
     np.testing.assert_allclose(np.asarray(a_p - alpha), np.asarray(da),
                                atol=1e-12)
-    moved = float(jnp.max(jnp.abs(da)))
-    assert moved > 1e-3     # the round stepped: equality is not 0 == 0
+    # every shard stepped (equality is not 0 == 0), and no shard's Δw is
+    # the whole sum
+    assert float(jnp.min(jnp.max(jnp.abs(da), axis=1))) > 1e-4
+    if k > 1:
+        assert float(jnp.max(jnp.abs(dw.sum(axis=0) - dw[0]))) > 1e-6
 
 
 @pytest.mark.parametrize("k", [1, 3, 4])
@@ -131,16 +145,38 @@ def test_pallas_logistic_interpret_matches_xla_fast(tiny_data, interleave,
     lanes beside the chains, K = 1 is one lane whichever kernel runs, and
     ``interleave=False`` is the shard-major kernel's one-lane solve.
     Step groups of 2, as at epsilon."""
-    _logistic_round_matches_fast(tiny_data, k, 30, mode, sigma,
-                                 interleave=interleave, unroll=2)
+    _round_matches_fast(tiny_data, k, 30, mode, sigma,
+                        interleave=interleave, unroll=2)
 
 
 @pytest.mark.parametrize("interleave", [True, False])
 def test_pallas_logistic_inert_tail(tiny_data, interleave):
     """``unroll`` ∤ H: the group past H solves its lanes like any other and
     its ``live`` mask drops the result."""
-    _logistic_round_matches_fast(tiny_data, 3, 31, "plus", 3.0,
-                                 interleave=interleave, unroll=4)
+    _round_matches_fast(tiny_data, 3, 31, "plus", 3.0,
+                        interleave=interleave, unroll=4)
+
+
+# mode -> the step's other parameters: the SVM family's two scaling laws
+# and the L1 family's prox step (solvers/prox_cocoa.py: n = 1, the lasso
+# rule)
+_SUMMED = {"plus": dict(sigma=4.0, loss="hinge"),
+           "cocoa": dict(sigma=1.0, loss="hinge"),
+           "prox": dict(sigma=4.0, loss="lasso", lam=0.05, n=1)}
+
+
+@pytest.mark.parametrize("mode", list(_SUMMED))
+@pytest.mark.parametrize("h", [12, 13])     # groups of 4: exact, and a tail
+@pytest.mark.parametrize("k", [1, 2, 4])
+@pytest.mark.parametrize("interleave", [True, False])
+def test_pallas_hands_back_the_shards_summed_dw(tiny_data, interleave, k, h,
+                                                mode):
+    """Both forms of the dense kernel add the K shards' Δw into ONE out
+    block in their epilogue, under every family's step.
+    ``interleave=True`` at K = 1 is a sum of one; h = 13 leaves the last
+    group of 4 an inert tail."""
+    _round_matches_fast(tiny_data, k, h, mode, **_SUMMED[mode],
+                        interleave=interleave, unroll=4)
 
 
 @pytest.mark.slow

@@ -107,6 +107,28 @@ def test_multiplexed_block_kernel_interpret(tiny_data):
     np.testing.assert_allclose(np.asarray(w_m), np.asarray(w_l), atol=1e-12)
 
 
+def test_multiplexed_dense_pallas_kernel_interpret(tiny_data):
+    """K = 4 shards over two devices, the dense Pallas kernel: each
+    device's ``per_round_batched`` call takes its two local shards and the
+    kernel hands back their Δw summed, one (1, d) row, into the one psum
+    a round — the w and α of the one-device run, where the same kernel
+    sums all four."""
+    k, devices = 4, 2
+    p = _params(tiny_data, num_rounds=4)
+    mesh = make_mesh(devices)
+    assert shards_per_device(mesh, k) == 2
+    ds_m = shard_dataset(tiny_data, k=k, layout="dense", dtype=jnp.float64,
+                         mesh=mesh)
+    ds_l = shard_dataset(tiny_data, k=k, layout="dense", dtype=jnp.float64)
+    kw = dict(plus=True, quiet=True, math="fast", pallas=True, scan_chunk=2)
+    w_m, a_m, traj = run_cocoa(ds_m, p, _debug(), mesh=mesh, **kw)
+    w_l, a_l, _ = run_cocoa(ds_l, p, _debug(), **kw)
+    assert traj.meta["solver_path"]["kernel"] == "pallas"
+    np.testing.assert_allclose(np.asarray(w_m), np.asarray(w_l), atol=1e-12)
+    np.testing.assert_allclose(np.asarray(a_m), np.asarray(a_l), atol=1e-12)
+    assert float(jnp.max(jnp.abs(a_l))) > 0
+
+
 def test_multiplexed_sgd(tiny_data):
     """The SGD family (TsSampler xs with a scalar t leaf) multiplexes."""
     p = _params(tiny_data)

@@ -110,6 +110,41 @@ def test_prox_fast_and_paths_match_exact(l2):
                                    err_msg=str(kw))
 
 
+# rows of A (the shared vector's length) -> the form the VMEM fit alone
+# picks for eight shards of two columns in x64: 48,000-long vectors, all
+# eight chains' beside each other, pass the interleaved kernel's 14 MiB
+@pytest.mark.parametrize("rows,form", [(96, "interleaved"),
+                                       (48_000, "shard_major")])
+def test_lasso_job_through_both_dense_forms_matches_fori(rows, form):
+    """A lasso job through the dense Pallas kernel, whose (1, n) result is
+    the eight shards' Δv summed in its epilogue, in the form the shape
+    resolves to — no flag picks it — against the ``fori`` path: x, r and
+    every certificate of the trajectory."""
+    from cocoa_tpu.ops import pallas_sdca
+
+    k = 8
+    A, b, _, data = _problem(seed=4, n=rows, d=16, sparsity=4)
+    lam = 0.1 * np.max(np.abs(A.T @ b))
+    p = _params(data.num_features, float(lam), num_rounds=10, local_iters=3)
+    ds = shard_columns(data, k, dtype=jnp.float64)
+    assert pallas_sdca.dense_form(k, ds.n_shard, ds.num_features, 8,
+                                  3) == form
+    kw = dict(quiet=True, math="fast", scan_chunk=5)
+    x0, r0, fori = run_prox_cocoa(ds, p, _DBG, pallas=False, **kw)
+    x1, r1, traj = run_prox_cocoa(ds, p, _DBG, pallas=True, **kw)
+    path = traj.meta["solver_path"]
+    assert (path["kernel"], path["form"]) == ("pallas", form)
+    assert fori.meta["solver_path"]["kernel"] != "pallas"
+    assert np.count_nonzero(np.asarray(x0)) > 0
+    np.testing.assert_allclose(np.asarray(x1), np.asarray(x0), atol=1e-10)
+    np.testing.assert_allclose(np.asarray(r1), np.asarray(r0), atol=1e-10)
+    assert [rec.round for rec in traj.records] == [
+        rec.round for rec in fori.records]
+    np.testing.assert_allclose([rec.gap for rec in traj.records],
+                               [rec.gap for rec in fori.records],
+                               rtol=1e-9, atol=1e-10)
+
+
 @pytest.mark.slow
 def test_prox_sparse_columns_match_dense():
     """The padded-CSC column layout must produce exactly the dense column
