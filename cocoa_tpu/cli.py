@@ -180,7 +180,8 @@ _EXTRA_FLAGS = ("mesh", "fp", "trajOut", "gapTarget", "resume", "scanChunk",
                 "staleRounds", "fleet", "fleetLanes",
                 "serve", "serveBatch", "serveSlaMs",
                 "serveMaxNnz", "serveDtype", "serveReplicas",
-                "serveRoute", "traceSample", "statusPort")  # run-level
+                "serveRoute", "traceSample", "statusPort",
+                "classes")  # run-level
 
 _BOOL_FIELDS = {"just_cocoa"}
 _INT_FIELDS = {"num_features", "num_splits", "chkpt_iter", "num_rounds",
@@ -412,6 +413,11 @@ def main(argv=None) -> int:
                            "ref)",
             "blockSize": "the block/Pallas kernels own their shard axes "
                          "and cannot ride the tenant vmap",
+            "classes": "a fleet's tenants are independent models, each "
+                       "over its own copy of its rows; T class models "
+                       "over ONE copy of the rows are a solo job's "
+                       "(--classes without --fleet; docs/DESIGN.md, "
+                       "one-vs-rest)",
         }
         if cfg.test_file:
             print("error: --testFile does not combine with --fleet: "
@@ -504,6 +510,8 @@ def main(argv=None) -> int:
                          "(cocoa_model_gap_age_seconds)",
             "resume": "the server always serves the newest validated "
                       "generation; there is nothing to resume",
+            "classes": "the server scores one model's margins; a "
+                       "one-vs-rest job's T models are not served yet",
             "ingestCache": "the slab cache serves TRAINING ingest; put "
                            "--ingestCache on the background trainer's "
                            "command line (the serve-side --trainFile "
@@ -1092,6 +1100,50 @@ def main(argv=None) -> int:
                else str(extras["evalDense"]).lower())
     eval_dense = ed_spec not in ("false", "auto")
 
+    # --classes=auto|<T>: the train (and test) file is multi-class and is
+    # loaded as one — class ids beside the reference's +-1 labels — and
+    # CoCoA / CoCoA+ train T models one-vs-rest over the one copy of the
+    # rows (solvers/cocoa.run_cocoa).  The one loader-side flag; what the
+    # class axis is not carried through yet is refused here, loudly
+    classes_flag = extras["classes"]
+    if classes_flag is not None:
+        why = None
+        if str(classes_flag).lower() != "auto":
+            try:
+                if int(classes_flag) < 2:
+                    raise ValueError
+            except ValueError:
+                why = (f"--classes takes auto or a class count >= 2, got "
+                       f"{classes_flag!r}")
+        if objective != "svm":
+            why = ("--classes trains SVMs one-vs-rest; --objective=lasso "
+                   "regresses on one target and has no class axis")
+        elif not cfg.just_cocoa:
+            why = ("--classes trains CoCoA / CoCoA+ one-vs-rest; the "
+                   "primal baselines carry no class axis: pass "
+                   "--justCoCoA=true")
+        elif mesh is not None:
+            why = ("--classes trains on one chip (--mesh=1): the class "
+                   "axis is not carried across a mesh")
+        elif cfg.layout == "sparse" or extras["hotCols"] is not None \
+                or ed_spec != "false":
+            why = ("--classes needs dense rows (--layout=dense): no kernel "
+                   "carries the class axis on sparse rows yet, so "
+                   "--layout=sparse, --hotCols and --evalDense do not "
+                   "apply")
+        elif extras["ingestCache"] or (extras["ingest"] or "auto") \
+                not in ("auto", "whole"):
+            why = ("--classes reads the file whole: --ingest=stream and "
+                   "--ingestCache keep no class ids")
+        elif extras["resume"] or cfg.chkpt_iter > 0 and cfg.chkpt_dir:
+            why = ("--classes writes and resumes no checkpoints yet (they "
+                   "hold one model's w and alpha): drop --resume / "
+                   "--chkptIter")
+        if why:
+            print(f"error: {why} (docs/DESIGN.md, one-vs-rest)",
+                  file=sys.stderr)
+            return 2
+
     # --ingest=stream|whole|auto: how the LIBSVM text reaches the device
     # (data/ingest.py).  Resolved against the mesh/objective BEFORE any
     # parse so a streamed run never pays a whole-file pass by accident.
@@ -1138,6 +1190,8 @@ def main(argv=None) -> int:
     except ValueError as e:
         print(f"error: {e}", file=sys.stderr)
         return 2
+    if classes_flag is not None:
+        ingest_mode = "whole"       # (auto resolved: the class ids' path)
 
     from cocoa_tpu.data import resolve_hot_cols, resolve_layout
 
@@ -1423,12 +1477,25 @@ def main(argv=None) -> int:
 
         if warm is None:
             try:
-                data = load_libsvm(cfg.train_file, cfg.num_features)
+                data = load_libsvm(cfg.train_file, cfg.num_features,
+                                   classes=classes_flag)
             except (OSError, ValueError) as e:  # missing file, bad
-                # numFeatures
+                # numFeatures, a class count the file contradicts
                 print(f"error: {e}", file=sys.stderr)
                 return 2
             n = data.n
+            if data.num_classes > 1:
+                if resolve_layout(data, cfg.layout, mesh) != "dense":
+                    print(f"error: --classes needs dense rows and "
+                          f"--layout={cfg.layout} resolves sparse for this "
+                          f"file: pass --layout=dense (no kernel carries "
+                          f"the class axis on sparse rows yet)",
+                          file=sys.stderr)
+                    return 2
+                if not quiet:
+                    print(f"classes: {data.num_classes} found "
+                          f"({list(data.class_values)[:12]}), trained "
+                          f"one-vs-rest over the one copy of the rows")
 
             # --hotCols=auto|off|<n>: the hot/cold column split (sparse
             # layout only, data/hybrid.py).  Resolved HERE — against the
@@ -1505,8 +1572,15 @@ def main(argv=None) -> int:
                     test_ds, _, rep = test_warm
                     ingest_reports.append(rep)
                 else:
-                    test_data = load_libsvm(cfg.test_file,
-                                            cfg.num_features)
+                    test_data = load_libsvm(
+                        cfg.test_file, cfg.num_features,
+                        classes=(None if classes_flag is None
+                                 else data.num_classes))
+                    if test_data.class_values != data.class_values:
+                        raise ValueError(
+                            f"{cfg.test_file}: its class labels "
+                            f"{test_data.class_values} are not the "
+                            f"training file's {data.class_values}")
                     snap = cache_snap()
                     test_ds = shard_dataset(test_data, k=k,
                                             layout=cfg.layout,
@@ -1752,21 +1826,33 @@ def main(argv=None) -> int:
         return out
 
     def finish(traj, w, alpha=None):
-        primal = objectives.primal_objective(ds, w, params.lam,
-                                             params.loss, params.smoothing)
-        gap = (
-            primal - objectives.dual_objective(ds, w, alpha, params.lam,
-                                               params.loss, params.smoothing)
-            if alpha is not None
-            else None
-        )
-        err = (
-            objectives.classification_error(test_ds, w)
-            if test_ds is not None
-            else None
-        )
+        gaps = None
+        if ds.num_classes > 1:
+            # one-vs-rest: the worst class's objectives (what the job was
+            # stopped on), the multi-class test error, every class's gap
+            primal, gap, err, gaps = objectives.evaluate(
+                ds, w, alpha, params.lam, test_ds=test_ds, loss=params.loss,
+                smoothing=params.smoothing)
+        else:
+            primal = objectives.primal_objective(ds, w, params.lam,
+                                                 params.loss,
+                                                 params.smoothing)
+            gap = (
+                primal - objectives.dual_objective(ds, w, alpha, params.lam,
+                                                   params.loss,
+                                                   params.smoothing)
+                if alpha is not None
+                else None
+            )
+            err = (
+                objectives.classification_error(test_ds, w)
+                if test_ds is not None
+                else None
+            )
         traj.meta.update(run_meta)
         traj.summary(primal, gap=gap, test_error=err)
+        if gaps is not None and not quiet:
+            print(f" Duality gap by class: {gaps}\n")
         if extras["trajOut"]:
             path = f"{extras['trajOut']}.{traj.algorithm.replace(' ', '_')}.jsonl"
             traj.dump_jsonl(path)

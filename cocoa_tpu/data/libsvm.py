@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
+from typing import Optional
 
 import numpy as np
 
@@ -56,6 +57,12 @@ class LibsvmData:
     indices: np.ndarray    # (nnz,) int32
     values: np.ndarray     # (nnz,) float64
     num_features: int
+    # a multi-class file read as one (:func:`load_libsvm` ``classes=``):
+    # class ids 0..T-1 BESIDE the +-1 ``labels`` (the reference's rule,
+    # kept), the count T, and the file's own label value of each id
+    classes: Optional[np.ndarray] = None   # (n,) int32
+    num_classes: int = 1
+    class_values: Optional[tuple] = None   # (T,) the labels as the file has them
 
     @property
     def n(self) -> int:
@@ -247,8 +254,65 @@ def _validate(data: LibsvmData, path: str) -> LibsvmData:
     return data
 
 
-def load_libsvm(path: str, num_features: int, prefer_native: bool = True) -> LibsvmData:
-    """Parse a LIBSVM file; uses the C++ fast path when available."""
+def read_classes(path: str, expect=None) -> tuple:
+    """``(class ids (n,) int32, T, the file's label of each id)`` of a
+    multi-class LIBSVM file: the first token of every non-blank line, read
+    as a number (LIBSVM's multi-class labels are integers: 0..9, 1..T, any
+    set of them), the distinct values in ascending order numbered 0..T-1.
+    ``expect`` (an int): the count the caller states; a file that holds
+    another count is refused with both numbers."""
+    values = []
+    with open(path, "rb") as f:
+        for raw in f:
+            parts = raw.split(None, 1)
+            if not parts:
+                continue
+            token = parts[0].decode("ascii", "replace")
+            try:
+                if not _NUM_CHARS.issuperset(token):
+                    raise ValueError
+                values.append(float(token))
+            except ValueError:
+                raise ValueError(
+                    f"{path}: row {len(values) + 1} has the label "
+                    f"{token!r}; a multi-class file's labels are numbers"
+                ) from None
+    # jaxlint: allow=f64 -- host-side parse of the label column
+    found, ids = np.unique(np.asarray(values, np.float64),
+                           return_inverse=True)
+    if expect is not None and len(found) != int(expect):
+        raise ValueError(
+            f"{path}: {int(expect)} classes were stated and the file holds "
+            f"{len(found)} distinct labels ({found[:12].tolist()}"
+            f"{' ...' if len(found) > 12 else ''}) over {len(values)} rows")
+    if len(found) < 2:
+        raise ValueError(
+            f"{path}: a multi-class file needs at least two distinct "
+            f"labels, found {found.tolist()}")
+    return (ids.astype(np.int32), len(found),
+            tuple(int(v) if float(v).is_integer() else float(v)
+                  for v in found))
+
+
+def load_libsvm(path: str, num_features: int, prefer_native: bool = True,
+                classes=None) -> LibsvmData:
+    """Parse a LIBSVM file; uses the C++ fast path when available.
+
+    ``classes`` (None | ``"auto"`` | an int T): None keeps the reference's
+    binary rule alone (a label that parses to 1 is +1, anything else -1).
+    Asked for classes, the result ALSO carries integer class ids and the
+    count found (:func:`read_classes`; ``labels`` are unchanged), and a
+    job over it trains one model per class, one-vs-rest
+    (solvers/cocoa.run_cocoa); an int is the count the caller expects."""
+    if classes is not None:
+        data = load_libsvm(path, num_features, prefer_native)
+        expect = None if str(classes).lower() == "auto" else int(classes)
+        ids, count, found = read_classes(path, expect)
+        if len(ids) != data.n:
+            raise ValueError(f"{path}: {len(ids)} labelled rows against "
+                             f"{data.n} parsed rows")
+        return dataclasses.replace(data, classes=ids, num_classes=count,
+                                   class_values=found)
     if prefer_native:
         from cocoa_tpu.data import native_loader
 

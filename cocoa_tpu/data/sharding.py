@@ -178,6 +178,18 @@ class ShardedDataset:
                                       #   target b, replicated like the
                                       #   residual it is the start of; no
                                       #   K axis, so not in shard_arrays()
+    classes: Optional[jax.Array] = None  # (K, n_shard) int32 class ids in
+                                      #   [0, num_classes) of a multi-class
+                                      #   set, BESIDE ``labels`` (which keep
+                                      #   the reference's +-1 rule): the
+                                      #   label of class t against the rest,
+                                      #   +1 where classes == t else -1, is
+                                      #   derived where it is used, never
+                                      #   stored T times (:func:`class_labels`)
+    num_classes: int = 1              # T: how many models a job over these
+                                      #   rows trains, one-vs-rest.  1 (every
+                                      #   binary set): ``classes`` is None
+                                      #   and nothing of the class axis runs
 
     @property
     def k(self) -> int:
@@ -203,6 +215,8 @@ class ShardedDataset:
             "mask": self.mask,
             "sq_norms": self.sq_norms,
         }
+        if self.classes is not None:
+            out["classes"] = self.classes
         if self.layout == "dense":
             out["X"] = self.X
         else:
@@ -231,17 +245,18 @@ class ShardedDataset:
             self.labels, self.mask, self.sq_norms,
             self.X, self.sp_indices, self.sp_values, self.X_eval,
             self.X_hot, self.hot_cols, self.row_order, self.sp_row_ptr,
-            self.sp_row_len, self.sp_row_iota, self.target,
+            self.sp_row_len, self.sp_row_iota, self.target, self.classes,
         )
-        aux = (self.layout, self.n, self.num_features, tuple(self.counts))
+        aux = (self.layout, self.n, self.num_features, tuple(self.counts),
+               self.num_classes)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         (labels, mask, sq_norms, X, sp_indices, sp_values, X_eval,
          X_hot, hot_cols, row_order, sp_row_ptr, sp_row_len,
-         sp_row_iota, target) = children
-        layout, n, num_features, counts = aux
+         sp_row_iota, target, classes) = children
+        layout, n, num_features, counts, num_classes = aux
         return cls(
             layout=layout,
             n=n,
@@ -261,7 +276,18 @@ class ShardedDataset:
             sp_row_len=sp_row_len,
             sp_row_iota=sp_row_iota,
             target=target,
+            classes=classes,
+            num_classes=num_classes,
         )
+
+
+def class_labels(classes: jax.Array, mask: jax.Array, t) -> jax.Array:
+    """The labels of class ``t`` against the rest over rows whose class ids
+    are ``classes``: +1 where the id is ``t``, -1 elsewhere, 0 on padding
+    (``mask``), in ``mask``'s dtype.  ``t`` may be traced, or an array that
+    broadcasts against ``classes`` (a leading class axis gives every
+    class's labels at once)."""
+    return jnp.where(classes == t, 1.0, -1.0).astype(mask.dtype) * mask
 
 
 try:
@@ -395,8 +421,10 @@ def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
     row (ops/rows.SLOT_GROUP).  α, and anything else kept by row, is in the
     dataset's order from here on; :func:`rows_as_built` maps it back."""
     if (ds.layout != "sparse" or ds.row_order is not None
-            or ds.sp_row_ptr is not None):
-        return ds               # (a stream's passes go by nonzeros as it is)
+            or ds.sp_row_ptr is not None or ds.classes is not None):
+        return ds               # (a stream's passes go by nonzeros as it is;
+                                # class ids are kept as built: no solver
+                                # carries the class axis on sparse rows yet)
     from cocoa_tpu.ops.pallas_sparse import row_lengths
 
     row_len = getattr(ds, "_row_len_cache", None)
@@ -778,6 +806,12 @@ def shard_dataset(
         and jax.process_count() > 1
         and not mesh_lib.has_fp(mesh)
     ):
+        if getattr(data, "num_classes", 1) > 1:
+            raise ValueError(
+                "a multi-class set (class ids beside the labels) is built "
+                "by one process: the per-process shard builder does not "
+                "carry the class ids (one-vs-rest runs on one chip, "
+                "docs/DESIGN.md)")
         if k % mesh.devices.size != 0:
             # the multiplexed distributed builder stacks m = K/D shards
             # per device; a non-divisor D has no even placement — the same
@@ -825,11 +859,19 @@ def shard_dataset(
         arrs["hot_cols"] = np.tile(hc[None], (k, 1))
     if stream:
         arrs["sp_row_iota"] = stream_row_iota(row_nnz.max(initial=1), k)
+    num_classes = int(getattr(data, "num_classes", 1))
+    if num_classes > 1:
+        # beside the labels, not through the slab cache: ids by row
+        arrs["classes"] = np.zeros((k, n_shard), np.int32)
+        for s in range(k):
+            arrs["classes"][s, :sizes[s]] = \
+                data.classes[offsets[s]:offsets[s + 1]]
     return order_rows_for_passes(_finalize_replicated(
-        arrs, layout=layout, n=n, d=d, mesh=mesh, sizes=sizes))
+        arrs, layout=layout, n=n, d=d, mesh=mesh, sizes=sizes,
+        num_classes=num_classes))
 
 
-def _finalize_replicated(arrs, *, layout, n, d, mesh, sizes
+def _finalize_replicated(arrs, *, layout, n, d, mesh, sizes, num_classes=1
                          ) -> ShardedDataset:
     """device_put the stacked (K, ...) host arrays and wrap them — the
     tail of every single-process build (replicated whole-file and
@@ -862,4 +904,6 @@ def _finalize_replicated(arrs, *, layout, n, d, mesh, sizes
         sp_row_ptr=put(arrs.get("sp_row_ptr")),
         sp_row_len=put(arrs.get("sp_row_len")),
         sp_row_iota=put(arrs.get("sp_row_iota")),
+        classes=put(arrs.get("classes")),
+        num_classes=num_classes,
     )

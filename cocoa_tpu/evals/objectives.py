@@ -99,6 +99,11 @@ def eval_metrics(
     the same f32 reciprocal explicitly — which is what keeps a T=1 fleet
     eval bit-identical to the solo certificate (tests/test_fleet.py).
     """
+    if w.ndim == 2:
+        return _eval_metrics_classes(
+            w, alpha, shard_arrays, lam, n, mesh, test_shard_arrays,
+            test_n, loss, smoothing)
+
     def over_n(x):
         return x / n if inv_n is None else x * inv_n
 
@@ -143,6 +148,53 @@ def eval_metrics(
     return jnp.stack([primal, gap, test_err])
 
 
+def _class_margins(w, X):
+    """X . W^T for every row and every class, (T, K, n_shard): one pass
+    over the rows for all T models.  float32 at ``highest`` precision: the
+    default on a TPU is one bfloat16 pass, which moves a margin by ~1e-3
+    and the certificate with it (PERF.md section 6, PR 38)."""
+    return jnp.einsum("td,knd->tkn", w, X,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _eval_metrics_classes(w, alpha, shard_arrays, lam, n, mesh,
+                          test_shard_arrays, test_n, loss, smoothing):
+    """:func:`eval_metrics` of a one-vs-rest job: w (T, d), alpha
+    (T, K, n_shard) over rows that carry class ids.  T primal / dual / gap
+    values from ONE pass over the rows; returned as ``[primal, gap,
+    test_error, gap_0 .. gap_{T-1}]`` with ``gap`` the WORST class's (the
+    stop rule, the budget and the divergence watch read it: a job ends
+    when every class holds its certificate) and ``primal`` that class's.
+    The test error is the multi-class one: a row counts as wrong where the
+    class of the largest margin is not its own."""
+    if mesh is not None or "X" not in shard_arrays:
+        raise ValueError("the class axis is evaluated on dense rows on one "
+                         "chip (docs/DESIGN.md, one-vs-rest)")
+    from cocoa_tpu.data.sharding import class_labels
+
+    t = w.shape[0]
+    ids = jnp.arange(t, dtype=shard_arrays["classes"].dtype)[:, None, None]
+    mask = shard_arrays["mask"]
+    y = class_labels(shard_arrays["classes"], mask, ids)     # (T, K, n_shard)
+    margins = _class_margins(w, shard_arrays["X"])
+    vals = losses.primal(loss, y * margins, smoothing=smoothing)
+    w_norm_sq = jnp.sum(w * w, axis=1)
+    primal = jnp.sum(vals * mask, axis=(1, 2)) / n + 0.5 * lam * w_norm_sq
+    dual_vals = losses.dual_term(loss, alpha, smoothing=smoothing)
+    dual = jnp.sum(dual_vals * mask, axis=(1, 2)) / n - 0.5 * lam * w_norm_sq
+    gaps = primal - dual
+    worst = jnp.argmax(gaps)
+    if test_shard_arrays is not None:
+        guess = jnp.argmax(_class_margins(w, test_shard_arrays["X"]), axis=0)
+        wrong = (guess != test_shard_arrays["classes"])
+        test_err = jnp.sum(wrong * test_shard_arrays["mask"]) / test_n
+    else:
+        test_err = jnp.asarray(jnp.nan, primal.dtype)
+    head = jnp.stack([primal[worst], gaps[worst],
+                      test_err.astype(primal.dtype)])
+    return jnp.concatenate([head, gaps])
+
+
 @functools.lru_cache(maxsize=None)
 def _eval_metrics_fn(mesh, lam, n, test_n, loss, smoothing):
     # None arguments (no dual state / no test set) are empty pytrees — jit
@@ -163,7 +215,9 @@ def evaluate(ds: ShardedDataset, w, alpha, lam, test_ds=None,
     """Fused host-side eval: returns (primal, gap_or_None,
     test_error_or_None) with exactly ONE device→host transfer (each fetch
     is a blocking round trip; the unfused path pays four).
-    ``alpha=None`` for primal-only solvers → gap is None."""
+    ``alpha=None`` for primal-only solvers → gap is None.  A one-vs-rest
+    job (w (T, d)) gets a fourth element, the list of every class's gap;
+    the first three are then the worst class's (``eval_metrics``)."""
     import numpy as np
 
     from cocoa_tpu.analysis import sanitize
@@ -181,11 +235,13 @@ def evaluate(ds: ShardedDataset, w, alpha, lam, test_ds=None,
     # cadence (the transfer-guard sanitizer disallows any other)
     with sanitize.intended_fetch("eval_fetch"):
         out = np.asarray(out)
-        primal, gap, test_err = (float(v) for v in out)
+        primal, gap, test_err = (float(v) for v in out[:3])
     return (
         primal,
         None if np.isnan(gap) else gap,
         None if np.isnan(test_err) else test_err,
+        # a one-vs-rest job: every class's gap, past the three
+        *((out[3:].tolist(),) if out.shape[0] > 3 else ()),
     )
 
 

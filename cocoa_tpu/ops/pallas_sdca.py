@@ -449,6 +449,25 @@ def _kernel_interleaved(
             alpha_ref[kk] = st_scs[kk][:, 2 * LANES:]
 
 
+def _row_spec(h: int, unroll: int, d8: int, j: int, kk=None):
+    """BlockSpec of sample j of group i: the folded row at [shard, idx, :,
+    :].  Groups past H (only when unroll does not divide H) clamp to the
+    last sample — the kernels compute the same clamped index, so the DMA'd
+    block always matches.  ``kk`` fixes the shard (interleaved 1-D grid);
+    kk=None reads it from the grid (shard-major 2-D grid)."""
+    exact = h % unroll == 0
+
+    def step_of(i_):
+        step = i_ * unroll + j if unroll > 1 else i_
+        return step if exact else jnp.minimum(step, h - 1)
+
+    if kk is None:
+        index_map = lambda k_, i_, idxs_: (k_, idxs_[k_, step_of(i_)], 0, 0)
+    else:
+        index_map = lambda i_, idxs_: (kk, idxs_[kk, step_of(i_)], 0, 0)
+    return pl.BlockSpec((1, 1, SUBLANES, d8), index_map)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("lam", "n", "mode", "sigma", "interpret", "loss",
@@ -543,23 +562,7 @@ def pallas_sdca_round(
     w_pad = jnp.pad(w.astype(dtype), (0, d - w.shape[0]))
     w_folded = w_pad.reshape(SUBLANES, d8)
 
-    def row_spec(j, kk=None):
-        # sample j of group i: the folded row at [shard, idx, :, :].  Groups
-        # past H (only when unroll does not divide H) clamp to the last
-        # sample — the kernels compute the same clamped index, so the DMA'd
-        # block always matches.  ``kk`` fixes the shard (interleaved 1-D
-        # grid); kk=None reads it from the grid (shard-major 2-D grid).
-        exact = h % unroll == 0
-
-        def step_of(i_):
-            step = i_ * unroll + j if unroll > 1 else i_
-            return step if exact else jnp.minimum(step, h - 1)
-
-        if kk is None:
-            index_map = lambda k_, i_, idxs_: (k_, idxs_[k_, step_of(i_)], 0, 0)
-        else:
-            index_map = lambda i_, idxs_: (kk, idxs_[kk, step_of(i_)], 0, 0)
-        return pl.BlockSpec((1, 1, SUBLANES, d8), index_map)
+    row_spec = functools.partial(_row_spec, h, unroll, d8)
 
     common = dict(
         lam_n=float(lam * n),
@@ -645,3 +648,234 @@ def pallas_sdca_round(
     # length cost 21 us for 1.6 MB at the lasso, as much as summing K = 8 of
     # them had (PERF.md §6, PR 37); 1-D, the cut rides the caller's add
     return dw.reshape(d)[:d_orig][None], alpha_inner
+
+
+# --- one-vs-rest: T class models over the one sampled row ------------------
+#
+# A dataset that states T > 1 classes (data/sharding.py) trains T binary
+# models — class t against the rest — that share every row: w is (T, d),
+# alpha (T, K, n_shard), and every lane takes the SAME sampled rows.  A step
+# of a chain is then one row DMA and one dynamic read of the state, as at
+# T = 1, and T margins, T coordinate updates, T rank-one updates on them:
+# the class axis rides the vector unit under the step's latency, which is
+# what paces the chain at T = 1 (module docstring; PERF.md section 6, PR 38).
+#
+# **State tiles.**  What a chain keeps per row is T alphas, the row's norm
+# and its class id: the labels y_t = +1 where class = t, else -1, are derived
+# in the step and never stored.  Rows are lane-blocked as at T = 1, with the
+# per-row values on the SUBLANES of the block's tile: shard k's state is
+# (n_blocks, R, 128), R = ``class_rows(T)`` = T + 2 rounded up to whole
+# sublane tiles, tile row t < T the block's alpha_t, row T the norms, row
+# T + 1 the class ids (as floats: small integers are exact).  One dynamic
+# read of the tile, one masked lane reduce, and the step holds its T alphas
+# as an (R, 1) column; it stays a column through the margins (the T row
+# products are reduced over the fold's sublanes, stacked class by class on
+# the sublanes of one tile, and reduced over lanes once), through
+# ``losses.alpha_step`` (elementwise: the T steps are solved side by side,
+# closed-form or Newton alike) and back into the tile.  Nothing of the step
+# passes through a scalar.
+#
+# **VMEM.**  The K chains advance in lockstep, as in the interleaved kernel,
+# so all K shards' tiles are resident: K x n_blocks x R x 128 values, 64.8
+# MB at 8 x 126,563 rows and T = 10.  That is past Mosaic's default scoped
+# limit and inside a v5e core's 128 MiB: the state goes in and out by one
+# DMA a shard at the grid's two ends (no pipelined, double-buffered block
+# of it), and the kernel asks for ``CLASS_VMEM_LIMIT``.  A set whose state
+# is larger does not fit (:func:`classes_fit`) and runs ``fori``.
+
+CLASS_VMEM_LIMIT = 110 << 20   # asked of Mosaic (a v5e core has 128 MiB)
+CLASS_VMEM_BUDGET = 96 << 20   # of it, what :func:`class_vmem_estimate`
+                               # may come to: the rest is the compiler's
+
+
+def class_rows(t: int) -> int:
+    """Sublane rows of a state tile: T alphas, the norm, the class id,
+    rounded up to whole (8, 128) tiles."""
+    return -(-(t + 2) // SUBLANES) * SUBLANES
+
+
+def class_vmem_estimate(k: int, n_shard: int, d: int, t: int,
+                        itemsize: int) -> int:
+    """Working set of the T-class kernel: all K shards' state tiles, the
+    (T, 8, d/8) w, K accumulators and the output of that shape (each
+    class's fold lane-padded to whole tiles), and K double-buffered row
+    blocks."""
+    n_blocks = -(-n_shard // LANES)
+    fold = SUBLANES * (-(-d // (SUBLANES * LANES)) * LANES)
+    return itemsize * (k * n_blocks * class_rows(t) * LANES
+                       + (k + 3) * t * fold + 2 * k * fold)
+
+
+def classes_fit(k: int, n_shard: int, d: int, t: int, itemsize: int) -> bool:
+    """Whether the T-class kernel holds a round of these sizes."""
+    return class_vmem_estimate(k, n_shard, d, t, itemsize) \
+        <= CLASS_VMEM_BUDGET
+
+
+def _advance_classes(chains, idxs_ref, step, w_ref, *, t, frozen,
+                     sig_eff, qii_factor, lam_n, coef_div, loss, smoothing):
+    """One coordinate step of every chain in ``chains`` — (shard, row block
+    ref, (T, 8, d/8) accumulator ref, (n_blocks, R, 128) state ref) — for
+    all T classes at once (the section's note above).  The margin of class
+    t is sum(x * (w_t + sig_eff dw_t)): one reduce a class where the T = 1
+    kernel makes two (x . w and x . dw apart), so a lane agrees with the
+    solo kernel to rounding, not to the bit."""
+    rows = class_rows(t)
+    dtype = w_ref.dtype
+    d8 = w_ref.shape[-1]
+    sub = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    sub_fold = jax.lax.broadcasted_iota(jnp.int32, (rows, d8), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, LANES), 1)
+    is_class = sub < t
+    for shard, x_ref, dw_acc, state in chains:
+        idx = idxs_ref[shard, step]
+        blk = idx // LANES
+        here = lane == idx - blk * LANES
+        tile = state[pl.ds(blk, 1)][0]        # (R, 128): one dynamic read
+        x = x_ref[0, 0]                       # (8, d8): the folded row
+        col = jnp.sum(jnp.where(here, tile, 0.0), axis=1, keepdims=True)
+        # rows past T: alpha = 1/2, y = 0 -- every loss's step stays finite
+        # there, and nothing of them is written
+        a = jnp.where(is_class, col, 0.5)
+        sq = jnp.sum(jnp.where(sub == t, col, 0.0), axis=0, keepdims=True)
+        cls = jnp.sum(jnp.where(sub == t + 1, col, 0.0), axis=0,
+                      keepdims=True)
+        y = jnp.where(is_class,
+                      jnp.where(sub.astype(dtype) == cls, 1.0, -1.0), 0.0)
+        stacked = jnp.zeros((rows, d8), dtype)
+        for c in range(t):
+            u = w_ref[c] if frozen else w_ref[c] + sig_eff * dw_acc[c]
+            part = jnp.sum(x * u, axis=0, keepdims=True)       # (1, d8)
+            stacked = jnp.where(sub_fold == c,
+                                jnp.broadcast_to(part, (rows, d8)), stacked)
+        margin = jnp.sum(stacked, axis=1, keepdims=True)       # (R, 1)
+        new_a = losses.alpha_step(loss, a, y * margin, sq * qii_factor,
+                                  lam_n, smoothing=smoothing)
+        coef = jnp.where(is_class, y * (new_a - a) / coef_div, 0.0)
+        state[pl.ds(blk, 1)] = jnp.where(
+            here & is_class, jnp.broadcast_to(new_a, (rows, LANES)),
+            tile)[None]
+        for c in range(t):
+            dw_acc[c] = dw_acc[c] + coef[c:c + 1, :] * x
+
+
+def _kernel_classes(
+    idxs_ref,        # scalar-prefetch: (K, H) int32 sampled rows
+    *refs,           # K row blocks, w, state in, 2 outs, 2K scratch
+    h: int,
+    k: int,
+    **step_kw,
+):
+    """The interleaved kernel with a class axis: 1-D grid over the H steps
+    (one a grid iteration: two measured 3.7% slower at the mnist8m cell's
+    shape, PERF.md section 6, PR 38), every shard's chain advanced in
+    lockstep, each chain's accumulator and state in scratch refs of its
+    own."""
+    x_refs = refs[:k]
+    w_ref, state_in, dw_ref, state_out = refs[k:k + 4]
+    dw_accs = refs[k + 4:2 * k + 4]
+    st_scs = refs[2 * k + 4:3 * k + 4]
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _init():
+        for kk in range(k):
+            dw_accs[kk][...] = jnp.zeros_like(dw_accs[kk])
+            pltpu.sync_copy(state_in.at[kk], st_scs[kk])
+
+    _advance_classes([(kk, x_refs[kk], dw_accs[kk], st_scs[kk])
+                      for kk in range(k)], idxs_ref, i, w_ref, **step_kw)
+
+    @pl.when(i == h - 1)
+    def _flush():
+        dw_sum = dw_accs[0][...]
+        for kk in range(1, k):          # shard 0 first, left to right
+            dw_sum = dw_sum + dw_accs[kk][...]
+        dw_ref[...] = dw_sum
+        for kk in range(k):
+            pltpu.sync_copy(st_scs[kk], state_out.at[kk])
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("lam", "n", "mode", "sigma", "interpret", "loss",
+                     "smoothing"),
+)
+def pallas_sdca_round_classes(
+    w: jax.Array,            # (T, d) the replicated class models
+    alpha: jax.Array,        # (T, K, n_shard)
+    X: jax.Array,            # (K, n_shard, d) dense rows, or folded
+    classes: jax.Array,      # (K, n_shard) int class ids in [0, T)
+    sq_norms: jax.Array,     # (K, n_shard)
+    idxs: jax.Array,         # (K, H) int32: every class takes these rows
+    lam: float,
+    n: int,
+    mode: str = "plus",
+    sigma: float = 1.0,
+    interpret: bool = False,
+    loss: str = "hinge",
+    smoothing: float = 1.0,
+):
+    """One SDCA round of T one-vs-rest models over the K shards' sampled
+    rows.  Returns (dw (T, d), the shards' updates summed shard 0 first;
+    alpha_inner (T, K, n_shard), locally advanced: callers apply the outer
+    scaling law), as :func:`pallas_sdca_round` does at T = 1."""
+    t, d_orig = w.shape
+    if X.ndim == 4:
+        k, n_shard, _, d8 = X.shape
+        X_folded = X
+    else:
+        X_folded = _fold(X, SUBLANES)
+        k, n_shard, _, d8 = X_folded.shape
+    d = SUBLANES * d8
+    h = idxs.shape[1]
+    dtype = X.dtype
+    check_dtype(dtype)
+    sig_eff, qii_factor = mode_factors(mode, sigma)
+    rows = class_rows(t)
+    n_blocks = -(-n_shard // LANES)
+    n_pad = n_blocks * LANES
+
+    def blocked(v):
+        v = jnp.pad(v.astype(dtype), [(0, 0)] * (v.ndim - 1)
+                    + [(0, n_pad - n_shard)])
+        return v.reshape(*v.shape[:-1], n_blocks, LANES)
+
+    state = jnp.concatenate(
+        [jnp.transpose(blocked(alpha), (1, 2, 0, 3)),
+         blocked(sq_norms)[:, :, None], blocked(classes)[:, :, None],
+         jnp.zeros((k, n_blocks, rows - t - 2, LANES), dtype)], axis=2)
+    w_folded = jnp.pad(w.astype(dtype), ((0, 0), (0, d - d_orig))).reshape(
+        t, SUBLANES, d8)
+
+    kernel = functools.partial(
+        _kernel_classes, k=k, t=t, h=h,
+        lam_n=float(lam * n), coef_div=float(coef_divisor(mode, lam * n)),
+        sig_eff=float(sig_eff), qii_factor=float(qii_factor),
+        frozen=(mode == "frozen"), loss=losses.validate(loss, smoothing),
+        smoothing=float(smoothing))
+    whole = pl.BlockSpec((t, SUBLANES, d8), lambda i_, idxs_: (0, 0, 0))
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    dw, state = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(h,),
+            in_specs=[*[_row_spec(h, 1, d8, 0, kk) for kk in range(k)],
+                      whole, any_],
+            out_specs=[whole, any_],
+            scratch_shapes=(
+                [pltpu.VMEM((t, SUBLANES, d8), dtype)] * k
+                + [pltpu.VMEM((n_blocks, rows, LANES), dtype)] * k),
+        ),
+        out_shape=[jax.ShapeDtypeStruct((t, SUBLANES, d8), dtype),
+                   jax.ShapeDtypeStruct(state.shape, dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=CLASS_VMEM_LIMIT),
+        interpret=interpret,
+        name="pallas_sdca_classes",
+    )(idxs, *([X_folded] * k), w_folded, state)
+    alpha_inner = jnp.transpose(state[:, :, :t], (2, 0, 1, 3)).reshape(
+        t, k, n_pad)[:, :, :n_shard]
+    return dw.reshape(t, d)[:, :d_orig], alpha_inner

@@ -518,6 +518,16 @@ def checkpoint_arguments(debug: DebugParams, name: str, round_t: int,
                  hist=hist, gap=_last_gap(traj)))
 
 
+def _per_class(per_class: list, gap_target) -> dict:
+    """What ``eval_fn``'s elements past (primal, gap, test error) add to
+    the record: a one-vs-rest job's list of per-class gaps (the three are
+    then the worst class's, so the stop rule, the budget and the watch read
+    the worst); nothing for any other job."""
+    from cocoa_tpu.telemetry.events import per_class_fields
+
+    return per_class_fields(per_class[0] if per_class else (), gap_target)
+
+
 class _GapWatch:
     """Windowed no-improvement watch over eval-cadence gap values;
     ``update(gap)`` returns True when the run should bail out (diverged or
@@ -574,9 +584,10 @@ def drive(
 
         if debug.debug_iter > 0 and t % debug.debug_iter == 0:
             with _tracing.span("eval", algorithm=name, round=t):
-                primal, gap, test_err = eval_fn(state)
+                primal, gap, test_err, *per_class = eval_fn(state)
                 _sanitize.count_launch()
-            traj.log_round(t, primal=primal, gap=gap, test_error=test_err)
+            traj.log_round(t, primal=primal, gap=gap, test_error=test_err,
+                           **_per_class(per_class, gap_target))
             if gap_target is not None and gap is not None and gap <= gap_target:
                 traj.stopped = "target"
                 break
@@ -649,7 +660,7 @@ def drive_chunked(
 
         if debug.debug_iter > 0 and end % debug.debug_iter == 0:
             with _tracing.span("eval", algorithm=name, round=end):
-                primal, gap, test_err = eval_fn(state)
+                primal, gap, test_err, *per_class = eval_fn(state)
                 _sanitize.count_launch()
             anneal_on = (gap_target is not None and divergence_guard
                          and anneal)
@@ -694,7 +705,8 @@ def drive_chunked(
                                    int(sched_a[A_RESTARTS]), staged,
                                    int(sched_a[A_TH_STAGE]), accel, quiet)
             traj.log_round(end, primal=primal, gap=gap, test_error=test_err,
-                           sigma=sigma_val, sigma_stage=stage, stall=stall_v)
+                           sigma=sigma_val, sigma_stage=stage, stall=stall_v,
+                           **_per_class(per_class, gap_target))
             if backed:
                 _emit_backoff(name, end, sigma_levels, stage, quiet,
                               f"{name}: σ′ anneal — gap stalled for "
@@ -811,7 +823,9 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
     # telemetry bus while the loop is still on device (side-effect-only:
     # nothing in the loop carry reads it, so a streaming run is
     # bit-identical to a non-streaming one — the fetch-fallback replays
-    # the same buffer).
+    # the same buffer).  An eval kernel that returns more than the three
+    # (a one-vs-rest job's per-class gaps, evals/objectives.py) gets a
+    # column for each past the seven; the loop reads none of them.
     n_cols = 7
 
     @functools.partial(jax.jit, donate_argnums=tuple(range(n_state)))
@@ -949,7 +963,8 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
                                     rst.astype(metrics.dtype)])
             else:
                 extra2 = jnp.stack([nanv, nanv])
-            row = jnp.concatenate([metrics, extra, extra2])
+            row = jnp.concatenate([metrics[:3], extra, extra2, metrics[3:]]
+                                  if n_more else [metrics, extra, extra2])
             if stream:
                 # side-effect-only event bridge: post this eval's row to
                 # the host WHILE THE LOOP RUNS.  Ordered, so the host sees
@@ -964,7 +979,10 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
             return (i + jnp.int32(1), done_tgt, done_stall, stall, best,
                     best_prev, state, traj)
 
-        traj0 = jnp.full((n_chunks, n_cols), jnp.nan, dtype=state[0].dtype)
+        n_more = jax.eval_shape(eval_kernel, state, shard_arrays,
+                                test_arrays).shape[0] - 3
+        traj0 = jnp.full((n_chunks, n_cols + n_more), jnp.nan,
+                         dtype=state[0].dtype)
         if mesh is not None:
             # metrics coming out of the shard_mapped eval carry the (Explicit)
             # mesh in their sharding type; the update target must match
@@ -1098,7 +1116,7 @@ def drive_on_device(
                               theta_hs=(accel.theta_hs
                                         if accel is not None else None),
                               init_theta_stage=init_theta,
-                              init_restarts=init_restarts)
+                              init_restarts=init_restarts, gap_target=tgt)
 
     run_key = None if cache_key is None else (cache_key, stream)
     run = _DEVICE_RUNS.get(run_key) if run_key is not None else None
@@ -1190,6 +1208,7 @@ def drive_on_device(
                 # events for this run were already emitted by the tap (live
                 # stream or fetch replay) — don't double-emit
                 emit=False,
+                **_tele.per_class_fields(traj_host[j, 7:], tgt),
             )
             if (not quiet and anneal and prev_sigma is not None
                     and sigma != prev_sigma):
@@ -1335,7 +1354,7 @@ def drive_device_full(
         t = head_end + 1
         if head_end % c == 0:
             with _tracing.span("eval", algorithm=name, round=head_end):
-                primal, gap, test_err = eval_fn(state)
+                primal, gap, test_err, *per_class = eval_fn(state)
                 _sanitize.count_launch()
             sigma_val = stage = stall_v = None
             backed = False
@@ -1361,7 +1380,8 @@ def drive_device_full(
                                    int(sched_a[A_TH_STAGE]), accel, quiet)
             traj.log_round(head_end, primal=primal, gap=gap,
                            test_error=test_err, sigma=sigma_val,
-                           sigma_stage=stage, stall=stall_v)
+                           sigma_stage=stage, stall=stall_v,
+                           **_per_class(per_class, gap_target))
             if backed:
                 _emit_backoff(name, head_end, sigma_levels, stage, quiet,
                               f"{name}: σ′ anneal — gap stalled for "

@@ -81,8 +81,20 @@ def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
             hot_cols=shards.get("hot_cols"), hot_panel=shards.get("X_hot"),
             **common,
         )
-    from cocoa_tpu.ops.pallas_sdca import pallas_sdca_round
+    from cocoa_tpu.ops.pallas_sdca import (pallas_sdca_round,
+                                           pallas_sdca_round_classes)
 
+    if "classes" in shards:
+        # one-vs-rest: w (T, d), alpha (T, K, n_shard); every class takes
+        # the round's one table of sampled rows, and its labels are derived
+        # from the class ids in the step.  dw arrives (T, d), summed over
+        # the shards: as the one element of a leading axis it takes the
+        # callers' shard sum
+        dw, alpha_inner = pallas_sdca_round_classes(
+            w, alpha, shards.get("X_folded", shards["X"]),
+            shards["classes"], shards["sq_norms"], idxs_kh, params.lam,
+            params.n, **common)
+        return dw[None], alpha_inner
     # margins are computed in-kernel against the VMEM-resident w (round 4;
     # the sampled row is DMA'd for the axpy anyway — precomputing X·w read
     # ALL of X per round, ~10x the rows the round touches at
@@ -213,7 +225,17 @@ class SolverPath:
     two kernels runs, chosen from the fit alone
     (ops/pallas_sdca.dense_form) — ``interleaved``: every shard's state in
     VMEM at once, the K chains advanced in lockstep; ``shard_major``: a
-    shard at a time."""
+    shard at a time.  ``classes``: T, the class models the job trains
+    one-vs-rest over the one set of rows (``ShardedDataset.num_classes``;
+    1 for every binary set: w (d,), alpha (K, n_shard), today's program).
+    At T > 1 w is (T, d), alpha (T, K, n_shard), every class takes the
+    job's one table of sampled rows, and on the dense Pallas path
+    (ops/pallas_sdca.pallas_sdca_round_classes, ``form`` ``interleaved``)
+    a chain's T coordinate steps are solved side by side (``step_solve``
+    ``lanes``) as one column of a state tile.  ``lane_fill`` (that path
+    only): of the vector positions a lockstep step's solve holds — K
+    chains x the tile's sublane rows (ops/pallas_sdca.class_rows) — the
+    share that are class models, K T / (K R)."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -233,6 +255,8 @@ class SolverPath:
     refused: str = ""
     objective: str = "svm"
     form: Optional[str] = None
+    classes: int = 1
+    lane_fill: Optional[float] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -282,7 +306,13 @@ class SolverPath:
         if self.objective != "svm":
             rows += f", objective {self.objective}"
         solve = (", the shards' steps solved in lanes"
-                 if self.step_solve == "lanes" else "")
+                 if self.step_solve == "lanes" and self.classes == 1 else "")
+        if self.classes > 1:
+            solve += (f", {self.classes} class models one-vs-rest over the "
+                      f"one sampled row"
+                      + ("" if self.lane_fill is None else
+                         f" (solved side by side, lane fill "
+                         f"{self.lane_fill:.3f})"))
         if self.pass_slot_share < 1.0:
             solve += (f", all-rows passes touch {self.pass_slot_share:.3f} "
                       f"of the padded slots")
@@ -357,6 +387,24 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
     k = ds.k
     dtype = ds.labels.dtype
     itemsize = jnp.dtype(dtype).itemsize
+    classes = int(getattr(ds, "num_classes", 1))
+    if classes > 1:
+        # what carries the class axis today, said once where the path is
+        # decided: dense rows, one chip, the sequential solve
+        if ds.layout != "dense":
+            raise ValueError(
+                f"a set of {classes} classes trains one-vs-rest on dense "
+                f"rows only: no kernel carries the class axis on the "
+                f"{ds.layout} layout yet (load it with --layout=dense, or "
+                f"drop --classes to train class 1 against the rest)")
+        if mesh is not None:
+            raise ValueError(
+                f"a set of {classes} classes trains one-vs-rest on one chip "
+                f"(--mesh=1): the class axis is not carried across a mesh")
+        if block_size > 0:
+            raise ValueError(
+                "the block-coordinate kernels carry no class axis: "
+                "block_size=0 with a multi-class set")
     # logical shards resident per device: k on the single-chip path, K/D on
     # a (possibly multiplexed) dp mesh — the unit the VMEM fit checks see
     m_local = shards_per_device(mesh, k) if mesh is not None else k
@@ -422,10 +470,14 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         # batch per grid step).  Oversized runs keep the fori_loop fast path
         # (explicit pallas=True overrides, and Mosaic then reports the
         # allocation failure itself).
-        from cocoa_tpu.ops.pallas_sdca import pick_unroll
+        from cocoa_tpu.ops.pallas_sdca import classes_fit, pick_unroll
 
-        fits = (vmem_fits or hbm_state) if sparse else pick_unroll(
-            ds.n_shard, ds.num_features, itemsize, local_iters) > 0
+        if classes > 1:
+            fits = classes_fit(m_local, ds.n_shard, ds.num_features,
+                               classes, itemsize)
+        else:
+            fits = (vmem_fits or hbm_state) if sparse else pick_unroll(
+                ds.n_shard, ds.num_features, itemsize, local_iters) > 0
         pallas = (
             math == "fast"
             and itemsize == 4
@@ -487,20 +539,27 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         placement["slot_fill"], placement["longest_row"] = _slot_stats(ds)
     if block_size <= 0:
         from cocoa_tpu.ops import losses
-        from cocoa_tpu.ops.pallas_sdca import dense_form, stores_row_major
+        from cocoa_tpu.ops.pallas_sdca import (class_rows, dense_form,
+                                               stores_row_major)
 
+        if classes > 1:
+            placement.update(
+                classes=classes,
+                lane_fill=classes / class_rows(classes) if pallas else None)
         return SolverPath(
             inner="sequential", kernel="pallas" if pallas else "fori",
             chain=None, interpret=bool(pallas and platform == "cpu"),
-            form=(dense_form(m_local, ds.n_shard, ds.num_features, itemsize,
-                             local_iters)
+            form=("interleaved" if pallas and classes > 1
+                  else dense_form(m_local, ds.n_shard, ds.num_features,
+                                  itemsize, local_iters)
                   if pallas and not sparse else None),
             rows=("row_major" if pallas and not sparse
                   and stores_row_major(ds.num_features)
                   else "device_default"),
             state="vmem" if pallas and not hbm_state else "hbm",
             step_solve=("lanes" if pallas and not sparse
-                        and losses.step_is_iterative(loss) else "scalar"),
+                        and (classes > 1 or losses.step_is_iterative(loss))
+                        else "scalar"),
             refused="" if pallas else refused,
             **placement)
     if block_chain == "xla":
@@ -557,6 +616,7 @@ def _sdca_round_parts(
     block_chain: str = "xla",
     block_distinct: bool = False,
     block_sparse_gram=None,
+    classes: int = 1,
 ):
     """The per-shard local update and driver-side apply shared by the
     per-round and chunked builders (so the two paths cannot diverge), for
@@ -572,7 +632,21 @@ def _sdca_round_parts(
     block-coordinate MXU kernel (ops/local_sdca.local_sdca_block) with that
     block size (a round of more than one block takes the two-phase
     software-pipelined scan, see local_sdca_block_batched).  Returns
-    (per_shard, per_round_batched | None, apply_fn)."""
+    (per_shard, per_round_batched | None, apply_fn).
+
+    ``classes`` = T > 1 (a one-vs-rest job, one chip): the state is w
+    (T, d), alpha (T, K, n_shard) and ``per_round_batched`` is always
+    there — the Pallas kernel with its class axis, or, on the ``fori``
+    path, the T = 1 ``per_shard`` under a vmap over classes and shards,
+    class t's labels derived from the class ids; every class takes the
+    round's one (K, H) table."""
+    if classes > 1:
+        one = _sdca_round_parts(
+            params, k, mode, scaling, sigma, math=math,
+            pallas_interpret=pallas_interpret, pallas_state=pallas_state)
+        return (one[0], _class_round(params, mode, scaling, sigma, classes,
+                                     one[0], pallas, pallas_interpret),
+                one[2])
     if math not in ("exact", "fast"):
         raise ValueError(f"math must be 'exact' or 'fast', got {math!r}")
     if block and pallas:
@@ -672,6 +746,32 @@ def _sdca_round_parts(
             return dw.sum(axis=0), alpha + scaling * da
 
     return per_shard, per_round_batched, apply_fn
+
+
+def _class_round(params: Params, mode: str, scaling: float, sigma: float,
+                 classes: int, per_shard, pallas: bool, interpret: bool):
+    """``per_round_batched(w (T, d), alpha (T, K, n_shard), idxs (K, H),
+    shards) -> (dw (T, d), alpha')`` of a one-vs-rest round
+    (:func:`_sdca_round_parts`)."""
+    from cocoa_tpu.data.sharding import class_labels
+
+    @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
+    def per_round_classes(w, alpha, idxs_kh, shards):
+        if pallas:
+            dw, a_inner = _pallas_batched(w, alpha, idxs_kh, shards, params,
+                                          mode, sigma, interpret)
+            return dw.sum(axis=0), alpha + scaling * (a_inner - alpha)
+        binary = {f: v for f, v in shards.items() if f != "classes"}
+
+        def one_class(w_t, alpha_t, t):
+            labels = class_labels(shards["classes"], shards["mask"], t)
+            dw, alpha_new = jax.vmap(per_shard, in_axes=(None, 0, 0, 0))(
+                w_t, alpha_t, idxs_kh, {**binary, "labels": labels})
+            return dw.sum(axis=0), alpha_new
+
+        return jax.vmap(one_class)(w, alpha, jnp.arange(classes))
+
+    return per_round_classes
 
 
 def make_round_step(mesh, params: Params, k: int, alg, **parts_kw):
@@ -776,6 +876,9 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     tiny program the device waits for, then its ``device_put`` on a mesh."""
     # plain ints: the cached start program must not keep ``ds`` alive
     d, k, n_shard = int(ds.num_features), ds.k, int(ds.n_shard)
+    # a one-vs-rest job's leaves carry the class axis first: w (T, d),
+    # alpha (T, K, n_shard); at T = 1 there is none
+    lead = (ds.num_classes,) if ds.num_classes > 1 else ()
     accel = arm == "accel"
     sched = (None if arm == "plain"
              else base.sched_init_values(start_round, sched_init, accel))
@@ -792,7 +895,7 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
                        if accel else ()),
                      *(() if sched is None else (NamedSharding(mesh, P()),)))
     if all(v is None for v in (w_init, alpha_init, hist_init, sched_init)):
-        key = (d, k, n_shard, str(dtype), arm, residual, mesh)
+        key = (d, k, n_shard, str(dtype), arm, residual, mesh, lead)
         start = _START_PROGRAMS.get(key)
         target = ds.target if residual else None
         _sanitize.count_launch()
@@ -802,9 +905,9 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
         # for the leaves, so the span's closing reading holds them
         with _tracing.cold_span("build_start") as cold:
             def start(sched, target):
-                w = (jnp.zeros(d, dtype=dtype) if target is None
+                w = (jnp.zeros(lead + (d,), dtype=dtype) if target is None
                      else (-target).astype(dtype))
-                alpha = jnp.zeros((k, n_shard), dtype=dtype)
+                alpha = jnp.zeros(lead + (k, n_shard), dtype=dtype)
                 hist = ((jnp.zeros((2, k, n_shard), dtype=dtype),)
                         if accel else ())
                 return (w, alpha, *hist,
@@ -822,9 +925,19 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     elif residual:
         w = (-ds.target).astype(dtype)
     else:
-        w = jnp.zeros(d, dtype=dtype)
-    alpha = (jnp.zeros((k, n_shard), dtype=dtype) if alpha_init is None
-             else base.align_alpha(alpha_init, ds, dtype))
+        w = jnp.zeros(lead + (d,), dtype=dtype)
+    if alpha_init is None:
+        alpha = jnp.zeros(lead + (k, n_shard), dtype=dtype)
+    elif lead:
+        alpha = jnp.array(alpha_init, dtype=dtype, copy=True)
+    else:
+        alpha = base.align_alpha(alpha_init, ds, dtype)
+    if lead and (w.shape, alpha.shape) != (lead + (d,),
+                                            lead + (k, n_shard)):
+        raise ValueError(
+            f"a job over {lead[0]} classes starts from w {lead + (d,)} and "
+            f"alpha {lead + (k, n_shard)}; it was handed {w.shape} and "
+            f"{alpha.shape}")
     leaves = [w, alpha]
     if accel:
         leaves.append(jnp.zeros((2,) + alpha.shape, dtype=dtype)
@@ -836,6 +949,38 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     if shardings is not None:
         leaves = [jax.device_put(a, sh) for a, sh in zip(leaves, shardings)]
     return tuple(leaves)
+
+
+def _check_class_job(ds: ShardedDataset, alg, arm: str, debug: DebugParams,
+                     test_ds, own_eval: bool) -> None:
+    """Refuse, by name and before anything is built, what a one-vs-rest job
+    (``ds.num_classes`` > 1) does not carry yet.  The layout, the mesh and
+    the block kernels are refused where the path is resolved
+    (:func:`resolve_solver_path`)."""
+    t = ds.num_classes
+    if ds.classes is None:
+        raise ValueError(f"the dataset states {t} classes and carries no "
+                         f"class ids (ShardedDataset.classes)")
+    if alg[0] == "prox" or own_eval:
+        raise ValueError(
+            f"a set of {t} classes trains {t} SVMs one-vs-rest "
+            f"(run_cocoa, run_minibatch_cd); the prox family "
+            f"(--objective=lasso) regresses on one target and has no "
+            f"class axis")
+    if arm != "plain":
+        raise ValueError(
+            f"a one-vs-rest job over {t} classes runs the plain outer "
+            f"loop: the --accel window bank and the sigma' / warm-start "
+            f"schedule leaf hold ONE model's state (pass --accel=off, no "
+            f"--sigma=auto, --sigmaSchedule or --warmStart)")
+    if debug.chkpt_dir and debug.chkpt_iter > 0:
+        raise ValueError(
+            f"checkpoints hold one model's (w, alpha): a one-vs-rest job "
+            f"over {t} classes writes none yet (drop --chkptIter)")
+    if test_ds is not None and test_ds.num_classes != t:
+        raise ValueError(
+            f"the training set states {t} classes and the test set "
+            f"{test_ds.num_classes}: load both with the same --classes")
 
 
 @_tracing.cold_entry
@@ -1023,6 +1168,14 @@ def run_sdca_family(
     scheduled = ((sigma_levels is not None and len(sigma_levels) > 1)
                  or warm_start is not None)
     arm = "accel" if accel else "sched" if scheduled else "plain"
+    classes = ds.num_classes
+    if classes > 1:
+        _check_class_job(ds, alg, arm, debug, test_ds,
+                         eval_fn is not None or eval_kernel is not None)
+        if scan_chunk <= 0 and not device_loop:
+            # the class axis rides the chunk kernels' batched round; the
+            # per-round driver is the same round at chunk = 1
+            scan_chunk = 1
     counted = (_sanitize.launches_total, _sanitize.intended_fetches_total)
     # init_state: one start program when the job starts from nothing —
     # dispatched here, ahead of the host's path to the loop's dispatch, so
@@ -1043,7 +1196,7 @@ def run_sdca_family(
         print(f"local solver: {path.describe()}; the shared vector is "
               f"{ds.num_features} long")
     parts_kw = dict(
-        math=math, pallas=pallas,
+        classes=classes, math=math, pallas=pallas,
         pallas_interpret=path.pallas and path.interpret,
         pallas_state=path.state,
         block=block_size, block_chain=block_chain,
@@ -1108,6 +1261,7 @@ def run_sdca_family(
 
     if eval_fn is None:
         def eval_fn(state):
+            # (a one-vs-rest job: a fourth element, every class's gap)
             # state[0:2] — the scheduled path appends the sched leaf; the
             # duality-gap certificate reads only (w, α) and is exact under
             # any σ′/loss stage (which is the backoff's soundness argument)
@@ -1389,7 +1543,7 @@ def run_sdca_family(
             params.lam, params.n, params.local_iters, params.beta,
             params.gamma, params.loss, params.smoothing,
             params.num_rounds, debug.debug_iter, start_round,
-            gap_target, ds.layout, str(dtype),
+            gap_target, ds.layout, str(dtype), classes,
         )
         state, traj = base.drive_device_paths(
             alg_name, params, debug, state0, chunk_kernel, chunk_fn,
@@ -1436,6 +1590,23 @@ def run_cocoa(
     (w, alpha, Trajectory).  See :func:`run_sdca_family` for the keyword
     options (mesh, rng, gap_target, scan_chunk, math, pallas, device_loop,
     checkpoint/resume).
+
+    **One-vs-rest.**  A dataset that states T > 1 classes
+    (``ds.num_classes``, class ids in ``ds.classes``: a multi-class file
+    loaded as one) trains T models in this one job, class t against the
+    rest, over the ONE copy of the rows: returns (w (T, d), alpha
+    (T, K, n_shard), Trajectory).  Every class takes the job's one sampler's
+    rows, so lane t is the run of class t against the rest under that
+    sampler (what a solo job on those labels gives, to rounding: the dense
+    Pallas kernel reduces x . (w_t + sigma' dw_t) once where the T = 1
+    kernel reduces x . w and x . dw apart).  The job stops at the first
+    evaluation at which EVERY class's gap is at or under the target; no
+    lane is frozen before that; the budget and the divergence watch read
+    the worst class.  A record's ``gap`` is the worst class's, ``primal``
+    that class's, ``class_gaps`` all of them.  The branch is on what the
+    dataset declares; at T = 1 nothing of it runs.  Not carried yet, and
+    refused by name: sparse rows, a mesh, ``--accel``, the sigma' schedule
+    and warm start, checkpoints, the block kernels.
 
     ``params.sigma="auto"`` (flag ``--sigma=auto``) exploits the measured
     σ′ trade-off (the aggressive σ′ = K·γ/2 HALVES
@@ -1503,6 +1674,14 @@ def run_cocoa(
     accel_on = (accel == "on"
                 or (accel == "auto" and plus
                     and kw.get("gap_target") is not None))
+    if accel_on and ds.num_classes > 1:
+        # ``auto`` means at T classes what it means at one: said here, by
+        # name, instead of resolving to something else
+        raise ValueError(
+            f"--accel={accel} resolves ON for this job, and its secant "
+            f"bank and jump hold one model's alpha: a one-vs-rest job over "
+            f"{ds.num_classes} classes runs the plain outer loop; pass "
+            f"--accel=off")
     if theta == "adaptive" and not accel_on:
         if accel == "off":
             raise ValueError(

@@ -448,6 +448,19 @@ def device_tap(tap):
         _DEVICE_TAP = prev
 
 
+def per_class_fields(gaps, gap_target) -> dict:
+    """What a one-vs-rest eval adds to its record and event: ``class_gaps``
+    (every class's gap, by class id) and ``classes_done`` (how many are at
+    or under ``gap_target``; None without one).  ``{}`` for no gaps: a
+    T = 1 job's records and events are what they were."""
+    gaps = [float(g) for g in gaps]
+    if not gaps:
+        return {}
+    done = (None if gap_target is None
+            else sum(g <= gap_target for g in gaps))
+    return dict(class_gaps=gaps, classes_done=done)
+
+
 class DeviceTap:
     """Decode device eval rows into bus events.
 
@@ -461,7 +474,10 @@ class DeviceTap:
     restarts]`` — gap/test_err NaN when not applicable, sigma_stage NaN
     outside σ′-anneal runs, theta_stage/restarts NaN outside ``--accel``
     runs (and absent entirely on pre-widening 5-col rows, which decode
-    unchanged).
+    unchanged).  A one-vs-rest job's rows carry every class's gap past
+    those seven (``gap`` is then the worst): they ride the ``round_eval``
+    event as ``class_gaps``, with ``classes_done`` counted against
+    ``gap_target``.
 
     ``init_stage`` / ``init_theta_stage`` / ``init_restarts`` seed
     transition detection with the values the state ENTERED this dispatch
@@ -472,8 +488,10 @@ class DeviceTap:
 
     def __init__(self, bus, algorithm: str, start_round: int, cadence: int,
                  sigma_levels=None, init_stage=None, theta_hs=None,
-                 init_theta_stage=None, init_restarts=None):
+                 init_theta_stage=None, init_restarts=None,
+                 gap_target=None):
         self.bus = bus
+        self.gap_target = gap_target
         self.algorithm = algorithm
         self.start_round = start_round
         self.cadence = cadence
@@ -497,6 +515,7 @@ class DeviceTap:
             "round_eval", algorithm=self.algorithm, t=t, primal=primal,
             gap=gap, test_error=test_err, sigma=sigma, sigma_stage=stage,
             stall=None if math.isnan(stall) else int(stall),
+            **per_class_fields(r[7:], self.gap_target),
         )
         if (stage is not None and self._prev_stage is not None
                 and stage != self._prev_stage):

@@ -39,6 +39,14 @@ class RoundRecord:
                                    # update (--sigmaSchedule=anneal runs only;
                                    # a change between consecutive records IS
                                    # the in-loop backoff event)
+    class_gaps: Optional[list] = None  # a one-vs-rest job (T > 1 class
+                                   # models over one set of rows): every
+                                   # class's duality gap at this eval, by
+                                   # class id; ``gap`` is the worst of them
+                                   # and ``primal`` that class's.  None at
+                                   # T = 1
+    classes_done: Optional[int] = None  # of them, how many are at or under
+                                   # the job's gap target (None without one)
 
 
 class Trajectory:
@@ -86,7 +94,7 @@ class Trajectory:
 
     def log_round(self, t, primal=None, gap=None, test_error=None,
                   wall_time=_STAMP, sigma=None, emit=True, sigma_stage=None,
-                  stall=None):
+                  stall=None, class_gaps=None, classes_done=None):
         """``wall_time=None`` marks the round's timing as unobservable (the
         device-resident driver syncs once for the whole run).
 
@@ -95,22 +103,28 @@ class Trajectory:
         in-flight by the io_callback bridge (or replayed from the fetch)
         before this record is built.  ``sigma_stage``/``stall`` ride the
         event only (the σ′ ladder index and the stall-watch counter after
-        this eval's update — the host drivers' twin of the device row)."""
-        self.records.append(
-            RoundRecord(
-                round=t,
-                wall_time=self.elapsed() if wall_time is self._STAMP else wall_time,
-                primal=primal,
-                gap=gap,
-                test_error=test_error,
-                sigma=sigma,
-            )
+        this eval's update — the host drivers' twin of the device row).
+        ``class_gaps`` / ``classes_done`` (a one-vs-rest job only): every
+        class's gap and how many are at or under the target; they ride the
+        record and, with ``emit``, the event."""
+        rec = RoundRecord(
+            round=t,
+            wall_time=self.elapsed() if wall_time is self._STAMP else wall_time,
+            primal=primal,
+            gap=gap,
+            test_error=test_error,
+            sigma=sigma,
+            class_gaps=class_gaps,
+            classes_done=classes_done,
         )
+        per_class = ({} if class_gaps is None else
+                     dict(class_gaps=class_gaps, classes_done=classes_done))
+        self.records.append(rec)
         if emit:
             _events.get_bus().emit(
                 "round_eval", algorithm=self.algorithm, t=int(t),
                 primal=primal, gap=gap, test_error=test_error, sigma=sigma,
-                sigma_stage=sigma_stage, stall=stall,
+                sigma_stage=sigma_stage, stall=stall, **per_class,
             )
         if not self.quiet:
             # reference console format (CoCoA.scala:52-55)
@@ -121,6 +135,10 @@ class Trajectory:
                 print(f"primal-dual gap: {gap}")
             if test_error is not None:
                 print(f"test error: {test_error}")
+            if class_gaps is not None:
+                print(f"per-class gaps: {class_gaps}"
+                      + ("" if classes_done is None else
+                         f" ({classes_done} of {len(class_gaps)} at target)"))
 
     def summary(self, primal, gap=None, test_error=None):
         """End-of-run block (OptUtils.scala:102-126 format) + the

@@ -149,7 +149,9 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
         "inner", "kernel", "chain", "interpret", "layout", "platform",
         "devices", "shards_per_device", "rows", "state", "step_solve",
         "pass_slot_share", "storage", "margin", "slot_fill", "longest_row",
-        "refused", "objective", "form"]
+        "refused", "objective", "form", "classes", "lane_fill"]
+    # a binary set: one model, no class axis
+    assert (path.classes, path.lane_fill) == (1, None)
     # the dual family; which dense kernel runs, on the dense Pallas path
     assert path.objective == "svm"
     assert path.form == ("interleaved" if pallas and layout == "dense"

@@ -64,7 +64,7 @@ class _Captured(Exception):
     pass
 
 
-def _capture_run(monkeypatch, d, accel, loss="hinge"):
+def _capture_run(monkeypatch, d, accel, loss="hinge", classes=1):
     """``(run, its arguments, the resolved SolverPath)`` of one CoCoA+ job
     on the dense Pallas path, stopped at the dispatch: the kernels are held
     at compiled (``interpret=False``; this process's platform is cpu), so
@@ -102,7 +102,9 @@ def _capture_run(monkeypatch, d, accel, loss="hinge"):
     ds = ShardedDataset(
         layout="dense", n=K * N_SHARD, num_features=d,
         counts=np.full(K, N_SHARD, np.int64), labels=ones, mask=ones,
-        sq_norms=ones, X=jnp.zeros((K, N_SHARD, d), jnp.float32))
+        sq_norms=ones, X=jnp.zeros((K, N_SHARD, d), jnp.float32),
+        classes=(jnp.zeros((K, N_SHARD), jnp.int32) if classes > 1
+                 else None), num_classes=classes)
     with pytest.raises(_Captured):
         run_cocoa(ds, Params(n=ds.n, num_rounds=600, local_iters=H,
                              lam=1e-3, loss=loss),
@@ -165,6 +167,66 @@ def test_device_loop_opens_with_no_copy_of_the_rows(monkeypatch, one_chip,
     else:
         # the fold cache, stored with the row index on the lanes; never X
         assert len(copied) == 1 and "X_folded" in copied[0], copied
+
+
+# --- one-vs-rest: T = 10 class models over the one copy of the rows ---------
+
+@pytest.mark.parametrize("loss", ["hinge", "logistic"])
+def test_ten_class_job_reads_one_copy_of_the_rows(monkeypatch, one_chip,
+                                                  loss):
+    """The mnist8m-shaped job (d = 784: a fold of (8, 98), neither a
+    multiple of 1,024 nor long; T = 10) compiled for the chip as the
+    program's own ``run``: the class kernel lowers through Mosaic inside
+    it; the one whole-array copy is the fold cache's (stored as the device
+    lays it out at this width: ``SolverPath.rows`` says so), never X's;
+    nothing holds the rows once per class, and no op works on a one-row
+    (1, d) matrix (PR 37's trap), per class or not."""
+    import jax
+
+    d, t = 784, 10
+    with jax.enable_x64(False):
+        run, args, path = _capture_run(monkeypatch, d, "off", loss=loss,
+                                       classes=t)
+        assert (path.classes, path.kernel, path.form, path.rows) == (
+            t, "pallas", "interleaved", "device_default")
+        assert path.lane_fill == 10 / 16
+        hlo = run.lower(*_on_chip(args, one_chip)).compile().as_text()
+    assert "pallas_sdca_classes" in hlo and "tpu_custom_call" in hlo
+    copied = _row_copies(hlo, d)
+    assert len(copied) == 1 and "X_folded" in copied[0], copied
+    per_class = re.findall(rf"f32\[{t},{K},{N_SHARD},(?:{d}|8,{d // 8})\]",
+                           hlo)
+    assert not per_class, per_class
+    assert not re.findall(rf"f32\[(?:{t},)?1,{d}\]", hlo)
+
+
+def test_class_kernel_compiles_at_the_cells_size(one_chip):
+    """The kernel alone at the benchmark's shapes (8 x 126,576 x 784, H =
+    12,656, T = 10: 62 MB of VMEM under the limit it asks for), and not at
+    the quarter share's, whose state no v5e core holds: the resolver's
+    ``classes_fit`` draws the line where the chip's compiler does."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    k, d, t = 8, 784, 10
+    on_chip = functools.partial(_shape_on, one_chip)
+
+    def lower(n_shard, h):
+        return pallas_sdca.pallas_sdca_round_classes.lower(
+            on_chip((t, d)), on_chip((t, k, n_shard)),
+            on_chip((k, n_shard, 8, d // 8)), on_chip((k, n_shard),
+                                                      jnp.int32),
+            on_chip((k, n_shard)), on_chip((k, h), jnp.int32), 1e-4,
+            k * n_shard, mode="plus", sigma=float(k))
+
+    with jax.enable_x64(False):
+        assert pallas_sdca.classes_fit(k, 126576, d, t, 4)
+        assert "tpu_custom_call" in lower(126576, 12656).compile().as_text()
+        assert not pallas_sdca.classes_fit(k, 253136, d, t, 4)
+        with pytest.raises(Exception, match="vmem"):
+            lower(253136, 25312).compile()
 
 
 # --- the logistic step solved in lanes: Mosaic takes it, with no chip ------
