@@ -140,10 +140,11 @@ def alpha_step(loss: str, a, z, qii, lam_n, smoothing: float = 1.0):
     step, a vector gives one independent step per element with the same
     operations in the same order.  Where the solve runs is the caller's
     choice: the ``fori`` kernels, the sparse and the block kernels call it
-    on one coordinate's scalars; the dense Pallas kernel calls an
-    iterative loss's step (``step_is_iterative``) once for all the shards
-    it advances in lockstep, one shard per lane (pallas_sdca
-    ``_solve_in_lanes``).
+    on one coordinate's scalars; the dense Pallas kernel calls a
+    closed-form step chain by chain on (1, 1) vectors (no value of its
+    step is 0-d: pallas_sdca ``_advance``) and an iterative loss's step
+    (``step_is_iterative``) once for all the shards it advances in
+    lockstep, one shard per lane (pallas_sdca ``_solve_in_lanes``).
 
     - hinge: the reference's exact sequence — projected gradient against the
       box's active face, vanishing-gradient no-op, qii==0 → 1, clip

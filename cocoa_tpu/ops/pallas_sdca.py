@@ -12,25 +12,41 @@ per-step margin is ``x·w₀ + sig_eff·(x·Δw)``, with **both dots computed
 in-kernel** against the VMEM-resident w₀ and Δw.  Round 3 precomputed
 margins0 = X·w₀ as one MXU matvec per round instead; round 4 retired it:
 the sampled row is already in VMEM for the axpy, so the w₀ dot is one more
-VPU reduce on data the step touches anyway (measured: free — scalar
-address generation bounds the step), while the matvec reads ALL of X every
-round — at localIterFrac = 0.1 that is 10× the rows the round touches
-(~90% of the demo round's HBM traffic, ~4 ms/round at epsilon scale).
+reduce on data the step touches anyway (measured on the chip: 19 ns of a
+518 ns lockstep step at epsilon, PERF.md §6, PR 39), while the matvec reads
+ALL of X every round — at localIterFrac = 0.1 that is 10× the rows the
+round touches (~90% of the demo round's HBM traffic, ~4 ms/round at epsilon
+scale).
 The sparse kernel (ops/pallas_sparse.py) has computed margins in-kernel
 since round 2 for the same reason.  Per step the kernel does the two row
 dots, the loss's coordinate update (ops/losses.py ``alpha_step``), one row
 axpy, and an α write.
 
-**Where the coordinate update runs.**  A closed-form loss's update (hinge's
-box projection, smooth_hinge's clip) is a handful of selects on the step's
-own 0-d values, emitted once per shard per step.  A loss whose update is an
-iterative solve (``losses.step_is_iterative``: logistic's ten Newton
-iterations) is not emitted per shard: the K shards that advance in lockstep
-put their (α, margin, ‖x‖²) on K lanes of one vector and ONE ``alpha_step``
-solves them together (:func:`_solve_in_lanes`) — K dependent chains of
-``exp`` and divide on single values cost K times what one chain on a vector
-does (PERF.md §6, PR 27).  The branch is static, taken at trace time from
-what the loss declares; no kernel here tests a loss's name.
+**Where the coordinate update runs: on the vector unit, all of it.**  No
+floating-point value of a step is 0-d.  The row's y, ‖x‖² and α are picked
+out of the state row, and the margin out of the row products, by reduces
+that keep their axes: (1, 1) vectors, which ``losses.alpha_step`` takes as
+it takes any one shape, and which broadcast into ``coef * x`` and the
+masked α write.  A reduce to 0-d is a trip to the scalar core and back, a
+float divide there a second one, and five reads, two divides and two splats
+a chain-step were 41% of the step: 880 ns a lockstep step of K = 8 chains at
+epsilon's shape with them, 518 without (PERF.md §6, PR 39).  Of the 518,
+319 are the grid iteration and its eight row DMAs alone (a kernel that only
+adds the fetched rows up: index maps, descriptor issue and waits, 40 ns a
+row whose 8 KB are 10 ns of HBM time), and the step's own vector work is
+the rest (taken out one at a time: the state row's picks and write 76, the
+closed-form solve 43, the two margin reduces 14); a longer row hides all of
+it (1.6 MB rows run at 92% of the HBM peak).  A closed-form loss's update
+(hinge's box projection, smooth_hinge's clip, the lasso's soft threshold)
+is a handful of selects, emitted once per shard per step.  A loss whose
+update is an iterative
+solve (``losses.step_is_iterative``: logistic's ten Newton iterations) is
+not emitted per shard: the K shards that advance in lockstep put their
+(α, margin, ‖x‖²) on K lanes of one vector and ONE ``alpha_step`` solves
+them together (:func:`_solve_in_lanes`) — K dependent chains of ``exp``
+and divide cost K times what one chain on a vector does (PERF.md §6,
+PR 27).  The branch is static, taken at trace time from what the loss
+declares; no kernel here tests a loss's name.
 
 **Folded rows.**  A (1, d) row uses one sublane — 1/8 of the VPU.  The
 caller reinterprets each dense row as an (8, d/8) tile instead (a free
@@ -241,23 +257,36 @@ def fold_rows(X: jax.Array, row_major: bool = False) -> jax.Array:
 STACK = 3  # lane-concatenated per-shard rows: [labels, sqn, alpha]
 
 
+def _pick(row, here):
+    """The one value of the (1, L) ``row`` where ``here`` is set, as a
+    (1, 1) vector: a masked lane reduce that keeps its axis, so the value
+    never leaves the vector unit."""
+    return jnp.sum(jnp.where(here, row, 0.0), axis=1, keepdims=True)
+
+
+def _total(tile):
+    """The sum of a 2-D tile as a (1, 1) vector: lanes, then sublanes,
+    each reduce keeping its axis (sublanes first measured the same)."""
+    return jnp.sum(jnp.sum(tile, axis=1, keepdims=True), axis=0,
+                   keepdims=True)
+
+
 def _solve_in_lanes(loss, triples, lam_n, smoothing):
-    """The new α of each chain in ``triples`` — chain c's 0-d (α, z, qii),
-    all at the same lockstep step — from ONE ``losses.alpha_step``: the
-    triples sit in lane c of three (1, 128) vectors (whole (8, 128) tiles
-    past 128 chains) and the step runs in the vector domain from its first
-    ``log`` to its last sigmoid.
+    """The new α of each chain in ``triples`` — chain c's (α, z, qii), each
+    a (1, 1) vector, all at the same lockstep step — from ONE
+    ``losses.alpha_step``: the triples sit in lane c of three (1, 128)
+    vectors (whole (8, 128) tiles past 128 chains) and the step runs in the
+    vector domain from its first ``log`` to its last sigmoid.  The new α's
+    come back as (1, 1) vectors, a masked reduce each.
 
     An iterative step (``losses.step_is_iterative``) is a dependent chain
     of transcendentals, the same length for every shard.  Emitted once per
-    shard on 0-d values, the K chains are paid one after another, each
-    ``exp`` and divide at the latency of a round trip between the scalar
-    and the vector side (PERF.md §6, PR 27: 155 cycles a Newton iteration
-    a chain at epsilon, K = 8; ~20 for all eight side by side in one
-    register).  Each lane computes what the scalar call computes,
-    operation for operation (``alpha_step`` is elementwise).  Lanes past
-    the last chain hold α = ½, z = 0, qii = 0, where the logistic step
-    stays put: nothing there is ever non-finite."""
+    shard, the K chains are paid one after another (PERF.md §6, PR 27: 155
+    cycles a Newton iteration a chain on 0-d values at epsilon, K = 8; ~20
+    for all eight side by side in one register).  Each lane computes what
+    the single call computes, operation for operation (``alpha_step`` is
+    elementwise).  Lanes past the last chain hold α = ½, z = 0, qii = 0,
+    where the logistic step stays put: nothing there is ever non-finite."""
     n = len(triples)
     rows = 1 if n <= LANES else -(-n // (SUBLANES * LANES)) * SUBLANES
     shape = (rows, LANES)
@@ -273,7 +302,7 @@ def _solve_in_lanes(loss, triples, lam_n, smoothing):
         q_v = jnp.where(here, qii, q_v)
     new_v = losses.alpha_step(loss, a_v, z_v, q_v, lam_n,
                               smoothing=smoothing)
-    return [jnp.sum(jnp.where(here, new_v, 0.0)) for here in mine]
+    return [_total(jnp.where(here, new_v, 0.0)) for here in mine]
 
 
 def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
@@ -283,21 +312,37 @@ def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
     (1, 3·LANES) lane concatenation: labels in lanes [0,128), ‖x‖²
     [128,256), α [256,384).
 
-    The concatenated layout is the kernel's key scalar-unit optimization:
-    all three per-step values arrive from ONE dynamic slice, and the α
-    write goes back through the same row — 2 dynamically-addressed VMEM
-    accesses per step instead of 5.  Address generation on the scalar core
-    is the per-step bottleneck, not the O(d) vector work (measured: the
-    frozen mode, which skips the Δw dot entirely, costs the same) — which
-    is also why the base margin is one more VPU reduce against the
-    VMEM-resident w₀ rather than a precomputed margins0 read (see the
-    module docstring: the whole-shard matvec it replaces was most of the
-    round's HBM traffic).
+    With the concatenated layout all three per-step values arrive from ONE
+    dynamic slice, and the α write goes back through the same row — 2
+    dynamically-addressed VMEM accesses per step instead of 5.  The base
+    margin is one more VPU reduce against the VMEM-resident w₀ rather than
+    a precomputed margins0 read (see the module docstring: the whole-shard
+    matvec it replaces was most of the round's HBM traffic).
+
+    **No floating-point value of a step is 0-d.**  y, ‖x‖², α and the
+    margin are (1, 1) vectors (:func:`_pick`, :func:`_total`: reduces that
+    keep their axes), ``losses.alpha_step`` runs elementwise on them, and
+    coef and the new α broadcast into ``coef * x`` and the masked state
+    write: nothing crosses to the scalar core and back.  Only the integer
+    address arithmetic (``idx``, ``blk``, ``sub_lane``) is the scalar
+    core's.  Measured on the chip at epsilon's shape (K = 8 interleaved,
+    d = 2,000; PERF.md §6, PR 39): 518 ns a lockstep step against 880 with
+    the five 0-d reads, two divides and two splats a chain-step this
+    replaced; frozen mode, one total fewer, 499; the grid iteration and
+    its eight row DMAs with no step at all, 319.  The three picks share the
+    one strided load of the row (its three 128-lane parts arrive on three
+    sublanes of one vreg); reading it as a (3, 128) tile, one masked reduce
+    for all three and a sublane reduce each to part them, measured 526: a
+    reduce more costs more than thirty selects fewer save, so the row stays
+    (1, 3·LANES).  A (1, 1) value must come from a reduce: Mosaic keeps a
+    reduce's result replicated along the reduced axis, which is what makes
+    the broadcasts free (a (1, 1) slice of a tile is refused: "broadcast
+    in both sublanes and lanes").
 
     A step is a *read* (one dynamic row read; y, ‖x‖², α and the margin
-    reduced to scalars), the loss's ``alpha_step``, and a *write* (coef,
+    reduced to (1, 1)), the loss's ``alpha_step``, and a *write* (coef,
     the Δw contribution, the masked α write).  A closed-form loss runs the
-    three chain by chain, ``alpha_step`` on the chain's own scalars.  An
+    three chain by chain, ``alpha_step`` on the chain's own values.  An
     iterative one (``losses.step_is_iterative``: a property the loss
     declares, static at trace time) runs every chain's read, ONE solve
     with a chain per lane (:func:`_solve_in_lanes`), then every chain's
@@ -310,12 +355,12 @@ def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
         sub_lane = idx - blk * LANES
         dw_k, w_k = dw_acc[...], w_ref[...]
         lane4 = jax.lax.broadcasted_iota(jnp.int32, (1, STACK * LANES), 1)
-        y = jnp.sum(jnp.where(lane4 == sub_lane, srow, 0.0))
-        sq = jnp.sum(jnp.where(lane4 == sub_lane + LANES, srow, 0.0))
-        a = jnp.sum(jnp.where(lane4 == sub_lane + 2 * LANES, srow, 0.0))
-        margin = jnp.sum(x * w_k)
+        y = _pick(srow, lane4 == sub_lane)
+        sq = _pick(srow, lane4 == sub_lane + LANES)
+        a = _pick(srow, lane4 == sub_lane + 2 * LANES)
+        margin = _total(x * w_k)
         if not frozen:
-            margin = margin + sig_eff * jnp.sum(x * dw_k)
+            margin = margin + sig_eff * _total(x * dw_k)
         return (blk, lane4, sub_lane, srow, x, y), (a, y * margin,
                                                     sq * qii_factor)
 
@@ -338,8 +383,8 @@ def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
         return
     for chain in chains:
         at, (a, z, qii) = read(*chain)
-        # the dual coordinate update is pure scalar jnp — shared with the
-        # fori_loop kernels via ops/losses.py (hinge = CoCoA.scala:166-178)
+        # the dual coordinate update is pure elementwise jnp — shared with
+        # the fori_loop kernels via ops/losses.py (hinge = CoCoA.scala:166-178)
         write(chain, at, a, losses.alpha_step(loss, a, z, qii, lam_n,
                                               smoothing=smoothing))
 

@@ -11,9 +11,10 @@ precompute the round's margins.  This kernel removes both:
   Δw in [128,256)), so a nonzero's margin contribution — which needs BOTH
   w[f] and Δw[f] — is ONE dynamic sublane slice + two 256-wide mask picks,
   and the scatter is a masked row update through the same slice.  Per
-  nonzero: 2 dynamically-addressed VMEM accesses.  (Scalar-core address
-  generation is the per-step bottleneck — same finding as the dense
-  kernel, see pallas_sdca._advance.)
+  nonzero: 2 dynamically-addressed VMEM accesses.  (What paces a step
+  here has not been measured on the chip; at the dense kernel it was the
+  step's 0-d values, each a trip to the scalar core and back, not the
+  addresses: pallas_sdca._advance, PERF.md §6, PR 39.)
 - margins are computed **in-kernel** from the VMEM-resident ``w``
   (``margin = x·w + sig_eff·(x·Δw)``, the same decomposition as
   ops/local_sdca.py ``mode_factors`` with margins0 evaluated on the fly),

@@ -187,13 +187,18 @@ class SolverPath:
     ops/pallas_sparse.py); ``hbm``: in HBM, only a segment's touched part
     of them on the chip (ops/pallas_sparse_hbm.py, the sparse kernel for
     sets whose d or n_shard outgrow VMEM; and every ``fori`` path).
-    ``step_solve``: how a coordinate step's new α is solved — ``lanes``:
-    the dense Pallas kernel under a loss whose step iterates
-    (ops/losses.step_is_iterative: logistic's Newton), the K shards it
-    advances in lockstep solved as one vector, a shard a lane;
-    ``scalar``: everything else — a closed-form step, and an iterative
-    one wherever a kernel still solves it chain by chain on one
-    coordinate's scalars (``fori``, the sparse and the block kernels).
+    ``step_solve``: where a coordinate step's values are while its new α
+    is solved — ``lanes``: the dense Pallas kernel under a loss whose step
+    iterates (ops/losses.step_is_iterative: logistic's Newton), the K
+    shards it advances in lockstep solved as one vector, a shard a lane
+    (and the class kernel, a class a sublane); ``vector``: the dense
+    Pallas kernel under a closed-form step (hinge, smoothed hinge, the
+    lasso's soft threshold), solved chain by chain on (1, 1) vectors — y,
+    ‖x‖², α and the margin come from reduces that keep their axes, so no
+    value of a step crosses to the scalar core (ops/pallas_sdca._advance;
+    ``lanes`` runs the same read and write); ``scalar``: everything else
+    — wherever a kernel still solves a step on one coordinate's 0-d
+    values (``fori``, the sparse, the stream and the block kernels).
     ``pass_slot_share``: of a sparse set's padded slots, the share one
     all-rows pass (the certificate's margins, the ``--accel`` jump) touches:
     1.0 where one block holds a shard or the rows' lengths are not known;
@@ -306,7 +311,9 @@ class SolverPath:
         if self.objective != "svm":
             rows += f", objective {self.objective}"
         solve = (", the shards' steps solved in lanes"
-                 if self.step_solve == "lanes" and self.classes == 1 else "")
+                 if self.step_solve == "lanes" and self.classes == 1
+                 else ", each step solved on the vector unit"
+                 if self.step_solve == "vector" else "")
         if self.classes > 1:
             solve += (f", {self.classes} class models one-vs-rest over the "
                       f"one sampled row"
@@ -557,9 +564,9 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                   and stores_row_major(ds.num_features)
                   else "device_default"),
             state="vmem" if pallas and not hbm_state else "hbm",
-            step_solve=("lanes" if pallas and not sparse
-                        and (classes > 1 or losses.step_is_iterative(loss))
-                        else "scalar"),
+            step_solve=("scalar" if not pallas or sparse
+                        else "lanes" if classes > 1
+                        or losses.step_is_iterative(loss) else "vector"),
             refused="" if pallas else refused,
             **placement)
     if block_chain == "xla":

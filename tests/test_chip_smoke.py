@@ -170,18 +170,27 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
     assert path.state == ("vmem" if pallas else "hbm")
     assert ("rows stored row-major" in path.describe()) == (
         rows == "row_major")
-    # hinge's step is a closed form: solved on the coordinate's own scalars
-    assert path.step_solve == "scalar"
+    # hinge's step is a closed form: on the dense Pallas kernel solved
+    # chain by chain on (1, 1) vectors, no value of it a scalar (PR 39);
+    # on the coordinate's own scalars in the fori and the sparse kernels
+    dense_pallas = bool(pallas and layout == "dense")
+    assert path.step_solve == ("vector" if dense_pallas else "scalar")
     assert "solved in lanes" not in path.describe()
+    assert ("each step solved on the vector unit" in path.describe()) == (
+        dense_pallas)
+    for closed_form in ("smooth_hinge", "lasso"):
+        assert resolve_solver_path(
+            ds, 8, math="fast", pallas=pallas,
+            loss=closed_form).step_solve == path.step_solve
     # logistic's iterates, and only the dense Pallas kernel solves its K
     # lockstep shards as one vector; the fori and the sparse kernels do not
     lanes = resolve_solver_path(ds, 8, math="fast", pallas=pallas,
                                 loss="logistic")
-    assert lanes.step_solve == (
-        "lanes" if pallas and layout == "dense" else "scalar")
+    assert lanes.step_solve == ("lanes" if dense_pallas else "scalar")
     assert ("solved in lanes" in lanes.describe()) == (
         lanes.step_solve == "lanes")
-    assert dataclasses.replace(lanes, step_solve="scalar") == path
+    assert "on the vector unit" not in lanes.describe()
+    assert dataclasses.replace(lanes, step_solve=path.step_solve) == path
 
 
 def test_logistic_run_says_its_steps_are_solved_in_lanes(
@@ -301,7 +310,10 @@ def test_epsilon_phase_with_interpreted_kernels(out, interpret_kernels):
     for name in ("seq", "block"):
         assert rep[name]["stopped"] == "target"
         assert rep[name]["alpha_devices"] == rep["data_devices"]
-        assert rep[name]["solver_path"]["step_solve"] == "scalar"   # hinge
+    # hinge on the dense Pallas kernel: no value of a step is a scalar; the
+    # block kernels still solve a coordinate on its own scalars
+    assert rep["seq"]["solver_path"]["step_solve"] == "vector"
+    assert rep["block"]["solver_path"]["step_solve"] == "scalar"
     assert rep["block"]["solver_path"]["kernel"] == "fused"
 
 

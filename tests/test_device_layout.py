@@ -236,7 +236,7 @@ def test_logistic_job_compiles_with_its_steps_solved_in_lanes(monkeypatch,
     """The epsilon-shaped logistic job (K = 8 interleaved shards, step
     groups of 2): the packed Newton solve of ops/pallas_sdca.py
     ``_solve_in_lanes`` — lane-iota selects into (1, 128) vectors, one
-    ``alpha_step`` on them, masked lane reduces back to scalars — lowers
+    ``alpha_step`` on them, masked lane reduces back to (1, 1) vectors — lowers
     through Mosaic inside the program's own ``run``, and the run's record
     says ``lanes``."""
     import jax

@@ -143,19 +143,22 @@ def _step_grid():
 def test_lane_packed_solve_is_the_scalar_solve(dtype, n):
     """``alpha_step`` is elementwise: n chains' logistic steps solved in
     the lanes of one (1, 128) vector (one (8, 128) past 128 chains) are
-    the n scalar steps, value for value — to 1e-12 in f64; to 4 ulp in
-    f32, where the CPU's vector and scalar ``exp`` differ in the last bit."""
+    the n single steps, value for value — to 1e-12 in f64; to 4 ulp in
+    f32, where the CPU's vector and scalar ``exp`` differ in the last bit.
+    A chain's values go in and come back as (1, 1) vectors, as the kernel
+    holds them."""
     from cocoa_tpu.ops.pallas_sdca import _solve_in_lanes
 
     lam_n = 2.0
     grid = (_step_grid() * 2)[:n]
-    triples = [tuple(jnp.asarray(v, dtype) for v in (a, z, q * lam_n))
+    triples = [tuple(jnp.full((1, 1), v, dtype) for v in (a, z, q * lam_n))
                for a, z, q in grid]
-    packed = np.asarray(jnp.stack(
-        _solve_in_lanes("logistic", triples, lam_n, 1.0)))
+    new = _solve_in_lanes("logistic", triples, lam_n, 1.0)
+    assert {v.shape for v in new} == {(1, 1)}
+    packed = np.asarray(jnp.stack(new)).ravel()
     each = np.asarray(jnp.stack(
         [losses.alpha_step("logistic", a, z, qii, lam_n)
-         for a, z, qii in triples]))
+         for a, z, qii in triples])).ravel()
     assert packed.dtype == each.dtype == np.dtype(dtype)
     assert np.all((each >= 0.0) & (each <= 1.0))
     tol = 1e-12 if dtype == "float64" else 4 * np.spacing(each)
@@ -171,11 +174,11 @@ def test_filler_lanes_stay_finite(dtype):
 
     from cocoa_tpu.ops.pallas_sdca import _solve_in_lanes
 
-    triples = [tuple(jnp.asarray(v, dtype) for v in t)
+    triples = [tuple(jnp.full((1, 1), v, dtype) for v in t)
                for t in [(0.0, 50.0, 0.0), (1.0, -50.0, 1e3), (0.5, 0.0, 0.0)]]
     with jax.debug_nans(True):
         new = _solve_in_lanes("logistic", triples, 2.0, 1.0)
-    assert float(new[2]) == 0.5
+    assert float(new[2][0, 0]) == 0.5
     # the filler's own values, alone: not so much as an inf on the way
     # (a chain at α = 1 in f32 does pass one: log(1/0), clipped to _U_MAX)
     half = jnp.full((1, 128), 0.5, dtype)
@@ -185,27 +188,104 @@ def test_filler_lanes_stay_finite(dtype):
     assert np.all(np.asarray(rest) == 0.5)
 
 
-def _kernel_jaxpr(loss, k, interleave):
+def _traced_round(loss, k, interleave, mode="plus", h=4):
     import jax
 
     from cocoa_tpu.ops.pallas_sdca import pallas_sdca_round
 
-    n_shard, d, h = 256, 16, 4     # two lane blocks: no (1, 128) state
+    n_shard, d = 256, 16           # two lane blocks: no (1, 128) state
     args = (jnp.zeros(d), jnp.zeros((k, n_shard)), jnp.zeros((k, n_shard, d)),
             jnp.ones((k, n_shard)), jnp.ones((k, n_shard)),
             jnp.zeros((k, h), jnp.int32))
-    return str(jax.make_jaxpr(lambda *a: pallas_sdca_round(
-        *a, 0.01, 1000, mode="plus", sigma=3.0, loss=loss, smoothing=S,
-        interleave=interleave, unroll=2))(*args))
+    return jax.make_jaxpr(lambda *a: pallas_sdca_round(
+        *a, 0.01, 1000, mode=mode, sigma=3.0, loss=loss, smoothing=S,
+        interleave=interleave, unroll=2))(*args)
+
+
+def _kernel_jaxpr(loss, k, interleave):
+    return str(_traced_round(loss, k, interleave))
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(j, "jaxpr", j)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk(sub)
+
+
+def _kernel_body(*args, **kw):
+    """The jaxpr of the traced round's one ``pallas_call``."""
+    (call,) = [e for e in _walk(_traced_round(*args, **kw).jaxpr)
+               if e.primitive.name == "pallas_call"]
+    return call.params["jaxpr"]
+
+
+# primitives whose operands map 1:1 onto their body's inputs (a cond's past
+# its index)
+_POSITIONAL = ("pjit", "jit", "closed_call", "cond")
+
+
+def _data_flow(jaxpr, data):
+    """``(found, outs)``: the equations of ``jaxpr`` (sub-jaxprs included)
+    that yield a 0-d float computed from data, and which of its outputs
+    carry data, given which of its inputs do (``data``).  What derives from
+    literals alone (the 0.0 and 1.0 handed to ``clip``, variables inside
+    its jaxpr) is a constant, not a value of the step."""
+    import jax.extend
+
+    found = []
+    env = dict(zip(jaxpr.invars, data))
+    env.update({v: True for v in jaxpr.constvars})
+
+    def carries(v):
+        return isinstance(v, jax.extend.core.Var) and env[v]
+
+    for eqn in jaxpr.eqns:
+        ins = [carries(v) for v in eqn.invars]
+        outs = [any(ins)] * len(eqn.outvars)
+        subs = list(_sub_jaxprs(eqn))
+        if subs and eqn.primitive.name in _POSITIONAL:
+            outs = [False] * len(outs)
+            for sub in subs:
+                inner, sub_outs = _data_flow(
+                    sub, ins[len(ins) - len(sub.invars):])
+                found += inner
+                outs = [a or b for a, b in zip(outs, sub_outs)]
+        elif subs:                  # a loop: everything in it may carry
+            for sub in subs:
+                found += _data_flow(sub, [any(ins)] * len(sub.invars))[0]
+        elif any(ins):
+            found += [str(eqn)[:120] for v in eqn.outvars
+                      if v.aval.shape == ()
+                      and jnp.issubdtype(v.aval.dtype, jnp.floating)]
+        env.update(zip(eqn.outvars, outs))
+    return found, [carries(v) for v in jaxpr.outvars]
+
+
+def _scalar_floats(jaxpr):
+    """Equations of a kernel body that yield a 0-d floating-point value
+    computed from the kernel's data: a value of the step that the scalar
+    core holds."""
+    return _data_flow(jaxpr, [True] * len(jaxpr.invars))[0]
 
 
 @pytest.mark.parametrize("interleave", [True, False])
 @pytest.mark.parametrize("loss", ALL)
 def test_only_an_iterative_step_is_solved_in_lanes(loss, interleave):
     """Read off the traced kernel: a closed-form loss's program holds no
-    transcendental and no (1, 128) packing vector (the scalar branch, as
-    before); logistic's holds ONE Newton chain per lockstep step on such a
-    vector, whatever K is — not K of them on 0-d values."""
+    transcendental and no (1, 128) packing vector (the chain-by-chain
+    branch, on (1, 1) vectors); logistic's holds ONE Newton chain per
+    lockstep step on such a vector, whatever K is — not K of them, a chain
+    each."""
     import re
 
     k, unroll = 3, 2
@@ -219,6 +299,42 @@ def test_only_an_iterative_step_is_solved_in_lanes(loss, interleave):
     assert len(exps) == unroll * (losses._NEWTON_ITERS + 1)
     assert {shape for _, shape in exps} == {"1,128"}
     assert text.count(" = log ") == unroll
+
+
+@pytest.mark.parametrize("interleave", [True, False])
+@pytest.mark.parametrize("loss, mode, h", [
+    (loss, mode, h) for loss in ALL
+    for mode, h in (("plus", 4), ("frozen", 4), ("plus", 5))   # 5: a tail
+] + [(rule, "prox", h) for rule in losses.PROX_RULES for h in (4, 5)])
+def test_no_value_of_a_step_is_a_scalar(loss, mode, h, interleave):
+    """Read off the traced kernel body, both forms and all four rules: no
+    floating-point value in it is 0-d.  y, the norm, alpha and the margin
+    are (1, 1) vectors from reduces that keep their axes, ``alpha_step``
+    runs elementwise on them, and coef and the new alpha broadcast into the
+    row update and the state write: a later edit that reduces to a scalar
+    (``jnp.sum(v)``), and so sends a value to the scalar core and back,
+    eight chains a lockstep step, fails here (PERF.md section 6, PR 39)."""
+    body = _kernel_body(loss, 3, interleave, mode=mode, h=h)
+    assert any(e.primitive.name == "reduce_sum" for e in _walk(body))
+    assert _scalar_floats(body) == []
+
+
+def test_the_scalar_reader_sees_a_scalar_trip():
+    """The check above is not vacuous: a kernel body that reduces to 0-d
+    and splats the value back is reported, a keepdims reduce is not, and
+    neither is a literal handed to ``clip``."""
+    import jax
+
+    def body(keep):
+        def f(x):
+            m = (jnp.sum(jnp.sum(x, axis=1, keepdims=True), axis=0,
+                         keepdims=True) if keep else jnp.sum(x))
+            return jnp.clip(x * m, 0.0, 1.0)
+        return jax.make_jaxpr(f)(jnp.ones((8, 16), jnp.float32)).jaxpr
+
+    assert _scalar_floats(body(True)) == []
+    found = _scalar_floats(body(False))
+    assert found and "reduce_sum" in found[0]
 
 
 def test_iterative_steps_are_declared_by_the_loss():
