@@ -1541,7 +1541,8 @@ def main(argv=None) -> int:
                                        dtype=dtype, mesh=mesh,
                                        eval_dense=eval_dense,
                                        hot_cols=hot_n,
-                                       cache=train_handle)
+                                       cache=train_handle,
+                                       rectangle=not cfg.just_cocoa)
                     status = "off"
                     if train_handle is not None:
                         status = populate_whole(
@@ -1587,7 +1588,8 @@ def main(argv=None) -> int:
                                             dtype=dtype, mesh=mesh,
                                             eval_dense=eval_dense,
                                             hot_cols=hot_n,
-                                            cache=test_handle)
+                                            cache=test_handle,
+                                            rectangle=not cfg.just_cocoa)
                     status = "off"
                     if test_handle is not None:
                         status = populate_whole(

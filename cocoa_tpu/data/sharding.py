@@ -161,7 +161,8 @@ class ShardedDataset:
                                       #   row of shard k now at position j
                                       #   was built at position row_order[k, j]
     # the STREAM storage of a sparse dataset (:func:`stream_suits`): rows of
-    # thousands of nonzeros are not padded to the longest.  sp_indices /
+    # a hundred nonzeros or of thousands, the longest a few times the mean,
+    # are not padded to the longest.  sp_indices /
     # sp_values are then (K, n_pieces, STREAM_PIECE): each shard's nonzeros
     # as one run of slots, row after row, a row starting on a STREAM_ALIGN
     # boundary (the slots up to it hold column 0, value 0), and
@@ -308,7 +309,7 @@ STREAM_ALIGN = 8                 # slots a row's start is aligned to (the
 STREAM_PIECE = 128               # slots a piece: one full lane row
 STREAM_SPARE_PIECES = 8          # past a shard's last row: a kernel's last
                                  # chunk of a row may read this far
-STREAM_MIN_MEAN = 256            # rows this long on average, and
+STREAM_MIN_MEAN = 96             # rows this long on average, and
 STREAM_RECTANGLE_RATIO = 2.0     # a rectangle this many times the stream
 
 
@@ -321,12 +322,18 @@ def stream_row_slots(row_nnz) -> np.ndarray:
 
 def stream_suits(row_nnz, itemsize: int = 4) -> bool:
     """Whether rows of these lengths are kept as a stream: from the lengths
-    the loader observes alone.  Long rows (the mean past STREAM_MIN_MEAN:
-    the nonzeros of a step are then its work, and the stream's own padding
-    is under 1.5%) that the rectangle would at least double.  Short-rowed
-    sets (kddb, rcv1, every small test set) keep the rectangle and with it
-    their bytes, kernels and trajectories.  float32 only: the stream's
-    passes are the kernels of ops/pallas_longrows.py."""
+    the loader observes alone.  Rows of a hundred nonzeros or more on
+    average (the mean past STREAM_MIN_MEAN: a step's work is then mostly
+    its nonzeros, not its fetch — the kernels' DMA ring runs across rows,
+    so a row shorter than a chunk does not wait for its own — and the
+    stream's own padding is under 4%) that the rectangle would at least
+    double: url (115.6 a row, the longest 4 x that: 9.8 GB as a rectangle,
+    2.3 as a stream) as much as webspam (3,727).  Short-rowed sets (kddb:
+    29 a row, a rectangle 1.9 x its stream; rcv1: 73; every small test set)
+    keep the rectangle and with it their bytes, kernels and trajectories.
+    The three points the gate sits between and what each read on the v5e:
+    PERF.md §6, PR 41.  float32 only: the stream's passes are the kernels
+    of ops/pallas_longrows.py."""
     row_nnz = np.asarray(row_nnz, np.int64)
     if itemsize != 4 or not row_nnz.size:
         return False
@@ -692,6 +699,7 @@ def shard_dataset(
     eval_dense: bool = False,
     hot_cols: int = 0,
     cache=None,
+    rectangle: bool = False,
 ) -> ShardedDataset:
     """Partition ``data`` into K balanced contiguous shards and device_put them.
 
@@ -725,6 +733,12 @@ def shard_dataset(
     (the zero-parse warm path lives in data/ingest.load_cached_dataset;
     here the parse is already paid, so a hit saves the slab build and a
     miss populates for the next process).
+
+    ``rectangle=True``: the caller runs a solver that reads sparse rows as
+    the (K, n_shard, W) rectangle alone (the primal baselines: the CLI
+    under ``--justCoCoA=false``), so the rows stay one whatever their
+    lengths;
+    by default the lengths decide (:func:`stream_suits`).
     """
     n, d = data.n, data.num_features
     layout = resolve_layout(data, layout, mesh)
@@ -753,11 +767,14 @@ def shard_dataset(
                 f"row nnz {int(row_nnz.max())} exceeds max_nnz {width}"
             )
 
-    # rows of thousands of nonzeros, the longest many times the mean: kept
-    # as a stream, not padded to the longest (from the lengths alone; a
-    # single process's plain sparse layout only: the hybrid split, a forced
-    # width and the multi-process assembly keep the rectangle)
+    # rows of a hundred nonzeros or of thousands, the longest a few times
+    # the mean: kept as a stream, not padded to the longest
+    # (:func:`stream_suits`: from the lengths alone; a single process's
+    # plain sparse layout only: the hybrid split, a forced width, the
+    # multi-process assembly and a caller that asks for it keep the
+    # rectangle)
     stream = (layout == "sparse" and not hot_cols and max_nnz is None
+              and not rectangle
               and not (mesh is not None and jax.process_count() > 1)
               and stream_suits(row_nnz, np_dtype.itemsize))
     if stream:

@@ -1,5 +1,6 @@
-"""Sparse rows in pieces: the kernels of sets whose rows hold thousands of
-nonzeros (webspam: 3,727 a row, the longest 8.8 times that).
+"""Sparse rows in pieces: the kernels of sets whose rows hold a hundred
+nonzeros or thousands and are too uneven for a rectangle (url: 115.6 a
+row, the longest 4 times that; webspam: 3,727, the longest 8.8 times).
 
 ``data/sharding.py`` keeps such a set as a **stream**: a shard's nonzeros
 as one run of (column, value) slots, row after row, each row starting on an
@@ -35,13 +36,28 @@ as they are stored:
   alone (:func:`margin_form`, reported as ``SolverPath.margin``);
 - ``axpy``: v += c_i x_i over a list of rows (the ``--accel`` jump).
 
-A row's slots reach the scalar core by DMA, HBM to SMEM, ``CHUNK_PIECES``
+A row's slots reach the scalar core by DMA, HBM to SMEM, a **chunk** of
 pieces at a time through a two-slot ring (addresses must be scalars), so a
 step's tables follow the row's own length and a row of any length takes as
-many chunks as it needs: SMEM holds two chunks, not a row.  Per nonzero one
-dynamic sublane read of the d-vector and a masked multiply-add (dots) or a
-masked single-lane store (axpy), as in ``pallas_sparse_hbm``; the cost is
-paced by nonzeros.
+many chunks as it needs: SMEM holds two chunks, not a row.  **The ring
+runs across rows** (PR 41): a pass knows the next row's address before it
+works on this one (the row block's ``start`` table is in SMEM; the chain's
+sampled rows are drawn before the round), so while the scalar core walks a
+chunk the ring's other slot is already taking what comes next — the row's
+next chunk or, at its last, the next row's first.  Only the first row of a
+``ROW_BLOCK`` waits for its own fetch.  In the chain a row of one chunk is
+fetched once: the update reads the chunk the dot left in the ring; a
+longer row's chunks come round a second time, its first behind the dot's
+last.  A chunk is ``CHUNK_PIECES`` pieces whatever the rows: with the ring
+across rows its bytes cost nothing that shows (url's rows fill a ninth of
+the slots a chunk brings and run alike at 4 pieces and at 8; what costs is
+a row cut in two: PERF.md §6, PR 41), so nothing chooses it;
+:func:`chunk_fill` says what share of what it moves is nonzeros.  Per
+nonzero one dynamic sublane read of the d-vector and a masked multiply-add
+(dots) or a masked single-lane store (axpy), as in ``pallas_sparse_hbm``;
+with the fetch hidden the cost is paced by nonzeros plus a step's own
+fixed work, which at rows of a hundred nonzeros is no longer small beside
+them (PERF.md §6, PR 41, has both).
 """
 
 from __future__ import annotations
@@ -50,6 +66,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -144,31 +161,54 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
                 pltpu.make_async_copy(vals_hbm.at[src], vbuf.at[slot],
                                       sem.at[1, slot]))
 
-    def over_row(start, cnt, body, init):
-        """``init = body(slot, j0, lo, hi, init)`` for each chunk of the row
-        whose first slot is ``start`` ALIGN-slot groups into the stream:
-        the chunk sits in ring slot ``slot``, its groups [lo, hi) hold the
-        row's slots, and its slot 0 is slot ``j0`` of the row (negative in
-        the first chunk of a row that starts inside a piece)."""
-        piece, lead = start >> 4, (start & 15) << 3
-        span = lead + cnt
-        n_chunks = jnp.where(cnt > 0, (span + (CHUNK - 1)) // CHUNK, 0)
+    def fetch(piece, c, slot):
+        for cp in copies(piece, c, slot):
+            cp.start()
 
-        @pl.when(n_chunks > 0)
-        def _prime():
-            for cp in copies(piece, 0, 0):
-                cp.start()
+    def row_at(i):
+        """Row ``i`` of the block as ``(piece, lead, span, chunks)``: its
+        first chunk starts at ``piece``, its slots are [lead, span) of the
+        chunks' run (``lead`` > 0: the row starts inside a piece), and it
+        takes ``chunks`` of them — one even where it holds nothing, so
+        that every row is one link of the ring."""
+        start, cnt = itabs[0][0, i], itabs[1][0, i]
+        lead = (start & 15) << 3
+        span = lead + cnt
+        return (start >> 4, lead, span,
+                jnp.maximum((span + (CHUNK - 1)) // CHUNK, 1))
+
+    def over_row(row, s0, body, init, after, moves=True):
+        """``init = body(slot, j0, lo, hi, init)`` for each chunk of
+        ``row`` (:func:`row_at`): chunk c sits in ring slot ``slot`` =
+        (s0 + c) & 1, its groups [lo, hi) hold the row's slots, and its
+        slot 0 is slot ``j0`` of the row (negative in the first chunk of a
+        row that starts inside a piece).  **The ring runs across rows:**
+        the row's chunk 0 is in flight in slot ``s0`` when this is called,
+        and before chunk c is waited for the other slot is given what
+        comes next: the row's chunk c + 1 or, at its last chunk,
+        whatever ``after(slot)`` starts (the next row's chunk 0; the
+        chain: this row's chunk 0 again, for its update).  ``moves``
+        false (a traced condition or the constant): the row's one chunk is
+        still in slot ``s0`` from the pass before, so nothing is started
+        and nothing waited for."""
+        piece, lead, span, n_chunks = row
 
         def chunk(c, acc):
-            slot = c & 1
+            slot = (s0 + c) & 1
 
-            @pl.when(c + 1 < n_chunks)
+            @pl.when(moves & (c + 1 < n_chunks))
             def _next():
-                for cp in copies(piece, c + 1, 1 - slot):
-                    cp.start()
+                fetch(piece, c + 1, 1 - slot)
 
-            for cp in copies(piece, c, slot):
-                cp.wait()
+            @pl.when(moves & (c + 1 == n_chunks))
+            def _after():
+                after(1 - slot)
+
+            @pl.when(moves)
+            def _wait():
+                for cp in copies(piece, c, slot):
+                    cp.wait()
+
             here = jnp.minimum(span - c * CHUNK, CHUNK)
             return body(slot, c * CHUNK - lead,
                         jnp.where(c == 0, lead >> 3, 0),
@@ -190,7 +230,7 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
 
         return lax.fori_loop(lo // per, (hi + (per - 1)) // per, piece, init)
 
-    def dot_row(start, cnt):
+    def dot_row(row, s0, after):
         """x . vec_sc: a masked multiply-add into a lane vector per nonzero,
         one cross-lane sum a row.  The slots between a row's end and the
         next ALIGN boundary hold column 0, value 0."""
@@ -208,15 +248,17 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
 
             return over_groups(slot, lo, hi, group, acc)
 
-        return jnp.sum(over_row(start, cnt, body,
-                                jnp.zeros((1, LANES), dtype)))
+        return jnp.sum(over_row(row, s0, body,
+                                jnp.zeros((1, LANES), dtype), after))
 
-    def axpy_row(start, cnt, coef):
+    def axpy_row(row, s0, coef, after, moves=True):
         """vec_sc += coef x: a masked store of the one lane each nonzero
         owns.  A row has no column twice, so within a group no store feeds
         a later slot's read and the group's reads all go first; a slot past
         the row's length (column 0, value 0) stores nothing: it would put
         back a lane read before this group's stores."""
+        cnt = row[2] - row[1]
+
         def body(slot, j0, lo, hi, carry):
             def group(pc, g, carry):
                 at = pc * PIECE + g * GROUP
@@ -234,41 +276,71 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
 
             return over_groups(slot, lo, hi, group, carry)
 
-        over_row(start, cnt, body, jnp.int32(0))
+        over_row(row, s0, body, jnp.int32(0), after, moves)
 
     def put(ref, r, value):
         pltpu.store(ref.at[0, pl.ds(_index(r >> 7), 1)],
                     jnp.broadcast_to(value, (1, LANES)).astype(dtype),
                     mask=lane == (r & (LANES - 1)))
 
-    def one_row(i, carry):
+    # the block's first row is the one fetch nothing hides: the tables of
+    # the block before are no longer in SMEM when its last row runs
+    fetch(row_at(0)[0], 0, 0)
+
+    def one_row(i, s0):
+        """Row ``i`` of the block, its chunk 0 in flight in ring slot
+        ``s0``; returns the slot the next row's is in flight in."""
         r = b * ROW_BLOCK + i                  # the row within the group
-        start, cnt = itabs[0][0, i], itabs[1][0, i]
+        row = row_at(i)
+        n_chunks = row[3]
+
+        def next_row(slot):
+            @pl.when(i + 1 < ROW_BLOCK)
+            def _start():
+                after = jnp.minimum(i + 1, ROW_BLOCK - 1)
+                fetch(itabs[0][0, after] >> 4, 0, slot)
+
         if mode == "dots":
-            put(out, r, dot_row(start, cnt))
-        elif mode == "axpy":
-            axpy_row(start, cnt, ftabs[0][0, i])
-        else:
-            prev = itabs[2][0, i]
-            y, qii, a0 = (t[0, i] for t in ftabs[-3:])
-            # a row this round already stepped on: alpha is that step's
-            pj = jnp.maximum(prev, 0)
-            prow = out[0, pl.ds(pj >> 7, 1)]
-            a_prev = jnp.sum(jnp.where(lane == (pj & (LANES - 1)), prow, 0.0))
-            a = jnp.where(prev >= 0, a_prev, a0)
-            # vec_sc holds v = w + sig_eff dw_k, or (split) dw_k beside
-            # the table of x . w
-            sig_eff = step_consts["sig_eff"]
-            margin = dot_row(start, cnt)
-            if split:
-                margin = ftabs[0][0, i] + sig_eff * margin
-            new_a = losses.alpha_step(
-                step_consts["loss"], a, y * margin, qii,
-                step_consts["lam_n"], smoothing=step_consts["smoothing"])
-            coef = y * (new_a - a) / step_consts["coef_div"]
-            axpy_row(start, cnt, coef if split else sig_eff * coef)
-            put(out, r, new_a)
-        return carry
+            put(out, r, dot_row(row, s0, next_row))
+            return (s0 + n_chunks) & 1
+        if mode == "axpy":
+            axpy_row(row, s0, ftabs[0][0, i], next_row)
+            return (s0 + n_chunks) & 1
+        prev = itabs[2][0, i]
+        y, qii, a0 = (t[0, i] for t in ftabs[-3:])
+        # a row this round already stepped on: alpha is that step's
+        pj = jnp.maximum(prev, 0)
+        prow = out[0, pl.ds(pj >> 7, 1)]
+        a_prev = jnp.sum(jnp.where(lane == (pj & (LANES - 1)), prow, 0.0))
+        a = jnp.where(prev >= 0, a_prev, a0)
+        # vec_sc holds v = w + sig_eff dw_k, or (split) dw_k beside
+        # the table of x . w
+        sig_eff = step_consts["sig_eff"]
+        # a row of one chunk is walked once: the update reads the chunk
+        # the dot left in the ring, and the next row's is already on its
+        # way; a longer row's chunk 0 comes round again behind the dot
+        again = n_chunks > 1
+
+        def after_dot(slot):
+            @pl.when(again)
+            def _again():
+                fetch(row[0], 0, slot)
+
+            @pl.when(~again)
+            def _on():
+                next_row(slot)
+
+        margin = dot_row(row, s0, after_dot)
+        if split:
+            margin = ftabs[0][0, i] + sig_eff * margin
+        new_a = losses.alpha_step(
+            step_consts["loss"], a, y * margin, qii,
+            step_consts["lam_n"], smoothing=step_consts["smoothing"])
+        coef = y * (new_a - a) / step_consts["coef_div"]
+        axpy_row(row, jnp.where(again, (s0 + n_chunks) & 1, s0),
+                 coef if split else sig_eff * coef, next_row, again)
+        put(out, r, new_a)
+        return jnp.where(again, s0, 1 - s0)
 
     lax.fori_loop(0, ROW_BLOCK, one_row, jnp.int32(0))
 
@@ -374,6 +446,17 @@ def rows_axpy(vec, sp_indices, sp_values, start, cnt, coefs,
                   _as_pieces(sp_indices), _as_pieces(sp_values),
                   _lane_blocked(vec, d_rows))
     return out.reshape(-1)[:vec.shape[0]]
+
+
+def chunk_fill(row_ptr, row_len) -> float:
+    """Of the slots the ring moves in one pass over every row, the share
+    that hold a nonzero, counted on the host: a row takes the chunks from
+    the piece it starts in to its last slot, one even where it holds
+    nothing (``row_ptr``: a row's first slot / ALIGN)."""
+    row_len = np.asarray(row_len, np.int64)
+    lead = np.asarray(row_ptr, np.int64) % (PIECE // ALIGN) * ALIGN
+    moved = np.maximum(-(-(lead + row_len) // CHUNK), 1).sum() * CHUNK
+    return float(row_len.sum() / moved) if moved else 1.0
 
 
 def shard_margins(w, shard: dict, interpret: bool):

@@ -1576,9 +1576,10 @@ def check_shards(ds: ShardedDataset, rectangle: bool = False) -> None:
     if rectangle and ds.sp_row_ptr is not None:
         raise ValueError(
             "this solver reads padded-CSR rectangles; the dataset's rows "
-            "(thousands of nonzeros, the longest many times the mean) are "
-            "kept as a stream, which the SDCA family solves "
-            "(run_cocoa / run_minibatch_cd)")
+            "(a hundred nonzeros or more on average, the longest a few "
+            "times the mean) are kept as a stream, which the SDCA family "
+            "solves (run_cocoa / run_minibatch_cd): shard them with "
+            "shard_dataset(..., rectangle=True) for this one")
     if np.any(ds.counts <= 0):
         raise ValueError(
             f"every shard needs at least one example; shard sizes are "

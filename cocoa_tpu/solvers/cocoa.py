@@ -206,11 +206,18 @@ class SolverPath:
     row (ops/rows.pass_slots, counted from the lengths: about a half for
     rows in length order, data/sharding.order_rows_by_length).
     ``storage`` (sparse sets): ``rectangle``: rows padded to the longest,
-    (K, n_shard, W); ``stream``: rows of thousands of nonzeros kept end to
-    end (data/sharding.stream_suits), solved by the kernels of
+    (K, n_shard, W); ``stream``: rows of a hundred nonzeros or of
+    thousands, the longest a few times the mean, kept end to end
+    (data/sharding.stream_suits), solved by the kernels of
     ops/pallas_longrows.py, which hold ONE d-vector in VMEM at a time
     (``state`` reads ``vmem``): w during the passes over rows, and during a
-    shard's chain what ``margin`` says.  ``margin`` (the stream's Pallas
+    shard's chain what ``margin`` says.  ``chunk_pieces`` (the stream
+    only; None anywhere else): the 128-slot pieces one DMA of those
+    kernels' ring brings to SMEM (their constant CHUNK_PIECES);
+    ``chunk_fill``: of the slots the ring moves in one pass over every row,
+    the share that hold a nonzero (pallas_longrows.chunk_fill, counted on
+    the host from the rows' starts and lengths; None where they are not
+    known): webspam's rows 0.87, url's 0.11.  ``margin`` (the stream's Pallas
     kernels, once :meth:`for_mode` knows the algorithm; None anywhere
     else): ``combined``: the chain holds v = w + sigma' dw_k and a step's
     margin is one dot against it; ``split``: it holds dw_k, and x . w of
@@ -257,6 +264,8 @@ class SolverPath:
     margin: Optional[str] = None
     slot_fill: Optional[float] = None
     longest_row: int = 0
+    chunk_pieces: Optional[int] = None
+    chunk_fill: Optional[float] = None
     refused: str = ""
     objective: str = "svm"
     form: Optional[str] = None
@@ -327,7 +336,10 @@ class SolverPath:
             fill = ("" if self.slot_fill is None
                     else f" (slot fill {self.slot_fill:.3f})")
             solve += (f", rows kept as a stream{fill}, the longest "
-                      f"{self.longest_row} nonzeros")
+                      f"{self.longest_row} nonzeros, fetched "
+                      f"{self.chunk_pieces} pieces a chunk"
+                      + ("" if self.chunk_fill is None
+                         else f" (chunk fill {self.chunk_fill:.3f})"))
             if self.margin:
                 solve += f", margin {self.margin}"
         return (f"{what}, {self.layout} layout{rows}{solve}, on "
@@ -357,21 +369,26 @@ def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
 
 
 def _slot_stats(ds: ShardedDataset) -> tuple:
-    """``(slot_fill, longest_row)`` of a sparse dataset (:class:`SolverPath`),
-    counted once on the host from the row lengths it carries and kept on
-    it."""
+    """``(slot_fill, longest_row, chunk_fill)`` of a sparse dataset
+    (:class:`SolverPath`; ``chunk_fill`` None off the stream), counted once
+    on the host from the row lengths it carries and kept on it."""
     stream = ds.sp_row_ptr is not None
     row_len = ds.sp_row_len if stream else getattr(ds, "_row_len_cache",
                                                    None)
     widest = int(ds.sp_row_iota.shape[-1] if stream
                  else ds.sp_indices.shape[-1])
     if not isinstance(row_len, jax.Array):      # none, or a shape alone
-        return None, widest
+        return None, widest, None
     cached = getattr(ds, "_slot_stats_cache", None)
     if cached is None:
         lens = np.asarray(row_len, np.int64)
+        fill = None
+        if stream:
+            from cocoa_tpu.ops.pallas_longrows import chunk_fill
+
+            fill = chunk_fill(ds.sp_row_ptr, lens)
         cached = (float(lens.sum() / max(1, ds.sp_indices.size)),
-                  int(lens.max(initial=0)))
+                  int(lens.max(initial=0)), fill)
         ds._slot_stats_cache = cached
     return cached
 
@@ -543,7 +560,12 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         storage="stream" if stream else "rectangle",
     )
     if sparse:
-        placement["slot_fill"], placement["longest_row"] = _slot_stats(ds)
+        (placement["slot_fill"], placement["longest_row"],
+         placement["chunk_fill"]) = _slot_stats(ds)
+    if stream:
+        from cocoa_tpu.ops.pallas_longrows import CHUNK_PIECES
+
+        placement["chunk_pieces"] = CHUNK_PIECES
     if block_size <= 0:
         from cocoa_tpu.ops import losses
         from cocoa_tpu.ops.pallas_sdca import (class_rows, dense_form,

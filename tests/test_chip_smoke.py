@@ -149,7 +149,10 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
         "inner", "kernel", "chain", "interpret", "layout", "platform",
         "devices", "shards_per_device", "rows", "state", "step_solve",
         "pass_slot_share", "storage", "margin", "slot_fill", "longest_row",
-        "refused", "objective", "form", "classes", "lane_fill"]
+        "chunk_pieces", "chunk_fill", "refused", "objective", "form",
+        "classes", "lane_fill"]
+    # no stream, no ring
+    assert (path.chunk_pieces, path.chunk_fill) == (None, None)
     # a binary set: one model, no class axis
     assert (path.classes, path.lane_fill) == (1, None)
     # the dual family; which dense kernel runs, on the dense Pallas path

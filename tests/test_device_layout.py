@@ -552,6 +552,58 @@ def test_stream_kernels_compile_at_webspam_size(one_chip, what):
     assert not re.search(rf"\[{k * pieces},1,128\][^ ]* copy\(", hlo)
 
 
+# url as the benchmark holds it (chipbench/configs/url.json: the whole
+# published set): 8 shards of 299,517 rows, three 2^24-slot windows each
+URL = dict(k=8, n=2396130, d=3231961, h=29951, pieces=3 * (1 << 17))
+
+
+@pytest.mark.parametrize("what", ["margins", "axpy", "round"])
+def test_stream_kernels_compile_at_url_size(one_chip, what):
+    """The kernels of ops/pallas_longrows.py at url's shapes: Mosaic takes
+    the ring that runs across rows (a DMA started in one row's loop and
+    waited for in the next row's), and the stream is read where it is
+    stored."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.data.sharding import pad_rows, split_sizes
+    from cocoa_tpu.ops import pallas_longrows as plr
+
+    k, d, h, n_pieces = (URL[x] for x in ("k", "d", "h", "pieces"))
+    n_shard = pad_rows(int(split_sizes(URL["n"], k).max()))
+
+    def sds(shape, dt=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    rows, irows = sds((k, n_shard)), sds((k, n_shard), jnp.int32)
+    stream = dict(sp_indices=sds((k, n_pieces, 128), jnp.int32),
+                  sp_values=sds((k, n_pieces, 128)), sp_row_ptr=irows,
+                  sp_row_len=irows)
+    assert plr.longrows_fits(d)
+    with jax.enable_x64(False):
+        if what == "margins":
+            fn = lambda w, sh: plr.shard_margins(w, sh, False)  # noqa: E731
+            args = (sds((d,)), stream)
+        elif what == "axpy":
+            fn = lambda c, sh, w: plr.shards_axpy(c, sh, w, False)  # noqa: E731
+            args = (rows, stream, sds((d,)))
+        else:
+            fn = lambda w, a, sh, y, q, i: plr.pallas_longrows_round(  # noqa: E731
+                w, a, sh["sp_indices"], sh["sp_values"], sh["sp_row_ptr"],
+                sh["sp_row_len"], y, q, i, 1e-5, URL["n"], mode="plus",
+                sigma=float(k))
+            args = (sds((d,)), rows, stream, rows, rows,
+                    sds((k, h), jnp.int32))
+        compiled = jax.jit(fn).lower(*args).compile()
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < 8 * d * 4, stats.temp_size_in_bytes
+    hlo = compiled.as_text()
+    name = {"margins": "dots", "axpy": "axpy", "round": "chain"}[what]
+    assert f"pallas_longrows_{name}" in hlo
+    assert not re.search(rf"\[{k},{n_pieces},128\][^ ]* copy\(", hlo)
+    assert not re.search(rf"\[{k * n_pieces},1,128\][^ ]* copy\(", hlo)
+
+
 # --- epsilon's lasso (the prox family's cell), with no chip -----------------
 
 LASSO = dict(k=8, cols=2000, n=400000, h=25, lam=72.0)

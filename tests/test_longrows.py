@@ -146,6 +146,11 @@ def test_stored_slots_do_not_follow_the_longest_row():
     ("kddb", np.r_[np.full(999, 29), 64], False),
     ("rcv1", np.r_[np.full(999, 73), 548], False),
     ("long_but_even", np.full(1000, 4000), False),
+    # url: a hundred-odd nonzeros a row, the longest 4.4 x that (PR 41)
+    ("url", np.r_[np.full(999, 116), 512], True),
+    ("just_under_the_gate", np.r_[np.full(999, 95), 512], False),
+    ("at_the_gate", np.r_[np.full(999, 96), 512], True),
+    ("url_but_even", np.r_[np.full(999, 116), 200], False),
 ])
 def test_the_rectangle_stays_for_sets_it_suits(name, lens, want):
     assert stream_suits(lens) == want

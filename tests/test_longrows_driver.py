@@ -31,6 +31,9 @@ def test_resolver_reports_the_stream(ds):
     assert path.interpret and path.longest_row == LONGEST
     assert 0.85 <= path.slot_fill <= 1.0    # (the cell's: over 1 / 1.10)
     assert "kept as a stream" in path.describe()
+    # the ring's chunk (the kernels' constant) and what it moves
+    assert path.chunk_pieces == 8 and 0.15 < path.chunk_fill < 0.4
+    assert "fetched 8 pieces a chunk (chunk fill 0." in path.describe()
     # how the chain takes a margin follows the algorithm, which the driver
     # knows: mini-batch CD never reads dw_k, so no v gives it back
     assert path.margin is None and "margin" not in path.describe()
