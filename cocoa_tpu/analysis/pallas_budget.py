@@ -206,8 +206,9 @@ def check_gate_estimate_agreement() -> list:
                 flag("cocoa_tpu.ops.pallas_chain",
                      f"fused_fits admits k={k} B={b} d={d} but "
                      f"estimate={est} exceeds FUSED_VMEM_BUDGET")
-        # dense folded-layout SDCA kernel: the unroll pickers must only
-        # ever choose group sizes whose estimates respect their budgets
+        # dense folded-layout SDCA kernel: the pickers must only ever
+        # choose a group size (shard-major) or a ring depth (interleaved)
+        # whose estimate respects its budget
         s = sdca.pick_unroll(n_shard, d, itemsize, h=b)
         if s > 0 and sdca.vmem_estimate(n_shard, d, itemsize, s) > \
                 sdca.VMEM_BUDGET:
@@ -218,8 +219,8 @@ def check_gate_estimate_agreement() -> list:
         if s > 0 and sdca.interleave_vmem_estimate(
                 k, n_shard, d, itemsize, s) > sdca.INTERLEAVE_BUDGET:
             flag("cocoa_tpu.ops.pallas_sdca",
-                 f"pick_interleave(k={k}, {n_shard}, {d}) chose S={s} "
-                 f"whose estimate exceeds INTERLEAVE_BUDGET")
+                 f"pick_interleave(k={k}, {n_shard}, {d}) chose a ring "
+                 f"{s} deep whose estimate exceeds INTERLEAVE_BUDGET")
         # sparse block-chain Gram/apply path: fits ⇒ the segment pair's
         # SMEM streams and the Gram tile's VMEM stay inside budget
         if sparse.sparse_chain_fits(k, n_shard, d, max_nnz, b, itemsize):

@@ -4,8 +4,13 @@ The H coordinate steps of local SDCA are inherently sequential
 (CoCoA.scala:148-188); under plain XLA each step pays HBM round-trips for
 the row gather and the Δw update.  This kernel keeps the hot state — the Δw
 accumulator and the shard's α vector — resident in VMEM scratch across all
-H steps and lets Pallas's grid pipeline prefetch each sampled row HBM→VMEM
-(double-buffered) while the previous step computes.
+H steps and has each sampled row on its way HBM→VMEM while earlier steps
+compute: a round's K x H row addresses are known before it starts
+(``idxs``, scalar-prefetched to SMEM), so the kernels that advance the K
+chains in lockstep (:func:`_kernel_interleaved`, :func:`_kernel_classes`)
+fetch by a DMA ring of their own, up to eight steps ahead
+(:func:`_ring_steps`), and the shard-major kernel (:func:`_kernel`) by
+Pallas's grid pipeline, one step ahead (:func:`_row_spec`).
 
 Uses the margins decomposition (ops/local_sdca.py ``mode_factors``): the
 per-step margin is ``x·w₀ + sig_eff·(x·Δw)``, with **both dots computed
@@ -31,12 +36,14 @@ masked α write.  A reduce to 0-d is a trip to the scalar core and back, a
 float divide there a second one, and five reads, two divides and two splats
 a chain-step were 41% of the step: 880 ns a lockstep step of K = 8 chains at
 epsilon's shape with them, 518 without (PERF.md §6, PR 39).  Of the 518,
-319 are the grid iteration and its eight row DMAs alone (a kernel that only
-adds the fetched rows up: index maps, descriptor issue and waits, 40 ns a
-row whose 8 KB are 10 ns of HBM time), and the step's own vector work is
-the rest (taken out one at a time: the state row's picks and write 76, the
-closed-form solve 43, the two margin reduces 14); a longer row hides all of
-it (1.6 MB rows run at 92% of the HBM peak).  A closed-form loss's update
+319 were the grid iteration and its eight pipelined row DMAs alone (a
+kernel that only adds the fetched rows up: index maps, descriptor issue and
+waits, 40 ns a row whose 8 KB are 10 ns of HBM time); fetched by the
+kernel's own ring the same rows cost 116, and the step 274 (§6, PR 42).
+The step's own vector work is the rest (taken out one at a time at PR 39:
+the state row's picks and write 76, the closed-form solve 43, the two
+margin reduces 14); a longer row hides all of it (1.6 MB rows run at 92%
+of the HBM peak).  A closed-form loss's update
 (hinge's box projection, smooth_hinge's clip, the lasso's soft threshold)
 is a handful of selects, emitted once per shard per step.  A loss whose
 update is an iterative
@@ -56,7 +63,17 @@ own tile-aligned DMA unit (no sublane-alignment tricks).  Requires
 d % 8 == 0; ``shard_dataset`` pads dense feature columns to a multiple of 8
 (zero columns touch nothing), and the wrapper pads on the fly otherwise.
 
-**Step groups.**  Grid is (K, ceil(H/S)): shard-major, step groups inner
+**How the rows reach a kernel.**  Folded once a dataset
+(:func:`fold_rows`), one operand.  The lockstep kernels leave it in HBM
+(``memory_space=pl.ANY``) and copy ``X.at[shard, idxs[shard, step]]`` into
+a VMEM ring themselves; Mosaic slices an HBM operand only where its last
+axis is whole lane tiles, so rows that are not stored so (epsilon's d/8 =
+250) are padded to it once a dispatch, before the loop over rounds
+(:func:`lane_aligned`).  The shard-major kernel takes the rows as ``S``
+BlockSpec operands of (1, 1, 8, lanes) blocks.
+
+**Step groups (the shard-major kernel).**  Grid is (K, ceil(H/S)):
+shard-major, step groups inner
 (TPU grids execute sequentially with the last dimension fastest, which is
 exactly the dependency order).  Each grid iteration runs S sequential
 coordinate steps (unrolled in the kernel body) against S independently-
@@ -76,12 +93,15 @@ one (1, 128) row.  Per-step cost is O(d + 128) regardless of shard size.
 
 Block/alignment rules used:
 
-- the sampled row arrives as a (1, 1, 8, d/8) block of the folded
-  (K, n_shard, 8, d/8) X, selected by ``idxs`` via scalar prefetch;
+- the shard-major kernel's sampled row arrives as a (1, 1, 8, d/8) block of
+  the folded (K, n_shard, 8, d/8) X, selected by ``idxs`` via scalar
+  prefetch; a lockstep kernel's as a whole-tile (8, lanes) DMA of the same
+  array, left in HBM, by the same ``idxs``;
 - the per-shard vectors arrive as ``(1, n_blocks, 128)`` blocks selected by
   the grid's k index (their second-to-last dim is the full axis, which is
   always legal); they stay VMEM-resident across that shard's H steps and
-  re-DMA only when k advances;
+  re-DMA only when k advances (the lockstep kernels hold all K shards' at
+  once, their whole round one grid iteration);
 - α leaves as per-shard blocks too: the kernel writes one at the shard's
   last step and Pallas flushes it to HBM when the grid moves to the next
   shard — no cross-shard masking;
@@ -169,35 +189,83 @@ INTERLEAVE_BUDGET = 14 << 20  # measured headroom: flush-only outputs and the
                               # shard-major kernel's 0.480 (PR 34): the
                               # fit sends such a shape shard-major and
                               # loses nothing
+RING_DEPTHS = (8, 4, 2)       # lockstep steps whose K rows the ring of the
+                              # interleaved and the class kernel holds: the
+                              # deepest that fits VMEM and RING_GROUP_MAX
+RING_GROUP_MAX = 32           # chain-steps (x class models) one group of
+                              # the ring's loop may hold.  A group is
+                              # ``depth`` lockstep steps of K chains lowered
+                              # one after another (_ring_steps), and what a
+                              # process's first job pays to lower and
+                              # compile the kernel grows with it, while a
+                              # deeper ring buys ever less: at epsilon's
+                              # K = 8 a step is 391 / 281 / 274 ns at depth
+                              # 2 / 4 / 8, at mnist8m's K = 8, T = 10 it is
+                              # 455 / 435 / 432, and that kernel's lowering
+                              # alone takes 0.4 / 0.8 / 1.6 s on this
+                              # container's CPU, 2-3 x that beside a chip
+                              # (PERF.md section 6, PR 42)
+RING_TAIL = 200               # steps a round must have for each step the
+                              # ring looks ahead: past the round's end the
+                              # ring still moves ``depth - 1`` steps' rows
+                              # (the last sample's, into slots nothing
+                              # reads) and, where ``depth`` does not divide
+                              # H, runs up to ``depth - 1`` masked steps:
+                              # under 1% of the round each.  At x4's H =
+                              # 409 and 643 KB rows, where a step IS its
+                              # row's DMA, depth 8 / 4 / 2 measured +3.0 /
+                              # +1.1 / +0.1% on the pipelined kernel
+                              # (PERF.md section 6, PR 42)
+
+
+def _ring_depth(fits, group: int, h: int) -> int:
+    """The deepest of ``RING_DEPTHS`` that ``fits(depth)`` in VMEM, whose
+    loop group, ``depth * group`` chain-steps, stays under
+    ``RING_GROUP_MAX``, and whose look-ahead is under a ``RING_TAIL``-th of
+    the round's ``h`` steps (the shallowest is held to the fit alone); 0
+    where none fits."""
+    for depth in RING_DEPTHS:
+        if fits(depth) and (depth == RING_DEPTHS[-1] or (
+                depth * group <= RING_GROUP_MAX
+                and (depth - 1) * RING_TAIL <= h)):
+            return depth
+    return 0
+
+
+def lane_tiles(d: int) -> int:
+    """d values folded to (8, d/8) and held in whole (8, 128) tiles: the
+    values a VMEM copy of one folded d-vector takes."""
+    return SUBLANES * (-(-d // (SUBLANES * LANES)) * LANES)
 
 
 def interleave_vmem_estimate(k: int, n_shard: int, d: int, itemsize: int,
-                             unroll: int) -> int:
+                             depth: int) -> int:
     """Working set of the shard-interleaved kernel: ALL K shards' stacked
     state resident at once (3·n_pad input + 3·n_pad scratch each), the w₀
-    operand, the Δw accumulators/outputs, and K·unroll double-buffered row
-    blocks."""
+    operand, the K Δw accumulators and the one summed Δw block out, and the
+    ring's ``depth`` lockstep steps of K folded rows."""
     n_pad = -(-n_shard // LANES) * LANES
-    return itemsize * (6 * k * n_pad + 3 * k * d + d + 2 * k * unroll * d)
+    return itemsize * (6 * k * n_pad
+                       + (k + 3 + depth * k) * lane_tiles(d))
 
 
 def pick_interleave(k: int, n_shard: int, d: int, itemsize: int, h: int) -> int:
-    """Step-group size for the interleaved kernel (0 = does not fit or
-    nothing to interleave; use the shard-major kernel)."""
+    """The ring depth the interleaved kernel runs at these sizes
+    (:func:`_ring_depth`: the rows beside the state under
+    ``INTERLEAVE_BUDGET``, K chain-steps a lockstep step, H steps a round;
+    0 = not even the shallowest fits, or nothing to interleave: use the
+    shard-major kernel)."""
     if k <= 1:
         return 0
-    for s in (2, 1):
-        if s <= max(1, h) and interleave_vmem_estimate(
-                k, n_shard, d, itemsize, s) <= INTERLEAVE_BUDGET:
-            return s
-    return 0
+    return _ring_depth(lambda depth: interleave_vmem_estimate(
+        k, n_shard, d, itemsize, depth) <= INTERLEAVE_BUDGET, k, h)
 
 
 def dense_form(k: int, n_shard: int, d: int, itemsize: int, h: int) -> str:
     """Which of the two kernels a round of these sizes runs, as
     :func:`pallas_sdca_round` resolves it from the fit alone:
     ``interleaved`` (:func:`_kernel_interleaved`, every shard's chain
-    advanced in lockstep) where :func:`pick_interleave` finds a group size,
+    advanced in lockstep) where :func:`pick_interleave` finds a ring depth,
     ``shard_major`` (:func:`_kernel`) otherwise.  The run's record carries
     it (``SolverPath.form``)."""
     return ("interleaved" if pick_interleave(k, n_shard, d, itemsize, h)
@@ -234,10 +302,13 @@ def fold_rows(X: jax.Array, row_major: bool = False) -> jax.Array:
 
     **What layout the result is stored in.**  A TPU array gets the
     dimension order that pads least under the (8, 128) tile — for
-    (K, n_shard, 8, 250) the ROW INDEX on the lanes, not d/8.  The kernel
-    DMAs one row as a (1, 1, 8, d/8) block and takes row-major only, so a
+    (K, n_shard, 8, 250) the ROW INDEX on the lanes, not d/8.  The kernels
+    DMA one row, 8 x d/8, at a time and take row-major only, so a
     jitted loop handed such an array opens with a transpose of all of it,
-    on every dispatch (10 ms and a 3.28 GB temporary at epsilon).
+    on every dispatch (10 ms and a 3.28 GB temporary at epsilon), and,
+    since the lockstep kernels fetch by their own ring (which slices whole
+    lane tiles only), with a pad of the transposed rows to 256 lanes
+    behind it (:func:`lane_aligned`: a second temporary of that size).
     ``row_major=True`` zero-pads d to a multiple of 8·128 first, so d/8
     fills whole lane tiles: row-major then pads nothing, it IS the device's
     layout for that shape, and the loop reads the array as stored.  These
@@ -252,6 +323,75 @@ def fold_rows(X: jax.Array, row_major: bool = False) -> jax.Array:
     if not row_major:
         return _fold(X, SUBLANES)
     return jax.jit(functools.partial(_fold, multiple=SUBLANES * LANES))(X)
+
+
+def fold_lanes(d: int, lanes: int) -> int:
+    """The lanes one sublane of a d-vector's fold holds, given the last
+    axis ``lanes`` of the folded rows it is to meet: flat index s * (this)
+    + c.  Rows come folded three ways: as :func:`fold_rows` makes them
+    plain ((8, d/8): ``lanes`` itself), stored row-major (d padded to whole
+    (8, 128) tiles BEFORE the fold, where :func:`stores_row_major` says so:
+    ``lanes`` itself again), or plain and then :func:`lane_aligned` (d/8
+    lanes of values, zeros up to ``lanes``)."""
+    plain = -(-d // SUBLANES)
+    if lanes == plain or stores_row_major(d):
+        return lanes
+    if lanes != -(-plain // LANES) * LANES:
+        raise ValueError(f"folded rows of {lanes} lanes hold no {d}-vector")
+    return plain
+
+
+def lane_aligned(X_folded: jax.Array) -> jax.Array:
+    """The folded rows with their last axis zero-padded to whole lane
+    tiles; rows that are (stored row-major, or a d/8 that is a multiple of
+    128) come back as they are, and no operation is emitted.
+
+    **Why, and where it is called.**  The lockstep kernels fetch a row by a
+    DMA of their own, ``X.at[shard, row]`` of an operand left in HBM, and
+    Mosaic slices an HBM ref only where its last axis is whole 128-lane
+    tiles ("Slice shape along dimension 3 must be aligned to tiling (128),
+    but is 250": the BlockSpec pipeline's own windows have no such rule,
+    which is why ``_row_spec`` never met it).  Stored so, epsilon's rows
+    are +73 MB of array and mnist8m's +0.97 GB; as a temporary of the loop
+    program they are the 3.28 GB the per-dispatch relayout (``copy.5``)
+    already wrote.  So the programs that loop over rounds call this ONCE a
+    dispatch, before the loop (:func:`with_aligned_rows`:
+    solvers/base._build_device_run, the chunk kernel of solvers/cocoa.py),
+    and XLA does not hoist it itself: a pad
+    inside a ``while`` body stays there and would copy the rows every
+    round.  The kernels' wrappers call it too, for a caller that did not,
+    and then it costs a pass over the rows each round."""
+    pad = -X_folded.shape[-1] % LANES
+    if not pad:
+        return X_folded
+    return jnp.pad(X_folded, ((0, 0),) * (X_folded.ndim - 1) + ((0, pad),))
+
+
+def with_aligned_rows(shards: dict) -> dict:
+    """``shards`` with its fold cache (``X_folded``, where there is one)
+    :func:`lane_aligned`: what a program that loops over rounds does once,
+    before its loop."""
+    if "X_folded" not in shards:
+        return shards
+    return {**shards, "X_folded": lane_aligned(shards["X_folded"])}
+
+
+def _fold_vec(v: jax.Array, d8: int, lanes: int) -> jax.Array:
+    """(..., d) -> (..., 8, lanes): the fold of :func:`fold_lanes`."""
+    lead = v.shape[:-1]
+    v = jnp.pad(v, [(0, 0)] * len(lead) + [(0, SUBLANES * d8 - v.shape[-1])])
+    v = v.reshape(*lead, SUBLANES, d8)
+    return jnp.pad(v, [(0, 0)] * (len(lead) + 1) + [(0, lanes - d8)])
+
+
+def unfold_vec(v: jax.Array, d: int) -> jax.Array:
+    """(..., 8, lanes) -> (..., d): :func:`_fold_vec` undone.  Where the
+    fold fills its lanes nothing is cut before the reshape (the 1-D cut of
+    PERF.md section 6, PR 37, rides the caller's add as it did)."""
+    d8 = fold_lanes(d, v.shape[-1])
+    if d8 != v.shape[-1]:
+        v = v[..., :d8]
+    return v.reshape(*v.shape[:-2], SUBLANES * d8)[..., :d]
 
 
 STACK = 3  # lane-concatenated per-shard rows: [labels, sqn, alpha]
@@ -307,8 +447,8 @@ def _solve_in_lanes(loss, triples, lam_n, smoothing):
 
 def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
              qii_factor, lam_n, coef_div, loss, smoothing):
-    """One coordinate step of every chain in ``chains``, each a (shard, row
-    block ref, Δw accumulator ref, state ref) whose state rows are the
+    """One coordinate step of every chain in ``chains``, each a (shard, (8,
+    lanes) row ref, Δw accumulator ref, state ref) whose state rows are the
     (1, 3·LANES) lane concatenation: labels in lanes [0,128), ‖x‖²
     [128,256), α [256,384).
 
@@ -351,7 +491,7 @@ def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
         idx = idxs_ref[shard, step]
         blk = idx // LANES
         srow = state[pl.ds(blk, 1)]           # (1, 3·LANES): one dyn read
-        x = x_ref[0, 0]                       # (8, d8): the folded row
+        x = x_ref[...]                        # (8, d8): the folded row
         sub_lane = idx - blk * LANES
         dw_k, w_k = dw_acc[...], w_ref[...]
         lane4 = jax.lax.broadcasted_iota(jnp.int32, (1, STACK * LANES), 1)
@@ -425,7 +565,7 @@ def _kernel(
         # groups past H clamp their index (the row spec's index map does the
         # same clamp, so the DMA'd block matches) and zero their update;
         # when unroll | H there is no tail and the masking drops out
-        _advance([(k_, x_refs[j], dw_acc, stacked_sc)], idxs_ref,
+        _advance([(k_, x_refs[j].at[0, 0], dw_acc, stacked_sc)], idxs_ref,
                  step if exact else jnp.minimum(step, h - 1),
                  None if exact else step < h, w_ref, **step_kw)
 
@@ -445,78 +585,158 @@ def _kernel(
         dw_ref[0] = dw_ref[0] + dw_acc[...]
 
 
+def _ring_steps(idxs_ref, x_hbm, ring, sem, advance, *, k: int, h: int,
+                depth: int):
+    """The H lockstep steps of a round, each chain's row fetched by the
+    kernel's own DMA ring: ``advance(step, slot, live)`` runs step ``step``
+    on the K rows in ``ring[slot]``.
+
+    A round's K x H row addresses are in SMEM before it starts (``idxs_ref``,
+    the scalar-prefetch operand), so nothing a step computes decides what
+    is fetched next.  The rows stay in HBM (``x_hbm``, ``pl.ANY``: one
+    operand, (K, n_shard, 8, lanes), lanes whole tiles:
+    :func:`lane_aligned`); the ring is a VMEM scratch (depth, K, 8, lanes)
+    with one DMA semaphore a slot, which that slot's K copies signal and
+    K waits drain.  Before the loop the first ``depth - 1`` steps' rows
+    are started; step s starts the K copies of step s + depth - 1 into the
+    slot step s - 1 has just left, waits on its own K, and runs.
+
+    **Why not the BlockSpec pipeline.**  As K pipelined (1, 1, 8, d/8) row
+    operands a grid iteration, a row cost 40 ns of scalar work (two index
+    maps, a compare with the block held, a branch, a descriptor, a wait,
+    each in a basic block of its own that nothing is scheduled under)
+    against 10 ns of HBM time for epsilon's 8 KB: 319 ns of a 518 ns
+    lockstep step with the step taken out (PERF.md section 6, PR 39).
+    Here the issue and the waits are straight-line code inside the step's
+    own block: 116 ns with the step taken out, 274 with it at depth 8,
+    164 / 281 at depth 4 (section 6, PR 42; K = 8).
+
+    **The loop's shape is what was measured fastest** (PR 42's step 0, ns a
+    lockstep step at epsilon's shape, whole step / fetch alone): the grid
+    kept and the copies by hand in its body 408-452 / 195-318; the whole
+    round one grid iteration and a ``fori_loop`` over steps with the slot
+    computed from the step 366-420 / 161-302; that loop in groups of
+    ``depth`` steps, so that every slot index is a constant, 274-392 /
+    116-289, each at depth 8 / 2; one semaphore a slot beats K by 7%.
+
+    **One body, traced once.**  The group is a ``fori_loop`` unrolled in
+    full: its step is traced once and lowered ``depth`` times with the slot
+    a constant (written out in Python the kernel was traced ``depth``
+    times, and a process's first job took 4.4 s longer at epsilon, 9 s at
+    mnist8m: section 6, PR 42).  There is no second loop for a last part
+    group: the round runs ceil(H / depth) whole groups, the steps past H
+    clamp their row index to the last sample's and zero their update (the
+    shard-major kernel's ``live`` mask; nothing of it is emitted where
+    ``depth`` divides H), every step starts its fetch (past the end: the
+    last sample's row again, into a slot nothing reads), and the
+    ``depth - 1`` fetches still in flight when the loop ends are waited
+    for after it."""
+    exact = h % depth == 0
+    last = h - 1
+
+    def start(step, slot):
+        step = jnp.minimum(step, last)
+        for kk in range(k):
+            pltpu.make_async_copy(
+                x_hbm.at[kk, idxs_ref[kk, step]], ring.at[slot, kk],
+                sem.at[slot]).start()
+
+    def wait(slot):
+        for kk in range(k):     # the K copies of a slot are one size: any
+            pltpu.make_async_copy(x_hbm.at[kk, 0], ring.at[slot, kk],
+                                  sem.at[slot]).wait()
+
+    for step in range(depth - 1):
+        start(step, step)
+
+    def group(g, carry):
+        def one(slot, carry):   # lowered ``depth`` times, ``slot`` a constant
+            step = g * depth + slot
+            start(step + depth - 1, (slot + depth - 1) % depth)
+            wait(slot)
+            if exact:
+                advance(step, slot, None)
+            else:
+                advance(jnp.minimum(step, last), slot, step < h)
+            return carry
+        return jax.lax.fori_loop(0, depth, one, carry, unroll=depth)
+
+    jax.lax.fori_loop(0, -(-h // depth), group, 0)
+    for slot in range(depth - 1):
+        wait(slot)
+
+
 def _kernel_interleaved(
     idxs_ref,        # scalar-prefetch: (K, H) int32 sampled rows
-    *refs,           # K*S row blocks, stacked_in, 2 outs, 2K scratch
+    x_hbm,           # (K, n_shard, 8, lanes) in HBM: the folded rows
+    w_ref,           # (8, lanes) VMEM: the replicated w₀ (margin base)
+    stacked_in,      # (K, n_blocks, 3·LANES) VMEM: every shard's state
+    dw_ref,          # out (1, 8, lanes): the shards' summed Δw
+    alpha_ref,       # out (K, n_blocks, LANES)
+    *scratch,        # K accumulators, K states, the ring, its semaphores
     h: int,
-    unroll: int,
-    n_groups: int,
     k: int,
+    depth: int,
     **step_kw,
 ):
-    """Shard-interleaved variant: 1-D grid over step groups; each iteration
-    advances EVERY shard's chain by S steps.  The K chains are independent
-    and — crucially — keep their state in SEPARATE scratch refs, so Mosaic
-    does not serialize them on ref aliasing and their per-step dependency
-    chains overlap (measured ~1.6x over the shard-major kernel at epsilon
-    scale, where the chain latency, not bandwidth, is the bound).  Needs
-    all K shards' stacked state VMEM-resident (interleave_vmem_estimate)."""
-    x_refs = refs[:k * unroll]           # x_refs[j*k + kk]
-    w_ref = refs[k * unroll]
-    stacked_in = refs[k * unroll + 1]
-    dw_ref, alpha_ref = refs[k * unroll + 2:k * unroll + 4]
-    dw_accs = refs[k * unroll + 4:k * unroll + 4 + k]
-    st_scs = refs[k * unroll + 4 + k:]
-    i = pl.program_id(0)
+    """Shard-interleaved variant: the whole round in one grid iteration,
+    each lockstep step advancing EVERY shard's chain.  The K chains are
+    independent and — crucially — keep their state in SEPARATE scratch
+    refs, so Mosaic does not serialize them on ref aliasing and their
+    per-step dependency chains overlap (measured ~1.6x over the shard-major
+    kernel at epsilon scale, where the chain latency, not bandwidth, is
+    the bound).  Needs all K shards' stacked state VMEM-resident
+    (interleave_vmem_estimate).  The rows come by :func:`_ring_steps`."""
+    dw_accs, st_scs = scratch[:k], scratch[k:2 * k]
+    ring, sem = scratch[2 * k:]
+    for kk in range(k):
+        dw_accs[kk][...] = jnp.zeros_like(dw_accs[kk])
+        st_scs[kk][...] = stacked_in[kk]
 
-    @pl.when(i == 0)
-    def _init():
-        for kk in range(k):
-            dw_accs[kk][...] = jnp.zeros_like(dw_accs[kk])
-            st_scs[kk][...] = stacked_in[kk]
-
-    exact = h % unroll == 0
-    for j in range(unroll):
-        step = i * unroll + j
-        live = None if exact else step < h
-        _advance([(kk, x_refs[j * k + kk], dw_accs[kk], st_scs[kk])
-                  for kk in range(k)], idxs_ref,
-                 step if exact else jnp.minimum(step, h - 1), live, w_ref,
+    def advance(step, slot, live):
+        _advance([(kk, ring.at[slot, kk], dw_accs[kk], st_scs[kk])
+                  for kk in range(k)], idxs_ref, step, live, w_ref,
                  **step_kw)
 
-    @pl.when(i == n_groups - 1)
-    def _flush():
-        dw_sum = dw_accs[0][...]
-        for kk in range(1, k):          # shard 0 first, left to right
-            dw_sum = dw_sum + dw_accs[kk][...]
-        dw_ref[0] = dw_sum
-        for kk in range(k):
-            alpha_ref[kk] = st_scs[kk][:, 2 * LANES:]
+    _ring_steps(idxs_ref, x_hbm, ring, sem, advance, k=k, h=h, depth=depth)
+
+    dw_sum = dw_accs[0][...]
+    for kk in range(1, k):          # shard 0 first, left to right
+        dw_sum = dw_sum + dw_accs[kk][...]
+    dw_ref[0] = dw_sum
+    for kk in range(k):
+        alpha_ref[kk] = st_scs[kk][:, 2 * LANES:]
 
 
-def _row_spec(h: int, unroll: int, d8: int, j: int, kk=None):
-    """BlockSpec of sample j of group i: the folded row at [shard, idx, :,
-    :].  Groups past H (only when unroll does not divide H) clamp to the
-    last sample — the kernels compute the same clamped index, so the DMA'd
-    block always matches.  ``kk`` fixes the shard (interleaved 1-D grid);
-    kk=None reads it from the grid (shard-major 2-D grid)."""
+def _row_spec(h: int, unroll: int, lanes: int, j: int):
+    """BlockSpec of sample j of group i of the shard-major kernel: the
+    folded row at [shard, idx, :, :], the shard read from the grid.
+    Groups past H (only when unroll does not divide H) clamp to the last
+    sample — the kernel computes the same clamped index, so the DMA'd
+    block always matches.
+
+    **The one fetch left to Pallas's pipeline** (``SolverPath.row_fetch``
+    ``pipelined``).  This kernel runs where one shard's state or rows are
+    too large for K of them to sit in VMEM side by side: the lasso's 1.6 MB
+    columns, 92% of the HBM peak with the step's vector work hidden under
+    the one DMA (PERF.md section 6, PR 37).  The 40 ns of scalar work a
+    pipelined row operand costs (:func:`_ring_steps`) are 0.02% of a step
+    there; a ring of two such rows holds what the double buffer holds."""
     exact = h % unroll == 0
 
     def step_of(i_):
         step = i_ * unroll + j if unroll > 1 else i_
         return step if exact else jnp.minimum(step, h - 1)
 
-    if kk is None:
-        index_map = lambda k_, i_, idxs_: (k_, idxs_[k_, step_of(i_)], 0, 0)
-    else:
-        index_map = lambda i_, idxs_: (kk, idxs_[kk, step_of(i_)], 0, 0)
-    return pl.BlockSpec((1, 1, SUBLANES, d8), index_map)
+    return pl.BlockSpec(
+        (1, 1, SUBLANES, lanes),
+        lambda k_, i_, idxs_: (k_, idxs_[k_, step_of(i_)], 0, 0))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("lam", "n", "mode", "sigma", "interpret", "loss",
-                     "smoothing", "unroll", "interleave"),
+                     "smoothing", "unroll", "interleave", "depth"),
 )
 def pallas_sdca_round(
     w: jax.Array,            # (d,) the replicated primal vector w₀
@@ -534,6 +754,7 @@ def pallas_sdca_round(
     smoothing: float = 1.0,
     unroll: int = 0,
     interleave=None,
+    depth: int = 0,
 ):
     """One SDCA round for K shards on this chip.  Returns (dw, alpha_inner):
     dw (1, d) the K shards' updates summed (shard 0 first, in X's dtype:
@@ -541,55 +762,49 @@ def pallas_sdca_round(
     (K, n_shard) the locally-advanced alpha (callers apply the outer
     scaling law).
 
-    ``unroll`` = coordinate steps per grid iteration (0 = auto: the largest
-    of 16/8/4/2/1 whose row blocks fit the VMEM budget).  Any value yields
-    the same math — it only changes DMA batching.
-
     ``interleave`` (None = auto: K > 1 and all shards' state fits VMEM)
     advances the K independent chains in lockstep with separate scratch
     refs, overlapping their per-step dependency chains — same math, ~1.6x
-    at epsilon scale.
+    at epsilon scale — and fetches their rows by a DMA ring of the
+    kernel's own (:func:`_ring_steps`), ``depth`` lockstep steps deep (0 =
+    auto: the deepest of ``RING_DEPTHS`` that fits, :func:`pick_interleave`;
+    1: each step's rows fetched as it starts).  Any depth yields the same
+    math — it only changes how far ahead the rows are asked for.
+
+    ``unroll`` (the shard-major kernel only) = coordinate steps per grid
+    iteration (0 = auto: the largest of 16/8/4/2/1 whose row blocks fit the
+    VMEM budget).  Any value yields the same math — it only changes DMA
+    batching.
 
     Inside ``shard_map`` this must run under ``check_vma=False`` (the
     chunked driver does; pallas_call's internal slices confuse the VMA
     checker)."""
+    d_orig = w.shape[0]
     if X.ndim == 4:
-        # pre-folded (K, n_shard, 8, d/8) — the hot paths fold once per run
-        # OUTSIDE the round loop: folding in here would relayout the whole X
-        # every round (the 3-D and 4-D tiled layouts differ physically)
-        k, n_shard, _, d8 = X.shape
-        d, d_orig = SUBLANES * d8, w.shape[0]   # past w: the fold's padding
+        # pre-folded (K, n_shard, 8, lanes) — the hot paths fold once per
+        # run OUTSIDE the round loop: folding in here would relayout the
+        # whole X every round (the 3-D and 4-D tiled layouts differ
+        # physically)
         X_folded = X
     else:
-        k, n_shard, d = X.shape
-        d_orig = d
-        if d % SUBLANES:
-            # hot configs avoid this copy: shard_dataset pads dense d to 8
-            pad = SUBLANES - d % SUBLANES
-            X = jnp.pad(X, ((0, 0), (0, 0), (0, pad)))
-            d += pad
-        d8 = d // SUBLANES
-        X_folded = X.reshape(k, n_shard, SUBLANES, d8)
+        # hot configs avoid this copy: shard_dataset pads dense d to 8
+        X_folded = _fold(X, SUBLANES)
+    k, n_shard, _, lanes = X_folded.shape
+    d8 = fold_lanes(d_orig, lanes)      # of them, the fold's own
+    d = SUBLANES * d8
     h = idxs.shape[1]
     dtype = X.dtype
     check_dtype(dtype)
     itemsize = jnp.dtype(dtype).itemsize
+    fit_depth = pick_interleave(k, n_shard, d, itemsize, h)
     if interleave is None:
-        # auto: the fit check must use the unroll that will actually run
-        # (an explicit large unroll can blow the all-shards VMEM budget)
-        fit = pick_interleave(k, n_shard, d, itemsize, h)
-        interleave = fit > 0 and (
-            not unroll
-            or interleave_vmem_estimate(k, n_shard, d, itemsize, unroll)
+        # auto: the fit check must use the depth that will actually run
+        # (an explicit deep ring can blow the all-shards VMEM budget)
+        interleave = fit_depth > 0 and (
+            not depth
+            or interleave_vmem_estimate(k, n_shard, d, itemsize, depth)
             <= INTERLEAVE_BUDGET
         )
-    if interleave and not unroll:
-        # the interleaved budget governs the group size (pick_unroll's
-        # single-shard budget would overshoot the all-shards working set)
-        unroll = pick_interleave(k, n_shard, d, itemsize, h) or 1
-    if not unroll:
-        unroll = pick_unroll(n_shard, d, itemsize, h) or 1
-    n_groups = -(-h // unroll)
     sig_eff, qii_factor = mode_factors(mode, sigma)
 
     # lane-block the per-shard vectors and lane-concatenate them into the
@@ -603,11 +818,6 @@ def pallas_sdca_round(
     stacked = jnp.concatenate(
         [blocked(labels), blocked(sq_norms), blocked(alpha)], axis=-1,
     )  # (K, n_blocks, STACK*LANES)
-    # the replicated w₀, folded like the rows (free reshape: contiguous)
-    w_pad = jnp.pad(w.astype(dtype), (0, d - w.shape[0]))
-    w_folded = w_pad.reshape(SUBLANES, d8)
-
-    row_spec = functools.partial(_row_spec, h, unroll, d8)
 
     common = dict(
         lam_n=float(lam * n),
@@ -618,81 +828,86 @@ def pallas_sdca_round(
         h=h,
         loss=losses.validate(loss, smoothing),
         smoothing=float(smoothing),
-        unroll=unroll,
-        n_groups=n_groups,
     )
 
     if interleave:
-        kernel = functools.partial(_kernel_interleaved, k=k, **common)
-
-
+        depth = depth or fit_depth or 2
+        X_folded = lane_aligned(X_folded)
+        lanes = X_folded.shape[-1]
+        kernel = functools.partial(_kernel_interleaved, k=k, depth=depth,
+                                   **common)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(n_groups,),
+            grid=(1,),
             in_specs=[
-                *[row_spec(j, kk)
-                  for j in range(unroll) for kk in range(k)],
-                pl.BlockSpec((SUBLANES, d8), lambda i_, idxs_: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((SUBLANES, lanes), lambda i_, idxs_: (0, 0)),
                 pl.BlockSpec((k, n_blocks, STACK * LANES),
                              lambda i_, idxs_: (0, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, SUBLANES, d8), lambda i_, idxs_: (0, 0, 0)),
+                pl.BlockSpec((1, SUBLANES, lanes),
+                             lambda i_, idxs_: (0, 0, 0)),
                 pl.BlockSpec((k, n_blocks, LANES),
                              lambda i_, idxs_: (0, 0, 0)),
             ],
             scratch_shapes=(
-                [pltpu.VMEM((SUBLANES, d8), dtype)] * k
+                [pltpu.VMEM((SUBLANES, lanes), dtype)] * k
                 + [pltpu.VMEM((n_blocks, STACK * LANES), dtype)] * k
+                + [pltpu.VMEM((depth, k, SUBLANES, lanes), dtype),
+                   pltpu.SemaphoreType.DMA((depth,))]
             ),
         )
-        n_row_ops = k * unroll
+        rows = [X_folded]
         semantics = ("arbitrary",)
     else:
-        kernel = functools.partial(_kernel, **common)
-
-
+        if not unroll:
+            unroll = pick_unroll(n_shard, d, itemsize, h) or 1
+        n_groups = -(-h // unroll)
+        kernel = functools.partial(_kernel, unroll=unroll,
+                                   n_groups=n_groups, **common)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(k, n_groups),
             in_specs=[
-                *[row_spec(j) for j in range(unroll)],
-                pl.BlockSpec((SUBLANES, d8), lambda k_, i_, idxs_: (0, 0)),
+                *[_row_spec(h, unroll, lanes, j) for j in range(unroll)],
+                pl.BlockSpec((SUBLANES, lanes),
+                             lambda k_, i_, idxs_: (0, 0)),
                 pl.BlockSpec((1, n_blocks, STACK * LANES),
                              lambda k_, i_, idxs_: (k_, 0, 0)),
             ],
             out_specs=[
-                pl.BlockSpec((1, SUBLANES, d8),
+                pl.BlockSpec((1, SUBLANES, lanes),
                              lambda k_, i_, idxs_: (0, 0, 0)),
                 pl.BlockSpec((1, n_blocks, LANES),
                              lambda k_, i_, idxs_: (k_, 0, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((SUBLANES, d8), dtype),
+                pltpu.VMEM((SUBLANES, lanes), dtype),
                 pltpu.VMEM((n_blocks, STACK * LANES), dtype),
             ],
         )
-        n_row_ops = unroll
+        rows = [X_folded] * unroll
         semantics = ("arbitrary", "arbitrary")
 
     dw, alpha_blocked = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((1, SUBLANES, d8), dtype),
+            jax.ShapeDtypeStruct((1, SUBLANES, lanes), dtype),
             jax.ShapeDtypeStruct((k, n_blocks, LANES), dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics,
         ),
         interpret=interpret,
-    )(idxs, *([X_folded] * n_row_ops), w_folded, stacked)
+    )(idxs, *rows, _fold_vec(w.astype(dtype), d8, lanes), stacked)
     alpha_inner = alpha_blocked.reshape(k, n_pad)[:, :n_shard]
     # unfolded and cut to length as a 1-D vector, THEN given its axis of
     # one: a (1, d) row is tiled a sublane a vreg, and cutting that to
     # length cost 21 us for 1.6 MB at the lasso, as much as summing K = 8 of
     # them had (PERF.md §6, PR 37); 1-D, the cut rides the caller's add
-    return dw.reshape(d)[:d_orig][None], alpha_inner
+    return unfold_vec(dw[0], d_orig)[None], alpha_inner
 
 
 # --- one-vs-rest: T class models over the one sampled row ------------------
@@ -740,31 +955,45 @@ def class_rows(t: int) -> int:
 
 
 def class_vmem_estimate(k: int, n_shard: int, d: int, t: int,
-                        itemsize: int) -> int:
+                        itemsize: int, depth: int = RING_DEPTHS[-1]) -> int:
     """Working set of the T-class kernel: all K shards' state tiles, the
     (T, 8, d/8) w, K accumulators and the output of that shape (each
-    class's fold lane-padded to whole tiles), and K double-buffered row
-    blocks."""
+    class's fold lane-padded to whole tiles), and the ring's ``depth``
+    lockstep steps of K folded rows."""
     n_blocks = -(-n_shard // LANES)
-    fold = SUBLANES * (-(-d // (SUBLANES * LANES)) * LANES)
+    fold = lane_tiles(d)
     return itemsize * (k * n_blocks * class_rows(t) * LANES
-                       + (k + 3) * t * fold + 2 * k * fold)
+                       + (k + 3) * t * fold + depth * k * fold)
+
+
+def class_ring_depth(k: int, n_shard: int, d: int, t: int,
+                     itemsize: int, h: int) -> int:
+    """The ring depth the T-class kernel runs at these sizes
+    (:func:`_ring_depth`: the rows beside the state under
+    ``CLASS_VMEM_BUDGET``, K x T class-steps a lockstep step, H steps a
+    round); 0 where not even the shallowest fits."""
+    return _ring_depth(lambda depth: class_vmem_estimate(
+        k, n_shard, d, t, itemsize, depth) <= CLASS_VMEM_BUDGET, k * t, h)
 
 
 def classes_fit(k: int, n_shard: int, d: int, t: int, itemsize: int) -> bool:
-    """Whether the T-class kernel holds a round of these sizes."""
+    """Whether the T-class kernel holds a round of these sizes (at its
+    shallowest ring)."""
     return class_vmem_estimate(k, n_shard, d, t, itemsize) \
         <= CLASS_VMEM_BUDGET
 
 
-def _advance_classes(chains, idxs_ref, step, w_ref, *, t, frozen,
+def _advance_classes(chains, idxs_ref, step, live, w_ref, *, t, frozen,
                      sig_eff, qii_factor, lam_n, coef_div, loss, smoothing):
-    """One coordinate step of every chain in ``chains`` — (shard, row block
-    ref, (T, 8, d/8) accumulator ref, (n_blocks, R, 128) state ref) — for
+    """One coordinate step of every chain in ``chains`` — (shard, (8,
+    lanes) row ref, (T, 8, d/8) accumulator ref, (n_blocks, R, 128) state
+    ref) — for
     all T classes at once (the section's note above).  The margin of class
     t is sum(x * (w_t + sig_eff dw_t)): one reduce a class where the T = 1
     kernel makes two (x . w and x . dw apart), so a lane agrees with the
-    solo kernel to rounding, not to the bit."""
+    solo kernel to rounding, not to the bit.  ``live`` (None, or a traced
+    condition): a step past H of the ring's last group, whose update is
+    zeroed and whose state write is dropped, as in :func:`_advance`."""
     rows = class_rows(t)
     dtype = w_ref.dtype
     d8 = w_ref.shape[-1]
@@ -777,7 +1006,7 @@ def _advance_classes(chains, idxs_ref, step, w_ref, *, t, frozen,
         blk = idx // LANES
         here = lane == idx - blk * LANES
         tile = state[pl.ds(blk, 1)][0]        # (R, 128): one dynamic read
-        x = x_ref[0, 0]                       # (8, d8): the folded row
+        x = x_ref[...]                        # (8, d8): the folded row
         col = jnp.sum(jnp.where(here, tile, 0.0), axis=1, keepdims=True)
         # rows past T: alpha = 1/2, y = 0 -- every loss's step stays finite
         # there, and nothing of them is written
@@ -796,9 +1025,10 @@ def _advance_classes(chains, idxs_ref, step, w_ref, *, t, frozen,
         margin = jnp.sum(stacked, axis=1, keepdims=True)       # (R, 1)
         new_a = losses.alpha_step(loss, a, y * margin, sq * qii_factor,
                                   lam_n, smoothing=smoothing)
-        coef = jnp.where(is_class, y * (new_a - a) / coef_div, 0.0)
+        wrote = is_class if live is None else is_class & live
+        coef = jnp.where(wrote, y * (new_a - a) / coef_div, 0.0)
         state[pl.ds(blk, 1)] = jnp.where(
-            here & is_class, jnp.broadcast_to(new_a, (rows, LANES)),
+            here & wrote, jnp.broadcast_to(new_a, (rows, LANES)),
             tile)[None]
         for c in range(t):
             dw_acc[c] = dw_acc[c] + coef[c:c + 1, :] * x
@@ -806,45 +1036,46 @@ def _advance_classes(chains, idxs_ref, step, w_ref, *, t, frozen,
 
 def _kernel_classes(
     idxs_ref,        # scalar-prefetch: (K, H) int32 sampled rows
-    *refs,           # K row blocks, w, state in, 2 outs, 2K scratch
+    x_hbm,           # (K, n_shard, 8, lanes) in HBM: the folded rows
+    w_ref,           # (T, 8, lanes) VMEM: the class models
+    state_in,        # (K, n_blocks, R, 128) in HBM: every shard's tiles
+    dw_ref,          # out (T, 8, lanes): the shards' summed updates
+    state_out,       # out, in HBM, as state_in
+    *scratch,        # K accumulators, K states, the ring, its semaphores
     h: int,
     k: int,
+    depth: int,
     **step_kw,
 ):
-    """The interleaved kernel with a class axis: 1-D grid over the H steps
-    (one a grid iteration: two measured 3.7% slower at the mnist8m cell's
-    shape, PERF.md section 6, PR 38), every shard's chain advanced in
-    lockstep, each chain's accumulator and state in scratch refs of its
-    own."""
-    x_refs = refs[:k]
-    w_ref, state_in, dw_ref, state_out = refs[k:k + 4]
-    dw_accs = refs[k + 4:2 * k + 4]
-    st_scs = refs[2 * k + 4:3 * k + 4]
-    i = pl.program_id(0)
+    """The interleaved kernel with a class axis: the whole round in one
+    grid iteration, every shard's chain advanced in lockstep, each chain's
+    accumulator and state in scratch refs of its own, the rows by
+    :func:`_ring_steps`."""
+    dw_accs, st_scs = scratch[:k], scratch[k:2 * k]
+    ring, sem = scratch[2 * k:]
+    for kk in range(k):
+        dw_accs[kk][...] = jnp.zeros_like(dw_accs[kk])
+        pltpu.sync_copy(state_in.at[kk], st_scs[kk])
 
-    @pl.when(i == 0)
-    def _init():
-        for kk in range(k):
-            dw_accs[kk][...] = jnp.zeros_like(dw_accs[kk])
-            pltpu.sync_copy(state_in.at[kk], st_scs[kk])
+    def advance(step, slot, live):
+        _advance_classes([(kk, ring.at[slot, kk], dw_accs[kk], st_scs[kk])
+                          for kk in range(k)], idxs_ref, step, live, w_ref,
+                         **step_kw)
 
-    _advance_classes([(kk, x_refs[kk], dw_accs[kk], st_scs[kk])
-                      for kk in range(k)], idxs_ref, i, w_ref, **step_kw)
+    _ring_steps(idxs_ref, x_hbm, ring, sem, advance, k=k, h=h, depth=depth)
 
-    @pl.when(i == h - 1)
-    def _flush():
-        dw_sum = dw_accs[0][...]
-        for kk in range(1, k):          # shard 0 first, left to right
-            dw_sum = dw_sum + dw_accs[kk][...]
-        dw_ref[...] = dw_sum
-        for kk in range(k):
-            pltpu.sync_copy(st_scs[kk], state_out.at[kk])
+    dw_sum = dw_accs[0][...]
+    for kk in range(1, k):          # shard 0 first, left to right
+        dw_sum = dw_sum + dw_accs[kk][...]
+    dw_ref[...] = dw_sum
+    for kk in range(k):
+        pltpu.sync_copy(st_scs[kk], state_out.at[kk])
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("lam", "n", "mode", "sigma", "interpret", "loss",
-                     "smoothing"),
+                     "smoothing", "depth"),
 )
 def pallas_sdca_round_classes(
     w: jax.Array,            # (T, d) the replicated class models
@@ -860,22 +1091,24 @@ def pallas_sdca_round_classes(
     interpret: bool = False,
     loss: str = "hinge",
     smoothing: float = 1.0,
+    depth: int = 0,
 ):
     """One SDCA round of T one-vs-rest models over the K shards' sampled
     rows.  Returns (dw (T, d), the shards' updates summed shard 0 first;
     alpha_inner (T, K, n_shard), locally advanced: callers apply the outer
-    scaling law), as :func:`pallas_sdca_round` does at T = 1."""
+    scaling law), as :func:`pallas_sdca_round` does at T = 1.  ``depth``:
+    the row ring's (0 = auto, :func:`class_ring_depth`), as there."""
     t, d_orig = w.shape
-    if X.ndim == 4:
-        k, n_shard, _, d8 = X.shape
-        X_folded = X
-    else:
-        X_folded = _fold(X, SUBLANES)
-        k, n_shard, _, d8 = X_folded.shape
-    d = SUBLANES * d8
+    X_folded = X if X.ndim == 4 else _fold(X, SUBLANES)
+    k, n_shard, _, lanes = X_folded.shape
+    d8 = fold_lanes(d_orig, lanes)
+    X_folded = lane_aligned(X_folded)
+    lanes = X_folded.shape[-1]
     h = idxs.shape[1]
     dtype = X.dtype
     check_dtype(dtype)
+    depth = depth or class_ring_depth(
+        k, n_shard, SUBLANES * d8, t, jnp.dtype(dtype).itemsize, h) or 2
     sig_eff, qii_factor = mode_factors(mode, sigma)
     rows = class_rows(t)
     n_blocks = -(-n_shard // LANES)
@@ -890,37 +1123,36 @@ def pallas_sdca_round_classes(
         [jnp.transpose(blocked(alpha), (1, 2, 0, 3)),
          blocked(sq_norms)[:, :, None], blocked(classes)[:, :, None],
          jnp.zeros((k, n_blocks, rows - t - 2, LANES), dtype)], axis=2)
-    w_folded = jnp.pad(w.astype(dtype), ((0, 0), (0, d - d_orig))).reshape(
-        t, SUBLANES, d8)
 
     kernel = functools.partial(
-        _kernel_classes, k=k, t=t, h=h,
+        _kernel_classes, k=k, t=t, h=h, depth=depth,
         lam_n=float(lam * n), coef_div=float(coef_divisor(mode, lam * n)),
         sig_eff=float(sig_eff), qii_factor=float(qii_factor),
         frozen=(mode == "frozen"), loss=losses.validate(loss, smoothing),
         smoothing=float(smoothing))
-    whole = pl.BlockSpec((t, SUBLANES, d8), lambda i_, idxs_: (0, 0, 0))
+    whole = pl.BlockSpec((t, SUBLANES, lanes), lambda i_, idxs_: (0, 0, 0))
     any_ = pl.BlockSpec(memory_space=pl.ANY)
     dw, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(h,),
-            in_specs=[*[_row_spec(h, 1, d8, 0, kk) for kk in range(k)],
-                      whole, any_],
+            grid=(1,),
+            in_specs=[any_, whole, any_],
             out_specs=[whole, any_],
             scratch_shapes=(
-                [pltpu.VMEM((t, SUBLANES, d8), dtype)] * k
-                + [pltpu.VMEM((n_blocks, rows, LANES), dtype)] * k),
+                [pltpu.VMEM((t, SUBLANES, lanes), dtype)] * k
+                + [pltpu.VMEM((n_blocks, rows, LANES), dtype)] * k
+                + [pltpu.VMEM((depth, k, SUBLANES, lanes), dtype),
+                   pltpu.SemaphoreType.DMA((depth,))]),
         ),
-        out_shape=[jax.ShapeDtypeStruct((t, SUBLANES, d8), dtype),
+        out_shape=[jax.ShapeDtypeStruct((t, SUBLANES, lanes), dtype),
                    jax.ShapeDtypeStruct(state.shape, dtype)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=CLASS_VMEM_LIMIT),
         interpret=interpret,
         name="pallas_sdca_classes",
-    )(idxs, *([X_folded] * k), w_folded, state)
+    )(idxs, X_folded, _fold_vec(w.astype(dtype), d8, lanes), state)
     alpha_inner = jnp.transpose(state[:, :, :t], (2, 0, 1, 3)).reshape(
         t, k, n_pad)[:, :, :n_shard]
-    return dw.reshape(t, d)[:, :d_orig], alpha_inner
+    return unfold_vec(dw, d_orig), alpha_inner

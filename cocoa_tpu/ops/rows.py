@@ -454,9 +454,12 @@ def shards_axpy(coefs: jax.Array, shards: dict, vec: jax.Array) -> jax.Array:
         # X at the loop's entry on every dispatch — 10 ms a job at epsilon,
         # for a branch that cell never takes.  Here it is one f32
         # multiply-reduce streaming the rows once, and X stays as stored
-        # for the eval.  Lanes past d are fold_rows' zero padding.
+        # for the eval.  Lanes past the fold's own are zero padding
+        # (fold_rows', or lane_aligned's at a dispatch's entry).
+        from cocoa_tpu.ops.pallas_sdca import unfold_vec
+
         dw = jnp.einsum("kn,knsc->sc", coefs, shards["X_folded"])
-        return vec + dw.reshape(-1)[:vec.shape[0]]
+        return vec + unfold_vec(dw, vec.shape[0])
     if "X" in shards:
         return vec + jnp.einsum("kn,knd->d", coefs, shards["X"])
     idx, val = shards["sp_indices"], shards["sp_values"]

@@ -836,6 +836,13 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
         # also carrying a per-round (n_chunks, C) t leaf for η(t) solvers);
         # static at trace time — a different block length just retraces
         n_chunks = jax.tree.leaves(idxs_all)[0].shape[0]
+        if isinstance(shard_arrays, dict):
+            # the dense kernels' row ring reads whole lane tiles: once a
+            # dispatch, here, because XLA leaves a pad inside the loop's
+            # body where it is (ops/pallas_sdca.lane_aligned)
+            from cocoa_tpu.ops.pallas_sdca import with_aligned_rows
+
+            shard_arrays = with_aligned_rows(shard_arrays)
 
         def cond(s):
             i, done_tgt, done_stall, stall, best, best_prev, state, traj = s

@@ -247,7 +247,13 @@ class SolverPath:
     ``lanes``) as one column of a state tile.  ``lane_fill`` (that path
     only): of the vector positions a lockstep step's solve holds — K
     chains x the tile's sublane rows (ops/pallas_sdca.class_rows) — the
-    share that are class models, K T / (K R)."""
+    share that are class models, K T / (K R).  ``row_fetch`` (the dense
+    Pallas kernel; None anywhere else): how a sampled row reaches VMEM —
+    ``ring``: by the kernel's own DMA ring, ``ring_depth`` lockstep steps
+    of K rows deep, the depth read from the VMEM fit
+    (ops/pallas_sdca._ring_steps; the interleaved and the class kernel);
+    ``pipelined``: as a BlockSpec operand of Pallas's grid pipeline, one
+    step ahead (the shard-major kernel; ``ring_depth`` None)."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -271,6 +277,8 @@ class SolverPath:
     form: Optional[str] = None
     classes: int = 1
     lane_fill: Optional[float] = None
+    row_fetch: Optional[str] = None
+    ring_depth: Optional[int] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -317,6 +325,8 @@ class SolverPath:
         rows = ", rows stored row-major" if self.rows == "row_major" else ""
         if self.form:
             what += f" {self.form}"
+        if self.row_fetch == "ring":
+            what += f", rows by a ring {self.ring_depth} steps deep"
         if self.objective != "svm":
             rows += f", objective {self.objective}"
         solve = (", the shards' steps solved in lanes"
@@ -568,20 +578,33 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         placement["chunk_pieces"] = CHUNK_PIECES
     if block_size <= 0:
         from cocoa_tpu.ops import losses
-        from cocoa_tpu.ops.pallas_sdca import (class_rows, dense_form,
+        from cocoa_tpu.ops.pallas_sdca import (class_ring_depth, class_rows,
+                                               pick_interleave,
                                                stores_row_major)
 
         if classes > 1:
             placement.update(
                 classes=classes,
                 lane_fill=classes / class_rows(classes) if pallas else None)
+        form = depth = None
+        if pallas and not sparse:
+            # the dense kernel's form and its ring's depth, from the VMEM
+            # fit alone (ops/pallas_sdca.dense_form); a class kernel forced
+            # past its fit runs the shallowest ring and Mosaic reports the
+            # allocation itself
+            depth = ((class_ring_depth(m_local, ds.n_shard, ds.num_features,
+                                       classes, itemsize, local_iters) or 2)
+                     if classes > 1
+                     else pick_interleave(m_local, ds.n_shard,
+                                          ds.num_features, itemsize,
+                                          local_iters) or None)
+            form = "interleaved" if depth else "shard_major"
+            placement.update(row_fetch="ring" if depth else "pipelined",
+                             ring_depth=depth)
         return SolverPath(
             inner="sequential", kernel="pallas" if pallas else "fori",
             chain=None, interpret=bool(pallas and platform == "cpu"),
-            form=("interleaved" if pallas and classes > 1
-                  else dense_form(m_local, ds.n_shard, ds.num_features,
-                                  itemsize, local_iters)
-                  if pallas and not sparse else None),
+            form=form,
             rows=("row_major" if pallas and not sparse
                   and stores_row_major(ds.num_features)
                   else "device_default"),
@@ -846,6 +869,11 @@ def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
     def chunk_kernel(w, alpha, idxs_ckh, shard_arrays):
         if isinstance(idxs_ckh, dict):
             idxs_ckh = sampler.tables_from_ts(idxs_ckh["t"])
+        # the fold cache lane-aligned once a chunk, outside its scan (the
+        # device loop has done it once a dispatch, and this emits nothing)
+        from cocoa_tpu.ops.pallas_sdca import with_aligned_rows
+
+        shard_arrays = with_aligned_rows(shard_arrays)
         return chunk_fanout(
             mesh, per_shard, apply_fn, w, alpha, idxs_ckh, shard_arrays,
             per_round_batched=per_round_batched,

@@ -150,9 +150,15 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
         "devices", "shards_per_device", "rows", "state", "step_solve",
         "pass_slot_share", "storage", "margin", "slot_fill", "longest_row",
         "chunk_pieces", "chunk_fill", "refused", "objective", "form",
-        "classes", "lane_fill"]
-    # no stream, no ring
+        "classes", "lane_fill", "row_fetch", "ring_depth"]
+    # no stream, no ring of chunks
     assert (path.chunk_pieces, path.chunk_fill) == (None, None)
+    # the dense Pallas kernel's rows come by its own ring, as deep as fits
+    # (rounds of 8 steps: the shallowest); nothing else has a row fetch
+    assert (path.row_fetch, path.ring_depth) == (
+        ("ring", 2) if pallas and layout == "dense" else (None, None))
+    assert ("rows by a ring 2 steps deep" in path.describe()) == (
+        pallas and layout == "dense")
     # a binary set: one model, no class axis
     assert (path.classes, path.lane_fill) == (1, None)
     # the dual family; which dense kernel runs, on the dense Pallas path

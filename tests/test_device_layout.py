@@ -64,16 +64,12 @@ class _Captured(Exception):
     pass
 
 
-def _capture_run(monkeypatch, d, accel, loss="hinge", classes=1):
-    """``(run, its arguments, the resolved SolverPath)`` of one CoCoA+ job
-    on the dense Pallas path, stopped at the dispatch: the kernels are held
-    at compiled (``interpret=False``; this process's platform is cpu), so
-    nothing here could run."""
-    import jax.numpy as jnp
-
-    from cocoa_tpu.config import DebugParams, Params
-    from cocoa_tpu.data.sharding import ShardedDataset
-    from cocoa_tpu.solvers import base, run_cocoa
+def _arm_capture(monkeypatch):
+    """Stop the next job at its dispatch: the returned dict gets ``run``,
+    its ``args`` and the resolved ``path`` (Pallas forced on, held at
+    compiled: this process's platform is cpu), and the entry raises
+    :class:`_Captured`."""
+    from cocoa_tpu.solvers import base
     from cocoa_tpu.solvers import cocoa as cocoa_mod
 
     got = {}
@@ -98,6 +94,21 @@ def _capture_run(monkeypatch, d, accel, loss="hinge", classes=1):
     monkeypatch.setattr(base, "_build_device_run", capturing)
     monkeypatch.setattr(cocoa_mod, "resolve_solver_path", compiled_pallas)
     base._DEVICE_RUNS.clear()
+    return got
+
+
+def _capture_run(monkeypatch, d, accel, loss="hinge", classes=1):
+    """``(run, its arguments, the resolved SolverPath)`` of one CoCoA+ job
+    on the dense Pallas path, stopped at the dispatch: the kernels are held
+    at compiled (``interpret=False``; this process's platform is cpu), so
+    nothing here could run."""
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import ShardedDataset
+    from cocoa_tpu.solvers import base, run_cocoa
+
+    got = _arm_capture(monkeypatch)
     ones = jnp.ones((K, N_SHARD), jnp.float32)
     ds = ShardedDataset(
         layout="dense", n=K * N_SHARD, num_features=d,
@@ -233,8 +244,8 @@ def test_class_kernel_compiles_at_the_cells_size(one_chip):
 
 def test_logistic_job_compiles_with_its_steps_solved_in_lanes(monkeypatch,
                                                               one_chip):
-    """The epsilon-shaped logistic job (K = 8 interleaved shards, step
-    groups of 2): the packed Newton solve of ops/pallas_sdca.py
+    """The epsilon-shaped logistic job (K = 8 interleaved shards, a row
+    ring 2 steps deep: H = 128): the packed Newton solve of ops/pallas_sdca.py
     ``_solve_in_lanes`` — lane-iota selects into (1, 128) vectors, one
     ``alpha_step`` on them, masked lane reduces back to (1, 1) vectors — lowers
     through Mosaic inside the program's own ``run``, and the run's record
@@ -264,7 +275,7 @@ def test_logistic_kernel_compiles_at_epsilon_size(one_chip):
     from cocoa_tpu.ops import pallas_sdca
 
     k, n_shard, d, h = 8, 50000, 2000, 5000
-    assert pallas_sdca.pick_interleave(k, n_shard, d, 4, h) == 2
+    assert pallas_sdca.pick_interleave(k, n_shard, d, 4, h) == 4
     on_chip = functools.partial(_shape_on, one_chip)
     rows = on_chip((k, n_shard))
     with jax.enable_x64(False):
@@ -285,7 +296,8 @@ SUMMED_DW = {
     "epsilon_lasso": (8, 256, 400_000, 50048, 25, "shard_major",
                       dict(mode="prox", sigma=8.0, loss="lasso",
                            smoothing=0.0)),
-    "epsilon": (8, 50000, 2000, 250, 5000, "interleaved",
+    # (the ring's rows are lane-aligned: 250 -> 256 lanes in the kernel)
+    "epsilon": (8, 50000, 2000, 256, 5000, "interleaved",
                 dict(mode="plus", sigma=8.0)),
     # imagenet.cocoa_plus.x4, one chip's two shards of the mesh's eight
     "imagenet_x4_chip": (2, 4094, 160_000, 20096, 409, "interleaved",
@@ -333,6 +345,103 @@ def test_dense_kernel_returns_one_dw_and_nothing_sums_k(one_chip, name):
     assert held == [], held
 
 
+# --- the dense cells' row fetch (PR 42), with no chip -----------------------
+
+# the benchmark's dense SVM cells as one chip holds them: (k shards on the
+# chip, rows a shard, d, H, lambda, the job's loss, classes) -> the form, how
+# the rows are stored, the ring's depth, and the most the loop program's
+# arguments and temporaries may come to (GB)
+DENSE_CELLS = {
+    "epsilon.cocoa_plus": ((8, 50000, 2000, 5000, 1e-3, "hinge", 1),
+                           "interleaved", "device_default", 4, 13.2),
+    "epsilon.logistic": ((8, 50000, 2000, 5000, 1e-3, "logistic", 1),
+                         "interleaved", "device_default", 4, 13.2),
+    # one chip's two shards of imagenet.cocoa_plus.x4's eight
+    "imagenet.cocoa_plus.x4": ((2, 4094, 160000, 409, 1e-5, "hinge", 1),
+                               "interleaved", "row_major", 2, 10.7),
+    "mnist8m.ovr_cocoa_plus": ((8, 126563, 784, 12656, 1e-4, "hinge", 10),
+                               "interleaved", "device_default", 2, 15.2),
+}
+
+
+def _capture_dense_cell(monkeypatch, k, n_shard, d, h, lam, loss, classes):
+    """``(run, its arguments, the SolverPath)`` of one CoCoA+ job at a dense
+    cell's shape with the cell's flags, stopped at the dispatch; the rows
+    and their fold cache are shapes only."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import ShardedDataset
+    from cocoa_tpu.ops import pallas_sdca
+    from cocoa_tpu.solvers import base, run_cocoa
+
+    got = _arm_capture(monkeypatch)
+    here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    ones = jnp.ones((k, n_shard), jnp.float32)
+    ds = ShardedDataset(
+        layout="dense", n=k * n_shard, num_features=d,
+        counts=np.full(k, n_shard, np.int64), labels=ones, mask=ones,
+        sq_norms=ones,
+        X=jax.ShapeDtypeStruct((k, n_shard, d), jnp.float32, sharding=here),
+        classes=(jnp.zeros((k, n_shard), jnp.int32) if classes > 1
+                 else None), num_classes=classes)
+    lanes = (-(-d // 1024) * 128 if pallas_sdca.stores_row_major(d)
+             else -(-d // 8))
+    ds._x_folded_cache = jax.ShapeDtypeStruct(
+        (k, n_shard, 8, lanes), jnp.float32, sharding=here)
+    with pytest.raises(_Captured):
+        run_cocoa(ds, Params(n=ds.n, num_rounds=600, local_iters=h, lam=lam,
+                             loss=loss),
+                  DebugParams(debug_iter=10, seed=0), plus=True, quiet=True,
+                  math="fast", device_loop=True, rng="permuted",
+                  gap_target=1e-4, accel="off" if classes > 1 else "auto")
+    base._DEVICE_RUNS.clear()
+    return got["run"], got["args"], got["path"]
+
+
+@pytest.mark.parametrize("cell", list(DENSE_CELLS))
+def test_dense_cell_compiles_with_its_rows_fetched_by_the_ring(
+        monkeypatch, one_chip, cell):
+    """The whole device loop of each dense SVM cell at its real shape,
+    compiled for one described v5e: Mosaic takes the kernel whose rows come
+    by its own DMA ring (an HBM operand sliced a row at a time: whole lane
+    tiles only, which is why the loop opens with ONE pad of the fold cache
+    where its last axis is not: ops/pallas_sdca.lane_aligned), the run's
+    record says the form the cell had before the ring and the depth the fit
+    gives, and the loop's arguments and temporaries fit the chip's 15.75 GB
+    with the room a process needs beside them.  (The lasso's cell, whose
+    shard-major kernel keeps the pipelined fetch, is compiled in
+    test_lasso_job_compiles_at_epsilon_size_with_a_float32_certificate.)"""
+    import jax
+
+    shape, form, rows, depth, held_gb = DENSE_CELLS[cell]
+    k, n_shard, d = shape[:3]
+    with jax.enable_x64(False):
+        run, args, path = _capture_dense_cell(monkeypatch, *shape)
+        assert (path.kernel, path.state, path.form, path.rows) == (
+            "pallas", "vmem", form, rows)
+        assert (path.row_fetch, path.ring_depth) == ("ring", depth)
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    stats = compiled.memory_analysis()
+    held = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert held <= held_gb * 1e9, (stats.argument_size_in_bytes,
+                                   stats.temp_size_in_bytes)
+    # the fold cache is relaid and padded at the entry, never in the loop:
+    # every pad that makes a rows-sized array sits in the entry computation
+    lanes = -(-d // 8)
+    aligned = -(-lanes // 128) * 128
+    entry = hlo[hlo.index("ENTRY"):]
+    padded = re.compile(rf"f32\[{k},{n_shard},8,{aligned}\]\S* pad\(")
+    if rows == "row_major":
+        assert not padded.search(hlo)
+    else:
+        assert len(padded.findall(entry)) == 1
+        assert len(padded.findall(hlo)) == 1
+
+
 # --- the sparse deployment that fills a chip (kddb), with no chip -----------
 
 KDDB = dict(n=19264097, d=29890095, k=8, width=64, frac=0.1, lam=1e-5)
@@ -351,30 +460,8 @@ def _capture_sparse_run(monkeypatch):
     from cocoa_tpu.data.sharding import (ShardedDataset, pad_rows,
                                          split_sizes)
     from cocoa_tpu.solvers import base, run_cocoa
-    from cocoa_tpu.solvers import cocoa as cocoa_mod
 
-    got = {}
-    build = base._build_device_run
-
-    def capturing(*args, **kw):
-        run = build(*args, **kw)
-
-        def call(*run_args):
-            got["run"], got["args"] = run, run_args
-            raise _Captured
-
-        return call
-
-    resolve = cocoa_mod.resolve_solver_path
-
-    def compiled_pallas(*args, **kw):
-        got["path"] = dataclasses.replace(
-            resolve(*args, **{**kw, "pallas": True}), interpret=False)
-        return got["path"]
-
-    monkeypatch.setattr(base, "_build_device_run", capturing)
-    monkeypatch.setattr(cocoa_mod, "resolve_solver_path", compiled_pallas)
-    base._DEVICE_RUNS.clear()
+    got = _arm_capture(monkeypatch)
     k, width = KDDB["k"], KDDB["width"]
     sizes = split_sizes(KDDB["n"], k)
     n_shard = pad_rows(int(sizes.max()))
@@ -620,30 +707,8 @@ def _capture_prox_run(monkeypatch):
     from cocoa_tpu.config import DebugParams, Params
     from cocoa_tpu.data.sharding import ShardedDataset, split_sizes
     from cocoa_tpu.solvers import base, run_prox_cocoa
-    from cocoa_tpu.solvers import cocoa as cocoa_mod
 
-    got = {}
-    build = base._build_device_run
-
-    def capturing(*args, **kw):
-        run = build(*args, **kw)
-
-        def call(*run_args):
-            got["run"], got["args"] = run, run_args
-            raise _Captured
-
-        return call
-
-    resolve = cocoa_mod.resolve_solver_path
-
-    def compiled_pallas(*args, **kw):
-        got["path"] = dataclasses.replace(
-            resolve(*args, **{**kw, "pallas": True}), interpret=False)
-        return got["path"]
-
-    monkeypatch.setattr(base, "_build_device_run", capturing)
-    monkeypatch.setattr(cocoa_mod, "resolve_solver_path", compiled_pallas)
-    base._DEVICE_RUNS.clear()
+    got = _arm_capture(monkeypatch)
     k, n = LASSO["k"], LASSO["n"]
     sizes = split_sizes(LASSO["cols"], k)
     d_shard = -(-int(sizes.max()) // 16) * 16
@@ -690,6 +755,8 @@ def test_lasso_job_compiles_at_epsilon_size_with_a_float32_certificate(
         assert pallas_sdca.pick_unroll(d_shard, n, 4, h) == 1
         assert (path.kernel, path.state, path.form, path.rows) == (
             "pallas", "vmem", "shard_major", "row_major")
+        # the one kernel whose rows still come by Pallas's pipeline
+        assert (path.row_fetch, path.ring_depth) == ("pipelined", None)
         compiled = run.lower(*_on_chip(args, one_chip)).compile()
     stats = compiled.memory_analysis()
     assert 6.5e9 < stats.argument_size_in_bytes < 6.7e9   # A, twice

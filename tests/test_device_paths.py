@@ -181,3 +181,54 @@ def test_device_loop_ckpt_round_matches_early_stop(tiny_data, tmp_path):
         f"checkpoint round {meta['round']} overstates executed "
         f"round {last_round}"
     )
+
+
+# --- the run's record says how the dense kernel's rows are fetched (PR 42) --
+
+@pytest.mark.parametrize("case, fetch, depth", [
+    ("dense_pallas_k4", "ring", 2),         # interleaved: the kernel's ring
+    ("dense_pallas_k1", "pipelined", None),     # shard-major: Pallas's
+    ("dense_fori", None, None),
+    ("sparse_pallas", None, None),
+    ("classes_pallas", "ring", 2),
+])
+def test_solver_path_carries_row_fetch_and_ring_depth(tiny_data, case, fetch,
+                                                      depth):
+    """``SolverPath.as_dict()`` has ``row_fetch`` and ``ring_depth`` in
+    every record; they say something on the dense Pallas path alone: the
+    ring and the depth the VMEM fit gives where the K chains advance in
+    lockstep, ``pipelined`` where a shard runs at a time, None on ``fori``
+    and on every sparse path."""
+    import dataclasses
+
+    from cocoa_tpu.solvers.cocoa import resolve_solver_path
+
+    layout = "sparse" if case.startswith("sparse") else "dense"
+    k = 1 if case.endswith("k1") else K
+    ds = shard_dataset(tiny_data, k=k, layout=layout, dtype=jnp.float32)
+    if case.startswith("classes"):
+        ds = dataclasses.replace(
+            ds, classes=jnp.zeros(ds.labels.shape, jnp.int32), num_classes=3)
+    path = resolve_solver_path(ds, 8, math="fast",
+                               pallas="fori" not in case).as_dict()
+    assert {"row_fetch", "ring_depth"} <= set(path)
+    assert (path["row_fetch"], path["ring_depth"]) == (fetch, depth)
+    assert (path["form"] is None) == (fetch is None)
+
+
+def test_a_dense_pallas_job_records_its_ring(tiny_data):
+    """The record a job returns (``Trajectory.meta``) and its console line
+    carry the mechanism: a reader of the run can tell that the ring ran,
+    and how deep."""
+    from cocoa_tpu.solvers import run_cocoa
+
+    ds = shard_dataset(tiny_data, k=K, layout="dense", dtype=jnp.float64)
+    _, _, traj = run_cocoa(ds, _params(tiny_data, num_rounds=4), _DBG,
+                           plus=True, quiet=True, math="fast", pallas=True)
+    path = traj.meta["solver_path"]
+    assert (path["form"], path["row_fetch"], path["ring_depth"]) == (
+        "interleaved", "ring", 2)      # rounds of 15 steps: the shallowest
+    _, _, fori = run_cocoa(ds, _params(tiny_data, num_rounds=4), _DBG,
+                           plus=True, quiet=True, math="fast", pallas=False)
+    assert fori.meta["solver_path"]["row_fetch"] is None
+    assert fori.meta["solver_path"]["ring_depth"] is None

@@ -199,7 +199,7 @@ def _traced_round(loss, k, interleave, mode="plus", h=4):
             jnp.zeros((k, h), jnp.int32))
     return jax.make_jaxpr(lambda *a: pallas_sdca_round(
         *a, 0.01, 1000, mode=mode, sigma=3.0, loss=loss, smoothing=S,
-        interleave=interleave, unroll=2))(*args)
+        interleave=interleave, unroll=2, depth=2))(*args)
 
 
 def _kernel_jaxpr(loss, k, interleave):
@@ -288,7 +288,10 @@ def test_only_an_iterative_step_is_solved_in_lanes(loss, interleave):
     each."""
     import re
 
-    k, unroll = 3, 2
+    # lockstep steps the traced body holds: the shard-major kernel's group
+    # of 2; the interleaved kernel's one (its ring's group is a loop
+    # unrolled at lowering, traced once whatever the depth)
+    k, unroll = 3, 1 if interleave else 2
     text = _kernel_jaxpr(loss, k, interleave)
     exps = re.findall(r":(\w+)\[([\d,]*)\] = exp ", text)
     packed = re.findall(r"\[1,128\]", text)
@@ -315,6 +318,32 @@ def test_no_value_of_a_step_is_a_scalar(loss, mode, h, interleave):
     (``jnp.sum(v)``), and so sends a value to the scalar core and back,
     eight chains a lockstep step, fails here (PERF.md section 6, PR 39)."""
     body = _kernel_body(loss, 3, interleave, mode=mode, h=h)
+    assert any(e.primitive.name == "reduce_sum" for e in _walk(body))
+    assert _scalar_floats(body) == []
+
+
+@pytest.mark.parametrize("depth, h", [(2, 4), (2, 5), (4, 8), (4, 3)])
+@pytest.mark.parametrize("loss", ALL)
+def test_no_value_of_a_class_step_is_a_scalar(loss, depth, h):
+    """The same reading of the T-class kernel's body, whose rows come by
+    the ring too: no 0-d float at any depth, in a round of whole ring
+    turns or one whose last group runs masked steps past H (the ``live``
+    mask is a comparison of integers, and zeroes the update as a select
+    on vectors)."""
+    import jax
+
+    from cocoa_tpu.ops.pallas_sdca import pallas_sdca_round_classes
+
+    k, n_shard, d, t = 3, 16, 16, 3
+    args = (jnp.zeros((t, d)), jnp.zeros((t, k, n_shard)),
+            jnp.ones((k, n_shard, d)), jnp.zeros((k, n_shard), jnp.int32),
+            jnp.ones((k, n_shard)), jnp.zeros((k, h), jnp.int32))
+    traced = jax.make_jaxpr(lambda *a: pallas_sdca_round_classes(
+        *a, 0.01, 1000, mode="plus", sigma=3.0, loss=loss, smoothing=S,
+        depth=depth))(*args)
+    (call,) = [e for e in _walk(traced.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    body = call.params["jaxpr"]
     assert any(e.primitive.name == "reduce_sum" for e in _walk(body))
     assert _scalar_floats(body) == []
 
