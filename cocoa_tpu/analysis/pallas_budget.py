@@ -168,6 +168,16 @@ def check_gate_estimate_agreement() -> list:
             flag("cocoa_tpu.ops.pallas_sparse_hbm",
                  f"hbm_plan(d={d}, W={max_nnz}, H={h}) = {plan} does not "
                  f"cover the round or its columns")
+    for size in (4, 8):
+        # the relayout of a fold cache that is not stored lane-aligned: its
+        # blocks depend on the dtype alone (f64: interpret mode), and the
+        # rows it picks keep its four buffers inside the budget
+        rows = sdca.pick_align_rows(size)
+        if rows not in sdca.ALIGN_ROWS or sdca.align_vmem_estimate(
+                rows, size) > sdca.VMEM_BUDGET:
+            flag("cocoa_tpu.ops.pallas_sdca",
+                 f"pick_align_rows({size}) chose {rows} rows a step, whose "
+                 f"estimate exceeds VMEM_BUDGET")
     for (k, n_shard, d, max_nnz, b, n_hot) in _SHAPES:
         # sequential sparse kernel: fits ⇒ estimate under budget AND the
         # SMEM segment split leaves at least one step per invocation

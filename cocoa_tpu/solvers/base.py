@@ -837,12 +837,14 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
         # static at trace time — a different block length just retraces
         n_chunks = jax.tree.leaves(idxs_all)[0].shape[0]
         if isinstance(shard_arrays, dict):
-            # the dense kernels' row ring reads whole lane tiles: once a
-            # dispatch, here, because XLA leaves a pad inside the loop's
-            # body where it is (ops/pallas_sdca.lane_aligned)
+            # the dense kernels' row ring reads whole lane tiles: a fold
+            # cache that is not stored so is relaid once a dispatch, here,
+            # by one kernel of the program's own (ops/pallas_sdca
+            # .lane_aligned), never in the loop's body, where it would
+            # run every round
             from cocoa_tpu.ops.pallas_sdca import with_aligned_rows
 
-            shard_arrays = with_aligned_rows(shard_arrays)
+            shard_arrays = with_aligned_rows(shard_arrays, mesh)
 
         def cond(s):
             i, done_tgt, done_stall, stall, best, best_prev, state, traj = s

@@ -180,9 +180,9 @@ class SolverPath:
     the dense kernel's folded rows are stored — ``row_major``: lane-padded
     so that the device keeps them as the device loop reads them (no
     relayout per dispatch); ``device_default``: everything else (on a TPU
-    that can be the row index on the lanes, which every dispatch
-    transposes first — ops/pallas_sdca.fold_rows).  ``state``: where w, Δw
-    and α live while the local solve runs — ``vmem``: resident on the chip
+    that can be the row index on the lanes, which every dispatch relays
+    first — ``row_align``, ops/pallas_sdca.lane_aligned).  ``state``: where
+    w, Δw and α live while the local solve runs — ``vmem``: resident on the chip
     for the round (the Pallas kernels of ops/pallas_sdca.py and
     ops/pallas_sparse.py); ``hbm``: in HBM, only a segment's touched part
     of them on the chip (ops/pallas_sparse_hbm.py, the sparse kernel for
@@ -253,7 +253,14 @@ class SolverPath:
     of K rows deep, the depth read from the VMEM fit
     (ops/pallas_sdca._ring_steps; the interleaved and the class kernel);
     ``pipelined``: as a BlockSpec operand of Pallas's grid pipeline, one
-    step ahead (the shard-major kernel; ``ring_depth`` None)."""
+    step ahead (the shard-major kernel; ``ring_depth`` None).
+    ``row_align`` (None where there is no fold cache: ``fori`` and every
+    sparse path): how the fold cache comes to be the row-major rows of
+    whole lane tiles those kernels read — ``stored``: it is kept so
+    (``rows`` ``row_major``), and a dispatch does nothing; ``kernel``: a
+    dispatch opens with one Pallas kernel that reads the cache in the
+    order the device stores it and writes the aligned rows
+    (ops/pallas_sdca.lane_aligned, the ``cocoa_row_align`` scope)."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -279,6 +286,7 @@ class SolverPath:
     lane_fill: Optional[float] = None
     row_fetch: Optional[str] = None
     ring_depth: Optional[int] = None
+    row_align: Optional[str] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -322,7 +330,9 @@ class SolverPath:
                     + (", state in HBM" if self.pallas
                        and self.state == "hbm" else "")
                     + (f" [{self.refused}]" if self.refused else ""))
-        rows = ", rows stored row-major" if self.rows == "row_major" else ""
+        rows = (", rows stored row-major" if self.rows == "row_major"
+                else ", rows relaid by a kernel once a dispatch"
+                if self.row_align == "kernel" else "")
         if self.form:
             what += f" {self.form}"
         if self.row_fetch == "ring":
@@ -599,15 +609,16 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                                           ds.num_features, itemsize,
                                           local_iters) or None)
             form = "interleaved" if depth else "shard_major"
+            row_major = stores_row_major(ds.num_features)
             placement.update(row_fetch="ring" if depth else "pipelined",
-                             ring_depth=depth)
+                             ring_depth=depth,
+                             rows="row_major" if row_major
+                             else "device_default",
+                             row_align="stored" if row_major else "kernel")
         return SolverPath(
             inner="sequential", kernel="pallas" if pallas else "fori",
             chain=None, interpret=bool(pallas and platform == "cpu"),
             form=form,
-            rows=("row_major" if pallas and not sparse
-                  and stores_row_major(ds.num_features)
-                  else "device_default"),
             state="vmem" if pallas and not hbm_state else "hbm",
             step_solve=("scalar" if not pallas or sparse
                         else "lanes" if classes > 1
@@ -851,11 +862,12 @@ def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
     ``lax.scan`` (parallel/fanout.py chunk_fanout).  On Pallas configs the
     caller (run_sdca_family) pre-folds ``shard_arrays["X_folded"]`` once per
     dataset — the kernel itself never folds.  Whether a dispatch still
-    relayouts the folded rows depends on how they are STORED
-    (``SolverPath.rows``): lane-padded, row-major is the device's own
-    layout for them and the loop reads them as they are; otherwise a TPU
-    may keep the row index on the lanes and transpose the whole array at
-    every dispatch's entry (ops/pallas_sdca.fold_rows).
+    relays the folded rows depends on how they are STORED
+    (``SolverPath.rows`` / ``row_align``): lane-padded, row-major is the
+    device's own layout for them and the loop reads them as they are;
+    otherwise a TPU keeps the row index on the lanes and every dispatch
+    opens with one pass of the relayout kernel over the whole array
+    (ops/pallas_sdca.lane_aligned).
 
     ``idxs_ckh`` is a concrete (C, K, H) table, or — device-sampling mode —
     the ``{"t": (C,)}`` spec expanded in-jit through ``sampler`` (index
@@ -873,7 +885,7 @@ def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
         # device loop has done it once a dispatch, and this emits nothing)
         from cocoa_tpu.ops.pallas_sdca import with_aligned_rows
 
-        shard_arrays = with_aligned_rows(shard_arrays)
+        shard_arrays = with_aligned_rows(shard_arrays, mesh)
         return chunk_fanout(
             mesh, per_shard, apply_fn, w, alpha, idxs_ckh, shard_arrays,
             per_round_batched=per_round_batched,

@@ -114,8 +114,11 @@ SCOPE_SPARSE_GATHER = "cocoa_sparse_gather"  # sparse rows past VMEM: the
                                           # gathers, local-id remap and
                                           # scatters that feed and drain the
                                           # chain (ops/pallas_sparse_hbm.py)
+SCOPE_ROW_ALIGN = "cocoa_row_align"       # the dense fold cache relaid into
+                                          # lane-aligned rows, once a dispatch
+                                          # (ops/pallas_sdca.lane_aligned)
 SCOPES = (SCOPE_LOCAL_SOLVE, SCOPE_DW_REDUCE, SCOPE_EVAL, SCOPE_INDICES,
-          SCOPE_ACCEL_JUMP, SCOPE_SPARSE_GATHER)
+          SCOPE_ACCEL_JUMP, SCOPE_SPARSE_GATHER, SCOPE_ROW_ALIGN)
 
 # the cold path (module docstring): the records ``Tracer.cold`` keeps, the
 # span a cold job's entry wears, and what one HBM reading holds of a device

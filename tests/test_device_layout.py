@@ -14,11 +14,16 @@ the benchmark's cells unseen until a trace on the chip showed them (PERF.md
 An array nobody gave a layout gets the device's default, which on a TPU is
 the dimension order that pads least under the (8, 128) tile: at d = 2,000
 the row index goes on the lanes (2,000 -> 2,048 would pad 2.4%), and the
-Pallas kernel, which takes row-major only, forces a transpose of the folded
-rows.  That one copy is the known remainder (``SolverPath.rows ==
-"device_default"``); nothing may copy ``X``, and at a width whose lane
-padding is small the fold cache is stored lane-padded, which makes
-row-major the device's own layout for it, and nothing is copied at all.
+Pallas kernels take row-major rows of whole lane tiles only.  XLA made
+those of such a cache in two whole-array ops, a ``copy`` (the transpose)
+and a ``pad`` (250 -> 256 lanes), 6.55 GB of temporaries at epsilon; since
+PR 43 the program does it in one pass of its own (``SolverPath.row_align
+== "kernel"``): the cache reaches the ``cocoa_row_align`` kernel through a
+``bitcast`` (the stored bytes, renamed), and the kernel's result is the
+ring kernel's operand (:func:`_assert_one_pass_over_the_fold_cache`).
+Nothing may copy ``X``, and at a width whose lane padding is small the fold
+cache is stored lane-padded, which makes row-major the device's own layout
+for it, and nothing is copied or relaid at all.
 
 All topology work happens inside fixtures and tests: only one process at a
 time may load the TPU's library, and every xdist worker imports this file.
@@ -33,18 +38,18 @@ import numpy as np
 import pytest
 
 K, N_SHARD, H = 8, 1280, 128
-WIDTHS = {"rows_on_lanes": 2000, "row_major": 2040}     # 2.4% / 0.39%
+# lane padding 2.4% / 0.39% / 30% (mnist8m's width, one model)
+WIDTHS = {"rows_on_lanes": 2000, "row_major": 2040, "narrow_rows": 784}
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A ``SingleDeviceSharding`` on one described v5e chip; the compile
-    cache is off while the module runs (an ahead-of-time executable is
-    written to it but cannot be read back without a chip)."""
+def four_chips():
+    """The devices of a described ``v5e:2x2``; the compile cache is off
+    while the module runs (an ahead-of-time executable is written to it but
+    cannot be read back without a chip)."""
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -55,9 +60,17 @@ def one_chip():
     cache_was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", cache_was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    """A ``SingleDeviceSharding`` on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(four_chips[0])
 
 
 class _Captured(Exception):
@@ -69,6 +82,7 @@ def _arm_capture(monkeypatch):
     its ``args`` and the resolved ``path`` (Pallas forced on, held at
     compiled: this process's platform is cpu), and the entry raises
     :class:`_Captured`."""
+    from cocoa_tpu.ops import pallas_sdca
     from cocoa_tpu.solvers import base
     from cocoa_tpu.solvers import cocoa as cocoa_mod
 
@@ -93,6 +107,8 @@ def _arm_capture(monkeypatch):
 
     monkeypatch.setattr(base, "_build_device_run", capturing)
     monkeypatch.setattr(cocoa_mod, "resolve_solver_path", compiled_pallas)
+    # the relayout at the dispatch's entry too (it asks the platform)
+    monkeypatch.setattr(pallas_sdca, "_interpreted", lambda: False)
     base._DEVICE_RUNS.clear()
     return got
 
@@ -145,6 +161,41 @@ def _shape_on(chip, shape, dtype="float32"):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
 
+def _assert_one_pass_over_the_fold_cache(compiled, k, n_shard, d):
+    """The fold cache (K, n_shard, 8, d/8), d/8 no whole lane tile, becomes
+    the kernels' rows in ONE pass of the program's own: no ``copy(`` and no
+    ``pad(`` anywhere in the optimised HLO makes an array of the cache's or
+    of the aligned rows' shape; in the entry computation the one
+    ``cocoa_row_align`` custom call takes nothing but ``bitcast``s of the
+    cache to (K, 8 d/8, n_shard), the order the device stores it in (one
+    an operand: each brings 128 rows of a grid step), and writes
+    (K, 8 n_shard, lanes), the aligned rows under another name; the loop's
+    body holds no such call; and the program's temporaries are the aligned
+    rows and 5% (``copy`` + ``pad`` held two such arrays)."""
+    hlo = compiled.as_text()
+    d8 = d // 8
+    lanes = -(-d8 // 128) * 128
+    rows = rf"f32\[{k},(?:{n_shard},8|{8 * n_shard}),(?:{d8}|{lanes})\]"
+    whole = [line.strip()[:160] for line in hlo.splitlines()
+             if re.search(rf"= {rows}\S* (?:copy|pad)\(", line)]
+    assert whole == [], whole
+    entry = hlo[hlo.index("ENTRY"):]
+    (cache,) = re.findall(
+        rf"(%\S+) = f32\[{k},{n_shard},8,{d8}\]\S* parameter\(", entry)
+    stored = re.findall(
+        rf"(%\S+) = f32\[{k},{8 * d8},{n_shard}\]\S* bitcast\("
+        + re.escape(cache) + r"\)", entry)
+    calls = [line for line in hlo.splitlines()
+             if "custom-call(" in line and "cocoa_row_align" in line]
+    assert len(calls) == 1 and calls[0] in entry, calls
+    call = re.search(rf"= f32\[{k},{8 * n_shard},{lanes}\]\S* "
+                     r"custom-call\(([^)]*)\)", calls[0])
+    assert call and stored, calls[0][:300]
+    assert set(call.group(1).split(", ")) == set(stored), call.group(1)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.05 * k * n_shard * 8 * lanes * 4, temp
+
+
 def _row_copies(hlo: str, d: int) -> list:
     """Names of the entry parameters of the rows' shapes that some
     ``copy(`` takes as its operand."""
@@ -168,16 +219,56 @@ def test_device_loop_opens_with_no_copy_of_the_rows(monkeypatch, one_chip,
     d = WIDTHS[case]
     with jax.enable_x64(False):     # as on the chip; the suite runs with x64
         run, args, path = _capture_run(monkeypatch, d, accel)
-        assert path.rows == ("row_major" if case == "row_major"
-                             else "device_default")
-        hlo = run.lower(*_on_chip(args, one_chip)).compile().as_text()
+        assert (path.rows, path.row_align) == (
+            ("row_major", "stored") if case == "row_major"
+            else ("device_default", "kernel"))
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo         # the kernel is in the program
-    copied = _row_copies(hlo, d)
+    # neither X nor the fold cache: stored lane-padded it is read as it is,
+    # stored with the row index on the lanes it is relaid by the program's
+    # own kernel, from the bytes as they lie
+    assert _row_copies(hlo, d) == []
     if case == "row_major":
-        assert copied == []
+        assert "cocoa_row_align" not in hlo
     else:
-        # the fold cache, stored with the row index on the lanes; never X
-        assert len(copied) == 1 and "X_folded" in copied[0], copied
+        _assert_one_pass_over_the_fold_cache(compiled, K, N_SHARD, d)
+
+
+def test_relayout_of_a_sharded_cache_stays_on_its_chip(four_chips):
+    """epsilon's fold cache across the 2x2's dp mesh, two shards a chip
+    (no cell: x4's cache is stored lane-padded).  Under the run's mesh the
+    relayout goes through ``shard_map`` and every chip relays the shards it
+    holds, from the bytes as they lie, with no collective; without the mesh
+    Mosaic refuses the call ("cannot be automatically partitioned") rather
+    than gather the rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from cocoa_tpu.ops import pallas_sdca
+    from cocoa_tpu.parallel.mesh import DP_AXIS
+
+    mesh = Mesh(np.array(four_chips), (DP_AXIS,))
+    cache = jax.ShapeDtypeStruct((8, 12500, 8, 250), jnp.float32,
+                                 sharding=NamedSharding(mesh, P(DP_AXIS)))
+
+    def relay(mesh):
+        return jax.jit(functools.partial(
+            pallas_sdca.lane_aligned, interpret=False,
+            mesh=mesh)).lower(cache).compile()
+
+    with jax.enable_x64(False):
+        compiled = relay(mesh)
+        with pytest.raises(Exception, match="shard_map"):
+            relay(None)
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("ENTRY"):]
+    assert "f32[2,2000,12500]{2,1,0:T(8,128)} bitcast(" in entry
+    assert re.search(r"f32\[2,100000,256\]\S* custom-call\(", entry)
+    assert not re.search(r"all-gather|all-reduce|collective-permute| copy\(",
+                         hlo)
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 # --- one-vs-rest: T = 10 class models over the one copy of the rows ---------
@@ -188,8 +279,9 @@ def test_ten_class_job_reads_one_copy_of_the_rows(monkeypatch, one_chip,
     """The mnist8m-shaped job (d = 784: a fold of (8, 98), neither a
     multiple of 1,024 nor long; T = 10) compiled for the chip as the
     program's own ``run``: the class kernel lowers through Mosaic inside
-    it; the one whole-array copy is the fold cache's (stored as the device
-    lays it out at this width: ``SolverPath.rows`` says so), never X's;
+    it; no whole-array copy is left, of X or of the fold cache (stored as
+    the device lays it out at this width, ``SolverPath.rows`` says so, and
+    relaid by the program's own kernel in one pass, ``row_align``);
     nothing holds the rows once per class, and no op works on a one-row
     (1, d) matrix (PR 37's trap), per class or not."""
     import jax
@@ -200,11 +292,12 @@ def test_ten_class_job_reads_one_copy_of_the_rows(monkeypatch, one_chip,
                                        classes=t)
         assert (path.classes, path.kernel, path.form, path.rows) == (
             t, "pallas", "interleaved", "device_default")
-        assert path.lane_fill == 10 / 16
-        hlo = run.lower(*_on_chip(args, one_chip)).compile().as_text()
+        assert (path.lane_fill, path.row_align) == (10 / 16, "kernel")
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    hlo = compiled.as_text()
     assert "pallas_sdca_classes" in hlo and "tpu_custom_call" in hlo
-    copied = _row_copies(hlo, d)
-    assert len(copied) == 1 and "X_folded" in copied[0], copied
+    assert _row_copies(hlo, d) == []
+    _assert_one_pass_over_the_fold_cache(compiled, K, N_SHARD, d)
     per_class = re.findall(rf"f32\[{t},{K},{N_SHARD},(?:{d}|8,{d // 8})\]",
                            hlo)
     assert not per_class, per_class
@@ -249,7 +342,8 @@ def test_logistic_job_compiles_with_its_steps_solved_in_lanes(monkeypatch,
     ``_solve_in_lanes`` — lane-iota selects into (1, 128) vectors, one
     ``alpha_step`` on them, masked lane reduces back to (1, 1) vectors — lowers
     through Mosaic inside the program's own ``run``, and the run's record
-    says ``lanes``."""
+    says ``lanes``; the fold cache reaches its ring in one pass, as under
+    hinge."""
     import jax
 
     from cocoa_tpu.ops import pallas_sdca
@@ -261,8 +355,9 @@ def test_logistic_job_compiles_with_its_steps_solved_in_lanes(monkeypatch,
                                        loss="logistic")
         assert (path.kernel, path.state, path.step_solve) == (
             "pallas", "vmem", "lanes")
-        hlo = run.lower(*_on_chip(args, one_chip)).compile().as_text()
-    assert "tpu_custom_call" in hlo
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_one_pass_over_the_fold_cache(compiled, K, N_SHARD, d)
 
 
 def test_logistic_kernel_compiles_at_epsilon_size(one_chip):
@@ -350,17 +445,18 @@ def test_dense_kernel_returns_one_dw_and_nothing_sums_k(one_chip, name):
 # the benchmark's dense SVM cells as one chip holds them: (k shards on the
 # chip, rows a shard, d, H, lambda, the job's loss, classes) -> the form, how
 # the rows are stored, the ring's depth, and the most the loop program's
-# arguments and temporaries may come to (GB)
+# arguments and temporaries may come to (GB; 13.2 and 15.2 at epsilon and
+# mnist8m while the fold cache came by XLA's copy + pad, until PR 43)
 DENSE_CELLS = {
     "epsilon.cocoa_plus": ((8, 50000, 2000, 5000, 1e-3, "hinge", 1),
-                           "interleaved", "device_default", 4, 13.2),
+                           "interleaved", "device_default", 4, 9.9),
     "epsilon.logistic": ((8, 50000, 2000, 5000, 1e-3, "logistic", 1),
-                         "interleaved", "device_default", 4, 13.2),
+                         "interleaved", "device_default", 4, 9.9),
     # one chip's two shards of imagenet.cocoa_plus.x4's eight
     "imagenet.cocoa_plus.x4": ((2, 4094, 160000, 409, 1e-5, "hinge", 1),
                                "interleaved", "row_major", 2, 10.7),
     "mnist8m.ovr_cocoa_plus": ((8, 126563, 784, 12656, 1e-4, "hinge", 10),
-                               "interleaved", "device_default", 2, 15.2),
+                               "interleaved", "device_default", 2, 10.9),
 }
 
 
@@ -406,8 +502,9 @@ def test_dense_cell_compiles_with_its_rows_fetched_by_the_ring(
     """The whole device loop of each dense SVM cell at its real shape,
     compiled for one described v5e: Mosaic takes the kernel whose rows come
     by its own DMA ring (an HBM operand sliced a row at a time: whole lane
-    tiles only, which is why the loop opens with ONE pad of the fold cache
-    where its last axis is not: ops/pallas_sdca.lane_aligned), the run's
+    tiles only, which is why the loop opens with ONE pass over the fold
+    cache where its last axis is not: ops/pallas_sdca.lane_aligned, the
+    program's own relayout kernel), the run's
     record says the form the cell had before the ring and the depth the fit
     gives, and the loop's arguments and temporaries fit the chip's 15.75 GB
     with the room a process needs beside them.  (The lasso's cell, whose
@@ -429,17 +526,14 @@ def test_dense_cell_compiles_with_its_rows_fetched_by_the_ring(
     held = stats.argument_size_in_bytes + stats.temp_size_in_bytes
     assert held <= held_gb * 1e9, (stats.argument_size_in_bytes,
                                    stats.temp_size_in_bytes)
-    # the fold cache is relaid and padded at the entry, never in the loop:
-    # every pad that makes a rows-sized array sits in the entry computation
-    lanes = -(-d // 8)
-    aligned = -(-lanes // 128) * 128
-    entry = hlo[hlo.index("ENTRY"):]
-    padded = re.compile(rf"f32\[{k},{n_shard},8,{aligned}\]\S* pad\(")
+    # the fold cache is relaid at the entry, once, by the program's own
+    # kernel, and never in the loop; stored lane-padded it is not relaid
     if rows == "row_major":
-        assert not padded.search(hlo)
+        assert (path.row_align, "cocoa_row_align" in hlo) == ("stored",
+                                                              False)
     else:
-        assert len(padded.findall(entry)) == 1
-        assert len(padded.findall(hlo)) == 1
+        assert path.row_align == "kernel"
+        _assert_one_pass_over_the_fold_cache(compiled, k, n_shard, d)
 
 
 # --- the sparse deployment that fills a chip (kddb), with no chip -----------

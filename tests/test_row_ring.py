@@ -153,10 +153,116 @@ def test_fold_lanes_of_the_three_ways_rows_come_folded(d, lanes, fold):
 
 def test_lane_aligned_pads_only_what_is_not_whole_tiles():
     X = jnp.ones((2, 4, 8, 250), jnp.float32)
-    out = pallas_sdca.lane_aligned(X)
+    out = pallas_sdca.lane_aligned(X)       # interpreted: the platform's
     assert out.shape == (2, 4, 8, 256)
     assert float(out[..., 250:].sum()) == 0.0
     whole = jnp.ones((2, 4, 8, 256), jnp.float32)
     assert pallas_sdca.lane_aligned(whole) is whole
     with pytest.raises(ValueError, match="hold no"):
         pallas_sdca.fold_lanes(2000, 384)
+
+
+# --- the relayout kernel: the fold cache to lane-aligned rows (PR 43) -------
+
+def _pad_of(folded):
+    """What XLA's ``copy`` + ``pad`` made of the fold cache until PR 43."""
+    return jnp.pad(folded, ((0, 0),) * 3 + ((0, -folded.shape[-1] % 128),))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "float64"])
+@pytest.mark.parametrize("k", [1, 8])
+@pytest.mark.parametrize("d", [2000, 784, 1024, 10400])
+def test_relayout_kernel_equals_the_pad_to_the_bit(d, k, dtype):
+    """``lane_aligned`` of a fold cache whose last axis is no whole lane
+    tile (epsilon's d/8 = 250, mnist8m's 98; 1,300: eleven tiles, a tile a
+    grid step as at every width) is ``jnp.pad`` of it, bit for bit, from
+    ONE ``pallas_call``: the last row block is partial (300 rows, blocks of
+    512 or 256, whose parts past the rows re-read the last), the last lane
+    tile's input block is taller than the stored array (8 x 256 against
+    2,000) and the lanes past d/8 come out zero; f64 as f32.  A cache that
+    is aligned as stored (d = 1,024) comes back as it is and nothing is
+    traced."""
+    n_shard = 300
+    X = jax.random.normal(jax.random.PRNGKey(d + k), (k, n_shard, d),
+                          jnp.dtype(dtype))
+    folded = pallas_sdca.fold_rows(X)
+    aligned = jax.jit(functools.partial(pallas_sdca.lane_aligned,
+                                        interpret=True))
+    traced = str(jax.make_jaxpr(aligned)(folded))
+    if d == 1024:
+        assert pallas_sdca.lane_aligned(folded, True) is folded
+        assert "pallas_call" not in traced
+        return
+    assert traced.count("pallas_call") == 1 and " pad" not in traced
+    out = aligned(folded)
+    assert out.dtype == folded.dtype
+    assert out.shape == (k, n_shard, 8, -(-d // 1024) * 128)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(_pad_of(folded)))
+    assert float(jnp.abs(out[..., :d // 8]).min()) > 0      # not 0 == 0
+
+
+def test_relayout_kernel_under_a_mesh_runs_a_shard_a_device():
+    """With the dp mesh of the run the kernel goes under ``shard_map``:
+    every device relays its own shards (a ``pallas_call`` on a sharded
+    array outside it would be handed the whole array), and the result is
+    the pad again, sharded as the cache was."""
+    from cocoa_tpu.parallel import make_mesh
+    from cocoa_tpu.parallel.mesh import DP_AXIS
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    mesh = make_mesh(4)
+    rows = NamedSharding(mesh, P(DP_AXIS))
+    folded = jax.device_put(jax.random.normal(
+        jax.random.PRNGKey(0), (8, 200, 8, 98), jnp.float32), rows)
+    out = jax.jit(functools.partial(pallas_sdca.lane_aligned, interpret=True,
+                                    mesh=mesh))(folded)
+    assert out.sharding.is_equivalent_to(rows, out.ndim)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.asarray(_pad_of(folded)))
+
+
+@pytest.mark.parametrize("cell, d, layout, pallas, want", [
+    ("epsilon", 2000, "dense", True, "kernel"),
+    ("mnist8m", 784, "dense", True, "kernel"),
+    ("imagenet", 160000, "dense", True, "stored"),
+    ("epsilon_lasso", 400000, "dense", True, "stored"),
+    ("kddb", 29890095, "sparse", True, None),
+    ("epsilon_fori", 2000, "dense", False, None),
+])
+def test_solver_path_says_how_the_rows_come_aligned(cell, d, layout, pallas,
+                                                    want):
+    """``SolverPath.row_align``: ``kernel`` where a dispatch relays the
+    fold cache, ``stored`` where the cache is kept lane-padded, None where
+    there is no fold cache (a sparse set, the ``fori`` path)."""
+    import types
+
+    from cocoa_tpu.solvers.cocoa import resolve_solver_path
+
+    labels = jnp.zeros((2, 128), jnp.float32)
+    ds = types.SimpleNamespace(
+        k=2, labels=labels, layout=layout, n_hot=0, n_shard=128,
+        num_features=d, sp_indices=jnp.zeros((2, 128, 4), jnp.int32),
+        sp_row_ptr=None)
+    path = resolve_solver_path(ds, 8, math="fast", pallas=pallas)
+    assert path.row_align == want
+    assert path.as_dict()["row_align"] == want
+    assert (path.rows == "row_major") == (want == "stored")
+    assert ("relaid by a kernel once a dispatch" in path.describe()) == (
+        want == "kernel")
+
+
+@pytest.mark.parametrize("itemsize, rows", [(4, 512), (8, 256)])
+def test_relayout_block_is_sized_from_its_vmem_estimate(itemsize, rows):
+    """The kernel's blocks (two buffers in, two out) are what
+    ``align_vmem_estimate`` counts, and ``pick_align_rows`` takes the most
+    rows a step that stay under ``VMEM_BUDGET``: 512 at f32 (8 MiB), 256 at
+    f64 (interpret mode only)."""
+    assert pallas_sdca.pick_align_rows(itemsize) == rows
+    est = pallas_sdca.align_vmem_estimate(rows, itemsize)
+    assert est == (2 * (rows // 128) * 8 * 128 * 128
+                   + 2 * 8 * rows * 128) * itemsize
+    assert est <= pallas_sdca.VMEM_BUDGET
+    assert all(pallas_sdca.align_vmem_estimate(more, itemsize)
+               > pallas_sdca.VMEM_BUDGET
+               for more in pallas_sdca.ALIGN_ROWS if more > rows)

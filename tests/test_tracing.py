@@ -295,10 +295,12 @@ def test_lowered_device_loop_carries_each_scope_once(lowered, case,
     the index tables, and under ``--accel`` the secant jump — on the
     batched Pallas path (interpreted here), the vmapped logistic path and
     a 4-device mesh; no name sits inside another, so an op belongs to one
-    phase.  Two names have a path of their own: the jump only under
-    ``--accel``, and ``cocoa_sparse_gather`` only where sparse rows take
+    phase.  Three names have a path of their own: the jump only under
+    ``--accel``, ``cocoa_sparse_gather`` only where sparse rows take
     the kernel whose state stays in HBM (a sibling of the local solve's
-    scope there, never inside it)."""
+    scope there, never inside it), and ``cocoa_row_align`` only where a
+    dense Pallas job's fold cache is not stored lane-aligned (d/8 = 4
+    here: the relayout at the dispatch's entry, before the loop)."""
     from cocoa_tpu.ops import pallas_sparse
     from cocoa_tpu.parallel import make_mesh
 
@@ -312,7 +314,8 @@ def test_lowered_device_loop_carries_each_scope_once(lowered, case,
     _loop_run(**kw)
     names = _loc_names(lowered[-1][0])
     own_case = {tracing.SCOPE_ACCEL_JUMP: "mesh_accel",
-                tracing.SCOPE_SPARSE_GATHER: "sparse_hbm"}
+                tracing.SCOPE_SPARSE_GATHER: "sparse_hbm",
+                tracing.SCOPE_ROW_ALIGN: "hinge_pallas"}
     for scope in tracing.SCOPES:
         assert any(scope in n for n in names) == (
             own_case.get(scope, case) == case), (scope, case)
