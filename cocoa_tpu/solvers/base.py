@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import contextlib
 from collections import OrderedDict
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -91,7 +91,7 @@ def stall_window(debug_iter: int) -> int:
 #   sched[0] = stage       index into the static σ′ ladder
 #   sched[1] = stall       consecutive no-improvement evals at this stage
 #   sched[2] = best        best gap seen since the stage started
-#   sched[3] = best_prev   best at the last watch reset (the _GapWatch twin)
+#   sched[3] = best_prev   best at the last watch reset (as _GapWatch's)
 #   sched[4] = t_next      1-based round the NEXT chunk starts at (the
 #                          chunk kernels advance it; the warm-start loss
 #                          handoff reads it — solvers/cocoa.py)
@@ -122,36 +122,12 @@ MAX_SIGMA_LEVELS = 8
 #   sched[11] = th_best    Θ watch best gap since the stage started
 #   sched[12] = th_best_prev
 #
-# The jump itself (solvers/cocoa.py accel_kernel head): with the two
-# banked snapshots h1, h2 and the current α, the window displacements
-# δ₁ = h2−h1 and δ₂ = α−h2 give the autocorrelation ρ = ⟨δ₁,δ₂⟩/⟨δ₁,δ₁⟩
-# of the outer iteration's limiting mode, and the secant/Anderson-1
-# fixed-point jump α ← α + c·δ₂ with c = ρ/(1−ρ) lands where the
-# geometric tail α + δ₂·(ρ + ρ² + …) is heading.  c is SIGNED and
-# data-derived: oscillation (ρ ≈ −1) makes it pairwise averaging
-# (c ≈ −½), slow drift (ρ → 1) aggressive extrapolation, clipped to
-# [ACCEL_CMIN, ACCEL_CMAX].  The jumped α is clipped back to the dual
-# box and masked, and w is advanced by the EXACT correspondence update
-# Σ y·Δα·x/(λn) (ops/rows.shards_axpy) — so (w, α) remains a feasible
-# primal-dual pair and the unmodified gap evaluation in
-# evals/objectives.py stays the certificate.  A gap RISE at an eval
-# boundary discards the bank (restart): damage from a bad jump is
-# bounded to one eval cadence.  All slots are small integers or f32
-# gaps — exact in float32, exact in the checkpoint meta JSON round trip.
-#
-# Measured-out alternatives on the rcv1-synth λ=1e-4 config: per-round
-# growing-β Nesterov momentum on w
-# DIVERGES (54 restarts, never certifies — one CoCoA+ round is a large
-# contraction step, and 25 unmonitored β→1 extrapolations overshoot the
-# dual box); eval-windowed fixed β down to 0.05 still diverges; damped
-# (negative-β) extrapolation cannot stabilize σ′ < K/2; Polyak–Ruppert
-# window averaging never beats the raw iterate; raising H near the
-# target buys only ~1.1×.  The tail has a MIXED spectrum — measured
-# ρ_α ≈ +0.73 drift with oscillatory modes on top — which is exactly
-# the regime the signed secant coefficient adapts to: measured 1.76×
-# fewer rounds to the 1e-4 certificate on full rcv1-synth at the safe
-# σ′ = K·γ (1100 → 625), 1.38× at σ′ = K/2 — the ratio grows with the
-# control's round count.
+# The jump itself is solvers/cocoa.secant_jump, at a chunk's head; a gap
+# RISE at an eval boundary discards the bank (restart), so the damage of a
+# bad jump is bounded to one eval cadence.  All slots are small integers or
+# f32 gaps — exact in float32, exact in the checkpoint meta JSON round
+# trip.  Why a signed secant coefficient and not momentum, and what was
+# measured out: docs/DESIGN.md §11.
 ACCEL_LEN = 8
 A_HIST = SCHED_LEN
 A_JUMP = SCHED_LEN + 1
@@ -225,110 +201,11 @@ def theta_ladder(h: int, adaptive: bool) -> tuple:
     return tuple(out)
 
 
-class AccelConfig:
-    """Static accelerated-loop configuration threaded through the drive*
-    ladder: the Θ ladder (per-stage inner-iteration counts) and the gap
-    target the near-target jump keys on.  Hashable (rides cache keys)."""
-
-    def __init__(self, theta_hs: tuple, gap_target=None):
-        self.theta_hs = tuple(int(v) for v in theta_hs)
-        self.n_theta = len(self.theta_hs)
-        self.gap_target = gap_target
-
-    def token(self):
-        return ("accel", self.theta_hs)
-
-
-def accel_host_step(sched, gap, n_theta: int, gap_target,
-                    seam: bool = False):
-    """Host twin of the device loop's per-eval accel update (same float32
-    arithmetic, so host-stepped and device drivers make identical
-    restart/arm/Θ decisions — the σ′ ``sched_host_step`` pattern).
-    ``seam`` marks a σ′ anneal backoff committed at this same eval
-    boundary — a round-map seam exactly like a Θ stage advance, with the
-    same bank treatment (see below).
-    Returns (new sched ndarray, restarted, theta_staged).
-
-    Window bookkeeping only — the secant jump ACTION runs at the head of
-    the next chunk dispatch (solvers/cocoa.py accel_kernel consumes the
-    armed ``A_JUMP`` flag, where the shard data the correspondence update
-    needs is in scope).  Three mutually exclusive outcomes per eval:
-
-    - gap ROSE: restart — the snapshot bank is discarded and restarts
-      from this eval's α (the caller banks it, see :func:`_accel_replace`);
-    - two windows banked and the gap still improving: ARM the jump — the
-      bank is frozen for the kernel head to consume, nothing is pushed;
-    - otherwise: bank this eval's α as the newest window snapshot."""
-    s = np.asarray(sched, dtype=np.float32).copy()
-    gv = (np.float32(np.inf) if gap is None or np.isnan(gap)
-          else np.float32(gap))
-    restarted = bool(gv > s[A_LASTGAP])
-    if restarted:
-        s[A_RESTARTS] += 1.0
-        s[A_HIST] = 1.0
-    elif s[A_HIST] >= 2.0:
-        s[A_JUMP] = 1.0
-        s[A_HIST] = 0.0
-    else:
-        s[A_HIST] = min(s[A_HIST] + 1.0, 2.0)
-    s[A_LASTGAP] = gv
-    staged = False
-    if n_theta > 1:
-        s[A_TH_BEST], s[A_TH_BPREV], s[A_TH_STALL] = _watch_update(
-            np, gv, s[A_TH_BEST], s[A_TH_BPREV], s[A_TH_STALL],
-            np.float32(THETA_REL))
-        tgt32 = (np.float32(-np.inf) if gap_target is None
-                 else np.float32(gap_target))
-        near = bool(gv <= np.float32(THETA_NEAR) * tgt32)
-        fire = bool(s[A_TH_STALL] >= np.float32(THETA_EVALS))
-        if s[A_TH_STAGE] < n_theta - 1 and (near or fire):
-            s[A_TH_STAGE] = (np.float32(n_theta - 1) if near
-                             else s[A_TH_STAGE] + 1)
-            s[A_TH_STALL] = 0.0
-            s[A_TH_BEST] = np.float32(np.inf)
-            s[A_TH_BPREV] = np.float32(np.inf)
-            # windows banked BEFORE the seam measured the old stage's
-            # round map — a secant ρ mixing maps extrapolates the wrong
-            # tail, so the bank drops to (at most) the α just banked,
-            # which is a valid anchor for the new map's first window.
-            # An already-armed jump stays armed: all three of its points
-            # predate the seam, so its extrapolation is consistent.
-            s[A_HIST] = min(s[A_HIST], 1.0)
-            staged = True
-    if seam:
-        # a σ′ backoff changed the round map at this boundary: cap the
-        # bank the same way a Θ stage advance does (armed jump stays
-        # armed — all its points predate the seam)
-        s[A_HIST] = min(s[A_HIST], np.float32(1.0))
-    return s, restarted, staged
-
-
-def _accel_replace(state, sched_np):
-    """Commit a host accel step back into the (w, alpha, hist, sched)
-    state: the sched leaf via :func:`_sched_replace`, plus — unless this
-    eval ARMED a jump (the bank is then frozen for the kernel head to
-    consume) — banking the current α as the newest window snapshot,
-    hist ← [hist[1], α].  ``jnp.stack`` materializes a fresh buffer, so
-    the hist leaf never aliases the separately-donated α arg."""
-    import jax
-    import jax.numpy as jnp
-
-    armed = float(sched_np[A_JUMP]) > 0.0
-    state = _sched_replace(state, sched_np)
-    if not armed:
-        hist = jnp.stack([state[2][1], state[1]])
-        sharding = getattr(state[2], "sharding", None)
-        if sharding is not None:
-            hist = jax.device_put(hist, sharding)
-        state = (*state[:2], hist, *state[3:])
-    return state
-
-
 def _emit_accel_events(name, t, restarted, restarts_total, staged, stage,
-                       accel: "AccelConfig", quiet):
+                       theta_hs: tuple, quiet):
     """The typed momentum_restart / theta_stage events for one eval
     boundary (emitted regardless of ``quiet`` — same policy as
-    :func:`_emit_backoff`)."""
+    the σ′ back-off's)."""
     from cocoa_tpu.telemetry import events as _tele
 
     bus = _tele.get_bus()
@@ -340,10 +217,10 @@ def _emit_accel_events(name, t, restarted, restarts_total, staged, stage,
                   f"secant window bank discarded)")
     if staged:
         bus.emit("theta_stage", algorithm=name, t=int(t), stage=int(stage),
-                 h=int(accel.theta_hs[int(stage)]))
+                 h=int(theta_hs[int(stage)]))
         if not quiet:
             print(f"{name}: Θ schedule — local accuracy raised to "
-                  f"H={accel.theta_hs[int(stage)]} at round {t}")
+                  f"H={theta_hs[int(stage)]} at round {t}")
 
 
 def anneal_levels(start: float, safe: float, factor: float = 2.0,
@@ -391,21 +268,13 @@ def sched_init_values(start_round: int, sched_init=None,
     return np.concatenate([head, tail]) if accel else head
 
 
-def sched_init_array(start_round: int, sched_init=None, accel: bool = False):
-    """:func:`sched_init_values` as a device array."""
-    import jax.numpy as jnp
-
-    return jnp.asarray(sched_init_values(start_round, sched_init, accel))
-
-
 def _watch_update(xp, gv, best, best_prev, stall, rel):
     """ONE windowed no-improvement step — the single arithmetic behind
-    every in-loop stall watch (the legacy device twin, the anneal device
-    branch, and :func:`sched_host_step`; ``xp`` is jnp when traced, np on
-    the host).  Callers pass ``rel`` at the dtype the comparison must run
-    in (float32 for the anneal twins — host and device must make
-    IDENTICAL backoff decisions for bit-identical resume).  Returns
-    (best, best_prev, stall)."""
+    every stall watch (the device loops' carry watch, the host's
+    :class:`_GapWatch`, and :func:`eval_boundary_update`'s σ′ and Θ
+    watches; ``xp`` is jnp when traced, np on the host).  Callers pass
+    ``rel`` at the dtype the comparison must run in (float32 for the
+    schedule leaf's).  Returns (best, best_prev, stall)."""
     best = xp.minimum(best, gv)
     improved = best <= rel * best_prev
     stall = xp.where(improved, xp.zeros_like(stall), stall + 1)
@@ -413,57 +282,179 @@ def _watch_update(xp, gv, best, best_prev, stall, rel):
     return best, best_prev, stall
 
 
-def _sched_replace(state, sched_np):
-    """Swap the host-updated sched vector back into the state tuple (the
-    sched leaf is by convention the LAST leaf of a scheduled state, and
-    the only 3rd leaf any driver state carries — the checkpoint savers
-    below rely on the same invariant).  The replacement keeps the old
-    leaf's placement: under an explicit mesh the initialization committed
-    sched with a replicated NamedSharding, and a bare jnp.asarray would
-    re-enter the donating jitted step with mismatched sharding typing."""
-    import jax
+def _write_back(state, sched_np, push: bool):
+    """Write one eval boundary's update back into the state tuple: the
+    sched vector (by convention the LAST leaf of a scheduled state — the
+    checkpoint savers rely on the same invariant) and, where the update
+    says ``push`` (a bank, no jump armed: an armed bank is frozen for the
+    kernel head to consume), the current α banked as the newest window
+    snapshot, hist ← [hist[1], α].  A replacement keeps the old leaf's
+    placement: under an explicit mesh the initialization committed the
+    leaves with a NamedSharding, and a bare array would re-enter the
+    donating jitted step with mismatched sharding typing.  ``jnp.stack``
+    materializes a fresh buffer, so the hist leaf never aliases the
+    separately-donated α arg."""
     import jax.numpy as jnp
 
-    arr = jnp.asarray(sched_np)
-    sharding = getattr(state[-1], "sharding", None)
-    if sharding is not None:
-        arr = jax.device_put(arr, sharding)
-    return (*state[:-1], arr)
+    def placed(new, old):
+        sharding = getattr(old, "sharding", None)
+        return new if sharding is None else jax.device_put(new, sharding)
+
+    state = (*state[:-1], placed(jnp.asarray(sched_np), state[-1]))
+    if push:
+        hist = placed(jnp.stack([state[2][1], state[1]]), state[2])
+        state = (*state[:2], hist, *state[3:])
+    return state
 
 
-def sched_host_step(sched, gap, stall_evals: int, n_stages: int):
-    """Host twin of the device-side schedule/watch update (same float32
-    arithmetic via :func:`_watch_update`, so the host-stepped drivers and
-    the device loop make identical backoff decisions).  Returns
-    (new sched ndarray, backed_off)."""
-    s = np.asarray(sched, dtype=np.float32).copy()
-    gv = (np.float32(np.inf) if gap is None or np.isnan(gap)
-          else np.float32(gap))
-    s[2], s[3], s[1] = _watch_update(np, gv, s[2], s[3], s[1],
-                                     np.float32(STALL_REL))
-    backed = bool(s[1] >= np.float32(stall_evals) and s[0] < n_stages - 1)
-    if backed:
-        # fresh watch at the new stage; the iterate (w, α) carries over
-        s[0] += 1.0
-        s[1] = 0.0
-        s[2] = np.float32(np.inf)
-        s[3] = np.float32(np.inf)
-    return s, backed
+class BoundaryUpdate(NamedTuple):
+    """What :func:`eval_boundary_update` hands back.  ``head`` / ``tail``:
+    the schedule leaf's new σ′ head (``SCHED_LEN`` fields on the last axis)
+    and bank / Θ tail (``ACCEL_LEN``), None for the half the job does not
+    run.  ``push``: bank the current α (no jump armed, no target hit).
+    ``backed`` / ``restarted`` / ``staged``: this eval backed σ′ off,
+    restarted the bank, advanced Θ.  ``cols``: the trajectory row's
+    (σ′ stage, stall, Θ stage, restarts) after the update."""
+    head: object
+    tail: object
+    push: object
+    backed: object
+    restarted: object
+    staged: object
+    cols: tuple
 
 
-def _emit_backoff(name, t, sigma_levels, stage, quiet, message=None):
-    """One σ′-anneal backoff: the typed ``sigma_backoff`` event (emitted
-    regardless of ``quiet`` — the machine-readable trace survives a
-    silenced console) plus the optional console line.  The host schedule
-    step bumps exactly one rung, so ``from_sigma`` is stage-1."""
+def eval_boundary_update(xp, sched, gv, done_tgt, *, stall_evals: int,
+                         n_stages: int, n_theta: int, tgt) -> BoundaryUpdate:
+    """THE eval-boundary update of the schedule leaf, for every driver:
+    traced in the device loop (``xp`` = jnp, scalar fields) and in the
+    fleet loop (a leading tenant axis), NumPy in the host-stepped drivers
+    — the same float32 ``where`` arithmetic, so all of them make the same
+    decisions and a resume is bit-identical.  ``sched``: the leaf, fields
+    on its last axis (layout notes at :data:`SCHED_LEN` and
+    :data:`ACCEL_LEN`); ``gv``: the eval's gap as float32, +inf for none;
+    ``done_tgt``: the eval hit its target — the watches still count, and
+    every ACTION (back-off, restart, arm, bank, Θ advance) is suppressed,
+    so the run ends on the state the target was certified at.
+
+    ``n_stages`` > 1 runs the σ′ stall watch: a window of ``stall_evals``
+    evals without improvement at a non-final stage bumps the stage (the
+    next chunk's kernel reads it) and starts a fresh watch; the final,
+    safe stage is inert.  ``n_theta`` >= 1 (the state carries ``hist``)
+    runs the secant bank, one of three outcomes an eval: the gap ROSE —
+    restart, the bank is discarded and begins again from this eval's α;
+    two windows banked and the gap still improving — ARM the jump for the
+    next chunk head and freeze the bank; otherwise bank this eval's α.
+    ``n_theta`` > 1 also runs the Θ ladder (near ``tgt``: straight to the
+    full H; a missed halving: one rung up).  A σ′ back-off or a Θ advance
+    is a seam in the round map: windows banked before it measured another
+    map, so the bank is capped at the α just banked (an armed jump stays
+    armed — all its points predate the seam)."""
+    f32 = xp.float32
+    head = tail = push = stg = stl = thst = rst = None
+    bo = restart = step = False
+    if n_stages > 1:
+        stg, stl, bst, bpv = (sched[..., 0], sched[..., 1], sched[..., 2],
+                              sched[..., 3])
+        bst, bpv, stl = _watch_update(xp, gv, bst, bpv, stl, f32(STALL_REL))
+        fired = stl >= f32(stall_evals)
+        bo = (fired & (stg < f32(n_stages - 1)) & xp.logical_not(done_tgt))
+        inf32 = f32(xp.inf)
+        stg = xp.where(bo, stg + 1, stg)
+        stl = xp.where(bo, f32(0), stl)
+        bst = xp.where(bo, inf32, bst)
+        bpv = xp.where(bo, inf32, bpv)
+        head = xp.stack([stg, stl, bst, bpv, sched[..., 4]], axis=-1)
+    if n_theta:
+        hl, rst, lg = (sched[..., A_HIST], sched[..., A_RESTARTS],
+                       sched[..., A_LASTGAP])
+        restart = (gv > lg) & xp.logical_not(done_tgt)
+        arm = ((hl >= f32(2)) & xp.logical_not(restart)
+               & xp.logical_not(done_tgt))
+        rst = xp.where(restart, rst + 1, rst)
+        hl = xp.where(
+            done_tgt, hl,
+            xp.where(arm, f32(0),
+                     xp.where(restart, f32(1), xp.minimum(hl + 1, f32(2)))))
+        jmp = xp.where(arm, f32(1), f32(0))
+        lg = xp.where(done_tgt, lg, gv)
+        push = xp.logical_not(arm) & xp.logical_not(done_tgt)
+        thst = sched[..., A_TH_STAGE]
+        thstl, thb, thbp = (sched[..., A_TH_STALL], sched[..., A_TH_BEST],
+                            sched[..., A_TH_BPREV])
+        if n_theta > 1:
+            thb, thbp, thstl = _watch_update(xp, gv, thb, thbp, thstl,
+                                             f32(THETA_REL))
+            tgt32 = f32(-xp.inf if tgt is None else tgt)
+            near = gv <= f32(THETA_NEAR) * tgt32
+            fire = thstl >= f32(THETA_EVALS)
+            can = thst < f32(n_theta - 1)
+            step = (near | fire) & can & xp.logical_not(done_tgt)
+            thst = xp.where(step,
+                            xp.where(near, f32(n_theta - 1), thst + 1), thst)
+            inf32 = f32(xp.inf)
+            thstl = xp.where(step, f32(0), thstl)
+            thb = xp.where(step, inf32, thb)
+            thbp = xp.where(step, inf32, thbp)
+            hl = xp.where(step, xp.minimum(hl, f32(1)), hl)
+        if n_stages > 1:
+            hl = xp.where(bo, xp.minimum(hl, f32(1)), hl)
+        tail = xp.stack([hl, jmp, rst, lg, thst, thstl, thb, thbp], axis=-1)
+    return BoundaryUpdate(head, tail, push, bo, restart, step,
+                          (stg, stl, thst, rst))
+
+
+def _host_eval(traj, name, t, state, eval_fn, gap_target, stall_evals,
+               sigma_levels, accel, quiet):
+    """One eval boundary of a host-stepped driver: evaluate, run
+    :func:`eval_boundary_update` in NumPy on ``state[-1]`` (where the job
+    carries a schedule: ``sigma_levels`` the σ′ ladder where the anneal is
+    on, ``accel`` the bank's Θ ladder), write the leaves back, log the
+    round and emit the events (typed events regardless of ``quiet``: the
+    machine-readable trace survives a silenced console).  Returns
+    ``(state, hit)``."""
     from cocoa_tpu.telemetry import events as _tele
 
-    _tele.get_bus().emit(
-        "sigma_backoff", algorithm=name, t=int(t),
-        sigma=sigma_levels[stage], from_sigma=sigma_levels[stage - 1],
-        stage=int(stage))
-    if message and not quiet:
-        print(message)
+    with _tracing.span("eval", algorithm=name, round=t):
+        primal, gap, test_err, *per_class = eval_fn(state)
+        _sanitize.count_launch()
+    hit = gap_target is not None and gap is not None and gap <= gap_target
+    fields, backed = {}, False
+    if sigma_levels is not None or accel is not None:
+        sched = np.asarray(state[-1], dtype=np.float32)
+        gv = (np.float32(np.inf) if gap is None or np.isnan(gap)
+              else np.float32(gap))
+        upd = eval_boundary_update(
+            np, sched, gv, hit, stall_evals=stall_evals,
+            n_stages=0 if sigma_levels is None else len(sigma_levels),
+            n_theta=0 if accel is None else len(accel), tgt=gap_target)
+        backed = bool(upd.backed)
+        if upd.head is not None:
+            stage = int(upd.cols[0])
+            fields = dict(sigma=sigma_levels[stage], sigma_stage=stage,
+                          stall=int(upd.cols[1]))
+        state = _write_back(state, np.concatenate(
+            [sched[:SCHED_LEN] if upd.head is None else upd.head,
+             *(() if upd.tail is None else (upd.tail,))]), bool(upd.push))
+        if accel is not None:
+            _emit_accel_events(name, t, bool(upd.restarted),
+                               int(upd.cols[3]), bool(upd.staged),
+                               int(upd.cols[2]), accel, quiet)
+    # (a one-vs-rest eval adds every class's gap past the three, which are
+    # then the worst class's: stop rule, budget and watch read the worst)
+    traj.log_round(t, primal=primal, gap=gap, test_error=test_err, **fields,
+                   **_tele.per_class_fields(
+                       per_class[0] if per_class else (), gap_target))
+    if backed:      # (one rung an eval: the σ′ left is the stage below)
+        _tele.get_bus().emit(
+            "sigma_backoff", algorithm=name, t=int(t),
+            sigma=sigma_levels[stage], from_sigma=sigma_levels[stage - 1],
+            stage=stage)
+        if not quiet:
+            print(f"{name}: σ′ anneal — gap stalled for {stall_evals} "
+                  f"evals; backing off to σ′={sigma_levels[stage]:g} at "
+                  f"round {t} (iterate kept, certificate exact)")
+    return state, hit
 
 
 def resolve_divergence_guard(flag: str, mode: str, sigma: float, k: int,
@@ -518,16 +509,6 @@ def checkpoint_arguments(debug: DebugParams, name: str, round_t: int,
                  hist=hist, gap=_last_gap(traj)))
 
 
-def _per_class(per_class: list, gap_target) -> dict:
-    """What ``eval_fn``'s elements past (primal, gap, test error) add to
-    the record: a one-vs-rest job's list of per-class gaps (the three are
-    then the worst class's, so the stop rule, the budget and the watch read
-    the worst); nothing for any other job."""
-    from cocoa_tpu.telemetry.events import per_class_fields
-
-    return per_class_fields(per_class[0] if per_class else (), gap_target)
-
-
 class _GapWatch:
     """Windowed no-improvement watch over eval-cadence gap values;
     ``update(gap)`` returns True when the run should bail out (diverged or
@@ -543,64 +524,9 @@ class _GapWatch:
     def update(self, gap) -> bool:
         if gap is None:
             return False
-        self.best = min(self.best, float(gap))
-        if self.best <= self.rel * self.best_prev:
-            self.stall = 0
-            self.best_prev = self.best
-        else:
-            self.stall += 1
-        return self.stall >= self.n
-
-
-def drive(
-    name: str,
-    params: Params,
-    debug: DebugParams,
-    state: tuple,
-    round_fn: Callable[[int, tuple], tuple],
-    eval_fn: Callable[[tuple], tuple],
-    quiet: bool = False,
-    gap_target: Optional[float] = None,
-    start_round: int = 1,
-    divergence_guard: bool = True,
-    ckpt_rows=None,
-):
-    """The outer driver loop shared by every solver (CoCoA.scala:39-63
-    skeleton): run rounds, gate evaluation to every ``debugIter`` rounds,
-    checkpoint every ``chkptIter`` rounds, optionally stop early on a
-    duality-gap target (or on measured divergence — see STALL_EVALS;
-    ``divergence_guard=False`` disarms the stall watch, see
-    :func:`resolve_divergence_guard`).
-
-    ``state`` is ``(w,)`` or ``(w, alpha)``; ``round_fn(t, state) -> state``;
-    ``eval_fn(state) -> (primal, gap_or_None, test_error_or_None)``.
-    Returns (state, Trajectory).
-    """
-    traj = Trajectory(name, quiet=quiet)
-    watch = _GapWatch(n_evals=stall_window(debug.debug_iter))
-    for t in range(start_round, params.num_rounds + 1):
-        state = round_fn(t, state)
-        _sanitize.count_launch()
-
-        if debug.debug_iter > 0 and t % debug.debug_iter == 0:
-            with _tracing.span("eval", algorithm=name, round=t):
-                primal, gap, test_err, *per_class = eval_fn(state)
-                _sanitize.count_launch()
-            traj.log_round(t, primal=primal, gap=gap, test_error=test_err,
-                           **_per_class(per_class, gap_target))
-            if gap_target is not None and gap is not None and gap <= gap_target:
-                traj.stopped = "target"
-                break
-            if (gap_target is not None and divergence_guard
-                    and watch.update(gap)):
-                traj.mark_diverged(t, watch.n)
-                break
-
-        if debug.chkpt_dir and debug.chkpt_iter > 0 and t % debug.chkpt_iter == 0:
-            args, kwargs = checkpoint_arguments(debug, name, t, state, traj,
-                                                ckpt_rows)
-            ckpt_lib.save(*args, **kwargs)
-    return state, traj
+        self.best, self.best_prev, self.stall = _watch_update(
+            np, float(gap), self.best, self.best_prev, self.stall, self.rel)
+        return bool(self.stall >= self.n)
 
 
 def drive_chunked(
@@ -616,36 +542,47 @@ def drive_chunked(
     chunk: int = 50,
     divergence_guard: bool = True,
     sigma_levels: Optional[tuple] = None,
-    accel: Optional["AccelConfig"] = None,
+    accel: Optional[tuple] = None,
     ckpt_rows=None,
 ):
-    """Chunked variant of :func:`drive`: rounds run device-side in blocks of
-    up to ``chunk`` via ``lax.scan`` (one dispatch per block instead of one
-    per round), with blocks aligned to the ``debugIter`` evaluation cadence
-    so the observable trajectory is identical to the per-round driver.
+    """The host-stepped outer driver shared by every solver
+    (CoCoA.scala:39-63 skeleton): run rounds, gate evaluation to every
+    ``debugIter`` rounds, checkpoint every ``chkptIter`` rounds, optionally
+    stop early on a duality-gap target (or on measured divergence — see
+    STALL_EVALS; ``divergence_guard=False`` disarms the stall watch, see
+    :func:`resolve_divergence_guard`).  Rounds run in blocks of up to
+    ``chunk`` (one dispatch a block; ``chunk=1`` is the per-round driver),
+    cut at the ``debugIter`` and ``chkptIter`` boundaries, so the
+    observable trajectory does not depend on ``chunk`` and same-size
+    blocks share one compiled executable.
 
-    ``chunk_fn(t0, c, state) -> state`` advances rounds t0..t0+c-1.
+    ``state`` is ``(w,)``, ``(w, alpha)`` or either with the leaves of a
+    schedule; ``chunk_fn(t0, c, state) -> state`` advances rounds
+    t0..t0+c-1; ``eval_fn(state) -> (primal, gap_or_None,
+    test_error_or_None)``.  Returns (state, Trajectory).
 
     ``sigma_levels`` (more than one): the run carries the σ′-anneal
     schedule in ``state[-1]`` (layout note at :data:`SCHED_LEN`); the
-    stall watch then BACKS OFF σ′ in place — :func:`sched_host_step`, the
-    host twin of the device loop's in-state update — instead of bailing
-    out, and the final (safe K·γ) stage simply runs to its round budget:
-    a scheduled run never reports DIVERGED, because its last rung is the
-    paper-safe bound.
+    stall watch then BACKS OFF σ′ in place (:func:`eval_boundary_update`,
+    what the device loop runs in its body) instead of bailing out, and the
+    final (safe K·γ) stage simply runs to its round budget: a scheduled
+    run never reports DIVERGED, because its last rung is the paper-safe
+    bound.  ``accel`` (the Θ ladder of a job whose state carries the
+    ``--accel`` bank, :func:`theta_ladder`): the restart / arm / bank step
+    and the Θ step ride the same update; an armed jump executes at the
+    head of the NEXT chunk dispatch — the kernel has the shard data in
+    scope.
     """
     if chunk <= 0:
         raise ValueError(f"chunk must be positive, got {chunk}")
-    anneal = sigma_levels is not None and len(sigma_levels) > 1
+    anneal_on = (sigma_levels is not None and len(sigma_levels) > 1
+                 and gap_target is not None and divergence_guard)
     traj = Trajectory(name, quiet=quiet)
     watch = _GapWatch(n_evals=stall_window(debug.debug_iter))
     t = start_round
     total = params.num_rounds
     ckpt_on = bool(debug.chkpt_dir) and debug.chkpt_iter > 0
     while t <= total:
-        # advance to the next eval/checkpoint boundary (or ``chunk`` rounds,
-        # whichever is nearest) so observable behavior matches the per-round
-        # driver and same-size blocks share one compiled executable
         end = min(total, t + chunk - 1)
         if debug.debug_iter > 0:
             end = min(end, ((t - 1) // debug.debug_iter + 1) * debug.debug_iter)
@@ -659,65 +596,14 @@ def drive_chunked(
         t = end + 1
 
         if debug.debug_iter > 0 and end % debug.debug_iter == 0:
-            with _tracing.span("eval", algorithm=name, round=end):
-                primal, gap, test_err, *per_class = eval_fn(state)
-                _sanitize.count_launch()
-            anneal_on = (gap_target is not None and divergence_guard
-                         and anneal)
-            hit = (gap_target is not None and gap is not None
-                   and gap <= gap_target)
-            sigma_val = stage = stall_v = None
-            backed = False
-            if anneal_on:
-                if hit:
-                    # the σ′ this eval ran under: on a target hit the
-                    # schedule update is moot — the run ends and the state
-                    # is NOT advanced — but the emitted stall counter must
-                    # still be the device twin's (the device loop runs the
-                    # watch arithmetic before it notices done_tgt, with
-                    # the backoff suppressed), so preview it un-committed
-                    s = np.asarray(state[-1], dtype=np.float32)
-                    gv = (np.float32(np.inf)
-                          if gap is None or np.isnan(gap)
-                          else np.float32(gap))
-                    _, _, stl = _watch_update(np, gv, s[2], s[3], s[1],
-                                              np.float32(STALL_REL))
-                    stage = int(s[0])
-                    stall_v = int(stl)
-                else:
-                    sched, backed = sched_host_step(
-                        state[-1], gap, watch.n, len(sigma_levels))
-                    state = _sched_replace(state, sched)
-                    stage = int(sched[0])
-                    stall_v = int(sched[1])
-                sigma_val = sigma_levels[stage]
-            if accel is not None and not hit:
-                # accelerated outer loop: the restart/arm/bank step + Θ
-                # step at the same eval boundary (accel_host_step is the
-                # device loop's bit-twin; the σ′ update above already
-                # committed, so state[-1] carries its fresh head).  An
-                # armed jump executes at the head of the NEXT chunk
-                # dispatch — the kernel has the shard data in scope.
-                sched_a, restarted, staged = accel_host_step(
-                    state[-1], gap, accel.n_theta, gap_target, seam=backed)
-                state = _accel_replace(state, sched_a)
-                _emit_accel_events(name, end, restarted,
-                                   int(sched_a[A_RESTARTS]), staged,
-                                   int(sched_a[A_TH_STAGE]), accel, quiet)
-            traj.log_round(end, primal=primal, gap=gap, test_error=test_err,
-                           sigma=sigma_val, sigma_stage=stage, stall=stall_v,
-                           **_per_class(per_class, gap_target))
-            if backed:
-                _emit_backoff(name, end, sigma_levels, stage, quiet,
-                              f"{name}: σ′ anneal — gap stalled for "
-                              f"{watch.n} evals; backing off to "
-                              f"σ′={sigma_levels[stage]:g} at round "
-                              f"{end} (iterate kept, certificate exact)")
+            state, hit = _host_eval(
+                traj, name, end, state, eval_fn, gap_target, watch.n,
+                sigma_levels if anneal_on else None, accel, quiet)
             if hit:
                 traj.stopped = "target"
                 break
             if (not anneal_on and gap_target is not None and divergence_guard
-                    and watch.update(gap)):
+                    and watch.update(traj.records[-1].gap)):
                 traj.mark_diverged(end, watch.n)
                 break
 
@@ -810,8 +696,7 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
     # tuple's sched leaf (persisting across super-block dispatches and
     # into checkpoints), and firing BACKS OFF the schedule stage in place
     # instead of stopping the loop; the final stage is the safe K·γ bound,
-    # so a scheduled run never stops "diverged" (see sched_host_step, the
-    # host twin).
+    # so a scheduled run never stops "diverged" (eval_boundary_update).
     anneal = check_div and n_stages > 1
     # every eval writes one [primal, gap, test_err, sigma_stage, stall,
     # theta_stage, restarts] row: cols 0-2 are the eval metrics, col 3 the
@@ -858,35 +743,8 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
             metrics = eval_kernel(state, shard_arrays, test_arrays)
             done_tgt = metrics[1] <= tgt
             nanv = jnp.asarray(jnp.nan, metrics.dtype)
-            if anneal:
-                # in-state schedule/watch update (float32, exactly the
-                # sched_host_step arithmetic): a fired window at a
-                # non-final stage bumps the stage — the NEXT chunk's
-                # kernel reads it and runs the backed-off σ′ — and
-                # resets the watch; at the final (safe) stage the watch
-                # is inert and the run continues to target or budget
-                sched = state[-1]
-                gv = jnp.where(jnp.isnan(metrics[1]), jnp.inf,
-                               metrics[1]).astype(jnp.float32)
-                stg, stl, bst, bpv = sched[0], sched[1], sched[2], sched[3]
-                bst, bpv, stl = _watch_update(jnp, gv, bst, bpv, stl,
-                                              jnp.float32(STALL_REL))
-                fired = stl >= jnp.float32(stall_evals)
-                bo = (fired & (stg < jnp.float32(n_stages - 1))
-                      & jnp.logical_not(done_tgt))
-                inf32 = jnp.float32(jnp.inf)
-                stg = jnp.where(bo, stg + 1, stg)
-                stl = jnp.where(bo, jnp.float32(0), stl)
-                bst = jnp.where(bo, inf32, bst)
-                bpv = jnp.where(bo, inf32, bpv)
-                head = jnp.stack([stg, stl, bst, bpv, sched[4]])
-                state = (*state[:-1],
-                         jnp.concatenate([head, sched[SCHED_LEN:]])
-                         if accel is not None else head)
-                extra = jnp.stack([stg.astype(metrics.dtype),
-                                   stl.astype(metrics.dtype)])
-            elif check_div:
-                # windowed no-improvement watch (the _GapWatch twin): NaN
+            if check_div and not anneal:
+                # windowed no-improvement watch (_GapWatch's, traced): NaN
                 # gaps (primal-only eval) map to +inf, leaving best — and
                 # the always-true inf <= rel·inf reset — untouched
                 gv = jnp.where(jnp.isnan(metrics[1]),
@@ -896,81 +754,42 @@ def _build_device_run(chunk_kernel, eval_kernel, gap_target, n_state,
                 # the target wins a tie (the host drivers check that order)
                 done_stall = (stall >= stall_evals) & jnp.logical_not(done_tgt)
                 extra = jnp.stack([nanv, stall.astype(metrics.dtype)])
-            else:
+            elif not anneal:
                 extra = jnp.stack([nanv, jnp.zeros((), metrics.dtype)])
-            if accel is not None:
-                # accelerated outer loop: the per-eval restart/arm/bank +
-                # Θ-schedule update, in-state (the accel_host_step twin —
-                # identical f32 arithmetic).  State-changing ACTIONS are
-                # suppressed on a target hit (the host drivers stop
-                # without committing), matching the σ′ backoff policy;
-                # the watch arithmetic itself commits either way.  An
-                # armed jump executes at the head of the next chunk
-                # (solvers/cocoa.py accel_kernel — the shard data the
-                # correspondence update needs is in scope there).
+            if anneal or accel is not None:
+                # the schedule leaf's update, in-state: the stage a fired
+                # watch bumps is read by the NEXT chunk's kernel, an armed
+                # jump executes at its head (solvers/cocoa.py: the shard
+                # data the correspondence update needs is in scope there)
                 sched = state[-1]
                 gv = jnp.where(jnp.isnan(metrics[1]), jnp.inf,
                                metrics[1]).astype(jnp.float32)
-                hl, rst, lg = (sched[A_HIST], sched[A_RESTARTS],
-                               sched[A_LASTGAP])
-                restart = (gv > lg) & jnp.logical_not(done_tgt)
-                arm = ((hl >= jnp.float32(2)) & jnp.logical_not(restart)
-                       & jnp.logical_not(done_tgt))
-                rst = jnp.where(restart, rst + 1, rst)
-                hl = jnp.where(
-                    done_tgt, hl,
-                    jnp.where(arm, jnp.float32(0),
-                              jnp.where(restart, jnp.float32(1),
-                                        jnp.minimum(hl + 1,
-                                                    jnp.float32(2)))))
-                jmp = jnp.where(arm, jnp.float32(1), jnp.float32(0))
-                lg = jnp.where(done_tgt, lg, gv)
-                push = jnp.logical_not(arm) & jnp.logical_not(done_tgt)
-                thst = sched[A_TH_STAGE]
-                thstl, thb, thbp = (sched[A_TH_STALL], sched[A_TH_BEST],
-                                    sched[A_TH_BPREV])
-                if accel.n_theta > 1:
-                    thb, thbp, thstl = _watch_update(
-                        jnp, gv, thb, thbp, thstl, jnp.float32(THETA_REL))
-                    tgt32 = jnp.float32(tgt)
-                    near = gv <= jnp.float32(THETA_NEAR) * tgt32
-                    fire = thstl >= jnp.float32(THETA_EVALS)
-                    can = thst < jnp.float32(accel.n_theta - 1)
-                    step = (near | fire) & can & jnp.logical_not(done_tgt)
-                    thst = jnp.where(
-                        step,
-                        jnp.where(near, jnp.float32(accel.n_theta - 1),
-                                  thst + 1),
-                        thst)
-                    inf32 = jnp.float32(jnp.inf)
-                    thstl = jnp.where(step, jnp.float32(0), thstl)
-                    thb = jnp.where(step, inf32, thb)
-                    thbp = jnp.where(step, inf32, thbp)
-                    # a stage advance caps the secant bank at the α just
-                    # banked: pre-seam window displacements measured the
-                    # old stage's round map (an armed jump stays armed —
-                    # all its points predate the seam; base layout note)
-                    hl = jnp.where(step, jnp.minimum(hl, jnp.float32(1)),
-                                   hl)
+                upd = eval_boundary_update(
+                    jnp, sched, gv, done_tgt, stall_evals=stall_evals,
+                    n_stages=n_stages if anneal else 0,
+                    n_theta=0 if accel is None else len(accel),
+                    tgt=gap_target)
+                stg, stl, thst, rst = upd.cols
+                if accel is not None:
+                    # the bank action: unless this eval armed a jump (the
+                    # bank is then frozen for the kernel head to consume),
+                    # the current α joins as the newest window snapshot;
+                    # state is (w, alpha, hist, sched)
+                    hist_leaf = jnp.where(
+                        upd.push, jnp.stack([state[2][1], state[1]]),
+                        state[2])
+                    state = (state[0], state[1], hist_leaf,
+                             jnp.concatenate(
+                                 [sched[:SCHED_LEN] if upd.head is None
+                                  else upd.head, upd.tail]))
+                    extra2 = jnp.stack([thst.astype(metrics.dtype),
+                                        rst.astype(metrics.dtype)])
+                else:
+                    state = (*state[:-1], upd.head)
                 if anneal:
-                    # a σ′ backoff committed above is a round-map seam
-                    # exactly like a Θ stage advance: same bank cap
-                    # (accel_host_step's ``seam`` is the host twin)
-                    hl = jnp.where(bo, jnp.minimum(hl, jnp.float32(1)),
-                                   hl)
-                tail = jnp.stack([hl, jmp, rst, lg, thst, thstl, thb,
-                                  thbp])
-                # the bank action: unless this eval armed a jump (the
-                # bank is then frozen for the kernel head to consume),
-                # the current α joins as the newest window snapshot;
-                # state is (w, alpha, hist, sched)
-                hist_leaf = jnp.where(
-                    push, jnp.stack([state[2][1], state[1]]), state[2])
-                state = (state[0], state[1], hist_leaf,
-                         jnp.concatenate([state[-1][:SCHED_LEN], tail]))
-                extra2 = jnp.stack([thst.astype(metrics.dtype),
-                                    rst.astype(metrics.dtype)])
-            else:
+                    extra = jnp.stack([stg.astype(metrics.dtype),
+                                       stl.astype(metrics.dtype)])
+            if accel is None:
                 extra2 = jnp.stack([nanv, nanv])
             row = jnp.concatenate([metrics[:3], extra, extra2, metrics[3:]]
                                   if n_more else [metrics, extra, extra2])
@@ -1048,7 +867,7 @@ def drive_on_device(
     stall_evals: int = STALL_EVALS,
     divergence_guard: bool = True,
     sigma_levels: Optional[tuple] = None,
-    accel: Optional["AccelConfig"] = None,
+    accel: Optional[tuple] = None,
 ):
     """Fully device-resident outer driver: the ENTIRE run — every round,
     every ``debugIter`` evaluation, and the gap-target early-stop test — is
@@ -1122,8 +941,7 @@ def drive_on_device(
         tap = _tele.DeviceTap(bus, name, start_round, c,
                               sigma_levels if anneal else None,
                               init_stage=init_stage,
-                              theta_hs=(accel.theta_hs
-                                        if accel is not None else None),
+                              theta_hs=accel,
                               init_theta_stage=init_theta,
                               init_restarts=init_restarts, gap_target=tgt)
 
@@ -1255,7 +1073,7 @@ def drive_device_full(
     mesh=None,
     divergence_guard: bool = True,
     sigma_levels: Optional[tuple] = None,
-    accel: Optional["AccelConfig"] = None,
+    accel: Optional[tuple] = None,
     overlap_io: bool = False,
     ckpt_rows=None,
 ):
@@ -1275,7 +1093,7 @@ def drive_device_full(
     runs block i.  Either way a block is one loop program and one read.
 
     With ``sigma_levels`` (σ′ anneal) the stall watch rides ``state[-1]``
-    ACROSS super-block boundaries — the host-twin watch below is then
+    ACROSS super-block boundaries — the host's own watch below is then
     unnecessary (and skipped): the device loop's counters are the single
     source of truth, and the checkpoints written at block boundaries carry
     them, which is what makes a mid-schedule resume bit-identical."""
@@ -1362,42 +1180,12 @@ def drive_device_full(
             _sanitize.count_launch()
         t = head_end + 1
         if head_end % c == 0:
-            with _tracing.span("eval", algorithm=name, round=head_end):
-                primal, gap, test_err, *per_class = eval_fn(state)
-                _sanitize.count_launch()
-            sigma_val = stage = stall_v = None
-            backed = False
-            hit = (gap_target is not None and gap is not None
-                   and gap <= gap_target)
-            if anneal:
-                # host-stepped eval feeds the SAME in-state watch the
-                # device loop reads (sched_host_step is its bit-twin)
-                sched, backed = sched_host_step(state[-1], gap, watch.n,
-                                                len(sigma_levels))
-                state = _sched_replace(state, sched)
-                stage = int(sched[0])
-                sigma_val = sigma_levels[stage]
-                stall_v = int(sched[1])
-            else:
-                watch.update(gap)
-            if accel is not None and not hit:
-                sched_a, restarted, staged = accel_host_step(
-                    state[-1], gap, accel.n_theta, gap_target, seam=backed)
-                state = _accel_replace(state, sched_a)
-                _emit_accel_events(name, head_end, restarted,
-                                   int(sched_a[A_RESTARTS]), staged,
-                                   int(sched_a[A_TH_STAGE]), accel, quiet)
-            traj.log_round(head_end, primal=primal, gap=gap,
-                           test_error=test_err, sigma=sigma_val,
-                           sigma_stage=stage, stall=stall_v,
-                           **_per_class(per_class, gap_target))
-            if backed:
-                _emit_backoff(name, head_end, sigma_levels, stage, quiet,
-                              f"{name}: σ′ anneal — gap stalled for "
-                              f"{watch.n} evals; backing off to "
-                              f"σ′={sigma_levels[stage]:g} at round "
-                              f"{head_end} (iterate kept, certificate "
-                              f"exact)")
+            # the SAME in-state watch the device loop reads
+            state, _ = _host_eval(
+                traj, name, head_end, state, eval_fn, gap_target, watch.n,
+                sigma_levels if anneal else None, accel, quiet)
+            if not anneal:
+                watch.update(traj.records[-1].gap)
         maybe_ckpt(head_end)
 
     n_full = max(0, (params.num_rounds - (t - 1)) // c)
@@ -1524,12 +1312,12 @@ def drive_device_full(
             if hit_target():
                 traj.stopped = "target"
                 break
-            # the in-loop watch state is per-block; the host twin spans
+            # the in-loop watch state is per-block; the host's spans
             # block boundaries (geometric blocks start with < STALL_EVALS
             # evals, where the in-loop watch alone could never fire).
             # Under σ′ anneal the watch rides state[-1] across blocks
             # instead, and a fired window backs off rather than stops —
-            # so there is no twin to run and nothing to mark diverged.
+            # so there is no watch to run and nothing to mark diverged.
             diverged = not anneal and divergence_guard and (
                 dev_traj.stopped == "diverged"
                 or any(watch.update(r.gap) for r in dev_traj.records)
@@ -1779,7 +1567,7 @@ def drive_device_paths(
     eval_arrays=None,
     divergence_guard: bool = True,
     sigma_levels: Optional[tuple] = None,
-    accel: Optional["AccelConfig"] = None,
+    accel: Optional[tuple] = None,
     overlap_io: bool = False,
     ckpt_rows=None,
 ):
@@ -1938,7 +1726,7 @@ def _build_fleet_run(chunk_kernel, eval_kernel, n_state,
             done0 = done_tgt | done_stall
             if jump_kernel is not None:
                 # the accel secant jump, per lane at the chunk head —
-                # the solo accel_kernel's position and arithmetic (an
+                # the solo chunk kernel's position and arithmetic (an
                 # unarmed or done lane's jump is the identity)
                 state = vjump(state, shard_arrays, scal)
             chunk = jax.tree.map(lambda a: a[i], idxs_all)
@@ -1956,35 +1744,7 @@ def _build_fleet_run(chunk_kernel, eval_kernel, n_state,
             done_now = (gap <= tgts) | done0
             newly = (gap <= tgts) & jnp.logical_not(done0)
             nans = jnp.full((t_fleet,), jnp.nan, metrics.dtype)
-            if anneal:
-                # per-tenant σ′ schedule/watch — the solo anneal branch
-                # with every scalar a (T,) column; frozen lanes keep
-                # their sched head bitwise (the watch must not keep
-                # counting a lane that stopped updating)
-                sched = state[-1]
-                gv = jnp.where(jnp.isnan(gap), jnp.inf,
-                               gap).astype(jnp.float32)
-                stg, stl = sched[:, 0], sched[:, 1]
-                bst, bpv = sched[:, 2], sched[:, 3]
-                bst, bpv, stl = _watch_update(jnp, gv, bst, bpv, stl,
-                                              jnp.float32(STALL_REL))
-                fired = stl >= jnp.float32(stall_evals)
-                bo = (fired & (stg < jnp.float32(n_stages - 1))
-                      & jnp.logical_not(done_now))
-                inf32 = jnp.float32(jnp.inf)
-                stg = jnp.where(bo, stg + 1, stg)
-                stl = jnp.where(bo, jnp.float32(0), stl)
-                bst = jnp.where(bo, inf32, bst)
-                bpv = jnp.where(bo, inf32, bpv)
-                head = jnp.stack([stg, stl, bst, bpv, sched[:, 4]],
-                                 axis=1)
-                sched_new = (jnp.concatenate(
-                    [head, sched[:, SCHED_LEN:]], axis=1)
-                    if accel else head)
-                sched_new = jnp.where(done0[:, None], sched, sched_new)
-                state = (*state[:-1], sched_new)
-                extra = jnp.stack([stg, stl], axis=1).astype(metrics.dtype)
-            elif check_div:
+            if check_div and not anneal:
                 # per-tenant no-improvement watch; only gap-targeted
                 # lanes can stop diverged (the solo guard is tied to a
                 # target's existence — lane-wise here)
@@ -2007,53 +1767,40 @@ def _build_fleet_run(chunk_kernel, eval_kernel, n_state,
                                         stall_chunk)
                 extra = jnp.stack([nans, stall.astype(metrics.dtype)],
                                   axis=1)
-            else:
+            elif not anneal:
                 extra = jnp.stack([nans, jnp.zeros_like(nans)], axis=1)
-            if accel:
-                # the per-tenant secant window bookkeeping — the solo
-                # accel branch with (T,) columns.  done_now gates every
-                # action exactly as the solo done_tgt does, which is
-                # also what freezes an already-done lane's tail.  The
-                # fleet runs the fixed-Θ ladder (n_theta == 1): the Θ
-                # slots ride unchanged.
+            if anneal or accel:
+                # the solo loop's schedule update with every field a (T,)
+                # column; done_now gates every action exactly as the solo
+                # done_tgt does, and a lane already frozen keeps its σ′
+                # head bitwise (the watch must not keep counting a lane
+                # that stopped updating).  The fleet runs the fixed-Θ
+                # ladder (n_theta == 1): the Θ slots ride unchanged.
                 sched = state[-1]
                 gv = jnp.where(jnp.isnan(gap), jnp.inf,
                                gap).astype(jnp.float32)
-                hl, rst, lg = (sched[:, A_HIST], sched[:, A_RESTARTS],
-                               sched[:, A_LASTGAP])
-                restart = (gv > lg) & jnp.logical_not(done_now)
-                arm = ((hl >= jnp.float32(2)) & jnp.logical_not(restart)
-                       & jnp.logical_not(done_now))
-                rst = jnp.where(restart, rst + 1, rst)
-                hl = jnp.where(
-                    done_now, hl,
-                    jnp.where(arm, jnp.float32(0),
-                              jnp.where(restart, jnp.float32(1),
-                                        jnp.minimum(hl + 1,
-                                                    jnp.float32(2)))))
-                jmp = jnp.where(arm, jnp.float32(1), jnp.float32(0))
-                lg = jnp.where(done_now, lg, gv)
-                push = jnp.logical_not(arm) & jnp.logical_not(done_now)
+                upd = eval_boundary_update(
+                    jnp, sched, gv, done_now, stall_evals=stall_evals,
+                    n_stages=n_stages if anneal else 0,
+                    n_theta=1 if accel else 0, tgt=None)
+                stg, stl, thst, rst = upd.cols
+                head = sched[:, :SCHED_LEN]
                 if anneal:
-                    # a committed σ′ backoff is a round-map seam: same
-                    # bank cap as the solo device loop
-                    hl = jnp.where(bo, jnp.minimum(hl, jnp.float32(1)),
-                                   hl)
-                tail = jnp.stack(
-                    [hl, jmp, rst, lg, sched[:, A_TH_STAGE],
-                     sched[:, A_TH_STALL], sched[:, A_TH_BEST],
-                     sched[:, A_TH_BPREV]], axis=1)
-                hist_leaf = jnp.where(
-                    push[:, None, None, None],
-                    jnp.stack([state[2][:, 1], state[1]], axis=1),
-                    state[2])
-                state = (state[0], state[1], hist_leaf,
-                         jnp.concatenate([sched[:, :SCHED_LEN], tail],
-                                         axis=1))
-                extra2 = jnp.stack(
-                    [sched[:, A_TH_STAGE], rst],
-                    axis=1).astype(metrics.dtype)
-            else:
+                    head = jnp.where(done0[:, None], head, upd.head)
+                    extra = jnp.stack([stg, stl],
+                                      axis=1).astype(metrics.dtype)
+                if accel:
+                    hist_leaf = jnp.where(
+                        upd.push[:, None, None, None],
+                        jnp.stack([state[2][:, 1], state[1]], axis=1),
+                        state[2])
+                    state = (state[0], state[1], hist_leaf,
+                             jnp.concatenate([head, upd.tail], axis=1))
+                    extra2 = jnp.stack([thst, rst],
+                                       axis=1).astype(metrics.dtype)
+                else:
+                    state = (*state[:-1], head)
+            if not accel:
                 extra2 = jnp.stack([nans, nans], axis=1)
             done_tgt = done_tgt | newly
             cert = jnp.where(newly, i + jnp.int32(1), cert)
@@ -2075,22 +1822,20 @@ def _build_fleet_run(chunk_kernel, eval_kernel, n_state,
     return run
 
 
-class FleetCarry:
+class FleetCarry(NamedTuple):
     """The per-tenant watch vectors chained across fleet super-block
-    dispatches (all donated run arguments; fresh via :meth:`init`).
-    ``cert_chunk`` / ``stall_chunk`` record the 1-based eval a lane
-    certified / stalled out at (0 = never) — what the host decodes
-    per-eval active-lane counts and per-tenant outcomes from."""
-
-    def __init__(self, done_tgt, done_stall, stall, best, best_prev,
-                 cert_chunk, stall_chunk):
-        self.done_tgt = done_tgt
-        self.done_stall = done_stall
-        self.stall = stall
-        self.best = best
-        self.best_prev = best_prev
-        self.cert_chunk = cert_chunk
-        self.stall_chunk = stall_chunk
+    dispatches, in the order the loop program takes them (all donated run
+    arguments; fresh via :meth:`init`).  ``cert_chunk`` / ``stall_chunk``
+    record the 1-based eval a lane certified / stalled out at (0 = never)
+    — what the host decodes per-eval active-lane counts and per-tenant
+    outcomes from."""
+    done_tgt: object
+    done_stall: object
+    stall: object
+    best: object
+    best_prev: object
+    cert_chunk: object
+    stall_chunk: object
 
     @classmethod
     def init(cls, t: int, dtype):
@@ -2102,10 +1847,6 @@ class FleetCarry:
             jnp.full((t,), jnp.inf, dtype),
             jnp.full((t,), jnp.inf, dtype),
             jnp.zeros((t,), jnp.int32), jnp.zeros((t,), jnp.int32))
-
-    def args(self):
-        return (self.done_tgt, self.done_stall, self.stall, self.best,
-                self.best_prev, self.cert_chunk, self.stall_chunk)
 
 
 def drive_fleet_on_device(
@@ -2160,17 +1901,13 @@ def drive_fleet_on_device(
                        rounds=n_chunks * c, cadence=c, tenants=t_fleet), \
             _sanitize.device_loop_guard():
         with _tracing.span("dispatch"):
-            out = run(*carry.args(), *state, idxs_all, shard_arrays, scal,
-                      gap_targets)
-        (i, done_tgt, done_stall, stall, best, best_prev, cert,
-         stall_chunk, state, traj_buf) = out
+            i, *watches, state, traj_buf = run(
+                *carry, *state, idxs_all, shard_arrays, scal, gap_targets)
         # the single host sync of the whole fleet block: the solo loop's
         # read (the watch vectors stay on the device for the next block)
         _, traj_host = fetch_loop_result(i, traj_buf, "fleet_loop_fetch")
         n_done = len(traj_host)
-    carry = FleetCarry(done_tgt, done_stall, stall, best, best_prev,
-                       cert, stall_chunk)
-    return state, carry, n_done, traj_host
+    return state, FleetCarry(*watches), n_done, traj_host
 
 
 class TsSampler:

@@ -856,8 +856,9 @@ def make_round_step(mesh, params: Params, k: int, alg, **parts_kw):
 
 def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
                        **parts_kw):
-    """The un-jitted traceable chunk body shared by :func:`make_chunk_step`
-    and the device-resident driver (so the two cannot diverge):
+    """The un-jitted traceable chunk body every branch of
+    :func:`build_sdca_loop`'s table is (so the host-stepped step and the
+    device-resident driver cannot diverge):
     (w, alpha, idxs_ckh, shard_arrays) -> (w', alpha'), C rounds as one
     ``lax.scan`` (parallel/fanout.py chunk_fanout).  On Pallas configs the
     caller (run_sdca_family) pre-folds ``shard_arrays["X_folded"]`` once per
@@ -901,25 +902,143 @@ def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
 _CHUNK_STEPS: dict = base.ExecutableCache()
 
 
-def make_chunk_step(mesh, params: Params, k: int, alg, sampler=None,
-                    **parts_kw):
-    """Build the jitted chunked step: C rounds as one device-side lax.scan
-    (see parallel/fanout.py chunk_fanout) — same math as make_round_step,
-    one host dispatch per chunk instead of per round.  Executables are cached
-    per configuration so repeated run_* calls don't pay a re-jit."""
-    key = (
-        mesh, k, alg, params.lam, params.n, params.local_iters,
+@jax.named_scope(_tracing.SCOPE_ACCEL_JUMP)
+def secant_jump(w, alpha, hist, shard_arrays, mesh, inv_lam_n):
+    """The secant (Anderson-1) jump from the banked window displacements
+    (solvers/base.py layout note): α ← α + c·(α − h2), c from the windows'
+    autocorrelation (base.secant_coef), clipped to the hinge-family dual
+    box and padding-masked, and w advanced by the EXACT correspondence
+    update Σ y·Δα·x/(λn) — (w, α) stays a feasible certified pair."""
+    # multiply-then-sum, not vdot: on a dp mesh (explicit axis types) a
+    # contraction over the sharded shard axis has no unambiguous output
+    # sharding and is rejected; a sum over it reduces to a replicated scalar
+    d1 = hist[1] - hist[0]
+    den = jnp.sum(d1 * d1)
+    rho = jnp.where(
+        den > 0,
+        jnp.sum(d1 * (alpha - hist[1]))
+        / jnp.where(den > 0, den, jnp.float32(1)),
+        jnp.float32(0))
+    cj = base.secant_coef(jnp, rho)
+    a_ext = jnp.clip(alpha + cj * (alpha - hist[1]),
+                     0.0, 1.0) * shard_arrays["mask"]
+    coefs = (shard_arrays["labels"] * (a_ext - alpha)
+             * jnp.float32(inv_lam_n))
+    if mesh is None:
+        return _rows.shards_axpy(coefs, shard_arrays, w), a_ext
+
+    # on a mesh the scatter is shard-local and the combine is the same one
+    # Δw psum a round pays (base.fanout)
+    def shard_axpy(w_, coefs_k, shard_k):
+        return (_rows.shards_axpy(
+            coefs_k[None], jax.tree.map(lambda a: a[None], shard_k),
+            jnp.zeros_like(w_)),)
+
+    (dw_jump,) = base.fanout(shard_axpy, mesh, w, coefs, shard_arrays)
+    return w + dw_jump, a_ext
+
+
+def build_sdca_loop(mesh, params: Params, k: int, alg, sampler, parts_kw, *,
+                    levels: tuple, branch_params: list, theta_hs: tuple,
+                    warm_end: int = 0, bank: bool = False):
+    """The loop program of an SDCA-family job, from its static description:
+    the σ′ ladder ``levels`` (base.anneal_levels), ``branch_params`` (the
+    job's Params, or under ``--warmStart`` the smooth-hinge phase that runs
+    for rounds ≤ ``warm_end`` and then the job's), the Θ ladder
+    ``theta_hs`` (base.theta_ladder) and ``bank`` (the state carries the
+    ``--accel`` window bank ``hist``).  Returns ``(chunk_kernel,
+    chunk_step, sched_token)``: the traceable ``chunk_kernel(state,
+    idxs_ckh, shard_arrays) -> state`` the device loop's body calls, the
+    jitted host-stepped ``chunk_step(*state, idxs_ckh, shard_arrays)``
+    (cached per configuration in ``_CHUNK_STEPS``) and the token that
+    names the description in a cache key.
+
+    ONE branch table, ``[branch(bp, lv, hs) for lv in levels for bp in
+    branch_params for hs in theta_hs]``, every branch the SAME
+    statically-specialized chunk (:func:`_make_chunk_kernel`: every
+    Pallas/block configuration keeps its baked-in scalars).  A one-entry
+    table with no bank is the plain job: state ``(w, α)``, the kernel IS
+    its branch.  Anything else carries the float32 schedule leaf last
+    (base.SCHED_LEN layout; donated, checkpointed and resumed with (w,
+    α)): the traced stage, round and Θ stage in it pick WHICH branch a
+    chunk runs — a ``lax.switch``, so σ′, the loss phase and H change IN
+    the device loop with no re-dispatch and no retrace, and a run that
+    never leaves its first branch is bit-identical to the fixed job.  A Θ
+    stage slices the sampled tables to its H_s prefix: every mode's draw
+    stream is prefix-stable, so a stage runs FEWER of the reference draws,
+    never different ones.  With ``bank`` the state is ``(w, α, hist,
+    sched)`` and a chunk opens by consuming an armed secant jump
+    (base.A_JUMP, set by the drivers' eval-boundary update;
+    :func:`secant_jump`): the rounds themselves are UNMODIFIED — the
+    acceleration lives between windows, so the certificate arithmetic
+    never changes."""
+    n_levels, n_phases, n_theta = (len(levels), len(branch_params),
+                                   len(theta_hs))
+    full_h = params.local_iters
+    scheduled = bank or n_levels * n_phases * n_theta > 1
+
+    def branch(bp, lv, hs):
+        kern = _make_chunk_kernel(
+            mesh, dataclasses.replace(bp, local_iters=int(hs)), k,
+            (alg[0], alg[1], lv), sampler=sampler, **parts_kw)
+        if hs >= full_h:
+            return kern
+        return lambda w, alpha, idxs_ckh, shard_arrays: kern(
+            w, alpha, idxs_ckh[:, :, :hs], shard_arrays)
+
+    table = [branch(bp, lv, hs) for lv in levels for bp in branch_params
+             for hs in theta_hs]
+    inv_lam_n = 1.0 / (params.lam * params.n)
+
+    def chunk_kernel(*args):
+        *state, idxs_ckh, shard_arrays = args
+        if not scheduled:
+            return table[0](*state, idxs_ckh, shard_arrays)
+        (w, alpha), sched = state[:2], state[-1]
+        if isinstance(idxs_ckh, dict):
+            idxs_ckh = sampler.tables_from_ts(idxs_ckh["t"])
+        c_len = idxs_ckh.shape[0]
+        th = ph = 0
+        if bank:
+            w, alpha = jax.lax.cond(
+                sched[base.A_JUMP] > 0,
+                lambda w, a: secant_jump(w, a, state[2], shard_arrays, mesh,
+                                          inv_lam_n),
+                lambda w, a: (w, a), w, alpha)
+            sched = sched.at[base.A_JUMP].set(jnp.float32(0))
+        stage = jnp.clip(sched[0].astype(jnp.int32), 0, n_levels - 1)
+        if bank:
+            th = jnp.clip(sched[base.A_TH_STAGE].astype(jnp.int32), 0,
+                          n_theta - 1)
+        if n_phases == 2:
+            # the chunk is warm iff it ends at or before warm_end; chunks
+            # never straddle an eval-cadence boundary (the drivers cut them
+            # there), so one test a chunk is exact for every driver
+            ph = jnp.where(
+                sched[4] + (c_len - 1) <= jnp.float32(warm_end), 0, 1)
+        w, alpha = jax.lax.switch((stage * n_phases + ph) * n_theta + th,
+                                  table, w, alpha, idxs_ckh, shard_arrays)
+        return (w, alpha, *state[2:-1],
+                sched.at[4].add(jnp.float32(c_len)))
+
+    sched_token = (None if not scheduled else
+                   (levels, warm_end, branch_params[0].loss,
+                    branch_params[0].smoothing, theta_hs, bank))
+    step_key = (
+        mesh, k, alg, sched_token, params.lam, params.n, params.local_iters,
         params.beta, params.gamma, params.loss, params.smoothing,
-        None if sampler is None else sampler.cache_token(),
-        tuple(sorted(parts_kw.items())),
+        sampler.cache_token(), tuple(sorted(parts_kw.items())),
     )
-    step = _CHUNK_STEPS.get(key)
-    if step is None:
-        kernel = _make_chunk_kernel(mesh, params, k, alg, sampler=sampler,
-                                    **parts_kw)
-        step = jax.jit(kernel, donate_argnums=(0, 1))
-        _CHUNK_STEPS[key] = step
-    return step
+    chunk_step = _CHUNK_STEPS.get(step_key)
+    if chunk_step is None:
+        # every leaf is donated but hist: it is read-only in the kernel
+        # (the drivers rebind it at eval boundaries)
+        chunk_step = _CHUNK_STEPS[step_key] = jax.jit(
+            chunk_kernel, donate_argnums=tuple(
+                i for i in range(2 + bank + scheduled)
+                if not (bank and i == 2)))
+    return (lambda state, idxs_ckh, shard_arrays: chunk_kernel(
+        *state, idxs_ckh, shard_arrays)), chunk_step, sched_token
 
 
 _START_PROGRAMS: dict = base.ExecutableCache()
@@ -1052,6 +1171,93 @@ def _check_class_job(ds: ShardedDataset, alg, arm: str, debug: DebugParams,
             f"{test_ds.num_classes}: load both with the same --classes")
 
 
+def _kernel_arrays(ds: ShardedDataset, path: SolverPath,
+                   block_size: int) -> dict:
+    """``ds.shard_arrays()`` with what the resolved kernels read beside
+    them, each made ONCE per DATASET and kept on the ds object: inside the
+    round loop it would be redone every round, and per RUN it is a fixed
+    cost a process that reuses the dataset — back-to-back jobs, sweep
+    loops, the sigma=auto trial+safe pair — would pay on every call.  Safe
+    to share: both are jit INPUTS (never donated), so no dispatch can
+    overwrite them."""
+    def once(attr, phase, make):
+        made = getattr(ds, attr, None)
+        if made is None:
+            with _tracing.cold_span(phase) as cold:
+                made = make()
+                cold.made(made)
+            setattr(ds, attr, made)
+        return made
+
+    shard_arrays = ds.shard_arrays()
+    if path.pallas and ds.layout == "dense":
+        # X folded for the dense kernel.  Where the lane padding is small
+        # it is stored lane-padded (path.rows), which makes the layout the
+        # device loop reads it in the device's own for that shape: the
+        # loop opens with no copy of it.
+        from cocoa_tpu.ops.pallas_sdca import fold_rows
+
+        shard_arrays["X_folded"] = once(
+            "_x_folded_cache", "fold_rows", lambda: fold_rows(
+                shard_arrays["X"], row_major=path.rows == "row_major"))
+    if ((path.pallas or block_size > 0) and ds.layout == "sparse"
+            and "sp_row_len" not in shard_arrays):
+        # per-row nnz counts for the kernels' group early exit (sequential
+        # sparse kernel AND the sparse block-chain path); a dataset in
+        # length order brings them itself
+        from cocoa_tpu.ops.pallas_sparse import row_lengths
+
+        shard_arrays["sp_row_len"] = once(
+            "_row_len_cache", "row_lengths",
+            lambda: row_lengths(shard_arrays["sp_values"]))
+    return shard_arrays
+
+
+def _loop_description(params: Params, debug: DebugParams, alg, path,
+                      block_size, gap_target, sigma_levels, warm_start,
+                      accel: bool, theta: str) -> dict:
+    """Validate the schedule flags of an SDCA-family job and return
+    :func:`build_sdca_loop`'s static description of it, as keywords."""
+    theta_hs = (params.local_iters,)
+    if accel:
+        if debug.debug_iter <= 0:
+            raise ValueError(
+                "--accel requires --debugIter > 0 (the momentum restart "
+                "rule rides the eval cadence)")
+        if theta == "adaptive" and gap_target is None:
+            raise ValueError(
+                "--theta=adaptive requires --gapTarget (the Θ ladder's "
+                "final full-accuracy stage is keyed to the target)")
+        theta_hs = base.theta_ladder(params.local_iters, theta == "adaptive")
+        if len(theta_hs) > 1 and (path.pallas or block_size > 0):
+            raise ValueError(
+                "--theta=adaptive slices the sequential (C, K, H) "
+                "index tables and is not available on the Pallas/"
+                "--blockSize paths (their kernels and the "
+                "block-distinct sampling license are keyed to the "
+                "full H); drop --theta=adaptive or the block flags")
+    warm_end = 0
+    branch_params = [params]
+    if warm_start is not None:
+        warm_s, warm_end = warm_start
+        if debug.debug_iter <= 0 or warm_end % debug.debug_iter != 0:
+            raise ValueError(
+                f"warm_start rounds ({warm_end}) must be a multiple of "
+                f"debugIter ({debug.debug_iter}, > 0): the loss handoff "
+                f"lands on the eval-cadence chunk boundary — the CLI "
+                f"rounds up for you")
+        branch_params = [
+            dataclasses.replace(params, loss="smooth_hinge",
+                                smoothing=float(warm_s)),
+            params,
+        ]
+    return dict(
+        levels=(tuple(float(v) for v in sigma_levels)
+                if sigma_levels is not None else (float(alg[2]),)),
+        branch_params=branch_params, theta_hs=theta_hs, warm_end=warm_end,
+        bank=accel)
+
+
 @_tracing.cold_entry
 def run_sdca_family(
     ds: ShardedDataset,
@@ -1091,24 +1297,27 @@ def run_sdca_family(
     mini-batch CD — they differ only in their ``alg`` scaling triple, see
     :func:`_alg_config`) and, with eval overrides, the primal prox family
     (solvers/prox_cocoa.py).  Train; returns (w, alpha, Trajectory).
+    Validate, resolve the path (:func:`resolve_solver_path`), make the
+    per-dataset caches, build the loop (:func:`build_sdca_loop`), drive it
+    (base.drive_device_paths).
 
-    ``sigma_levels`` / ``warm_start`` select the SCHEDULED path (the
-    --sigmaSchedule=anneal / --warmStart machinery, normally reached via
-    :func:`run_cocoa`): the solver state gains a tiny float32 schedule
-    leaf (base.SCHED_LEN layout) carried through the drive* ladder —
-    donated, checkpointed and resumed with (w, α) — and the chunk kernel
-    becomes a ``lax.switch`` over statically-specialized per-(σ′ stage,
-    loss phase) kernels, selected by the traced stage/round in the
-    schedule leaf.  σ′ therefore changes IN the device while_loop with no
-    re-dispatch, no retrace and no restart; each branch is exactly the
-    fixed-configuration kernel, so a run that never backs off is
-    bit-identical to the corresponding fixed-σ′ run.  ``sigma_levels`` is
-    the static σ′ ladder (base.anneal_levels; the stall watch fires →
-    stage += 1); ``warm_start=(s, warm_end)`` runs smooth_hinge(s) for
-    rounds ≤ warm_end (a ``debugIter`` multiple — the chunk/eval cadence
-    boundary the in-scan handoff lands on) before the final loss;
-    ``sched_init`` restores a mid-schedule checkpoint (base.sched layout,
-    bit-identical resume).
+    The schedule (normally reached via :func:`run_cocoa`; what each means
+    for the loop program is :func:`build_sdca_loop`'s to say):
+    ``sigma_levels`` — the static σ′ ladder of ``--sigmaSchedule=anneal``
+    (base.anneal_levels; the stall watch fires → stage += 1);
+    ``warm_start=(s, warm_end)`` — smooth_hinge(s) for rounds ≤ warm_end
+    (a ``debugIter`` multiple) before the final loss; ``accel=True`` — the
+    ACCELERATED outer loop (docs/DESIGN.md "Accelerated outer loop"; the
+    outer-acceleration structure of Smith et al., arXiv:1711.05305 with a
+    measured secant extrapolation in place of fixed momentum): at each
+    eval boundary the drivers bank the current α, and once two consecutive
+    improving windows are banked the next chunk opens with a secant jump;
+    a gap rise restarts the bank (base.eval_boundary_update);
+    ``theta="adaptive"`` — with it, the Θ local-accuracy ladder
+    (base.theta_ladder): early rounds run H/2 inner steps, tightening to
+    the full H near the target.  ``sched_init`` / ``hist_init`` restore
+    the schedule leaf and the window bank from a checkpoint (bit-identical
+    mid-schedule resume).
 
     ``eval_fn(state) -> (primal, gap|None, test_err|None)`` and
     ``eval_kernel(state, shard_arrays, test_arrays) -> (3,) metrics``
@@ -1119,75 +1328,25 @@ def run_sdca_family(
 
     Extensions over the reference: ``gap_target`` stops early once the
     duality gap — checked at the ``debugIter`` cadence — falls below the
-    target (the baseline metric counts comm-rounds and wall-clock to reach
-    it); ``w_init``/``alpha_init``/``start_round`` resume from a checkpoint
-    (see cocoa_tpu.checkpoint) — round-indexed RNG makes the resumed
-    trajectory identical to an uninterrupted run; ``scan_chunk > 0`` runs
-    rounds device-side in blocks of that size via ``lax.scan`` (fewer host
-    dispatches, same math and observable trajectory).
+    target; ``w_init``/``alpha_init``/``start_round`` resume from a
+    checkpoint (see cocoa_tpu.checkpoint) — round-indexed RNG makes the
+    resumed trajectory identical to an uninterrupted run; ``scan_chunk >
+    0`` runs rounds device-side in blocks of that size via ``lax.scan``;
+    ``device_loop=True`` runs the ENTIRE loop — rounds, evaluations, early
+    stop — as one ``lax.while_loop`` on device (base.drive_on_device;
+    needs debug_iter > 0).  Every driver shows the same trajectory.
 
-    ``math="fast"`` enables the margins-decomposition inner loop (equal in
-    real arithmetic; floating-point rounds differ from the reference order —
-    trajectories agree to ~1e-6, convergence behavior is unchanged).
-    ``pallas`` (None = auto: fast math + f32 + TPU backend + fits on-chip)
-    runs the inner loop as a Pallas TPU kernel — the folded-row dense
-    kernel or the lane-blocked sparse (padded-CSR) kernel, by layout;
-    requires ``math="fast"``.
-
-    ``block_size > 0`` (flag ``--blockSize``) runs the fast inner loop as
-    the block-coordinate MXU kernel (ops/local_sdca.local_sdca_block):
-    same sampled index stream, margins via cached block Gram matrices —
-    identical in real arithmetic to the sequential fast path, restructured
-    so the per-coordinate critical path is O(B) scalar work instead of an
-    O(d) dot.  Requires ``math="fast"``; mutually exclusive with the
-    Pallas sequential kernels.
-
-    ``device_loop=True`` runs the ENTIRE training loop — all rounds, the
-    ``debugIter``-cadence evaluations, and the gap-target early-stop — as
-    one ``lax.while_loop`` on device: one dispatch, one host fetch (see
-    base.drive_on_device).  Observable trajectory identical to the
-    host-stepped drivers; requires debug_iter > 0, not compatible with
-    checkpointing (chkpt_iter).
-
-    ``block_sparse_gram`` (None = auto by layout and fit) selects the
-    sparse block-chain path for padded-CSR data: the block Gram and margin
-    base come from SMEM CSR streams in-kernel and the Δw apply is a sparse
-    scatter (ops/pallas_sparse) — no (K, B, d) densify.
-
-    ``overlap_io=True`` (flag ``--overlapComm``, single-process runs
-    only — resolved by the CLI): checkpoint WRITES on the device-loop
-    path ride a daemon writer thread so their serialization + disk IO
-    overlaps the next super-block's dispatch (base.drive_device_full);
-    the state snapshot stays synchronous, so the written bytes are
-    bit-identical to a synchronous save.
-
-    ``divergence_guard`` ("auto" | "on" | "off", flag --divergenceGuard)
-    controls the gap-target stall watch: auto arms it only when σ′ is
-    overridden below the safe K·γ bound (base.resolve_divergence_guard).
-
-    ``accel=True`` (flag ``--accel``, resolved by :func:`run_cocoa`) runs
-    the ACCELERATED outer loop (docs/DESIGN.md "Accelerated outer loop";
-    the outer-acceleration structure of Smith et al., arXiv:1711.05305
-    with a measured secant extrapolation in place of fixed momentum):
-    the state gains a (2, K, n_shard) dual-history leaf ``hist`` and the
-    bank/jump/Θ slots on the sched vector (base.ACCEL_LEN layout).  At
-    each eval boundary the drivers bank the current α as a window
-    snapshot; once two consecutive improving windows are banked, the
-    next chunk dispatch opens with a secant (Anderson-1) jump — α moves
-    by c·(α − h2) with the signed, data-derived c = ρ/(1−ρ) from the
-    window displacements' autocorrelation (base.secant_coef), clipped
-    back into the dual box, and w advanced by the exact correspondence
-    update Σ y·Δα·x/(λn) (ops/rows.shards_axpy) — so the certified pair
-    (w, α) stays a feasible primal-dual pair and the unmodified gap
-    evaluation stays the certificate.  A gap rise at an eval boundary
-    restarts the bank (one-eval-cadence damage bound).
-    ``theta="adaptive"`` additionally runs the Θ local-accuracy ladder
-    (base.theta_ladder): early rounds run H/2 inner steps, resolved ON
-    DEVICE from the current gap estimate via the same
-    statically-specialized ``lax.switch`` branch machinery as the σ′
-    stages, tightening to the full H near the target.  ``hist_init``
-    restores the window bank from a checkpoint (bit-identical
-    mid-momentum resume).
+    ``math="fast"``: the margins-decomposition inner loop (equal in real
+    arithmetic; trajectories agree to ~1e-6).  ``pallas`` (None = auto:
+    fast math + f32 + TPU backend + fits on-chip) runs it as a Pallas TPU
+    kernel, by layout.  ``block_size > 0`` (``--blockSize``) runs it as
+    the block-coordinate MXU kernel (ops/local_sdca.local_sdca_block) on
+    the same sampled index stream, ``block_sparse_gram`` (None = auto) its
+    sparse CSR-Gram form; needs ``math="fast"``, excludes ``pallas``.
+    ``overlap_io=True`` (``--overlapComm``, single-process runs):
+    checkpoint WRITES on the device-loop path ride a writer thread
+    (base.drive_device_full).  ``divergence_guard`` ("auto" | "on" |
+    "off"): the gap-target stall watch (base.resolve_divergence_guard).
     """
     base.check_shards(ds)
     # a sparse set whose all-rows passes run in row blocks: they stop at a
@@ -1218,13 +1377,11 @@ def run_sdca_family(
 
     dtype = ds.labels.dtype
     if gap_target is not None and dtype == jnp.bfloat16:
-        # bf16 cannot certify a small duality gap: the dual objective's
-        # Σα/n accumulation and the primal−dual cancellation both sit
-        # below bf16's ~2^-8 relative resolution, so the computed gap is
-        # noise at 1e-4 scale and the trajectory stalls far above it
-        # (measured in tests/test_bf16.py; predicted by docs/DESIGN.md
-        # §6).  A gap-targeted bf16 run would either burn its whole round
-        # budget or "certify" on rounding artifacts — reject it instead.
+        # the dual objective's Σα/n accumulation and the primal−dual
+        # cancellation both sit below bf16's ~2^-8 relative resolution, so
+        # the computed gap is noise at 1e-4 scale (measured in
+        # tests/test_bf16.py): such a run would burn its whole round budget
+        # or "certify" on rounding artifacts
         raise ValueError(
             "gap-targeted runs cannot certify in bfloat16 (the duality "
             "gap is below bf16 resolution — docs/DESIGN.md §6); use "
@@ -1232,8 +1389,7 @@ def run_sdca_family(
             "bf16 run"
         )
     # the loop's carry beyond (w, α): --accel the window bank and the
-    # schedule leaf, a σ′ schedule or warm start the schedule leaf (both
-    # take the chunked or the device-resident driver: see below)
+    # schedule leaf, a σ′ schedule or warm start the schedule leaf
     scheduled = ((sigma_levels is not None and len(sigma_levels) > 1)
                  or warm_start is not None)
     arm = "accel" if accel else "sched" if scheduled else "plain"
@@ -1241,10 +1397,6 @@ def run_sdca_family(
     if classes > 1:
         _check_class_job(ds, alg, arm, debug, test_ds,
                          eval_fn is not None or eval_kernel is not None)
-        if scan_chunk <= 0 and not device_loop:
-            # the class axis rides the chunk kernels' batched round; the
-            # per-round driver is the same round at chunk = 1
-            scan_chunk = 1
     counted = (_sanitize.launches_total, _sanitize.intended_fetches_total)
     # init_state: one start program when the job starts from nothing —
     # dispatched here, ahead of the host's path to the loop's dispatch, so
@@ -1253,7 +1405,6 @@ def run_sdca_family(
         state0 = _start_state(ds, dtype, mesh, arm, alg[0] == "prox",
                               start_round, w_init, alpha_init, hist_init,
                               sched_init)
-    w, alpha = state0[:2]
 
     path = resolve_solver_path(
         ds, params.local_iters, mesh, math=math, pallas=pallas,
@@ -1280,367 +1431,79 @@ def run_sdca_family(
             and bool(np.all(np.asarray(ds.counts) % params.local_iters == 0))
         ),
     )
-    # the Pallas kernels (sequential and block-chain) own the shard axis
-    # themselves, which neither the per-round driver's vmap path nor its
-    # plain fanout shard_map can express — route through the chunked driver
-    if (pallas or block_chain != "xla") and scan_chunk <= 0:
-        scan_chunk = 1
-
     sampler = base.IndexSampler(rng, debug.seed, params.local_iters, ds.counts)
     sampler.device = base.resolve_sampling(sampling, sampler,
                                            params.num_rounds)
-    shard_arrays = ds.shard_arrays()
-    if pallas and ds.layout == "dense":
-        # fold X for the dense kernel ONCE per DATASET (cached on the ds
-        # object): folding inside the round loop would relayout the whole
-        # X every round, and folding per RUN is a fixed cost a process
-        # that reuses the dataset — back-to-back jobs, sweep loops, the
-        # sigma=auto trial+safe pair — would pay on every call.  Safe to
-        # share: the folded tile is a jit INPUT (never donated), so no
-        # dispatch can overwrite it.  Where the lane padding is small it
-        # is stored lane-padded (path.rows), which makes the layout the
-        # device loop reads it in the device's own for that shape: the
-        # loop opens with no copy of it.
-        folded = getattr(ds, "_x_folded_cache", None)
-        if folded is None:
-            from cocoa_tpu.ops.pallas_sdca import fold_rows
-
-            with _tracing.cold_span("fold_rows") as cold:
-                folded = fold_rows(shard_arrays["X"],
-                                   row_major=path.rows == "row_major")
-                cold.made(folded)
-            ds._x_folded_cache = folded
-        shard_arrays = {**shard_arrays, "X_folded": folded}
-    if ((pallas or block_size > 0) and ds.layout == "sparse"
-            and "sp_row_len" not in shard_arrays):
-        # per-row nnz counts for the kernels' group early exit (sequential
-        # sparse kernel AND the sparse block-chain path) — same per-dataset
-        # cache rationale as the dense fold above (per round it would
-        # re-read the whole values array inside the scan); a dataset in
-        # length order brings them itself
-        row_len = getattr(ds, "_row_len_cache", None)
-        if row_len is None:
-            from cocoa_tpu.ops.pallas_sparse import row_lengths
-
-            with _tracing.cold_span("row_lengths") as cold:
-                row_len = row_lengths(shard_arrays["sp_values"])
-                cold.made(row_len)
-            ds._row_len_cache = row_len
-        shard_arrays = {**shard_arrays, "sp_row_len": row_len}
+    shard_arrays = _kernel_arrays(ds, path, block_size)
 
     if eval_fn is None:
         def eval_fn(state):
             # (a one-vs-rest job: a fourth element, every class's gap)
-            # state[0:2] — the scheduled path appends the sched leaf; the
-            # duality-gap certificate reads only (w, α) and is exact under
-            # any σ′/loss stage (which is the backoff's soundness argument)
+            # state[0:2]: the duality-gap certificate reads only (w, α) and
+            # is exact under any σ′/loss stage (which is the backoff's
+            # soundness argument)
             return objectives.evaluate(
                 ds, state[0], state[1], params.lam, test_ds=test_ds,
                 loss=params.loss, smoothing=params.smoothing)
 
-    def record_job(traj):
-        # beside the path: what the drive ladder issued for this job and
-        # how often it read the device (sanitize.count_launch,
-        # sanitize.intended_fetch), counted on the host
-        launches = _sanitize.launches_total - counted[0]
-        fetches = _sanitize.intended_fetches_total - counted[1]
-        # and, of a first job, what its cold branches took in seconds and
-        # in bytes (telemetry/tracing.py; empty for a warm job)
-        cold = _tracing.finish_job()
-        traj.meta.update(solver_path=path.as_dict(),
-                         vector_len=int(ds.num_features),
-                         launches=launches, fetches=fetches, cold=cold)
-        if not quiet:
-            print(f"drive ladder: {launches} programs launched, "
-                  f"{fetches} host fetches")
-            if cold:
-                print(f"cold path: {_tracing.cold_line(cold)}")
+    description = _loop_description(
+        params, debug, alg, path, block_size, gap_target, sigma_levels,
+        warm_start, accel, theta)
+    # the per-round program (under the chunked driver at chunk = 1) carries
+    # (w, α) across a vmap or a plain fanout shard_map: no schedule leaf, no
+    # class axis, no Pallas kernel (those own the shard axis themselves)
+    per_round = not (device_loop or scan_chunk > 0 or arm != "plain"
+                     or classes > 1 or pallas or block_chain != "xla")
+    chunk_kernel = sched_token = None
+    if per_round:
+        step = make_round_step(mesh, params, k, alg, **parts_kw)
 
-    if theta not in ("fixed", "adaptive"):
-        raise ValueError(f"theta must be fixed|adaptive, got {theta!r}")
-    if accel:
-        if debug.debug_iter <= 0:
-            raise ValueError(
-                "--accel requires --debugIter > 0 (the momentum restart "
-                "rule rides the eval cadence)")
-        if theta == "adaptive" and gap_target is None:
-            raise ValueError(
-                "--theta=adaptive requires --gapTarget (the Θ ladder's "
-                "final full-accuracy stage is keyed to the target)")
-    if (scheduled or accel) and scan_chunk <= 0 and not device_loop:
-        # the schedule leaf rides the chunked/device drivers' state; the
-        # per-round driver path is equivalent at chunk=1 (pinned by tests)
-        scan_chunk = 1
+        def chunk_fn(t0, c, state):
+            return step(*state, sampler.round_indices(t0), shard_arrays)
+    else:
+        chunk_kernel, chunk_step, sched_token = build_sdca_loop(
+            mesh, params, k, alg, sampler, parts_kw, **description)
 
-    if device_loop or scan_chunk > 0:
-        import dataclasses as _dc
+        def chunk_fn(t0, c, state):
+            return chunk_step(*state, sampler.chunk_indices(t0, c),
+                              shard_arrays)
 
-        sched_token = None
-        accel_cfg = None
-        if scheduled or accel:
-            levels = (tuple(float(v) for v in sigma_levels)
-                      if sigma_levels is not None else (float(alg[2]),))
-            warm_end = 0
-            branch_params = [params]
-            if warm_start is not None:
-                warm_s, warm_end = warm_start
-                if debug.debug_iter <= 0:
-                    raise ValueError(
-                        "warm_start needs debug_iter > 0 (the loss handoff "
-                        "lands on the eval-cadence chunk boundary)")
-                if warm_end % debug.debug_iter != 0:
-                    raise ValueError(
-                        f"warm_start rounds ({warm_end}) must be a multiple "
-                        f"of debugIter ({debug.debug_iter}) — the CLI "
-                        f"rounds up for you")
-                branch_params = [
-                    _dc.replace(params, loss="smooth_hinge",
-                                smoothing=float(warm_s)),
-                    params,
-                ]
-            n_phases = len(branch_params)
-            n_levels = len(levels)
-        if accel:
-            # --- the accelerated outer loop ------------------------------
-            # Branch table = (σ′ stage × loss phase × Θ stage), every
-            # branch the SAME statically-specialized chunk the plain
-            # scheduled path builds (_make_chunk_kernel): the Θ stage
-            # slices the sampled index tables to its H_s prefix — every
-            # mode's draw stream is prefix-stable, so a stage only runs
-            # FEWER of the reference draws, never different ones — and
-            # the traced schedule state picks which branch runs, exactly
-            # the σ′ anneal pattern.  The chunk head additionally
-            # consumes an armed secant jump (A_JUMP, set by the drivers'
-            # eval-boundary bookkeeping): the rounds themselves are
-            # UNMODIFIED CoCoA+ — acceleration lives entirely between
-            # windows, so the certificate arithmetic never changes.
-            accel_cfg = base.AccelConfig(
-                base.theta_ladder(params.local_iters, theta == "adaptive"),
-                gap_target)
-            n_theta = accel_cfg.n_theta
-            full_h = params.local_iters
-            if n_theta > 1 and (parts_kw.get("pallas")
-                                or parts_kw.get("block", 0) > 0):
-                raise ValueError(
-                    "--theta=adaptive slices the sequential (C, K, H) "
-                    "index tables and is not available on the Pallas/"
-                    "--blockSize paths (their kernels and the "
-                    "block-distinct sampling license are keyed to the "
-                    "full H); drop --theta=adaptive or the block flags")
-
-            def _accel_branch(bp, lv, hs):
-                bph = (bp if hs >= full_h
-                       else _dc.replace(bp, local_iters=int(hs)))
-                kern = _make_chunk_kernel(mesh, bph, k,
-                                          (alg[0], alg[1], lv),
-                                          sampler=sampler, **parts_kw)
-
-                def branch(w, alpha, idxs_ckh, shard_arrays):
-                    idxs = (idxs_ckh if hs >= full_h
-                            else idxs_ckh[:, :, :hs])
-                    return kern(w, alpha, idxs, shard_arrays)
-
-                return branch
-
-            branches = [_accel_branch(bp, lv, hs)
-                        for lv in levels for bp in branch_params
-                        for hs in accel_cfg.theta_hs]
-            inv_lam_n = 1.0 / (params.lam * params.n)
-
-            def accel_kernel(w, alpha, hist, sched, idxs_ckh,
-                             shard_arrays):
-                if isinstance(idxs_ckh, dict):
-                    idxs_ckh = sampler.tables_from_ts(idxs_ckh["t"])
-                c_len = idxs_ckh.shape[0]
-
-                @jax.named_scope(_tracing.SCOPE_ACCEL_JUMP)
-                def take_jump(w, alpha):
-                    # secant (Anderson-1) jump from the banked window
-                    # displacements (solvers/base.py layout note): the
-                    # jumped α is clipped to the hinge-family dual box
-                    # and padding-masked, and w advances by the EXACT
-                    # correspondence update — (w, α) stays a feasible
-                    # certified pair
-                    # multiply-then-sum, not vdot: on a dp mesh (explicit
-                    # axis types) a contraction over the sharded shard axis
-                    # has no unambiguous output sharding and is rejected;
-                    # a sum over it reduces to a replicated scalar
-                    d1 = hist[1] - hist[0]
-                    den = jnp.sum(d1 * d1)
-                    rho = jnp.where(
-                        den > 0,
-                        jnp.sum(d1 * (alpha - hist[1]))
-                        / jnp.where(den > 0, den, jnp.float32(1)),
-                        jnp.float32(0))
-                    cj = base.secant_coef(jnp, rho)
-                    a_ext = jnp.clip(alpha + cj * (alpha - hist[1]),
-                                     0.0, 1.0) * shard_arrays["mask"]
-                    coefs = (shard_arrays["labels"] * (a_ext - alpha)
-                             * jnp.float32(inv_lam_n))
-                    if mesh is None:
-                        return _rows.shards_axpy(coefs, shard_arrays, w), \
-                            a_ext
-
-                    # on a mesh the scatter is shard-local and the combine
-                    # is the same one Δw psum a round pays (base.fanout)
-                    def shard_axpy(w_, coefs_k, shard_k):
-                        return (_rows.shards_axpy(
-                            coefs_k[None],
-                            jax.tree.map(lambda a: a[None], shard_k),
-                            jnp.zeros_like(w_)),)
-
-                    (dw_jump,) = base.fanout(shard_axpy, mesh, w, coefs,
-                                             shard_arrays)
-                    return w + dw_jump, a_ext
-
-                w, alpha = jax.lax.cond(
-                    sched[base.A_JUMP] > 0, take_jump,
-                    lambda w, a: (w, a), w, alpha)
-                sched = sched.at[base.A_JUMP].set(jnp.float32(0))
-                stage = jnp.clip(sched[0].astype(jnp.int32), 0,
-                                 n_levels - 1)
-                th = jnp.clip(sched[base.A_TH_STAGE].astype(jnp.int32), 0,
-                              n_theta - 1)
-                if n_phases == 2:
-                    # same invariant as the scheduled branch below:
-                    # chunks never straddle an eval-cadence boundary, so
-                    # one phase test per chunk is exact (keep the two
-                    # branch-index computations in sync)
-                    warm_now = (sched[4] + (c_len - 1)
-                                <= jnp.float32(warm_end))
-                    ph = jnp.where(warm_now, 0, 1)
-                else:
-                    ph = 0
-                br = (stage * n_phases + ph) * n_theta + th
-                w2, a2 = jax.lax.switch(br, branches, w, alpha, idxs_ckh,
-                                        shard_arrays)
-                sched2 = sched.at[4].add(jnp.float32(c_len))
-                return w2, a2, hist, sched2
-
-            def chunk_kernel(state, idxs_ckh, shard_arrays):
-                return accel_kernel(state[0], state[1], state[2], state[3],
-                                    idxs_ckh, shard_arrays)
-
-            sched_token = ("accel", levels, warm_end,
-                           branch_params[0].loss,
-                           branch_params[0].smoothing,
-                           accel_cfg.theta_hs)
-            step_key = (
-                "accel", mesh, k, alg[0], alg[1], sched_token,
-                params.lam, params.n, params.local_iters, params.beta,
-                params.gamma, params.loss, params.smoothing,
-                sampler.cache_token(), tuple(sorted(parts_kw.items())),
-            )
-            chunk_step = _CHUNK_STEPS.get(step_key)
-            if chunk_step is None:
-                # hist is read-only in the kernel (the drivers rebind it
-                # at eval boundaries), so it stays un-donated
-                chunk_step = jax.jit(accel_kernel,
-                                     donate_argnums=(0, 1, 3))
-                _CHUNK_STEPS[step_key] = chunk_step
-
-            def chunk_fn(t0, c, state):
-                return chunk_step(state[0], state[1], state[2], state[3],
-                                  sampler.chunk_indices(t0, c),
-                                  shard_arrays)
-
-        elif scheduled:
-            # one statically-specialized kernel per (σ′ stage, loss phase):
-            # every Pallas/block configuration keeps its baked-in scalars,
-            # and the traced schedule state only picks WHICH one runs
-            branches = [
-                _make_chunk_kernel(mesh, bp, k, (alg[0], alg[1], lv),
-                                   sampler=sampler, **parts_kw)
-                for lv in levels for bp in branch_params
-            ]
-
-            def sched_kernel(w, alpha, sched, idxs_ckh, shard_arrays):
-                c_len = jax.tree.leaves(idxs_ckh)[0].shape[0]
-                stage = jnp.clip(sched[0].astype(jnp.int32), 0, n_levels - 1)
-                if n_phases == 2:
-                    # the chunk is warm iff it ends at or before warm_end;
-                    # chunks never straddle an eval-cadence boundary (the
-                    # drivers cut them there), so this is exact for every
-                    # driver and chunk split
-                    warm_now = sched[4] + (c_len - 1) <= jnp.float32(warm_end)
-                    br = stage * 2 + jnp.where(warm_now, 0, 1)
-                else:
-                    br = stage
-                w2, a2 = jax.lax.switch(br, branches, w, alpha, idxs_ckh,
-                                        shard_arrays)
-                return w2, a2, sched.at[4].add(jnp.float32(c_len))
-
-            def chunk_kernel(state, idxs_ckh, shard_arrays):
-                return sched_kernel(state[0], state[1], state[2], idxs_ckh,
-                                    shard_arrays)
-
-            sched_token = (levels, warm_end,
-                           branch_params[0].loss, branch_params[0].smoothing)
-            step_key = (
-                "sched", mesh, k, alg[0], alg[1], sched_token,
-                params.lam, params.n, params.local_iters, params.beta,
-                params.gamma, params.loss, params.smoothing,
-                sampler.cache_token(), tuple(sorted(parts_kw.items())),
-            )
-            chunk_step = _CHUNK_STEPS.get(step_key)
-            if chunk_step is None:
-                chunk_step = jax.jit(sched_kernel, donate_argnums=(0, 1, 2))
-                _CHUNK_STEPS[step_key] = chunk_step
-
-            def chunk_fn(t0, c, state):
-                return chunk_step(state[0], state[1], state[2],
-                                  sampler.chunk_indices(t0, c), shard_arrays)
-        else:
-            levels = None
-            raw_kernel = _make_chunk_kernel(mesh, params, k, alg,
-                                            sampler=sampler, **parts_kw)
-
-            def chunk_kernel(state, idxs_ckh, shard_arrays):
-                return raw_kernel(state[0], state[1], idxs_ckh, shard_arrays)
-
-            chunk_step = make_chunk_step(mesh, params, k, alg,
-                                         sampler=sampler, **parts_kw)
-
-            def chunk_fn(t0, c, state):
-                return chunk_step(state[0], state[1],
-                                  sampler.chunk_indices(t0, c), shard_arrays)
-
-        cache_key = (
-            "sdca", alg_name, alg, math, pallas, block_size, block_chain,
-            block_sparse_gram, sched_token,
-            sampler.cache_token(), k, mesh,
-            params.lam, params.n, params.local_iters, params.beta,
-            params.gamma, params.loss, params.smoothing,
-            params.num_rounds, debug.debug_iter, start_round,
-            gap_target, ds.layout, str(dtype), classes,
-        )
-        state, traj = base.drive_device_paths(
-            alg_name, params, debug, state0, chunk_kernel, chunk_fn,
-            eval_fn, sampler, shard_arrays, alpha_in_state=True, mesh=mesh,
-            test_ds=test_ds, quiet=quiet, gap_target=gap_target,
-            start_round=start_round, scan_chunk=scan_chunk,
-            device_loop=device_loop, cache_key=cache_key,
-            eval_kernel=eval_kernel, eval_arrays=eval_arrays,
-            divergence_guard=guard_on,
-            sigma_levels=levels, accel=accel_cfg,
-            overlap_io=overlap_io, ckpt_rows=ckpt_rows,
-        )
-        record_job(traj)
-        return state[0], state[1], traj
-
-    step = make_round_step(mesh, params, k, alg, **parts_kw)
-
-    def round_fn(t, state):
-        w, alpha = state
-        return step(w, alpha, sampler.round_indices(t), shard_arrays)
-
-    (w, alpha), traj = base.drive(
-        alg_name, params, debug, (w, alpha), round_fn, eval_fn,
-        quiet=quiet, gap_target=gap_target, start_round=start_round,
-        divergence_guard=guard_on, ckpt_rows=ckpt_rows,
+    cache_key = (
+        "sdca", alg_name, alg, math, pallas, block_size, block_chain,
+        block_sparse_gram, sched_token,
+        sampler.cache_token(), k, mesh,
+        params.lam, params.n, params.local_iters, params.beta,
+        params.gamma, params.loss, params.smoothing,
+        params.num_rounds, debug.debug_iter, start_round,
+        gap_target, ds.layout, str(dtype), classes,
     )
-    record_job(traj)
-    return w, alpha, traj
+    state, traj = base.drive_device_paths(
+        alg_name, params, debug, state0, chunk_kernel, chunk_fn,
+        eval_fn, sampler, shard_arrays, alpha_in_state=True, mesh=mesh,
+        test_ds=test_ds, quiet=quiet, gap_target=gap_target,
+        start_round=start_round, scan_chunk=max(scan_chunk, 1),
+        device_loop=device_loop, cache_key=cache_key,
+        eval_kernel=eval_kernel, eval_arrays=eval_arrays,
+        divergence_guard=guard_on,
+        sigma_levels=description["levels"] if arm != "plain" else None,
+        accel=description["theta_hs"] if accel else None,
+        overlap_io=overlap_io, ckpt_rows=ckpt_rows,
+    )
+    # beside the path: what the drive ladder issued for this job and how
+    # often it read the device, counted on the host (analysis/sanitize.py);
+    # of a first job, its cold branches' seconds and bytes (else empty)
+    launches = _sanitize.launches_total - counted[0]
+    fetches = _sanitize.intended_fetches_total - counted[1]
+    cold = _tracing.finish_job()
+    traj.meta.update(solver_path=path.as_dict(),
+                     vector_len=int(ds.num_features),
+                     launches=launches, fetches=fetches, cold=cold)
+    if not quiet:
+        print(f"drive ladder: {launches} programs launched, "
+              f"{fetches} host fetches")
+        if cold:
+            print(f"cold path: {_tracing.cold_line(cold)}")
+    return state[0], state[1], traj
 
 
 def run_cocoa(

@@ -158,12 +158,12 @@ def run_dist_gd(
 
     step = make_round_step(mesh, params, k)
 
-    def round_fn(t, state):
+    def round_fn(t, c, state):
         (w,) = state
         return (step(w, jnp.asarray(float(t), dtype=dtype), shard_arrays),)
 
-    (w,), traj = base.drive(
+    (w,), traj = base.drive_chunked(
         "Dist SGD", params, debug, (w,), round_fn, eval_fn,
-        quiet=quiet, start_round=start_round,
+        quiet=quiet, start_round=start_round, chunk=1,
     )
     return w, traj

@@ -41,6 +41,7 @@ from cocoa_tpu.data.fleet import FleetDataset
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops.local_sdca import local_sdca, local_sdca_fast
 from cocoa_tpu.solvers import base
+from cocoa_tpu.solvers.cocoa import secant_jump
 from cocoa_tpu.telemetry import tracing as _tracing
 
 DRIVE_MODES = ("plain", "anneal", "accel")
@@ -175,7 +176,7 @@ def run_cocoa_fleet(
         "inv_n": jnp.asarray(np.float32(1.0)
                              / fleet.n.astype(np.float32)),
         # the accel jump's 1/(λn), host-f64 then cast — exactly the
-        # constant the solo accel_kernel bakes in
+        # constant the solo loop kernel bakes in
         "inv_lam_n": jnp.asarray((1.0 / lam_n64).astype(np.float32)),
     }
     tgts_np = np.where(np.isnan(fleet.gap_targets), -np.inf,
@@ -277,34 +278,14 @@ def run_cocoa_fleet(
             return (w2, a2, hist, sched.at[4].add(jnp.float32(c_len)))
 
         def jump_kernel(state, data, scal_t):
-            # the solo accel_kernel's chunk-head secant jump, lane-local
-            # (run through lax.map by the driver so its einsums lower
-            # exactly as the solo executable's — base._build_fleet_run):
-            # the jumped α is box-clipped and padding-masked, and w
-            # advances by the exact correspondence update, so the lane's
-            # (w, α) stays a feasible certified pair
+            # the solo loop's chunk-head secant jump, lane-local (run
+            # through lax.map by the driver so its einsums lower exactly
+            # as the solo executable's — base._build_fleet_run)
             w, alpha, hist, sched = state
-
-            @jax.named_scope(_tracing.SCOPE_ACCEL_JUMP)
-            def take_jump(w, alpha):
-                from cocoa_tpu.ops import rows as _rows
-
-                d1 = hist[1] - hist[0]
-                den = jnp.vdot(d1, d1)
-                rho = jnp.where(
-                    den > 0,
-                    jnp.vdot(d1, alpha - hist[1])
-                    / jnp.where(den > 0, den, jnp.float32(1)),
-                    jnp.float32(0))
-                cj = base.secant_coef(jnp, rho)
-                a_ext = jnp.clip(alpha + cj * (alpha - hist[1]),
-                                 0.0, 1.0) * data["mask"]
-                coefs = (data["labels"] * (a_ext - alpha)
-                         * scal_t["inv_lam_n"])
-                return _rows.shards_axpy(coefs, data, w), a_ext
-
             w, alpha = jax.lax.cond(
-                sched[base.A_JUMP] > 0, take_jump,
+                sched[base.A_JUMP] > 0,
+                lambda w, a: secant_jump(w, a, hist, data, None,
+                                         scal_t["inv_lam_n"]),
                 lambda w, a: (w, a), w, alpha)
             return (w, alpha, hist,
                     sched.at[base.A_JUMP].set(jnp.float32(0)))

@@ -202,13 +202,13 @@ def run_sgd(
 
     step = make_round_step(mesh, params, k, local)
 
-    def round_fn(t, state):
+    def round_fn(t, c, state):
         (w,) = state
         idxs = sampler.round_indices(t)
         return (step(w, idxs, jnp.asarray(float(t), dtype=dtype), shard_arrays),)
 
-    (w,), traj = base.drive(
+    (w,), traj = base.drive_chunked(
         name, params, debug, (w,), round_fn, eval_fn,
-        quiet=quiet, start_round=start_round,
+        quiet=quiet, start_round=start_round, chunk=1,
     )
     return w, traj

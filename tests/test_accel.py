@@ -20,14 +20,14 @@ What these tests pin:
 - ``--accel=off`` is BIT-IDENTICAL to the pre-acceleration code across
   all three drive modes (per-round, host-chunked, device loop);
 - the host-chunked and device-loop accelerated drivers make identical
-  decisions and produce identical states (accel_host_step is the device
-  loop's f32 bit-twin);
+  decisions and produce identical states (both run
+  base.eval_boundary_update, NumPy on the host, traced on the device);
 - a mid-momentum checkpoint resume (hist leaf + extended sched slots) is
   bit-identical to the uninterrupted run;
 - the typed ``momentum_restart`` / ``theta_stage`` events flow through
   the bus identically on the host and device paths, and the sched-leaf
   accel machinery (bank/arm/jump rule, Θ ladder, restart action)
-  matches its host twin slot for slot;
+  is pinned slot for slot, and at np, at jnp and at a (T,) batch alike;
 - the flag surface validations.
 """
 
@@ -73,6 +73,19 @@ def _run(ds, n, accel=None, theta=None, num_rounds=100, lam=1e-2,
 # --- unit: the schedule arithmetic ------------------------------------------
 
 
+def _boundary(s, gap, n_theta, tgt, n_stages=0, stall_evals=3, done=False,
+              xp=np):
+    """One eval boundary through base.eval_boundary_update: (new sched
+    leaf, the update)."""
+    s = xp.asarray(s, xp.float32)
+    upd = base.eval_boundary_update(
+        xp, s, xp.asarray(gap, xp.float32), xp.asarray(done),
+        stall_evals=stall_evals, n_stages=n_stages, n_theta=n_theta, tgt=tgt)
+    head = s[..., :base.SCHED_LEN] if upd.head is None else upd.head
+    return xp.concatenate(
+        [head, *(() if upd.tail is None else (upd.tail,))], axis=-1), upd
+
+
 def test_theta_ladder():
     assert base.theta_ladder(253, False) == (253,)
     # the ladder starts at H/2 — an H/4 rung was measured to COST rounds
@@ -84,48 +97,49 @@ def test_theta_ladder():
     assert base.theta_ladder(1, True) == (1,)
 
 
-def test_sched_init_array_accel_shapes():
-    s = np.asarray(base.sched_init_array(7, accel=True))
+def test_sched_init_values_accel_shapes():
+    s = base.sched_init_values(7, accel=True)
     assert s.shape == (base.SCHED_LEN + base.ACCEL_LEN,)
     assert s[4] == 7.0
     assert s[base.A_HIST] == 0.0 and s[base.A_JUMP] == 0.0
     assert np.isinf(s[base.A_LASTGAP]) and s[base.A_RESTARTS] == 0.0
     # a plain (5,) restore under accel gains fresh accel slots
-    plain = np.asarray(base.sched_init_array(3))
-    ext = np.asarray(base.sched_init_array(3, sched_init=plain, accel=True))
+    plain = base.sched_init_values(3)
+    ext = base.sched_init_values(3, sched_init=plain,
+                                 accel=True)
     np.testing.assert_array_equal(ext[:base.SCHED_LEN], plain)
     assert ext.shape == (base.SCHED_LEN + base.ACCEL_LEN,)
     # an accel-length restore WITHOUT accel keeps its σ′ head
-    back = np.asarray(base.sched_init_array(3, sched_init=ext))
+    back = base.sched_init_values(3, sched_init=ext)
     np.testing.assert_array_equal(back, plain)
     with pytest.raises(ValueError, match="shape"):
-        base.sched_init_array(1, sched_init=np.zeros(9, np.float32))
+        base.sched_init_values(1, sched_init=np.zeros(9, np.float32))
 
 
-def test_accel_host_step_bank_arm_restart():
+def test_eval_boundary_bank_arm_restart():
     """The window bookkeeping: improving evals BANK α snapshots; two
     banked windows ARM the jump for the next chunk head (and freeze the
     bank); a gap RISE discards the bank (restarts += 1, the bank
     restarts from this eval's α).  All exact f32 arithmetic."""
-    s = np.asarray(base.sched_init_array(1, accel=True))
+    s = base.sched_init_values(1, accel=True)
     # first eval: last_gap is inf — bank one window
-    s, restarted, staged = base.accel_host_step(s, 1.0, 1, None)
-    assert not restarted and s[base.A_HIST] == 1.0
-    assert s[base.A_JUMP] == 0.0
+    s, upd = _boundary(s, 1.0, 1, None)
+    assert not upd.restarted and not upd.staged and s[base.A_HIST] == 1.0
+    assert s[base.A_JUMP] == 0.0 and upd.push
     assert s[base.A_LASTGAP] == np.float32(1.0)
     # second improving eval: two windows banked
-    s, restarted, _ = base.accel_host_step(s, 0.5, 1, None)
-    assert not restarted and s[base.A_HIST] == 2.0
+    s, upd = _boundary(s, 0.5, 1, None)
+    assert not upd.restarted and s[base.A_HIST] == 2.0
     assert s[base.A_JUMP] == 0.0
     # third improving eval: the jump ARMS and the bank is consumed
-    s, restarted, _ = base.accel_host_step(s, 0.25, 1, None)
-    assert not restarted
+    s, upd = _boundary(s, 0.25, 1, None)
+    assert not upd.restarted and not upd.push
     assert s[base.A_JUMP] == 1.0 and s[base.A_HIST] == 0.0
     # the chunk head clears the armed flag when it takes the jump
     s[base.A_JUMP] = 0.0
     # a RISE restarts: bank discarded, restarted from this eval's α
-    s, restarted, _ = base.accel_host_step(s, 0.6, 1, None)
-    assert restarted and s[base.A_HIST] == 1.0
+    s, upd = _boundary(s, 0.6, 1, None)
+    assert upd.restarted and upd.push and s[base.A_HIST] == 1.0
     assert s[base.A_JUMP] == 0.0 and s[base.A_RESTARTS] == 1.0
 
 
@@ -148,26 +162,109 @@ def test_secant_coef():
         np.float32(base.ACCEL_CMIN)
 
 
-def test_accel_host_step_theta_ladder_advance():
+def test_eval_boundary_theta_ladder_advance():
     """Θ advances on the halve-per-eval stall watch, jumps to the final
     stage near the target, and is inert at the last rung."""
     tgt = 1e-4
-    s = np.asarray(base.sched_init_array(1, accel=True))
+    s = base.sched_init_values(1, accel=True)
     # fast-decay phase: gap halves every eval — the loose stage holds
-    s, _, staged = base.accel_host_step(s, 8.0, 3, tgt)
-    assert not staged and s[base.A_TH_STAGE] == 0.0
-    s, _, staged = base.accel_host_step(s, 3.0, 3, tgt)
-    assert not staged
+    s, upd = _boundary(s, 8.0, 3, tgt)
+    assert not upd.staged and s[base.A_TH_STAGE] == 0.0
+    s, upd = _boundary(s, 3.0, 3, tgt)
+    assert not upd.staged
     # decay slows below 2x/eval -> one miss fires the watch
-    s, _, staged = base.accel_host_step(s, 2.0, 3, tgt)
-    assert staged and s[base.A_TH_STAGE] == 1.0
+    s, upd = _boundary(s, 2.0, 3, tgt)
+    assert upd.staged and s[base.A_TH_STAGE] == 1.0
     assert s[base.A_TH_STALL] == 0.0 and np.isinf(s[base.A_TH_BEST])
     # near the target: jump straight to the final stage
-    s, _, staged = base.accel_host_step(s, 9e-4, 3, tgt)
-    assert staged and s[base.A_TH_STAGE] == 2.0
+    s, upd = _boundary(s, 9e-4, 3, tgt)
+    assert upd.staged and s[base.A_TH_STAGE] == 2.0
     # final rung: the ladder is inert
-    s, _, staged = base.accel_host_step(s, 8.9e-4, 3, tgt)
-    assert not staged and s[base.A_TH_STAGE] == 2.0
+    s, upd = _boundary(s, 8.9e-4, 3, tgt)
+    assert not upd.staged and s[base.A_TH_STAGE] == 2.0
+
+
+# One fixed sequence of gaps through the ONE function, crossing everything
+# it decides: an arm (eval 2), a restart with a Θ stage by a missed halving
+# (4), a second restart (5), a σ′ back-off — three evals without the watch's
+# best improving — that is also a seam under a bank of two (6), Θ's
+# near-target jump (7), a target hit (8: counted, nothing acted on).
+_GAPS = [1.0, 0.4, 0.19, 0.09, 0.3, 0.35, 0.3, 9e-4, 5e-5]
+_KW = dict(n_theta=4, tgt=1e-4, n_stages=3, stall_evals=3)
+
+
+def _walk(xp, gaps, **kw):
+    s = xp.asarray(base.sched_init_values(1, accel=True))
+    if np.ndim(gaps[0]):
+        s = xp.stack([s] * len(gaps[0]))
+    out, flags = [], []
+    for g in gaps:
+        s, upd = _boundary(s, g, done=np.asarray(g) <= kw["tgt"], xp=xp,
+                           **kw)
+        out.append(np.asarray(s))
+        flags.append(tuple(np.asarray(f).tolist() for f in (
+            upd.push, upd.backed, upd.restarted, upd.staged)))
+        # what the chunk head does to an armed jump
+        s = xp.asarray(np.where(
+            np.arange(s.shape[-1]) == base.A_JUMP, 0.0, np.asarray(s)),
+            xp.float32)
+    return out, flags
+
+
+@pytest.mark.parametrize("how", ["np", "jnp", "jit", "batch"])
+def test_eval_boundary_update_one_arithmetic(how):
+    """NumPy on the host, jnp eager, jnp under jit (the device loop's) and
+    a (T,) batch (the fleet's) against T scalar calls: equal float32 fields
+    after every eval of the sequence — the property the host twins' tests
+    only sampled — and the sequence does cross every decision."""
+    want, flags = _walk(np, _GAPS, **_KW)
+    # (push, backed, restarted, staged) per eval
+    assert flags == [
+        (True, False, False, False), (True, False, False, False),
+        (False, False, False, False),        # two windows banked: armed
+        (True, False, False, False),
+        (True, False, True, True),           # rose: restart; Θ: no halving
+        (True, False, True, False),
+        (True, True, False, True),           # σ′ backs off (and Θ steps)
+        (True, False, False, True),          # near the target: Θ to full H
+        (False, False, False, False)]        # hit: counted, nothing acted on
+    assert want[2][base.A_JUMP] == 1.0 and want[2][base.A_HIST] == 0.0
+    assert want[5][base.A_RESTARTS] == 2.0
+    # the seam: a bank of one plus this eval's α, capped back to one
+    assert want[5][base.A_HIST] == want[6][base.A_HIST] == 1.0
+    assert want[6][0] == 1.0 and want[6][1] == 0.0 and np.isinf(want[6][2])
+    assert [w[base.A_TH_STAGE] for w in want[3:8]] == [0, 1, 1, 2, 3]
+    np.testing.assert_array_equal(want[8][base.A_HIST:base.A_TH_STAGE + 1],
+                                  want[7][base.A_HIST:base.A_TH_STAGE + 1])
+    assert all(w.dtype == np.float32 for w in want)
+    if how == "np":
+        return
+    if how == "batch":
+        # lane t runs the sequence from its t-th eval on (the tail padded
+        # with the last gap): a batch of lanes in different states
+        lanes = [_GAPS[t:] + [_GAPS[-1]] * t for t in range(4)]
+        got, _ = _walk(jnp, [np.float32(g) for g in zip(*lanes)], **_KW)
+        solo = [_walk(np, lane, **_KW)[0] for lane in lanes]
+        for e, rows in enumerate(got):
+            np.testing.assert_array_equal(
+                rows, np.stack([solo[t][e] for t in range(4)]))
+        return
+    if how == "jit":
+        import jax
+
+        step = jax.jit(lambda s, g, d: _boundary(s, g, done=d, xp=jnp,
+                                                 **_KW)[0])
+        s = base.sched_init_values(1, accel=True)
+        for e, g in enumerate(_GAPS):
+            s = np.array(step(s, np.float32(g), g <= _KW["tgt"]))
+            np.testing.assert_array_equal(s, want[e])
+            s[base.A_JUMP] = 0.0
+        return
+    got, got_flags = _walk(jnp, _GAPS, **_KW)
+    assert got_flags == flags
+    for a, b in zip(got, want):
+        assert a.dtype == np.float32
+        np.testing.assert_array_equal(a, b)
 
 
 # --- accel=off is the pre-acceleration code, bit for bit --------------------
@@ -568,20 +665,24 @@ def test_theta_adaptive_degrades_when_accel_auto_resolves_off():
                   gap_target=1e-6, accel="off", theta="adaptive")
 
 
-def test_accel_host_step_sigma_seam_caps_bank():
+def test_eval_boundary_sigma_seam_caps_bank():
     """A σ′ anneal backoff at the same eval boundary is a round-map seam
     exactly like a Θ stage advance: the secant bank caps at the α just
     banked, and an already-armed jump stays armed."""
-    sched = np.array(base.sched_init_array(1, accel=True), dtype=np.float32)
+    sched = base.sched_init_values(1, accel=True)
     sched[base.A_LASTGAP] = np.float32(1.0)
     sched[base.A_HIST] = np.float32(1.0)
+    # the σ′ watch one eval short of its window, its best under this gap
+    sched[1:4] = (2.0, 0.1, 0.1)
     # improving eval + seam: would bank to 2, capped back to 1
-    s, restarted, _ = base.accel_host_step(sched, 0.5, 1, 1e-6, seam=True)
-    assert not restarted and s[base.A_HIST] == np.float32(1.0)
+    s, upd = _boundary(sched, 0.5, 1, 1e-6, n_stages=2)
+    assert upd.backed and s[0] == 1.0
+    assert not upd.restarted and s[base.A_HIST] == np.float32(1.0)
     assert s[base.A_JUMP] == np.float32(0.0)
     # armed jump survives the seam (hist already 0 after arming)
     sched[base.A_HIST] = np.float32(2.0)
     sched[base.A_LASTGAP] = np.float32(1.0)
-    s, _, _ = base.accel_host_step(sched, 0.5, 1, 1e-6, seam=True)
+    s, upd = _boundary(sched, 0.5, 1, 1e-6, n_stages=2)
+    assert upd.backed
     assert s[base.A_JUMP] == np.float32(1.0)
     assert s[base.A_HIST] == np.float32(0.0)

@@ -12,6 +12,7 @@ handed in, whether there is a mesh — must not change a bit of the result.
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -208,3 +209,124 @@ def test_fetch_helper_reads_once_and_cuts_on_the_host():
     assert head.tolist() == [6] and rows.shape == (6, 4)
     assert isinstance(rows, np.ndarray)
     assert sanitize.intended_fetches_total - before == 2
+
+
+# --- the loop builder (solvers/cocoa.build_sdca_loop) ------------------------
+
+_WARM = dataclasses.replace(
+    Params(n=64, num_rounds=40, local_iters=12, lam=1e-2),
+    loss="smooth_hinge", smoothing=0.5)
+# an arm's static description -> the table it must make, in branch-index
+# order (stage . n_phases + phase) . n_theta + theta
+_DESCRIPTIONS = {
+    "plain": dict(levels=(4.0,), phases=1, theta_hs=(12,), bank=False),
+    "sched": dict(levels=(1.0, 2.0, 4.0), phases=1, theta_hs=(12,),
+                  bank=False),
+    "sched_warm": dict(levels=(4.0,), phases=2, theta_hs=(12,), bank=False),
+    "accel": dict(levels=(4.0,), phases=1, theta_hs=(12,), bank=True),
+    "accel_warm_theta": dict(levels=(2.0, 4.0), phases=2, theta_hs=(6, 12),
+                             bank=True),
+}
+
+
+@pytest.mark.parametrize("arm", list(_DESCRIPTIONS))
+def test_builder_table_is_levels_x_phases_x_theta(tiny_data, arm,
+                                                  monkeypatch):
+    """One branch table for every arm: ``len(levels) . n_phases . n_theta``
+    chunk kernels, made in the order the one branch index reads them, a Θ
+    stage at its own H; the plain arm's jitted step takes and returns
+    exactly (w, α), every other arm's the leaves it was handed."""
+    from cocoa_tpu.solvers import cocoa
+
+    d = _DESCRIPTIONS[arm]
+    ds = shard_dataset(tiny_data, k=K, layout="dense", dtype=jnp.float32)
+    params = dataclasses.replace(_WARM, n=tiny_data.n, loss="hinge",
+                                 smoothing=0.0)
+    phases = [dataclasses.replace(_WARM, n=tiny_data.n), params][-d["phases"]:]
+    made, real = [], cocoa._make_chunk_kernel
+
+    def counting(mesh, p, k, alg, **kw):
+        made.append((alg[2], p.loss, p.local_iters))
+        return real(mesh, p, k, alg, **kw)
+
+    monkeypatch.setattr(cocoa, "_make_chunk_kernel", counting)
+    sampler = base.IndexSampler("permuted", 0, 12, ds.counts, device=True)
+    kernel, step, token = cocoa.build_sdca_loop(
+        None, params, K, ("plus", 1.0, d["levels"][0]), sampler,
+        dict(math="fast"), levels=d["levels"], branch_params=phases,
+        theta_hs=d["theta_hs"], warm_end=10 * (d["phases"] - 1),
+        bank=d["bank"])
+    assert made == [(lv, p.loss, hs) for lv in d["levels"] for p in phases
+                    for hs in d["theta_hs"]]
+    assert (token is None) == (arm == "plain")
+
+    state = [jnp.zeros(ds.num_features, jnp.float32),
+             jnp.zeros((K, ds.n_shard), jnp.float32)]
+    if d["bank"]:
+        state.append(jnp.zeros((2, K, ds.n_shard), jnp.float32))
+    if arm != "plain":
+        state.append(jnp.asarray(
+            base.sched_init_values(1, accel=d["bank"])))
+    idxs = sampler.chunk_indices(1, 5)
+    out = jax.eval_shape(step, *state, idxs, ds.shard_arrays())
+    assert [(o.shape, o.dtype) for o in out] == [(s.shape, s.dtype)
+                                                 for s in state]
+    assert jax.eval_shape(kernel, tuple(state), idxs,
+                          ds.shard_arrays()) == out
+    if arm == "plain":
+        assert len(out) == 2
+        with pytest.raises(TypeError):      # (w, α) and nothing else
+            jax.eval_shape(step, *state, state[1], idxs, ds.shard_arrays())
+
+
+@pytest.mark.parametrize("arm", ["plain", "accel", "sched"])
+@pytest.mark.parametrize("loop", ["device_loop", "host_stepped"])
+def test_equal_jobs_share_one_step_and_one_loop(tiny_data, arm, loop):
+    """Two jobs with equal arguments: one entry of the step cache and one
+    of the loop cache, and the second compiles nothing."""
+    from cocoa_tpu.analysis import sanitize
+    from cocoa_tpu.solvers import cocoa
+
+    job, _ = _svm_job(tiny_data, None, arm, dtype=jnp.float32)
+    kw = {} if loop == "device_loop" else dict(device_loop=False,
+                                               scan_chunk=1)
+    first = job(**kw)
+    cached = len(cocoa._CHUNK_STEPS), len(base._DEVICE_RUNS)
+    with sanitize.watch_compiles() as compiles:
+        again = job(**kw)
+    assert (len(cocoa._CHUNK_STEPS), len(base._DEVICE_RUNS)) == cached
+    assert [c.name for c in compiles] == []
+    _same(first, again)
+
+
+@pytest.mark.parametrize("arm", ["plain", "accel", "sched"])
+def test_host_stepped_counts(tiny_data, arm):
+    """What the ONE host-stepped driver counts, at chunk = 1 as at the
+    eval cadence: a launch a chunk and one an eval beside the start
+    program's, one sanctioned read an eval, and the device loop's
+    trajectory."""
+    job, _ = _svm_job(tiny_data, None, arm)
+    # (a target out of reach: the sched arm needs one, and every round runs)
+    looped = job(gap_target=1e-12)
+    one = job(gap_target=1e-12, device_loop=False, scan_chunk=1)
+    five = job(gap_target=1e-12, device_loop=False, scan_chunk=5)
+    assert one[1].meta["launches"] == 1 + 40 + 8
+    assert five[1].meta["launches"] == 1 + 8 + 8
+    assert one[1].meta["fetches"] == five[1].meta["fetches"] == 8
+    _same(one, five)
+    _same(one, looped)
+
+
+def test_per_round_driver_is_the_chunked_one_at_chunk_1(tiny_data):
+    """No ``scan_chunk`` and no device loop: the per-round program under
+    the chunked driver — the same counts as the chunk program at chunk = 1
+    and the same records (the two programs round alike here)."""
+    job, _ = _svm_job(tiny_data, None)
+    per_round = job(gap_target=None, device_loop=False)
+    chunked = job(gap_target=None, device_loop=False, scan_chunk=1)
+    assert per_round[1].meta["launches"] == chunked[1].meta["launches"] \
+        == 1 + 40 + 8
+    assert per_round[1].meta["solver_path"] == chunked[1].meta["solver_path"]
+    assert _records(per_round[1])[1] == _records(chunked[1])[1]
+    assert [r[0] for r in _records(per_round[1])[0]] == \
+        [r[0] for r in _records(chunked[1])[0]]

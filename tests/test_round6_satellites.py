@@ -99,10 +99,10 @@ def test_drive_guard_off_runs_full_budget(monkeypatch):
 
     def run(guard):
         state = (jnp.zeros(4),)
-        traj = base.drive(
-            "t", params, debug, state, lambda t, s: s,
+        traj = base.drive_chunked(
+            "t", params, debug, state, lambda t, c, s: s,
             lambda s: (1.0, 1.0, None),   # constant gap: pure stall
-            quiet=True, gap_target=1e-6, divergence_guard=guard,
+            quiet=True, gap_target=1e-6, divergence_guard=guard, chunk=1,
         )[1]
         return traj
 

@@ -75,18 +75,23 @@ def test_anneal_levels_ladder():
     assert all(a < b for a, b in zip(lv, lv[1:]))
 
 
-def test_sched_host_step_is_gapwatch_twin():
+def _sigma_step(s, gap, stall_evals, n_stages):
+    upd = base.eval_boundary_update(
+        np, s, np.float32(gap), False, stall_evals=stall_evals,
+        n_stages=n_stages, n_theta=0, tgt=None)
+    return upd.head, bool(upd.backed)
+
+
+def test_eval_boundary_update_is_gapwatch():
     """Same windowed no-improvement semantics as base._GapWatch, plus the
-    backoff action (stage += 1, fresh watch) instead of a bail-out.  (The
-    twin matches the DEVICE watch bit-for-bit — NaN/None gaps map to +inf
-    like the in-loop code, a policy only primal-only evals ever see; the
-    anneal paths always have a real gap.)"""
-    s = base.sched_init_array(1)
-    s = np.asarray(s)
+    backoff action (stage += 1, fresh watch) instead of a bail-out.  (NaN /
+    None gaps reach it as +inf, as in the in-loop code, a policy only
+    primal-only evals ever see; the anneal paths always have a real gap.)"""
+    s = base.sched_init_values(1)
     seq = [1.0, 0.9, 0.7, 5.0, 0.6, 0.55]
     fires = []
     for g in seq:
-        s, backed = base.sched_host_step(s, g, stall_evals=3, n_stages=2)
+        s, backed = _sigma_step(s, g, stall_evals=3, n_stages=2)
         fires.append(backed)
     # the _GapWatch fixture from test_divergence: reset at 0.7, then three
     # straight non-improving evals fire the window
@@ -94,7 +99,7 @@ def test_sched_host_step_is_gapwatch_twin():
     assert s[0] == 1.0 and s[1] == 0.0 and np.isinf(s[2]) and np.isinf(s[3])
     # at the last stage the watch is inert: it never "fires" again
     for g in (0.55, 0.55, 0.55, 0.55, 0.55):
-        s, backed = base.sched_host_step(s, g, stall_evals=3, n_stages=2)
+        s, backed = _sigma_step(s, g, stall_evals=3, n_stages=2)
         assert not backed
     assert s[0] == 1.0
 

@@ -166,7 +166,7 @@ def test_telemetry_on_vs_off_state_bit_identical(tmp_path):
 
 def test_host_and_device_paths_emit_identical_streams():
     """The host-chunked twin makes identical schedule decisions
-    (sched_host_step is the device watch's bit-twin), so the two paths'
+    (both run base.eval_boundary_update), so the two paths'
     event streams must agree on every value the math determines."""
     ev_host = _collect()
     _backoff_run(device_loop=False)
