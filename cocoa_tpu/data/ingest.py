@@ -404,7 +404,8 @@ def _stream_build(
     width = 0
     if layout == "sparse":
         width = int(max_nnz if max_nnz is not None
-                    else max(1, index.row_nnz.max(initial=1)))
+                    else sharding_lib.rectangle_width(
+                        index.row_nnz.max(initial=1)))
         if n and int(index.row_nnz.max(initial=0)) > width:
             raise ValueError(
                 f"row nnz {int(index.row_nnz.max())} exceeds max_nnz "
@@ -568,6 +569,7 @@ def _stream_build(
             arrs["hot_cols"] = np.tile(hc[None], (k, 1))
         ds = sharding_lib._finalize_replicated(
             arrs, layout=layout, n=n, d=d_eff, mesh=mesh, sizes=sizes)
+    sharding_lib.note_row_lengths(ds, index.row_nnz)
 
     parse_seconds = time.perf_counter() - t0
     status = "off"
@@ -629,7 +631,7 @@ def load_cached_dataset(handle, stats, k, *, layout: str, dtype,
             width = max(1, resid_max)
             hot_ids = hybrid_lib.hottest_columns(stats.hist, hot_cols)
         else:
-            width = max(1, int(stats.max_row_nnz))
+            width = sharding_lib.rectangle_width(stats.max_row_nnz)
     d_eff = mesh_lib.pad_features(d, mesh) if layout == "dense" else d
     view = handle.view(layout=layout, k=k, n_shard=n_shard, width=width,
                        n_hot=hot_cols, d=d_eff, dtype=np_dtype,
@@ -669,6 +671,7 @@ def load_cached_dataset(handle, stats, k, *, layout: str, dtype,
             arrs["hot_cols"] = np.tile(hc[None], (k, 1))
         ds = sharding_lib._finalize_replicated(
             arrs, layout=layout, n=n, d=d_eff, mesh=mesh, sizes=sizes)
+    sharding_lib.note_row_lengths(ds, longest=stats.max_row_nnz)
     info = StreamBuildInfo(
         rows=0, nnz=0, bytes_read=0,
         parse_seconds=time.perf_counter() - t0,

@@ -40,6 +40,42 @@ def pad_rows(n_rows: int) -> int:
     return -(-n_rows // 16) * 16
 
 
+RECTANGLE_GROUP = 8              # slots a group: the sublanes of a tile, and
+                                 # ops/rows.SLOT_GROUP
+
+
+def rectangle_width(longest: int) -> int:
+    """Slots a row of the padded-CSR rectangle (K, n_shard, W) takes where
+    the longest row has ``longest`` nonzeros: a whole number of 8-slot
+    groups (the slots past a row hold column 0, value 0, as ever).  The
+    block passes walk a row's slots eight at a time (ops/rows.SLOT_GROUP),
+    and a TPU stores a rectangle with the row index on the lanes and the
+    slots on the sublanes only where W is whole sublane tiles: at K = 8 and
+    W = 39 it puts K on the sublanes instead (0% padding against 39 -> 40),
+    and every program that reads (W, 128) tiles of rows, the row fetch of
+    ops/pallas_sparse_hbm.py, then opens with a copy of the whole dataset
+    (3.99 GB of temporaries at a quarter of criteo; tests/
+    test_device_layout.py).  An explicit ``max_nnz`` is kept as given."""
+    return -(-max(1, int(longest)) // RECTANGLE_GROUP) * RECTANGLE_GROUP
+
+
+def note_row_lengths(ds: "ShardedDataset", row_nnz=(),
+                     longest=None) -> "ShardedDataset":
+    """Keep on a rectangle what its loader saw of its real rows' lengths on
+    the host (``row_nnz``: their counts of nonzeros; or the ``longest``
+    alone, from a loader that kept no more): the longest, which the width
+    no longer says and a run's record reports (``SolverPath.longest_row``)
+    before anything has read the lengths on the device; and whether they
+    are all that long, which :func:`passes_want_order` asks."""
+    if ds.layout == "sparse" and ds.sp_row_ptr is None and not ds.n_hot:
+        row_nnz = np.asarray(row_nnz).reshape(-1)
+        ds._longest_row = int(row_nnz.max(initial=0) if longest is None
+                              else longest)
+        ds._one_length = bool(row_nnz.size) and \
+            0 < int(row_nnz.min()) == ds._longest_row
+    return ds
+
+
 def resolve_layout_stats(n: int, d: int, nnz: int, layout: str,
                          mesh=None) -> str:
     """The one place the ``layout="auto"`` rule lives, from dataset
@@ -455,15 +491,27 @@ def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
     return ds
 
 
+def rows_of_one_length(ds: "ShardedDataset") -> bool:
+    """Whether every row of a padded-CSR rectangle has the same count of
+    nonzeros (click logs: one nonzero a field), as its loader noted from
+    the lengths it saw on the host (:func:`note_row_lengths`); a dataset
+    nobody noted anything on answers no and is ordered as ever."""
+    return bool(getattr(ds, "_one_length", False))
+
+
 def passes_want_order(ds: "ShardedDataset") -> bool:
     """Where :func:`order_rows_by_length` moves a number and has not run: a
     sparse dataset whose all-rows passes run in row blocks
-    (ops/rows.row_block, from the shapes alone)."""
+    (ops/rows.row_block, from the shapes alone) and whose rows differ in
+    length — rows of one length have nothing to order: every block's
+    longest row is every row, the stable sort is the identity, and it
+    would relay the whole rectangle to say so."""
     from cocoa_tpu.ops import rows
 
     return (ds.layout == "sparse" and ds.row_order is None
             and ds.sp_row_ptr is None and rows.row_block(
-            ds.n_shard, ds.sp_indices.shape[-1]) < ds.n_shard)
+            ds.n_shard, ds.sp_indices.shape[-1]) < ds.n_shard
+            and not rows_of_one_length(ds))
 
 
 def order_rows_for_passes(ds: "ShardedDataset") -> "ShardedDataset":
@@ -761,7 +809,7 @@ def shard_dataset(
     width = 0
     if layout == "sparse":
         width = int(max_nnz if max_nnz is not None
-                    else max(1, row_nnz.max(initial=1)))
+                    else rectangle_width(row_nnz.max(initial=1)))
         if n and int(row_nnz.max(initial=0)) > width:
             raise ValueError(
                 f"row nnz {int(row_nnz.max())} exceeds max_nnz {width}"
@@ -843,15 +891,16 @@ def shard_dataset(
                 f"{mesh.devices.size} devices"
             )
         d_eff = mesh_lib.pad_features(d, mesh) if layout == "dense" else d
-        return order_rows_for_passes(_shard_dataset_distributed(
-            data, k, layout, np_dtype, mesh, sizes, offsets, n_shard,
-            # mirror the replicated path: only the dense layout pads d
-            d_eff,
-            width, row_nnz, row_sq, rank=rank, n_hot=n_hot,
-            hot_ids=hot_ids, eval_dense=eval_dense,
-            cache_view=_slab_view(cache, layout, k, n_shard, width,
-                                  n_hot, d_eff, np_dtype, eval_dense),
-        ))
+        return order_rows_for_passes(note_row_lengths(
+            _shard_dataset_distributed(
+                data, k, layout, np_dtype, mesh, sizes, offsets, n_shard,
+                # mirror the replicated path: only the dense layout pads d
+                d_eff,
+                width, row_nnz, row_sq, rank=rank, n_hot=n_hot,
+                hot_ids=hot_ids, eval_dense=eval_dense,
+                cache_view=_slab_view(cache, layout, k, n_shard, width,
+                                      n_hot, d_eff, np_dtype, eval_dense),
+            ), row_nnz))
 
     if layout == "dense":
         d = mesh_lib.pad_features(d, mesh)
@@ -883,9 +932,9 @@ def shard_dataset(
         for s in range(k):
             arrs["classes"][s, :sizes[s]] = \
                 data.classes[offsets[s]:offsets[s + 1]]
-    return order_rows_for_passes(_finalize_replicated(
+    return order_rows_for_passes(note_row_lengths(_finalize_replicated(
         arrs, layout=layout, n=n, d=d, mesh=mesh, sizes=sizes,
-        num_classes=num_classes))
+        num_classes=num_classes), row_nnz))
 
 
 def _finalize_replicated(arrs, *, layout, n, d, mesh, sizes, num_classes=1

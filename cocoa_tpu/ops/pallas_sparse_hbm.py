@@ -137,7 +137,13 @@ def hbm_smem_estimate(chunk: int, w_r: int) -> int:
 def rows_on_lanes(n_shard: int, max_nnz: int) -> bool:
     """Whether a TPU stores (K, n_shard, W) with the row index on the lanes:
     it takes the dimension order that pads least under the (8, 128) tile
-    (tests/test_device_layout.py)."""
+    (tests/test_device_layout.py).  Of the two orders that put W on a tile
+    axis; there is a third, K on the sublanes, which pads nothing where K
+    is a multiple of 8 and wins wherever W is not: the loader keeps W whole
+    sublane tiles (data/sharding.rectangle_width), so a rectangle it built
+    never meets it; one forced to another width (``max_nnz=39`` at K = 8)
+    does, and the fetch below then reads it through a copy of all of
+    it."""
     pad = lambda n, to: -(-n // to) * to / n  # noqa: E731
     return pad(n_shard, LANES) * pad(max_nnz, 8) \
         < pad(max_nnz, LANES) * pad(n_shard, 8)

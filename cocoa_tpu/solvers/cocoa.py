@@ -254,6 +254,16 @@ class SolverPath:
     (ops/pallas_sdca._ring_steps; the interleaved and the class kernel);
     ``pipelined``: as a BlockSpec operand of Pallas's grid pipeline, one
     step ahead (the shard-major kernel; ``ring_depth`` None).
+    ``local_ids``, ``segments``, ``table_width`` (the HBM-state sparse
+    kernel only, ops/pallas_sparse_hbm.HbmPlan; None anywhere else): which
+    plan a shard's round runs — ``direct``: the local id IS the column, [w
+    | Δw] whole in VMEM for the round, no sorts and no per-column gathers
+    (M = d fits the budget: d = 10⁶); ``sorted``: a segment's distinct
+    columns ranked by two sorts, w and Δw gathered and scattered once a
+    column (kddb); ``segments`` the pieces a shard's round is cut into
+    (``plan.t``), ``table_width`` the slots a step takes in the chain's
+    SMEM tables (``plan.w_r``: whole 32-slot groups, 64 for rows of 39: the
+    chain walks its groups as far as the row's length reaches).
     ``row_align`` (None where there is no fold cache: ``fori`` and every
     sparse path): how the fold cache comes to be the row-major rows of
     whole lane tiles those kernels read — ``stored``: it is kept so
@@ -287,6 +297,9 @@ class SolverPath:
     row_fetch: Optional[str] = None
     ring_depth: Optional[int] = None
     row_align: Optional[str] = None
+    local_ids: Optional[str] = None
+    segments: Optional[int] = None
+    table_width: Optional[int] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -329,6 +342,9 @@ class SolverPath:
                     + (f" ({how})" if self.kernel == "pallas" else "")
                     + (", state in HBM" if self.pallas
                        and self.state == "hbm" else "")
+                    + (f" ({self.local_ids} local ids, {self.segments} "
+                       f"segment(s) a shard, tables {self.table_width} "
+                       f"slots wide)" if self.local_ids else "")
                     + (f" [{self.refused}]" if self.refused else ""))
         rows = (", rows stored row-major" if self.rows == "row_major"
                 else ", rows relaid by a kernel once a dispatch"
@@ -372,8 +388,8 @@ def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
     """:attr:`SolverPath.pass_slot_share` of a sparse dataset, counted once
     from the row lengths it carries (``_row_len_cache``: attached by the
     ordering or by an earlier run) and kept on it beside them."""
-    if ds.sp_row_ptr is not None:
-        return 1.0
+    if ds.sp_row_ptr is not None or getattr(ds, "row_order", None) is None:
+        return 1.0              # (rows as built: the passes see no lengths)
     width = int(ds.sp_indices.shape[-1])
     row_len = getattr(ds, "_row_len_cache", None)
     if (not isinstance(row_len, jax.Array)      # none, or a shape alone
@@ -397,8 +413,9 @@ def _slot_stats(ds: ShardedDataset) -> tuple:
                                                    None)
     widest = int(ds.sp_row_iota.shape[-1] if stream
                  else ds.sp_indices.shape[-1])
-    if not isinstance(row_len, jax.Array):      # none, or a shape alone
-        return None, widest, None
+    if not isinstance(row_len, jax.Array):      # none, or a shape alone:
+        # what the loader saw (data/sharding.note_row_lengths), else W
+        return None, int(getattr(ds, "_longest_row", widest)), None
     cached = getattr(ds, "_slot_stats_cache", None)
     if cached is None:
         lens = np.asarray(row_len, np.int64)
@@ -596,6 +613,13 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             placement.update(
                 classes=classes,
                 lane_fill=classes / class_rows(classes) if pallas else None)
+        if pallas and hbm_state:
+            from cocoa_tpu.ops.pallas_sparse_hbm import hbm_plan
+
+            plan = hbm_plan(ds.num_features, width, local_iters, itemsize)
+            placement.update(
+                local_ids="direct" if plan.direct else "sorted",
+                segments=plan.t, table_width=plan.w_r)
         form = depth = None
         if pallas and not sparse:
             # the dense kernel's form and its ring's depth, from the VMEM
@@ -1207,9 +1231,13 @@ def _kernel_arrays(ds: ShardedDataset, path: SolverPath,
         # length order brings them itself
         from cocoa_tpu.ops.pallas_sparse import row_lengths
 
+        # (jitted: one reduce, where the eager form holds a (K, n_shard, W)
+        # mask and its int32 twin, 1.6 x the values, while it runs)
         shard_arrays["sp_row_len"] = once(
             "_row_len_cache", "row_lengths",
-            lambda: row_lengths(shard_arrays["sp_values"]))
+            lambda: jax.jit(row_lengths)(shard_arrays["sp_values"]))
+        _slot_stats(ds)     # counted on the host here, once, in the job
+                            # that made the lengths: not in the next one
     return shard_arrays
 
 

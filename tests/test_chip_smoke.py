@@ -150,7 +150,8 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
         "devices", "shards_per_device", "rows", "state", "step_solve",
         "pass_slot_share", "storage", "margin", "slot_fill", "longest_row",
         "chunk_pieces", "chunk_fill", "refused", "objective", "form",
-        "classes", "lane_fill", "row_fetch", "ring_depth", "row_align"]
+        "classes", "lane_fill", "row_fetch", "ring_depth", "row_align",
+        "local_ids", "segments", "table_width"]
     # no stream, no ring of chunks
     assert (path.chunk_pieces, path.chunk_fill) == (None, None)
     # the dense Pallas kernel's rows come by its own ring, as deep as fits

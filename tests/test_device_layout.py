@@ -541,12 +541,19 @@ def test_dense_cell_compiles_with_its_rows_fetched_by_the_ring(
 KDDB = dict(n=19264097, d=29890095, k=8, width=64, frac=0.1, lam=1e-5)
 
 
-def _capture_sparse_run(monkeypatch):
+# a quarter of criteo as the benchmark holds it (chipbench/configs/
+# criteo.json): every row 39 nonzeros, the rectangle as wide as the loader
+# stores that (data/sharding.rectangle_width)
+CRITEO = dict(n=11460154, d=1000000, k=8, width=39, frac=0.1, lam=1e-5)
+
+
+def _capture_sparse_run(monkeypatch, shape=KDDB, loss="hinge", width=None):
     """``(run, its arguments, the SolverPath)`` of one CoCoA+ job at the
-    kddb shape with the cell's flags, stopped at the dispatch.  The dataset
-    is shapes only (nothing is made: 10 GB), so the one array the program
-    derives from the rows eagerly, the per-row lengths, is given as a shape
-    too."""
+    kddb shape (or ``shape``) with the cell's flags, stopped at the
+    dispatch.  The dataset is shapes only (nothing is made: 10 GB), so the
+    one array the program derives from the rows eagerly, the per-row
+    lengths, is given as a shape too.  kddb's rows are as if in length
+    order; rows of one length (criteo) are as built and say so."""
     import jax
     import jax.numpy as jnp
 
@@ -556,8 +563,8 @@ def _capture_sparse_run(monkeypatch):
     from cocoa_tpu.solvers import base, run_cocoa
 
     got = _arm_capture(monkeypatch)
-    k, width = KDDB["k"], KDDB["width"]
-    sizes = split_sizes(KDDB["n"], k)
+    k, width = shape["k"], width or shape["width"]
+    sizes = split_sizes(shape["n"], k)
     n_shard = pad_rows(int(sizes.max()))
     here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
 
@@ -566,16 +573,19 @@ def _capture_sparse_run(monkeypatch):
 
     rows = sds((k, n_shard), jnp.float32)
     ds = ShardedDataset(
-        layout="sparse", n=KDDB["n"], num_features=KDDB["d"],
+        layout="sparse", n=shape["n"], num_features=shape["d"],
         counts=sizes.astype(np.int64), labels=rows, mask=rows,
         sq_norms=rows, sp_indices=sds((k, n_shard, width), jnp.int32),
         sp_values=sds((k, n_shard, width), jnp.float32))
     ds._row_len_cache = sds((k, n_shard), jnp.int32)
-    ds.row_order = sds((k, n_shard), jnp.int32)     # as if in length order
-    h = int(KDDB["frac"] * KDDB["n"] / k)
+    if shape is KDDB:
+        ds.row_order = sds((k, n_shard), jnp.int32)  # as if in length order
+    else:
+        ds._one_length = True           # data/sharding.note_row_lengths
+    h = int(shape["frac"] * shape["n"] / k)
     with pytest.raises(_Captured):
         run_cocoa(ds, Params(n=ds.n, num_rounds=300, local_iters=h,
-                             lam=KDDB["lam"]),
+                             lam=shape["lam"], loss=loss),
                   DebugParams(debug_iter=5, seed=0), plus=True, quiet=True,
                   math="fast", device_loop=True, rng="permuted",
                   gap_target=1e-2, accel="auto")
@@ -625,6 +635,55 @@ def test_kddb_job_fits_one_chip_and_copies_nothing_large(monkeypatch,
     whole = re.compile(rf"\[{k},{block},{width}\]|\[{k},{width},{block}\]|"
                        rf"\[{k * block * width}\]")
     assert [line.strip()[:160] for line in ops if whole.search(line)] == []
+
+
+@pytest.mark.parametrize("stored", ["as_the_loader_stores_39", "at_39"])
+def test_criteo_quarter_job_copies_no_rows_at_the_loaders_width(
+        monkeypatch, one_chip, stored):
+    """The whole device loop of a logistic job on a quarter of criteo
+    (shapes only; every row 39 nonzeros) compiled for one described v5e.
+    As the loader stores those rows (``rectangle_width``: 40 slots, whole
+    sublane tiles) the row index is on the lanes, the row fetch reads the
+    arrays as they are stored, and no ``copy(`` makes a (K, n_shard,
+    W)-sized array in any order: arguments plus temporaries stay under 4.6
+    GB, the program is the fetch and the chain (two ``tpu_custom_call``s)
+    on the ``direct`` plan.  At W = 39 itself (what the loader built until
+    PR 45) the device puts K on the sublanes and the same program opens
+    with two copies of all the rows: 3.9 GB of arguments and 4.0 GB of
+    temporaries — the reading the rule was made from, kept so that a
+    change to either side shows."""
+    import jax
+
+    from cocoa_tpu.data.sharding import rectangle_width
+
+    width = rectangle_width(CRITEO["width"]) if stored != "at_39" else 39
+    assert rectangle_width(CRITEO["width"]) == 40
+    with jax.enable_x64(False):
+        run, args, path, n_shard = _capture_sparse_run(
+            monkeypatch, CRITEO, "logistic", width)
+        assert (path.kernel, path.state, path.step_solve) == (
+            "pallas", "hbm", "scalar")
+        assert (path.local_ids, path.segments, path.table_width) == (
+            "direct", 1, 64)
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    stats = compiled.memory_analysis()
+    held = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") == 2    # the fetch and the chain
+    k = CRITEO["k"]
+    assert n_shard == 1432528
+    rows = re.compile(rf"\[{k},{n_shard},{width}\]|\[{k},{width},"
+                      rf"{n_shard}\]|\[{width},{k},{n_shard}\]|"
+                      rf"\[{n_shard},{k},{width}\]")
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if " copy(" in line and rows.search(line.split(" copy(")[0])]
+    if stored == "at_39":
+        assert len(copies) == 2 and held > 7.5e9, (copies, held)
+        return
+    assert copies == []
+    assert 3.8e9 < stats.argument_size_in_bytes < 4.1e9   # the deployment
+    assert held <= 4.6e9, (stats.argument_size_in_bytes,
+                           stats.temp_size_in_bytes)
 
 
 def test_ordering_a_kddb_shard_happens_in_its_donated_rows(one_chip):

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 
 from cocoa_tpu.data.libsvm import LibsvmData
 from cocoa_tpu.data.sharding import (STREAM_ALIGN, STREAM_PIECE,
-                                     shard_dataset, stream_suits)
+                                     rectangle_width, shard_dataset,
+                                     stream_suits)
 from cocoa_tpu.ops import pallas_longrows as plr
 from cocoa_tpu.ops import rows as rows_ops
 
@@ -132,7 +133,8 @@ def test_a_caller_that_reads_rectangles_gets_one(data):
 
     rect = shard_dataset(data, k=K, layout="sparse", rectangle=True)
     assert rect.sp_row_ptr is None
-    assert rect.sp_indices.shape == (K, rect.n_shard, max(HEAD))
+    assert rect.sp_indices.shape == (K, rect.n_shard,
+                                     rectangle_width(max(HEAD)))
     stream = shard_dataset(data, k=K, layout="sparse")
     params = Params(n=data.n, num_rounds=1, local_iters=4, lam=LAM)
     with pytest.raises(ValueError, match="rectangle=True"):

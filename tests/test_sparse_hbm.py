@@ -251,6 +251,49 @@ def test_resolver_never_leaves_a_sparse_set_on_fori_on_a_tpu(name,
     assert (cpu.kernel, cpu.state) == ("fori", "hbm")   # the CPU reference
 
 
+@pytest.mark.parametrize("name, plan", [
+    # a quarter of criteo (chipbench/configs/criteo.json), as the loader
+    # stores rows of 39 and at 39 itself: d = 10^6 fits the budget whole
+    ("criteo_quarter", (11460154, 1000000, 8, 40, "direct", 1, 64)),
+    ("criteo_quarter_at_39", (11460154, 1000000, 8, 39, "direct", 1, 64)),
+    ("kddb", (19264097, 29890095, 8, 64, "sorted", 2, 64)),
+])
+def test_solver_path_says_which_plan_the_hbm_kernel_runs(name, plan,
+                                                         monkeypatch):
+    """``hbm_plan`` at a cell's sizes, and the three fields of the run's
+    record that repeat it: ``direct`` (the local id is the column, [w | dw]
+    whole in VMEM, ``t`` = 1) at d = 10^6, ``sorted`` in two segments at
+    kddb; None off the HBM-state kernel."""
+    from cocoa_tpu.solvers import cocoa as cocoa_mod
+
+    n, d, k, width, ids, segments, table = plan
+    h = int(0.1 * n / k)
+    p = ph.hbm_plan(d, width, h)
+    assert (p.direct, p.t, p.w_r) == (ids == "direct", segments, table)
+    if ids == "direct":
+        assert (p.s, p.m) == (143264, 1000448)      # [w | dw]: 8 MB
+        assert ph.hbm_vmem_estimate(p.s, p.m, 4) < 10 << 20
+    ds = _shapes(n, d, k, width)
+    tpu = type("Device", (), {"platform": "tpu"})()
+    monkeypatch.setattr(cocoa_mod.jax, "devices", lambda *a: [tpu])
+    path = cocoa_mod.resolve_solver_path(ds, h, None, math="fast",
+                                         loss="logistic")
+    assert (path.state, path.step_solve) == ("hbm", "scalar")
+    assert (path.local_ids, path.segments, path.table_width) == (
+        ids, segments, table)
+    assert f"{ids} local ids, {segments} segment(s) a shard, tables " \
+        f"{table} slots wide" in path.describe()
+    monkeypatch.undo()
+    cpu = cocoa_mod.resolve_solver_path(ds, h, None, math="fast")
+    assert (cpu.kernel, cpu.local_ids, cpu.segments, cpu.table_width) == (
+        "fori", None, None, None)
+    assert "local ids" not in cpu.describe()
+    rcv1 = _shapes(*SHAPES["rcv1"][:4])
+    monkeypatch.setattr(cocoa_mod.jax, "devices", lambda *a: [tpu])
+    vmem = cocoa_mod.resolve_solver_path(rcv1, 253, None, math="fast")
+    assert (vmem.state, vmem.local_ids) == ("vmem", None)
+
+
 # --- the whole driver on the new path ---------------------------------------
 
 
