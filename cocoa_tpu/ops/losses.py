@@ -139,12 +139,15 @@ def alpha_step(loss: str, a, z, qii, lam_n, smoothing: float = 1.0):
     no reduction, no data-dependent control flow): 0-d values give one
     step, a vector gives one independent step per element with the same
     operations in the same order.  Where the solve runs is the caller's
-    choice: the ``fori`` kernels, the sparse and the block kernels call it
-    on one coordinate's scalars; the dense Pallas kernel calls a
-    closed-form step chain by chain on (1, 1) vectors (no value of its
-    step is 0-d: pallas_sdca ``_advance``) and an iterative loss's step
-    (``step_is_iterative``) once for all the shards it advances in
-    lockstep, one shard per lane (pallas_sdca ``_solve_in_lanes``).
+    choice: the ``fori`` kernels, the VMEM-resident sparse kernel, the
+    stream's chain and the block kernels call it on one coordinate's
+    scalars; the dense Pallas kernel calls a closed-form step chain by
+    chain on (1, 1) vectors (no value of its step is 0-d: pallas_sdca
+    ``_advance``) and an iterative loss's step (``step_is_iterative``)
+    once for all the shards it advances in lockstep, one shard per lane
+    (pallas_sdca ``_solve_in_lanes``); the sparse kernel whose state stays
+    in HBM runs one chain at a time and calls every loss's step on that
+    chain's (1, 1) vectors (pallas_sparse_hbm ``_chain_kernel``).
 
     - hinge: the reference's exact sequence — projected gradient against the
       box's active face, vanishing-gradient no-op, qii==0 → 1, clip

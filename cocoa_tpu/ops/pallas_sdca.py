@@ -529,14 +529,14 @@ def unfold_vec(v: jax.Array, d: int) -> jax.Array:
 STACK = 3  # lane-concatenated per-shard rows: [labels, sqn, alpha]
 
 
-def _pick(row, here):
+def lane_pick(row, here):
     """The one value of the (1, L) ``row`` where ``here`` is set, as a
     (1, 1) vector: a masked lane reduce that keeps its axis, so the value
     never leaves the vector unit."""
     return jnp.sum(jnp.where(here, row, 0.0), axis=1, keepdims=True)
 
 
-def _total(tile):
+def tile_total(tile):
     """The sum of a 2-D tile as a (1, 1) vector: lanes, then sublanes,
     each reduce keeping its axis (sublanes first measured the same)."""
     return jnp.sum(jnp.sum(tile, axis=1, keepdims=True), axis=0,
@@ -574,7 +574,7 @@ def _solve_in_lanes(loss, triples, lam_n, smoothing):
         q_v = jnp.where(here, qii, q_v)
     new_v = losses.alpha_step(loss, a_v, z_v, q_v, lam_n,
                               smoothing=smoothing)
-    return [_total(jnp.where(here, new_v, 0.0)) for here in mine]
+    return [tile_total(jnp.where(here, new_v, 0.0)) for here in mine]
 
 
 def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
@@ -592,12 +592,12 @@ def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
     matvec it replaces was most of the round's HBM traffic).
 
     **No floating-point value of a step is 0-d.**  y, ‖x‖², α and the
-    margin are (1, 1) vectors (:func:`_pick`, :func:`_total`: reduces that
-    keep their axes), ``losses.alpha_step`` runs elementwise on them, and
-    coef and the new α broadcast into ``coef * x`` and the masked state
-    write: nothing crosses to the scalar core and back.  Only the integer
-    address arithmetic (``idx``, ``blk``, ``sub_lane``) is the scalar
-    core's.  Measured on the chip at epsilon's shape (K = 8 interleaved,
+    margin are (1, 1) vectors (:func:`lane_pick`, :func:`tile_total`:
+    reduces that keep their axes), ``losses.alpha_step`` runs elementwise
+    on them, and coef and the new α broadcast into ``coef * x`` and the
+    masked state write: nothing crosses to the scalar core and back.  Only
+    the integer address arithmetic (``idx``, ``blk``, ``sub_lane``) is the
+    scalar core's.  Measured on the chip at epsilon's shape (K = 8 interleaved,
     d = 2,000; PERF.md §6, PR 39): 518 ns a lockstep step against 880 with
     the five 0-d reads, two divides and two splats a chain-step this
     replaced; frozen mode, one total fewer, 499; the grid iteration and
@@ -627,12 +627,12 @@ def _advance(chains, idxs_ref, step, live, w_ref, *, frozen, sig_eff,
         sub_lane = idx - blk * LANES
         dw_k, w_k = dw_acc[...], w_ref[...]
         lane4 = jax.lax.broadcasted_iota(jnp.int32, (1, STACK * LANES), 1)
-        y = _pick(srow, lane4 == sub_lane)
-        sq = _pick(srow, lane4 == sub_lane + LANES)
-        a = _pick(srow, lane4 == sub_lane + 2 * LANES)
-        margin = _total(x * w_k)
+        y = lane_pick(srow, lane4 == sub_lane)
+        sq = lane_pick(srow, lane4 == sub_lane + LANES)
+        a = lane_pick(srow, lane4 == sub_lane + 2 * LANES)
+        margin = tile_total(x * w_k)
         if not frozen:
-            margin = margin + sig_eff * _total(x * dw_k)
+            margin = margin + sig_eff * tile_total(x * dw_k)
         return (blk, lane4, sub_lane, srow, x, y), (a, y * margin,
                                                     sq * qii_factor)
 

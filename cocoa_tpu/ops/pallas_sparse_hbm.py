@@ -7,10 +7,11 @@ same chain — for the sampled row i of shard k: margin = x_i·w +
 σ′·x_i·Δw_k, the α step of ``losses.alpha_step``, Δw_k += coef·x_i
 (CoCoA.scala:148-188; ``local_sdca_fast``) — with w, α and the rows left
 in HBM, and only what a **segment** of one shard's steps touches on the
-chip.  Shards run one after another (a chain is bound by the scalar core's
-address arithmetic, so interleaving K of them bought nothing measurable,
-and one shard's working set is an eighth of eight); a shard's round is cut
-into T segments of S steps where its touched columns outgrow VMEM.
+chip.  Shards run one after another (one shard's working set is an eighth
+of eight, and interleaving K chains bought nothing measurable while the
+scalar core's address arithmetic bound a step; what a step costs now is
+under "The chain" below); a shard's round is cut into T segments of S
+steps where its touched columns outgrow VMEM.
 
 What the v5e measured for the pieces decides the design (PERF.md §6,
 PR 26): an XLA gather or scatter of single elements costs 11-24 ns an
@@ -47,6 +48,23 @@ the lanes:
   (addresses must be scalars), so a segment is not bound by what SMEM
   holds: S follows from the VMEM budget alone.  Δw is written by masked
   single-lane stores, so a row's W updates do not wait on one another.
+  **No floating-point value of a step is 0-d** (PR 46, as the dense
+  kernel's ``pallas_sdca._advance``): y, σ′‖x‖² and α leave SMEM as splats
+  to (1, 1) — scalar to vector, the cheap direction — the margin's total
+  and a repeated row's α are reduces that keep their axes,
+  ``losses.alpha_step`` runs elementwise on (1, 1) vectors under every
+  loss, and coef broadcasts into the scatter's multiply-add; the scalar
+  core keeps the integers (nnz, prev, the local ids and their row and
+  lane, the trip count) and hands each nonzero's value over as a splat.
+  One chain runs at a time, so a value that went to the scalar core and
+  came back was paid in full: measured on the v5e, the kernel alone
+  (PERF.md §6, PR 46), a hinge step 1,157 → 942 ns at criteo's shape (39
+  nonzeros, two 32-slot trips) and 879 → 675 at kddb's (29.4, one trip):
+  four trips (the α pick, the margin's total, two divides) were ~210 ns;
+  logistic's ten Newton iterations 1,792 → 429 ns on top of either.  What
+  is left of a step is its two slot loops: by difference (criteo's two
+  trips a step against kddb's 1.33) ~400 ns a 32-slot trip of both loops
+  and ~150 ns around them.
 
 A row sampled twice in one segment (any ``rng`` but ``permuted``; there, a
 segment that crosses an epoch) reads its α from the earlier step's output:
@@ -72,7 +90,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from cocoa_tpu.ops import losses
 from cocoa_tpu.ops.local_sdca import coef_divisor, mode_factors
-from cocoa_tpu.ops.pallas_sdca import LANES, check_dtype
+from cocoa_tpu.ops.pallas_sdca import (LANES, check_dtype, lane_pick,
+                                       tile_total)
 from cocoa_tpu.ops.pallas_sparse import GROUP, row_lengths
 from cocoa_tpu.ops.rows import spread_index, spread_table
 from cocoa_tpu.telemetry.tracing import (SCOPE_LOCAL_SOLVE,
@@ -320,19 +339,20 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
         base = s * wt
         cnt = itab_ref[0, base + w_r]
         prev = itab_ref[0, base + w_r + 1]
-        y = ftab_ref[0, base + w_r]
-        qii = ftab_ref[0, base + w_r + 1]
-        a0 = ftab_ref[0, base + w_r + 2]
+        # y, σ′‖x‖² and α leave SMEM as splats: scalar to vector, the one
+        # direction a float of the step ever takes
+        y, qii, a0 = (jnp.full((1, 1), ftab_ref[0, base + w_r + i], dtype)
+                      for i in range(N_FLT))
         n_trips = (cnt + (group - 1)) // group
         # a row this segment already stepped on: α is that step's output
         pj = jnp.maximum(prev, 0)
         prow = a_out[pl.ds(pj >> 7, 1)]                       # (1, LANES)
-        a_prev = jnp.sum(jnp.where(lane == (pj & (LANES - 1)), prow, 0.0))
-        a = jnp.where(prev >= 0, a_prev, a0)
+        a = jnp.where(prev >= 0,
+                      lane_pick(prow, lane == (pj & (LANES - 1))), a0)
 
         # margin = x·w + sig_eff·x·Δw: per nonzero one dynamic sublane read
         # of the [w | Δw] row and a masked multiply-add into a lane vector;
-        # ONE cross-lane sum a step
+        # ONE cross-lane sum a step, which keeps its axes
         def margin_body(g, acc):
             for u in range(group):
                 f = itab_ref[0, base + g * group + u]
@@ -348,9 +368,9 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
 
         acc = lax.fori_loop(0, n_trips, margin_body,
                             jnp.zeros((1, 2 * LANES), dtype))
-        new_a = losses.alpha_step(loss, a, y * jnp.sum(acc), qii, lam_n,
+        new_a = losses.alpha_step(loss, a, y * tile_total(acc), qii, lam_n,
                                   smoothing=smoothing)
-        coef = y * (new_a - a) / coef_div
+        coef = y * (new_a - a) / coef_div                     # (1, 1)
 
         # Δw += coef·x: a masked store of the one lane each nonzero owns.
         # A row has no column twice (LIBSVM rows, ``shard_dataset``), so

@@ -196,9 +196,14 @@ class SolverPath:
     lasso's soft threshold), solved chain by chain on (1, 1) vectors — y,
     ‖x‖², α and the margin come from reduces that keep their axes, so no
     value of a step crosses to the scalar core (ops/pallas_sdca._advance;
-    ``lanes`` runs the same read and write); ``scalar``: everything else
-    — wherever a kernel still solves a step on one coordinate's 0-d
-    values (``fori``, the sparse, the stream and the block kernels).
+    ``lanes`` runs the same read and write), and the sparse rectangle's
+    HBM-state kernel under ANY loss (ops/pallas_sparse_hbm._chain_kernel
+    runs one chain at a time, so logistic's Newton iterations too run on
+    that chain's (1, 1) values; y, σ′‖x‖² and α enter as splats of its
+    SMEM table, the margin's total and a repeated row's α as reduces that
+    keep their axes); ``scalar``: everything else — wherever a kernel
+    still solves a step on one coordinate's 0-d values (``fori``, the
+    VMEM-resident sparse kernel, the stream and the block kernels).
     ``pass_slot_share``: of a sparse set's padded slots, the share one
     all-rows pass (the certificate's margins, the ``--accel`` jump) touches:
     1.0 where one block holds a shard or the rows' lengths are not known;
@@ -644,9 +649,10 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             chain=None, interpret=bool(pallas and platform == "cpu"),
             form=form,
             state="vmem" if pallas and not hbm_state else "hbm",
-            step_solve=("scalar" if not pallas or sparse
-                        else "lanes" if classes > 1
-                        or losses.step_is_iterative(loss) else "vector"),
+            step_solve=("scalar" if not pallas or sparse and not hbm_state
+                        else "lanes" if not sparse and (
+                            classes > 1 or losses.step_is_iterative(loss))
+                        else "vector"),
             refused="" if pallas else refused,
             **placement)
     if block_chain == "xla":

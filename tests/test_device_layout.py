@@ -662,7 +662,7 @@ def test_criteo_quarter_job_copies_no_rows_at_the_loaders_width(
         run, args, path, n_shard = _capture_sparse_run(
             monkeypatch, CRITEO, "logistic", width)
         assert (path.kernel, path.state, path.step_solve) == (
-            "pallas", "hbm", "scalar")
+            "pallas", "hbm", "vector")
         assert (path.local_ids, path.segments, path.table_width) == (
             "direct", 1, 64)
         compiled = run.lower(*_on_chip(args, one_chip)).compile()

@@ -163,7 +163,7 @@ def test_job_on_the_direct_plan_follows_fori_and_the_reference(gen,
     w1, a1, t1 = _job(ds, h=h, pallas=True)
     path = t1.meta["solver_path"]
     assert (path["kernel"], path["state"], path["interpret"],
-            path["step_solve"]) == ("pallas", "hbm", True, "scalar")
+            path["step_solve"]) == ("pallas", "hbm", True, "vector")
     assert (path["local_ids"], path["segments"], path["table_width"]) == (
         "direct", 1, 64)
     assert path["longest_row"] == 39 and ds.sp_indices.shape[-1] == 40

@@ -270,7 +270,7 @@ def _data_flow(jaxpr, data):
             for sub in subs:
                 found += _data_flow(sub, [any(ins)] * len(sub.invars))[0]
         elif any(ins):
-            found += [str(eqn)[:120] for v in eqn.outvars
+            found += [eqn for v in eqn.outvars
                       if v.aval.shape == ()
                       and jnp.issubdtype(v.aval.dtype, jnp.floating)]
         env.update(zip(eqn.outvars, outs))
@@ -281,6 +281,10 @@ def _scalar_floats(jaxpr):
     """Equations of a kernel body that yield a 0-d floating-point value
     computed from the kernel's data: a value of the step that the scalar
     core holds."""
+    return [str(eqn)[:120] for eqn in _scalar_float_eqns(jaxpr)]
+
+
+def _scalar_float_eqns(jaxpr):
     return _data_flow(jaxpr, [True] * len(jaxpr.invars))[0]
 
 
@@ -350,6 +354,67 @@ def test_no_value_of_a_class_step_is_a_scalar(loss, depth, h):
     body = _round_body(traced)
     assert any(e.primitive.name == "reduce_sum" for e in _walk(body))
     assert _scalar_floats(body) == []
+
+
+def _chain_body(loss, mode, width):
+    """The jaxpr of the HBM-state sparse round's chain kernel
+    (``pallas_sparse_hbm._chain_kernel``), traced at a toy size: the
+    kernel's body does not depend on the sizes but through the slot
+    group's unrolling (``width`` slots a trip)."""
+    import jax
+
+    from cocoa_tpu.ops.pallas_sparse_hbm import pallas_sparse_hbm_round
+
+    k, n_shard, d, h = 2, 64, 512, 16
+    rows = jnp.ones((k, n_shard))
+    args = (jnp.zeros(d), jnp.zeros((k, n_shard)),
+            jnp.zeros((k, n_shard, width), jnp.int32),
+            jnp.ones((k, n_shard, width)), rows, rows,
+            jnp.zeros((k, h), jnp.int32))
+    traced = jax.make_jaxpr(lambda *a: pallas_sparse_hbm_round(
+        *a, 0.01, 1000, mode=mode, sigma=3.0, loss=loss, smoothing=S,
+        interpret=True))(*args)
+    (call,) = [e for e in _walk(traced.jaxpr)
+               if e.primitive.name == "pallas_call"
+               and e.params["name"] == "pallas_sparse_hbm_round"]
+    return call.params["jaxpr"]
+
+
+@pytest.mark.parametrize("mode", ["plus", "frozen"])
+@pytest.mark.parametrize("loss", ALL)
+def test_no_value_of_a_sparse_hbm_step_is_computed_as_a_scalar(loss, mode):
+    """Read off the traced chain kernel of the HBM-state sparse round: the
+    only 0-d floats in it are loads of its SMEM step table — y, the scaled
+    norm and alpha, splatted to (1, 1) at once, and a nonzero's value in
+    the margin's and the scatter's slot loops, splatted into a multiply.
+    No reduce, divide, transcendental, product, sum or select yields a 0-d
+    float: the margin's total and a repeated row's alpha are reduces that
+    keep their axes and ``alpha_step`` runs elementwise on (1, 1) vectors,
+    so no float goes to the scalar core and comes back (PERF.md section 6,
+    PR 46: the ten Newton iterations on 0-d values were 1.79 us of
+    criteo's 2.95 us step).  Under logistic every ``exp`` of the traced
+    step is (1, 1): ``_NEWTON_ITERS`` + 1 of them and one ``log``."""
+    width = 8
+    body = _chain_body(loss, mode, width)
+    scalars = _scalar_float_eqns(body)
+    assert {e.primitive.name for e in scalars} == {"get"}, [
+        str(e)[:120] for e in scalars if e.primitive.name != "get"]
+    # (y, q, alpha) once a step; a value a slot in each of the two loops
+    assert len(scalars) == 3 + 2 * width
+    prims = list(_walk(body))
+    # both reduces are there (the pick of a repeated row's alpha, the
+    # margin's total), and neither reduces its last axis away
+    sums = [e.outvars[0].aval.shape for e in prims
+            if e.primitive.name == "reduce_sum"]
+    assert len(sums) >= 2 and () not in sums
+    exps = [e.outvars[0].aval.shape for e in prims
+            if e.primitive.name == "exp"]
+    logs = [e for e in prims if e.primitive.name in ("log", "logistic")]
+    if not losses.step_is_iterative(loss):
+        assert not exps and not logs
+        return
+    assert exps == [(1, 1)] * (losses._NEWTON_ITERS + 1)
+    assert [e.primitive.name for e in logs] == ["log"]
 
 
 def test_the_scalar_reader_sees_a_scalar_trip():

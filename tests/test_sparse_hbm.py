@@ -102,6 +102,10 @@ CASES = {
     "repeats": _case(k=3, n_shard=64, width=40, h=50, repeats=True,
                      plan=PLAN(t=2, s=32, m=2048, w_r=64, chunk=32,
                                direct=False)),
+    # the same on the ``direct`` plan (criteo's), the whole round one
+    # segment: most steps read the alpha an earlier step wrote, a (1, 1)
+    # pick out of the kernel's own output block
+    "repeats_direct": _case(n_shard=48, d=300, h=64, repeats=True),
     # W a multiple of 128: the rows are stored row-major, a plain gather
     # of rows fetches them
     "row_major": _case(n_shard=64, width=128, plan=PLAN(
@@ -130,6 +134,11 @@ def test_round_matches_the_fori_path_and_the_oracle(case, mode, loss):
     n, sigma = k * n_shard, (float(k) if mode == "plus" else 1.0)
     plan = c["plan"] or ph.hbm_plan(d, c["width"], h, 4)
     assert plan.direct == (c["plan"] is None)
+    if c["repeats"]:        # some segment does step on a row twice
+        pad = np.pad(idxs, ((0, 0), (0, plan.t * plan.s - h)),
+                     constant_values=-1).reshape(k * plan.t, plan.s)
+        assert max(np.unique(seg[seg >= 0], return_counts=True)[1].max()
+                   for seg in pad) >= 2
     args = [jnp.asarray(a) for a in (w, alpha, cols, vals, y, sq, idxs)]
     dw, a_new = ph.pallas_sparse_hbm_round(
         *args, LAM, n, mode=mode, sigma=sigma, interpret=True, loss=loss,
@@ -278,7 +287,10 @@ def test_solver_path_says_which_plan_the_hbm_kernel_runs(name, plan,
     monkeypatch.setattr(cocoa_mod.jax, "devices", lambda *a: [tpu])
     path = cocoa_mod.resolve_solver_path(ds, h, None, math="fast",
                                          loss="logistic")
-    assert (path.state, path.step_solve) == ("hbm", "scalar")
+    # the chain solves its step on (1, 1) vectors under any loss; the
+    # VMEM-resident sparse kernel and the fori path on a coordinate's scalars
+    assert (path.state, path.step_solve) == ("hbm", "vector")
+    assert "each step solved on the vector unit" in path.describe()
     assert (path.local_ids, path.segments, path.table_width) == (
         ids, segments, table)
     assert f"{ids} local ids, {segments} segment(s) a shard, tables " \
@@ -287,11 +299,13 @@ def test_solver_path_says_which_plan_the_hbm_kernel_runs(name, plan,
     cpu = cocoa_mod.resolve_solver_path(ds, h, None, math="fast")
     assert (cpu.kernel, cpu.local_ids, cpu.segments, cpu.table_width) == (
         "fori", None, None, None)
+    assert cpu.step_solve == "scalar"
     assert "local ids" not in cpu.describe()
     rcv1 = _shapes(*SHAPES["rcv1"][:4])
     monkeypatch.setattr(cocoa_mod.jax, "devices", lambda *a: [tpu])
     vmem = cocoa_mod.resolve_solver_path(rcv1, 253, None, math="fast")
-    assert (vmem.state, vmem.local_ids) == ("vmem", None)
+    assert (vmem.state, vmem.local_ids, vmem.step_solve) == (
+        "vmem", None, "scalar")
 
 
 # --- the whole driver on the new path ---------------------------------------
