@@ -54,8 +54,10 @@ therefore a **dynamic-trip ``fori_loop`` over GROUP-slot bodies**: the
 trip count is ceil(row_nnz / GROUP) from a scalar-prefetched per-row
 count, the body unrolls GROUP slots (values and indices are SMEM scalar
 reads at dynamic group offsets), and the round-3 dead-end — ~200 ns of
-scalar-branch overhead per dynamic iteration — amortizes to ~6 ns per
-nonzero at GROUP=32.  Per step the cost tracks ceil(nnz/32)·32 slots
+scalar-branch overhead per dynamic iteration (read 2026-07-31, in this
+kernel, with every float of a step on the scalar core; ops/
+pallas_sparse_hbm.py's docstring has the reading since, PR 47) — amortizes
+to ~6 ns per nonzero at GROUP=32.  Per step the cost tracks ceil(nnz/32)·32 slots
 instead of W, compile size is ONE group body per pass per shard, and any
 padded width works with no special tail.
 

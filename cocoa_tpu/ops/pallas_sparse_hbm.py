@@ -58,13 +58,40 @@ the lanes:
   lane, the trip count) and hands each nonzero's value over as a splat.
   One chain runs at a time, so a value that went to the scalar core and
   came back was paid in full: measured on the v5e, the kernel alone
-  (PERF.md §6, PR 46), a hinge step 1,157 → 942 ns at criteo's shape (39
-  nonzeros, two 32-slot trips) and 879 → 675 at kddb's (29.4, one trip):
-  four trips (the α pick, the margin's total, two divides) were ~210 ns;
-  logistic's ten Newton iterations 1,792 → 429 ns on top of either.  What
-  is left of a step is its two slot loops: by difference (criteo's two
-  trips a step against kddb's 1.33) ~400 ns a 32-slot trip of both loops
-  and ~150 ns around them.
+  (PERF.md §6, PR 46), four trips (the α pick, the margin's total, two
+  divides) were ~210 ns of a hinge step, logistic's ten Newton iterations
+  1,792 → 429 ns.
+  **The walk** (PR 47).  What is left of a step is its two passes over
+  the row's slots, the margin's and the scatter's.  The tables are as wide
+  as the rectangle's own 8-slot groups (``plan.w_r``, ``ops/rows
+  .SLOT_GROUP``: 40 for criteo's rows of 39, 64 while it was whole 32-slot
+  groups).  A pass writes its first ``head`` slots out as straight-line
+  code of the step, whatever the row's length, and walks what the row has
+  past them in loops: ``(cnt - head) // 32`` trips of a 32-slot body, then
+  the remainder in trips of ``TAIL_GROUP`` = 8 slots.  ``head`` follows
+  from what the plan observed, never from a flag: all ``w_r`` slots where
+  the loader saw rows of one length (``HbmPlan.unrolled``, from
+  ``data/sharding.rows_of_one_length``: no dynamic trip at all), else the
+  first 32.  The scatter's mask ``slot < cnt`` covers the written-out
+  slots too, so a short row or a padded step (``cnt`` -1) adds
+  ``row × 0.0`` to its margin and stores nothing: (Δw, α) are the bits
+  they were under every walk.  Why a head: measured on the v5e (PERF.md
+  §6, PR 47; the kernel alone, hinge, ns a step), inside a loop's body or a
+  branch a slot of both passes costs 12.5 ns, a dynamic trip 7-10 ns and
+  what is around the two passes ~115 ns; the same slots as straight-line
+  code of the step cost ~250 ns a step less, because nothing crosses a
+  region's edge: the scatter's table reads and row loads cannot start
+  under the α step's latency, nor the margin's under the α pick's.
+  criteo's shape: 942 walking 64 slots in two 32-slot trips a pass (the
+  parent), 643 at 32 + 8 in loops, 444 with 32 written out and a trip of
+  8, **366 with all 40 written out** (633 with the 40 behind one branch);
+  kddb's lengths (mean 29.4, to 64): 675 in whole 32-slot groups, 575 in
+  loops of 32 then 8 (32.8 slots a step), 524 / 451 / 424 / **420** with
+  the first 8 / 16 / 24 / 32 written out ahead of those loops (37.7 slots
+  a step at 32), 649 with all 64 written out: so ragged rows get a head of
+  32 and only rows of one length the whole width.  The written-out
+  scatter reads 32 slots' rows before it stores them, as a trip's body
+  does (8 at a time: 455 for 366; 16: 420; all 40: 368).
 
 A row sampled twice in one segment (any ``rng`` but ``permuted``; there, a
 segment that crosses an epoch) reads its α from the earlier step's output:
@@ -84,6 +111,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -93,11 +121,12 @@ from cocoa_tpu.ops.local_sdca import coef_divisor, mode_factors
 from cocoa_tpu.ops.pallas_sdca import (LANES, check_dtype, lane_pick,
                                        tile_total)
 from cocoa_tpu.ops.pallas_sparse import GROUP, row_lengths
-from cocoa_tpu.ops.rows import spread_index, spread_table
+from cocoa_tpu.ops.rows import SLOT_GROUP, spread_index, spread_table
 from cocoa_tpu.telemetry.tracing import (SCOPE_LOCAL_SOLVE,
                                          SCOPE_SPARSE_GATHER)
 
 CHUNK = 32                       # steps per SMEM block
+TAIL_GROUP = 8                   # slots a trip past a row's whole GROUPs
 FETCH_ROWS = 8                   # rows fetched per grid step of _fetch_rows
 FETCH_BLOCK = 1 << 15            # rows per call of it (their indices: SMEM)
 COLUMN_CHUNK = 1 << 17           # distinct columns gathered per loop trip
@@ -113,14 +142,20 @@ _NONE = 2 ** 31 - 1              # sorts after every column
 class HbmPlan:
     """How one shard's round is cut: ``t`` segments of ``s`` steps (``s`` a
     multiple of ``chunk``), ``m`` local ids (a multiple of 1024 and of the
-    column chunk), ``w_r`` the GROUP-rounded slot width, ``direct``: the
-    local id is the column (M = d_pad)."""
+    column chunk), ``w_r`` the slots a step takes in the tables (whole
+    ``SLOT_GROUP``s, the rectangle's own), ``direct``: the local id is the
+    column (M = d_pad), ``unrolled``: a step's two passes are all ``w_r``
+    slots written out, no dynamic trip (the loader saw rows of one length:
+    nothing of that walk is padding but the rectangle's own); otherwise
+    the first GROUP are, and loops walk what a row has past them
+    (:func:`walk_head`)."""
     t: int
     s: int
     m: int
     w_r: int
     chunk: int
     direct: bool
+    unrolled: bool = False
 
     @property
     def column_chunk(self) -> int:
@@ -128,8 +163,24 @@ class HbmPlan:
 
 
 def _w_round(max_nnz: int) -> int:
-    group = min(GROUP, max(1, max_nnz))
-    return -(-max_nnz // group) * group
+    return -(-max(1, max_nnz) // SLOT_GROUP) * SLOT_GROUP
+
+
+def walk_head(w_r: int, unrolled: bool) -> int:
+    """Slots of a pass the chain writes out ahead of its loops: all of
+    them where the plan is ``unrolled``, else the first GROUP."""
+    return w_r if unrolled else min(GROUP, w_r)
+
+
+def walk_slots(lens, max_nnz: int, unrolled: bool):
+    """Slots one pass of the chain's walk covers for rows of ``lens``
+    nonzeros in a rectangle ``max_nnz`` wide (a NumPy array, on the host):
+    the head, then past it the row's whole GROUPs and its TAIL_GROUPs as
+    far as its length reaches."""
+    head = walk_head(_w_round(max_nnz), unrolled)
+    past = np.maximum(lens - head, 0)
+    whole = past // GROUP * GROUP
+    return head + whole + -(-(past - whole) // TAIL_GROUP) * TAIL_GROUP
 
 
 def _table_width(w_r: int) -> int:
@@ -168,9 +219,12 @@ def rows_on_lanes(n_shard: int, max_nnz: int) -> bool:
         < pad(max_nnz, LANES) * pad(n_shard, 8)
 
 
-def hbm_plan(d: int, max_nnz: int, h: int, itemsize: int = 4):
+def hbm_plan(d: int, max_nnz: int, h: int, itemsize: int = 4,
+             one_length: bool = False):
     """The plan of one shard's round, or None where not even one block of
-    steps fits (the caller keeps the ``fori`` path)."""
+    steps fits (the caller keeps the ``fori`` path).  ``one_length``: the
+    loader saw every row with the same count of nonzeros
+    (data/sharding.rows_of_one_length)."""
     w_r = _w_round(max_nnz)
     if (h + 2 * LANES) * w_r >= (1 << 31):
         return None                     # slot positions are int32
@@ -204,7 +258,8 @@ def hbm_plan(d: int, max_nnz: int, h: int, itemsize: int = 4):
     # even segments: the last is not left with a sliver of steps
     s = -(-(-(-h // t)) // chunk) * chunk
     m = m_of(s)
-    return HbmPlan(t=t, s=s, m=m, w_r=w_r, chunk=chunk, direct=m >= d_pad)
+    return HbmPlan(t=t, s=s, m=m, w_r=w_r, chunk=chunk, direct=m >= d_pad,
+                   unrolled=bool(one_length))
 
 
 def hbm_refusal(d: int, max_nnz: int, h: int, itemsize: int = 4) -> str:
@@ -312,6 +367,11 @@ def _index(i):
     return i.astype(jnp.asarray(0).dtype)
 
 
+def _log2(n: int) -> int:
+    assert n & (n - 1) == 0, n
+    return n.bit_length() - 1
+
+
 def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
                   ftab_ref,   # SMEM (1, CHUNK·wt) f32: values, y, q, α
                   wd_hbm,     # ANY (M/128, 2·128): [w | Δw carried]
@@ -320,10 +380,10 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
                   wd_sc,      # VMEM scratch (M/128, 2·128)
                   *, lam_n: float, coef_div: float, sig_eff: float,
                   frozen: bool, w_r: int, chunk: int, loss: str,
-                  smoothing: float):
+                  smoothing: float, head: int, tail: int):
     c = pl.program_id(0)
     wt = _table_width(w_r)
-    group = min(GROUP, w_r)
+    assert (w_r - head) % tail == 0 and GROUP % tail == 0, (w_r, tail)
 
     @pl.when(c == 0)
     def _init():
@@ -343,20 +403,41 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
         # direction a float of the step ever takes
         y, qii, a0 = (jnp.full((1, 1), ftab_ref[0, base + w_r + i], dtype)
                       for i in range(N_FLT))
-        n_trips = (cnt + (group - 1)) // group
         # a row this segment already stepped on: α is that step's output
         pj = jnp.maximum(prev, 0)
         prow = a_out[pl.ds(pj >> 7, 1)]                       # (1, LANES)
         a = jnp.where(prev >= 0,
                       lane_pick(prow, lane == (pj & (LANES - 1))), a0)
 
+        def walk(slots, init):
+            """``init = slots(first, count, init)`` over the step's slots
+            in order, ``count`` static: the first ``head`` of them written
+            out, whatever the row's length (a padded step, ``cnt`` -1,
+            adds zeros and stores nothing), and past them the row's whole
+            GROUPs and then its ``tail``-slot groups, as far as its
+            length reaches."""
+            for first in range(0, head, GROUP):
+                init = slots(first, min(GROUP, head - first), init)
+            if head == w_r:
+                return init
+            live = jnp.maximum(cnt - head, 0)
+            whole, left = live >> _log2(GROUP), live & (GROUP - 1)
+            if w_r - head >= GROUP:
+                init = lax.fori_loop(
+                    0, whole,
+                    lambda g, v: slots(head + g * GROUP, GROUP, v), init)
+            return lax.fori_loop(
+                0, (left + (tail - 1)) >> _log2(tail),
+                lambda g, v: slots(head + whole * GROUP + g * tail, tail, v),
+                init)
+
         # margin = x·w + sig_eff·x·Δw: per nonzero one dynamic sublane read
         # of the [w | Δw] row and a masked multiply-add into a lane vector;
         # ONE cross-lane sum a step, which keeps its axes
-        def margin_body(g, acc):
-            for u in range(group):
-                f = itab_ref[0, base + g * group + u]
-                vj = ftab_ref[0, base + g * group + u]
+        def margin_slots(first, count, acc):
+            for u in range(count):
+                f = itab_ref[0, base + first + u]
+                vj = ftab_ref[0, base + first + u]
                 row = wd_sc[pl.ds(f >> 7, 1)]                 # (1, 2·LANES)
                 fl = f & (LANES - 1)
                 pick = jnp.where(lane2 == fl, 1.0, 0.0)
@@ -366,8 +447,7 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
                 acc = acc + row * (pick * vj)
             return acc
 
-        acc = lax.fori_loop(0, n_trips, margin_body,
-                            jnp.zeros((1, 2 * LANES), dtype))
+        acc = walk(margin_slots, jnp.zeros((1, 2 * LANES), dtype))
         new_a = losses.alpha_step(loss, a, y * tile_total(acc), qii, lam_n,
                                   smoothing=smoothing)
         coef = y * (new_a - a) / coef_div                     # (1, 1)
@@ -376,9 +456,9 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
         # A row has no column twice (LIBSVM rows, ``shard_dataset``), so
         # within a step no store feeds a later slot's read, and the
         # group's reads all go first.
-        def scatter_body(g, carry_):
-            fs = [itab_ref[0, base + g * group + u] for u in range(group)]
-            vs = [ftab_ref[0, base + g * group + u] for u in range(group)]
+        def scatter_slots(first, count, carry_):
+            fs = [itab_ref[0, base + first + u] for u in range(count)]
+            vs = [ftab_ref[0, base + first + u] for u in range(count)]
             rows = [wd_sc[pl.ds(f >> 7, 1)] for f in fs]
             for u, (f, vj, row) in enumerate(zip(fs, vs, rows)):
                 # a slot past the row's length holds id 0 and value 0: its
@@ -387,10 +467,10 @@ def _chain_kernel(itab_ref,   # SMEM (1, CHUNK·wt) int32: local ids, nnz, prev
                 pltpu.store(wd_sc.at[pl.ds(_index(f >> 7), 1)],
                             row + coef * vj,
                             mask=(lane2 == (f & (LANES - 1)) + LANES)
-                            & (g * group + u < cnt))
+                            & (first + u < cnt))
             return carry_
 
-        lax.fori_loop(0, n_trips, scatter_body, jnp.int32(0))
+        walk(scatter_slots, jnp.int32(0))
         pltpu.store(a_out.at[pl.ds(_index(j >> 7), 1)],
                     jnp.broadcast_to(new_a, (1, LANES)).astype(dtype),
                     mask=lane == (j & (LANES - 1)))
@@ -411,7 +491,8 @@ def _chain_call(plan: HbmPlan, dtype, interpret: bool, **consts):
     rows = plan.m // LANES
     return pl.pallas_call(
         functools.partial(_chain_kernel, w_r=plan.w_r, chunk=plan.chunk,
-                          **consts),
+                          head=walk_head(plan.w_r, plan.unrolled),
+                          tail=TAIL_GROUP, **consts),
         grid=(plan.s // plan.chunk,),
         in_specs=[
             pl.BlockSpec((1, plan.chunk * wt), lambda c: (0, c),

@@ -26,7 +26,7 @@ from cocoa_tpu.analysis import sanitize as _sanitize
 from cocoa_tpu.config import DebugParams, Params
 from cocoa_tpu.data.sharding import (ShardedDataset, order_rows_for_passes,
                                      passes_want_order, rows_as_built,
-                                     rows_as_ordered)
+                                     rows_as_ordered, rows_of_one_length)
 from cocoa_tpu.evals import objectives
 from cocoa_tpu.ops import local_sdca
 from cocoa_tpu.ops import rows as _rows
@@ -35,11 +35,12 @@ from cocoa_tpu.telemetry import tracing as _tracing
 
 
 def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
-                    interpret, state="vmem"):
+                    interpret, state="vmem", hbm_plan=None):
     """One Pallas SDCA round over all K shards: dense kernel (margins
     precomputed as one MXU matvec, folded-row X) or sparse kernel (margins
     read in-kernel from the VMEM-resident w; ``state="hbm"``: the kernel
-    whose w, Δw and α stay in HBM, ops/pallas_sparse_hbm.py).  Returns
+    whose w, Δw and α stay in HBM, ops/pallas_sparse_hbm.py, on the plan
+    the resolver made for it, ``hbm_plan``; None: the shapes' own).  Returns
     (dw, alpha_inner (K, n_shard)): dw's rows add up to the K shards' Δw —
     (1, d), summed by the kernel, from the dense, the HBM-state and the
     stream kernels; (K, d) from the VMEM-resident sparse kernel."""
@@ -65,7 +66,7 @@ def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
             w, alpha, shards["sp_indices"], shards["sp_values"],
             shards["labels"], shards["sq_norms"], idxs_kh,
             params.lam, params.n, row_len=shards.get("sp_row_len"),
-            **common,
+            plan=hbm_plan, **common,
         )
         return dw_sum[None], alpha_inner
     if "sp_indices" in shards:
@@ -267,8 +268,17 @@ class SolverPath:
     columns ranked by two sorts, w and Δw gathered and scattered once a
     column (kddb); ``segments`` the pieces a shard's round is cut into
     (``plan.t``), ``table_width`` the slots a step takes in the chain's
-    SMEM tables (``plan.w_r``: whole 32-slot groups, 64 for rows of 39: the
-    chain walks its groups as far as the row's length reaches).
+    SMEM tables (``plan.w_r``: whole 8-slot groups, the rectangle's own: 40
+    for rows of 39).  ``slot_walk``, ``slots_walked`` (the same kernel):
+    how a step's two passes cover them — ``unrolled``: all ``table_width``
+    slots written out, no dynamic trip (the loader saw rows of one length,
+    data/sharding.rows_of_one_length: criteo walks 40.0 slots for 39
+    nonzeros); ``grouped``: the first 32 written out whatever the row's
+    length, and past them the row's whole 32-slot groups, then its 8-slot
+    groups, as far as its length reaches — ``slots_walked`` the mean over
+    the real rows, counted on the host from their lengths (None where they
+    are not known; kddb's 37.7 for 29.4 nonzeros, 42.9 in whole 32-slot
+    groups).
     ``row_align`` (None where there is no fold cache: ``fori`` and every
     sparse path): how the fold cache comes to be the row-major rows of
     whole lane tiles those kernels read — ``stored``: it is kept so
@@ -305,6 +315,8 @@ class SolverPath:
     local_ids: Optional[str] = None
     segments: Optional[int] = None
     table_width: Optional[int] = None
+    slots_walked: Optional[float] = None
+    slot_walk: Optional[str] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -336,6 +348,19 @@ class SolverPath:
             return "pallas_interpret" if self.interpret else "pallas"
         return "xla"
 
+    def _walk(self) -> str:
+        """The clause of :meth:`describe` on the HBM-state chain's walk."""
+        from cocoa_tpu.ops.pallas_sparse_hbm import (GROUP, TAIL_GROUP,
+                                                     walk_head)
+
+        how = (", unrolled" if self.slot_walk == "unrolled" else
+               f", the first {walk_head(self.table_width, False)} written "
+               f"out, then in groups of {GROUP} and {TAIL_GROUP}")
+        if self.slots_walked is None:       # the lengths are not known
+            return f", a row's slots walked{how}"
+        return (f", walks {self.slots_walked:.1f} of {self.table_width} "
+                f"slots a step{how}")
+
     def describe(self) -> str:
         how = "interpreted" if self.interpret else "compiled"
         if self.chain == "xla":
@@ -349,7 +374,8 @@ class SolverPath:
                        and self.state == "hbm" else "")
                     + (f" ({self.local_ids} local ids, {self.segments} "
                        f"segment(s) a shard, tables {self.table_width} "
-                       f"slots wide)" if self.local_ids else "")
+                       f"slots wide{self._walk()})" if self.local_ids
+                       else "")
                     + (f" [{self.refused}]" if self.refused else ""))
         rows = (", rows stored row-major" if self.rows == "row_major"
                 else ", rows relaid by a kernel once a dispatch"
@@ -410,9 +436,12 @@ def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
 
 
 def _slot_stats(ds: ShardedDataset) -> tuple:
-    """``(slot_fill, longest_row, chunk_fill)`` of a sparse dataset
-    (:class:`SolverPath`; ``chunk_fill`` None off the stream), counted once
-    on the host from the row lengths it carries and kept on it."""
+    """``(slot_fill, longest_row, chunk_fill, slots_walked)`` of a sparse
+    dataset (:class:`SolverPath`; ``chunk_fill`` None off the stream,
+    ``slots_walked`` — the mean slots a pass of the HBM-state chain walks
+    for a real row, ops/pallas_sparse_hbm.walk_slots — None on it),
+    counted once on the host from the row lengths it carries and kept on
+    it."""
     stream = ds.sp_row_ptr is not None
     row_len = ds.sp_row_len if stream else getattr(ds, "_row_len_cache",
                                                    None)
@@ -420,19 +449,37 @@ def _slot_stats(ds: ShardedDataset) -> tuple:
                  else ds.sp_indices.shape[-1])
     if not isinstance(row_len, jax.Array):      # none, or a shape alone:
         # what the loader saw (data/sharding.note_row_lengths), else W
-        return None, int(getattr(ds, "_longest_row", widest)), None
+        return None, int(getattr(ds, "_longest_row", widest)), None, None
     cached = getattr(ds, "_slot_stats_cache", None)
     if cached is None:
         lens = np.asarray(row_len, np.int64)
-        fill = None
+        fill = walked = None
         if stream:
             from cocoa_tpu.ops.pallas_longrows import chunk_fill
 
             fill = chunk_fill(ds.sp_row_ptr, lens)
+        else:
+            from cocoa_tpu.ops.pallas_sparse_hbm import walk_slots
+
+            # (the rows that pad a shard have no nonzero and no step)
+            walked = float(walk_slots(lens[lens > 0], widest,
+                                      rows_of_one_length(ds)).sum()
+                           / max(1, ds.n))
         cached = (float(lens.sum() / max(1, ds.sp_indices.size)),
-                  int(lens.max(initial=0)), fill)
+                  int(lens.max(initial=0)), fill, walked)
         ds._slot_stats_cache = cached
     return cached
+
+
+def _hbm_plan(ds: ShardedDataset, local_iters: int):
+    """The plan of the HBM-state sparse kernel for a round of
+    ``local_iters`` steps on ``ds`` (ops/pallas_sparse_hbm.hbm_plan): from
+    the shapes, and from whether the loader saw rows of one length."""
+    from cocoa_tpu.ops.pallas_sparse_hbm import hbm_plan
+
+    return hbm_plan(ds.num_features, int(ds.sp_indices.shape[-1]),
+                    local_iters, jnp.dtype(ds.labels.dtype).itemsize,
+                    one_length=rows_of_one_length(ds))
 
 
 def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
@@ -603,7 +650,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
     )
     if sparse:
         (placement["slot_fill"], placement["longest_row"],
-         placement["chunk_fill"]) = _slot_stats(ds)
+         placement["chunk_fill"]) = _slot_stats(ds)[:3]
     if stream:
         from cocoa_tpu.ops.pallas_longrows import CHUNK_PIECES
 
@@ -619,12 +666,13 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                 classes=classes,
                 lane_fill=classes / class_rows(classes) if pallas else None)
         if pallas and hbm_state:
-            from cocoa_tpu.ops.pallas_sparse_hbm import hbm_plan
-
-            plan = hbm_plan(ds.num_features, width, local_iters, itemsize)
+            plan = _hbm_plan(ds, local_iters)
             placement.update(
                 local_ids="direct" if plan.direct else "sorted",
-                segments=plan.t, table_width=plan.w_r)
+                segments=plan.t, table_width=plan.w_r,
+                slots_walked=(float(plan.w_r) if plan.unrolled
+                              else _slot_stats(ds)[3]),
+                slot_walk="unrolled" if plan.unrolled else "grouped")
         form = depth = None
         if pallas and not sparse:
             # the dense kernel's form and its ring's depth, from the VMEM
@@ -705,6 +753,7 @@ def _sdca_round_parts(
     pallas: bool = False,
     pallas_interpret: bool = False,
     pallas_state: str = "vmem",
+    hbm_plan=None,
     block: int = 0,
     block_chain: str = "xla",
     block_distinct: bool = False,
@@ -721,10 +770,12 @@ def _sdca_round_parts(
     step — equal in real arithmetic, rounds differently than the reference
     order.  ``pallas=True`` further runs the inner loop as a Pallas TPU
     kernel — ops/pallas_sdca.py for the dense layout, ops/pallas_sparse.py
-    for padded-CSR.  ``block > 0`` runs the fast inner loop as the
-    block-coordinate MXU kernel (ops/local_sdca.local_sdca_block) with that
-    block size (a round of more than one block takes the two-phase
-    software-pipelined scan, see local_sdca_block_batched).  Returns
+    for padded-CSR (``pallas_state="hbm"``: ops/pallas_sparse_hbm.py, on
+    ``hbm_plan``, the plan the resolver reported).  ``block > 0`` runs the
+    fast inner loop as the block-coordinate MXU kernel
+    (ops/local_sdca.local_sdca_block) with that block size (a round of more
+    than one block takes the two-phase software-pipelined scan, see
+    local_sdca_block_batched).  Returns
     (per_shard, per_round_batched | None, apply_fn).
 
     ``classes`` = T > 1 (a one-vs-rest job, one chip): the state is w
@@ -794,7 +845,7 @@ def _sdca_round_parts(
             batched = jax.tree.map(lambda a: a[None], shard_k)
             dw, a_inner = _pallas_batched(
                 w, alpha_k[None], idxs_k[None], batched, params, mode,
-                sigma, pallas_interpret, pallas_state,
+                sigma, pallas_interpret, pallas_state, hbm_plan,
             )
             da = a_inner[0] - alpha_k
             return dw[0], alpha_k + scaling * da
@@ -825,7 +876,7 @@ def _sdca_round_parts(
         def per_round_batched(w, alpha, idxs_kh, shards):
             dw, a_inner = _pallas_batched(
                 w, alpha, idxs_kh, shards, params, mode, sigma,
-                pallas_interpret, pallas_state,
+                pallas_interpret, pallas_state, hbm_plan,
             )
             alpha_new = alpha + scaling * (a_inner - alpha)
             return dw.sum(axis=0), alpha_new
@@ -1453,6 +1504,8 @@ def run_sdca_family(
         classes=classes, math=math, pallas=pallas,
         pallas_interpret=path.pallas and path.interpret,
         pallas_state=path.state,
+        hbm_plan=(_hbm_plan(ds, params.local_iters) if path.local_ids
+                  else None),
         block=block_size, block_chain=block_chain,
         block_sparse_gram=block_sparse_gram,
         # permuted sampling with n_local % H == 0 keeps every round inside

@@ -664,7 +664,8 @@ def test_criteo_quarter_job_copies_no_rows_at_the_loaders_width(
         assert (path.kernel, path.state, path.step_solve) == (
             "pallas", "hbm", "vector")
         assert (path.local_ids, path.segments, path.table_width) == (
-            "direct", 1, 64)
+            "direct", 1, 40)
+        assert (path.slot_walk, path.slots_walked) == ("unrolled", 40.0)
         compiled = run.lower(*_on_chip(args, one_chip)).compile()
     stats = compiled.memory_analysis()
     held = stats.argument_size_in_bytes + stats.temp_size_in_bytes

@@ -165,7 +165,9 @@ def test_job_on_the_direct_plan_follows_fori_and_the_reference(gen,
     assert (path["kernel"], path["state"], path["interpret"],
             path["step_solve"]) == ("pallas", "hbm", True, "vector")
     assert (path["local_ids"], path["segments"], path["table_width"]) == (
-        "direct", 1, 64)
+        "direct", 1, 40)
+    # rows of one length: both slot loops written out, 40 slots for 39
+    assert (path["slot_walk"], path["slots_walked"]) == ("unrolled", 40.0)
     assert path["longest_row"] == 39 and ds.sp_indices.shape[-1] == 40
     assert ds.row_order is None
     assert t1.stopped == t0.stopped == "target"

@@ -356,14 +356,16 @@ def test_no_value_of_a_class_step_is_a_scalar(loss, depth, h):
     assert _scalar_floats(body) == []
 
 
-def _chain_body(loss, mode, width):
+def _chain_body(loss, mode, width, unrolled):
     """The jaxpr of the HBM-state sparse round's chain kernel
     (``pallas_sparse_hbm._chain_kernel``), traced at a toy size: the
     kernel's body does not depend on the sizes but through the slot
-    group's unrolling (``width`` slots a trip)."""
+    loops' bodies (``width`` slots a pass, written out where ``unrolled``,
+    else a 32-slot and an 8-slot trip body)."""
     import jax
 
-    from cocoa_tpu.ops.pallas_sparse_hbm import pallas_sparse_hbm_round
+    from cocoa_tpu.ops.pallas_sparse_hbm import (hbm_plan,
+                                                 pallas_sparse_hbm_round)
 
     k, n_shard, d, h = 2, 64, 512, 16
     rows = jnp.ones((k, n_shard))
@@ -373,16 +375,19 @@ def _chain_body(loss, mode, width):
             jnp.zeros((k, h), jnp.int32))
     traced = jax.make_jaxpr(lambda *a: pallas_sparse_hbm_round(
         *a, 0.01, 1000, mode=mode, sigma=3.0, loss=loss, smoothing=S,
-        interpret=True))(*args)
+        interpret=True,
+        plan=hbm_plan(d, width, h, one_length=unrolled)))(*args)
     (call,) = [e for e in _walk(traced.jaxpr)
                if e.primitive.name == "pallas_call"
                and e.params["name"] == "pallas_sparse_hbm_round"]
     return call.params["jaxpr"]
 
 
+@pytest.mark.parametrize("walk", ["grouped", "unrolled"])
 @pytest.mark.parametrize("mode", ["plus", "frozen"])
 @pytest.mark.parametrize("loss", ALL)
-def test_no_value_of_a_sparse_hbm_step_is_computed_as_a_scalar(loss, mode):
+def test_no_value_of_a_sparse_hbm_step_is_computed_as_a_scalar(loss, mode,
+                                                               walk):
     """Read off the traced chain kernel of the HBM-state sparse round: the
     only 0-d floats in it are loads of its SMEM step table — y, the scaled
     norm and alpha, splatted to (1, 1) at once, and a nonzero's value in
@@ -393,9 +398,11 @@ def test_no_value_of_a_sparse_hbm_step_is_computed_as_a_scalar(loss, mode):
     so no float goes to the scalar core and comes back (PERF.md section 6,
     PR 46: the ten Newton iterations on 0-d values were 1.79 us of
     criteo's 2.95 us step).  Under logistic every ``exp`` of the traced
-    step is (1, 1): ``_NEWTON_ITERS`` + 1 of them and one ``log``."""
-    width = 8
-    body = _chain_body(loss, mode, width)
+    step is (1, 1): ``_NEWTON_ITERS`` + 1 of them and one ``log``.  Both
+    walks of a step's slots (PR 47): 40 slots written out, and a 32-slot
+    and an 8-slot trip body."""
+    width = 40
+    body = _chain_body(loss, mode, width, walk == "unrolled")
     scalars = _scalar_float_eqns(body)
     assert {e.primitive.name for e in scalars} == {"get"}, [
         str(e)[:120] for e in scalars if e.primitive.name != "get"]

@@ -14,6 +14,8 @@ gather-sum) dw and alpha agree to a few float32 ulps of values of order 1:
 steps is held to 2e-5.
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -52,7 +54,8 @@ def _shards(k, n_shard, d, width, seed, lengths="mixed"):
     return cols, vals, y, (vals.astype(np.float64) ** 2).sum(-1).astype(F32)
 
 
-def _fori(w, alpha, cols, vals, y, sq, idxs, n, mode, sigma, loss):
+def _fori(w, alpha, cols, vals, y, sq, idxs, n, mode, sigma, loss,
+          smoothing=1.0):
     """The portable path: shard_margins + local_sdca_fast per shard."""
     dws, alphas = [], []
     for a in range(cols.shape[0]):
@@ -60,7 +63,8 @@ def _fori(w, alpha, cols, vals, y, sq, idxs, n, mode, sigma, loss):
                      sq_norms=sq[a])
         da, dw = local_sdca_fast(
             shard_margins(w, shard), alpha[a], shard, idxs[a], LAM, n,
-            jnp.zeros_like(w), mode=mode, sigma=sigma, loss=loss)
+            jnp.zeros_like(w), mode=mode, sigma=sigma, loss=loss,
+            smoothing=smoothing)
         dws.append(dw)
         alphas.append(alpha[a] + da)
     return sum(dws), jnp.stack(alphas)
@@ -263,25 +267,33 @@ def test_resolver_never_leaves_a_sparse_set_on_fori_on_a_tpu(name,
 @pytest.mark.parametrize("name, plan", [
     # a quarter of criteo (chipbench/configs/criteo.json), as the loader
     # stores rows of 39 and at 39 itself: d = 10^6 fits the budget whole
-    ("criteo_quarter", (11460154, 1000000, 8, 40, "direct", 1, 64)),
-    ("criteo_quarter_at_39", (11460154, 1000000, 8, 39, "direct", 1, 64)),
+    ("criteo_quarter", (11460154, 1000000, 8, 40, "direct", 1, 40)),
+    ("criteo_quarter_at_39", (11460154, 1000000, 8, 39, "direct", 1, 40)),
     ("kddb", (19264097, 29890095, 8, 64, "sorted", 2, 64)),
 ])
 def test_solver_path_says_which_plan_the_hbm_kernel_runs(name, plan,
                                                          monkeypatch):
-    """``hbm_plan`` at a cell's sizes, and the three fields of the run's
-    record that repeat it: ``direct`` (the local id is the column, [w | dw]
-    whole in VMEM, ``t`` = 1) at d = 10^6, ``sorted`` in two segments at
-    kddb; None off the HBM-state kernel."""
+    """``hbm_plan`` at a cell's sizes, and the fields of the run's record
+    that repeat it: ``direct`` (the local id is the column, [w | dw] whole
+    in VMEM, ``t`` = 1) at d = 10^6, ``sorted`` in two segments at kddb;
+    the tables as wide as the rectangle's own 8-slot groups (40 for rows
+    of 39, stored at 40 or forced to 39); the walk ``unrolled`` over all of
+    them where the loader saw rows of one length and ``grouped`` otherwise
+    (shapes alone carry no lengths: ``slots_walked`` None); None off the
+    HBM-state kernel."""
     from cocoa_tpu.solvers import cocoa as cocoa_mod
 
     n, d, k, width, ids, segments, table = plan
     h = int(0.1 * n / k)
     p = ph.hbm_plan(d, width, h)
-    assert (p.direct, p.t, p.w_r) == (ids == "direct", segments, table)
+    assert (p.direct, p.t, p.w_r, p.unrolled) == (ids == "direct", segments,
+                                                  table, False)
+    assert ph._table_width(p.w_r) == (48 if table == 40 else 80)
     if ids == "direct":
         assert (p.s, p.m) == (143264, 1000448)      # [w | dw]: 8 MB
         assert ph.hbm_vmem_estimate(p.s, p.m, 4) < 10 << 20
+        assert ph.hbm_plan(d, width, h, one_length=True) == \
+            dataclasses.replace(p, unrolled=True)
     ds = _shapes(n, d, k, width)
     tpu = type("Device", (), {"platform": "tpu"})()
     monkeypatch.setattr(cocoa_mod.jax, "devices", lambda *a: [tpu])
@@ -293,12 +305,20 @@ def test_solver_path_says_which_plan_the_hbm_kernel_runs(name, plan,
     assert "each step solved on the vector unit" in path.describe()
     assert (path.local_ids, path.segments, path.table_width) == (
         ids, segments, table)
+    assert (path.slot_walk, path.slots_walked) == ("grouped", None)
     assert f"{ids} local ids, {segments} segment(s) a shard, tables " \
-        f"{table} slots wide" in path.describe()
+        f"{table} slots wide, a row's slots walked, the first 32 written " \
+        f"out, then in groups of 32 and 8)" in path.describe()
+    ds._one_length = True               # data/sharding.note_row_lengths
+    one = cocoa_mod.resolve_solver_path(ds, h, None, math="fast")
+    assert (one.slot_walk, one.slots_walked, one.table_width) == (
+        "unrolled", float(table), table)
+    assert f"tables {table} slots wide, walks {table}.0 of {table} slots " \
+        f"a step, unrolled)" in one.describe()
     monkeypatch.undo()
     cpu = cocoa_mod.resolve_solver_path(ds, h, None, math="fast")
-    assert (cpu.kernel, cpu.local_ids, cpu.segments, cpu.table_width) == (
-        "fori", None, None, None)
+    assert (cpu.kernel, cpu.local_ids, cpu.segments, cpu.table_width,
+            cpu.slot_walk, cpu.slots_walked) == ("fori",) + (None,) * 5
     assert cpu.step_solve == "scalar"
     assert "local ids" not in cpu.describe()
     rcv1 = _shapes(*SHAPES["rcv1"][:4])
