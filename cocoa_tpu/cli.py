@@ -206,6 +206,12 @@ def _resolve_auto_block(ds_active, mesh, k: int, dtype,
     return bs
 
 
+def _device_memory_limit():
+    """What the first device says it can hold (``memory_stats()``'s
+    ``bytes_limit``), or None on a backend without the counter (the CPU)."""
+    return (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
 def parse_args(argv: list[str]):
     """--key=value (or bare --flag == true, hingeDriver.scala:13-19)."""
     options: dict[str, str] = {}
@@ -1125,12 +1131,12 @@ def main(argv=None) -> int:
         elif mesh is not None:
             why = ("--classes trains on one chip (--mesh=1): the class "
                    "axis is not carried across a mesh")
-        elif cfg.layout == "sparse" or extras["hotCols"] is not None \
-                or ed_spec != "false":
-            why = ("--classes needs dense rows (--layout=dense): no kernel "
-                   "carries the class axis on sparse rows yet, so "
-                   "--layout=sparse, --hotCols and --evalDense do not "
-                   "apply")
+        elif extras["hotCols"] is not None or ed_spec != "false":
+            why = ("--classes trains on dense rows (a class a sublane) or "
+                   "on a padded-CSR rectangle (--layout=sparse: the classes "
+                   "on the lanes, label sets too); the hot-column panel "
+                   "and the dense eval twin carry no class axis: drop "
+                   "--hotCols / --evalDense")
         elif extras["ingestCache"] or (extras["ingest"] or "auto") \
                 not in ("auto", "whole"):
             why = ("--classes reads the file whole: --ingest=stream and "
@@ -1485,17 +1491,49 @@ def main(argv=None) -> int:
                 return 2
             n = data.n
             if data.num_classes > 1:
-                if resolve_layout(data, cfg.layout, mesh) != "dense":
-                    print(f"error: --classes needs dense rows and "
-                          f"--layout={cfg.layout} resolves sparse for this "
-                          f"file: pass --layout=dense (no kernel carries "
-                          f"the class axis on sparse rows yet)",
-                          file=sys.stderr)
+                # what runs: dense rows (one class id a row, a class a
+                # sublane of the dense kernel) and a padded-CSR rectangle
+                # (label sets too, the classes on the lanes of the
+                # HBM-state chain).  What does not, yet: a mesh, --accel,
+                # checkpoints (refused above and in run_cocoa), rows kept
+                # as a stream, and T x d past one chip's HBM
+                sets = data.classes.ndim == 2
+                lays = resolve_layout(data, cfg.layout, mesh)
+                if sets and lays == "dense":
+                    print(f"error: the file's rows carry label SETS (up to "
+                          f"{data.classes.shape[1]} labels a row), which "
+                          f"train on sparse rows, the class axis on the "
+                          f"lanes, and --layout={cfg.layout} resolves "
+                          f"dense for this file: pass --layout=sparse "
+                          f"(docs/DESIGN.md, one-vs-rest)", file=sys.stderr)
                     return 2
+                if lays != "dense":
+                    from cocoa_tpu.data.sharding import class_pad
+
+                    # the state alone against what the device says it
+                    # has (a backend without the counter, the CPU, refuses
+                    # nothing here)
+                    state = 4.0 * class_pad(data.num_classes) * (
+                        3 * cfg.num_features + n)
+                    limit = _device_memory_limit()
+                    if limit and state > limit:
+                        print(f"error: {data.num_classes} classes on "
+                              f"sparse rows hold W, two dW (d x T_pad) and "
+                              f"alpha (n x T_pad) in one device's memory: "
+                              f"{state / 1e9:.3g} GB of the {limit / 1e9:.3g}"
+                              f" GB here; T x d past one chip is not "
+                              f"carried yet (train the labels in batches: "
+                              f"one-vs-rest models are independent)",
+                              file=sys.stderr)
+                        return 2
                 if not quiet:
                     print(f"classes: {data.num_classes} found "
-                          f"({list(data.class_values)[:12]}), trained "
-                          f"one-vs-rest over the one copy of the rows")
+                          f"({list(data.class_values)[:12]}"
+                          + (f"; label sets, up to {data.classes.shape[1]} "
+                             f"a row" if sets else "")
+                          + f"), trained one-vs-rest over the one copy of "
+                          f"the rows, the class axis on the "
+                          f"{'sublanes' if lays == 'dense' else 'lanes'}")
 
             # --hotCols=auto|off|<n>: the hot/cold column split (sparse
             # layout only, data/hybrid.py).  Resolved HERE — against the
@@ -1542,7 +1580,8 @@ def main(argv=None) -> int:
                                        eval_dense=eval_dense,
                                        hot_cols=hot_n,
                                        cache=train_handle,
-                                       rectangle=not cfg.just_cocoa)
+                                       rectangle=(not cfg.just_cocoa
+                                                  or data.num_classes > 1))
                     status = "off"
                     if train_handle is not None:
                         status = populate_whole(
@@ -1589,7 +1628,8 @@ def main(argv=None) -> int:
                                             eval_dense=eval_dense,
                                             hot_cols=hot_n,
                                             cache=test_handle,
-                                            rectangle=not cfg.just_cocoa)
+                                            rectangle=(not cfg.just_cocoa
+                                                  or data.num_classes > 1))
                     status = "off"
                     if test_handle is not None:
                         status = populate_whole(

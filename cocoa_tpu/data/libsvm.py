@@ -60,7 +60,9 @@ class LibsvmData:
     # a multi-class file read as one (:func:`load_libsvm` ``classes=``):
     # class ids 0..T-1 BESIDE the +-1 ``labels`` (the reference's rule,
     # kept), the count T, and the file's own label value of each id
-    classes: Optional[np.ndarray] = None   # (n,) int32
+    # (a MULTI-LABEL file, a row's label a comma-separated set: (n, L) ids,
+    # L the largest set, -1 where a set is shorter)
+    classes: Optional[np.ndarray] = None   # (n,) or (n, L) int32
     num_classes: int = 1
     class_values: Optional[tuple] = None   # (T,) the labels as the file has them
 
@@ -222,6 +224,33 @@ def _parse_python_stream(path: str, num_features: int, lo: int, hi):
     return data, np.asarray(offsets, dtype=np.int64)
 
 
+def _load_unlabelled_rows(path: str, num_features: int) -> LibsvmData:
+    """A multi-label file's rows (:func:`_label_sets`): a row in no
+    label's set opens with its first FEATURE, which the parsers would take
+    for the label, so such a line is parsed behind a label of its own.
+    Line by line in Python: the file is read whole (``--classes``)."""
+    rows = []
+    with open(path, "rb") as f:
+        for raw in f:
+            line = raw.decode("latin-1")
+            first = line.split(None, 1)
+            if first and ":" in first[0]:
+                line = "-1 " + line
+            row = _parse_line(line)
+            if row is not None:
+                rows.append(row)
+    indptr = np.zeros(len(rows) + 1, np.int64)
+    np.cumsum([len(r[1]) for r in rows], out=indptr[1:])
+    cat = lambda i, dt: (np.concatenate([r[i] for r in rows])  # noqa: E731
+                         if rows else np.empty(0, dt))
+    return _validate(LibsvmData(
+        # jaxlint: allow=f64 -- exact parse output; cast at device_put
+        labels=np.asarray([r[0] for r in rows], np.float64), indptr=indptr,
+        # jaxlint: allow=f64 -- exact parse output; cast at device_put
+        indices=cat(1, np.int32), values=cat(2, np.float64),
+        num_features=num_features), path)
+
+
 def load_libsvm_python(path: str, num_features: int) -> LibsvmData:
     """Pure-Python reference parser (semantic oracle for the native one)."""
     return _parse_python_stream(path, num_features, 0, None)[0]
@@ -254,37 +283,57 @@ def _validate(data: LibsvmData, path: str) -> LibsvmData:
     return data
 
 
-def read_classes(path: str, expect=None) -> tuple:
-    """``(class ids (n,) int32, T, the file's label of each id)`` of a
-    multi-class LIBSVM file: the first token of every non-blank line, read
-    as a number (LIBSVM's multi-class labels are integers: 0..9, 1..T, any
-    set of them), the distinct values in ascending order numbered 0..T-1.
-    ``expect`` (an int): the count the caller states; a file that holds
-    another count is refused with both numbers."""
-    values = []
+def _label_sets(path: str) -> tuple:
+    """``(values, sizes)`` of a file's label column: every row's labels in
+    file order as one flat list of numbers, and how many each row has.  A
+    row's label is one number (a multi-class file) or LIBSVM's multi-label
+    form, numbers joined by commas (``3,17,204 12:0.5 ...``); a row whose
+    line opens with a feature (``12:0.5 ...``, the extreme-classification
+    files' row in no label's set) has none."""
+    values, sizes = [], []
     with open(path, "rb") as f:
         for raw in f:
             parts = raw.split(None, 1)
             if not parts:
                 continue
             token = parts[0].decode("ascii", "replace")
+            labels = [] if ":" in token else token.split(",")
             try:
-                if not _NUM_CHARS.issuperset(token):
+                if not all(t and _NUM_CHARS.issuperset(t) for t in labels):
                     raise ValueError
-                values.append(float(token))
+                values.extend(float(t) for t in labels)
             except ValueError:
                 raise ValueError(
-                    f"{path}: row {len(values) + 1} has the label "
-                    f"{token!r}; a multi-class file's labels are numbers"
+                    f"{path}: row {len(sizes) + 1} has the label "
+                    f"{token!r}; a multi-class file's labels are numbers "
+                    f"(a multi-label row's, numbers joined by commas)"
                 ) from None
+            sizes.append(len(labels))
+    return values, np.asarray(sizes, np.int64)
+
+
+def read_classes(path: str, expect=None) -> tuple:
+    """``(class ids int32, T, the file's label of each id)`` of a
+    multi-class LIBSVM file: the first token of every non-blank line, read
+    as a number (LIBSVM's multi-class labels are integers: 0..9, 1..T, any
+    set of them), the distinct values in ascending order numbered 0..T-1.
+    The ids are (n,) where every row has exactly one label, else (n, L)
+    label SETS (:func:`_label_sets`), L the largest set and -1 past a
+    row's own.  ``expect`` (an int): the count the caller states; a file
+    that holds another count is refused with both numbers."""
+    values, sizes = _label_sets(path)
     # jaxlint: allow=f64 -- host-side parse of the label column
     found, ids = np.unique(np.asarray(values, np.float64),
                            return_inverse=True)
+    if sizes.size and not np.all(sizes == 1):
+        sets = np.full((len(sizes), max(1, int(sizes.max()))), -1, np.int64)
+        sets[np.arange(sets.shape[1]) < sizes[:, None]] = ids
+        ids = sets
     if expect is not None and len(found) != int(expect):
         raise ValueError(
             f"{path}: {int(expect)} classes were stated and the file holds "
             f"{len(found)} distinct labels ({found[:12].tolist()}"
-            f"{' ...' if len(found) > 12 else ''}) over {len(values)} rows")
+            f"{' ...' if len(found) > 12 else ''}) over {len(sizes)} rows")
     if len(found) < 2:
         raise ValueError(
             f"{path}: a multi-class file needs at least two distinct "
@@ -305,9 +354,10 @@ def load_libsvm(path: str, num_features: int, prefer_native: bool = True,
     job over it trains one model per class, one-vs-rest
     (solvers/cocoa.run_cocoa); an int is the count the caller expects."""
     if classes is not None:
-        data = load_libsvm(path, num_features, prefer_native)
         expect = None if str(classes).lower() == "auto" else int(classes)
         ids, count, found = read_classes(path, expect)
+        data = (_load_unlabelled_rows(path, num_features) if ids.ndim == 2
+                else load_libsvm(path, num_features, prefer_native))
         if len(ids) != data.n:
             raise ValueError(f"{path}: {len(ids)} labelled rows against "
                              f"{data.n} parsed rows")

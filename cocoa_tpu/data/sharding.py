@@ -222,7 +222,13 @@ class ShardedDataset:
                                       #   label of class t against the rest,
                                       #   +1 where classes == t else -1, is
                                       #   derived where it is used, never
-                                      #   stored T times (:func:`class_labels`)
+                                      #   stored T times (:func:`class_labels`).
+                                      #   A MULTI-LABEL set keeps a row's SET
+                                      #   of labels: (K, n_shard, L) ids, a
+                                      #   set shorter than L (and a padding
+                                      #   row) filled with -1; y_ti = +1
+                                      #   where t is among row i's ids.  One
+                                      #   id a row is the set of size one
     num_classes: int = 1              # T: how many models a job over these
                                       #   rows trains, one-vs-rest.  1 (every
                                       #   binary set): ``classes`` is None
@@ -240,6 +246,14 @@ class ShardedDataset:
     @property
     def n_shard(self) -> int:
         return self.labels.shape[1]
+
+    @property
+    def label_slots(self) -> Optional[int]:
+        """Ids a row's label set is stored in: 1 where ``classes`` holds one
+        class id a row, L where it holds sets, None at T = 1."""
+        if self.classes is None:
+            return None
+        return 1 if self.classes.ndim == 2 else int(self.classes.shape[-1])
 
     @property
     def dtype(self):
@@ -320,11 +334,60 @@ class ShardedDataset:
 
 def class_labels(classes: jax.Array, mask: jax.Array, t) -> jax.Array:
     """The labels of class ``t`` against the rest over rows whose class ids
-    are ``classes``: +1 where the id is ``t``, -1 elsewhere, 0 on padding
-    (``mask``), in ``mask``'s dtype.  ``t`` may be traced, or an array that
-    broadcasts against ``classes`` (a leading class axis gives every
-    class's labels at once)."""
-    return jnp.where(classes == t, 1.0, -1.0).astype(mask.dtype) * mask
+    are ``classes``: +1 where the id is ``t`` (label sets, a trailing axis
+    of ids beside ``mask``'s: where ``t`` is among the row's ids), -1
+    elsewhere, 0 on padding (``mask``), in ``mask``'s dtype.  ``t`` may be
+    traced, or an array that broadcasts against ``mask`` (a leading class
+    axis gives every class's labels at once)."""
+    if classes.ndim > mask.ndim:
+        hit = (classes == jnp.asarray(t, classes.dtype)[..., None]).any(-1)
+    else:
+        hit = classes == t
+    return jnp.where(hit, 1.0, -1.0).astype(mask.dtype) * mask
+
+
+# the class axis on the LANES (sparse rows, ops/pallas_sparse_lanes.py): T
+# class models are held T_pad wide, whole (8, 128) float32 tiles, and kept
+# AS tiles: W is (d, R, 128) and alpha (K, n_shard, R, 128), R = T_pad / 128,
+# class t at [t // 128, t % 128].  A TPU tiles an array's last two axes, so
+# this is the shape in which one column's T values, or one row's, are one
+# contiguous 4 KB run of HBM (a (d, T_pad) array is tiled over 8 columns)
+CLASS_TILE = 1024
+CLASS_LANES = 128
+
+
+def class_pad(num_classes: int) -> int:
+    """T_pad: ``num_classes`` rounded up to whole CLASS_TILE-lane tiles."""
+    return -(-int(num_classes) // CLASS_TILE) * CLASS_TILE
+
+
+def class_tile_shape(num_classes: int) -> tuple:
+    """(R, 128): the trailing axes that hold T_pad class values."""
+    return (class_pad(num_classes) // CLASS_LANES, CLASS_LANES)
+
+
+def class_vector(tiles: jax.Array, num_classes: int) -> jax.Array:
+    """The (.., T) values of the real classes out of (.., R, 128) tiles."""
+    return tiles.reshape(tiles.shape[:-2] + (-1,))[..., :num_classes]
+
+
+def label_sets(classes: jax.Array, rows_ndim: int) -> jax.Array:
+    """``classes`` with the slot axis it may lack: (.., L) ids for rows of
+    ``rows_ndim`` leading axes (one class id a row is the set of size
+    one)."""
+    return classes if classes.ndim > rows_ndim else classes[..., None]
+
+
+def class_signs(ids: jax.Array, num_classes: int, dtype) -> jax.Array:
+    """y with the class axis LAST, as tiles: for rows whose label sets are
+    ``ids`` (.., L) (:func:`label_sets`; -1 fills a short set), the
+    (.., R, 128) array that is +1 where class t is among the row's ids and
+    -1 elsewhere.  A padding row is all -1: the caller's mask leaves it
+    out."""
+    shape = class_tile_shape(num_classes)
+    lanes = jnp.arange(shape[0] * shape[1], dtype=ids.dtype).reshape(shape)
+    return jnp.where((ids[..., None, None] == lanes).any(-3), 1.0,
+                     -1.0).astype(dtype)
 
 
 try:
@@ -464,10 +527,8 @@ def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
     row (ops/rows.SLOT_GROUP).  α, and anything else kept by row, is in the
     dataset's order from here on; :func:`rows_as_built` maps it back."""
     if (ds.layout != "sparse" or ds.row_order is not None
-            or ds.sp_row_ptr is not None or ds.classes is not None):
-        return ds               # (a stream's passes go by nonzeros as it is;
-                                # class ids are kept as built: no solver
-                                # carries the class axis on sparse rows yet)
+            or ds.sp_row_ptr is not None):
+        return ds               # (a stream's passes go by nonzeros as it is)
     from cocoa_tpu.ops.pallas_sparse import row_lengths
 
     row_len = getattr(ds, "_row_len_cache", None)
@@ -476,6 +537,11 @@ def order_rows_by_length(ds: "ShardedDataset") -> "ShardedDataset":
     order, key, (row_len, ds.labels, ds.mask, ds.sq_norms) = \
         _order_row_scalars(row_len, ds.sp_values.shape[-1],
                            [row_len, ds.labels, ds.mask, ds.sq_norms])
+    if ds.classes is not None:
+        # the class ids (a row's label set) go with their rows
+        ds.classes = jnp.take_along_axis(
+            ds.classes, order.reshape(order.shape + (1,) * (
+                ds.classes.ndim - 2)), 1)
     for name in ("sp_indices", "sp_values", "X_hot", "X_eval"):
         a = getattr(ds, name)
         if a is None:
@@ -928,7 +994,12 @@ def shard_dataset(
     num_classes = int(getattr(data, "num_classes", 1))
     if num_classes > 1:
         # beside the labels, not through the slab cache: ids by row
-        arrs["classes"] = np.zeros((k, n_shard), np.int32)
+        # (label sets: a trailing axis of ids, -1 where a set is shorter;
+        # a padding row has no label)
+        sets = np.ndim(data.classes) == 2
+        arrs["classes"] = np.full(
+            (k, n_shard) + np.shape(data.classes)[1:], -1 if sets else 0,
+            np.int32)
         for s in range(k):
             arrs["classes"][s, :sizes[s]] = \
                 data.classes[offsets[s]:offsets[s + 1]]

@@ -79,7 +79,7 @@ def eval_metrics(
     w, alpha, shard_arrays, lam, n, mesh=None,
     test_shard_arrays=None, test_n: int = 0,
     loss: str = "hinge", smoothing: float = 1.0,
-    inv_n=None,
+    inv_n=None, classes: int = 0,
 ):
     """Jit-traceable fused evaluation: (primal, gap, test_error) as one
     stacked device array — a single fan-out over the training data (plus one
@@ -98,7 +98,15 @@ def eval_metrics(
     reciprocal multiply; a traced n cannot be folded, so the fleet passes
     the same f32 reciprocal explicitly — which is what keeps a T=1 fleet
     eval bit-identical to the solo certificate (tests/test_fleet.py).
+
+    ``classes`` = T: a one-vs-rest job whose class axis rides the lanes
+    (w (d, R, 128): sparse rows, ops/pallas_sparse_lanes.py) says how many
+    of its T_pad lanes are models; a dense one's w (T, d) says so itself.
     """
+    if w.ndim == 3:
+        return _eval_metrics_lanes(
+            w, alpha, shard_arrays, lam, n, mesh, test_shard_arrays,
+            test_n, loss, smoothing, classes)
     if w.ndim == 2:
         return _eval_metrics_classes(
             w, alpha, shard_arrays, lam, n, mesh, test_shard_arrays,
@@ -195,8 +203,46 @@ def _eval_metrics_classes(w, alpha, shard_arrays, lam, n, mesh,
     return jnp.concatenate([head, gaps])
 
 
+def _eval_metrics_lanes(w, alpha, shard_arrays, lam, n, mesh,
+                        test_shard_arrays, test_n, loss, smoothing, classes):
+    """:func:`_eval_metrics_classes` with the class axis on the lanes: w
+    (d, R, 128), alpha (K, n_shard, R, 128) over padded-CSR rows that carry
+    label sets, ``classes`` = T of the R·128 lanes models.  One blocked
+    pass over the rows, a shard after another (ops/rows.class_loss_sums:
+    a W row a nonzero, T margins a row), gives all T primal / dual / gap
+    values; the same vector comes back, ``[primal, gap, test_error, gap_0
+    .. gap_{T-1}]`` on the worst class.  The test error of a multi-label
+    set is label-wise: the share of (row, class) pairs with the wrong
+    sign."""
+    if (mesh is not None or "sp_indices" not in shard_arrays
+            or "sp_row_ptr" in shard_arrays or classes < 1):
+        raise ValueError("the class axis on the lanes is evaluated on "
+                         "padded-CSR rows on one chip, T stated "
+                         "(docs/DESIGN.md, one-vs-rest)")
+    from cocoa_tpu.data.sharding import class_vector
+    from cocoa_tpu.ops.rows import class_loss_sums
+
+    def shard_sums(arrays, alpha):
+        return class_vector(class_loss_sums(w, alpha, arrays, classes, loss,
+                                            smoothing), classes)   # (3, T)
+
+    sums = shard_sums(shard_arrays, alpha)
+    w_norm_sq = class_vector(jnp.sum(w * w, axis=0), classes)
+    primal = sums[0] / n + 0.5 * lam * w_norm_sq
+    gaps = primal - (sums[1] / n - 0.5 * lam * w_norm_sq)
+    worst = jnp.argmax(gaps)
+    if test_shard_arrays is not None:
+        test_err = (shard_sums(test_shard_arrays, None)[2].sum()
+                    / (test_n * classes))
+    else:
+        test_err = jnp.asarray(jnp.nan, primal.dtype)
+    head = jnp.stack([primal[worst], gaps[worst],
+                      test_err.astype(primal.dtype)])
+    return jnp.concatenate([head, gaps])
+
+
 @functools.lru_cache(maxsize=None)
-def _eval_metrics_fn(mesh, lam, n, test_n, loss, smoothing):
+def _eval_metrics_fn(mesh, lam, n, test_n, loss, smoothing, classes=0):
     # None arguments (no dual state / no test set) are empty pytrees — jit
     # specializes on the pytree structure, no separate static flags needed
     @jax.jit
@@ -204,7 +250,7 @@ def _eval_metrics_fn(mesh, lam, n, test_n, loss, smoothing):
         return eval_metrics(
             w, alpha, shard_arrays, lam, n, mesh=mesh,
             test_shard_arrays=test_shard_arrays, test_n=test_n,
-            loss=loss, smoothing=smoothing,
+            loss=loss, smoothing=smoothing, classes=classes,
         )
 
     return f
@@ -226,6 +272,8 @@ def evaluate(ds: ShardedDataset, w, alpha, lam, test_ds=None,
         mesh_of(ds.labels), float(lam), ds.n,
         test_ds.n if test_ds is not None else 0,
         loss, float(smoothing),
+        # (the class axis on the lanes: T of the T_pad lanes are models)
+        *((ds.num_classes,) if w.ndim == 3 else ()),
     )
     out = f(
         w, alpha, ds.shard_arrays(),

@@ -148,7 +148,9 @@ class HbmPlan:
     slots written out, no dynamic trip (the loader saw rows of one length:
     nothing of that walk is padding but the rectangle's own); otherwise
     the first GROUP are, and loops walk what a row has past them
-    (:func:`walk_head`)."""
+    (:func:`walk_head`).  ``t_pad`` > 0: a plan of the chain with a class
+    axis on the lanes, T_pad models wide, which
+    ops/pallas_sparse_lanes.lanes_plan makes (``hbm_plan`` never does)."""
     t: int
     s: int
     m: int
@@ -156,6 +158,7 @@ class HbmPlan:
     chunk: int
     direct: bool
     unrolled: bool = False
+    t_pad: int = 0
 
     @property
     def column_chunk(self) -> int:

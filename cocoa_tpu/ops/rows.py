@@ -347,6 +347,96 @@ def shard_margins(w: jax.Array, shard: dict) -> jax.Array:
     return m
 
 
+def class_row_block(n_rows: int, t_pad: int) -> int:
+    """Rows per block of an all-rows pass with a class axis ``t_pad`` wide
+    on the lanes: a slot group's gather is (group, block, t_pad) values, so
+    the block follows T — GATHER_BLOCK_SLOTS values a slot, 32 MB a group
+    (1,024 rows at one class tile), where :func:`row_block`'s 2^20 slots
+    would be a 4 GB temporary at 4 KB a slot."""
+    return min(n_rows, max(128, GATHER_BLOCK_SLOTS // t_pad // 128 * 128))
+
+
+def class_loss_sums(w: jax.Array, alpha, arrays: dict, classes: int,
+                    loss: str, smoothing: float) -> jax.Array:
+    """What the rows of (K, n_shard, W) padded-CSR shards add to T
+    one-vs-rest certificates, from ONE pass over them in blocks of rows
+    (:func:`class_row_block`), a shard after another: (3, R, 128) per-class
+    sums over the real rows of the primal loss at the margins x_i.W (W (d,
+    R, 128), the class axis on the lanes as tiles:
+    data/sharding.class_tile_shape), of the dual term of ``alpha`` (K,
+    n_shard, R, 128; None: zeros) and of the wrong signs y·margin <= 0.  A
+    block's margins are T values a row, a W row a nonzero, a slot group at
+    a time as far as the block's longest row reaches; y_ti comes from the
+    rows' label sets (``arrays["classes"]``) and never exists T times
+    over.  Every block is sliced from the whole arrays where it is used:
+    nothing the size of a shard's alpha is ever copied."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from cocoa_tpu.data.sharding import class_signs, label_sets
+    from cocoa_tpu.ops import losses
+    from cocoa_tpu.ops.pallas_sparse_hbm import rows_on_lanes
+
+    idx, val, mask = (arrays[f] for f in ("sp_indices", "sp_values", "mask"))
+    # (the label sets as the device stores them, the row index on the lanes)
+    ids_t = jnp.swapaxes(label_sets(arrays["classes"], 2), -1, -2)
+    row_len = arrays.get("sp_row_len")
+    k, n, width = idx.shape
+    tile = w.shape[1:]
+    block = class_row_block(n, tile[0] * tile[1])
+    nb = -(-n // block)
+    # a block is sliced from the arrays in the layout the device stores
+    # them in (the note at :func:`_block_by_groups`): rows on the lanes
+    # where that pads less (kddb's W = 64), else row-major (W = 256), where
+    # a group is 8 neighbouring slots of the block's rows
+    on_lanes = rows_on_lanes(n, width)
+    group, n_groups = _slot_groups(width)
+
+    def one(t, sums):
+        shard, b = t // nb, t % nb
+        start = jnp.minimum(b * block, n - block)
+
+        def rows(a, axis=1):
+            """Rows [start, start + block) of shard ``shard`` of ``a``."""
+            a = lax.dynamic_slice_in_dim(a, shard, 1, 0)
+            return lax.dynamic_slice_in_dim(a, start, block, axis)[0]
+
+        if on_lanes:
+            cols, vals = (_block_by_groups(
+                lax.dynamic_index_in_dim(a, shard, 0, keepdims=False),
+                start, block) for a in (idx, val))
+        else:
+            cols, vals = (jnp.pad(
+                rows(a), ((0, 0), (0, n_groups * group - width)))
+                for a in (idx, val))
+
+        def add_group(g, m):
+            if on_lanes:
+                i = lax.dynamic_index_in_dim(cols, g, 0, keepdims=False)
+                v = lax.dynamic_index_in_dim(vals, g, 0, keepdims=False)
+                return m + (w[i] * v[..., None, None]).sum(0)
+            i = lax.dynamic_slice_in_dim(cols, g * group, group, 1)
+            v = lax.dynamic_slice_in_dim(vals, g * group, group, 1)
+            return m + (w[i] * v[..., None, None]).sum(1)
+
+        trips = _group_trips(None if row_len is None else rows(row_len),
+                             0, block, 0, width)
+        m = lax.fori_loop(0, trips, add_group,
+                          jnp.zeros((block,) + tile, w.dtype))
+        # the last block starts early enough to end on the last row: the
+        # rows it shares with its neighbour are the neighbour's
+        own = (rows(mask) * (start + jnp.arange(block) >= b * block)
+               )[:, None, None]
+        ym = class_signs(rows(ids_t, 2).T, classes, w.dtype) * m
+        primal = losses.primal(loss, ym, smoothing=smoothing)
+        dual = (jnp.zeros_like(m) if alpha is None else
+                losses.dual_term(loss, rows(alpha), smoothing=smoothing))
+        return sums + jnp.stack([(primal * own).sum(0), (dual * own).sum(0),
+                                 (jnp.where(ym <= 0, 1.0, 0.0) * own).sum(0)])
+
+    return lax.fori_loop(0, k * nb, one, jnp.zeros((3,) + tile, w.dtype))
+
+
 def gather_dequant(w: jax.Array, idx: jax.Array) -> jax.Array:
     """``w[idx]`` that understands the packed low-precision serving
     forms (serving/quantize.py): the model's DEVICE dtype is the

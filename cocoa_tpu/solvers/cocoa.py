@@ -24,7 +24,8 @@ import numpy as np
 
 from cocoa_tpu.analysis import sanitize as _sanitize
 from cocoa_tpu.config import DebugParams, Params
-from cocoa_tpu.data.sharding import (ShardedDataset, order_rows_for_passes,
+from cocoa_tpu.data.sharding import (CLASS_TILE, ShardedDataset, class_pad,
+                                     class_tile_shape, order_rows_for_passes,
                                      passes_want_order, rows_as_built,
                                      rows_as_ordered, rows_of_one_length)
 from cocoa_tpu.evals import objectives
@@ -278,7 +279,19 @@ class SolverPath:
     groups, as far as its length reaches — ``slots_walked`` the mean over
     the real rows, counted on the host from their lengths (None where they
     are not known; kddb's 37.7 for 29.4 nonzeros, 42.9 in whole 32-slot
-    groups).
+    groups).  ``class_axis`` (None at T = 1): which axis of the kernels'
+    tiles carries the T class models — ``sublanes``: dense rows, w (T, d)
+    and alpha (T, K, n_shard), a class a sublane of the dense class
+    kernel's state tile (T + 2 <= 16); ``lanes``: sparse rows that carry
+    label SETS (``label_slots`` ids a row; 1: one class id a row), W (d, R,
+    128) and alpha (K, n_shard, R, 128) with the T models on the lanes of
+    ``class_tiles`` = T_pad / 1,024 whole (8, 128) tiles a column, the
+    HBM-state chain fetching a step's W and dw rows itself
+    (ops/pallas_sparse_lanes.py: ``local_ids`` ``direct``, one call a
+    shard's round, ``ids_per_segment`` the [w | dw] rows VMEM holds at a
+    time, (2 T_pad 4) B each; ``lane_fill`` T / T_pad; ``slots_walked``
+    in the rectangle's 8-slot groups).  The rule is the layout's: dense
+    rows take the sublanes, padded-CSR rows the lanes.
     ``row_align`` (None where there is no fold cache: ``fori`` and every
     sparse path): how the fold cache comes to be the row-major rows of
     whole lane tiles those kernels read — ``stored``: it is kept so
@@ -317,6 +330,10 @@ class SolverPath:
     table_width: Optional[int] = None
     slots_walked: Optional[float] = None
     slot_walk: Optional[str] = None
+    class_axis: Optional[str] = None
+    class_tiles: Optional[int] = None
+    label_slots: Optional[int] = None
+    ids_per_segment: Optional[int] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -353,6 +370,12 @@ class SolverPath:
         from cocoa_tpu.ops.pallas_sparse_hbm import (GROUP, TAIL_GROUP,
                                                      walk_head)
 
+        if self.class_axis == "lanes":
+            return (f", a step's W and dw rows fetched by the chain, "
+                    f"{self.ids_per_segment} ids in VMEM at a time"
+                    + ("" if self.slots_walked is None else
+                       f", {self.slots_walked:.1f} slots a step in groups "
+                       f"of {_rows.SLOT_GROUP}"))
         how = (", unrolled" if self.slot_walk == "unrolled" else
                f", the first {walk_head(self.table_width, False)} written "
                f"out, then in groups of {GROUP} and {TAIL_GROUP}")
@@ -395,7 +418,11 @@ class SolverPath:
                       f"one sampled row"
                       + ("" if self.lane_fill is None else
                          f" (solved side by side, lane fill "
-                         f"{self.lane_fill:.3f})"))
+                         f"{self.lane_fill:.3f})")
+                      + (f", the class axis on the {self.class_axis}"
+                         + (f" ({self.class_tiles} tile(s) of 1,024, "
+                            f"{self.label_slots} label id(s) a row)"
+                            if self.class_axis == "lanes" else "")))
         if self.pass_slot_share < 1.0:
             solve += (f", all-rows passes touch {self.pass_slot_share:.3f} "
                       f"of the padded slots")
@@ -461,10 +488,14 @@ def _slot_stats(ds: ShardedDataset) -> tuple:
         else:
             from cocoa_tpu.ops.pallas_sparse_hbm import walk_slots
 
-            # (the rows that pad a shard have no nonzero and no step)
-            walked = float(walk_slots(lens[lens > 0], widest,
-                                      rows_of_one_length(ds)).sum()
-                           / max(1, ds.n))
+            # (the rows that pad a shard have no nonzero and no step; with
+            # a class axis on the lanes a step fetches whole slot groups)
+            real = lens[lens > 0]
+            walked = float(
+                (-(-real // _rows.SLOT_GROUP) * _rows.SLOT_GROUP
+                 if ds.num_classes > 1 else
+                 walk_slots(real, widest, rows_of_one_length(ds))).sum()
+                / max(1, ds.n))
         cached = (float(lens.sum() / max(1, ds.sp_indices.size)),
                   int(lens.max(initial=0)), fill, walked)
         ds._slot_stats_cache = cached
@@ -473,12 +504,21 @@ def _slot_stats(ds: ShardedDataset) -> tuple:
 
 def _hbm_plan(ds: ShardedDataset, local_iters: int):
     """The plan of the HBM-state sparse kernel for a round of
-    ``local_iters`` steps on ``ds`` (ops/pallas_sparse_hbm.hbm_plan): from
-    the shapes, and from whether the loader saw rows of one length."""
+    ``local_iters`` steps on ``ds``: from the shapes, and at T = 1 from
+    whether the loader saw rows of one length
+    (ops/pallas_sparse_hbm.hbm_plan); with a class axis, the lane chain's
+    (ops/pallas_sparse_lanes.lanes_plan)."""
+    width = int(ds.sp_indices.shape[-1])
+    itemsize = jnp.dtype(ds.labels.dtype).itemsize
+    if getattr(ds, "num_classes", 1) > 1:
+        from cocoa_tpu.ops.pallas_sparse_lanes import lanes_plan
+
+        return lanes_plan(width, local_iters, itemsize,
+                          class_pad(ds.num_classes),
+                          getattr(ds, "label_slots", None) or 1)
     from cocoa_tpu.ops.pallas_sparse_hbm import hbm_plan
 
-    return hbm_plan(ds.num_features, int(ds.sp_indices.shape[-1]),
-                    local_iters, jnp.dtype(ds.labels.dtype).itemsize,
+    return hbm_plan(ds.num_features, width, local_iters, itemsize,
                     one_length=rows_of_one_length(ds))
 
 
@@ -503,13 +543,23 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
     classes = int(getattr(ds, "num_classes", 1))
     if classes > 1:
         # what carries the class axis today, said once where the path is
-        # decided: dense rows, one chip, the sequential solve
-        if ds.layout != "dense":
+        # decided: dense rows (a class a sublane) or a padded-CSR
+        # rectangle (the classes on the lanes), one chip, the sequential
+        # solve
+        if ds.layout != "dense" and (ds.sp_row_ptr is not None or ds.n_hot):
             raise ValueError(
                 f"a set of {classes} classes trains one-vs-rest on dense "
-                f"rows only: no kernel carries the class axis on the "
-                f"{ds.layout} layout yet (load it with --layout=dense, or "
-                f"drop --classes to train class 1 against the rest)")
+                f"rows or on a padded-CSR rectangle: no kernel carries the "
+                f"class axis on "
+                f"{'rows kept as a stream' if ds.sp_row_ptr is not None else 'the hybrid layout (--hotCols)'}"
+                f" yet (drop --classes to train class 1 against the rest)")
+        label_slots = getattr(ds, "label_slots", None) or 1
+        if ds.layout == "dense" and label_slots > 1:
+            raise ValueError(
+                f"label sets ({label_slots} ids a row) train on sparse "
+                f"rows, the class axis on the lanes: the dense class "
+                f"kernel reads one class id a row (load it with "
+                f"--layout=sparse)")
         if mesh is not None:
             raise ValueError(
                 f"a set of {classes} classes trains one-vs-rest on one chip "
@@ -554,12 +604,23 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         from cocoa_tpu.ops.pallas_sparse import sparse_kernel_fits
         from cocoa_tpu.ops.pallas_sparse_hbm import sparse_hbm_fits
 
-        vmem_fits = sparse_kernel_fits(
+        vmem_fits = classes == 1 and sparse_kernel_fits(
             m_local, ds.n_shard, ds.num_features, width, local_iters,
             itemsize, n_hot=ds.n_hot)
-        hbm_state = (not vmem_fits and not ds.n_hot and sparse_hbm_fits(
-            ds.num_features, width, local_iters, itemsize))
-        if not vmem_fits and not hbm_state and not ds.n_hot:
+        # (a class axis rides the lanes of the HBM-state chain alone: W
+        # (d, T_pad) is not a VMEM-resident shape)
+        if classes > 1:
+            from cocoa_tpu.ops.pallas_sparse_lanes import lanes_fits
+
+            hbm_state = lanes_fits(width, local_iters, itemsize,
+                                   class_pad(classes), label_slots)
+        else:
+            hbm_state = (not vmem_fits and not ds.n_hot and sparse_hbm_fits(
+                ds.num_features, width, local_iters, itemsize))
+        if classes > 1 and not hbm_state:
+            refused = (f"a step's {width} [w | dw] rows of {classes} "
+                       f"classes outgrow the chain's VMEM")
+        elif not vmem_fits and not hbm_state and not ds.n_hot:
             from cocoa_tpu.ops.pallas_sparse_hbm import hbm_refusal
 
             refused = hbm_refusal(ds.num_features, width, local_iters,
@@ -585,7 +646,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         # allocation failure itself).
         from cocoa_tpu.ops.pallas_sdca import classes_fit, pick_unroll
 
-        if classes > 1:
+        if classes > 1 and not sparse:
             fits = classes_fit(m_local, ds.n_shard, ds.num_features,
                                classes, itemsize)
         else:
@@ -664,15 +725,26 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         if classes > 1:
             placement.update(
                 classes=classes,
-                lane_fill=classes / class_rows(classes) if pallas else None)
+                class_axis="lanes" if sparse else "sublanes",
+                lane_fill=(classes / class_pad(classes) if sparse else
+                           classes / class_rows(classes) if pallas
+                           else None))
+            if sparse:
+                placement.update(
+                    class_tiles=class_pad(classes) // CLASS_TILE,
+                    label_slots=label_slots)
         if pallas and hbm_state:
             plan = _hbm_plan(ds, local_iters)
             placement.update(
                 local_ids="direct" if plan.direct else "sorted",
                 segments=plan.t, table_width=plan.w_r,
                 slots_walked=(float(plan.w_r) if plan.unrolled
-                              else _slot_stats(ds)[3]),
-                slot_walk="unrolled" if plan.unrolled else "grouped")
+                              else _slot_stats(ds)[3]))
+            if plan.t_pad:
+                placement.update(ids_per_segment=plan.m)
+            else:
+                placement.update(
+                    slot_walk="unrolled" if plan.unrolled else "grouped")
         form = depth = None
         if pallas and not sparse:
             # the dense kernel's form and its ring's depth, from the VMEM
@@ -789,7 +861,8 @@ def _sdca_round_parts(
             params, k, mode, scaling, sigma, math=math,
             pallas_interpret=pallas_interpret, pallas_state=pallas_state)
         return (one[0], _class_round(params, mode, scaling, sigma, classes,
-                                     one[0], pallas, pallas_interpret),
+                                     one[0], pallas, pallas_interpret,
+                                     hbm_plan),
                 one[2])
     if math not in ("exact", "fast"):
         raise ValueError(f"math must be 'exact' or 'fast', got {math!r}")
@@ -893,11 +966,29 @@ def _sdca_round_parts(
 
 
 def _class_round(params: Params, mode: str, scaling: float, sigma: float,
-                 classes: int, per_shard, pallas: bool, interpret: bool):
+                 classes: int, per_shard, pallas: bool, interpret: bool,
+                 lanes_plan=None):
     """``per_round_batched(w (T, d), alpha (T, K, n_shard), idxs (K, H),
     shards) -> (dw (T, d), alpha')`` of a one-vs-rest round
-    (:func:`_sdca_round_parts`)."""
+    (:func:`_sdca_round_parts`).  On padded-CSR rows the class axis rides
+    the lanes — w (d, R, 128), alpha (K, n_shard, R, 128),
+    ops/pallas_sparse_lanes.py: its HBM-state chain on ``lanes_plan``, or
+    the same round in plain XLA — and the round names its own scopes."""
     from cocoa_tpu.data.sharding import class_labels
+
+    def per_round_lanes(w, alpha, idxs_kh, shards):
+        from cocoa_tpu.ops import pallas_sparse_lanes as lanes
+
+        common = dict(lam=params.lam, n=params.n, classes=classes,
+                      mode=mode, sigma=sigma, scaling=scaling,
+                      loss=params.loss, smoothing=params.smoothing)
+        if pallas:
+            return lanes.pallas_sparse_lanes_round(
+                w, alpha, shards, idxs_kh, plan=lanes_plan,
+                interpret=interpret, **common)
+        with jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE):
+            return lanes.sparse_lanes_round_fori(w, alpha, shards, idxs_kh,
+                                                 **common)
 
     @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
     def per_round_classes(w, alpha, idxs_kh, shards):
@@ -915,7 +1006,12 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
 
         return jax.vmap(one_class)(w, alpha, jnp.arange(classes))
 
-    return per_round_classes
+    def per_round(w, alpha, idxs_kh, shards):
+        # the layout's rule (SolverPath.class_axis), read off the arrays
+        return (per_round_lanes if "sp_indices" in shards
+                else per_round_classes)(w, alpha, idxs_kh, shards)
+
+    return per_round
 
 
 def make_round_step(mesh, params: Params, k: int, alg, **parts_kw):
@@ -1145,9 +1241,17 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     tiny program the device waits for, then its ``device_put`` on a mesh."""
     # plain ints: the cached start program must not keep ``ds`` alive
     d, k, n_shard = int(ds.num_features), ds.k, int(ds.n_shard)
-    # a one-vs-rest job's leaves carry the class axis first: w (T, d),
-    # alpha (T, K, n_shard); at T = 1 there is none
-    lead = (ds.num_classes,) if ds.num_classes > 1 else ()
+    # a one-vs-rest job's leaves carry the class axis: first on dense rows,
+    # w (T, d), alpha (T, K, n_shard); last and as tiles on sparse rows (the
+    # lanes form), W (d, R, 128), alpha (K, n_shard, R, 128)
+    # (data/sharding.class_tile_shape); at T = 1 there is none
+    lanes = ds.num_classes > 1 and ds.layout == "sparse"
+    lead = (ds.num_classes,) if ds.num_classes > 1 and not lanes else ()
+    trail = class_tile_shape(ds.num_classes) if lanes else ()
+    if lanes and any(v is not None for v in (w_init, alpha_init)):
+        raise ValueError(
+            f"a job over {ds.num_classes} classes on sparse rows starts "
+            f"from alpha = 0, W = 0: no state is handed in yet")
     accel = arm == "accel"
     sched = (None if arm == "plain"
              else base.sched_init_values(start_round, sched_init, accel))
@@ -1164,7 +1268,7 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
                        if accel else ()),
                      *(() if sched is None else (NamedSharding(mesh, P()),)))
     if all(v is None for v in (w_init, alpha_init, hist_init, sched_init)):
-        key = (d, k, n_shard, str(dtype), arm, residual, mesh, lead)
+        key = (d, k, n_shard, str(dtype), arm, residual, mesh, lead, trail)
         start = _START_PROGRAMS.get(key)
         target = ds.target if residual else None
         _sanitize.count_launch()
@@ -1174,9 +1278,9 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
         # for the leaves, so the span's closing reading holds them
         with _tracing.cold_span("build_start") as cold:
             def start(sched, target):
-                w = (jnp.zeros(lead + (d,), dtype=dtype) if target is None
-                     else (-target).astype(dtype))
-                alpha = jnp.zeros(lead + (k, n_shard), dtype=dtype)
+                w = (jnp.zeros(lead + (d,) + trail, dtype=dtype)
+                     if target is None else (-target).astype(dtype))
+                alpha = jnp.zeros(lead + (k, n_shard) + trail, dtype=dtype)
                 hist = ((jnp.zeros((2, k, n_shard), dtype=dtype),)
                         if accel else ())
                 return (w, alpha, *hist,
@@ -1194,9 +1298,9 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     elif residual:
         w = (-ds.target).astype(dtype)
     else:
-        w = jnp.zeros(lead + (d,), dtype=dtype)
+        w = jnp.zeros(lead + (d,) + trail, dtype=dtype)
     if alpha_init is None:
-        alpha = jnp.zeros(lead + (k, n_shard), dtype=dtype)
+        alpha = jnp.zeros(lead + (k, n_shard) + trail, dtype=dtype)
     elif lead:
         alpha = jnp.array(alpha_init, dtype=dtype, copy=True)
     else:
@@ -1522,6 +1626,16 @@ def run_sdca_family(
     sampler.device = base.resolve_sampling(sampling, sampler,
                                            params.num_rounds)
     shard_arrays = _kernel_arrays(ds, path, block_size)
+    if path.class_axis == "lanes":
+        # the certificate on the lanes form needs T stated: the state is
+        # T_pad wide (objectives.eval_metrics ``classes``)
+        def eval_kernel(state, shard_arrays, test_arrays):
+            return objectives.eval_metrics(
+                state[0], state[1], shard_arrays, params.lam, params.n,
+                test_shard_arrays=test_arrays,
+                test_n=test_ds.n if test_ds is not None else 0,
+                loss=params.loss, smoothing=params.smoothing,
+                classes=classes)
 
     if eval_fn is None:
         def eval_fn(state):

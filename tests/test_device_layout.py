@@ -687,6 +687,85 @@ def test_criteo_quarter_job_copies_no_rows_at_the_loaders_width(
                            stats.temp_size_in_bytes)
 
 
+AMAZONCAT = dict(n=1186239, d=203882, k=8, width=256, classes=1000, slots=8,
+                 frac=0.1, lam=1e-4)
+
+
+def test_amazoncat_job_fits_one_chip_and_copies_no_state(monkeypatch,
+                                                        one_chip):
+    """The whole device loop of a one-vs-rest job over label sets at
+    amazoncat13k's shapes (shapes only: 8 GB) — rounds on the chain whose
+    class axis rides the lanes, the certificate in row blocks — compiled
+    for one described v5e.  W (d, 8, 128) and alpha (K, n_shard, 8, 128)
+    are held AS tiles and alpha is aliased through the chain's call, so
+    nothing copies an alpha-sized array (held as (K, n_shard, 1024) the
+    reshape to tiles was a copy of all 4.86 GB each way: 6.6 GB of
+    temporaries a round); the rectangle is row-major at W = 256 and every
+    pass slices it as stored (the kddb form of the block pass, rows on the
+    lanes, cost two whole-array copies: 16.2 GB held); the label ids are
+    read with the row on the lanes, as stored (a gather of whole (L,) rows
+    copied them all into a 16-fold padded form, 0.6 GB).  Arguments plus temporaries
+    stay under 11.0 GB of the chip's 15.75."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import (ShardedDataset, pad_rows,
+                                         split_sizes)
+    from cocoa_tpu.solvers import base, run_cocoa
+
+    shape = AMAZONCAT
+    with jax.enable_x64(False):
+        got = _arm_capture(monkeypatch)
+        k, width = shape["k"], shape["width"]
+        sizes = split_sizes(shape["n"], k)
+        n_shard = pad_rows(int(sizes.max()))
+        here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+        def sds(dims, dt):
+            return jax.ShapeDtypeStruct(dims, dt, sharding=here)
+
+        rows = sds((k, n_shard), jnp.float32)
+        ds = ShardedDataset(
+            layout="sparse", n=shape["n"], num_features=shape["d"],
+            counts=sizes.astype(np.int64), labels=rows, mask=rows,
+            sq_norms=rows, sp_indices=sds((k, n_shard, width), jnp.int32),
+            sp_values=sds((k, n_shard, width), jnp.float32),
+            classes=sds((k, n_shard, shape["slots"]), jnp.int32),
+            num_classes=shape["classes"])
+        ds._row_len_cache = sds((k, n_shard), jnp.int32)
+        ds.row_order = sds((k, n_shard), jnp.int32)    # as if in length order
+        h = int(shape["frac"] * shape["n"] / k)
+        with pytest.raises(_Captured):
+            run_cocoa(ds, Params(n=ds.n, num_rounds=300, local_iters=h,
+                                 lam=shape["lam"]),
+                      DebugParams(debug_iter=5, seed=0), plus=True,
+                      quiet=True, math="fast", device_loop=True,
+                      rng="permuted", gap_target=1e-2, accel="off")
+        base._DEVICE_RUNS.clear()
+        path = got["path"]
+        assert (path.kernel, path.state, path.class_axis, path.class_tiles,
+                path.label_slots) == ("pallas", "hbm", "lanes", 1, 8)
+        assert (path.local_ids, path.segments, path.table_width,
+                path.ids_per_segment, h) == ("direct", 1, 256, 256, 14827)
+        compiled = got["run"].lower(*_on_chip(got["args"],
+                                              one_chip)).compile()
+    stats = compiled.memory_analysis()
+    held = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 8.1e9 < stats.argument_size_in_bytes < 8.3e9    # the deployment
+    assert held <= 11.0e9, (stats.argument_size_in_bytes,   # 8.18 + 2.54
+                            stats.temp_size_in_bytes)
+    hlo = compiled.as_text()
+    assert "pallas_sparse_lanes_round" in hlo
+    assert n_shard == 148288
+    large = re.compile(rf"\[{k},{n_shard},(8,128|1024|{width})\]|"
+                       rf"\[{k},{width},{n_shard}\]|"
+                       rf"\[{k},{n_shard},{shape['slots']}\]")
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if " copy(" in line and large.search(line.split(" copy(")[0])]
+    assert copies == []
+
+
 def test_ordering_a_kddb_shard_happens_in_its_donated_rows(one_chip):
     """``data.sharding._order_rows`` at kddb's shapes, a shard at a time:
     the 4.9 GB row array comes back in the buffer it was donated in (the

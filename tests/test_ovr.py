@@ -224,10 +224,12 @@ def test_the_resolver_refuses_sparse_rows_a_mesh_and_blocks():
     ds = types.SimpleNamespace(
         k=2, labels=labels, layout="sparse", n_hot=0, n_shard=128,
         num_features=64, sp_indices=jnp.zeros((2, 128, 4), jnp.int32),
-        sp_row_ptr=None, num_classes=3)
-    with pytest.raises(ValueError, match="dense rows only"):
+        sp_row_ptr=labels, num_classes=3)
+    # (sparse rows as a rectangle carry the classes on the lanes since
+    # PR 48, tests/test_labels.py; rows kept as a stream carry none)
+    with pytest.raises(ValueError, match="rows kept as a stream"):
         resolve_solver_path(ds, 8, math="fast")
-    ds.layout = "dense"
+    ds.layout, ds.sp_row_ptr = "dense", None
     with pytest.raises(ValueError, match="block"):
         resolve_solver_path(ds, 8, math="fast", block_size=128)
     path = resolve_solver_path(ds, 8, math="fast", pallas=True)
@@ -309,7 +311,7 @@ def test_a_class_count_the_file_contradicts_is_refused_with_the_numbers(
 @pytest.mark.parametrize("flags, said", [
     (["--objective=lasso"], "--objective=lasso"),
     (["--fleet=/nowhere.jsonl"], "--classes"),
-    (["--layout=sparse"], "--layout=dense"),
+    (["--layout=sparse", "--hotCols=8"], "carry no class axis"),
     (["--justCoCoA=false"], "--justCoCoA=true"),
     (["--classes=7"], "7 classes were stated"),
     (["--accel=auto", "--gapTarget=1e-2"], "--accel=off"),
