@@ -58,6 +58,26 @@ nonzero one dynamic sublane read of the d-vector and a masked multiply-add
 with the fetch hidden the cost is paced by nonzeros plus a step's own
 fixed work, which at rows of a hundred nonzeros is no longer small beside
 them (PERF.md §6, PR 41, has both).
+
+**No floating-point value of a step, a row or a coefficient is 0-d**
+(PR 49, as ``pallas_sparse_hbm._chain_kernel`` since PR 46 and
+``pallas_sdca._advance`` since PR 39).  The scalar core keeps the integers
+— a row's start, count and ``prev``, a column's sublane and lane, the trip
+counts, the ring's slots — and hands floats over as splats of SMEM loads,
+scalar to vector, the cheap direction: a nonzero's value into the margin's
+masked multiply-add and into the update's product; the chain's y, sigma'
+|x|^2, alpha and (``split``) the table's x . w to (1, 1) vectors; axpy's
+coefficient once a row.  What is computed stays on the vector side: a
+row's total is ONE cross-lane reduce that keeps its axes (``tile_total``),
+a repeated row's alpha a masked lane reduce (``lane_pick``), selected on
+the integer ``prev``; ``losses.alpha_step`` runs elementwise on (1, 1)
+vectors under every loss, logistic's Newton iterations too; ``coef``
+(1, 1) broadcasts into ``row + coef * value`` and the new alpha, or a
+``dots`` row's total, into the masked store of its lane of the output
+block.  One chain runs at a time, so a value that went to the scalar core
+and came back (a reduce to 0-d, the hinge rule's divide, ``coef``'s, the
+splat) was paid in full: the same IEEE operations in the same order,
+measured on the v5e in PERF.md §6, PR 49.
 """
 
 from __future__ import annotations
@@ -75,7 +95,8 @@ from cocoa_tpu.data.sharding import STREAM_ALIGN as ALIGN
 from cocoa_tpu.data.sharding import STREAM_PIECE as PIECE
 from cocoa_tpu.ops import losses
 from cocoa_tpu.ops.local_sdca import coef_divisor, mode_factors
-from cocoa_tpu.ops.pallas_sdca import LANES, check_dtype
+from cocoa_tpu.ops.pallas_sdca import (LANES, check_dtype, lane_pick,
+                                       tile_total)
 
 assert PIECE == LANES             # a piece is one full lane row: the unit a
                                  # DMA may start at
@@ -154,6 +175,11 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
 
     lane = lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
+    def splat(table, i):
+        """Row ``i``'s float of an SMEM table as a (1, 1) vector: scalar to
+        vector, the one direction a float of a row ever takes."""
+        return jnp.full((1, 1), table[0, i], dtype)
+
     def copies(piece, c, slot):
         src = pl.ds(piece + c * CHUNK_PIECES, CHUNK_PIECES)
         return (pltpu.make_async_copy(cols_hbm.at[src], cbuf.at[slot],
@@ -231,9 +257,10 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
         return lax.fori_loop(lo // per, (hi + (per - 1)) // per, piece, init)
 
     def dot_row(row, s0, after):
-        """x . vec_sc: a masked multiply-add into a lane vector per nonzero,
-        one cross-lane sum a row.  The slots between a row's end and the
-        next ALIGN boundary hold column 0, value 0."""
+        """x . vec_sc as a (1, 1) vector: a masked multiply-add into a lane
+        vector per nonzero, one cross-lane sum a row, which keeps its axes.
+        The slots between a row's end and the next ALIGN boundary hold
+        column 0, value 0."""
         def body(slot, j0, lo, hi, acc):
             del j0
 
@@ -248,15 +275,16 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
 
             return over_groups(slot, lo, hi, group, acc)
 
-        return jnp.sum(over_row(row, s0, body,
-                                jnp.zeros((1, LANES), dtype), after))
+        return tile_total(over_row(row, s0, body,
+                                   jnp.zeros((1, LANES), dtype), after))
 
     def axpy_row(row, s0, coef, after, moves=True):
-        """vec_sc += coef x: a masked store of the one lane each nonzero
-        owns.  A row has no column twice, so within a group no store feeds
-        a later slot's read and the group's reads all go first; a slot past
-        the row's length (column 0, value 0) stores nothing: it would put
-        back a lane read before this group's stores."""
+        """vec_sc += coef x, ``coef`` a (1, 1) vector: a nonzero's value is
+        splatted into the product, and a masked store writes the one lane
+        the nonzero owns.  A row has no column twice, so within a group no
+        store feeds a later slot's read and the group's reads all go first;
+        a slot past the row's length (column 0, value 0) stores nothing: it
+        would put back a lane read before this group's stores."""
         cnt = row[2] - row[1]
 
         def body(slot, j0, lo, hi, carry):
@@ -279,6 +307,7 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
         over_row(row, s0, body, jnp.int32(0), after, moves)
 
     def put(ref, r, value):
+        """Row ``r``'s (1, 1) result into its lane of the per-row block."""
         pltpu.store(ref.at[0, pl.ds(_index(r >> 7), 1)],
                     jnp.broadcast_to(value, (1, LANES)).astype(dtype),
                     mask=lane == (r & (LANES - 1)))
@@ -304,15 +333,15 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
             put(out, r, dot_row(row, s0, next_row))
             return (s0 + n_chunks) & 1
         if mode == "axpy":
-            axpy_row(row, s0, ftabs[0][0, i], next_row)
+            axpy_row(row, s0, splat(ftabs[0], i), next_row)
             return (s0 + n_chunks) & 1
         prev = itabs[2][0, i]
-        y, qii, a0 = (t[0, i] for t in ftabs[-3:])
+        y, qii, a0 = (splat(t, i) for t in ftabs[-3:])
         # a row this round already stepped on: alpha is that step's
         pj = jnp.maximum(prev, 0)
         prow = out[0, pl.ds(pj >> 7, 1)]
-        a_prev = jnp.sum(jnp.where(lane == (pj & (LANES - 1)), prow, 0.0))
-        a = jnp.where(prev >= 0, a_prev, a0)
+        a = jnp.where(prev >= 0,
+                      lane_pick(prow, lane == (pj & (LANES - 1))), a0)
         # vec_sc holds v = w + sig_eff dw_k, or (split) dw_k beside
         # the table of x . w
         sig_eff = step_consts["sig_eff"]
@@ -332,11 +361,11 @@ def _kernel(*refs, mode: str, n_f: int, split: bool, step_consts: dict):
 
         margin = dot_row(row, s0, after_dot)
         if split:
-            margin = ftabs[0][0, i] + sig_eff * margin
+            margin = splat(ftabs[0], i) + sig_eff * margin
         new_a = losses.alpha_step(
             step_consts["loss"], a, y * margin, qii,
             step_consts["lam_n"], smoothing=step_consts["smoothing"])
-        coef = y * (new_a - a) / step_consts["coef_div"]
+        coef = y * (new_a - a) / step_consts["coef_div"]      # (1, 1)
         axpy_row(row, jnp.where(again, (s0 + n_chunks) & 1, s0),
                  coef if split else sig_eff * coef, next_row, again)
         put(out, r, new_a)

@@ -203,9 +203,17 @@ class SolverPath:
     runs one chain at a time, so logistic's Newton iterations too run on
     that chain's (1, 1) values; y, σ′‖x‖² and α enter as splats of its
     SMEM table, the margin's total and a repeated row's α as reduces that
-    keep their axes); ``scalar``: everything else — wherever a kernel
-    still solves a step on one coordinate's 0-d values (``fori``, the
-    VMEM-resident sparse kernel, the stream and the block kernels).
+    keep their axes), and since PR 49 a stream's kernels under any loss
+    (ops/pallas_longrows._kernel, one chain at a time too: the chain's y,
+    σ′‖x‖², α and, ``split``, the table's x·w are splats of SMEM loads,
+    its margin's total and a repeated row's α reduces that keep their
+    axes, ``alpha_step`` elementwise on (1, 1), coef a (1, 1) vector into
+    the update's multiply-add; the all-rows passes alike: a ``dots``
+    row's total goes to its lane of the output without leaving the
+    vector side, an ``axpy`` row's coefficient is one splat);
+    ``scalar``: everything else — wherever a kernel still solves a step
+    on one coordinate's 0-d values (``fori``, the VMEM-resident sparse
+    kernel and the block kernels).
     ``pass_slot_share``: of a sparse set's padded slots, the share one
     all-rows pass (the certificate's margins, the ``--accel`` jump) touches:
     1.0 where one block holds a shard or the rows' lengths are not known;
@@ -769,7 +777,8 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             chain=None, interpret=bool(pallas and platform == "cpu"),
             form=form,
             state="vmem" if pallas and not hbm_state else "hbm",
-            step_solve=("scalar" if not pallas or sparse and not hbm_state
+            step_solve=("scalar" if not pallas
+                        or sparse and not (hbm_state or stream)
                         else "lanes" if not sparse and (
                             classes > 1 or losses.step_is_iterative(loss))
                         else "vector"),

@@ -48,6 +48,30 @@ def test_resolver_reports_the_stream(ds):
         resolve_solver_path(ds, 12, None, math="fast", block_size=128)
 
 
+@pytest.mark.parametrize("loss", ["hinge", "smooth_hinge", "logistic"])
+def test_a_stream_on_the_pallas_path_solves_its_step_on_vectors(ds, loss):
+    """``step_solve`` on rows kept as a stream: ``vector`` on the Pallas
+    path under every loss (one chain at a time, its step's floats (1, 1)
+    vectors: ops/pallas_longrows._kernel, PR 49; the body is read in
+    tests/test_losses.py), said on the console line; ``scalar`` on
+    ``fori``; the margin's form changes nothing of it."""
+    from cocoa_tpu.solvers.cocoa import resolve_solver_path
+
+    path = resolve_solver_path(ds, 12, None, math="fast", pallas=True,
+                               loss=loss)
+    assert (path.kernel, path.storage, path.step_solve) == (
+        "pallas", "stream", "vector")
+    assert "each step solved on the vector unit" in path.describe()
+    assert "solved in lanes" not in path.describe()
+    for mode in ("plus", "frozen"):
+        assert path.for_mode(mode).step_solve == "vector"
+    fori = resolve_solver_path(ds, 12, None, math="fast", pallas=False,
+                               loss=loss)
+    assert (fori.kernel, fori.storage, fori.step_solve) == (
+        "fori", "stream", "scalar")
+    assert "on the vector unit" not in fori.describe()
+
+
 def test_a_row_that_outgrows_smem_is_refused_with_the_numbers():
     from cocoa_tpu.ops.pallas_sparse_hbm import hbm_refusal
 
@@ -76,8 +100,10 @@ def test_driver_on_the_stream_matches_the_fori_path(data, ds, loss):
     path = runs["pallas"][2].meta["solver_path"]
     assert (path["kernel"], path["storage"], path["margin"]) == (
         "pallas", "stream", "combined")
+    assert path["step_solve"] == "vector"
     fori = runs["fori"][2].meta["solver_path"]
     assert (fori["kernel"], fori["margin"]) == ("fori", None)
+    assert fori["step_solve"] == "scalar"
     np.testing.assert_allclose(runs["pallas"][0], runs["fori"][0], atol=1e-5)
     np.testing.assert_allclose(runs["pallas"][1], runs["fori"][1], atol=1e-5)
     gaps = [rec.gap for rec in runs["pallas"][2].records]

@@ -356,6 +356,37 @@ def test_no_value_of_a_class_step_is_a_scalar(loss, depth, h):
     assert _scalar_floats(body) == []
 
 
+def _assert_floats_stay_vectors(body, loads):
+    """The only 0-d floats of ``body`` computed from its data are ``loads``
+    loads of SMEM tables, and no reduce of it drops its last axis away;
+    returns its reduces' shapes."""
+    scalars = _scalar_float_eqns(body)
+    assert {e.primitive.name for e in scalars} <= {"get"}, [
+        str(e)[:120] for e in scalars if e.primitive.name != "get"]
+    assert len(scalars) == loads
+    sums = [e.outvars[0].aval.shape for e in _walk(body)
+            if e.primitive.name == "reduce_sum"]
+    assert () not in sums
+    return sums
+
+
+def _assert_step_on_vectors(body, loss, loads):
+    """A chain's step in ``body``: SMEM loads its only 0-d floats, both
+    reduces there (the pick of a repeated row's alpha, the margin's
+    total), and under logistic every ``exp`` (1, 1), ``_NEWTON_ITERS`` + 1
+    of them and one ``log``; no transcendental under a closed form."""
+    assert len(_assert_floats_stay_vectors(body, loads)) >= 2
+    prims = list(_walk(body))
+    exps = [e.outvars[0].aval.shape for e in prims
+            if e.primitive.name == "exp"]
+    logs = [e for e in prims if e.primitive.name in ("log", "logistic")]
+    if not losses.step_is_iterative(loss):
+        assert not exps and not logs
+        return
+    assert exps == [(1, 1)] * (losses._NEWTON_ITERS + 1)
+    assert [e.primitive.name for e in logs] == ["log"]
+
+
 def _chain_body(loss, mode, width, unrolled):
     """The jaxpr of the HBM-state sparse round's chain kernel
     (``pallas_sparse_hbm._chain_kernel``), traced at a toy size: the
@@ -403,25 +434,87 @@ def test_no_value_of_a_sparse_hbm_step_is_computed_as_a_scalar(loss, mode,
     and an 8-slot trip body."""
     width = 40
     body = _chain_body(loss, mode, width, walk == "unrolled")
-    scalars = _scalar_float_eqns(body)
-    assert {e.primitive.name for e in scalars} == {"get"}, [
-        str(e)[:120] for e in scalars if e.primitive.name != "get"]
     # (y, q, alpha) once a step; a value a slot in each of the two loops
-    assert len(scalars) == 3 + 2 * width
-    prims = list(_walk(body))
-    # both reduces are there (the pick of a repeated row's alpha, the
-    # margin's total), and neither reduces its last axis away
-    sums = [e.outvars[0].aval.shape for e in prims
-            if e.primitive.name == "reduce_sum"]
-    assert len(sums) >= 2 and () not in sums
-    exps = [e.outvars[0].aval.shape for e in prims
-            if e.primitive.name == "exp"]
-    logs = [e for e in prims if e.primitive.name in ("log", "logistic")]
-    if not losses.step_is_iterative(loss):
-        assert not exps and not logs
-        return
-    assert exps == [(1, 1)] * (losses._NEWTON_ITERS + 1)
-    assert [e.primitive.name for e in logs] == ["log"]
+    _assert_step_on_vectors(body, loss, 3 + 2 * width)
+
+
+def _stream_bodies(kind, loss="hinge", mode="plus"):
+    """``{kernel name: jaxpr}`` of the stream's kernels
+    (``pallas_longrows._kernel``) as one pass traces them at a toy size —
+    ``dots`` (the certificate's margins), ``axpy`` (the ``--accel`` jump)
+    or ``chain`` (a round: under ``frozen`` its ``dots`` pass too).  The
+    body does not depend on the sizes: a slot group is 8 slots written
+    out, once in the margin's loop and once in the update's."""
+    import jax
+
+    from cocoa_tpu.ops import pallas_longrows as plr
+
+    k, n_shard, d, h, pieces = 2, 16, 2048, 8, 16
+    f32 = jnp.float32
+    rows, irows = jnp.ones((k, n_shard), f32), jnp.zeros((k, n_shard),
+                                                         jnp.int32)
+    shard = dict(sp_indices=jnp.zeros((k, pieces, plr.PIECE), jnp.int32),
+                 sp_values=jnp.ones((k, pieces, plr.PIECE), f32),
+                 sp_row_ptr=irows, sp_row_len=irows)
+    w = jnp.zeros(d, f32)
+    if kind == "dots":
+        traced = jax.make_jaxpr(
+            lambda w, sh: plr.shard_margins(w, sh, True))(w, shard)
+    elif kind == "axpy":
+        traced = jax.make_jaxpr(
+            lambda c, sh, w: plr.shards_axpy(c, sh, w, True))(rows, shard, w)
+    else:
+        traced = jax.make_jaxpr(lambda w, a, sh, i: plr.pallas_longrows_round(
+            w, a, sh["sp_indices"], sh["sp_values"], sh["sp_row_ptr"],
+            sh["sp_row_len"], rows, rows, i, 0.01, 1000, mode=mode,
+            sigma=3.0, loss=loss, smoothing=S, interpret=True))(
+                w, jnp.zeros((k, n_shard), f32), shard,
+                jnp.zeros((k, h), jnp.int32))
+    return {e.params["name"]: e.params["jaxpr"] for e in _walk(traced.jaxpr)
+            if e.primitive.name == "pallas_call"}
+
+
+@pytest.mark.parametrize("mode", ["plus", "frozen"])
+@pytest.mark.parametrize("loss", ALL)
+def test_no_value_of_a_stream_step_is_computed_as_a_scalar(loss, mode):
+    """Read off the traced chain of rows kept as a stream
+    (``pallas_longrows_chain``), both margin forms (``plus``: combined,
+    against the resident v = w + sigma' dw_k; ``frozen``: split, x . w
+    from a table): the only 0-d floats in it are loads of SMEM — y, the
+    scaled norm, alpha and (split) the table's x . w, splatted to (1, 1)
+    at once, and a nonzero's value, 8 slots written out in the margin's
+    group and 8 in the update's, splatted into a multiply.  The margin's
+    total and a repeated row's alpha are reduces that keep their axes,
+    ``alpha_step`` runs elementwise on (1, 1) vectors and ``coef``
+    broadcasts into the update's multiply-add: no float of a step goes to
+    the scalar core and comes back (PERF.md section 6, PR 49; the
+    rectangle's chain has been so since PR 46).  Under logistic every
+    ``exp`` of the step is (1, 1): ``_NEWTON_ITERS`` + 1 and one ``log``."""
+    from cocoa_tpu.ops.pallas_longrows import GROUP, margin_form
+
+    split = margin_form(mode) == "split"
+    bodies = _stream_bodies("chain", loss, mode)
+    assert set(bodies) == {"pallas_longrows_chain"} | (
+        {"pallas_longrows_dots"} if split else set())
+    _assert_step_on_vectors(bodies["pallas_longrows_chain"], loss,
+                            3 + split + 2 * GROUP)
+
+
+@pytest.mark.parametrize("kind", ["dots", "axpy"])
+def test_no_value_of_a_stream_pass_is_computed_as_a_scalar(kind):
+    """The same reading of the stream's all-rows passes: ``dots`` (every
+    row's x . w: the certificate's margins) keeps a row's total a (1, 1)
+    vector from its one reduce to its lane of the output block; ``axpy``
+    (the ``--accel`` jump) splats a row's coefficient once and multiplies
+    it with each nonzero's splatted value on the vector unit.  The loads
+    of SMEM — a nonzero's value, 8 slots a group, and axpy's coefficient
+    — are their only 0-d floats."""
+    from cocoa_tpu.ops.pallas_longrows import GROUP
+
+    (body,) = _stream_bodies(kind).values()
+    sums = _assert_floats_stay_vectors(
+        body, GROUP + (1 if kind == "axpy" else 0))
+    assert bool(sums) == (kind == "dots")     # a row's total; axpy sums none
 
 
 def test_the_scalar_reader_sees_a_scalar_trip():

@@ -204,7 +204,8 @@ def _idxs(ds, h=20, seed=7):
     return idxs
 
 
-MODES = [("plus", "hinge"), ("plus", "logistic"), ("frozen", "hinge")]
+MODES = [("plus", "hinge"), ("plus", "logistic"), ("frozen", "hinge"),
+         ("cocoa", "hinge"), ("frozen", "logistic"), ("plus", "smooth_hinge")]
 
 
 @pytest.fixture(scope="module")
@@ -242,8 +243,16 @@ def test_the_round_matches_the_oracle(data, ds, rounds):
 # sha256 of the round's (dw, alpha) bytes as the kernel of PR 41's parent
 # commit gives them on these inputs (the ring inside a row: prime a row's
 # first chunk, wait for it at once, twice a step), made by running that
-# commit's ops/pallas_longrows.py on this file's fixtures in interpret mode
+# commit's ops/pallas_longrows.py on this file's fixtures in interpret mode;
+# the last three and PASSES_BEFORE by PR 49's parent commit, whose step
+# held its floats 0-d (the first three are the same on both)
 BEFORE = {
+    ("cocoa", "hinge"):
+        "f2469da8961d9300cb9ffa9f8c18b3bb4841e2656e251e5397067920fa2dbbb7",
+    ("frozen", "logistic"):
+        "6d9985fbe69780ff41a3528201b9850278cdfe2080f6e38df6292f9c6e86d182",
+    ("plus", "smooth_hinge"):
+        "5c76fbdb2da4eabaec3a8cc7e3b92b216ab12cc8ccf5462c5a3a92dc518484e4",
     ("plus", "hinge"):
         "8548fd68c0b99e4089cd6337683db35dd4bd00537a790cab5dca5563132b0eb8",
     ("plus", "logistic"):
@@ -261,6 +270,24 @@ def test_the_round_is_bit_equal_to_the_kernel_of_before(rounds, mode, loss):
     dw, a_new = rounds[3][mode, loss]
     assert hashlib.sha256(dw.tobytes() + a_new.tobytes()).hexdigest() \
         == BEFORE[mode, loss]
+
+
+PASSES_BEFORE = {
+    "dots":
+        "dd28113b45b0870b8c87f24b75bb7937dd6ba1dc87865ab31b65cf7526a06c2a",
+    "axpy":
+        "fe60f135cf776233541188007e6b9cb03b87b180b56a8015bb03eba197e26213",
+}
+
+
+@pytest.mark.parametrize("what", ["dots", "axpy"])
+def test_the_passes_are_bit_equal_to_the_kernel_of_before(passes, what):
+    """A row's total kept on the vector side is the sum it was (a reduce
+    that keeps its axes adds the same lanes), and a coefficient splatted
+    before its product with a nonzero's value is the same float32 product:
+    every margin and every column of the jump, to the bit (PR 49)."""
+    got = passes[2] if what == "dots" else passes[3]
+    assert hashlib.sha256(got.tobytes()).hexdigest() == PASSES_BEFORE[what]
 
 
 # --- a url-shaped file through the loader and the driver ---------------------
@@ -293,6 +320,8 @@ def test_a_url_shaped_file_runs_the_stream_end_to_end(tmp_path, capsys):
     meta = traj.meta["solver_path"]
     assert (meta["kernel"], meta["storage"], meta["chunk_pieces"]) == (
         "pallas", "stream", 8)
+    assert meta["step_solve"] == "vector"
+    assert "each step solved on the vector unit" in said
     assert meta["chunk_fill"] == plr.chunk_fill(ds.sp_row_ptr,
                                                 ds.sp_row_len)
     assert "fetched 8 pieces a chunk (chunk fill 0.1" in said
