@@ -685,9 +685,8 @@ def check_pallas_budget_ast(src: SourceFile, index: ModuleIndex,
 
 # the tracing surface (telemetry/tracing.py): the context-manager forms
 # (a span, and a cold span, which besides reads every device's allocator
-# and waits for what it made) and the decorator form, module-level or on
-# a Tracer instance
-_SPAN_CALLEES = {"span", "cold_span", "traced"}
+# and waits for what it made), module-level or on a Tracer instance
+_SPAN_CALLEES = {"span", "cold_span"}
 
 # receiver names that identify the tracing module/object — required for
 # the attribute form so ``re.Match.span()`` and other unrelated ``span``
@@ -696,12 +695,11 @@ _TRACING_RECEIVERS = ("tracing", "tracer")
 
 
 def _is_span_call(node: ast.Call) -> Optional[str]:
-    """'span'/'cold_span'/'traced' when ``node`` is a TRACING call, else
-    None.
+    """'span'/'cold_span' when ``node`` is a TRACING call, else None.
     Matches ``tracing.span(...)`` / ``_tracing.span(...)`` /
     ``get_tracer().span(...)`` (receiver names the tracing surface), a
-    bare imported ``span("phase", ...)``/``traced("phase")`` (string
-    phase argument — what distinguishes it from e.g. ``m.span()``)."""
+    bare imported ``span("phase", ...)`` (string phase argument — what
+    distinguishes it from e.g. ``m.span()``)."""
     tail = _callee_tail(node)
     if tail not in _SPAN_CALLEES:
         return None
@@ -761,23 +759,6 @@ def check_span_hygiene(src: SourceFile, index: ModuleIndex) -> list:
                      f"it to the host boundary (the dispatch/fetch site, "
                      f"solvers/base.py pattern)")
                 continue
-    # the decorator form on a function that is itself traced: the span
-    # would wrap the traced body — same failure, different spelling
-    for d in index.defs:
-        if id(d) not in traced or not isinstance(
-                d, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            continue
-        for dec in d.decorator_list:
-            form = (_is_span_call(dec) if isinstance(dec, ast.Call)
-                    else None)
-            if form == "traced":
-                findings.append(Finding(
-                    rule="span-hygiene", severity="error", path=src.path,
-                    line=dec.lineno, col=dec.col_offset,
-                    message=(f"@traced decorator on `{d.name}`, which is "
-                             f"jitted/traced — the span would wrap the "
-                             f"trace, not the execution; decorate the "
-                             f"host-side caller instead")))
     # span attrs that read traced values from an ENCLOSING traced scope:
     # a host-side closure built inside a kernel builder may legally span,
     # but passing a traced array as an attribute materializes it on the

@@ -6,15 +6,15 @@ quietly retraces per super-block, a scalar read that blocks on the
 device inside the round loop — by wiring two runtime probes around the
 drive loops:
 
-- **compile watch** — every XLA compile is observable.  jax logs
-  ``Finished XLA compilation of jit(<name>) ...`` on the
-  ``jax._src.dispatch`` logger at DEBUG (independent of the
-  ``jax_log_compiles`` flag); :func:`watch_compiles` captures those
-  records, and :func:`install_compile_events` bridges them onto the
-  telemetry bus as typed ``compile`` events for the production
-  ``--metrics`` counters.  The invariant the tests pin: the device loop
-  executable compiles exactly ONCE per config — a second identical run
-  compiles nothing.
+- **compile watch** — every XLA compile is observable.  jax hands each
+  backend build's seconds to ``jax.monitoring``; the program's one
+  observer of builds (telemetry/tracing.py, "The build account") makes a
+  record of it, ``compile`` or, where the persistent cache answered,
+  ``load``.  :func:`watch_compiles` collects those records, and
+  :func:`install_compile_events` bridges them onto the telemetry bus as
+  typed ``compile`` events for the production ``--metrics`` counters.
+  The invariant the tests pin: the device loop executable compiles
+  exactly ONCE per config — a second identical run compiles nothing.
 - **transfer guard** — :func:`sanitizer(strict="all")` arms the
   device-loop contract: inside each dispatch→fetch region (which the
   driver marks via :func:`device_loop_guard`) jax's transfer guards
@@ -38,14 +38,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import logging
-import re
 import threading
-
-_DISPATCH_LOGGER = "jax._src.dispatch"
-_COMPILE_RE = re.compile(
-    r"Finished XLA compilation of (?:jit\(|pmap\()?([^)]+?)\)? in "
-    r"([0-9.eE+-]+) sec")
 
 # process-lifetime count of sanctioned device→host fetches (the
 # production mirror of what a sanitizer context observes per run)
@@ -75,63 +68,32 @@ class CompileRecord:
     seconds: float
 
 
-class _CompileLogWatch(logging.Handler):
-    """Capture per-executable compile records off the dispatch logger."""
-
-    def __init__(self, sink):
-        super().__init__(level=logging.DEBUG)
-        self._sink = sink
-
-    def emit(self, record):
-        try:
-            m = _COMPILE_RE.search(record.getMessage())
-        except Exception:   # never let logging break the run
-            return
-        if m:
-            self._sink(CompileRecord(name=m.group(1),
-                                     seconds=float(m.group(2))))
-
-
-def _mute_passthrough_handlers() -> list:
-    """jax installs a NOTSET StreamHandler on its root logger; once we
-    lower the dispatch logger to DEBUG, that handler would echo every
-    compile record to stderr.  Raise NOTSET handlers to WARNING (their
-    de-facto threshold under default levels — observable behavior is
-    unchanged, including ``jax_log_compiles``' WARNING-level lines) and
-    return an undo list."""
-    undo = []
-    for h in logging.getLogger("jax").handlers:
-        if h.level == logging.NOTSET:
-            h.setLevel(logging.WARNING)
-            undo.append(h)
-    return undo
+def _compile_sink(sink):
+    """What the build account's watchers are handed, narrowed to the
+    backend's builds: ``sink(CompileRecord)`` for every ``compile`` and
+    ``load`` record (the seconds jax's own compile log prints), nested in
+    another build or not."""
+    def told(build: dict):
+        if build["stage"] in ("compile", "load"):
+            sink(CompileRecord(name=build["fun_name"],
+                               seconds=build["dur_s"]))
+    return told
 
 
 @contextlib.contextmanager
 def watch_compiles():
     """Yield a list that accumulates one :class:`CompileRecord` per XLA
-    compile finishing while the context is open.  Lowers the dispatch
-    logger to DEBUG for the duration (console output is unchanged — see
-    :func:`_mute_passthrough_handlers`)."""
+    compile (or cache load) finishing while the context is open, on any
+    thread."""
+    from cocoa_tpu.telemetry import tracing
+
     records: list = []
-    handler = _CompileLogWatch(records.append)
-    logger = logging.getLogger(_DISPATCH_LOGGER)
-    prev_level = logger.level
-    muted = _mute_passthrough_handlers()
-    logger.addHandler(handler)
-    if logger.getEffectiveLevel() > logging.DEBUG:
-        logger.setLevel(logging.DEBUG)
+    told = _compile_sink(records.append)
+    tracing.watch_builds(told)
     try:
         yield records
     finally:
-        logger.removeHandler(handler)
-        if _BUS_BRIDGE is None:
-            logger.setLevel(prev_level)
-            for h in muted:
-                h.setLevel(logging.NOTSET)
-        # else: the process-lifetime compile→event bridge (installed
-        # while this watch was open, or before it) needs the DEBUG level
-        # and the muted passthroughs to keep counting — leave them
+        tracing.unwatch_builds(told)
 
 
 _BUS_BRIDGE = None
@@ -141,28 +103,17 @@ def install_compile_events(bus) -> None:
     """Bridge compile records onto the telemetry bus as ``compile``
     events (idempotent; installed by ``EventBus.configure`` so any run
     with ``--metrics``/``--events`` gets ``compiles_total`` for free).
-    The handler stays attached for the process lifetime — ``emit`` on an
-    inactive bus is a no-op, so there is no tax once sinks detach.
-
-    Known tradeoff: the dispatch logger stays at DEBUG from here on, so
-    an application that attached its own DEBUG-level root handler will
-    start seeing jax dispatch debug lines once telemetry was enabled
-    (the default root handler drops them; ``jax_log_compiles`` output is
-    unaffected)."""
+    The watcher stays for the process lifetime — ``emit`` on an inactive
+    bus is a no-op, and a warm call of a compiled program reaches no
+    watcher at all, so there is no tax once sinks detach."""
     global _BUS_BRIDGE
     if _BUS_BRIDGE is not None:
         return
+    from cocoa_tpu.telemetry import tracing
 
-    def sink(rec: CompileRecord):
-        bus.emit("compile", name=rec.name, seconds=rec.seconds)
-
-    handler = _CompileLogWatch(sink)
-    logger = logging.getLogger(_DISPATCH_LOGGER)
-    _mute_passthrough_handlers()
-    logger.addHandler(handler)
-    if logger.getEffectiveLevel() > logging.DEBUG:
-        logger.setLevel(logging.DEBUG)
-    _BUS_BRIDGE = handler
+    _BUS_BRIDGE = _compile_sink(
+        lambda rec: bus.emit("compile", name=rec.name, seconds=rec.seconds))
+    tracing.watch_builds(_BUS_BRIDGE)
 
 
 @contextlib.contextmanager

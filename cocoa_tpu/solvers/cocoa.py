@@ -1604,11 +1604,14 @@ def run_sdca_family(
                               start_round, w_init, alpha_init, hist_init,
                               sched_init)
 
-    path = resolve_solver_path(
-        ds, params.local_iters, mesh, math=math, pallas=pallas,
-        block_size=block_size, block_chain=block_chain,
-        block_sparse_gram=block_sparse_gram, loss=params.loss,
-    ).for_mode(alg[0], params.smoothing)
+    # (in a first job the kernels' modules are imported here, Pallas with
+    # them: a second that gets a cold span of its own)
+    with _tracing.first_job_span("resolve_path"):
+        path = resolve_solver_path(
+            ds, params.local_iters, mesh, math=math, pallas=pallas,
+            block_size=block_size, block_chain=block_chain,
+            block_sparse_gram=block_sparse_gram, loss=params.loss,
+        ).for_mode(alg[0], params.smoothing)
     pallas, block_chain = path.pallas, path.block_chain
     if not quiet:
         print(f"local solver: {path.describe()}; the shared vector is "
@@ -1713,6 +1716,8 @@ def run_sdca_family(
               f"{fetches} host fetches")
         if cold:
             print(f"cold path: {_tracing.cold_line(cold)}")
+        for build in _tracing.stray_builds():
+            print(_tracing.stray_line(build))
     return state[0], state[1], traj
 
 

@@ -362,6 +362,17 @@ COLD_SPAN_FIELDS = {"job": (int, type(None)), "hbm_open": (list,),
 HBM_READING_FIELDS = {"device": (int,),
                       "bytes_in_use": (int, type(None)),
                       "peak_bytes_in_use": (int, type(None))}
+# the build account of a cold span (telemetry/tracing.py "The build
+# account"; absent from a stream written before it): the build records
+# that fell in the span, an object each, and their sums
+COLD_BUILD_FIELDS = {"builds": (list,), "trace_s": _NUM, "lower_s": _NUM,
+                     "compile_s": _NUM, "load_s": _NUM,
+                     "cache_misses": (int,)}
+BUILD_RECORD_FIELDS = {"order": (int,), "stage": (str,), "fun_name": (str,),
+                       "start_ts": _NUM, "dur_s": _NUM,
+                       "job": (int, type(None)),
+                       "span": (str, type(None)), "jobs_opened": (int,),
+                       "inner": (int,), "inner_s": _NUM}
 
 
 def _typecheck(obj, fields, where, errors, required=True):
@@ -375,6 +386,17 @@ def _typecheck(obj, fields, where, errors, required=True):
             errors.append(f"{where}: field {name!r} has type "
                           f"{type(v).__name__}, expected "
                           f"{'/'.join(t.__name__ for t in types)}")
+
+
+def _typecheck_each(obj, key, fields, each, where, errors):
+    """Every element of the list ``obj[key]`` is an object with ``fields``
+    (one ``each``: "a device", "a build")."""
+    for item in obj.get(key) or ():
+        if not isinstance(item, dict):
+            errors.append(f"{where}: {key} holds a {type(item).__name__}, "
+                          f"expected an object {each}")
+        else:
+            _typecheck(item, fields, f"{where}: {key}", errors)
 
 
 def check_event_lines(objs) -> list:
@@ -414,14 +436,12 @@ def check_event_lines(objs) -> list:
         if ev == "span" and any(k in obj for k in COLD_SPAN_FIELDS):
             _typecheck(obj, COLD_SPAN_FIELDS, where, errors)
             for key in ("hbm_open", "hbm_close"):
-                for reading in obj.get(key) or ():
-                    if not isinstance(reading, dict):
-                        errors.append(f"{where}: {key} holds a "
-                                      f"{type(reading).__name__}, expected "
-                                      f"an object a device")
-                    else:
-                        _typecheck(reading, HBM_READING_FIELDS,
-                                   f"{where}: {key}", errors)
+                _typecheck_each(obj, key, HBM_READING_FIELDS, "a device",
+                                where, errors)
+            if any(k in obj for k in COLD_BUILD_FIELDS):
+                _typecheck(obj, COLD_BUILD_FIELDS, where, errors)
+                _typecheck_each(obj, "builds", BUILD_RECORD_FIELDS,
+                                "a build", where, errors)
         if ev == "run_start":
             man = obj.get("manifest")
             split = man.get("layout_split") if isinstance(man, dict) else None

@@ -66,12 +66,15 @@ Design constraints, in order:
   ``order_rows`` (data/sharding.order_rows_by_length, once a dataset),
   ``fold_rows`` and ``row_lengths`` (the per-dataset caches of
   ``run_sdca_family``), ``build_start`` (the start program's first call,
-  through to ready), ``build_loop`` (the loop program's first dispatch,
-  the ``_DEVICE_RUNS`` miss: trace, lower, compile or cache load) and
-  ``first_run`` (from that dispatch's return to the first fetch's); and,
-  around the rest of a solver entry's call from the first of them on,
-  ``first_job`` (:func:`cold_entry`).  A cold span is
-  the same annotation and, armed, the same ``span`` event; armed or not it
+  through to ready), ``resolve_path`` (``resolve_solver_path`` in a call
+  that ``build_start`` made a first job: the kernels' modules' first
+  import, ``jax.experimental.pallas`` a second of it, and the fits;
+  :meth:`Tracer.first_job_span`), ``build_loop`` (the loop program's
+  first dispatch, the ``_DEVICE_RUNS`` miss: trace, lower, compile or
+  cache load) and ``first_run`` (from that dispatch's return to the first
+  fetch's); and, around the rest of a solver entry's call from the first
+  of them on, ``first_job`` (:func:`cold_entry`).  A cold span is the
+  same annotation and, armed, the same ``span`` event; armed or not it
   also leaves one record on ``Tracer.cold`` (bounded, oldest dropped),
   with an HBM reading (``memory_stats()`` of each device the job's data
   spans) at open and at close, the close after what the span launched
@@ -79,13 +82,42 @@ Design constraints, in order:
   its arguments' shapes: :func:`program_memory` compiles from them on
   demand, and nothing on a job's path pays for it.  A warm job opens
   none of these, reads no clock and no allocator for them.
+- **The build account** (:func:`observe_builds`): how a cold
+  span's seconds divide between tracing, lowering, compiling and loading,
+  program by program.  jax times each of them itself and hands the time
+  to ``jax.monitoring``'s listeners on the thread that builds; the tracer
+  registers its own once a process and makes of every event one *build
+  record*: ``stage`` (``trace`` the Python function to a jaxpr, ``lower``
+  the jaxpr to MLIR, Mosaic's kernels with it, ``compile`` the backend's
+  build, ``load`` the same where the persistent cache answered), the
+  program's ``fun_name``, ``start_ts`` / ``dur_s`` (jax's own clock reads),
+  ``job`` (the ordinal of the solver entry's call it fell in, else None),
+  ``span`` (the phase of the innermost cold span open on that thread,
+  else None), ``jobs_opened`` (how many entry calls the process had
+  opened by then), ``order`` (its place in the process's sequence of
+  builds) and, of a backend build the cache was asked for, ``cache``
+  (``hit`` / ``miss``: an entry was written).  A traced function's inner
+  ``jit``s (``sin``, ``matmul`` inside ``run``) report their own seconds
+  inside the outer's: only a build that ran inside no other leaves a
+  record, with the number of builds inside it (``inner``) and the seconds
+  of those directly inside it (``inner_s``), so records add up to wall
+  clock.  A record goes to the bounded ``Tracer.builds`` and to each cold
+  span open on its thread, whose closing record and ``span`` event gain
+  ``builds`` and the sums ``trace_s``, ``lower_s``, ``compile_s``,
+  ``load_s``, ``cache_misses`` (schema.COLD_BUILD_FIELDS); one inside an
+  entry's call and under no cold span (a later super-block's shape
+  retracing ``run``, a host-driven job's chunk step) is the call's
+  *stray* (:func:`stray_builds`), which the entry names on the console.
+  A warm call of a jitted function records no event, so a warm job runs
+  no listener; a listener reads no device and never raises into jax.
 
 Span event fields: ``phase`` (the instrument point's name), ``span_id``
 / ``parent_id`` (per-process, thread-safe counter), ``worker`` (the
 process index the tracer was configured with), ``start_ts`` (wall),
 ``dur_s`` (monotonic), plus free-form attributes (``round``, ``path``,
 ``key``, ``generation``, ...) the call site tags on; a cold span's event
-adds ``job``, ``hbm_open`` and ``hbm_close`` (schema.COLD_SPAN_FIELDS).
+adds ``job``, ``hbm_open`` and ``hbm_close`` (schema.COLD_SPAN_FIELDS) and
+the build account's ``builds`` and sums (schema.COLD_BUILD_FIELDS).
 """
 
 from __future__ import annotations
@@ -94,10 +126,12 @@ import collections
 import contextlib
 import functools
 import itertools
+import re
 import threading
 import time
 
 import jax
+from jax import monitoring
 from jax.profiler import TraceAnnotation
 
 # the profiler-clock name of a span: ``cocoa/<phase>``
@@ -125,6 +159,33 @@ SCOPES = (SCOPE_LOCAL_SOLVE, SCOPE_DW_REDUCE, SCOPE_EVAL, SCOPE_INDICES,
 COLD_CAP = 256
 FIRST_JOB = "first_job"
 HBM_KEYS = ("bytes_in_use", "peak_bytes_in_use")
+
+# the build account (module docstring): jax.monitoring's names for the
+# stages of a build, for what the persistent cache answered, and the sums
+# a cold span's closing record keeps of its build records
+BUILDS_CAP = 1024
+STAGE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_OF_EVENT = {"/jax/compilation_cache/cache_hits": "hit",
+                  "/jax/compilation_cache/cache_misses": "miss"}
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+BUILD_STAGES = ("trace", "lower", "compile", "load")
+BUILD_SUMS = tuple(stage + "_s" for stage in BUILD_STAGES) + ("cache_misses",)
+_PROGRAM_RE = re.compile(r"^(?:jit|pmap)\((.*)\)$")
+
+
+def build_sums(builds) -> dict:
+    """Of build records the seconds by stage and the programs compiled
+    and written to the persistent cache."""
+    sums = {stage + "_s": sum(b["dur_s"] for b in builds
+                              if b["stage"] == stage)
+            for stage in BUILD_STAGES}
+    sums["cache_misses"] = sum(b.get("cache") == "miss" for b in builds)
+    return sums
 
 
 def memory_stats(device):
@@ -160,14 +221,16 @@ class _Job:
     """One call of a solver entry (:func:`cold_entry`): the ordinal its
     cold spans share, the dataset whose devices they read, the depth of
     the span stack the entry was called at (where ``first_job`` belongs),
-    and, once a cold branch was taken, ``first_job`` and the records."""
+    once a cold branch was taken ``first_job`` and the records, and the
+    builds that fell in the call under no cold span (``strays``)."""
 
-    __slots__ = ("ordinal", "ds", "depth", "first", "records")
+    __slots__ = ("ordinal", "ds", "depth", "first", "records", "strays")
 
     def __init__(self, ordinal, ds, depth):
         self.ordinal, self.ds, self.depth = ordinal, ds, depth
         self.first = None
         self.records = []
+        self.strays = None
 
 
 def _devices_of(job) -> list:
@@ -186,6 +249,7 @@ class ColdSpan:
     def __init__(self, tracer: "Tracer", phase: str, attrs: dict):
         self.tracer, self.phase, self.attrs = tracer, str(phase), attrs
         self._made = self._program = None
+        self.builds = []        # the builds that fell in it
         self.closed = False
 
     def made(self, *values) -> None:
@@ -213,6 +277,7 @@ class ColdSpan:
         self.devices = (job.first.devices if job is not None
                         and job.first is not None else _devices_of(job))
         self.hbm_open = hbm_reading(self.devices)
+        tr._cold_open().append(self)
         self.start_ts = time.time()
         self.t0 = time.perf_counter()
         return self
@@ -223,6 +288,7 @@ class ColdSpan:
         if self._made is not None and error is None:
             jax.block_until_ready(self._made)
         dur = time.perf_counter() - self.t0
+        tr._cold_open().remove(self)
         hbm_close = hbm_reading(self.devices)
         tr._stack().remove(self.sid)
         self._note.__exit__(None, None, None)
@@ -230,7 +296,8 @@ class ColdSpan:
         record = dict(phase=self.phase, span_id=self.sid,
                       parent_id=self.parent, job=job, start_s=self.t0,
                       dur_s=dur, hbm_open=self.hbm_open, hbm_close=hbm_close,
-                      program=self._program, **self.attrs)
+                      program=self._program, builds=self.builds,
+                      **build_sums(self.builds), **self.attrs)
         if error is not None:
             record["error"] = error
         tr.cold.append(record)
@@ -256,9 +323,9 @@ def _fullest(reading: list) -> dict:
 
 def cold_summary(records) -> list:
     """What ``Trajectory.meta["cold"]`` holds of a job's cold records: per
-    span its phase, seconds, and the rise of the peak across it in bytes,
-    on the device that ends fullest (None where the backend has no
-    counters)."""
+    span its phase, seconds, the rise of the peak across it in bytes, on
+    the device that ends fullest (None where the backend has no counters),
+    and how its builds divide (:func:`build_sums`, with their number)."""
     out = []
     for r in records:
         last = _fullest(r["hbm_close"])
@@ -266,16 +333,30 @@ def cold_summary(records) -> list:
                      if d["device"] == last["device"])
         peak, was = last["peak_bytes_in_use"], first["peak_bytes_in_use"]
         out.append(dict(phase=r["phase"], dur_s=r["dur_s"],
-                        peak_rise=None if peak is None else peak - was))
+                        peak_rise=None if peak is None else peak - was,
+                        builds=len(r["builds"]),
+                        compiled=sum(b["stage"] == "compile"
+                                     for b in r["builds"]),
+                        **{k: r[k] for k in BUILD_SUMS}))
     return out
 
 
 def cold_line(summary: list) -> str:
     """A job's :func:`cold_summary` as the console's ``cold path:`` line:
-    ``first_job`` first, then the spans in the order they closed."""
+    ``first_job`` first, then the spans in the order they closed; of a
+    span that built programs, how the build's seconds divide (``compiled``
+    with the number of programs where the backend built and no cache
+    answered, ``load`` where one did)."""
     def one(c):
         rise = c["peak_rise"]
+        split = [f"{name} {c[name + '_s']:.3f}"
+                 for name in ("trace", "lower") if c.get("builds")]
+        if c.get("compiled"):
+            split.append(f"compiled {c['compiled']} in {c['compile_s']:.3f}")
+        if c.get("load_s"):
+            split.append(f"load {c['load_s']:.3f}")
         return (f"{c['phase']} {c['dur_s']:.3f} s"
+                + (f" ({', '.join(split)})" if split else "")
                 + ("" if not rise else f" (peak +{rise / 1e9:.3f} GB)"))
 
     spans = sorted(summary, key=lambda c: c["phase"] != FIRST_JOB)
@@ -303,8 +384,8 @@ def program_memory(record: dict):
 
 class Tracer:
     """Process-global span source.  ``configure(enabled=True, worker=i)``
-    arms it (the CLI does this under ``--trace``); ``span``/``traced``
-    are the two instrumentation forms.  Spans are emitted through the
+    arms it (the CLI does this under ``--trace``); ``span`` is the
+    instrumentation form.  Spans are emitted through the
     process-global EventBus, so they ride the same JSONL sink, metrics
     writer, and flight-recorder ring as every other event — and an
     armed tracer with an inert bus emits nothing (one more cheap guard).
@@ -317,6 +398,15 @@ class Tracer:
         self._local = threading.local()
         self._jobs = itertools.count(1)
         self.cold = collections.deque(maxlen=COLD_CAP)
+        # the build account: the records, their order, the entry calls
+        # opened so far, the listeners' calls (what observing costs is
+        # this many times a call's microseconds) and who else is told
+        self.builds = collections.deque(maxlen=BUILDS_CAP)
+        self._order = itertools.count(1)
+        self.jobs_opened = 0
+        self.listener_calls = 0
+        self.listener_error = None
+        self._watchers = []
 
     def configure(self, enabled: bool = True, worker=None) -> "Tracer":
         self.enabled = bool(enabled)
@@ -326,19 +416,33 @@ class Tracer:
 
     def reset(self):
         """Disarm and forget the worker tag + id counter, the job
-        ordinals and the cold records (tests)."""
+        ordinals, the cold records and the build records (tests); the
+        builds' watchers stay, and so does the one registration with
+        ``jax.monitoring``."""
         self.enabled = False
         self.worker = None
         self._ids = itertools.count(1)
         self._local = threading.local()
         self._jobs = itertools.count(1)
         self.cold.clear()
+        self.builds.clear()
+        self._order = itertools.count(1)
+        self.jobs_opened = self.listener_calls = 0
+        self.listener_error = None
+        observe_builds()
 
     def _stack(self) -> list:
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
         return st
+
+    def _cold_open(self) -> list:
+        """The cold spans open on this thread, innermost last."""
+        spans = getattr(self._local, "cold_open", None)
+        if spans is None:
+            spans = self._local.cold_open = []
+        return spans
 
     def _push(self, at=None) -> tuple:
         """A new span's id, put on this thread's stack (on top, or at
@@ -401,6 +505,17 @@ class Tracer:
         :meth:`ColdSpan.made` names."""
         return ColdSpan(self, phase, attrs)
 
+    def first_job_span(self, phase: str, **attrs):
+        """A cold span where this thread's solver-entry call has already
+        taken a cold branch (its ``first_job`` is open), else nothing: for
+        a stretch every job walks and only a first job pays for (the
+        kernels' modules' first import under ``resolve_path``).  A warm
+        job reads a thread-local for it."""
+        job = getattr(self._local, "job", None)
+        if job is None or job.first is None:
+            return contextlib.nullcontext()
+        return ColdSpan(self, phase, attrs)
+
     def cold_entry(self, fn):
         """Decorator of a solver entry ``fn(ds, ...)``: gives the call its
         job ordinal, and closes the ``first_job`` span that the call's first
@@ -412,6 +527,7 @@ class Tracer:
             ds = args[0] if args else kwargs.get("ds")
             outer = getattr(local, "job", None)
             local.job = _Job(next(self._jobs), ds, len(self._stack()))
+            self.jobs_opened = local.job.ordinal
             error = None
             try:
                 return fn(*args, **kwargs)
@@ -435,15 +551,82 @@ class Tracer:
             job.first.close(error)
         return cold_summary(job.records)
 
-    def traced(self, phase: str, **attrs):
-        """Decorator form: ``@tracer.traced("checkpoint_save")``."""
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                with self.span(phase, **attrs):
-                    return fn(*args, **kwargs)
-            return wrapper
-        return deco
+    def stray_builds(self) -> list:
+        """The builds of this thread's solver-entry call that fell under
+        no cold span, in order (empty outside an entry, and for a
+        call that built nothing so)."""
+        job = getattr(self._local, "job", None)
+        return list(getattr(job, "strays", None) or ())
+
+    # -- the build account: jax.monitoring's listeners (module docstring).
+    # Each runs on the thread that builds, inside jax: it touches the
+    # tracer's own bookkeeping and nothing else, and whatever goes wrong in
+    # it stays here (``listener_error``).
+
+    def _build_opens(self, event: str) -> None:
+        """A stage begins (jax records its start as a scalar): one level
+        deeper on this thread; a backend build forgets what the cache told
+        an earlier one that never finished."""
+        local = self._local
+        local.depth = getattr(local, "depth", 0) + 1
+        if event == BACKEND_EVENT:
+            local.cache = local.retrieval_s = None
+
+    def _build_closes(self, event: str, start: float, end: float,
+                      fun_name: str = "") -> None:
+        """A stage ended.  Inside another build (most are: a traced
+        function's inner ``jit``s) it is counted on the outer's record to
+        come and, a backend build apart, nothing else; else it leaves its
+        build record, kept and handed to the watchers."""
+        local = self._local
+        depth = local.depth = max(getattr(local, "depth", 1) - 1, 0)
+        if depth:
+            local.inner = getattr(local, "inner", 0) + 1
+            if depth == 1:
+                local.inner_s = getattr(local, "inner_s", 0.0) + end - start
+            if event != BACKEND_EVENT or not self._watchers:
+                return
+        job = getattr(local, "job", None)
+        spans = getattr(local, "cold_open", None) or ()
+        named = _PROGRAM_RE.match(str(fun_name))
+        record = dict(
+            order=next(self._order), stage=STAGE_OF_EVENT[event],
+            fun_name=named.group(1) if named else str(fun_name),
+            start_ts=start, dur_s=end - start,
+            job=None if job is None else job.ordinal,
+            span=spans[-1].phase if spans else None,
+            jobs_opened=self.jobs_opened)
+        if event == BACKEND_EVENT:
+            cache = getattr(local, "cache", None)
+            if cache is not None:
+                record["cache"] = cache
+            if cache == "hit":
+                record["stage"] = "load"
+                record["retrieval_s"] = getattr(local, "retrieval_s", None)
+            local.cache = local.retrieval_s = None
+        if not depth:
+            record["inner"] = getattr(local, "inner", 0)
+            record["inner_s"] = getattr(local, "inner_s", 0.0)
+            local.inner, local.inner_s = 0, 0.0
+            self.builds.append(record)
+            for cold in spans:
+                cold.builds.append(record)
+            if job is not None and not spans:
+                if job.strays is None:
+                    job.strays = []
+                job.strays.append(record)
+        for told in tuple(self._watchers):
+            told(record)
+
+    def watch_builds(self, told) -> None:
+        """``told(record)`` for every build record from now on, on the
+        thread that builds (analysis/sanitize.py's compile watch and the
+        bus's ``compile`` event hang here)."""
+        self._watchers.append(told)
+
+    def unwatch_builds(self, told) -> None:
+        if told in self._watchers:
+            self._watchers.remove(told)
 
 
 _TRACER = Tracer()
@@ -463,14 +646,14 @@ def span(phase: str, **attrs):
     return _TRACER.span(phase, **attrs)
 
 
-def traced(phase: str, **attrs):
-    """Module-level convenience decorator."""
-    return _TRACER.traced(phase, **attrs)
-
-
 def cold_span(phase: str, **attrs) -> ColdSpan:
     """Module-level convenience: ``with tracing.cold_span("fold_rows"):``"""
     return _TRACER.cold_span(phase, **attrs)
+
+
+def first_job_span(phase: str, **attrs):
+    """Module-level convenience (:meth:`Tracer.first_job_span`)."""
+    return _TRACER.first_job_span(phase, **attrs)
 
 
 def cold_entry(fn):
@@ -481,6 +664,101 @@ def cold_entry(fn):
 def finish_job() -> list:
     """Module-level convenience (:meth:`Tracer.finish_job`)."""
     return _TRACER.finish_job()
+
+
+def stray_builds() -> list:
+    """Module-level convenience (:meth:`Tracer.stray_builds`)."""
+    return _TRACER.stray_builds()
+
+
+def stray_line(build: dict) -> str:
+    """A stray build as the console names it."""
+    return (f"built outside the cold path: {build['fun_name']} "
+            f"{build['stage']} {build['dur_s']:.3f} s")
+
+
+# jax.monitoring's listeners.  Each filters by the event's name before
+# anything else (most events are not a build's), counts itself, and keeps
+# what goes wrong in it to itself: never let observing break a build.
+
+def _on_scalar(event, value, **kw):
+    if event in STAGE_OF_EVENT:
+        try:
+            _TRACER.listener_calls += 1
+            _TRACER._build_opens(event)
+        except Exception as e:
+            _TRACER.listener_error = repr(e)
+
+
+def _on_time_span(event, start, end, fun_name="", **kw):
+    if event in STAGE_OF_EVENT:
+        try:
+            _TRACER.listener_calls += 1
+            _TRACER._build_closes(event, start, end, fun_name)
+        except Exception as e:
+            _TRACER.listener_error = repr(e)
+
+
+def _on_event(event, **kw):
+    if event in CACHE_OF_EVENT:
+        try:
+            _TRACER.listener_calls += 1
+            _TRACER._local.cache = CACHE_OF_EVENT[event]
+        except Exception as e:
+            _TRACER.listener_error = repr(e)
+
+
+def _on_duration(event, seconds, **kw):
+    if event == CACHE_RETRIEVAL_EVENT:
+        try:
+            _TRACER.listener_calls += 1
+            _TRACER._local.retrieval_s = seconds
+        except Exception as e:
+            _TRACER.listener_error = repr(e)
+
+
+_LISTENERS = (
+    (_on_scalar, monitoring.register_scalar_listener,
+     monitoring.unregister_scalar_listener),
+    (_on_time_span, monitoring.register_event_time_span_listener,
+     monitoring.unregister_event_time_span_listener),
+    (_on_event, monitoring.register_event_listener,
+     monitoring.unregister_event_listener),
+    (_on_duration, monitoring.register_event_duration_secs_listener,
+     monitoring.unregister_event_duration_listener),
+)
+_observing = False
+
+
+def observe_builds(on: bool = True) -> None:
+    """Register the build account's listeners with ``jax.monitoring``,
+    once a process however often it is asked (at import, and by
+    :func:`reset`); ``on=False`` takes them off again (a measurement of
+    what observing costs)."""
+    global _observing
+    if on == _observing:
+        return
+    for listener, register, unregister in _LISTENERS:
+        if on:
+            register(listener)
+        else:
+            try:
+                unregister(listener)
+            except AssertionError:      # someone cleared jax's lists
+                pass
+    _observing = on
+
+
+observe_builds()
+
+
+def watch_builds(told) -> None:
+    """Module-level convenience (:meth:`Tracer.watch_builds`)."""
+    _TRACER.watch_builds(told)
+
+
+def unwatch_builds(told) -> None:
+    _TRACER.unwatch_builds(told)
 
 
 def reset():

@@ -10,6 +10,8 @@ fake that counts its calls: the CPU backend has no counters of its own.
 """
 
 import json
+import logging
+import re
 
 import jax.numpy as jnp
 import numpy as np
@@ -127,8 +129,12 @@ def test_first_job_of_a_dense_set_leaves_its_cold_spans(tiny_data,
     cold = list(tracing.get_tracer().cold)
     phases = _by_phase(cold)
     assert set(phases) == {"first_job", "fold_rows", "build_start",
-                           "build_loop", "first_run"}
+                           "resolve_path", "build_loop", "first_run"}
     assert all(len(v) == 1 for v in phases.values())
+    # they close in the order the job walks them
+    assert [r["phase"] for r in cold] == [
+        "build_start", "resolve_path", "fold_rows", "build_loop",
+        "first_run", "first_job"]
     # one job, one ordinal; every span under first_job, which closes last
     assert {r["job"] for r in cold} == {1}
     (first,) = phases["first_job"]
@@ -373,3 +379,370 @@ def test_armed_cold_job_equals_a_disarmed_one_bit_for_bit(tiny_data,
     np.testing.assert_array_equal(a1, a2)
     assert [(r.round, r.primal, r.gap) for r in t1.records] == \
         [(r.round, r.primal, r.gap) for r in t2.records]
+
+
+# -- the build account (telemetry/tracing.py): one record a stage of every
+# build, from jax.monitoring's listeners, on the span it fell in ---------
+
+BACKEND = {"compile", "load"}   # the suite's compile cache may answer
+
+
+def _program():
+    """A jitted function no process has called: two inner ``jit``s inside
+    an outer one, so the outer's trace holds theirs."""
+    import jax
+
+    inner = jax.jit(lambda x: jnp.sin(x) @ x)
+    return jax.jit(lambda x: inner(x) + jnp.matmul(x, x))
+
+
+def _stages(builds):
+    return [b["stage"] if b["stage"] not in BACKEND else "backend"
+            for b in builds]
+
+
+def test_a_jit_first_called_in_a_cold_span_leaves_its_build_records():
+    tracer, run = tracing.get_tracer(), _program()
+    x = jnp.ones((8, 8), jnp.float32)
+    run.__wrapped__.__name__ = "probe_run"
+    seen = len(tracer.builds)
+    with tracing.cold_span("build_loop") as cold:
+        run(x).block_until_ready()
+    record = tracer.cold[-1]
+    mine = [b for b in record["builds"] if b["fun_name"] == "probe_run"]
+    assert _stages(mine) == ["trace", "lower", "backend"]
+    assert all(b["span"] == "build_loop" and b["job"] is None for b in mine)
+    assert [b["order"] for b in mine] == sorted(b["order"] for b in mine)
+    assert all(b in tracer.builds for b in mine)
+    assert len(tracer.builds) - seen == len(record["builds"])
+    # the sums are the records', stage by stage
+    for stage in tracing.BUILD_STAGES:
+        assert record[stage + "_s"] == pytest.approx(sum(
+            b["dur_s"] for b in record["builds"] if b["stage"] == stage))
+    assert record["trace_s"] > 0 and record["lower_s"] > 0
+    assert record["compile_s"] + record["load_s"] > 0
+    assert record["trace_s"] + record["lower_s"] + record["compile_s"] \
+        + record["load_s"] <= record["dur_s"]
+    assert cold.builds is record["builds"]
+    # inner jits are in the outer's seconds, once: no record of their own,
+    # counted on the outer's, their seconds no more than its
+    (trace,) = [b for b in mine if b["stage"] == "trace"]
+    assert trace["inner"] >= 2 and 0 < trace["inner_s"] <= trace["dur_s"]
+    assert not any(b["fun_name"] in ("sin", "matmul", "<lambda>")
+                   and b["stage"] == "trace" and b is not trace
+                   and trace["start_ts"] <= b["start_ts"]
+                   <= trace["start_ts"] + trace["dur_s"]
+                   for b in tracer.builds)
+    # a second call builds nothing: no record, no listener call
+    n, calls = len(tracer.builds), tracer.listener_calls
+    with tracing.cold_span("build_loop"):
+        run(x).block_until_ready()
+    assert tracer.cold[-1]["builds"] == []
+    assert (len(tracer.builds), tracer.listener_calls) == (n, calls)
+    assert tracer.listener_error is None
+
+
+def test_nested_cold_spans_each_hold_the_build_and_name_the_innermost():
+    run = _program()
+    with tracing.cold_span("order_rows"):
+        with tracing.cold_span("fold_rows"):
+            run(jnp.ones((4, 4), jnp.float32))
+    inner, outer = list(tracing.get_tracer().cold)[-2:]
+    assert inner["phase"] == "fold_rows" and outer["phase"] == "order_rows"
+    assert inner["builds"] and inner["builds"] == outer["builds"]
+    assert {b["span"] for b in inner["builds"]} == {"fold_rows"}
+    assert outer["trace_s"] == inner["trace_s"]
+
+
+_SECOND_PROCESS = """
+import json, sys
+from cocoa_tpu.utils import compile_cache
+compile_cache.enable()
+import jax, jax.numpy as jnp
+from cocoa_tpu.telemetry import tracing
+
+def cached_probe(x):
+    return jnp.tanh(x) @ x + 3.0
+
+x = jnp.ones((16, 16), jnp.float32)        # its own eager programs: before
+with tracing.cold_span("build_loop"):
+    jax.jit(cached_probe)(x).block_until_ready()
+r = tracing.get_tracer().cold[-1]
+mine = [b for b in r["builds"] if b["fun_name"] == "cached_probe"]
+print(json.dumps({"stages": [b["stage"] for b in mine],
+                  "cache": [b.get("cache") for b in mine],
+                  "misses": sum(b.get("cache") == "miss" for b in mine),
+                  "load_s": sum(b["dur_s"] for b in mine
+                                if b["stage"] == "load"),
+                  "line": tracing.cold_line(tracing.cold_summary([r]))}))
+"""
+
+
+def test_a_second_process_loads_what_the_first_compiled(tmp_path):
+    """With a persistent cache directory the first process's backend build
+    is a ``compile`` that wrote an entry (a miss), the second's a ``load``
+    and no miss; the console's line says ``compiled`` and ``load``."""
+    import os
+    import subprocess
+    import sys
+
+    env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cc"),
+           "JAX_PLATFORMS": "cpu"}
+    env.pop("COCOA_NO_COMPILE_CACHE", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def process():
+        out = subprocess.run([sys.executable, "-c", _SECOND_PROCESS],
+                             env=env, cwd=root, capture_output=True,
+                             text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    first, second = process(), process()
+    assert first["stages"] == ["trace", "lower", "compile"]
+    assert first["misses"] == 1 and first["cache"][-1] == "miss"
+    assert "compiled 1 in " in first["line"] and "load" not in first["line"]
+    assert second["stages"] == ["trace", "lower", "load"]
+    assert second["misses"] == 0 and second["cache"][-1] == "hit"
+    assert second["load_s"] > 0
+    assert "load " in second["line"] and "compiled" not in second["line"]
+
+
+def test_a_build_in_an_entry_under_no_cold_span_is_named_with_its_job(
+        capsys):
+    run = _program()
+    run.__wrapped__.__name__ = "late_shape"
+
+    @tracing.cold_entry
+    def entry(ds, quiet=False):
+        run(jnp.ones((3, 3), jnp.float32))
+        strays = tracing.stray_builds()
+        for build in strays:
+            print(tracing.stray_line(build))
+        return strays
+
+    assert tracing.stray_builds() == []         # outside any entry
+    entry(None)
+    strays = entry(None)
+    assert strays == []                         # warm: nothing built
+    out = capsys.readouterr().out.splitlines()
+    named = [ln for ln in out if "late_shape" in ln]
+    assert [ln.split()[6] for ln in named][:2] == ["trace", "lower"]
+    assert all(ln.startswith("built outside the cold path: late_shape ")
+               and ln.endswith(" s") for ln in named) and len(named) == 3
+    mine = [b for b in tracing.get_tracer().builds
+            if b["fun_name"] == "late_shape"]
+    assert [(b["job"], b["span"], b["jobs_opened"]) for b in mine] \
+        == [(1, None, 1)] * 3
+    assert list(tracing.get_tracer().cold) == []     # and no cold span
+
+
+def test_a_listeners_exception_never_reaches_jax(monkeypatch):
+    tracer = tracing.get_tracer()
+
+    def boom(*a, **kw):
+        raise RuntimeError("observer down")
+
+    monkeypatch.setattr(tracer, "_build_closes", boom)
+    monkeypatch.setattr(tracer, "_build_opens", boom)
+    out = _program()(jnp.ones((5, 5), jnp.float32))
+    assert float(out[0, 0]) == pytest.approx(np.sin(1.0) * 5 + 5)
+    assert "observer down" in tracer.listener_error
+    assert len(tracer.builds) == 0
+    # a watcher that raises is the listener's to swallow too
+    monkeypatch.undo()
+    tracing.watch_builds(boom)
+    try:
+        _program()(jnp.ones((6, 6), jnp.float32))
+    finally:
+        tracing.unwatch_builds(boom)
+    assert len(tracer.builds) > 0
+
+
+def test_reset_leaves_one_registration():
+    from jax._src import monitoring
+
+    def registered():
+        return [
+            monitoring.get_scalar_listeners().count(tracing._on_scalar),
+            monitoring.get_event_time_span_listeners().count(
+                tracing._on_time_span),
+            monitoring.get_event_listeners().count(tracing._on_event),
+            monitoring.get_event_duration_listeners().count(
+                tracing._on_duration)]
+
+    assert registered() == [1, 1, 1, 1]
+    for _ in range(3):
+        tracing.reset()
+        tracing.observe_builds()
+    assert registered() == [1, 1, 1, 1]
+    tracing.observe_builds(False)
+    try:
+        assert registered() == [0, 0, 0, 0]
+        _program()(jnp.ones((7, 7), jnp.float32))
+        assert len(tracing.get_tracer().builds) == 0
+    finally:
+        tracing.reset()             # puts the registration back
+    assert registered() == [1, 1, 1, 1]
+
+
+def test_ten_warm_jobs_call_no_listener_and_open_what_they_did(
+        tiny_data, cold_process, monkeypatch):
+    ds = _dense(tiny_data)
+    _svm(ds)
+    tracer = tracing.get_tracer()
+    calls, n_builds, n_cold = (tracer.listener_calls, len(tracer.builds),
+                               len(tracer.cold))
+    assert calls > 0 and n_builds > 0
+    notes = _Annotations()
+    monkeypatch.setattr(tracing, "TraceAnnotation", notes)
+    reads = cold_process.calls
+    for _ in range(10):
+        _, _, traj = _svm(ds)
+        assert traj.meta["cold"] == []
+    assert tracer.listener_calls == calls
+    assert (len(tracer.builds), len(tracer.cold)) == (n_builds, n_cold)
+    assert notes.opened == WARM_ANNOTATIONS * 10
+    assert cold_process.calls == reads
+    assert tracer.jobs_opened == 11
+
+
+def test_first_job_shows_the_split_on_console_meta_and_span_event(
+        tiny_data, tmp_path, capsys):
+    path = tmp_path / "events.jsonl"
+    tele_events.get_bus().configure(jsonl_path=str(path))
+    tracing.configure(enabled=True, worker=0)
+    _, _, traj = _svm(_dense(tiny_data), quiet=False)
+    tele_events.get_bus().reset()
+    meta = {c["phase"]: c for c in traj.meta["cold"]}
+    loop, first = meta["build_loop"], meta["first_job"]
+    assert loop["builds"] == 3 and loop["trace_s"] > 0 and loop["lower_s"] > 0
+    assert loop["compile_s"] + loop["load_s"] > 0
+    assert loop["cache_misses"] in (0, 1)
+    # first_job holds its children's builds: the whole job's account
+    assert first["builds"] >= loop["builds"] + meta["build_start"]["builds"]
+    assert first["trace_s"] >= loop["trace_s"]
+    assert meta["first_run"]["builds"] == 0
+    (line,) = [ln for ln in capsys.readouterr().out.splitlines()
+               if ln.startswith("cold path:")]
+    assert f"build_loop {loop['dur_s']:.3f} s (trace {loop['trace_s']:.3f}, " \
+           f"lower {loop['lower_s']:.3f}, " in line
+    assert ("load " in line) or ("compiled " in line)
+    ran = "first_run %.3f s" % meta["first_run"]["dur_s"]
+    assert ran in line and ran + " (trace" not in line   # it built nothing
+    # the armed tracer's span event carries the records and the sums
+    lines = [json.loads(ln) for ln in open(path)]
+    assert tele_schema.check_event_lines(list(enumerate(lines, 1))) == []
+    (event,) = [e for e in lines if e["event"] == "span"
+                and e["phase"] == "build_loop"]
+    assert [b["stage"] for b in event["builds"]][:2] == ["trace", "lower"]
+    assert {b["fun_name"] for b in event["builds"]} == {"run"}
+    assert event["trace_s"] == loop["trace_s"]
+    assert all(b["job"] == event["job"] == 1 for b in event["builds"])
+    broken = dict(event, builds=[{"stage": 3}], seq=10 ** 6)
+    assert tele_schema.check_event_lines([(1, broken)])
+    # the bus's compile event is the same record's backend stage
+    compiles = [e for e in lines if e["event"] == "compile"]
+    assert any(e["name"] == "run" and e["seconds"] == pytest.approx(
+        loop["compile_s"] + loop["load_s"]) for e in compiles)
+
+
+class _DispatchLog(logging.Handler):
+    """jax's own compile log, read as the handler this repo had read it:
+    ``Finished XLA compilation of jit(<name>) in <s> sec`` at DEBUG."""
+
+    PATTERN = re.compile(
+        r"Finished XLA compilation of (?:jit\(|pmap\()?([^)]+?)\)? in "
+        r"([0-9.eE+-]+) sec")
+
+    def __init__(self):
+        super().__init__(level=logging.DEBUG)
+        self.seen = []
+
+    def emit(self, record):
+        m = self.PATTERN.search(record.getMessage())
+        if m:
+            self.seen.append((m.group(1), float(m.group(2))))
+
+
+def _builds_while_traced(x):
+    import jax
+
+    with jax.ensure_compile_time_eval():
+        c = jnp.arange(5.0, dtype=jnp.float32) * jnp.float32(2.5)
+    return x + c
+
+
+def test_watch_compiles_reads_what_the_compile_log_prints(tiny_data):
+    """The build account's ``compile`` / ``load`` records beside jax's
+    dispatch log, which ``watch_compiles`` scraped before: the same
+    programs in the same order, the same seconds to the log's nine
+    digits, over a whole first job and its eager ops."""
+    import jax
+
+    from cocoa_tpu.analysis import sanitize
+
+    logger = logging.getLogger("jax._src.dispatch")
+    handler, level = _DispatchLog(), logger.level
+    muted = [h for h in logging.getLogger("jax").handlers
+             if h.level == logging.NOTSET]
+    for h in muted:
+        h.setLevel(logging.WARNING)
+    logger.addHandler(handler)
+    logger.setLevel(logging.DEBUG)
+    try:
+        with sanitize.watch_compiles() as compiles:
+            _svm(_dense(tiny_data))
+            _program()(jnp.ones((9, 9), jnp.float32))
+            jnp.arange(7) * 3                       # eager: its own programs
+            five = jnp.ones(5, jnp.float32)
+            n_builds = len(tracing.get_tracer().builds)
+            jax.jit(_builds_while_traced)(five)
+    finally:
+        logger.removeHandler(handler)
+        logger.setLevel(level)
+        for h in muted:
+            h.setLevel(logging.NOTSET)
+    assert len(compiles) >= 4
+    assert [c.name for c in compiles] == [name for name, _ in handler.seen]
+    # a backend build INSIDE another build (an eager op while a function
+    # is traced) leaves no record of its own, is counted on the outer's,
+    # and still reaches the watch, as it reached the log
+    late = list(tracing.get_tracer().builds)[n_builds:]
+    assert {b["fun_name"] for b in late} == {"_builds_while_traced"}
+    (trace,) = [b for b in late if b["stage"] == "trace"]
+    assert trace["inner"] >= 4
+    assert "multiply" in [c.name for c in compiles]
+    assert len(compiles) > len(list(tracing.get_tracer().builds)) // 3
+    for c, (_, seconds) in zip(compiles, handler.seen):
+        assert round(c.seconds, 9) == pytest.approx(seconds, abs=2e-9)
+    # and once the context closed, the list stops growing
+    n = len(compiles)
+    _program()(jnp.ones((10, 10), jnp.float32))
+    assert len(compiles) == n
+
+
+def test_first_job_span_opens_only_where_the_call_is_already_cold():
+    """``resolve_path``'s form: nothing outside an entry, nothing in a call
+    that took no cold branch, a cold span under ``first_job`` in one that
+    did."""
+    tracer = tracing.get_tracer()
+    with tracing.first_job_span("resolve_path") as nothing:
+        assert nothing is None
+    assert len(tracer.cold) == 0
+
+    @tracing.cold_entry
+    def entry(ds, cold: bool):
+        if cold:
+            with tracing.cold_span("build_start"):
+                pass
+        with tracing.first_job_span("resolve_path") as span:
+            return span
+
+    assert entry(None, False) is None and len(tracer.cold) == 0
+    span = entry(None, True)
+    assert isinstance(span, tracing.ColdSpan)
+    assert [(r["phase"], r["job"]) for r in tracer.cold] == [
+        ("build_start", 2), ("resolve_path", 2), ("first_job", 2)]
+    first = tracer.cold[-1]
+    assert tracer.cold[1]["parent_id"] == first["span_id"]

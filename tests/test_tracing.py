@@ -93,20 +93,17 @@ def test_span_nesting_parent_ids_and_attrs():
     assert outer_s["start_ts"] <= inner_s["start_ts"] + 1.0
 
 
-def test_traced_decorator_and_error_attribute():
+def test_span_whose_body_raises_carries_error():
     events = _collect()
     tracing.configure(enabled=True)
-
-    @tracing.traced("work", kind="unit")
-    def work(x):
-        return x + 1
-
-    assert work(1) == 2
+    with tracing.span("work", kind="unit"):
+        pass
     with pytest.raises(ValueError):
         with tracing.span("doomed"):
             raise ValueError("boom")
     spans = [e for e in events if e["event"] == "span"]
     assert spans[0]["phase"] == "work" and spans[0]["kind"] == "unit"
+    assert "error" not in spans[0]
     assert spans[1]["phase"] == "doomed" and spans[1]["error"] == "ValueError"
 
 
