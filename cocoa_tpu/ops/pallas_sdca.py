@@ -1074,10 +1074,24 @@ def pallas_sdca_round(
 # DMA a shard at the grid's two ends (no pipelined, double-buffered block
 # of it), and the kernel asks for ``CLASS_VMEM_LIMIT``.  A set whose state
 # is larger does not fit (:func:`classes_fit`) and runs ``fori``.
+#
+# **Between rounds** the tiles are the training loop's carry, in HBM: a
+# chunk of rounds packs them once from alpha (T, K, n_shard)
+# (:func:`class_state_pack`), every round's call takes them and gives them
+# back in one aliased buffer with the outer scaling law already applied
+# (:func:`_scale_tiles`, in the kernel's epilogue), and alpha is read back
+# once, for the eval behind the chunk (:func:`class_state_alpha`).
 
 CLASS_VMEM_LIMIT = 110 << 20   # asked of Mosaic (a v5e core has 128 MiB)
 CLASS_VMEM_BUDGET = 96 << 20   # of it, what :func:`class_vmem_estimate`
                                # may come to: the rest is the compiler's
+# blocks of a shard's tiles whose old values come back at a time for the
+# scaling law (:func:`_scale_tiles`): 32 x R x 128 floats, 256 KB at R = 16,
+# in each of two slots.  The read is what the law costs (0.17 ms of
+# mnist8m's round for 64.8 MB; its arithmetic alone 0.04): 8 blocks a copy
+# read 0.29 ms, 32 to 128 blocks and two to four slots all 0.17 (PERF.md
+# section 6, PR 51)
+LAW_BLOCKS = 32
 
 
 def class_rows(t: int) -> int:
@@ -1088,13 +1102,15 @@ def class_rows(t: int) -> int:
 
 def class_vmem_estimate(k: int, n_shard: int, d: int, t: int,
                         itemsize: int, depth: int = RING_DEPTHS[-1]) -> int:
-    """Working set of the T-class kernel: all K shards' state tiles, the
-    (T, 8, d/8) w, K accumulators and the output of that shape (each
-    class's fold lane-padded to whole tiles), and the ring's ``depth``
-    lockstep steps of K folded rows."""
+    """Working set of the T-class kernel: all K shards' state tiles and
+    the scaling law's two slots of old ones, the (T, 8, d/8) w, K
+    accumulators and the output of that shape (each class's fold
+    lane-padded to whole tiles), and the ring's ``depth`` lockstep steps of
+    K folded rows."""
     n_blocks = -(-n_shard // LANES)
     fold = lane_tiles(d)
-    return itemsize * (k * n_blocks * class_rows(t) * LANES
+    return itemsize * ((k * n_blocks + 2 * min(LAW_BLOCKS, n_blocks))
+                       * class_rows(t) * LANES
                        + (k + 3) * t * fold + depth * k * fold)
 
 
@@ -1172,19 +1188,23 @@ def _kernel_classes(
     w_ref,           # (T, 8, lanes) VMEM: the class models
     state_in,        # (K, n_blocks, R, 128) in HBM: every shard's tiles
     dw_ref,          # out (T, 8, lanes): the shards' summed updates
-    state_out,       # out, in HBM, as state_in
-    *scratch,        # K accumulators, K states, the ring, its semaphores
+    state_out,       # out, in HBM, as state_in (and, aliased, state_in)
+    *scratch,        # K accumulators, K states, the ring, its semaphores,
+                     # and under a scaling law its old tiles and theirs
     h: int,
     k: int,
     depth: int,
+    scaling,
     **step_kw,
 ):
     """The interleaved kernel with a class axis: the whole round in one
     grid iteration, every shard's chain advanced in lockstep, each chain's
     accumulator and state in scratch refs of its own, the rows by
-    :func:`_ring_steps`."""
+    :func:`_ring_steps`.  ``scaling`` (None: the advanced tiles leave as
+    they are): the round's outer scaling law, :func:`_scale_tiles`, on a
+    shard's tiles before they leave."""
     dw_accs, st_scs = scratch[:k], scratch[k:2 * k]
-    ring, sem = scratch[2 * k:]
+    ring, sem, *law = scratch[2 * k:]
     for kk in range(k):
         dw_accs[kk][...] = jnp.zeros_like(dw_accs[kk])
         pltpu.sync_copy(state_in.at[kk], st_scs[kk])
@@ -1201,7 +1221,177 @@ def _kernel_classes(
         dw_sum = dw_sum + dw_accs[kk][...]
     dw_ref[...] = dw_sum
     for kk in range(k):
+        if scaling is not None:
+            _scale_tiles(state_in.at[kk], st_scs[kk], *law, t=step_kw["t"],
+                         scaling=scaling)
         pltpu.sync_copy(st_scs[kk], state_out.at[kk])
+
+
+def _scale_tiles(old_hbm, st, old, sem, *, t: int, scaling: float):
+    """The outer scaling law on one shard's advanced tiles ``st`` (n_blocks,
+    R, 128), in VMEM: ``old + scaling (st - old)`` on the alpha sublanes
+    (``sub < t``), the norms, the class ids and the padding left as they
+    are.  The round's input tiles are still in HBM (``old_hbm``: nothing
+    has written the state's result yet) and come back ``LAW_BLOCKS`` blocks
+    at a time through the two slots of ``old``, a slot's copy in flight
+    under the other's arithmetic (one vector expression a chunk: its loads
+    all issue before its stores).  The operations are the ones the loop
+    ran on (T, K, n_shard) before the state stayed in tile form: a
+    subtract, a multiply and an add in float32, so the job's (W, alpha)
+    does not move by a bit; ``old + 1.0 (st - old)`` is not ``st`` in
+    floating point, so the law runs at gamma = 1 too."""
+    n_blocks, rows, _ = st.shape
+    cb = min(LAW_BLOCKS, n_blocks)
+    n_full, tail = divmod(n_blocks, cb)
+    is_alpha = jax.lax.broadcasted_iota(jnp.int32, (1, rows, LANES), 1) < t
+
+    def fetch(c, slot, size):
+        return pltpu.make_async_copy(old_hbm.at[pl.ds(c * cb, size)],
+                                     old.at[slot, pl.ds(0, size)],
+                                     sem.at[slot])
+
+    def scale(c, slot, size):
+        at = pl.ds(c * cb, size)
+        new, was = st[at], old[slot, pl.ds(0, size)]
+        st[at] = jnp.where(is_alpha, was + scaling * (new - was), new)
+
+    fetch(0, 0, cb).start()
+
+    def chunk(c, carry):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_full)
+        def _next():
+            fetch(c + 1, 1 - slot, cb).start()
+
+        fetch(c, slot, cb).wait()
+        scale(c, slot, cb)
+        return carry
+
+    jax.lax.fori_loop(0, n_full, chunk, 0)
+    if tail:
+        last = fetch(n_full, 0, tail)
+        last.start()
+        last.wait()
+        scale(n_full, 0, tail)
+
+
+def class_state_pack(alpha: jax.Array, sq_norms: jax.Array,
+                     classes: jax.Array) -> jax.Array:
+    """The class kernel's state from what the loop holds: alpha (T, K,
+    n_shard), the rows' norms and class ids (K, n_shard) -> (K, n_blocks, R,
+    128) tiles (the section's note above: rows lane-blocked, tile row t < T
+    the block's alpha_t, row T the norms, row T + 1 the class ids as
+    floats, zeros past them and past n_shard).  Once a chunk of rounds,
+    outside its scan (solvers/cocoa._make_chunk_kernel): a transpose, two
+    pads and a concatenate over 64.8 MB at mnist8m's eighth, 0.2 ms, too
+    much for every round."""
+    t, k, n_shard = alpha.shape
+    dtype = alpha.dtype
+    n_blocks = -(-n_shard // LANES)
+
+    def blocked(v):
+        v = jnp.pad(v.astype(dtype), [(0, 0)] * (v.ndim - 1)
+                    + [(0, n_blocks * LANES - n_shard)])
+        return v.reshape(*v.shape[:-1], n_blocks, LANES)
+
+    return jnp.concatenate(
+        [jnp.transpose(blocked(alpha), (1, 2, 0, 3)),
+         blocked(sq_norms)[:, :, None], blocked(classes)[:, :, None],
+         jnp.zeros((k, n_blocks, class_rows(t) - t - 2, LANES), dtype)],
+        axis=2)
+
+
+def class_state_alpha(state: jax.Array, t: int, n_shard: int) -> jax.Array:
+    """alpha (T, K, n_shard) back out of the tiles of
+    :func:`class_state_pack`: what the eval, the stop rule and the caller
+    read, once a chunk."""
+    k, n_blocks = state.shape[:2]
+    return jnp.transpose(state, (2, 0, 1, 3))[:t].reshape(
+        t, k, n_blocks * LANES)[:, :, :n_shard]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("lam", "n", "mode", "sigma", "interpret", "loss",
+                     "smoothing", "depth", "scaling"),
+)
+def pallas_sdca_round_classes_tiles(
+    w: jax.Array,            # (T, d) the replicated class models
+    state: jax.Array,        # (K, n_blocks, R, 128): class_state_pack's
+    X: jax.Array,            # (K, n_shard, d) dense rows, or folded
+    idxs: jax.Array,         # (K, H) int32: every class takes these rows
+    lam: float,
+    n: int,
+    mode: str = "plus",
+    sigma: float = 1.0,
+    interpret: bool = False,
+    loss: str = "hinge",
+    smoothing: float = 1.0,
+    depth: int = 0,
+    scaling=None,
+):
+    """One SDCA round of T one-vs-rest models over the K shards' sampled
+    rows, on the kernel's own state: (dw (T, d), the shards' updates summed
+    shard 0 first; the state tiles after the round).  The tiles go in and
+    come out as one buffer (the operand is aliased to the result), so a
+    loop that carries them holds one copy.  ``scaling`` (None: the tiles'
+    alphas leave locally advanced, and the caller applies the outer law):
+    the round's scaling law, applied to the alphas in the kernel's epilogue
+    (:func:`_scale_tiles`).  ``depth``: the row ring's (0 = auto,
+    :func:`class_ring_depth`), as :func:`pallas_sdca_round`'s."""
+    t, d_orig = w.shape
+    X_folded = X if X.ndim == 4 else _fold(X, SUBLANES)
+    k, n_shard, _, lanes = X_folded.shape
+    d8 = fold_lanes(d_orig, lanes)
+    X_folded = lane_aligned(X_folded, interpret)
+    lanes = X_folded.shape[-1]
+    h = idxs.shape[1]
+    dtype = X.dtype
+    check_dtype(dtype)
+    depth = depth or class_ring_depth(
+        k, n_shard, SUBLANES * d8, t, jnp.dtype(dtype).itemsize, h) or 2
+    sig_eff, qii_factor = mode_factors(mode, sigma)
+    _, n_blocks, rows, _ = state.shape
+
+    kernel = functools.partial(
+        _kernel_classes, k=k, t=t, h=h, depth=depth,
+        scaling=None if scaling is None else float(scaling),
+        lam_n=float(lam * n), coef_div=float(coef_divisor(mode, lam * n)),
+        sig_eff=float(sig_eff), qii_factor=float(qii_factor),
+        frozen=(mode == "frozen"), loss=losses.validate(loss, smoothing),
+        smoothing=float(smoothing))
+    whole = pl.BlockSpec((t, SUBLANES, lanes), lambda i_, idxs_: (0, 0, 0))
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    law = ([] if scaling is None else
+           [pltpu.VMEM((2, min(LAW_BLOCKS, n_blocks), rows, LANES), dtype),
+            pltpu.SemaphoreType.DMA((2,))])
+    dw, state = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[any_, whole, any_],
+            out_specs=[whole, any_],
+            scratch_shapes=(
+                [pltpu.VMEM((t, SUBLANES, lanes), dtype)] * k
+                + [pltpu.VMEM((n_blocks, rows, LANES), dtype)] * k
+                + [pltpu.VMEM((depth, k, SUBLANES, lanes), dtype),
+                   pltpu.SemaphoreType.DMA((depth,))]
+                + law),
+        ),
+        out_shape=[jax.ShapeDtypeStruct((t, SUBLANES, lanes), dtype),
+                   jax.ShapeDtypeStruct(state.shape, dtype)],
+        # operands count the scalar-prefetch table: the state is the fourth
+        input_output_aliases={3: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=CLASS_VMEM_LIMIT),
+        interpret=interpret,
+        name="pallas_sdca_classes",
+    )(idxs, X_folded, _fold_vec(w.astype(dtype), d8, lanes),
+      state.astype(dtype))
+    return unfold_vec(dw, d_orig), state
 
 
 @functools.partial(
@@ -1218,73 +1408,15 @@ def pallas_sdca_round_classes(
     idxs: jax.Array,         # (K, H) int32: every class takes these rows
     lam: float,
     n: int,
-    mode: str = "plus",
-    sigma: float = 1.0,
-    interpret: bool = False,
-    loss: str = "hinge",
-    smoothing: float = 1.0,
-    depth: int = 0,
+    **kw,
 ):
-    """One SDCA round of T one-vs-rest models over the K shards' sampled
-    rows.  Returns (dw (T, d), the shards' updates summed shard 0 first;
-    alpha_inner (T, K, n_shard), locally advanced: callers apply the outer
-    scaling law), as :func:`pallas_sdca_round` does at T = 1.  ``depth``:
-    the row ring's (0 = auto, :func:`class_ring_depth`), as there."""
-    t, d_orig = w.shape
-    X_folded = X if X.ndim == 4 else _fold(X, SUBLANES)
-    k, n_shard, _, lanes = X_folded.shape
-    d8 = fold_lanes(d_orig, lanes)
-    X_folded = lane_aligned(X_folded, interpret)
-    lanes = X_folded.shape[-1]
-    h = idxs.shape[1]
-    dtype = X.dtype
-    check_dtype(dtype)
-    depth = depth or class_ring_depth(
-        k, n_shard, SUBLANES * d8, t, jnp.dtype(dtype).itemsize, h) or 2
-    sig_eff, qii_factor = mode_factors(mode, sigma)
-    rows = class_rows(t)
-    n_blocks = -(-n_shard // LANES)
-    n_pad = n_blocks * LANES
-
-    def blocked(v):
-        v = jnp.pad(v.astype(dtype), [(0, 0)] * (v.ndim - 1)
-                    + [(0, n_pad - n_shard)])
-        return v.reshape(*v.shape[:-1], n_blocks, LANES)
-
-    state = jnp.concatenate(
-        [jnp.transpose(blocked(alpha), (1, 2, 0, 3)),
-         blocked(sq_norms)[:, :, None], blocked(classes)[:, :, None],
-         jnp.zeros((k, n_blocks, rows - t - 2, LANES), dtype)], axis=2)
-
-    kernel = functools.partial(
-        _kernel_classes, k=k, t=t, h=h, depth=depth,
-        lam_n=float(lam * n), coef_div=float(coef_divisor(mode, lam * n)),
-        sig_eff=float(sig_eff), qii_factor=float(qii_factor),
-        frozen=(mode == "frozen"), loss=losses.validate(loss, smoothing),
-        smoothing=float(smoothing))
-    whole = pl.BlockSpec((t, SUBLANES, lanes), lambda i_, idxs_: (0, 0, 0))
-    any_ = pl.BlockSpec(memory_space=pl.ANY)
-    dw, state = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[any_, whole, any_],
-            out_specs=[whole, any_],
-            scratch_shapes=(
-                [pltpu.VMEM((t, SUBLANES, lanes), dtype)] * k
-                + [pltpu.VMEM((n_blocks, rows, LANES), dtype)] * k
-                + [pltpu.VMEM((depth, k, SUBLANES, lanes), dtype),
-                   pltpu.SemaphoreType.DMA((depth,))]),
-        ),
-        out_shape=[jax.ShapeDtypeStruct((t, SUBLANES, lanes), dtype),
-                   jax.ShapeDtypeStruct(state.shape, dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=CLASS_VMEM_LIMIT),
-        interpret=interpret,
-        name="pallas_sdca_classes",
-    )(idxs, X_folded, _fold_vec(w.astype(dtype), d8, lanes), state)
-    alpha_inner = jnp.transpose(state[:, :, :t], (2, 0, 1, 3)).reshape(
-        t, k, n_pad)[:, :, :n_shard]
-    return unfold_vec(dw, d_orig), alpha_inner
+    """:func:`pallas_sdca_round_classes_tiles` for a caller that holds alpha
+    as (T, K, n_shard): the state packed, one round, alpha taken back out.
+    Returns (dw (T, d); alpha_inner (T, K, n_shard), locally advanced:
+    callers apply the outer scaling law), as :func:`pallas_sdca_round` does
+    at T = 1.  The training loop does not call this: it packs once a chunk
+    of rounds and carries the tiles."""
+    dw, state = pallas_sdca_round_classes_tiles(
+        w, class_state_pack(alpha.astype(X.dtype), sq_norms, classes), X,
+        idxs, lam, n, **kw)
+    return dw, class_state_alpha(state, *alpha.shape[::2])

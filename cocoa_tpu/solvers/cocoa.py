@@ -83,20 +83,9 @@ def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
             hot_cols=shards.get("hot_cols"), hot_panel=shards.get("X_hot"),
             **common,
         )
-    from cocoa_tpu.ops.pallas_sdca import (pallas_sdca_round,
-                                           pallas_sdca_round_classes)
+    from cocoa_tpu.ops.pallas_sdca import pallas_sdca_round
 
-    if "classes" in shards:
-        # one-vs-rest: w (T, d), alpha (T, K, n_shard); every class takes
-        # the round's one table of sampled rows, and its labels are derived
-        # from the class ids in the step.  dw arrives (T, d), summed over
-        # the shards: as the one element of a leading axis it takes the
-        # callers' shard sum
-        dw, alpha_inner = pallas_sdca_round_classes(
-            w, alpha, shards.get("X_folded", shards["X"]),
-            shards["classes"], shards["sq_norms"], idxs_kh, params.lam,
-            params.n, **common)
-        return dw[None], alpha_inner
+    # (a one-vs-rest job's round is :func:`_class_round`'s)
     # margins are computed in-kernel against the VMEM-resident w (round 4;
     # the sampled row is DMA'd for the axpy anyway — precomputing X·w read
     # ALL of X per round, ~10x the rows the round touches at
@@ -299,7 +288,14 @@ class SolverPath:
     shard's round, ``ids_per_segment`` the [w | dw] rows VMEM holds at a
     time, (2 T_pad 4) B each; ``lane_fill`` T / T_pad; ``slots_walked``
     in the rectangle's 8-slot groups).  The rule is the layout's: dense
-    rows take the sublanes, padded-CSR rows the lanes.
+    rows take the sublanes, padded-CSR rows the lanes.  ``class_state``
+    (the dense class kernel; None anywhere else): the form alpha has
+    between the rounds of a chunk — ``tiles``: the kernel's own state
+    tiles (K, n_blocks, R, 128), packed from the loop's alpha (T, K,
+    n_shard) once a chunk and taken back out for the eval behind it, a
+    round handing the kernel the bytes it wrote the round before
+    (:func:`_class_round`; ``fori`` and the lanes path carry alpha as the
+    loop's state holds it).
     ``row_align`` (None where there is no fold cache: ``fori`` and every
     sparse path): how the fold cache comes to be the row-major rows of
     whole lane tiles those kernels read — ``stored``: it is kept so
@@ -339,6 +335,7 @@ class SolverPath:
     slots_walked: Optional[float] = None
     slot_walk: Optional[str] = None
     class_axis: Optional[str] = None
+    class_state: Optional[str] = None
     class_tiles: Optional[int] = None
     label_slots: Optional[int] = None
     ids_per_segment: Optional[int] = None
@@ -430,7 +427,9 @@ class SolverPath:
                       + (f", the class axis on the {self.class_axis}"
                          + (f" ({self.class_tiles} tile(s) of 1,024, "
                             f"{self.label_slots} label id(s) a row)"
-                            if self.class_axis == "lanes" else "")))
+                            if self.class_axis == "lanes" else "")
+                         + (f", alpha kept as {self.class_state} across a "
+                            f"chunk's rounds" if self.class_state else "")))
         if self.pass_slot_share < 1.0:
             solve += (f", all-rows passes touch {self.pass_slot_share:.3f} "
                       f"of the padded slots")
@@ -734,6 +733,7 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             placement.update(
                 classes=classes,
                 class_axis="lanes" if sparse else "sublanes",
+                class_state="tiles" if pallas and not sparse else None,
                 lane_fill=(classes / class_pad(classes) if sparse else
                            classes / class_rows(classes) if pallas
                            else None))
@@ -857,7 +857,11 @@ def _sdca_round_parts(
     (ops/local_sdca.local_sdca_block) with that block size (a round of more
     than one block takes the two-phase software-pipelined scan, see
     local_sdca_block_batched).  Returns
-    (per_shard, per_round_batched | None, apply_fn).
+    (per_shard, per_round_batched | None, apply_fn, carry_form | None).
+    ``carry_form(shards)`` (None but on a one-vs-rest job): None where the
+    carry ``per_round_batched`` advances is alpha as the loop's state holds
+    it, else the pair ``(pack, unpack)`` between that alpha and the carry,
+    which a chunk applies once around its scan (:func:`_class_round`).
 
     ``classes`` = T > 1 (a one-vs-rest job, one chip): the state is w
     (T, d), alpha (T, K, n_shard) and ``per_round_batched`` is always
@@ -869,10 +873,10 @@ def _sdca_round_parts(
         one = _sdca_round_parts(
             params, k, mode, scaling, sigma, math=math,
             pallas_interpret=pallas_interpret, pallas_state=pallas_state)
-        return (one[0], _class_round(params, mode, scaling, sigma, classes,
-                                     one[0], pallas, pallas_interpret,
-                                     hbm_plan),
-                one[2])
+        per_round, carry_form = _class_round(
+            params, mode, scaling, sigma, classes, one[0], pallas,
+            pallas_interpret, hbm_plan)
+        return one[0], per_round, one[2], carry_form
     if math not in ("exact", "fast"):
         raise ValueError(f"math must be 'exact' or 'fast', got {math!r}")
     if block and pallas:
@@ -900,7 +904,7 @@ def _sdca_round_parts(
             # CoCoA.scala:101 / MinibatchCD.scala:127-128
             return dw, alpha_k + scaling * da
 
-        return per_shard, None, apply_fn
+        return per_shard, None, apply_fn, None
 
     from cocoa_tpu.ops.local_sdca import (
         local_sdca_block, local_sdca_block_batched, local_sdca_fast,
@@ -971,18 +975,31 @@ def _sdca_round_parts(
             da, dw = block_round(w, alpha, idxs_kh, shards)
             return dw.sum(axis=0), alpha + scaling * da
 
-    return per_shard, per_round_batched, apply_fn
+    return per_shard, per_round_batched, apply_fn, None
 
 
 def _class_round(params: Params, mode: str, scaling: float, sigma: float,
                  classes: int, per_shard, pallas: bool, interpret: bool,
                  lanes_plan=None):
-    """``per_round_batched(w (T, d), alpha (T, K, n_shard), idxs (K, H),
-    shards) -> (dw (T, d), alpha')`` of a one-vs-rest round
-    (:func:`_sdca_round_parts`).  On padded-CSR rows the class axis rides
-    the lanes — w (d, R, 128), alpha (K, n_shard, R, 128),
-    ops/pallas_sparse_lanes.py: its HBM-state chain on ``lanes_plan``, or
-    the same round in plain XLA — and the round names its own scopes."""
+    """``(per_round_batched, carry_form)`` of a one-vs-rest round
+    (:func:`_sdca_round_parts`): ``per_round_batched(w, carry, idxs (K, H),
+    shards) -> (dw, carry')``, the scaling law applied.  What the carry is
+    follows the layout's rule (``SolverPath.class_axis``), read off the
+    arrays:
+
+    - padded-CSR rows: the class axis rides the lanes — w (d, R, 128), the
+      carry alpha (K, n_shard, R, 128) as the loop's state holds it,
+      ops/pallas_sparse_lanes.py: its HBM-state chain on ``lanes_plan``, or
+      the same round in plain XLA — and the round names its own scopes;
+    - dense rows on the class kernel (``SolverPath.class_state``
+      ``tiles``): w (T, d), the carry the kernel's own state tiles (K,
+      n_blocks, R, 128) (ops/pallas_sdca.class_state_pack): the round is
+      one call that takes the tiles and gives them back, the law applied
+      to them in its epilogue, and ``carry_form`` hands the chunk the
+      pack and its inverse, so that alpha (T, K, n_shard) is relaid once a
+      chunk of rounds and not once a round;
+    - dense rows on ``fori``: the carry alpha (T, K, n_shard), the T = 1
+      ``per_shard`` under a vmap over classes and shards."""
     from cocoa_tpu.data.sharding import class_labels
 
     def per_round_lanes(w, alpha, idxs_kh, shards):
@@ -1000,11 +1017,20 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
                                                  **common)
 
     @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
+    def per_round_tiles(w, state, idxs_kh, shards):
+        from cocoa_tpu.ops.pallas_sdca import pallas_sdca_round_classes_tiles
+
+        # every class takes the round's one table of sampled rows, and its
+        # labels are derived from the class ids in the step; dw arrives
+        # (T, d), summed over the shards
+        return pallas_sdca_round_classes_tiles(
+            w, state, shards.get("X_folded", shards["X"]), idxs_kh,
+            params.lam, params.n, mode=mode, sigma=sigma,
+            interpret=interpret, loss=params.loss,
+            smoothing=params.smoothing, scaling=scaling)
+
+    @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
     def per_round_classes(w, alpha, idxs_kh, shards):
-        if pallas:
-            dw, a_inner = _pallas_batched(w, alpha, idxs_kh, shards, params,
-                                          mode, sigma, interpret)
-            return dw.sum(axis=0), alpha + scaling * (a_inner - alpha)
         binary = {f: v for f, v in shards.items() if f != "classes"}
 
         def one_class(w_t, alpha_t, t):
@@ -1015,18 +1041,33 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
 
         return jax.vmap(one_class)(w, alpha, jnp.arange(classes))
 
-    def per_round(w, alpha, idxs_kh, shards):
-        # the layout's rule (SolverPath.class_axis), read off the arrays
+    def per_round(w, carry, idxs_kh, shards):
         return (per_round_lanes if "sp_indices" in shards
-                else per_round_classes)(w, alpha, idxs_kh, shards)
+                else per_round_tiles if pallas
+                else per_round_classes)(w, carry, idxs_kh, shards)
 
-    return per_round
+    def carry_form(shards):
+        if not pallas or "sp_indices" in shards:
+            return None
+        from cocoa_tpu.ops.pallas_sdca import (class_state_alpha,
+                                               class_state_pack)
+
+        # the pack and its inverse are the solve's: they wear its scope
+        solve = jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
+        n_shard = shards["sq_norms"].shape[-1]
+        return (solve(lambda alpha: class_state_pack(
+                    alpha, shards["sq_norms"], shards["classes"])),
+                solve(lambda state: class_state_alpha(state, classes,
+                                                      n_shard)))
+
+    return per_round, carry_form
 
 
 def make_round_step(mesh, params: Params, k: int, alg, **parts_kw):
     """Build the jitted (w, alpha, idxs, shard_arrays) -> (w', alpha') step.
     ``alg`` = (mode, scaling, sigma), see :func:`_alg_config`."""
-    per_shard, _, apply_fn = _sdca_round_parts(params, k, *alg, **parts_kw)
+    per_shard, _, apply_fn, _ = _sdca_round_parts(params, k, *alg,
+                                                  **parts_kw)
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def round_step(w, alpha, idxs, shard_arrays):
@@ -1061,7 +1102,7 @@ def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
     draws stay on device; see base.IndexSampler)."""
     from cocoa_tpu.parallel.fanout import chunk_fanout
 
-    per_shard, per_round_batched, apply_fn = _sdca_round_parts(
+    per_shard, per_round_batched, apply_fn, carry_form = _sdca_round_parts(
         params, k, *alg, **parts_kw
     )
 
@@ -1073,14 +1114,20 @@ def _make_chunk_kernel(mesh, params: Params, k: int, alg, sampler=None,
         from cocoa_tpu.ops.pallas_sdca import with_aligned_rows
 
         shard_arrays = with_aligned_rows(shard_arrays, mesh)
-        return chunk_fanout(
-            mesh, per_shard, apply_fn, w, alpha, idxs_ckh, shard_arrays,
-            per_round_batched=per_round_batched,
+        # likewise the class kernel's state: alpha packed into its tiles
+        # once a chunk, the scan's carry the tiles, alpha taken back out
+        # for the eval behind the chunk (:func:`_class_round`); on every
+        # other path the carry is alpha as it is
+        form = carry_form(shard_arrays) if carry_form else None
+        w, carry = chunk_fanout(
+            mesh, per_shard, apply_fn, w, form[0](alpha) if form else alpha,
+            idxs_ckh, shard_arrays, per_round_batched=per_round_batched,
             # pallas_call's internal slices confuse shard_map's VMA type
             # checker; the manual pcast/psum handling makes it safe to skip
             check_vma=not (parts_kw.get("pallas", False)
                            or parts_kw.get("block_chain", "xla") != "xla"),
         )
+        return w, form[1](carry) if form else carry
 
     return chunk_kernel
 

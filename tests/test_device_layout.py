@@ -304,6 +304,82 @@ def test_ten_class_job_reads_one_copy_of_the_rows(monkeypatch, one_chip,
     assert not re.findall(rf"f32\[(?:{t},)?1,{d}\]", hlo)
 
 
+def _computations(hlo: str) -> dict:
+    """The optimised HLO's computations, name -> instruction lines."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line.strip())
+    return comps
+
+
+def _called_from(comps: dict, root: str) -> set:
+    """``root`` and every computation it reaches (fusions, nested loops)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += [c for c in re.findall(
+                r"(?:calls|to_apply|body|condition)=(%[\w.\-]+)", line)
+                if c in comps]
+    return seen
+
+
+def test_class_job_relays_its_state_once_a_chunk(monkeypatch, one_chip):
+    """The class kernel's state stays in tile form across a chunk's rounds
+    (PR 51).  In the program's own ``run`` compiled for the chip, the
+    ``pallas_sdca_classes`` call sits in the body of the INNER ``while`` —
+    the scan over a chunk's rounds, itself in the body of the device
+    loop's — and nothing that inner loop reaches transposes, copies,
+    concatenates, pads or slices an array of the tiles' (K, n_blocks, R,
+    128) or of alpha's (T, K, n_shard) kind: the round's call takes the
+    loop's carry as it is.  The pack and its inverse are in the device
+    loop's body, beside the eval, under the solve's scope.  (On the tree
+    before PR 51 the inner loop held three pads, two copies and a slice
+    of these shapes, every round.)"""
+    import jax
+
+    d, t = 784, 10
+    with jax.enable_x64(False):
+        run, args, path = _capture_run(monkeypatch, d, "off", classes=t)
+        assert (path.class_axis, path.class_state) == ("sublanes", "tiles")
+        compiled = run.lower(*_on_chip(args, one_chip)).compile()
+    comps = _computations(compiled.as_text())
+    (inner,) = [name for name, lines in comps.items() if any(
+        "custom-call(" in line and "pallas_sdca_classes" in line
+        for line in lines)]
+    loops = [name for name, lines in comps.items() if any(
+        " while(" in line and f"body={inner}," in line for line in lines)]
+    assert len(loops) == 1, (inner, loops)
+    (outer,) = loops
+    assert any(" while(" in line and f"body={outer}," in line
+               for lines in comps.values() for line in lines), outer
+    n_blocks, rows = N_SHARD // 128, 16
+    kinds = "|".join([f"{K},{n_blocks},(?:{rows}|{t}|1),128",   # the tiles
+                      f"{t},{K},(?:{N_SHARD}|{n_blocks},128)"])  # alpha
+    relays = re.compile(
+        rf"= f32\[(?:{kinds})\]\S* "
+        r"(transpose|copy|concatenate|pad|slice)\(")
+    in_rounds = [line[:140] for name in _called_from(comps, inner)
+                 for line in comps[name] if relays.search(line)]
+    assert in_rounds == [], in_rounds
+    once_a_chunk = [m.group(1) for line in comps[outer]
+                    + [ln for name in _called_from(comps, outer)
+                       - _called_from(comps, inner) for ln in comps[name]]
+                    for m in [relays.search(line)]
+                    if m and "cocoa_local_solve" in line]
+    assert "pad" in once_a_chunk and "slice" in once_a_chunk, once_a_chunk
+
+
 def test_class_kernel_compiles_at_the_cells_size(one_chip):
     """The kernel alone at the benchmark's shapes (8 x 126,576 x 784, H =
     12,656, T = 10: 62 MB of VMEM under the limit it asks for), and not at

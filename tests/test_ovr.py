@@ -349,3 +349,214 @@ def test_the_cli_trains_a_multiclass_file_as_one(multiclass_file, capsys):
     assert "4 class models one-vs-rest" in out
     assert "per-class gaps:" in out and "(4 of 4 at target)" in out
     assert "Duality gap by class:" in out
+
+
+# --- the class kernel's state stays in tile form across a chunk (PR 51) -----
+
+def _digest(*arrays):
+    import hashlib
+
+    return hashlib.sha256(
+        b"".join(np.asarray(a).tobytes() for a in arrays)).hexdigest()
+
+
+@pytest.mark.parametrize("n_shard", [200, 33 * 128 + 5],
+                         ids=["two_blocks", "thirty_four_blocks"])
+@pytest.mark.parametrize("t", [1, 3, 10, 14])
+def test_pack_then_alpha_is_alpha_to_the_bit(t, n_shard):
+    """``class_state_alpha`` undoes ``class_state_pack`` where n_shard is no
+    multiple of 128, at one and at two 8-row groups of ``class_rows``; the
+    norms and the class ids sit on tile rows T and T + 1, zeros past them
+    and past n_shard."""
+    import jax.numpy as jnp
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    rng = np.random.default_rng(t)
+    k = 2
+    alpha = rng.random((t, k, n_shard)).astype(np.float32)
+    sq = rng.random((k, n_shard)).astype(np.float32)
+    cls = rng.integers(0, t, (k, n_shard)).astype(np.int32)
+    state = pallas_sdca.class_state_pack(jnp.asarray(alpha), jnp.asarray(sq),
+                                         jnp.asarray(cls))
+    rows, n_blocks = pallas_sdca.class_rows(t), -(-n_shard // 128)
+    assert rows == (8 if t <= 6 else 16)
+    assert state.shape == (k, n_blocks, rows, 128)
+    back = pallas_sdca.class_state_alpha(state, t, n_shard)
+    assert _digest(back) == _digest(alpha)
+    flat = np.asarray(state).transpose(2, 0, 1, 3).reshape(rows, k, -1)
+    np.testing.assert_array_equal(flat[t, :, :n_shard], sq)
+    np.testing.assert_array_equal(flat[t + 1, :, :n_shard], cls)
+    assert not flat[t + 2:].any() and not flat[:, :, n_shard:].any()
+
+
+def _per_round_pack(params, mode, scaling, sigma, classes, per_shard, pallas,
+                    interpret, lanes_plan=None):
+    """The class round as it was before the loop carried the tiles, kept
+    here as the reference: the state tile built from (T, K, n_shard) EVERY
+    round, taken apart again every round, the scaling law on the
+    (T, K, n_shard) form.  Shaped as ``solvers/cocoa._class_round``."""
+    import jax.numpy as jnp
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    def per_round(w, alpha, idxs_kh, shards):
+        t, k, n_shard = alpha.shape
+        dtype = alpha.dtype
+        rows = pallas_sdca.class_rows(t)
+        n_blocks = -(-n_shard // 128)
+        n_pad = n_blocks * 128
+
+        def blocked(v):
+            v = jnp.pad(v.astype(dtype), [(0, 0)] * (v.ndim - 1)
+                        + [(0, n_pad - n_shard)])
+            return v.reshape(*v.shape[:-1], n_blocks, 128)
+
+        state = jnp.concatenate(
+            [jnp.transpose(blocked(alpha), (1, 2, 0, 3)),
+             blocked(shards["sq_norms"])[:, :, None],
+             blocked(shards["classes"])[:, :, None],
+             jnp.zeros((k, n_blocks, rows - t - 2, 128), dtype)], axis=2)
+        dw, state = pallas_sdca.pallas_sdca_round_classes_tiles(
+            w, state, shards.get("X_folded", shards["X"]), idxs_kh,
+            params.lam, params.n, mode=mode, sigma=sigma,
+            interpret=interpret, loss=params.loss,
+            smoothing=params.smoothing)
+        a_inner = jnp.transpose(state[:, :, :t], (2, 0, 1, 3)).reshape(
+            t, k, n_pad)[:, :, :n_shard]
+        return dw, alpha + scaling * (a_inner - alpha)
+
+    return per_round, None
+
+
+def _class_job(ds, *, plus, rounds=12, pallas=True, **kw):
+    """A 12-round one-vs-rest job, an eval every 4 rounds.  beta = 1 at
+    K = 2: CoCoA's averaging scales by 1/2, CoCoA+ by gamma = 1, so every
+    product of the scaling law is exact and a multiply-add the CPU backend
+    contracts rounds as the two operations do."""
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.solvers import base, cocoa, run_cocoa
+
+    base._DEVICE_RUNS.clear()
+    cocoa._CHUNK_STEPS.clear()
+    return run_cocoa(
+        ds, Params(n=ds.n, num_rounds=rounds, local_iters=H, lam=LAM,
+                   beta=1.0),
+        DebugParams(debug_iter=4, seed=0), plus=plus, quiet=True,
+        math="fast", rng="permuted", accel="off", pallas=pallas,
+        **{"device_loop": True, **kw})
+
+
+@pytest.mark.parametrize("device_loop", [True, False],
+                         ids=["device_loop", "host_stepped"])
+@pytest.mark.parametrize("plus", [True, False],
+                         ids=["cocoa_plus_gamma_1", "cocoa_averaging_half"])
+def test_tiles_across_a_chunk_are_the_per_round_pack_to_the_bit(
+        monkeypatch, plus, device_loop):
+    from cocoa_tpu.solvers import cocoa
+
+    t = 3
+    x, cls = rows_and_classes(t, seed=7)
+    ds = dataset(x, against_rest(cls, 0), cls, t)
+    w, alpha, traj = _class_job(ds, plus=plus, device_loop=device_loop)
+    assert traj.meta["solver_path"]["class_state"] == "tiles"
+    assert [r.round for r in traj.records] == [4, 8, 12]
+    monkeypatch.setattr(cocoa, "_class_round", _per_round_pack)
+    w0, alpha0, traj0 = _class_job(ds, plus=plus, device_loop=device_loop)
+    assert float(np.abs(np.asarray(alpha0)).max()) > 0
+    assert _digest(w, alpha) == _digest(w0, alpha0)
+    assert [(r.gap, r.class_gaps) for r in traj.records] == \
+        [(r.gap, r.class_gaps) for r in traj0.records]
+
+
+@pytest.mark.parametrize("pallas", [True, False], ids=["tiles", "fori"])
+def test_a_resume_at_an_eval_boundary_is_the_uninterrupted_job(pallas):
+    """The loop's state between chunks is (w, alpha (T, K, n_shard)) on
+    every path: what a caller takes at round 8 and hands back in continues
+    to round 12 as the job that never stopped."""
+    t = 3
+    x, cls = rows_and_classes(t, seed=7)
+    ds = dataset(x, against_rest(cls, 0), cls, t)
+    w, alpha, traj = _class_job(ds, plus=True, pallas=pallas)
+    w8, alpha8, _ = _class_job(ds, plus=True, pallas=pallas, rounds=8)
+    assert w8.shape == (t, D) and alpha8.shape == (t, K, N_SHARD)
+    w12, alpha12, resumed = _class_job(
+        ds, plus=True, pallas=pallas, w_init=w8, alpha_init=alpha8,
+        start_round=9)
+    assert [r.round for r in resumed.records] == [12]
+    assert _digest(w12, alpha12) == _digest(w, alpha)
+    assert resumed.records[-1].class_gaps == traj.records[-1].class_gaps
+
+
+def test_the_fori_class_path_carries_alpha_as_it_is(monkeypatch):
+    """Off the class kernel nothing of the tile form runs: the resolver
+    says so, the round hands the chunk no pack, and a chunk's program
+    holds no array of the tiles' shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import Params
+    from cocoa_tpu.ops import pallas_sdca
+    from cocoa_tpu.solvers import cocoa
+
+    t = 3
+    x, cls = rows_and_classes(t)
+    ds = dataset(x, against_rest(cls, 0), cls, t)
+    _, _, traj = _class_job(ds, plus=True, pallas=False, rounds=4)
+    assert (traj.meta["solver_path"]["kernel"],
+            traj.meta["solver_path"]["class_state"]) == ("fori", None)
+    params = Params(n=ds.n, num_rounds=4, local_iters=H, lam=LAM)
+    shards = ds.shard_arrays()
+    for pallas, packs in ((False, False), (True, True)):
+        *_, carry_form = cocoa._sdca_round_parts(
+            params, K, "plus", 1.0, float(K), math="fast", pallas=pallas,
+            pallas_interpret=True, classes=t)
+        assert (carry_form(shards) is not None) == packs
+    monkeypatch.setattr(pallas_sdca, "class_state_pack", None)  # not called
+    kernel = cocoa._make_chunk_kernel(None, params, K, ("plus", 1.0, float(K)),
+                                      math="fast", classes=t)
+    idxs = jnp.zeros((2, K, H), jnp.int32)
+    text = str(jax.make_jaxpr(kernel)(
+        jnp.zeros((t, D), jnp.float32), jnp.zeros((t, K, N_SHARD),
+                                                  jnp.float32), idxs, shards))
+    assert f"[{K},1,{pallas_sdca.class_rows(t)},128]" not in text
+
+
+@pytest.mark.parametrize("scaling", [1.0, 0.5])
+@pytest.mark.parametrize("n_shard", [200, 34 * 128 - 5, 70 * 128],
+                         ids=["2_blocks", "34_blocks", "70_blocks"])
+def test_the_law_in_the_epilogue_is_the_law_on_alpha(n_shard, scaling):
+    """One round of the tiles kernel with the scaling law in its epilogue
+    (old tiles back from HBM ``LAW_BLOCKS`` blocks at a time: one part
+    chunk, one whole and a tail, two whole and a tail) against the round
+    without it and ``alpha + scaling (inner - alpha)`` outside; the norms
+    and the class ids pass through."""
+    import jax.numpy as jnp
+
+    from cocoa_tpu.ops import pallas_sdca
+
+    t, k, h = 3, 2, 8
+    rng = np.random.default_rng(n_shard)
+    x = rng.normal(size=(k, n_shard, D)).astype(np.float32)
+    x /= np.linalg.norm(x, axis=-1, keepdims=True)
+    alpha = jnp.asarray(rng.integers(0, 5, (t, k, n_shard)) / 4, jnp.float32)
+    sq = jnp.asarray((x * x).sum(-1))
+    cls = jnp.asarray(rng.integers(0, t, (k, n_shard)), jnp.int32)
+    idxs = jnp.asarray(np.stack([rng.permutation(n_shard)[:h]
+                                 for _ in range(k)]), jnp.int32)
+    w = jnp.asarray(rng.normal(size=(t, D)) / 4, jnp.float32)
+    state = pallas_sdca.class_state_pack(alpha, sq, cls)
+    kw = dict(mode="plus", sigma=float(k), interpret=True)
+    dw0, inner = pallas_sdca.pallas_sdca_round_classes_tiles(
+        w, state, jnp.asarray(x), idxs, LAM, k * n_shard, **kw)
+    dw, scaled = pallas_sdca.pallas_sdca_round_classes_tiles(
+        w, state, jnp.asarray(x), idxs, LAM, k * n_shard, scaling=scaling,
+        **kw)
+    a_inner = pallas_sdca.class_state_alpha(inner, t, n_shard)
+    assert float(jnp.abs(a_inner - alpha).max()) > 0
+    want = alpha + scaling * (a_inner - alpha)
+    assert _digest(dw) == _digest(dw0)
+    assert _digest(pallas_sdca.class_state_alpha(scaled, t, n_shard)) == \
+        _digest(want)
+    np.testing.assert_array_equal(np.asarray(scaled)[:, :, t:],
+                                  np.asarray(state)[:, :, t:])
