@@ -1132,11 +1132,13 @@ def main(argv=None) -> int:
             why = ("--classes trains on one chip (--mesh=1): the class "
                    "axis is not carried across a mesh")
         elif extras["hotCols"] is not None or ed_spec != "false":
-            why = ("--classes trains on dense rows (a class a sublane) or "
-                   "on a padded-CSR rectangle (--layout=sparse: the classes "
-                   "on the lanes, label sets too); the hot-column panel "
-                   "and the dense eval twin carry no class axis: drop "
-                   "--hotCols / --evalDense")
+            why = ("--classes trains on dense rows (a class a sublane; the "
+                   "classes on the lanes, a block of rows a step, where "
+                   "the T models outgrow the sublanes) or on a padded-CSR "
+                   "rectangle (--layout=sparse: the classes on the lanes, "
+                   "label sets too); the hot-column panel and the dense "
+                   "eval twin carry no class axis: drop --hotCols / "
+                   "--evalDense")
         elif extras["ingestCache"] or (extras["ingest"] or "auto") \
                 not in ("auto", "whole"):
             why = ("--classes reads the file whole: --ingest=stream and "
@@ -1491,10 +1493,12 @@ def main(argv=None) -> int:
                 return 2
             n = data.n
             if data.num_classes > 1:
-                # what runs: dense rows (one class id a row, a class a
-                # sublane of the dense kernel) and a padded-CSR rectangle
-                # (label sets too, the classes on the lanes of the
-                # HBM-state chain).  What does not, yet: a mesh, --accel,
+                # what runs: dense rows (one class id a row: a class a
+                # sublane of the dense kernel, or the classes on the lanes
+                # of the block solve where T models outgrow its state
+                # tiles) and a padded-CSR rectangle (label sets too, the
+                # classes on the lanes of the HBM-state chain).  What does
+                # not, yet: a mesh, --accel,
                 # checkpoints (refused above and in run_cocoa), rows kept
                 # as a stream, and T x d past one chip's HBM
                 sets = data.classes.ndim == 2
@@ -1532,8 +1536,10 @@ def main(argv=None) -> int:
                           + (f"; label sets, up to {data.classes.shape[1]} "
                              f"a row" if sets else "")
                           + f"), trained one-vs-rest over the one copy of "
-                          f"the rows, the class axis on the "
-                          f"{'sublanes' if lays == 'dense' else 'lanes'}")
+                          f"the rows"
+                          + (" (the local solver's line says where the "
+                             "class axis rides)" if lays == "dense" else
+                             ", the class axis on the lanes"))
 
             # --hotCols=auto|off|<n>: the hot/cold column split (sparse
             # layout only, data/hybrid.py).  Resolved HERE — against the

@@ -100,8 +100,10 @@ def eval_metrics(
     eval bit-identical to the solo certificate (tests/test_fleet.py).
 
     ``classes`` = T: a one-vs-rest job whose class axis rides the lanes
-    (w (d, R, 128): sparse rows, ops/pallas_sparse_lanes.py) says how many
-    of its T_pad lanes are models; a dense one's w (T, d) says so itself.
+    (w (d, R, 128): sparse rows, ops/pallas_sparse_lanes.py, and dense rows
+    whose T models outgrew the sublane kernel, ops/block_lanes.py) says how
+    many of its T_pad lanes are models; a dense one's w (T, d) says so
+    itself.
     """
     if w.ndim == 3:
         return _eval_metrics_lanes(
@@ -206,34 +208,47 @@ def _eval_metrics_classes(w, alpha, shard_arrays, lam, n, mesh,
 def _eval_metrics_lanes(w, alpha, shard_arrays, lam, n, mesh,
                         test_shard_arrays, test_n, loss, smoothing, classes):
     """:func:`_eval_metrics_classes` with the class axis on the lanes: w
-    (d, R, 128), alpha (K, n_shard, R, 128) over padded-CSR rows that carry
-    label sets, ``classes`` = T of the R·128 lanes models.  One blocked
-    pass over the rows, a shard after another (ops/rows.class_loss_sums:
-    a W row a nonzero, T margins a row), gives all T primal / dual / gap
-    values; the same vector comes back, ``[primal, gap, test_error, gap_0
-    .. gap_{T-1}]`` on the worst class.  The test error of a multi-label
-    set is label-wise: the share of (row, class) pairs with the wrong
-    sign."""
-    if (mesh is not None or "sp_indices" not in shard_arrays
-            or "sp_row_ptr" in shard_arrays or classes < 1):
+    (d, R, 128), alpha (K, n_shard, R, 128), ``classes`` = T of the R·128
+    lanes models, over padded-CSR rows that carry label sets or over dense
+    rows that carry one class id each.  One blocked pass over the rows, a
+    shard after another, gives all T primal / dual / gap values — on
+    sparse rows ops/rows.class_loss_sums (a W row a nonzero, T margins a
+    row), on dense rows ops/rows.dense_class_loss_sums (a block's margins
+    one product at ``highest`` precision; a block is a whole shard until
+    its temporaries would pass ops/rows.DENSE_CLASS_BLOCK_BYTES) — and the
+    same vector comes back, ``[primal, gap, test_error, gap_0 ..
+    gap_{T-1}]`` on the worst class.  The test error of a multi-label set
+    is label-wise, the share of (row, class) pairs with the wrong sign; of
+    a dense multi-class set it is :func:`_eval_metrics_classes`'s, the
+    share of rows whose largest margin is not their own class's."""
+    dense = "X" in shard_arrays
+    if (mesh is not None or classes < 1 or "sp_row_ptr" in shard_arrays
+            or not dense and "sp_indices" not in shard_arrays):
         raise ValueError("the class axis on the lanes is evaluated on "
-                         "padded-CSR rows on one chip, T stated "
-                         "(docs/DESIGN.md, one-vs-rest)")
+                         "dense rows or padded-CSR rows on one chip, T "
+                         "stated (docs/DESIGN.md, one-vs-rest)")
     from cocoa_tpu.data.sharding import class_vector
-    from cocoa_tpu.ops.rows import class_loss_sums
+    from cocoa_tpu.ops.rows import class_loss_sums, dense_class_loss_sums
 
     def shard_sums(arrays, alpha):
-        return class_vector(class_loss_sums(w, alpha, arrays, classes, loss,
+        """((2+, T) per-class sums, the wrong answers' count)."""
+        if dense:
+            sums, wrong = dense_class_loss_sums(w, alpha, arrays, classes,
+                                                loss, smoothing)
+            return class_vector(sums, classes), wrong
+        sums = class_vector(class_loss_sums(w, alpha, arrays, classes, loss,
                                             smoothing), classes)   # (3, T)
+        return sums, sums[2].sum()
 
-    sums = shard_sums(shard_arrays, alpha)
+    sums, _ = shard_sums(shard_arrays, alpha)
     w_norm_sq = class_vector(jnp.sum(w * w, axis=0), classes)
     primal = sums[0] / n + 0.5 * lam * w_norm_sq
     gaps = primal - (sums[1] / n - 0.5 * lam * w_norm_sq)
     worst = jnp.argmax(gaps)
     if test_shard_arrays is not None:
-        test_err = (shard_sums(test_shard_arrays, None)[2].sum()
-                    / (test_n * classes))
+        # (answers: a (row, class) pair on sparse rows, a row on dense)
+        test_err = (shard_sums(test_shard_arrays, None)[1]
+                    / (test_n * (1 if dense else classes)))
     else:
         test_err = jnp.asarray(jnp.nan, primal.dtype)
     head = jnp.stack([primal[worst], gaps[worst],

@@ -58,8 +58,11 @@ CHAIN_VMEM_BUDGET = 12 << 20  # leave ~4 MB of the ~16 MB VMEM for Mosaic
 # to the XLA chain.  The resolver (solvers/cocoa.py auto_block_size)
 # walks this ranking and takes the FIRST candidate that passes the same
 # fit accounting the dispatch layer uses — a ranked choice, not
-# largest-that-fits.  No cell runs a block path, so the ranking has no
-# number on record (ROADMAP D4).
+# largest-that-fits.  No cell runs THIS block family (the T = 1 kernels
+# behind --blockSize), so the ranking has no number on record (ROADMAP D4);
+# the block path a cell does run, ilsvrc1k's T class models on the lanes
+# (ops/block_lanes.py, PR 53), derives its B from its own fit and shares
+# only the restructuring (ops/local_sdca.local_sdca_block) with these.
 BLOCK_SIZE_PREFERENCE = (128, 256, 512)
 
 

@@ -437,6 +437,83 @@ def class_loss_sums(w: jax.Array, alpha, arrays: dict, classes: int,
     return lax.fori_loop(0, k * nb, one, jnp.zeros((3,) + tile, w.dtype))
 
 
+# The largest temporary an all-rows pass over DENSE rows with a class axis
+# on the lanes may hold: a block's margins are (block, T_pad) float32, and
+# the loss values and the dual terms as much again.  Under it the pass is
+# one block a shard (a small set's certificate is one product); over it the
+# rows go by blocks: 16,384 rows at one class tile, where a shard of
+# 40,037 rows x 1,024 lanes would hold three 164 MB temporaries and K
+# shards at once three of 1.3 GB.
+DENSE_CLASS_BLOCK_BYTES = 64 << 20
+
+
+def dense_class_row_block(n_rows: int, t_pad: int, itemsize: int = 4) -> int:
+    """Rows per block of :func:`dense_class_loss_sums`: a shard whole where
+    its (n_rows, t_pad) margins stay under ``DENSE_CLASS_BLOCK_BYTES``,
+    else as many rows, in whole sublane groups, as do."""
+    return min(n_rows, max(
+        8, DENSE_CLASS_BLOCK_BYTES // (t_pad * itemsize) // 8 * 8))
+
+
+def dense_class_loss_sums(w: jax.Array, alpha, arrays: dict, classes: int,
+                          loss: str, smoothing: float) -> tuple:
+    """:func:`class_loss_sums` on DENSE rows (K, n_shard, d) that carry one
+    class id a row: ``(sums (2, R, 128), wrong)`` — per class, over the
+    real rows, the primal loss at the margins X . W (W (d, R, 128), one
+    product a block of rows at ``highest`` precision: a TPU's default is
+    one bfloat16 pass, which moves a margin by ~1e-3 and the certificate
+    with it) and the dual term of ``alpha`` (K, n_shard, R, 128; None:
+    zeros); ``wrong`` the rows whose largest margin is not their own
+    class's (the multi-class error's count).  The rows go by blocks
+    (:func:`dense_class_row_block`), a shard after another, each sliced
+    from the whole arrays where it is used: no temporary is T times a
+    shard."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from cocoa_tpu.data.sharding import class_signs
+    from cocoa_tpu.ops import losses
+
+    x, mask, ids = arrays["X"], arrays["mask"], arrays["classes"]
+    k, n, d = x.shape
+    tile = w.shape[1:]
+    t_pad = tile[0] * tile[1]
+    w2 = w.reshape(d, t_pad)
+    block = dense_class_row_block(n, t_pad, w.dtype.itemsize)
+    nb = -(-n // block)
+    live = jnp.arange(t_pad) < classes
+
+    def one(t, carry):
+        sums, wrong = carry
+        shard, b = t // nb, t % nb
+        start = jnp.minimum(b * block, n - block)
+
+        def rows(a):
+            """Rows [start, start + block) of shard ``shard`` of ``a``."""
+            a = lax.dynamic_slice_in_dim(a, shard, 1, 0)
+            return lax.dynamic_slice_in_dim(a, start, block, 1)[0]
+
+        m = jnp.dot(rows(x), w2, precision=lax.Precision.HIGHEST)
+        # the last block starts early enough to end on the last row: the
+        # rows it shares with its neighbour are the neighbour's
+        own = rows(mask) * (start + jnp.arange(block) >= b * block)
+        cls = rows(ids)
+        ym = class_signs(cls[:, None], classes, w.dtype) \
+            * m.reshape((block,) + tile)
+        primal = losses.primal(loss, ym, smoothing=smoothing)
+        dual = (jnp.zeros_like(primal) if alpha is None else
+                losses.dual_term(loss, rows(alpha), smoothing=smoothing))
+        guess = jnp.argmax(jnp.where(live, m, -jnp.inf), axis=1)
+        own3 = own[:, None, None]
+        return (sums + jnp.stack([(primal * own3).sum(0),
+                                  (dual * own3).sum(0)]),
+                wrong + ((guess != cls) * own).sum())
+
+    return lax.fori_loop(0, k * nb, one,
+                         (jnp.zeros((2,) + tile, w.dtype),
+                          jnp.zeros((), w.dtype)))
+
+
 def gather_dequant(w: jax.Array, idx: jax.Array) -> jax.Array:
     """``w[idx]`` that understands the packed low-precision serving
     forms (serving/quantize.py): the model's DEVICE dtype is the

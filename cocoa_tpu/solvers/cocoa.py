@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import sys
 from typing import Optional
 
 import jax
@@ -33,6 +34,11 @@ from cocoa_tpu.ops import local_sdca
 from cocoa_tpu.ops import rows as _rows
 from cocoa_tpu.solvers import base
 from cocoa_tpu.telemetry import tracing as _tracing
+
+
+# the module whose first import is a first job's ``resolve_path`` second
+# (:func:`run_sdca_family`): the dense kernels', with Pallas behind it
+_KERNELS = "cocoa_tpu.ops.pallas_sdca"
 
 
 def _pallas_batched(w, alpha, idxs_kh, shards, params, mode, sigma,
@@ -302,7 +308,22 @@ class SolverPath:
     (``rows`` ``row_major``), and a dispatch does nothing; ``kernel``: a
     dispatch opens with one Pallas kernel that reads the cache in the
     order the device stores it and writes the aligned rows
-    (ops/pallas_sdca.lane_aligned, the ``cocoa_row_align`` scope)."""
+    (ops/pallas_sdca.lane_aligned, the ``cocoa_row_align`` scope).
+    ``plan`` (None but where said): the resolved kernel's own record of
+    what a round runs, in place of more flat fields here.  A one-vs-rest
+    job over DENSE rows whose T models outgrow the sublane kernel's state
+    tiles (ops/pallas_sdca.classes_fit says no: T = 1,000 at any size)
+    keeps them on the LANES too — W (d, R, 128), alpha (K, n_shard, R,
+    128), as on sparse rows — and solves a BLOCK of rows a step
+    (ops/block_lanes.py; ``inner`` ``block``, ``kernel`` ``products``: the
+    block's margins, ONE Gram matrix for all T classes and the update are
+    matrix products, XLA's; ``chain`` is what replays the block's steps in
+    order on lane vectors, ``pallas`` on a TPU, ``xla`` anywhere else;
+    ``step_solve`` ``lanes``, ``lane_fill`` T / T_pad): its ``plan`` is
+    ops/block_lanes.BlockLanesPlan — ``block`` = B rows a step and
+    ``blocks`` a shard's round, from the shapes and the replay kernel's
+    SMEM and VMEM fit, and the precision of each of the three products
+    (``margins``, ``gram``, ``update``) by name."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -339,6 +360,7 @@ class SolverPath:
     class_tiles: Optional[int] = None
     label_slots: Optional[int] = None
     ids_per_segment: Optional[int] = None
+    plan: Optional[object] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -391,7 +413,14 @@ class SolverPath:
 
     def describe(self) -> str:
         how = "interpreted" if self.interpret else "compiled"
-        if self.chain == "xla":
+        if self.kernel == "products":
+            what = (f"block of {self.plan.block} rows a step "
+                    f"({self.plan.blocks} a round), margins "
+                    f"({self.plan.margins}), Gram ({self.plan.gram}) and "
+                    f"update ({self.plan.update}) as matrix products, "
+                    f"{self.chain} replay"
+                    + (f" ({how})" if self.chain == "pallas" else ""))
+        elif self.chain == "xla":
             what = "block, xla chain"
         elif self.inner == "block":
             what = f"block {self.kernel}, pallas chain ({how})"
@@ -529,6 +558,33 @@ def _hbm_plan(ds: ShardedDataset, local_iters: int):
                     one_length=rows_of_one_length(ds))
 
 
+def class_state_on_lanes(ds: ShardedDataset, mesh=None, *,
+                         math: str = "exact", pallas=None,
+                         block_size: int = 0) -> bool:
+    """Whether a job on ``ds`` keeps its T class models on the LANES: W
+    (d, R, 128), alpha (K, n_shard, R, 128) (data/sharding.class_tile_shape).
+    Read off what the dataset is, never off a flag: padded-CSR rows always
+    (ops/pallas_sparse_lanes.py); dense rows where the sublane kernel's
+    state tiles do not fit (ops/pallas_sdca.classes_fit says no) and the
+    block solve can take them (ops/block_lanes.py: fast math, float32, one
+    class id a row, one chip, no kernel forced by the caller).  Everything
+    else — T = 1, and every dense set the sublane kernel holds — keeps w
+    (T, d), alpha (T, K, n_shard).  :func:`_start_state` shapes the leaves
+    by it and :func:`resolve_solver_path` the path."""
+    classes = int(getattr(ds, "num_classes", 1))
+    if classes <= 1:
+        return False
+    if ds.layout != "dense":
+        return True
+    if (math != "fast" or pallas or block_size > 0 or mesh is not None
+            or jnp.dtype(ds.labels.dtype).itemsize != 4
+            or (getattr(ds, "label_slots", None) or 1) > 1):
+        return False
+    from cocoa_tpu.ops.pallas_sdca import classes_fit
+
+    return not classes_fit(ds.k, ds.n_shard, ds.num_features, classes, 4)
+
+
 def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                         math: str = "exact", pallas=None,
                         block_size: int = 0, block_chain=None,
@@ -550,9 +606,10 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
     classes = int(getattr(ds, "num_classes", 1))
     if classes > 1:
         # what carries the class axis today, said once where the path is
-        # decided: dense rows (a class a sublane) or a padded-CSR
-        # rectangle (the classes on the lanes), one chip, the sequential
-        # solve
+        # decided: dense rows (a class a sublane of the sequential solve
+        # where the state tiles fit VMEM, else the classes on the lanes of
+        # the block solve) or a padded-CSR rectangle (the classes on the
+        # lanes of the sequential solve), one chip
         if ds.layout != "dense" and (ds.sp_row_ptr is not None or ds.n_hot):
             raise ValueError(
                 f"a set of {classes} classes trains one-vs-rest on dense "
@@ -565,16 +622,20 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             raise ValueError(
                 f"label sets ({label_slots} ids a row) train on sparse "
                 f"rows, the class axis on the lanes: the dense class "
-                f"kernel reads one class id a row (load it with "
-                f"--layout=sparse)")
+                f"kernels, the sublanes' and the block solve, read one "
+                f"class id a row (load it with --layout=sparse)")
         if mesh is not None:
             raise ValueError(
                 f"a set of {classes} classes trains one-vs-rest on one chip "
                 f"(--mesh=1): the class axis is not carried across a mesh")
         if block_size > 0:
             raise ValueError(
-                "the block-coordinate kernels carry no class axis: "
-                "block_size=0 with a multi-class set")
+                "block_size picks the tile of the T = 1 block-coordinate "
+                "kernels, which carry no class axis; a multi-class set "
+                "whose models ride the lanes of dense rows runs the block "
+                "solve on a block derived from its shapes "
+                "(ops/block_lanes.block_lanes_plan): block_size=0 with a "
+                "multi-class set")
     # logical shards resident per device: k on the single-chip path, K/D on
     # a (possibly multiplexed) dp mesh — the unit the VMEM fit checks see
     m_local = shards_per_device(mesh, k) if mesh is not None else k
@@ -632,6 +693,33 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
 
             refused = hbm_refusal(ds.num_features, width, local_iters,
                                   itemsize)
+    if class_state_on_lanes(ds, mesh, math=math, pallas=pallas,
+                            block_size=block_size) and not sparse:
+        # T models over dense rows that the sublane kernel does not hold:
+        # a block of rows a step, the class axis on the lanes
+        # (ops/block_lanes.py).  Its replay is the Pallas kernel on a TPU
+        # and XLA's loop anywhere else; ``block_chain`` overrides (tests)
+        from cocoa_tpu.ops.block_lanes import block_fits, block_lanes_plan
+
+        t_pad = class_pad(classes)
+        plan = block_lanes_plan(local_iters, t_pad, itemsize)
+        if block_chain is None:
+            # (the kernel where even its smallest block fits: T_pad past
+            # ~190,000 lanes outgrows eight rows of it)
+            block_chain = ("pallas" if platform == "tpu" and block_fits(
+                plan.block, t_pad, itemsize) else "xla")
+        elif block_chain not in ("xla", "pallas", "pallas_interpret"):
+            raise ValueError(f"block_chain must be xla|pallas|"
+                             f"pallas_interpret, got {block_chain!r}")
+        return SolverPath(
+            inner="block", kernel="products",
+            chain="xla" if block_chain == "xla" else "pallas",
+            interpret=block_chain == "pallas_interpret",
+            layout=layout, platform=platform,
+            devices=len(ds.labels.sharding.device_set),
+            shards_per_device=m_local, step_solve="lanes", classes=classes,
+            class_axis="lanes", class_tiles=t_pad // CLASS_TILE,
+            label_slots=1, lane_fill=classes / t_pad, plan=plan)
     if block_size > 0:
         # the block-coordinate kernel is an alternative inner loop — it and
         # the Pallas sequential kernels are mutually exclusive by design
@@ -875,7 +963,7 @@ def _sdca_round_parts(
             pallas_interpret=pallas_interpret, pallas_state=pallas_state)
         per_round, carry_form = _class_round(
             params, mode, scaling, sigma, classes, one[0], pallas,
-            pallas_interpret, hbm_plan)
+            pallas_interpret, hbm_plan, block_chain)
         return one[0], per_round, one[2], carry_form
     if math not in ("exact", "fast"):
         raise ValueError(f"math must be 'exact' or 'fast', got {math!r}")
@@ -980,7 +1068,7 @@ def _sdca_round_parts(
 
 def _class_round(params: Params, mode: str, scaling: float, sigma: float,
                  classes: int, per_shard, pallas: bool, interpret: bool,
-                 lanes_plan=None):
+                 lanes_plan=None, block_chain: str = "xla"):
     """``(per_round_batched, carry_form)`` of a one-vs-rest round
     (:func:`_sdca_round_parts`): ``per_round_batched(w, carry, idxs (K, H),
     shards) -> (dw, carry')``, the scaling law applied.  What the carry is
@@ -991,6 +1079,11 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
       carry alpha (K, n_shard, R, 128) as the loop's state holds it,
       ops/pallas_sparse_lanes.py: its HBM-state chain on ``lanes_plan``, or
       the same round in plain XLA — and the round names its own scopes;
+    - dense rows on the block solve (``lanes_plan`` an
+      ops/block_lanes.BlockLanesPlan: the T models outgrew the sublane
+      kernel): the same w and carry, a block of rows a step, its replay
+      ``block_chain``'s; the whole round under the solve's scope, the
+      products and the replay under scopes of their own inside it;
     - dense rows on the class kernel (``SolverPath.class_state``
       ``tiles``): w (T, d), the carry the kernel's own state tiles (K,
       n_blocks, R, 128) (ops/pallas_sdca.class_state_pack): the round is
@@ -1015,6 +1108,16 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
         with jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE):
             return lanes.sparse_lanes_round_fori(w, alpha, shards, idxs_kh,
                                                  **common)
+
+    @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
+    def per_round_block(w, alpha, idxs_kh, shards):
+        from cocoa_tpu.ops.block_lanes import block_lanes_round
+
+        return block_lanes_round(
+            w, alpha, shards, idxs_kh, params.lam, params.n, classes,
+            lanes_plan, mode=mode, sigma=sigma, scaling=scaling,
+            loss=params.loss, smoothing=params.smoothing,
+            replay=block_chain)
 
     @jax.named_scope(_tracing.SCOPE_LOCAL_SOLVE)
     def per_round_tiles(w, state, idxs_kh, shards):
@@ -1043,6 +1146,7 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
 
     def per_round(w, carry, idxs_kh, shards):
         return (per_round_lanes if "sp_indices" in shards
+                else per_round_block if w.ndim == 3
                 else per_round_tiles if pallas
                 else per_round_classes)(w, carry, idxs_kh, shards)
 
@@ -1279,7 +1383,7 @@ _START_PROGRAMS: dict = base.ExecutableCache()
 
 def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
                  start_round: int, w_init, alpha_init, hist_init,
-                 sched_init) -> tuple:
+                 sched_init, lanes: bool = False) -> tuple:
     """The start state of an SDCA-family job, ``(w, α)`` and per ``arm``
     the leaves its loop carries: ``"accel"`` the (2, K, n_shard) window
     bank and the schedule leaf, ``"sched"`` the schedule leaf, ``"plain"``
@@ -1297,17 +1401,18 @@ def _start_state(ds: ShardedDataset, dtype, mesh, arm: str, residual: bool,
     tiny program the device waits for, then its ``device_put`` on a mesh."""
     # plain ints: the cached start program must not keep ``ds`` alive
     d, k, n_shard = int(ds.num_features), ds.k, int(ds.n_shard)
-    # a one-vs-rest job's leaves carry the class axis: first on dense rows,
-    # w (T, d), alpha (T, K, n_shard); last and as tiles on sparse rows (the
-    # lanes form), W (d, R, 128), alpha (K, n_shard, R, 128)
-    # (data/sharding.class_tile_shape); at T = 1 there is none
-    lanes = ds.num_classes > 1 and ds.layout == "sparse"
+    # a one-vs-rest job's leaves carry the class axis: first where a class
+    # is a sublane of the dense kernel, w (T, d), alpha (T, K, n_shard);
+    # last and as tiles in the lanes form (``lanes``:
+    # :func:`class_state_on_lanes`), W (d, R, 128), alpha (K, n_shard, R,
+    # 128) (data/sharding.class_tile_shape); at T = 1 there is none
     lead = (ds.num_classes,) if ds.num_classes > 1 and not lanes else ()
     trail = class_tile_shape(ds.num_classes) if lanes else ()
     if lanes and any(v is not None for v in (w_init, alpha_init)):
         raise ValueError(
-            f"a job over {ds.num_classes} classes on sparse rows starts "
-            f"from alpha = 0, W = 0: no state is handed in yet")
+            f"a job over {ds.num_classes} classes whose models ride the "
+            f"lanes starts from alpha = 0, W = 0: no state is handed in "
+            f"yet")
     accel = arm == "accel"
     sched = (None if arm == "plain"
              else base.sched_init_values(start_round, sched_init, accel))
@@ -1643,22 +1748,37 @@ def run_sdca_family(
         _check_class_job(ds, alg, arm, debug, test_ds,
                          eval_fn is not None or eval_kernel is not None)
     counted = (_sanitize.launches_total, _sanitize.intended_fetches_total)
-    # init_state: one start program when the job starts from nothing —
-    # dispatched here, ahead of the host's path to the loop's dispatch, so
-    # the device fills the leaves meanwhile (_start_state)
-    with _tracing.span("init_state"):
-        state0 = _start_state(ds, dtype, mesh, arm, alg[0] == "prox",
-                              start_round, w_init, alpha_init, hist_init,
-                              sched_init)
 
-    # (in a first job the kernels' modules are imported here, Pallas with
-    # them: a second that gets a cold span of its own)
-    with _tracing.first_job_span("resolve_path"):
-        path = resolve_solver_path(
-            ds, params.local_iters, mesh, math=math, pallas=pallas,
-            block_size=block_size, block_chain=block_chain,
-            block_sparse_gram=block_sparse_gram, loss=params.loss,
-        ).for_mode(alg[0], params.smoothing)
+    def resolve(early=False):
+        # (in a first job the kernels' modules are imported here, Pallas
+        # with them: a second that gets a cold span of its own.  A job that
+        # resolves ahead of its start program has opened no ``first_job``
+        # yet: where that import is still to pay it opens the span itself)
+        with (_tracing.cold_span if early and _KERNELS not in sys.modules
+              else _tracing.first_job_span)("resolve_path"):
+            return resolve_solver_path(
+                ds, params.local_iters, mesh, math=math, pallas=pallas,
+                block_size=block_size, block_chain=block_chain,
+                block_sparse_gram=block_sparse_gram, loss=params.loss,
+            ).for_mode(alg[0], params.smoothing)
+
+    # the leaves of T class models over dense rows take their shape from
+    # the resolved path (a class a sublane, or the classes on the lanes),
+    # so that job resolves first; every other job's start program goes out
+    # ahead of the host's walk to the loop's dispatch, as before
+    path = (resolve(early=True) if classes > 1 and ds.layout == "dense"
+            else None)
+    # init_state: one start program when the job starts from nothing —
+    # dispatched here, so the device fills the leaves meanwhile
+    # (_start_state)
+    with _tracing.span("init_state"):
+        state0 = _start_state(
+            ds, dtype, mesh, arm, alg[0] == "prox", start_round, w_init,
+            alpha_init, hist_init, sched_init,
+            lanes=classes > 1 and (ds.layout == "sparse"
+                                   or path.class_axis == "lanes"))
+    if path is None:
+        path = resolve()
     pallas, block_chain = path.pallas, path.block_chain
     if not quiet:
         print(f"local solver: {path.describe()}; the shared vector is "
@@ -1668,7 +1788,7 @@ def run_sdca_family(
         pallas_interpret=path.pallas and path.interpret,
         pallas_state=path.state,
         hbm_plan=(_hbm_plan(ds, params.local_iters) if path.local_ids
-                  else None),
+                  else path.plan),
         block=block_size, block_chain=block_chain,
         block_sparse_gram=block_sparse_gram,
         # permuted sampling with n_local % H == 0 keeps every round inside
@@ -1736,6 +1856,9 @@ def run_sdca_family(
         params.gamma, params.loss, params.smoothing,
         params.num_rounds, debug.debug_iter, start_round,
         gap_target, ds.layout, str(dtype), classes,
+        # (T class models over dense rows: a class a sublane or the classes
+        # on the lanes is another state and another loop)
+        path.class_axis,
     )
     state, traj = base.drive_device_paths(
         alg_name, params, debug, state0, chunk_kernel, chunk_fn,
@@ -1798,9 +1921,14 @@ def run_cocoa(
     lane is frozen before that; the budget and the divergence watch read
     the worst class.  A record's ``gap`` is the worst class's, ``primal``
     that class's, ``class_gaps`` all of them.  The branch is on what the
-    dataset declares; at T = 1 nothing of it runs.  Not carried yet, and
-    refused by name: sparse rows, a mesh, ``--accel``, the sigma' schedule
-    and warm start, checkpoints, the block kernels.
+    dataset declares; at T = 1 nothing of it runs.  Where the T models
+    outgrow the dense class kernel's state tiles (ops/pallas_sdca.classes_fit:
+    T = 1,000 at any size) the job keeps them on the lanes, as on sparse
+    rows — returns (W (d, R, 128), alpha (K, n_shard, R, 128), Trajectory)
+    — and solves a block of rows a step (ops/block_lanes.py): chosen from
+    the shapes, never by a flag.  Not carried yet, and refused by name:
+    rows kept as a stream, a mesh, ``--accel``, the sigma' schedule and warm
+    start, checkpoints, ``--blockSize`` (the T = 1 block kernels' tile).
 
     ``params.sigma="auto"`` (flag ``--sigma=auto``) exploits the measured
     σ′ trade-off (the aggressive σ′ = K·γ/2 HALVES

@@ -151,8 +151,18 @@ SCOPE_SPARSE_GATHER = "cocoa_sparse_gather"  # sparse rows past VMEM: the
 SCOPE_ROW_ALIGN = "cocoa_row_align"       # the dense fold cache relaid into
                                           # lane-aligned rows, once a dispatch
                                           # (ops/pallas_sdca.lane_aligned)
+# the two halves of a block round of T class models on the lanes of dense
+# rows (ops/block_lanes.py), both INSIDE the local solve's scope, which
+# holds the whole round: an op's innermost scope says which half paces it
+SCOPE_WIDE_PRODUCTS = "cocoa_wide_products"  # a block's rows gathered, its
+                                          # margins, its Gram matrix and its
+                                          # update: the matrix products
+SCOPE_WIDE_REPLAY = "cocoa_wide_replay"   # the block's steps in order on
+                                          # lane vectors, a row's alphas in
+                                          # and out
 SCOPES = (SCOPE_LOCAL_SOLVE, SCOPE_DW_REDUCE, SCOPE_EVAL, SCOPE_INDICES,
-          SCOPE_ACCEL_JUMP, SCOPE_SPARSE_GATHER, SCOPE_ROW_ALIGN)
+          SCOPE_ACCEL_JUMP, SCOPE_SPARSE_GATHER, SCOPE_ROW_ALIGN,
+          SCOPE_WIDE_PRODUCTS, SCOPE_WIDE_REPLAY)
 
 # the cold path (module docstring): the records ``Tracer.cold`` keeps, the
 # span a cold job's entry wears, and what one HBM reading holds of a device

@@ -21,6 +21,17 @@
 # local-accuracy ladder — early rounds run H/2 inner steps, tightening
 # to the full H near the target, resolved on device from the gap
 # estimate (docs/DESIGN.md "Accelerated outer loop").
+# A multi-class file trains one-vs-rest over ONE copy of its rows: append
+# --classes=auto --accel=off --mesh=1 (and --layout=dense for dense
+# features).  Ten classes ride the sublanes of the dense kernel; a dense
+# file of hundreds or thousands of classes (CNN features: --numFeatures=4096,
+# ILSVRC's 1,000 classes) resolves, from its shapes alone, to a block of
+# 256 rows a step with the classes on the lanes — the margins, one Gram
+# matrix and the update as matrix products (docs/DESIGN.md section 3f):
+#   python -m cocoa_tpu.cli --trainFile=features.dat --numFeatures=4096 \
+#     --numSplits=8 --lambda=1e-4 --localIterFrac=0.1 --numRounds=600 \
+#     --justCoCoA=true --math=fast --deviceLoop --rng=permuted \
+#     --gapTarget=1e-3 --classes=auto --layout=dense --accel=off --mesh=1
 cd "$(dirname "$0")"
 exec python -m cocoa_tpu.cli \
   --trainFile=data/small_train.dat \

@@ -184,3 +184,78 @@ def cocoa_outer(
             dw_sum += dw
         w = w + scaling * dw_sum
     return w, alphas
+
+
+# --- T one-vs-rest chains over one X (tests/test_wide_classes.py) ----------
+
+
+def _logistic_step(a, z, qii, lam_n):
+    """The logistic coordinate step to convergence, in float64: the root
+    u of g(u) = u + z + (qii / lam_n) (sigmoid(u) - a) = 0, g increasing
+    with g' >= 1, by 60 Newton steps from the current alpha's logit."""
+    ac = np.clip(a, 1e-12, 1 - 1e-12)
+    q = qii / lam_n
+    u = np.clip(np.log(ac / (1 - ac)), -35.0, 35.0)
+    for _ in range(60):
+        sig = 1.0 / (1.0 + np.exp(-u))
+        u = np.clip(u - (u + z + q * (sig - ac)) / (1 + q * sig * (1 - sig)),
+                    -35.0, 35.0)
+    return 1.0 / (1.0 + np.exp(-u))
+
+
+def ovr_local_sdca(X, cls, W, alpha, idxs, lam, n, sigma, loss="hinge",
+                   dtype=np.float32):
+    """T sequential CoCoA+ chains of one shard over ONE index stream, class
+    t against the rest, written out: no blocks, no Gram matrix, a row dot
+    and a row axpy a step and a class.  ``X`` (n_local, d), ``cls``
+    (n_local,) class ids, ``W`` (T, d), ``alpha`` (T, n_local), ``idxs``
+    (H,).  The T chains are independent, so a step advances them side by
+    side (one NumPy expression over the class axis); the arithmetic of a
+    chain is ``local_sdca``'s, in ``dtype``.  Returns (alpha', dW (T, d))."""
+    t_count = W.shape[0]
+    X = X.astype(dtype)
+    W = W.astype(dtype)
+    alpha = alpha.astype(dtype).copy()
+    dW = np.zeros_like(W)
+    lam_n = dtype(lam * n)
+    sigma = dtype(sigma)
+    for idx in idxs:
+        x = X[idx]
+        y = np.where(np.arange(t_count) == cls[idx], 1, -1).astype(dtype)
+        a = alpha[:, idx]
+        z = y * (W @ x + sigma * (dW @ x))
+        qii = dtype(x @ x) * sigma
+        if loss == "hinge":
+            grad = (z - 1) * lam_n
+            proj = np.where(a <= 0, np.minimum(grad, 0),
+                            np.where(a >= 1, np.maximum(grad, 0), grad))
+            step = (np.clip(a - grad / qii, 0, 1) if qii != 0
+                    else np.ones_like(a))
+            new_a = np.where(proj != 0, step, a).astype(dtype)
+        elif loss == "logistic":
+            new_a = _logistic_step(a.astype(np.float64), z.astype(np.float64),
+                                   float(qii), float(lam_n)).astype(dtype)
+        else:
+            raise ValueError(loss)
+        dW += np.outer(y * (new_a - a) / lam_n, x).astype(dtype)
+        alpha[:, idx] = new_a
+    return alpha, dW
+
+
+def ovr_cocoa_plus(X_shards, cls_shards, tables, lam, n, t_count,
+                   loss="hinge", gamma=1.0, dtype=np.float32):
+    """CoCoA+ (adding, sigma' = K gamma) over K shards for T classes from
+    alpha = 0, W = 0: ``tables`` (rounds, K, H) local row ids, the job's
+    index stream.  Returns (W (T, d), [alpha_k (T, n_k)])."""
+    k = len(X_shards)
+    W = np.zeros((t_count, X_shards[0].shape[1]), dtype)
+    alphas = [np.zeros((t_count, x.shape[0]), dtype) for x in X_shards]
+    for table in tables:
+        dW = np.zeros_like(W)
+        for s in range(k):
+            alphas[s], dws = ovr_local_sdca(
+                X_shards[s], cls_shards[s], W, alphas[s], table[s], lam, n,
+                k * gamma, loss, dtype)
+            dW += dws
+        W = W + dtype(gamma) * dW
+    return W, alphas

@@ -153,7 +153,7 @@ def test_solver_path_says_how_the_rows_are_stored(layout, d, pallas, rows):
         "classes", "lane_fill", "row_fetch", "ring_depth", "row_align",
         "local_ids", "segments", "table_width", "slots_walked",
         "slot_walk", "class_axis", "class_state", "class_tiles",
-        "label_slots", "ids_per_segment"]
+        "label_slots", "ids_per_segment", "plan"]
     # no stream, no ring of chunks
     assert (path.chunk_pieces, path.chunk_fill) == (None, None)
     # the dense Pallas kernel's rows come by its own ring, as deep as fits

@@ -77,11 +77,11 @@ class _Captured(Exception):
     pass
 
 
-def _arm_capture(monkeypatch):
+def _arm_capture(monkeypatch, told=(("pallas", True),)):
     """Stop the next job at its dispatch: the returned dict gets ``run``,
-    its ``args`` and the resolved ``path`` (Pallas forced on, held at
-    compiled: this process's platform is cpu), and the entry raises
-    :class:`_Captured`."""
+    its ``args`` and the resolved ``path`` (Pallas forced on — ``told``:
+    what the resolver is told — and held at compiled: this process's
+    platform is cpu), and the entry raises :class:`_Captured`."""
     from cocoa_tpu.ops import pallas_sdca
     from cocoa_tpu.solvers import base
     from cocoa_tpu.solvers import cocoa as cocoa_mod
@@ -102,7 +102,7 @@ def _arm_capture(monkeypatch):
 
     def compiled_pallas(*args, **kw):
         got["path"] = dataclasses.replace(
-            resolve(*args, **{**kw, "pallas": True}), interpret=False)
+            resolve(*args, **{**kw, **dict(told)}), interpret=False)
         return got["path"]
 
     monkeypatch.setattr(base, "_build_device_run", capturing)
@@ -839,6 +839,85 @@ def test_amazoncat_job_fits_one_chip_and_copies_no_state(monkeypatch,
                        rf"\[{k},{n_shard},{shape['slots']}\]")
     copies = [line.strip()[:160] for line in hlo.splitlines()
               if " copy(" in line and large.search(line.split(" copy(")[0])]
+    assert copies == []
+
+
+ILSVRC1K = dict(n=320292, d=4096, k=8, classes=1000, frac=0.1, lam=1e-4)
+
+
+def test_ilsvrc_job_resolves_to_the_lanes_and_fits_one_chip(monkeypatch,
+                                                            one_chip):
+    """The whole device loop of a one-vs-rest job at ilsvrc1k's shapes
+    (shapes only: 6.6 GB) compiled for one described v5e: from the shapes
+    alone — no flag, the resolver's own answer but for its replay held at
+    the compiled Pallas kernel, which this process's platform (cpu) would
+    not pick — the job resolves to a block of 256 rows a step with the
+    class axis on the lanes, 16 blocks a round.  W (d, 8, 128) and alpha (K,
+    n_shard, 8, 128) are held AS tiles; the rows are read as stored (d =
+    4,096 is 32 whole lane tiles: no fold cache, nothing relaid) and alpha
+    is scattered into in place: nothing copies a rows- or alpha-sized
+    array, the K running vectors V_k (134 MB) are the loop's largest
+    temporary, and the certificate's row blocks hold 64 MB at a time where
+    one product over all rows would hold 1.3 GB three times over.
+    Arguments plus temporaries stay under 6.9 GB of the chip's 15.75."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import (ShardedDataset, pad_rows,
+                                         split_sizes)
+    from cocoa_tpu.solvers import base, run_cocoa
+
+    shape = ILSVRC1K
+    with jax.enable_x64(False):
+        got = _arm_capture(monkeypatch, told=(("block_chain", "pallas"),))
+        k, d = shape["k"], shape["d"]
+        sizes = split_sizes(shape["n"], k)
+        n_shard = pad_rows(int(sizes.max()))
+        here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+        def sds(dims, dt):
+            return jax.ShapeDtypeStruct(dims, dt, sharding=here)
+
+        rows = sds((k, n_shard), jnp.float32)
+        ds = ShardedDataset(
+            layout="dense", n=shape["n"], num_features=d,
+            counts=sizes.astype(np.int64), labels=rows, mask=rows,
+            sq_norms=rows, X=sds((k, n_shard, d), jnp.float32),
+            classes=sds((k, n_shard), jnp.int32),
+            num_classes=shape["classes"])
+        h = int(shape["frac"] * shape["n"] / k)
+        with pytest.raises(_Captured):
+            run_cocoa(ds, Params(n=ds.n, num_rounds=600, local_iters=h,
+                                 lam=shape["lam"]),
+                      DebugParams(debug_iter=10, seed=0), plus=True,
+                      quiet=True, math="fast", device_loop=True,
+                      rng="permuted", gap_target=1e-3, accel="off")
+        base._DEVICE_RUNS.clear()
+        path = got["path"]
+        assert (path.inner, path.kernel, path.chain, path.interpret,
+                path.class_axis, path.class_tiles, path.classes) == (
+            "block", "products", "pallas", False, "lanes", 1, 1000)
+        assert (path.plan.block, path.plan.blocks, h, n_shard) == (
+            256, 16, 4003, 40048)
+        compiled = got["run"].lower(*_on_chip(got["args"],
+                                              one_chip)).compile()
+    stats = compiled.memory_analysis()
+    held = stats.argument_size_in_bytes + stats.temp_size_in_bytes
+    assert 6.5e9 < stats.argument_size_in_bytes < 6.7e9    # the deployment
+    assert held <= 6.9e9, (stats.argument_size_in_bytes,    # 6.58 + 0.17
+                           stats.temp_size_in_bytes)
+    hlo = compiled.as_text()
+    # the replay kernel is there, under the replay's scope INSIDE the solve's
+    assert re.search(r'op_name="[^"]*cocoa_local_solve/[^"]*cocoa_wide_replay/'
+                     r'pallas_block_lanes_replay', hlo)
+    assert re.search(r'op_name="[^"]*cocoa_local_solve/[^"]*'
+                     r'cocoa_wide_products/[^"]*dot_general', hlo)
+    large = re.compile(rf"\[{k},{n_shard},(8,128|1024|{d})\]|"
+                       rf"\[{k * n_shard},(8,128|1024|{d})\]")
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if (" copy(" in line or " transpose(" in line)
+              and large.search(line.split("(")[0])]
     assert copies == []
 
 

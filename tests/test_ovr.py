@@ -391,7 +391,7 @@ def test_pack_then_alpha_is_alpha_to_the_bit(t, n_shard):
 
 
 def _per_round_pack(params, mode, scaling, sigma, classes, per_shard, pallas,
-                    interpret, lanes_plan=None):
+                    interpret, lanes_plan=None, block_chain="xla"):
     """The class round as it was before the loop carried the tiles, kept
     here as the reference: the state tile built from (T, K, n_shard) EVERY
     round, taken apart again every round, the scaling law on the

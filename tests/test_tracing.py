@@ -250,7 +250,7 @@ def lowered(monkeypatch):
 
 
 def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off",
-              sparse=False, sampling="auto"):
+              sparse=False, sampling="auto", classes=1):
     import jax
 
     from cocoa_tpu.data.synth import synth_dense_sharded
@@ -265,6 +265,12 @@ def _loop_run(loss="hinge", mesh=None, pallas=None, accel="off",
                            layout="sparse", dtype=jnp.float32)
     else:
         ds = synth_dense_sharded(256, 32, K, seed=1, mesh=mesh)
+    if classes > 1:
+        import jax.numpy as jnp
+
+        ds.num_classes = classes
+        ds.classes = jnp.asarray(np.random.default_rng(1).integers(
+            0, classes, ds.labels.shape), jnp.int32)
     params = Params(n=ds.n, num_rounds=20, local_iters=16, lam=1e-2,
                     loss=loss)
     w, alpha, traj = run_cocoa(
@@ -284,7 +290,8 @@ def _loc_names(text):
 
 
 @pytest.mark.parametrize("case", ["hinge_pallas", "logistic",
-                                  "mesh_accel", "sparse_hbm"])
+                                  "mesh_accel", "sparse_hbm",
+                                  "wide_classes"])
 def test_lowered_device_loop_carries_each_scope_once(lowered, case,
                                                      monkeypatch):
     """The scope names reach the lowered loop — the kernel and its glue
@@ -297,28 +304,45 @@ def test_lowered_device_loop_carries_each_scope_once(lowered, case,
     the kernel whose state stays in HBM (a sibling of the local solve's
     scope there, never inside it), and ``cocoa_row_align`` only where a
     dense Pallas job's fold cache is not stored lane-aligned (d/8 = 4
-    here: the relayout at the dispatch's entry, before the loop)."""
-    from cocoa_tpu.ops import pallas_sparse
+    here: the relayout at the dispatch's entry, before the loop).  The ONE
+    nesting is the block solve's (T class models on the lanes of dense
+    rows, ops/block_lanes.py): its two halves, the matrix products and the
+    replay, are named in the body of the scan the local solve's scope
+    holds, so a compiled op's path carries the solve's name AND a half's;
+    no lowered name does."""
+    from cocoa_tpu.ops import pallas_sdca, pallas_sparse
     from cocoa_tpu.parallel import make_mesh
 
     kw = {"hinge_pallas": dict(pallas=True), "logistic": dict(
         loss="logistic"), "mesh_accel": dict(mesh=make_mesh(4),
                                              accel="on"),
-          "sparse_hbm": dict(sparse=True, pallas=True)}[case]
+          "sparse_hbm": dict(sparse=True, pallas=True),
+          "wide_classes": dict(classes=17)}[case]
     if case == "sparse_hbm":     # the VMEM-resident kernel would fit here
         monkeypatch.setattr(pallas_sparse, "sparse_kernel_fits",
                             lambda *a, **k: False)
+    if case == "wide_classes":   # the sublane kernel would hold the set
+        monkeypatch.setattr(pallas_sdca, "CLASS_VMEM_BUDGET", 0)
     _loop_run(**kw)
     names = _loc_names(lowered[-1][0])
+    halves = (tracing.SCOPE_WIDE_PRODUCTS, tracing.SCOPE_WIDE_REPLAY)
     own_case = {tracing.SCOPE_ACCEL_JUMP: "mesh_accel",
                 tracing.SCOPE_SPARSE_GATHER: "sparse_hbm",
-                tracing.SCOPE_ROW_ALIGN: "hinge_pallas"}
+                tracing.SCOPE_ROW_ALIGN: "hinge_pallas",
+                **dict.fromkeys(halves, "wide_classes")}
     for scope in tracing.SCOPES:
         assert any(scope in n for n in names) == (
             own_case.get(scope, case) == case), (scope, case)
         assert "/" not in scope
     assert [n for n in names
             if sum(n.count(sc) for sc in tracing.SCOPES) > 1] == []
+    if case == "wide_classes":
+        # (lowered, the halves are named in the body the solve's scan
+        # calls; compiled, their ops read cocoa_local_solve/while/body/
+        # closed_call/cocoa_wide_products/..: tests/test_device_layout.py)
+        assert tracing.SCOPE_LOCAL_SOLVE + "/while/body/closed_call" in names
+        assert any(n.startswith(tracing.SCOPE_WIDE_PRODUCTS + "/")
+                   and n.endswith("dot_general") for n in names)
     if case == "hinge_pallas":      # the kernel call itself is inside
         assert any(n.startswith(tracing.SCOPE_LOCAL_SOLVE + "/jit(pallas")
                    for n in names)
