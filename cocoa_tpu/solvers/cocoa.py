@@ -322,8 +322,11 @@ class SolverPath:
     ``step_solve`` ``lanes``, ``lane_fill`` T / T_pad): its ``plan`` is
     ops/block_lanes.BlockLanesPlan — ``block`` = B rows a step and
     ``blocks`` a shard's round, from the shapes and the replay kernel's
-    SMEM and VMEM fit, and the precision of each of the three products
-    (``margins``, ``gram``, ``update``) by name."""
+    fit, ``sub`` = b steps a sub-block of the two-level replay (a step's
+    margin sums its own sub-block's earlier steps in the chain; what the
+    earlier sub-blocks owe is a matrix product), and the precision of each
+    of the four products (``margins``, ``gram``, ``cross``, ``update``) by
+    name."""
     inner: str
     kernel: str
     chain: Optional[str]
@@ -415,10 +418,11 @@ class SolverPath:
         how = "interpreted" if self.interpret else "compiled"
         if self.kernel == "products":
             what = (f"block of {self.plan.block} rows a step "
-                    f"({self.plan.blocks} a round), margins "
-                    f"({self.plan.margins}), Gram ({self.plan.gram}) and "
-                    f"update ({self.plan.update}) as matrix products, "
-                    f"{self.chain} replay"
+                    f"({self.plan.blocks} a round) in sub-blocks of "
+                    f"{self.plan.sub}, margins ({self.plan.margins}), Gram "
+                    f"({self.plan.gram}), what a sub-block is owed "
+                    f"({self.plan.cross}) and update ({self.plan.update}) "
+                    f"as matrix products, {self.chain} replay"
                     + (f" ({how})" if self.chain == "pallas" else ""))
         elif self.chain == "xla":
             what = "block, xla chain"

@@ -852,13 +852,15 @@ def test_ilsvrc_job_resolves_to_the_lanes_and_fits_one_chip(monkeypatch,
     alone — no flag, the resolver's own answer but for its replay held at
     the compiled Pallas kernel, which this process's platform (cpu) would
     not pick — the job resolves to a block of 256 rows a step with the
-    class axis on the lanes, 16 blocks a round.  W (d, 8, 128) and alpha (K,
-    n_shard, 8, 128) are held AS tiles; the rows are read as stored (d =
-    4,096 is 32 whole lane tiles: no fold cache, nothing relaid) and alpha
-    is scattered into in place: nothing copies a rows- or alpha-sized
-    array, the K running vectors V_k (134 MB) are the loop's largest
-    temporary, and the certificate's row blocks hold 64 MB at a time where
-    one product over all rows would hold 1.3 GB three times over.
+    class axis on the lanes, 16 blocks a round, a block replayed in eight
+    sub-blocks of 32 steps (what the earlier ones owe a sub-block: a matrix
+    product at ``highest``, in the program with the other three).  W (d, 8,
+    128) and alpha (K, n_shard, 8, 128) are held AS tiles; the rows are read
+    as stored (d = 4,096 is 32 whole lane tiles: no fold cache, nothing
+    relaid) and alpha is scattered into in place: nothing copies a rows- or
+    alpha-sized array, the K running vectors V_k (134 MB) are the loop's
+    largest temporary, and the certificate's row blocks hold 64 MB at a time
+    where one product over all rows would hold 1.3 GB three times over.
     Arguments plus temporaries stay under 6.9 GB of the chip's 15.75."""
     import jax
     import jax.numpy as jnp
@@ -900,6 +902,7 @@ def test_ilsvrc_job_resolves_to_the_lanes_and_fits_one_chip(monkeypatch,
             "block", "products", "pallas", False, "lanes", 1, 1000)
         assert (path.plan.block, path.plan.blocks, h, n_shard) == (
             256, 16, 4003, 40048)
+        assert (path.plan.sub, path.plan.cross) == (32, "highest")
         compiled = got["run"].lower(*_on_chip(got["args"],
                                               one_chip)).compile()
     stats = compiled.memory_analysis()
@@ -907,12 +910,31 @@ def test_ilsvrc_job_resolves_to_the_lanes_and_fits_one_chip(monkeypatch,
     assert 6.5e9 < stats.argument_size_in_bytes < 6.7e9    # the deployment
     assert held <= 6.9e9, (stats.argument_size_in_bytes,    # 6.58 + 0.17
                            stats.temp_size_in_bytes)
+    # (the two-level replay holds no more than the one-level one did: a
+    # block's M0, C and alpha rows once, a sub-block's pieces of 1 MB)
+    assert stats.temp_size_in_bytes <= 0.18e9, stats.temp_size_in_bytes
     hlo = compiled.as_text()
     # the replay kernel is there, under the replay's scope INSIDE the solve's
     assert re.search(r'op_name="[^"]*cocoa_local_solve/[^"]*cocoa_wide_replay/'
                      r'pallas_block_lanes_replay', hlo)
-    assert re.search(r'op_name="[^"]*cocoa_local_solve/[^"]*'
-                     r'cocoa_wide_products/[^"]*dot_general', hlo)
+    # (Mosaic took it at b = 32 steps a call: the loop over a block's eight
+    # sub-blocks is XLA's, inside the scan over the round's blocks)
+    # the four products of the solve under the products' scope, each at the
+    # precision the plan states; the sub-blocks' cross product among them
+    said = re.findall(
+        r'operand_precision=\{\w+,(\w+)\}, metadata=\{op_name="[^"]*'
+        r'cocoa_local_solve/[^"]*cocoa_wide_products/([^"/]*)/dot_general"',
+        hlo)
+    assert {name: precision for precision, name in said} == {
+        "kbd,kdt->kbt": path.plan.margins, "kbd,kcd->kbc": path.plan.gram,
+        "kbc,kct->kbt": path.plan.cross, "kbd,kbt->kdt": path.plan.update}
+    # nothing of the solve is rounded to bfloat16, and no op of its on the
+    # matrix unit is left at the one-pass default, which would round its
+    # operands there (ROADMAP S11)
+    solve = [line for line in hlo.splitlines() if "cocoa_local_solve" in line]
+    assert not [line[:160] for line in solve if "bf16[" in line]
+    mxu = [line for line in solve if re.search(r" (dot|convolution)\(", line)]
+    assert len(mxu) == 4 and all("operand_precision={hi" in m for m in mxu)
     large = re.compile(rf"\[{k},{n_shard},(8,128|1024|{d})\]|"
                        rf"\[{k * n_shard},(8,128|1024|{d})\]")
     copies = [line.strip()[:160] for line in hlo.splitlines()

@@ -7,6 +7,10 @@ block of rows a step (ops/block_lanes.py), on the CPU.
   logistic, rows drawn twice inside a block, through both replays (XLA's
   loop and the interpreted Pallas kernel) — tight enough that a W or a
   certificate through one bfloat16 rounding fails;
+- the two-level replay of a block (sub-blocks of b steps in order, what the
+  earlier sub-blocks owe as a matrix product) against the one-level chain
+  over all B steps, on a block that holds a row drawn twice in two
+  sub-blocks, one drawn twice inside a sub-block and a padded tail;
 - alpha in its box, W = w(alpha), nothing on the lanes past T, the
   worst-class stop; CoCoA's averaging and mini-batch CD against the class
   axis on the sublanes (``fori``); the same job through the CLI;
@@ -139,6 +143,9 @@ CASES = [
     (1901, 50, 130, 1, "hinge", "permuted", "xla"),
     (1901, 50, 130, 2, "logistic", "permuted", "xla"),
     (1901, 50, 130, 5, "hinge", "reference", "pallas_interpret"),
+    # B = 232: eight sub-blocks of 32 steps, the last one's tail padded
+    (6007, 37, 17, 2, "hinge", "reference", "xla"),
+    (6007, 37, 17, 1, "logistic", "permuted", "pallas_interpret"),
 ]
 
 
@@ -157,6 +164,11 @@ def test_the_block_solve_is_t_sequential_chains(wide, n, d, t, rounds, loss,
     # whole blocks, d no whole lane tile
     assert blocks >= 2 and h % block and n % block and d % 128
     assert blocks * block >= h > (blocks - 1) * block
+    # the replay in two levels: several sub-blocks a block, and B no whole
+    # number of them (the last one ends in padded steps)
+    sub = path["plan"]["sub"]
+    assert -(-block // sub) >= (8 if n == 6007 else 3) and block % sub
+    assert path["plan"]["cross"] == "highest"
     tables, (w_ref, alphas_ref) = oracle_job(ds, h, rounds, loss, rng)
     if rng == "reference":      # drawn with replacement: rows twice a block
         first = tables[0, 0, :block]
@@ -172,6 +184,106 @@ def test_the_block_solve_is_t_sequential_chains(wide, n, d, t, rounds, loss,
     # nothing on the lanes past T
     assert not np.asarray(w).reshape(d, -1)[:, t:].any()
     assert not np.asarray(alpha).reshape(2, ds.n_shard, -1)[..., t:].any()
+
+
+# --- the replay in two levels -------------------------------------------------
+
+def a_block(k, b, d, t, loss, seed=11):
+    """One block of K x B sampled rows as ``block_lanes_round`` hands it to
+    the replay, from real rows (so G IS the rows' Gram matrix and a row
+    drawn twice meets its own squared norm there): row 5 again at step 50
+    (two sub-blocks at b = 8 and at b = 32), row 17 again at 19 and at 22
+    (inside one sub-block at both), row 3 again at 30 (two at b = 8, one at
+    b = 32), the last six steps padded (``code`` -1)."""
+    import jax.numpy as jnp
+
+    from cocoa_tpu.data.sharding import class_tile_shape
+
+    r = np.random.default_rng(seed)
+    n_rows = 400
+    tile = class_tile_shape(t)
+    t_pad = tile[0] * tile[1]
+    x = r.normal(size=(k, n_rows, d)).astype(np.float32)
+    x /= np.linalg.norm(x, axis=-1, keepdims=True)
+    cls = r.integers(0, t, (k, n_rows)).astype(np.int32)
+    idx = np.stack([r.permutation(n_rows)[:b] for _ in range(k)])
+    for again, first in ((50, 5), (19, 17), (22, 17), (30, 3)):
+        idx[:, again] = idx[:, first]
+    alpha = np.zeros((k, n_rows, t_pad), np.float32)
+    alpha[..., :t] = r.uniform(0, 1, (k, n_rows, t)) * (
+        r.uniform(size=(k, n_rows, t)) < 0.5)
+    if loss == "logistic":      # the open box
+        alpha[..., :t] = np.clip(alpha[..., :t], 1e-3, 1 - 1e-3)
+    vec = np.zeros((k, d, t_pad), np.float32)
+    vec[..., :t] = 0.5 * r.normal(size=(k, d, t))
+    sh = np.arange(k)[:, None]
+    xb = x[sh, idx]
+    at = np.arange(b)
+    same = (idx[:, :, None] == idx[:, None, :]) & (at[None, :] < at[:, None])
+    keep = at < b - 6
+    return dict(
+        m0=jnp.asarray(np.einsum("kbd,kdt->kbt", xb, vec)),
+        gram=jnp.asarray(np.einsum("kbd,kcd->kbc", xb, xb)),
+        a0=jnp.asarray(alpha[sh, idx].reshape((k, b) + tile)),
+        code=jnp.asarray(np.where(keep, cls[sh, idx], -1).astype(np.int32)),
+        q=jnp.asarray((xb * xb).sum(-1)),
+        prev=jnp.asarray(np.where(same, at, -1).max(2).astype(np.int32)))
+
+
+@pytest.mark.parametrize("sig_eff", [0.0, 2.0])
+@pytest.mark.parametrize("loss", ["hinge", "logistic"])
+@pytest.mark.parametrize("chain", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("sub", [8, 32, 72])
+def test_the_two_level_replay_is_the_one_level_one(sub, chain, loss, sig_eff):
+    """A block's B = 72 steps in sub-blocks of 8 (nine of them), of 32
+    (three, the last one's 24 steps past B padded) and of B (one chain),
+    through XLA's loop and the interpreted kernel, against ONE chain over
+    all B steps whose every margin sums every earlier step (the replay as
+    it was in one level): the same alpha' and the same c to float32
+    rounding of a margin's sum, reassociated — the later draw of a row
+    starts from what the earlier left whether the two share a sub-block
+    (``prev`` inside the chain) or not (gathered between chains), and its
+    margin carries the earlier c through the chain's sum or through the
+    cross product.  With sigma' = 0 (mini-batch CD: no Gram matrix, no
+    sum) the two-level replay IS one chain, and there is no cross
+    product to run."""
+    import functools
+
+    import jax
+
+    from cocoa_tpu.ops import block_lanes as bl
+
+    k, b, t = 2, 72, 17
+    blk = a_block(k, b, 24, t, loss)
+    if not sig_eff:
+        blk["gram"] = None
+    # lambda n = 0.5: half of the hinge steps end inside the box (at the
+    # jobs' lambda n = 20 every step of these unit rows ends on its edge)
+    consts = dict(sig_eff=sig_eff, classes=t, lam_n=0.5, coef_div=0.5,
+                  loss=loss, smoothing=1.0)
+    one_a, one_c = bl._replay_xla(
+        **dict(blk, m0=blk["m0"].reshape(blk["a0"].shape)), **consts)
+    run = bl._replay_xla if chain == "xla" else functools.partial(
+        bl._replay_pallas, interpret=True)
+    two = jax.jit(functools.partial(
+        bl._replay_two_level, run, sub=sub,
+        cross=bl.PRECISIONS[bl.CROSS_PRECISION], **consts))
+    # the cross product is in the program where there is something to owe
+    assert ("kbc,kct->kbt" in two.lower(**blk).as_text(debug_info=True)) == (
+        bool(sig_eff) and sub < b)
+    two_a, two_c = two(**blk)
+    assert two_a.shape == one_a.shape and two_c.shape == blk["m0"].shape
+    one_c = np.asarray(one_c).reshape(two_c.shape)
+    # the steps moved something, a row's later draw too, the padded ones
+    # nothing
+    assert min(np.abs(one_c[:, j]).max() for j in (5, 50, 19, 22, 30)) > 1e-3
+    assert not one_c[:, b - 6:].any() and not np.asarray(two_c)[:, b - 6:].any()
+    np.testing.assert_array_equal(np.asarray(two_a)[:, b - 6:],
+                                  np.asarray(blk["a0"])[:, b - 6:])
+    scale = np.abs(one_c).max()
+    assert np.abs(np.asarray(two_c) - one_c).max() <= 2e-6 * scale
+    assert np.abs(np.asarray(two_a) - np.asarray(one_a)).max() <= 2e-6
+    assert not np.asarray(two_c)[..., t:].any()
 
 
 @pytest.mark.parametrize("loss", ["hinge", "logistic"])
@@ -312,10 +424,12 @@ def test_a_set_it_does_not_hold_goes_to_the_lanes_from_its_shapes(wide):
         "block", "products", "xla", "lanes", 1, "lanes")
     assert path.lane_fill == 1000 / 1024
     # B from the fit: G twice in SMEM holds 256 rows; 16 even blocks of H
-    assert path.plan == BlockLanesPlan(block=256, blocks=16) == \
+    assert path.plan == BlockLanesPlan(block=256, blocks=16, sub=32) == \
         block_lanes_plan(4003, 1024)
-    assert path.plan.update == "highest"
-    assert "block of 256 rows a step (16 a round)" in path.describe()
+    assert (path.plan.update, path.plan.cross) == ("highest", "highest")
+    said = path.describe()
+    assert "block of 256 rows a step (16 a round) in sub-blocks of 32" in said
+    assert "what a sub-block is owed (highest)" in said
     # what keeps a dense multi-class set off the lanes: exact math, a
     # kernel the caller forced
     assert not class_state_on_lanes(ds, math="exact")
@@ -326,16 +440,23 @@ def test_a_set_it_does_not_hold_goes_to_the_lanes_from_its_shapes(wide):
         resolve_solver_path(ds, 4003, math="fast", block_size=128)
 
 
-@pytest.mark.parametrize("h, t_pad, block, blocks", [
-    (4003, 1024, 256, 16), (12656, 1024, 256, 50), (300, 1024, 152, 2),
-    (7, 1024, 8, 1), (4003, 8192, 192, 21)])
-def test_the_block_comes_from_the_fit(h, t_pad, block, blocks):
-    from cocoa_tpu.ops.block_lanes import block_fits, block_lanes_plan
+@pytest.mark.parametrize("h, t_pad, block, blocks, sub", [
+    (4003, 1024, 256, 16, 32), (12656, 1024, 256, 50, 32),
+    (300, 1024, 152, 2, 32), (7, 1024, 8, 1, 8), (4003, 8192, 192, 21, 32),
+    (40, 1024, 40, 1, 24), (30, 1024, 32, 1, 32), (100, 1024, 104, 1, 32)])
+def test_the_block_comes_from_the_fit(h, t_pad, block, blocks, sub):
+    from cocoa_tpu.ops.block_lanes import (SUB_STEPS, block_fits,
+                                           block_lanes_plan)
 
     plan = block_lanes_plan(h, t_pad)
-    assert (plan.block, plan.blocks) == (block, blocks)
+    assert (plan.block, plan.blocks, plan.sub) == (block, blocks, sub)
     assert block_fits(plan.block, t_pad, 4) and plan.block % 8 == 0
     assert plan.blocks * plan.block >= h > (plan.blocks - 1) * plan.block
+    # b from B alone: whole sublane groups, the fewest sub-blocks of at most
+    # SUB_STEPS steps, fewer than b padded steps behind the last
+    subs = -(-block // sub)
+    assert sub % 8 == 0 and sub <= SUB_STEPS
+    assert subs == -(-block // SUB_STEPS) and 0 <= subs * sub - block < sub
 
 
 # --- the certificate in row blocks ------------------------------------------
@@ -406,6 +527,7 @@ def test_the_same_job_through_the_cli(wide, tmp_path, capsys):
 
     from cocoa_tpu import cli
     from cocoa_tpu.data import load_libsvm, shard_dataset
+    from cocoa_tpu.telemetry import events as tele
 
     r = np.random.default_rng(7)
     n, d, t = 700, 12, 17
@@ -417,16 +539,32 @@ def test_the_same_job_through_the_cli(wide, tmp_path, capsys):
         for i in range(n):
             f.write(f"{labels[i]} " + " ".join(
                 f"{j + 1}:{x[i, j]:.6f}" for j in range(d)) + "\n")
-    out = str(tmp_path / "traj")
-    assert cli.main([
-        f"--trainFile={path}", f"--numFeatures={d}", "--numSplits=2",
-        f"--lambda={LAM}", "--localIterFrac=0.3", "--mesh=1",
-        "--justCoCoA=true", "--math=fast", "--deviceLoop", "--rng=permuted",
-        "--accel=off", "--numRounds=5", "--debugIter=5", f"--seed={SEED}",
-        "--classes=auto", "--layout=dense", f"--trajOut={out}"]) == 0
+    out, events = str(tmp_path / "traj"), str(tmp_path / "ev.jsonl")
+    try:
+        assert cli.main([
+            f"--trainFile={path}", f"--numFeatures={d}", "--numSplits=2",
+            f"--lambda={LAM}", "--localIterFrac=0.3", "--mesh=1",
+            "--justCoCoA=true", "--math=fast", "--deviceLoop",
+            "--rng=permuted", "--accel=off", "--numRounds=5", "--debugIter=5",
+            f"--seed={SEED}", "--classes=auto", "--layout=dense",
+            f"--trajOut={out}", f"--events={events}"]) == 0
+    finally:
+        tele.get_bus().reset()
     said = capsys.readouterr().out
     assert "local solver: block of" in said
     assert "the class axis on the lanes" in said
+    # H = 105 steps a shard: one block of 112 rows in four sub-blocks of 32
+    # (16 padded steps), on the console's line, in ``run_start`` and on the
+    # trajectory
+    assert ("block of 112 rows a step (1 a round) in sub-blocks of 32, "
+            "margins (high), Gram (highest), what a sub-block is owed "
+            "(highest) and update (highest) as matrix products") in said
+    with open(events) as f:
+        (start,) = [e for e in map(json.loads, f)
+                    if e["event"] == "run_start"]
+    plan = dict(block=112, blocks=1, sub=32, margins="high", gram="highest",
+                cross="highest", update="highest")
+    assert start["manifest"]["solver_path"]["plan"] == plan
     names = sorted(f for f in os.listdir(tmp_path) if f.startswith("traj."))
     assert len(names) == 2 and "+" in names[0], names    # CoCoA+, CoCoA
     with open(tmp_path / names[0]) as f:
@@ -436,6 +574,7 @@ def test_the_same_job_through_the_cli(wide, tmp_path, capsys):
                        layout="dense")
     _, (_, _, traj) = run_job(ds, rounds=5, rng="permuted")
     assert cli_gaps == traj.records[-1].class_gaps
+    assert traj.meta["solver_path"]["plan"] == plan
 
 
 # --- the cold account of a class job that resolves first --------------------
