@@ -2,7 +2,7 @@
 a Newton solve on one coordinate's 0-d values inside the chain kernel):
 device time of the local-solve scope per outer round over its K x H
 coordinate steps, in ns — what one step of the chain costs with its solve,
-to set beside a closed-form cell's (kddb's hinge step: ``sparse_solve_ms`` /
+to set beside a closed-form cell's (kddb's hinge step: ``local_solve_ms`` /
 (K x H)).  Nothing off the padded-CSR sequential Pallas path, or where the
 trace carries no program scope."""
 

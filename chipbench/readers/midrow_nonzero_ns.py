@@ -2,7 +2,7 @@
 scope per outer round over the nonzeros of the rows that round samples
 (K shards x H steps x the configuration's mean nonzeros a row), in ns —
 what the chain pays a sampled nonzero, fetch and step's fixed work
-included, to set beside the long-row cell's (``longrow_solve_ms`` over its
+included, to set beside the long-row cell's (``local_solve_ms`` over its
 6.5e7 sampled nonzeros).  Nothing where the configuration states no mean
 or the trace carries no program scope."""
 

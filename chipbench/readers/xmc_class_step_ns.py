@@ -2,7 +2,7 @@
 time of the local-solve scope per outer round over its K x H coordinate
 steps (the shards run one after another) and over T class models again, in
 ns — what one class's coordinate step costs once T of them share the
-sampled row's fetch, to set beside ``xmc_step_ns`` (the same over K x H
+sampled row's fetch, to set beside ``ctr_step_ns`` (the same over K x H
 alone), kddb's ns a step and mnist8m's ``ovr_class_step_ns``.  T is what
 the run's record says; nothing where it states no class axis on the
 lanes."""

@@ -20,15 +20,7 @@ HBM_PARTS = ["hbm_entry_gb", "hbm_rise_layout_gb", "hbm_rise_job_gb",
 NEW = HBM_PARTS + ["hbm_resident_gb", "hbm_program_temp_gb", "cold_layout_s",
                    "cold_build_s", "cold_job_s"]
 MB = 1_000_000
-# the cells the nine entries name: the three dense SVM cells they were
-# written for and, since PR 52, the four later cells that read the account
-# under no name of their own (url, criteo and amazoncat13k read it under
-# their prefixed twins: midrow_*, ctr_*, xmc_*)
-LISTED = ["epsilon.cocoa_plus", "epsilon.logistic", "imagenet.cocoa_plus.x4",
-          "kddb.cocoa_plus", "webspam.cocoa_plus",
-          "epsilon-lasso.prox_cocoa_plus", "mnist8m.ovr_cocoa_plus"]
-TWINS = {"url.cocoa_plus": "midrow_", "criteo.logistic": "ctr_",
-         "amazoncat13k.ovr_cocoa_plus": "xmc_"}
+CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 @pytest.fixture
@@ -142,24 +134,23 @@ def test_new_entries_resolve_to_the_one_reader(name):
     read, params = registry.layer_reader(BENCH, name)
     assert read.__module__.endswith("cold_account") and params == {
         "part": name}
-    assert metric["workloads"] == LISTED
+    # every cell reads the account under these nine names, one entry a
+    # part (PR 55): a later cell owes them for nothing
+    assert "workloads" not in metric
     assert metric["source"] == "program_counter"
     assert metric["moves"] == ("setup_s" if name.startswith("cold_")
                                else "peak_hbm_gb")
     assert metric["unit"] == ("s" if name.startswith("cold_") else "GB")
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
-def test_the_nine_are_owed_where_their_entries_say(cell):
-    """Every cell reads the nine once: under the entries' own names where
-    they list it, else under its prefixed twins (no later cell is held to
-    either: a new cell's PR says which)."""
-    owed = {m["name"] for m in registry.metrics_of(BENCH, "per_layer", cell)}
-    if cell in LISTED:
-        assert set(NEW) <= owed
-    elif cell in TWINS:
-        assert not owed & set(NEW)
-        assert {TWINS[cell] + name for name in NEW} <= owed
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_owes_the_nine_under_the_unprefixed_names(cell):
+    """One name a reading: no entry but these nine resolves to the cold
+    account, and each cell owes all nine."""
+    owed = registry.metrics_of(BENCH, "per_layer", cell)
+    account = [m["name"] for m in owed if registry.layer_reader(
+        BENCH, m["name"])[0].__module__.endswith("cold_account")]
+    assert sorted(account) == sorted(NEW)
 
 
 def test_one_account_a_run_its_final_reading_first(monkeypatch):

@@ -29,22 +29,23 @@ from owed import check_cell  # noqa: E402
 
 BENCH = registry.load_benchmark(ROOT)
 CELL = "criteo.logistic"
-SCOPES = ["ctr_solve_ms", "ctr_gather_share", "ctr_eval_share",
-          "ctr_jump_share", "ctr_unscoped_share", "ctr_solve_roofline",
+# no entry is this cell's alone since PR 55: the scope readings it shares
+# with the other sparse cells, one entry each; ``ctr_step_ns`` is read at
+# amazoncat13k too
+BLOCK = []
+SCOPES = ["local_solve_ms", "sparse_gather_share", "eval_share",
+          "accel_jump_share", "unscoped_share", "sparse_solve_roofline",
           "ctr_step_ns"]
 # peak_hbm_gb and setup_s by part: the dense cells' account
-# (chipbench/readers/cold_account.py), read in this cell under its own names
-ACCOUNTS = {"ctr_" + name: moves for moves, names in {
+# (chipbench/readers/cold_account.py), read in this cell under the
+# account's own entries
+ACCOUNTS = {name: moves for moves, names in {
     "peak_hbm_gb": ["hbm_entry_gb", "hbm_rise_layout_gb", "hbm_rise_job_gb",
                     "hbm_rise_after_gb", "hbm_resident_gb",
                     "hbm_program_temp_gb"],
     "setup_s": ["cold_layout_s", "cold_build_s", "cold_job_s"],
 }.items() for name in names}
-NEW_METRICS = SCOPES + list(ACCOUNTS)
-# the same names in the order BENCHMARK.json holds them: setup_s's parts
-# before peak_hbm_gb's, the step's cost last
-BLOCK = (SCOPES[:-1] + [a for a in ACCOUNTS if "_cold_" in a]
-         + [a for a in ACCOUNTS if "_hbm_" in a] + SCOPES[-1:])
+SHARED = SCOPES + list(ACCOUNTS)
 GENERIC = ["device_idle_share", "fixed_s", "launches_per_job", "round_ms",
            "top_op_share", "compile_s", "compiles_in_window"]
 SMALL = dict(name="small", n=3000, d=2048, num_splits=4,
@@ -133,7 +134,7 @@ def test_the_configuration_is_a_quarter_of_the_published_file():
     assert "1803.06333" in cfg["what"]
 
 
-@pytest.mark.parametrize("name", NEW_METRICS + GENERIC)
+@pytest.mark.parametrize("name", SHARED + GENERIC)
 def test_a_traced_line_of_the_cell_can_carry_the_metric(name):
     readers = {m["name"]: (m, read, params) for m, read, params
                in registry.layer_readers(BENCH, CELL)}
@@ -141,46 +142,45 @@ def test_a_traced_line_of_the_cell_can_carry_the_metric(name):
     assert callable(read)
     assert m["moves"] == ACCOUNTS.get(
         name, "setup_s" if name == "compile_s" else "job_s")
-    if name in NEW_METRICS:
-        assert CELL in m["workloads"]   # (a later cell may be appended)
+    if name in SHARED:
+        assert CELL in m.get("workloads", [CELL])
     else:
         assert "workloads" not in m
-    # each under the reader, parameters, layer, unit and source of its twin
-    # at kddb, url or mnist8m
-    twins = {"ctr_solve_ms": "sparse_solve_ms",
-             "ctr_gather_share": "sparse_gather_share",
-             "ctr_eval_share": "sparse_eval_share",
-             "ctr_jump_share": "midrow_jump_share",
-             "ctr_unscoped_share": "sparse_unscoped_share",
-             "ctr_solve_roofline": "sparse_solve_roofline",
-             "ctr_step_ns": "ovr_class_step_ns",
-             **{a: "midrow_" + a[len("ctr_"):] for a in ACCOUNTS}}
-    if name in twins:
-        (entry,) = [e for e in BENCH["per_layer"] if e["name"] == twins[name]]
+    module = read.__module__.rsplit("_readers_", 1)[-1]
+    want = {"local_solve_ms": ("scope_share", {"scope": "cocoa_local_solve",
+                                               "per_round": True}),
+            "sparse_gather_share": ("scope_share",
+                                    {"scope": "cocoa_sparse_gather"}),
+            "eval_share": ("scope_share", {"scope": "cocoa_eval"}),
+            "accel_jump_share": ("scope_share",
+                                 {"scope": "cocoa_accel_jump"}),
+            "unscoped_share": ("scope_share", {"scope": None}),
+            "sparse_solve_roofline": ("sparse_solve_roofline", {}),
+            "ctr_step_ns": ("ctr_step_ns", {}),
+            **{a: ("cold_account", {"part": a}) for a in ACCOUNTS}}
+    if name in want:
+        assert (module, params) == want[name]
+    if name == "ctr_step_ns":
+        # the unit, source and direction of mnist8m's step cost
+        (entry,) = [e for e in BENCH["per_layer"]
+                    if e["name"] == "ovr_class_step_ns"]
         assert (m["layer"], m["unit"], m["source"], m["better"]) == (
             entry["layer"], entry["unit"], entry["source"], entry["better"])
-        if name != "ctr_step_ns":
-            old_read, old_params = registry.layer_reader(BENCH, twins[name])
-            assert params == old_params
-            assert read.__module__ == old_read.__module__
 
 
 def test_the_cell_owes_these_metrics():
-    check_cell(BENCH, CELL, BLOCK, GENERIC)
-    assert len(NEW_METRICS) == 16
-    # and no older cell owes a new one
-    for older in ("epsilon.cocoa_plus", "epsilon.logistic",
-                  "imagenet.cocoa_plus.x4", "kddb.cocoa_plus",
-                  "webspam.cocoa_plus", "epsilon-lasso.prox_cocoa_plus",
-                  "mnist8m.ovr_cocoa_plus", "url.cocoa_plus"):
-        assert not set(NEW_METRICS) & {
-            m["name"] for m in registry.metrics_of(BENCH, "per_layer", older)}
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
+    assert len(SHARED) == 16
+    # ``ctr_step_ns`` is read where a chain's step is worth its own cost:
+    # here and at amazoncat13k, and nowhere older
+    (entry,) = [e for e in BENCH["per_layer"] if e["name"] == "ctr_step_ns"]
+    assert entry["workloads"][:2] == [CELL, "amazoncat13k.ovr_cocoa_plus"]
 
 
 def test_the_readers_read_nothing_where_there_is_nothing():
     """A tree without cold records, and a job off the padded-CSR Pallas
     path: nothing, and no error."""
-    read, params = registry.layer_reader(BENCH, "ctr_hbm_program_temp_gb")
+    read, params = registry.layer_reader(BENCH, "hbm_program_temp_gb")
     assert read(None, [], {"cold_account": None}, **params) is None
     assert read(None, [], {"cold_account": {"hbm_program_temp_gb": 0.33}},
                 **params) == 0.33

@@ -20,8 +20,11 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from chipbench import cost_model, reduce_trace, registry, run  # noqa: E402
+
+from owed import COLD  # noqa: E402
 
 TINY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
                     "tiny")
@@ -178,10 +181,11 @@ def test_what_an_entry_may_list(name, cell):
     - the cell exists, and the entry's reader resolves by the entry's name
       (``registry.layer_reader``) to a ``read(trace, jobs, cell, ...)``
       whose keyword arguments hold every one of its ``params``;
-    - entries that share a reader and its ``params`` read one thing under
-      several names, so they share ``unit``, ``better``, ``source`` and
-      ``layer`` (``moves`` may differ: one quantity can move two
-      end-to-end metrics in two kinds of cell);
+    - ONE NAME A READING (PR 55): no other entry resolves to the same
+      reader and the same ``params``.  A reader never sees the entry's
+      name, so two such entries would print one number twice; a cell that
+      reads what another cell reads stands in that entry's ``workloads``
+      and brings entries only for readers or parameters of its own;
     - an entry with NO ``workloads`` key is owed by every cell, those that
       later PRs add too: a traced line that lacks it there is refused.  The
       builder of a PR that adds such an entry shows a traced line of EVERY
@@ -195,12 +199,65 @@ def test_what_an_entry_may_list(name, cell):
     assert list(accepted)[:3] == ["trace", "jobs", "cell"]
     assert set(params) <= set(list(accepted)[3:]), \
         f"{name}: {sorted(params)} are not keyword arguments of its reader"
-    for twin in BENCH["per_layer"]:
-        if twin is not metric and _reader_of(twin["name"]) == \
-                _reader_of(name):
-            for key in ("unit", "better", "source", "layer"):
-                assert twin[key] == metric[key], \
-                    f"{name} and {twin['name']} read one thing, {key} differs"
+    twins = [m["name"] for m in BENCH["per_layer"]
+             if _reader_of(m["name"]) == _reader_of(name)]
+    assert twins == [name], \
+        f"{twins} read one thing ({_reader_of(name)}): merge them into " \
+        f"{twins[0]}, its workloads the union of theirs"
+
+
+# --- the merge of PR 55 lost no reading ---------------------------------------
+
+# the parent's 128 entries as PR 54 left them: name, the reader and the
+# parameters ``registry.layer_reader`` resolved the name to, the cells it
+# existed in (frozen: the 73 names that went are nowhere else)
+with open(os.path.join(os.path.dirname(TINY), "per_layer_pr54.json")) as _f:
+    PR54 = json.load(_f)
+# the pairs PR 55 added: ilsvrc1k's cold account, which had no room
+NEW_PAIRS = [(part, "ilsvrc1k.ovr_cocoa_plus") for part in COLD]
+
+
+def _was(old):
+    """An entry of the fixture as ``_reader_of`` would give it."""
+    return old["reader"], json.dumps(old["params"], sort_keys=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _owed_as(cell):
+    """``{(reader, params as text): entry's name}`` of what a cell owes."""
+    return {_reader_of(m["name"]): m["name"]
+            for m in registry.metrics_of(BENCH, "per_layer", cell)}
+
+
+@pytest.mark.parametrize("old", PR54, ids=lambda e: e["name"])
+def test_a_reading_of_pr54_is_still_owed_where_it_was(old):
+    """Every (old name, cell) pair of the parent is owed by that cell
+    today under an entry that resolves to the same reader and the same
+    parameters: the value is a function of (reader, params, cell, trace),
+    so the merge renamed readings and lost none."""
+    key = _was(old)
+    assert old["cells"]
+    for cell in old["cells"]:
+        assert key in _owed_as(cell), \
+            f"{cell} no longer reads {old['name']}'s {key}"
+    survivor = {_owed_as(cell)[key] for cell in old["cells"]}
+    assert len(survivor) == 1       # one name for it in every cell
+    (survivor,) = survivor
+    (first,) = [e for e in PR54 if e["name"] == survivor]   # no new name
+    assert _was(first) == key
+
+
+@pytest.mark.parametrize("name,cell", NEW_PAIRS,
+                         ids=[n for n, _ in NEW_PAIRS])
+def test_a_pair_pr55_added_is_the_cold_account_at_ilsvrc1k(name, cell):
+    """The nine (entry, cell) pairs the parent had under no name: the
+    parts of the cold account at the one cell that had no room for them.
+    (That no OTHER pair of the parent's names and cells was new is PR 55's
+    own count, 368 + 9 = 377: CHANGES.md; a later ``benchmark`` PR may
+    list an older cell in an older entry.)"""
+    key = ("cold_account", json.dumps({"part": name}))
+    assert _reader_of(name) == key and (name, cell) in LISTED
+    assert not [e for e in PR54 if cell in e["cells"] and _was(e) == key]
 
 
 def test_no_metric_file_without_an_entry():

@@ -1,7 +1,8 @@
 """The label-set cell's own pieces, on the CPU: the harness finds everything
-``amazoncat13k.ovr_cocoa_plus`` names; it owes at least the ``xmc_*``
-metrics, the seven generic ones and three end-to-end ones, each new metric
-over a reader the benchmark has; the configuration's arithmetic (H, the
+``amazoncat13k.ovr_cocoa_plus`` names; it owes at least its three
+``xmc_*`` entries, the shared readings it stands listed in, the seven
+generic ones and three end-to-end ones, each over a reader the benchmark
+has; the configuration's arithmetic (H, the
 steps a round, the bytes of alpha and W, the two floors); the stand-in
 generator makes what it says (unit rows, a bias column in every row, label
 sets whose frequencies follow the rank law), the same from the same seed,
@@ -27,7 +28,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from chipbench import cost_model_labels, registry  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 
-from owed import check_cell  # noqa: E402
+from owed import COLD, check_cell  # noqa: E402
 
 BENCH = registry.load_benchmark(ROOT)
 CELL = "amazoncat13k.ovr_cocoa_plus"
@@ -41,18 +42,18 @@ SMALL = dict(name="small", n=1024, d=300, mean_nnz=10.0, num_classes=24,
                                  planted_hot_cut=8))
 SMALL["lambda"] = 1e-2
 SEED = 4800000029               # past 2**31: the driver's are large
-SCOPED = {"xmc_solve_ms": ("cocoa_local_solve", True),
-          "xmc_gather_share": ("cocoa_sparse_gather", False),
-          "xmc_eval_share": ("cocoa_eval", False),
-          "xmc_dw_reduce_share": ("cocoa_dw_reduce", False),
-          "xmc_unscoped_share": (None, False)}
-COLD = ["cold_layout_s", "cold_build_s", "cold_job_s", "hbm_entry_gb",
-        "hbm_rise_layout_gb", "hbm_rise_job_gb", "hbm_rise_after_gb",
-        "hbm_resident_gb", "hbm_program_temp_gb"]
+# the scope readings the cell shares with other cells, one entry each
+# (PR 55): {entry: (scope, per round)}
+SCOPED = {"local_solve_ms": ("cocoa_local_solve", True),
+          "sparse_gather_share": ("cocoa_sparse_gather", False),
+          "eval_share": ("cocoa_eval", False),
+          "sparse_dw_reduce_share": ("cocoa_dw_reduce", False),
+          "unscoped_share": (None, False)}
+# the three entries only this cell reads (readers of its own)
 OWN_READERS = ["xmc_class_step_ns", "xmc_solve_roofline",
                "xmc_eval_roofline"]
-NEW_METRICS = (list(SCOPED) + ["xmc_step_ns"] + OWN_READERS
-               + ["xmc_" + part for part in COLD])
+BLOCK = OWN_READERS
+SHARED = list(SCOPED) + ["ctr_step_ns"] + COLD
 GENERIC = ["device_idle_share", "fixed_s", "launches_per_job", "round_ms",
            "top_op_share", "compile_s", "compiles_in_window"]
 
@@ -116,16 +117,18 @@ def test_the_harness_resolves_the_cell():
 
 
 def test_the_cell_owes_these_metrics():
-    check_cell(BENCH, CELL, NEW_METRICS, GENERIC)
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
     moves = {"cold": "setup_s", "hbm": "peak_hbm_gb"}
     for m in BENCH["per_layer"]:
-        if m["name"] in NEW_METRICS:
+        if m["name"] in BLOCK:
             assert m["workloads"] == [CELL]
-            assert m["moves"] == moves.get(m["name"].split("_")[1], "job_s")
+        if m["name"] in BLOCK + SHARED:
+            assert CELL in m.get("workloads", [CELL])
+            assert m["moves"] == moves.get(m["name"].split("_")[0], "job_s")
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
-def test_a_new_metric_names_a_reader_the_benchmark_has(name):
+@pytest.mark.parametrize("name", BLOCK + SHARED)
+def test_a_metric_of_the_cell_names_a_reader_the_benchmark_has(name):
     read, params = registry.layer_reader(BENCH, name)
     assert callable(read)
     module = read.__module__.rsplit("_readers_", 1)[-1]
@@ -133,12 +136,10 @@ def test_a_new_metric_names_a_reader_the_benchmark_has(name):
         scope, per_round = SCOPED[name]
         want = ("scope_share", {"scope": scope, **(
             {"per_round": True} if per_round else {})})
-    elif name == "xmc_step_ns":
-        want = ("ctr_step_ns", {})
-    elif name in OWN_READERS:
-        want = (name, {})
+    elif name in COLD:
+        want = ("cold_account", {"part": name})
     else:
-        want = ("cold_account", {"part": name[len("xmc_"):]})
+        want = (name, {})
     assert (module, params) == want
 
 

@@ -38,11 +38,15 @@ SMALL = dict(name="small", n=96, d=2048, num_splits=4, local_iter_frac=0.25,
              generator="dense_columns_planted",
              generator_args=dict(flip=0.02, support=12))
 SEED = 3000000019               # past 2**31: the driver's are large
-NEW_METRICS = ["prox_solve_ms", "prox_solve_roofline", "prox_eval_share",
-               "prox_dv_reduce_share", "prox_unscoped_share",
-               "prox_fixed_init_s", "prox_fixed_stage_s",
-               "prox_fixed_dispatch_s", "prox_fixed_fetch_s",
-               "prox_fixed_unspanned_s", "prox_round_roofline"]
+# no entry is this cell's alone since PR 55: what its PR entered under
+# ``prox_*`` names are the dense cells' readings, one entry each
+# (``sparse_dw_reduce_share``: the ``cocoa_dw_reduce`` scope, here the
+# summed dv)
+BLOCK = []
+SHARED = ["local_solve_ms", "local_solve_roofline", "eval_share",
+          "sparse_dw_reduce_share", "unscoped_share", "fixed_init_s",
+          "fixed_stage_s", "fixed_dispatch_s", "fixed_fetch_s",
+          "fixed_unspanned_s", "round_roofline"]
 GENERIC = ["device_idle_share", "fixed_s", "launches_per_job", "round_ms",
            "top_op_share", "compile_s", "compiles_in_window"]
 
@@ -146,48 +150,35 @@ def test_the_harness_resolves_the_cell():
     assert 1e-6 < check.R_TOL < 2e-3        # under one bf16 rounding of x
 
 
-@pytest.mark.parametrize("name", NEW_METRICS + GENERIC)
+@pytest.mark.parametrize("name", SHARED + GENERIC)
 def test_a_traced_line_of_the_cell_can_carry_the_metric(name):
     readers = {m["name"]: (m, read, params) for m, read, params
                in registry.layer_readers(BENCH, CELL)}
     m, read, params = readers[name]
     assert callable(read)
     assert m["moves"] == ("setup_s" if name == "compile_s" else "job_s")
-    if name in NEW_METRICS:
-        assert m["workloads"] == [CELL]
-        assert all(name not in [x["name"] for x in registry.metrics_of(
-            BENCH, "per_layer", w["name"])]
-            for w in BENCH["workloads"] if w["name"] != CELL)
-        # a data file that names a reader the benchmark already had
-        spec = registry.load_json(os.path.join(
-            BENCH["_dir"], "layer_metrics", name + ".json"))
-        assert os.path.exists(os.path.join(
-            BENCH["_dir"], "readers", spec["reader"] + ".py"))
+    if name in SHARED:
+        assert CELL in m.get("workloads", [CELL])
         spans = ["init_state", "wait_indices", "dispatch", "fetch"]
         assert params == {
-            "prox_solve_ms": {"scope": "cocoa_local_solve",
-                              "per_round": True},
-            "prox_solve_roofline": {"scope": "cocoa_local_solve"},
-            "prox_eval_share": {"scope": "cocoa_eval"},
-            "prox_dv_reduce_share": {"scope": "cocoa_dw_reduce"},
-            "prox_unscoped_share": {"scope": None},
-            "prox_fixed_init_s": {"span": spans[0]},
-            "prox_fixed_stage_s": {"span": spans[1]},
-            "prox_fixed_dispatch_s": {"span": spans[2]},
-            "prox_fixed_fetch_s": {"span": spans[3]},
-            "prox_fixed_unspanned_s": {"less": spans},
-            "prox_round_roofline": {}}[name]
-        # the dense cells' metric of the same reader, under their name
-        twin = name[len("prox_"):]
-        if twin.startswith("fixed_") or twin == "round_roofline":
-            there, its = registry.layer_reader(BENCH, twin)
-            assert (read.__module__, params) == (there.__module__, its)
+            "local_solve_ms": {"scope": "cocoa_local_solve",
+                               "per_round": True},
+            "local_solve_roofline": {"scope": "cocoa_local_solve"},
+            "eval_share": {"scope": "cocoa_eval"},
+            "sparse_dw_reduce_share": {"scope": "cocoa_dw_reduce"},
+            "unscoped_share": {"scope": None},
+            "fixed_init_s": {"span": spans[0]},
+            "fixed_stage_s": {"span": spans[1]},
+            "fixed_dispatch_s": {"span": spans[2]},
+            "fixed_fetch_s": {"span": spans[3]},
+            "fixed_unspanned_s": {"less": spans},
+            "round_roofline": {}}[name]
     else:
         assert "workloads" not in m
 
 
 def test_the_cell_owes_these_metrics():
-    check_cell(BENCH, CELL, NEW_METRICS, GENERIC)
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
 
 
 def test_the_configurations_arithmetic():

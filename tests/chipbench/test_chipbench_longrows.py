@@ -36,9 +36,11 @@ SMALL = dict(name="small", n=1024, d=65536, num_splits=4,
              generator_args=dict(max_nnz=3000, mean_nnz=300, sigma_nnz=0.6,
                                  flip=0.02, planted_hot_cut=256))
 SMALL["lambda"] = 1e-3
-NEW_METRICS = ["longrow_solve_ms", "longrow_gather_share",
-               "longrow_eval_share", "longrow_unscoped_share",
-               "longrow_solve_roofline"]
+# no entry is this cell's alone since PR 55: what its PR entered under
+# ``longrow_*`` names are readings other cells share, one entry each
+BLOCK = []
+SHARED = ["local_solve_ms", "sparse_gather_share", "eval_share",
+          "unscoped_share", "sparse_solve_roofline"]
 GENERIC = ["device_idle_share", "fixed_s", "launches_per_job", "round_ms",
            "top_op_share", "compile_s", "compiles_in_window"]
 ALIGN = 8
@@ -101,34 +103,31 @@ def test_the_harness_resolves_the_cell():
     assert 1e-5 < check.W_TOL < 2e-3        # under one bf16 rounding of w
 
 
-@pytest.mark.parametrize("name", NEW_METRICS + GENERIC)
+@pytest.mark.parametrize("name", SHARED + GENERIC)
 def test_a_traced_line_of_the_cell_can_carry_the_metric(name):
     """A traced run is refused if its line lacks a per-layer metric that
-    exists in the cell: each has a reader the harness finds, the new ones
-    exist in this cell only."""
+    exists in the cell: each has a reader the harness finds, the shared
+    ones under the one entry of their reading."""
     readers = {m["name"]: (m, read, params) for m, read, params
                in registry.layer_readers(BENCH, CELL)}
     m, read, params = readers[name]
     assert callable(read)
     assert m["moves"] == ("setup_s" if name == "compile_s" else "job_s")
-    if name in NEW_METRICS:
-        assert m["workloads"] == [CELL]
-        assert all(name not in [x["name"] for x in registry.metrics_of(
-            BENCH, "per_layer", w["name"])]
-            for w in BENCH["workloads"] if w["name"] != CELL)
+    if name in SHARED:
+        assert CELL in m.get("workloads", [CELL])
     else:
         assert "workloads" not in m
-    want = {"longrow_solve_ms": {"scope": "cocoa_local_solve",
-                                 "per_round": True},
-            "longrow_gather_share": {"scope": "cocoa_sparse_gather"},
-            "longrow_eval_share": {"scope": "cocoa_eval"},
-            "longrow_unscoped_share": {"scope": None}}
+    want = {"local_solve_ms": {"scope": "cocoa_local_solve",
+                               "per_round": True},
+            "sparse_gather_share": {"scope": "cocoa_sparse_gather"},
+            "eval_share": {"scope": "cocoa_eval"},
+            "unscoped_share": {"scope": None}}
     if name in want:
         assert params == want[name]
 
 
 def test_the_cell_owes_these_metrics():
-    check_cell(BENCH, CELL, NEW_METRICS, GENERIC)
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
     from chipbench.readers import round_roofline
     cell = registry.resolve_cell(BENCH, CELL)
     assert round_roofline.floor_of({
@@ -346,7 +345,7 @@ def test_roofline_bytes_equal_a_hand_count():
     model = cost_model_sparse.sparse_round(8, 4375, 3727)
     assert model["steps"] == 35000 and model["nonzeros"] == 130445000
     assert model["hbm_bytes"] == 130445000 * 20 + 35000 * 16 == 2609460000
-    read, params = registry.layer_reader(BENCH, "longrow_solve_roofline")
+    read, params = registry.layer_reader(BENCH, "sparse_solve_roofline")
     assert params == {} and "sparse_solve_roofline" in read.__module__
     floor_s = registry.load_module(BENCH, "readers",
                                    "sparse_solve_roofline").floor_s

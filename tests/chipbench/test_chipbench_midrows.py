@@ -36,13 +36,15 @@ SMALL = dict(name="small", n=768, d=32768, num_splits=4,
              generator_args=dict(max_nnz=512, mean_nnz=115.6, sigma_nnz=0.5,
                                  flip=0.02, planted_hot_cut=256))
 SMALL["lambda"] = 1e-3
-SCOPES = ["midrow_solve_ms", "midrow_eval_share", "midrow_jump_share",
-          "midrow_unscoped_share", "midrow_solve_roofline",
-          "midrow_eval_roofline", "midrow_nonzero_ns"]
+# the two entries only this cell reads (readers of its own)
+BLOCK = ["midrow_eval_roofline", "midrow_nonzero_ns"]
+# the scope readings it shares with other cells, one entry each (PR 55)
+SCOPES = ["local_solve_ms", "eval_share", "accel_jump_share",
+          "unscoped_share", "sparse_solve_roofline"]
 # peak_hbm_gb, setup_s and a job's fixed part by part: the dense cells'
 # accounts (chipbench/readers/cold_account.py, fixed_part.py), read in
-# this cell under its own names
-ACCOUNTS = {"midrow_" + name: moves for moves, names in {
+# this cell under the accounts' own entries
+ACCOUNTS = {name: moves for moves, names in {
     "peak_hbm_gb": ["hbm_entry_gb", "hbm_rise_layout_gb", "hbm_rise_job_gb",
                     "hbm_rise_after_gb", "hbm_resident_gb",
                     "hbm_program_temp_gb"],
@@ -50,7 +52,7 @@ ACCOUNTS = {"midrow_" + name: moves for moves, names in {
     "job_s": ["fixed_init_s", "fixed_stage_s", "fixed_dispatch_s",
               "fixed_fetch_s", "fixed_unspanned_s"],
 }.items() for name in names}
-NEW_METRICS = SCOPES + list(ACCOUNTS)
+SHARED = SCOPES + list(ACCOUNTS)
 GENERIC = ["device_idle_share", "fixed_s", "launches_per_job", "round_ms",
            "top_op_share", "compile_s", "compiles_in_window"]
 
@@ -128,7 +130,7 @@ def test_the_configuration_is_the_whole_published_set():
     assert "1611.02189" in cfg["what"]
 
 
-@pytest.mark.parametrize("name", NEW_METRICS + GENERIC)
+@pytest.mark.parametrize("name", BLOCK + SHARED + GENERIC)
 def test_a_traced_line_of_the_cell_can_carry_the_metric(name):
     readers = {m["name"]: (m, read, params) for m, read, params
                in registry.layer_readers(BENCH, CELL)}
@@ -136,45 +138,39 @@ def test_a_traced_line_of_the_cell_can_carry_the_metric(name):
     assert callable(read)
     assert m["moves"] == ACCOUNTS.get(
         name, "setup_s" if name == "compile_s" else "job_s")
-    if name in NEW_METRICS:
+    if name in BLOCK:
         assert m["workloads"] == [CELL]
+    elif name in SHARED:
+        assert CELL in m.get("workloads", [CELL])
     else:
         assert "workloads" not in m
-    want = {"midrow_solve_ms": {"scope": "cocoa_local_solve",
-                                "per_round": True},
-            "midrow_eval_share": {"scope": "cocoa_eval"},
-            "midrow_jump_share": {"scope": "cocoa_accel_jump"},
-            "midrow_unscoped_share": {"scope": None}}
+    want = {"local_solve_ms": {"scope": "cocoa_local_solve",
+                               "per_round": True},
+            "eval_share": {"scope": "cocoa_eval"},
+            "accel_jump_share": {"scope": "cocoa_accel_jump"},
+            "unscoped_share": {"scope": None}}
     if name in want:
         assert params == want[name]
-    if name == "midrow_solve_roofline":
-        assert "sparse_solve_roofline" in read.__module__
     if name in ACCOUNTS:
-        # the same reader and parameters as the dense cells' entry, and
-        # the same layer
-        old = name[len("midrow_"):]
-        (entry,) = [e for e in BENCH["per_layer"] if e["name"] == old]
-        old_read, old_params = registry.layer_reader(BENCH, old)
-        assert params == old_params
-        assert read.__module__ == old_read.__module__
-        assert (m["layer"], m["unit"], m["source"]) == (
-            entry["layer"], entry["unit"], entry["source"])
+        module = read.__module__.rsplit("_readers_", 1)[-1]
+        assert module == ("fixed_part" if name.startswith("fixed_")
+                          else "cold_account")
 
 
 def test_the_cell_owes_these_metrics():
-    check_cell(BENCH, CELL, NEW_METRICS, GENERIC)
-    # and no older cell owes a new one
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
+    # and no other cell owes an entry of its block
     for w in BENCH["workloads"]:
         if w["name"] != CELL:
-            assert not set(NEW_METRICS) & {m["name"] for m in
-                                           registry.metrics_of(
-                                               BENCH, "per_layer", w["name"])}
+            assert not set(BLOCK) & {m["name"] for m in
+                                     registry.metrics_of(
+                                         BENCH, "per_layer", w["name"])}
 
 
 def test_the_accounts_read_nothing_where_the_program_keeps_none():
     """A tree without cold records or ``cocoa/`` spans: nothing, and no
     error (the dense cells' readers, unedited)."""
-    read, params = registry.layer_reader(BENCH, "midrow_hbm_resident_gb")
+    read, params = registry.layer_reader(BENCH, "hbm_resident_gb")
     assert read(None, [], {"cold_account": None}, **params) is None
     assert read(None, [], {"cold_account": {"hbm_resident_gb": 3.4}},
                 **params) == 3.4

@@ -33,9 +33,11 @@ SMALL = dict(name="small", n=3000, d=2000, num_splits=4, local_iter_frac=0.1,
              generator_args=dict(max_nnz=16, mean_nnz=6.0, sigma_nnz=0.5,
                                  flip=0.02, planted_hot_cut=16))
 SMALL["lambda"] = 1e-3
-NEW_METRICS = ["sparse_solve_ms", "sparse_gather_share", "sparse_eval_share",
-               "sparse_unscoped_share", "sparse_dw_reduce_share",
-               "sparse_solve_roofline"]
+# no entry is this cell's alone since PR 55: what its PR entered under
+# ``sparse_*`` names are readings later cells share, one entry each
+BLOCK = []
+SHARED = ["local_solve_ms", "sparse_gather_share", "eval_share",
+          "unscoped_share", "sparse_dw_reduce_share", "sparse_solve_roofline"]
 
 
 @pytest.fixture(scope="module")
@@ -74,14 +76,14 @@ def test_the_harness_resolves_the_cell():
     assert callable(check.audit) and callable(check.job_problem)
     readers = {m["name"]: (read, params) for m, read, params
                in registry.layer_readers(BENCH, CELL)}
-    assert set(NEW_METRICS) <= set(readers)
+    assert set(SHARED) <= set(readers)
     assert readers["sparse_gather_share"][1] == {
         "scope": "cocoa_sparse_gather"}
-    assert readers["sparse_solve_ms"][1] == {"scope": "cocoa_local_solve",
-                                             "per_round": True}
-    for name in NEW_METRICS:        # the new metrics exist in this cell only
+    assert readers["local_solve_ms"][1] == {"scope": "cocoa_local_solve",
+                                            "per_round": True}
+    for name in SHARED:     # each under one entry, which lists the cell
         (m,) = [m for m in BENCH["per_layer"] if m["name"] == name]
-        assert m["workloads"] == [CELL]
+        assert CELL in m.get("workloads", [CELL])
 
 
 def test_a_traced_line_of_the_cell_can_carry_every_metric_it_owes():
@@ -95,7 +97,7 @@ def test_a_traced_line_of_the_cell_can_carry_every_metric_it_owes():
                  solver_path={**cell["job"]["expect_path"],
                               "layout": "sparse"})
     assert round_roofline.floor_of({**cell, **state}) is None
-    check_cell(BENCH, CELL, NEW_METRICS, GENERIC)
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
 
 
 def test_generator_makes_what_it_says(gen):

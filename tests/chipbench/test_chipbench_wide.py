@@ -1,7 +1,7 @@
 """The wide-class cell's own pieces, on the CPU: the harness finds
 everything ``ilsvrc1k.ovr_cocoa_plus`` names; it owes at least the six
-``wide_*`` metrics, the seven generic ones, the three shared entries it was
-appended to and three end-to-end ones; the configuration's arithmetic (H,
+``wide_*`` metrics, the seven generic ones, the twelve shared readings
+(the cold account's nine among them) and three end-to-end ones; the configuration's arithmetic (H,
 the block, the bytes of the rows and of alpha, the two floors); the
 stand-in generator makes what it says (unit rows, exchangeable classes, 2%
 relabelled), the same from the same seed, and asks the program before it
@@ -27,16 +27,10 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from chipbench import cost_model, cost_model_wide, registry  # noqa: E402
 from chipbench import run as harness  # noqa: E402
 
-import test_chipbench_additions  # noqa: E402
-from owed import GENERIC, check_cell  # noqa: E402
+from owed import COLD, GENERIC, check_cell  # noqa: E402
 
 BENCH = registry.load_benchmark(ROOT)
 CELL = "ilsvrc1k.ovr_cocoa_plus"
-# ``test_chipbench_additions.py`` (frozen) holds every cell of BENCHMARK.json
-# to its own test module's lists through a hand-written table of module
-# names; a cell appended after it registers here, where every collection of
-# the suite imports it (PERF.md section 7)
-test_chipbench_additions.MODULES.setdefault(CELL, __name__)
 SMALL = dict(name="small", n=3001, d=70, num_classes=24, num_splits=2,
              local_iter_frac=0.2, dtype="float32", loss="hinge",
              layout="dense", generator="dense_multiclass_wide",
@@ -49,10 +43,10 @@ SOLVE = {"wide_solve_ms": "ms", "wide_class_step_ns": "class_step_ns",
          "wide_solve_roofline": "roofline"}
 BLOCK = ["wide_solve_ms", "wide_products_share", "wide_replay_share",
          "wide_class_step_ns", "wide_solve_roofline", "wide_eval_roofline"]
-NEW_METRICS = BLOCK
-# the entries that listed other cells and had this one appended (the
-# benchmark holds 128 per-layer entries: PERF.md section 7)
-SHARED = ["eval_share", "unscoped_share", "indices_share"]
+# the readings the cell shares with every other cell, one entry each: the
+# two scope shares and, since PR 55, the nine parts of the cold account
+# (no ``workloads`` key), and ``indices_share``, whose list it stands in
+SHARED = ["eval_share", "unscoped_share", "indices_share"] + COLD
 
 
 @pytest.fixture(scope="module")
@@ -132,13 +126,12 @@ def test_the_harness_resolves_the_cell():
 
 
 def test_the_cell_owes_these_metrics():
-    check_cell(BENCH, CELL, BLOCK, GENERIC + SHARED)
+    check_cell(BENCH, CELL, BLOCK, GENERIC, SHARED)
     for m in BENCH["per_layer"]:
         if m["name"] in BLOCK:
             assert m["workloads"] == [CELL] and m["moves"] == "job_s"
-        if m["name"] in SHARED:     # appended: the last of the list
-            assert m["workloads"][-1] == CELL or CELL in m["workloads"]
-    assert len(BENCH["per_layer"]) <= 128
+        if m["name"] in SHARED:
+            assert CELL in m.get("workloads", [CELL])
 
 
 @pytest.mark.parametrize("name", BLOCK)
