@@ -1134,9 +1134,10 @@ def main(argv=None) -> int:
         elif extras["hotCols"] is not None or ed_spec != "false":
             why = ("--classes trains on dense rows (a class a sublane; the "
                    "classes on the lanes, a block of rows a step, where "
-                   "the T models outgrow the sublanes) or on a padded-CSR "
-                   "rectangle (--layout=sparse: the classes on the lanes, "
-                   "label sets too); the hot-column panel and the dense "
+                   "the T models outgrow the sublanes) or on sparse rows, "
+                   "a rectangle or a stream (--layout=sparse: the classes "
+                   "on the lanes, label sets too); the hot-column panel "
+                   "and the dense "
                    "eval twin carry no class axis: drop --hotCols / "
                    "--evalDense")
         elif extras["ingestCache"] or (extras["ingest"] or "auto") \
@@ -1496,11 +1497,11 @@ def main(argv=None) -> int:
                 # what runs: dense rows (one class id a row: a class a
                 # sublane of the dense kernel, or the classes on the lanes
                 # of the block solve where T models outgrow its state
-                # tiles) and a padded-CSR rectangle (label sets too, the
-                # classes on the lanes of the HBM-state chain).  What does
-                # not, yet: a mesh, --accel,
-                # checkpoints (refused above and in run_cocoa), rows kept
-                # as a stream, and T x d past one chip's HBM
+                # tiles) and sparse rows, a padded-CSR rectangle or a
+                # stream as the loader's rule says (label sets too, the
+                # classes on the lanes of the HBM-state chains).  What does
+                # not, yet: a mesh, --accel, checkpoints (refused above and
+                # in run_cocoa), and T x d past one chip's HBM
                 sets = data.classes.ndim == 2
                 lays = resolve_layout(data, cfg.layout, mesh)
                 if sets and lays == "dense":
@@ -1586,8 +1587,7 @@ def main(argv=None) -> int:
                                        eval_dense=eval_dense,
                                        hot_cols=hot_n,
                                        cache=train_handle,
-                                       rectangle=(not cfg.just_cocoa
-                                                  or data.num_classes > 1))
+                                       rectangle=not cfg.just_cocoa)
                     status = "off"
                     if train_handle is not None:
                         status = populate_whole(
@@ -1634,8 +1634,7 @@ def main(argv=None) -> int:
                                             eval_dense=eval_dense,
                                             hot_cols=hot_n,
                                             cache=test_handle,
-                                            rectangle=(not cfg.just_cocoa
-                                                  or data.num_classes > 1))
+                                            rectangle=not cfg.just_cocoa)
                     status = "off"
                     if test_handle is not None:
                         status = populate_whole(

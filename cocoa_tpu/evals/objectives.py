@@ -209,11 +209,14 @@ def _eval_metrics_lanes(w, alpha, shard_arrays, lam, n, mesh,
                         test_shard_arrays, test_n, loss, smoothing, classes):
     """:func:`_eval_metrics_classes` with the class axis on the lanes: w
     (d, R, 128), alpha (K, n_shard, R, 128), ``classes`` = T of the R·128
-    lanes models, over padded-CSR rows that carry label sets or over dense
-    rows that carry one class id each.  One blocked pass over the rows, a
-    shard after another, gives all T primal / dual / gap values — on
-    sparse rows ops/rows.class_loss_sums (a W row a nonzero, T margins a
-    row), on dense rows ops/rows.dense_class_loss_sums (a block's margins
+    lanes models, over sparse rows that carry label sets (a padded-CSR
+    rectangle or a stream) or over dense rows that carry one class id each.
+    One blocked pass over the rows, a shard after another, gives all T
+    primal / dual / gap values — on sparse rows ops/rows.class_loss_sums (a
+    W row a nonzero, T margins a row; on a stream
+    ops/pallas_longrows_lanes.stream_class_loss_sums, the blocks cut by
+    the rows' starts), on dense rows ops/rows.dense_class_loss_sums (a
+    block's margins
     one product at ``highest`` precision; a block is a whole shard until
     its temporaries would pass ops/rows.DENSE_CLASS_BLOCK_BYTES) — and the
     same vector comes back, ``[primal, gap, test_error, gap_0 ..
@@ -222,11 +225,12 @@ def _eval_metrics_lanes(w, alpha, shard_arrays, lam, n, mesh,
     a dense multi-class set it is :func:`_eval_metrics_classes`'s, the
     share of rows whose largest margin is not their own class's."""
     dense = "X" in shard_arrays
-    if (mesh is not None or classes < 1 or "sp_row_ptr" in shard_arrays
+    if (mesh is not None or classes < 1
             or not dense and "sp_indices" not in shard_arrays):
         raise ValueError("the class axis on the lanes is evaluated on "
-                         "dense rows or padded-CSR rows on one chip, T "
-                         "stated (docs/DESIGN.md, one-vs-rest)")
+                         "dense rows or sparse rows (a padded-CSR "
+                         "rectangle, a stream) on one chip, T stated "
+                         "(docs/DESIGN.md, one-vs-rest)")
     from cocoa_tpu.data.sharding import class_vector
     from cocoa_tpu.ops.rows import class_loss_sums, dense_class_loss_sums
 
@@ -236,8 +240,13 @@ def _eval_metrics_lanes(w, alpha, shard_arrays, lam, n, mesh,
             sums, wrong = dense_class_loss_sums(w, alpha, arrays, classes,
                                                 loss, smoothing)
             return class_vector(sums, classes), wrong
-        sums = class_vector(class_loss_sums(w, alpha, arrays, classes, loss,
-                                            smoothing), classes)   # (3, T)
+        sparse_sums = class_loss_sums
+        if "sp_row_ptr" in arrays:
+            # rows kept as a stream: the blocks are cut by the rows' starts
+            from cocoa_tpu.ops.pallas_longrows_lanes import (
+                stream_class_loss_sums as sparse_sums)
+        sums = class_vector(sparse_sums(w, alpha, arrays, classes, loss,
+                                        smoothing), classes)       # (3, T)
         return sums, sums[2].sum()
 
     sums, _ = shard_sums(shard_arrays, alpha)

@@ -427,9 +427,16 @@ def sparse_lanes_round_fori(w, alpha, shards: dict, idxs, lam: float, n: int,
                             sigma: float = 1.0, scaling: float = 1.0,
                             loss: str = "hinge", smoothing: float = 1.0):
     """:func:`pallas_sparse_lanes_round` in plain XLA: the same chain, a
-    row gather and a scatter-add a step, one shard after another."""
-    sp_indices, sp_values = shards["sp_indices"], shards["sp_values"]
-    k = sp_indices.shape[0]
+    row gather and a scatter-add a step, one shard after another.  A row
+    comes through ``ops/rows.get_row``: a rectangle's (W,) slots as they
+    are, or a (W,) window of rows kept as a stream (the plain twin of
+    ops/pallas_longrows_lanes.py's chain too)."""
+    from cocoa_tpu.ops.rows import get_row
+
+    k = shards["sp_indices"].shape[0]
+    stored = {f: shards[f] for f in ("sp_indices", "sp_values", "sp_row_ptr",
+                                     "sp_row_len", "sp_row_iota")
+              if f in shards}
     h, tile, dtype = idxs.shape[1], w.shape[1:], w.dtype
     sig_eff, qii_factor = mode_factors(mode, sigma)
     lam_n, coef_div = lam * n, coef_divisor(mode, lam * n)
@@ -442,14 +449,14 @@ def sparse_lanes_round_fori(w, alpha, shards: dict, idxs, lam: float, n: int,
         shard, idx = xs
         take = lambda a: lax.dynamic_index_in_dim(  # noqa: E731
             a, shard, 0, keepdims=False)
-        cols_k, vals_k, ids_k, q_k = (
-            take(sp_indices), take(sp_values), take(ids_all),
-            take(shards["sq_norms"]) * qii_factor)
+        rows_k = jax.tree.map(take, stored)
+        ids_k, q_k = take(ids_all), take(shards["sq_norms"]) * qii_factor
 
         def step(j, state):
             alpha_k, dwk = state
             i = idx[j]
-            c, v = cols_k[i], vals_k[i].astype(dtype)
+            row = get_row(rows_k, i)
+            c, v = row.idx, row.val.astype(dtype)
             rows = w[c] if mode == "frozen" else w[c] + sig_eff * dwk[c]
             z = (rows * v[:, None, None]).sum(0)
             y = class_signs(ids_k[i], classes, dtype)

@@ -293,8 +293,17 @@ class SolverPath:
     (ops/pallas_sparse_lanes.py: ``local_ids`` ``direct``, one call a
     shard's round, ``ids_per_segment`` the [w | dw] rows VMEM holds at a
     time, (2 T_pad 4) B each; ``lane_fill`` T / T_pad; ``slots_walked``
-    in the rectangle's 8-slot groups).  The rule is the layout's: dense
-    rows take the sublanes, padded-CSR rows the lanes.  ``class_state``
+    in the rectangle's 8-slot groups); on rows kept as a stream
+    (``storage`` ``stream``) the same state, the chain of
+    ops/pallas_longrows_lanes.py walking a sampled row's slots out of the
+    stream by the DMA ring across rows and moving a row of W and of dw_k a
+    nonzero through a VMEM ring — ``step_solve`` ``lanes``, ``margin``
+    ``split`` under every mode (W and dw_k are two arrays), ``plan`` its
+    StreamLanesPlan (``ring`` slots, ``row_block`` steps an SMEM block,
+    ``steps`` a shard's round padded to whole blocks), ``pass_slot_share``
+    the share of the stored slots the certificate's whole reads touch
+    (pallas_longrows_lanes.pass_slot_share).  The rule is the layout's:
+    dense rows take the sublanes, sparse rows the lanes.  ``class_state``
     (the dense class kernel; None anywhere else): the form alpha has
     between the rounds of a chunk — ``tiles``: the kernel's own state
     tiles (K, n_blocks, R, 128), packed from the loop's alpha (T, K,
@@ -379,6 +388,9 @@ class SolverPath:
                 path, objective="elastic_net" if smoothing > 0 else "lasso")
         if not (path.pallas and path.storage == "stream"):
             return path
+        if path.class_axis == "lanes":
+            # W and dw_k are two arrays in HBM under every mode
+            return dataclasses.replace(path, margin="split")
         from cocoa_tpu.ops.pallas_longrows import margin_form
 
         return dataclasses.replace(path, margin=margin_form(mode))
@@ -476,6 +488,9 @@ class SolverPath:
                          else f" (chunk fill {self.chunk_fill:.3f})"))
             if self.margin:
                 solve += f", margin {self.margin}"
+            if self.class_axis == "lanes" and self.plan is not None:
+                solve += (f", a nonzero's W and dw rows fetched by the "
+                          f"chain through a ring of {self.plan.ring}")
         return (f"{what}, {self.layout} layout{rows}{solve}, on "
                 f"{self.platform} x "
                 f"{self.devices} ({self.shards_per_device} shard(s) per "
@@ -486,6 +501,17 @@ def _pass_slot_share(ds: ShardedDataset, together: int) -> float:
     """:attr:`SolverPath.pass_slot_share` of a sparse dataset, counted once
     from the row lengths it carries (``_row_len_cache``: attached by the
     ordering or by an earlier run) and kept on it beside them."""
+    if (ds.sp_row_ptr is not None and getattr(ds, "num_classes", 1) > 1
+            and isinstance(ds.sp_row_ptr, jax.Array)):
+        # a stream with a class axis: the certificate's pass reads each
+        # block of rows' run of the stream in whole reads
+        cached = getattr(ds, "_pass_slot_share_cache", None)
+        if cached is None:
+            from cocoa_tpu.ops.pallas_longrows_lanes import pass_slot_share
+
+            cached = ds._pass_slot_share_cache = (0, pass_slot_share(
+                ds.sp_row_ptr, ds.sp_row_len, int(ds.sp_indices.shape[1])))
+        return cached[1]
     if ds.sp_row_ptr is not None or getattr(ds, "row_order", None) is None:
         return 1.0              # (rows as built: the passes see no lengths)
     width = int(ds.sp_indices.shape[-1])
@@ -550,6 +576,12 @@ def _hbm_plan(ds: ShardedDataset, local_iters: int):
     (ops/pallas_sparse_lanes.lanes_plan)."""
     width = int(ds.sp_indices.shape[-1])
     itemsize = jnp.dtype(ds.labels.dtype).itemsize
+    if getattr(ds, "num_classes", 1) > 1 and ds.sp_row_ptr is not None:
+        from cocoa_tpu.ops.pallas_longrows_lanes import stream_lanes_plan
+
+        return stream_lanes_plan(local_iters, itemsize,
+                                 class_pad(ds.num_classes),
+                                 getattr(ds, "label_slots", None) or 1)
     if getattr(ds, "num_classes", 1) > 1:
         from cocoa_tpu.ops.pallas_sparse_lanes import lanes_plan
 
@@ -567,10 +599,12 @@ def class_state_on_lanes(ds: ShardedDataset, mesh=None, *,
                          block_size: int = 0) -> bool:
     """Whether a job on ``ds`` keeps its T class models on the LANES: W
     (d, R, 128), alpha (K, n_shard, R, 128) (data/sharding.class_tile_shape).
-    Read off what the dataset is, never off a flag: padded-CSR rows always
-    (ops/pallas_sparse_lanes.py); dense rows where the sublane kernel's
-    state tiles do not fit (ops/pallas_sdca.classes_fit says no) and the
-    block solve can take them (ops/block_lanes.py: fast math, float32, one
+    Read off what the dataset is, never off a flag: sparse rows always — a
+    padded-CSR rectangle (ops/pallas_sparse_lanes.py) and rows kept as a
+    stream (ops/pallas_longrows_lanes.py) alike; dense rows where the
+    sublane kernel's state tiles do not fit (ops/pallas_sdca.classes_fit
+    says no) and the block solve can take them (ops/block_lanes.py: fast
+    math, float32, one
     class id a row, one chip, no kernel forced by the caller).  Everything
     else — T = 1, and every dense set the sublane kernel holds — keeps w
     (T, d), alpha (T, K, n_shard).  :func:`_start_state` shapes the leaves
@@ -612,15 +646,16 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
         # what carries the class axis today, said once where the path is
         # decided: dense rows (a class a sublane of the sequential solve
         # where the state tiles fit VMEM, else the classes on the lanes of
-        # the block solve) or a padded-CSR rectangle (the classes on the
-        # lanes of the sequential solve), one chip
-        if ds.layout != "dense" and (ds.sp_row_ptr is not None or ds.n_hot):
+        # the block solve) or sparse rows, a padded-CSR rectangle or a
+        # stream (the classes on the lanes of the sequential solve), one
+        # chip
+        if ds.layout != "dense" and ds.n_hot:
             raise ValueError(
                 f"a set of {classes} classes trains one-vs-rest on dense "
-                f"rows or on a padded-CSR rectangle: no kernel carries the "
-                f"class axis on "
-                f"{'rows kept as a stream' if ds.sp_row_ptr is not None else 'the hybrid layout (--hotCols)'}"
-                f" yet (drop --classes to train class 1 against the rest)")
+                f"rows, on a padded-CSR rectangle or on rows kept as a "
+                f"stream: no kernel carries the class axis on the hybrid "
+                f"layout (--hotCols) yet (drop --hotCols, or drop --classes "
+                f"to train class 1 against the rest)")
         label_slots = getattr(ds, "label_slots", None) or 1
         if ds.layout == "dense" and label_slots > 1:
             raise ValueError(
@@ -661,11 +696,22 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                              "rectangles; rows kept as a stream "
                              "(data/sharding.stream_suits) run the "
                              "sequential solve: block_size=0")
-        vmem_fits = longrows_fits(ds.num_features, itemsize)
-        if not vmem_fits:
-            refused = (f"rows kept as a stream need one float32 d-vector in "
-                       f"VMEM: d = {ds.num_features} x {itemsize} B against "
-                       f"{VEC_VMEM_BUDGET} B")
+        if classes > 1:
+            # (T models ride the lanes of the stream's own HBM-state chain,
+            # ops/pallas_longrows_lanes.py: no d-vector is held in VMEM)
+            from cocoa_tpu.ops.pallas_longrows_lanes import stream_lanes_fits
+
+            hbm_state = stream_lanes_fits(local_iters, itemsize,
+                                          class_pad(classes), label_slots)
+            if not hbm_state:
+                refused = (f"a ring of [w | dw] rows of {classes} classes "
+                           f"outgrows the stream chain's VMEM")
+        else:
+            vmem_fits = longrows_fits(ds.num_features, itemsize)
+            if not vmem_fits:
+                refused = (f"rows kept as a stream need one float32 "
+                           f"d-vector in VMEM: d = {ds.num_features} x "
+                           f"{itemsize} B against {VEC_VMEM_BUDGET} B")
     elif sparse:
         # which sequential sparse kernel could hold the set: the
         # VMEM-resident one (the SMEM feature-index table and the
@@ -833,7 +879,10 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
                 placement.update(
                     class_tiles=class_pad(classes) // CLASS_TILE,
                     label_slots=label_slots)
-        if pallas and hbm_state:
+        if pallas and hbm_state and stream:
+            # the stream's lane chain: its own record says what a round runs
+            placement.update(plan=_hbm_plan(ds, local_iters))
+        elif pallas and hbm_state:
             plan = _hbm_plan(ds, local_iters)
             placement.update(
                 local_ids="direct" if plan.direct else "sorted",
@@ -871,8 +920,9 @@ def resolve_solver_path(ds: ShardedDataset, local_iters: int, mesh=None, *,
             state="vmem" if pallas and not hbm_state else "hbm",
             step_solve=("scalar" if not pallas
                         or sparse and not (hbm_state or stream)
-                        else "lanes" if not sparse and (
-                            classes > 1 or losses.step_is_iterative(loss))
+                        else "lanes" if (stream and classes > 1
+                                         or not sparse and (
+                            classes > 1 or losses.step_is_iterative(loss)))
                         else "vector"),
             refused="" if pallas else refused,
             **placement)
@@ -1100,11 +1150,20 @@ def _class_round(params: Params, mode: str, scaling: float, sigma: float,
     from cocoa_tpu.data.sharding import class_labels
 
     def per_round_lanes(w, alpha, idxs_kh, shards):
-        from cocoa_tpu.ops import pallas_sparse_lanes as lanes
-
         common = dict(lam=params.lam, n=params.n, classes=classes,
                       mode=mode, sigma=sigma, scaling=scaling,
                       loss=params.loss, smoothing=params.smoothing)
+        if pallas and "sp_row_ptr" in shards:
+            # rows kept as a stream: the chain walks a row's nonzeros out
+            # of the stream itself
+            from cocoa_tpu.ops.pallas_longrows_lanes import (
+                pallas_stream_lanes_round)
+
+            return pallas_stream_lanes_round(
+                w, alpha, shards, idxs_kh, plan=lanes_plan,
+                interpret=interpret, **common)
+        from cocoa_tpu.ops import pallas_sparse_lanes as lanes
+
         if pallas:
             return lanes.pallas_sparse_lanes_round(
                 w, alpha, shards, idxs_kh, plan=lanes_plan,
@@ -1861,8 +1920,9 @@ def run_sdca_family(
         params.num_rounds, debug.debug_iter, start_round,
         gap_target, ds.layout, str(dtype), classes,
         # (T class models over dense rows: a class a sublane or the classes
-        # on the lanes is another state and another loop)
-        path.class_axis,
+        # on the lanes is another state and another loop; sparse rows as a
+        # rectangle or as a stream another chain on another plan)
+        path.class_axis, path.storage,
     )
     state, traj = base.drive_device_paths(
         alg_name, params, debug, state0, chunk_kernel, chunk_fn,
@@ -1930,9 +1990,13 @@ def run_cocoa(
     T = 1,000 at any size) the job keeps them on the lanes, as on sparse
     rows — returns (W (d, R, 128), alpha (K, n_shard, R, 128), Trajectory)
     — and solves a block of rows a step (ops/block_lanes.py): chosen from
-    the shapes, never by a flag.  Not carried yet, and refused by name:
-    rows kept as a stream, a mesh, ``--accel``, the sigma' schedule and warm
-    start, checkpoints, ``--blockSize`` (the T = 1 block kernels' tile).
+    the shapes, never by a flag.  Sparse rows kept as a stream
+    (data/sharding.stream_suits: long, uneven rows) carry the class axis on
+    the lanes too, their chain walking the stream itself
+    (ops/pallas_longrows_lanes.py).  Not carried yet, and refused by name:
+    the hybrid layout, a mesh, ``--accel``, the sigma' schedule and warm
+    start, a state handed in, checkpoints, ``--blockSize`` (the T = 1 block
+    kernels' tile).
 
     ``params.sigma="auto"`` (flag ``--sigma=auto``) exploits the measured
     σ′ trade-off (the aggressive σ′ = K·γ/2 HALVES

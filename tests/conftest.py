@@ -98,31 +98,3 @@ def tiny_data():
         values=np.concatenate(values),
         num_features=d,
     )
-
-
-def pytest_collection_modifyitems(items):
-    """``tests/chipbench/test_chipbench_jobs.py`` hands every job's entry
-    ROW shards of a tiny LIBSVM file and asks that every flag on the job's
-    line be a keyword argument of its entry.  A prox job's entry takes
-    COLUMN shards that carry their target, refuses row shards, and has no
-    keyword for ``--objective`` (its name says what it runs).  That file is
-    the accepted benchmark's and no PR's but a benchmark PR's to edit, so
-    its case for such a job is an expected failure, by exactly that
-    refusal, until a job can say how its tiny dataset is built;
-    ``test_chipbench_lasso.py::test_job_is_what_its_flag_line_runs_on_column_shards``
-    holds the job's file against its flag line meanwhile."""
-    import json
-
-    jobs = os.path.join(os.path.dirname(_REPO_DATA), "chipbench", "jobs")
-    for item in items:
-        if getattr(item, "originalname", "") != \
-                "test_job_is_what_its_flag_line_runs":
-            continue
-        with open(os.path.join(
-                jobs, item.callspec.params["name"] + ".json")) as f:
-            entry = json.load(f)["entry"]
-        if entry == "run_prox_cocoa":
-            item.add_marker(pytest.mark.xfail(
-                raises=ValueError, strict=True,
-                reason="the jobs test feeds row shards; the prox entry "
-                       "takes column shards with their target"))

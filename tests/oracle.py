@@ -259,3 +259,75 @@ def ovr_cocoa_plus(X_shards, cls_shards, tables, lam, n, t_count,
             dW += dws
         W = W + dtype(gamma) * dW
     return W, alphas
+
+
+def labelset_cocoa_plus(indptr, indices, values, label_ids, bounds, tables,
+                        lam, n, t_count, d, loss="hinge", gamma=1.0):
+    """One-vs-rest CoCoA+ over LABEL SETS from CSR rows, in float64: T
+    chains side by side, class t against the rest, y_ti = +1 where t is
+    among row i's ids (``label_ids`` (n, L), -1 fills a short set).  Shard
+    s holds rows [bounds[s], bounds[s + 1]); ``tables`` (rounds, K, H) are
+    the job's sampled local row ids, ``d`` the columns.  A step is ``ovr_local_sdca``'s over a
+    sparse row: a dot and an axpy over the row's own nonzeros.  Returns (W
+    (d, T), [alpha_k (n_k, T)])."""
+    k = len(bounds) - 1
+    lam_n, sigma = lam * n, k * gamma
+    W = np.zeros((d, t_count))
+    alphas = [np.zeros((bounds[s + 1] - bounds[s], t_count))
+              for s in range(k)]
+    classes = np.arange(t_count)
+    for table in tables:
+        dW = np.zeros_like(W)
+        for s in range(k):
+            dws = np.zeros_like(W)
+            for idx in table[s]:
+                i = bounds[s] + idx
+                c = indices[indptr[i]:indptr[i + 1]]
+                x = values[indptr[i]:indptr[i + 1]].astype(np.float64)
+                y = np.where((label_ids[i][:, None] == classes).any(0), 1.0,
+                             -1.0)
+                a = alphas[s][idx]
+                z = y * (x @ W[c] + sigma * (x @ dws[c]))
+                qii = float(x @ x) * sigma
+                if loss == "hinge":
+                    grad = (z - 1) * lam_n
+                    proj = np.where(a <= 0, np.minimum(grad, 0),
+                                    np.where(a >= 1, np.maximum(grad, 0),
+                                             grad))
+                    step = (np.clip(a - grad / qii, 0, 1) if qii != 0
+                            else np.ones_like(a))
+                    new_a = np.where(proj != 0, step, a)
+                elif loss == "logistic":
+                    new_a = _logistic_step(a, z, qii, lam_n)
+                else:
+                    raise ValueError(loss)
+                dws[c] += np.outer(x, y * (new_a - a) / lam_n)
+                alphas[s][idx] = new_a
+            dW += dws
+        W = W + gamma * dW
+    return W, alphas
+
+
+def labelset_gaps(indptr, indices, values, label_ids, W, alpha, lam,
+                  loss="hinge"):
+    """Every class's (primal, dual, gap) at (W (d, T), alpha (n, T)) over
+    CSR rows that carry label sets, in float64."""
+    n, t_count = alpha.shape
+    classes = np.arange(t_count)
+    loss_sum, dual_sum = np.zeros(t_count), np.zeros(t_count)
+    for i in range(n):
+        c = indices[indptr[i]:indptr[i + 1]]
+        x = values[indptr[i]:indptr[i + 1]].astype(np.float64)
+        y = np.where((label_ids[i][:, None] == classes).any(0), 1.0, -1.0)
+        z, a = y * (x @ W[c]), alpha[i]
+        if loss == "hinge":
+            loss_sum += np.maximum(0.0, 1.0 - z)
+            dual_sum += a
+        else:
+            loss_sum += np.logaddexp(0.0, -z)
+            ac = np.clip(a, 1e-300, 1.0)
+            bc = np.clip(1.0 - a, 1e-300, 1.0)
+            dual_sum += -(a * np.log(ac) + (1 - a) * np.log(bc))
+    reg = 0.5 * lam * (W * W).sum(0)
+    primal, dual = loss_sum / n + reg, dual_sum / n - reg
+    return primal, dual, primal - dual

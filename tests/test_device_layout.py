@@ -842,6 +842,96 @@ def test_amazoncat_job_fits_one_chip_and_copies_no_state(monkeypatch,
     assert copies == []
 
 
+DELICIOUS = dict(n=196606, d=782585, k=8, pieces=65536, longest=8192,
+                 classes=1000, slots=8, frac=0.1, lam=1e-4)
+
+
+@pytest.mark.parametrize("loss", ["hinge", "logistic"])
+def test_delicious_job_fits_one_chip_and_copies_no_state(monkeypatch,
+                                                        one_chip, loss):
+    """The whole device loop of a one-vs-rest job over label sets on rows
+    kept as a STREAM at delicious200k's shapes (shapes only: 4.5 GB) —
+    rounds on the chain that walks the stream with the class axis on the
+    lanes, the certificate in blocks cut by the rows' starts — compiled for
+    one described v5e, Mosaic included, under the hinge and under logistic
+    (whose Newton steps run on the (R, 128) tiles).  W (d, 8, 128) is 3.21
+    GB here, so every W-sized temporary counts, and there are TWO: dW of
+    the shard in hand and of the round, 6.41 GB, which is what the
+    compiler's own peak holds beside the arguments (``peak_memory_in_bytes``
+    less the arguments: the buffer assignment's ``preallocated-temp``,
+    6,413,042,176 B, as its dump gives it; a job ran beside a ballast that
+    leaves room for two and not for three, PERF.md section 6, PR 57).
+    ``temp_size_in_bytes`` reads a THIRD W-sized array, 9.62 GB, that no
+    buffer is assigned to: it counts the donated W the loops carry once
+    more (a two-loop toy program with a donated carry reads the same one
+    array too many), so it is held here only to what it read when this was
+    written; and nothing copies W, alpha or the stream."""
+    import jax
+    import jax.numpy as jnp
+
+    from cocoa_tpu.config import DebugParams, Params
+    from cocoa_tpu.data.sharding import (ShardedDataset, pad_rows,
+                                         split_sizes)
+    from cocoa_tpu.solvers import base, run_cocoa
+
+    shape = DELICIOUS
+    with jax.enable_x64(False):
+        got = _arm_capture(monkeypatch)
+        k, pieces = shape["k"], shape["pieces"]
+        sizes = split_sizes(shape["n"], k)
+        n_shard = pad_rows(int(sizes.max()))
+        here = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+
+        def sds(dims, dt):
+            return jax.ShapeDtypeStruct(dims, dt, sharding=here)
+
+        rows = sds((k, n_shard), jnp.float32)
+        irows = sds((k, n_shard), jnp.int32)
+        ds = ShardedDataset(
+            layout="sparse", n=shape["n"], num_features=shape["d"],
+            counts=sizes.astype(np.int64), labels=rows, mask=rows,
+            sq_norms=rows, sp_indices=sds((k, pieces, 128), jnp.int32),
+            sp_values=sds((k, pieces, 128), jnp.float32),
+            sp_row_ptr=irows, sp_row_len=irows,
+            sp_row_iota=sds((k, shape["longest"]), jnp.int32),
+            classes=sds((k, n_shard, shape["slots"]), jnp.int32),
+            num_classes=shape["classes"])
+        h = int(shape["frac"] * shape["n"] / k)
+        with pytest.raises(_Captured):
+            run_cocoa(ds, Params(n=ds.n, num_rounds=300, local_iters=h,
+                                 lam=shape["lam"], loss=loss),
+                      DebugParams(debug_iter=5, seed=0), plus=True,
+                      quiet=True, math="fast", device_loop=True,
+                      rng="permuted", gap_target=1e-2, accel="off")
+        base._DEVICE_RUNS.clear()
+        path = got["path"]
+        assert (path.kernel, path.state, path.storage, path.class_axis,
+                path.class_tiles, path.label_slots, path.step_solve) == (
+            "pallas", "hbm", "stream", "lanes", 1, 8, "lanes")
+        assert (path.plan.ring, path.plan.steps, path.plan.row_block, h,
+                n_shard) == (512, 2560, 256, 2457, 24576)
+        compiled = got["run"].lower(*_on_chip(got["args"],
+                                              one_chip)).compile()
+    stats = compiled.memory_analysis()
+    w_bytes = shape["d"] * 1024 * 4
+    assert 4.5e9 < stats.argument_size_in_bytes < 4.6e9    # the deployment
+    # two W-sized temporaries and 0.1 GB beside the arguments, not three
+    held = stats.peak_memory_in_bytes - stats.argument_size_in_bytes
+    assert 2 * w_bytes <= held <= 2 * w_bytes + 0.1e9, (
+        stats.peak_memory_in_bytes, stats.argument_size_in_bytes)
+    assert stats.temp_size_in_bytes <= 3 * w_bytes + 0.1e9, (
+        stats.temp_size_in_bytes)
+    hlo = compiled.as_text()
+    assert "pallas_longrows_lanes_round" in hlo
+    large = re.compile(rf"\[{shape['d']},8,128\]|"
+                       rf"\[{k},{n_shard},(8,128|1024)\]|"
+                       rf"\[{k},{pieces},128\]|\[{k * pieces},1,128\]|"
+                       rf"\[{k},{n_shard},{shape['slots']}\]")
+    copies = [line.strip()[:160] for line in hlo.splitlines()
+              if " copy(" in line and large.search(line.split(" copy(")[0])]
+    assert copies == []
+
+
 ILSVRC1K = dict(n=320292, d=4096, k=8, classes=1000, frac=0.1, lam=1e-4)
 
 

@@ -375,7 +375,7 @@ def test_the_path_says_which_axis_carries_the_classes():
     assert one.class_axis is None and one.classes == 1
 
 
-@pytest.mark.parametrize("what", ["dense_sets", "stream", "accel", "init",
+@pytest.mark.parametrize("what", ["dense_sets", "hybrid", "accel", "init",
                                   "checkpoint", "block"])
 def test_what_the_lanes_do_not_carry_is_refused_by_name(what, tmp_path):
     import dataclasses
@@ -394,10 +394,16 @@ def test_what_the_lanes_do_not_carry_is_refused_by_name(what, tmp_path):
             resolve_solver_path(ds, 6, None, math="fast")
         return
     ds = shard_dataset(data, k=4, layout="sparse")
-    if what == "stream":
-        stream = dataclasses.replace(ds, sp_row_ptr=ds.labels)
-        with pytest.raises(ValueError, match="kept as a stream"):
-            resolve_solver_path(stream, 6, None, math="fast")
+    if what == "hybrid":
+        # (rows kept as a stream carry the class axis since PR 57:
+        # tests/test_labelstream.py; the hot-column panel does not)
+        import jax.numpy as jnp
+
+        hot = dataclasses.replace(
+            ds, X_hot=jnp.zeros((4, ds.n_shard, 8)),
+            hot_cols=jnp.zeros((4, 8), jnp.int32))
+        with pytest.raises(ValueError, match="hybrid layout"):
+            resolve_solver_path(hot, 6, None, math="fast")
     elif what == "block":
         with pytest.raises(ValueError, match="no class axis"):
             resolve_solver_path(ds, 6, None, math="fast", block_size=8)

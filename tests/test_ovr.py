@@ -224,12 +224,14 @@ def test_the_resolver_refuses_sparse_rows_a_mesh_and_blocks():
     ds = types.SimpleNamespace(
         k=2, labels=labels, layout="sparse", n_hot=0, n_shard=128,
         num_features=64, sp_indices=jnp.zeros((2, 128, 4), jnp.int32),
-        sp_row_ptr=labels, num_classes=3)
+        sp_row_ptr=None, num_classes=3)
     # (sparse rows as a rectangle carry the classes on the lanes since
-    # PR 48, tests/test_labels.py; rows kept as a stream carry none)
-    with pytest.raises(ValueError, match="rows kept as a stream"):
+    # PR 48, tests/test_labels.py, and rows kept as a stream since PR 57,
+    # tests/test_labelstream.py; the hot-column panel carries none)
+    ds.n_hot = 8
+    with pytest.raises(ValueError, match="the hybrid layout"):
         resolve_solver_path(ds, 8, math="fast")
-    ds.layout, ds.sp_row_ptr = "dense", None
+    ds.layout, ds.n_hot = "dense", 0
     with pytest.raises(ValueError, match="block"):
         resolve_solver_path(ds, 8, math="fast", block_size=128)
     path = resolve_solver_path(ds, 8, math="fast", pallas=True)
